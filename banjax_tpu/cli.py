@@ -52,11 +52,31 @@ log = logging.getLogger(__name__)
 KAFKA_STATUS_INTERVAL_SECONDS = 19  # banjax.go:204
 
 
+def place_compile_cache() -> str:
+    """Place JAX's persistent compile cache before the first jit: where
+    JAX_COMPILATION_CACHE_DIR says when it is set (JAX reads it itself;
+    nothing is set in code), else at <checkout>/.jax_cache — a fixed path,
+    because the path is part of the cache key and every (rows, L_p)
+    bucket is tens of seconds of Mosaic.  Returns the directory."""
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        return env_dir
+    import jax
+
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache",
+    )
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 def build_matcher(config, banner, static_lists, regex_states, health=None):
     """The Matcher seam flag (BASELINE.json): cpu (default) or tpu."""
     if config.matcher == "tpu":
         from banjax_tpu.matcher.runner import TpuMatcher
 
+        place_compile_cache()
         return TpuMatcher(config, banner, static_lists, regex_states,
                           health=health)
     if health is not None:

@@ -45,8 +45,8 @@ import time
 
 
 def _pin_cpu_backend() -> None:
-    # mirror __graft_entry__._backend_guard: a worker must never grab a
-    # real accelerator out from under the host process
+    # a worker must never grab a real accelerator out from under the
+    # host process
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 

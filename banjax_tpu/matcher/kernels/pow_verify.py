@@ -27,7 +27,6 @@ from __future__ import annotations
 
 import functools
 import hashlib
-import os
 from typing import List, Sequence, Tuple
 
 import numpy as np
@@ -171,9 +170,7 @@ def leading_zero_bits_batch(
 def _default_interpret() -> bool:
     import jax
 
-    if os.environ.get("BANJAX_POW_INTERPRET"):
-        return True
-    return jax.default_backend() == "cpu"
+    return jax.default_backend() != "tpu"
 
 
 def pow_selftest(interpret: bool = None) -> None:

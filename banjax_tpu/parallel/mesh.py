@@ -30,25 +30,10 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from banjax_tpu.obs import trace
-
-try:
-    from jax import shard_map as _shard_map  # jax >= 0.8
-except ImportError:  # pragma: no cover — older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
-def shard_map(f, mesh, in_specs, out_specs, check_vma=False):
-    """Version shim: the replication-check kwarg was renamed check_rep →
-    check_vma across jax releases; accept either installed spelling."""
-    try:
-        return _shard_map(f, mesh, in_specs=in_specs, out_specs=out_specs,
-                          check_vma=check_vma)
-    except TypeError:
-        return _shard_map(f, mesh, in_specs=in_specs, out_specs=out_specs,
-                          check_rep=check_vma)
 
 from banjax_tpu.matcher import nfa_jax
 from banjax_tpu.matcher.kernels import nfa_match as pallas_nfa
@@ -583,9 +568,10 @@ class ShardedMatchBackend:
                 # to the single-stage path, not kill consume_lines
                 import logging
 
-                logging.getLogger(__name__).info(
-                    "fused mesh prefilter unavailable (%s); single-stage", e
-                )
+                msg = f"fused mesh prefilter unavailable ({e}); single-stage"
+                logging.getLogger(__name__).info(msg)
+                if self.health is not None:
+                    self.health.degraded(msg)
                 self.plan = None
         if fused is not None:
             fn, params, K = fused

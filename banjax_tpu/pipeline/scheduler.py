@@ -115,7 +115,8 @@ def resolve_encode_workers(v: int) -> int:
 
 class _Batch:
     __slots__ = ("lines", "matcher", "state", "t_encode_ms", "t_device_ms",
-                 "t0_device", "kind", "trace_id", "root_span", "e2e")
+                 "t0_device", "kind", "trace_id", "root_span", "e2e",
+                 "builds0")
 
     def __init__(self, lines: List[str], kind: str = "lines"):
         self.lines = lines      # log lines, or _Command items (kind="cmd")
@@ -124,6 +125,11 @@ class _Batch:
         self.t_encode_ms = 0.0
         self.t_device_ms = 0.0
         self.t0_device = 0.0
+        # the matcher's count of device programs built, taken when the
+        # batch starts: a batch during which it moved paid for a compile
+        # (or a compile-cache load) and is no latency sample for the
+        # breaker's budget or the batch sizer
+        self.builds0 = 0
         self.kind = kind
         # span propagation (obs/trace.py): trace id allocated at the
         # encode stage's take; the root "admission" span opens there and
@@ -453,9 +459,12 @@ class PipelineScheduler:
 
     def _encode_batch(self, batch: _Batch) -> None:
         lines = batch.lines
-        t0 = time.perf_counter()
+        # the getter builds the matcher on first use / hot reload (rule
+        # compile, kernel self-tests): start-up, not this batch's encode
         matcher = self._matcher_getter()
+        t0 = time.perf_counter()
         batch.matcher = matcher
+        batch.builds0 = self._builds(batch)
         breaker = getattr(matcher, "breaker", None)
         with trace.span("encode", batch.trace_id,
                         parent=batch.root_span.span_id) as sp:
@@ -655,7 +664,10 @@ class PipelineScheduler:
             self.stats.observe_device(batch.t_device_ms / 1e3)
             note = getattr(batch.matcher, "note_device_outcome", None)
             if note is not None:
-                note(batch.t_device_ms / 1e3, ok=True)
+                if self._builds(batch) != batch.builds0:
+                    note(batch.t_device_ms / 1e3, ok=True, compiled=True)
+                else:
+                    note(batch.t_device_ms / 1e3, ok=True)
         self._q_drain.put(batch)
 
     def _device_failure(self, batch: _Batch, stage: str = "device") -> None:
@@ -676,6 +688,11 @@ class PipelineScheduler:
         note = getattr(batch.matcher, "note_device_outcome", None)
         if note is not None:
             note(batch.t_device_ms / 1e3, ok=False)
+
+    @staticmethod
+    def _builds(batch: _Batch) -> int:
+        fn = getattr(batch.matcher, "compile_events", None)
+        return fn() if fn is not None else 0
 
     # ---- drain stage (admission order — the ordering contract) ----
 
@@ -756,8 +773,8 @@ class PipelineScheduler:
                     # drain completion, measured against the oldest
                     # tailer-read stamp per hop
                     now_mono = time.monotonic()
-                    for hop, t0 in batch.e2e.items():
-                        self.stats.observe_e2e(hop, now_mono - t0)
+                    for hop, t_read in batch.e2e.items():
+                        self.stats.observe_e2e(hop, now_mono - t_read)
                 if self._health is not None:
                     self._health.ok()
             else:
@@ -775,7 +792,8 @@ class PipelineScheduler:
                     "device": batch.t_device_ms,
                     "drain": t_drain_ms,
                 }
-                self._sizer.observe(n, stage_ms)
+                if self._builds(batch) == batch.builds0:
+                    self._sizer.observe(n, stage_ms)
                 # labeled per-stage duration histograms for /metrics —
                 # recorded per batch regardless of tracing (the trace ring
                 # is the sampled view, the histogram the complete one)

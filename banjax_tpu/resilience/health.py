@@ -46,6 +46,9 @@ class ComponentHealth:
         self._status = HealthStatus.HEALTHY
         self._detail = ""
         self._last_beat = clock()
+        # optional read-only facts about the component (the matcher's
+        # resolved device path), shown beside its status in /healthz
+        self.info: Optional[dict] = None
 
     def beat(self) -> None:
         """Heartbeat: refreshes liveness without changing the status."""
@@ -116,5 +119,7 @@ class HealthRegistry:
             entry = {"status": str(status), "age_seconds": round(age, 1)}
             if detail:
                 entry["detail"] = detail
+            if comp.info:
+                entry["info"] = comp.info
             out[comp.name] = entry
         return {"status": str(overall), "components": out}

@@ -1,5 +1,4 @@
-"""Hardware tuning sweep — run AFTER scripts/hw_session.sh has banked the
-headline sections. Sweeps the fused prefilter's (block_b, cols) tiling and
+"""Hardware tuning sweep. Sweeps the fused prefilter's (block_b, cols) tiling and
 the device-resident batch size on the real chip, printing one JSON line per
 configuration; the best configuration can then be pinned in
 prefilter.FusedPrefilter's defaults and bench re-run.

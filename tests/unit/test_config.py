@@ -357,3 +357,35 @@ def test_fleet_observability_keys_defaults_and_validation():
     ):
         with pytest.raises(ValueError):
             config_from_yaml_text(bad)
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_is_placed_from_outside_or_in_the_checkout(
+    monkeypatch, tmp_path, from_env
+):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing is set in code; unset,
+    the cache goes to the fixed <checkout>/.jax_cache."""
+    import os
+
+    import jax
+
+    from banjax_tpu import cli
+
+    was = jax.config.jax_compilation_cache_dir
+    sentinel = str(tmp_path / "untouched")
+    jax.config.update("jax_compilation_cache_dir", sentinel)
+    try:
+        if from_env:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+            assert cli.place_compile_cache() == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == sentinel
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            want = os.path.join(
+                os.path.dirname(os.path.dirname(os.path.abspath(cli.__file__))),
+                ".jax_cache",
+            )
+            assert cli.place_compile_cache() == want
+            assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)

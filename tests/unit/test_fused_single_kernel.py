@@ -356,3 +356,106 @@ def test_downgrade_leaves_health_note(monkeypatch):
 def test_config_validation_rejects_bad_value():
     with pytest.raises(ValueError, match="pallas_single_kernel"):
         config_from_yaml_text("pallas_single_kernel: maybe\n")
+
+
+# ---------------------------------------------------------------------------
+# the matcher's description + no quiet way off the device path (PR 24)
+# ---------------------------------------------------------------------------
+
+
+def _raise_unsupported(*a, **k):
+    from banjax_tpu.matcher.kernels.nfa_match import PallasUnsupported
+
+    raise PallasUnsupported("synthetic")
+
+
+def _raise_runtime(*a, **k):
+    raise RuntimeError("synthetic")
+
+
+def _mesh_xla_only(orig):
+    def init(self, *a, backend="pallas", **k):
+        if backend != "xla":
+            _raise_unsupported()
+        orig(self, *a, backend=backend, **k)
+
+    return init
+
+
+@pytest.mark.parametrize("site", [
+    "pallas-asked-off-tpu", "pallas-prep", "prefilter-unavailable",
+    "plan-build", "single-kernel", "mesh-plan", "mesh-pallas",
+])
+def test_every_init_downgrade_leaves_a_degraded_note(monkeypatch, site):
+    """Each init-time step off the intended device path must show as a
+    DEGRADED note on the `matcher` health component — and survive the
+    per-batch breaker accounting — and in describe()['downgrades']."""
+    from banjax_tpu.matcher import prefilter
+    from banjax_tpu.matcher.kernels import nfa_match
+    from banjax_tpu.parallel import mesh
+
+    ov = {"matcher_backend": "pallas-interpret"}
+    if site == "pallas-asked-off-tpu":
+        ov = {"matcher_backend": "pallas"}
+    elif site == "pallas-prep":
+        monkeypatch.setattr(nfa_match, "prepare", _raise_unsupported)
+    elif site == "prefilter-unavailable":
+        monkeypatch.setattr(
+            prefilter.FusedPrefilter, "__init__", _raise_unsupported
+        )
+    elif site == "plan-build":
+        monkeypatch.setattr(prefilter, "build_plan", _raise_runtime)
+    elif site == "single-kernel":
+        monkeypatch.setattr(fmw, "scan_selftest", _raise_runtime)
+        ov["matcher_device_windows"] = True
+    elif site == "mesh-plan":
+        monkeypatch.setattr(prefilter, "build_plan", _raise_runtime)
+        ov = {"matcher_mesh_devices": 2}
+    elif site == "mesh-pallas":
+        monkeypatch.setattr(
+            mesh.ShardedMatchBackend, "__init__",
+            _mesh_xla_only(mesh.ShardedMatchBackend.__init__),
+        )
+        ov.update(matcher_mesh_devices=2)
+    health = HealthRegistry()
+    m, _ = _mk(TpuMatcher, _rules_yaml([r"GET /a.*"]), health=health, **ov)
+    try:
+        comp = health.get("matcher")
+        status, detail, _ = comp.effective_status()
+        assert status == HealthStatus.DEGRADED, (site, detail)
+        assert "; ".join(m.describe()["downgrades"]) == detail
+        now = time.time()
+        m.consume_lines([f"{now:.6f} 1.2.3.4 GET h.com GET /a HTTP/1.1 ua -"], now)
+        assert comp.effective_status()[0] == HealthStatus.DEGRADED
+    finally:
+        m.close()
+
+
+def test_description_is_honest_on_the_cpu_backend():
+    """auto on a CPU backend is the XLA scan with an interpreted window
+    scan: describe() says so, no downgrade is noted, and /healthz's
+    matcher entry carries the same description."""
+    health = HealthRegistry()
+    m, _ = _mk(TpuMatcher, _rules_yaml([r"GET /a.*"]), health=health,
+               matcher_device_windows=True)
+    try:
+        d = m.describe()
+        assert d["platform"] == "cpu" and d["device_count"] == len(jax.devices())
+        assert d["nfa_backend"] == "xla" and d["match_interpret"] is None
+        assert d["fused_protocol"] == "single-kernel"
+        assert d["scan_interpret"] is True
+        assert d["prefilter"] is True and d["mesh_shape"] is None
+        assert d["downgrades"] == []
+        entry = health.snapshot()["components"]["matcher"]
+        assert entry["status"] == "healthy" and entry["info"] == d
+    finally:
+        m.close()
+
+    interp, _ = _mk(TpuMatcher, _rules_yaml([r"GET /a.*"]),
+                    matcher_backend="pallas-interpret")
+    try:
+        d = interp.describe()
+        assert d["nfa_backend"] == "pallas" and d["match_interpret"] is True
+        assert d["fused_protocol"] == "classic"
+    finally:
+        interp.close()

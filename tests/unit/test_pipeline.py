@@ -520,6 +520,74 @@ class TestProbeAndBudget:
         assert m.effective_latency_budget_s() == pytest.approx(0.3, rel=0.1)
 
 
+class TestStartUpSamples:
+    """What the sizer and the breaker's budget learn from (PR 24: found on
+    the first chip run — a burst collapsed the batch target to 64, and a
+    cold start opened the breaker)."""
+
+    def test_drain_sample_is_not_the_tailer_read_age(self):
+        """The drain-stage sample handed to the sizer is the drain's own
+        wall time, not the age of the batch since the tailer read it."""
+
+        class PlainMatcher:
+            def consume_lines(self, lines, now_unix=None):
+                return [ConsumeLineResult() for _ in lines]
+
+        sched = PipelineScheduler(PlainMatcher)
+        sched.start()
+        sched.submit(["a b c d e f g"] * 10,
+                     t_read=time.monotonic() - 1000.0)
+        assert sched.flush(10)
+        sched.stop()
+        assert sched._sizer.stage_ewma_ms["drain"] < 60_000.0
+
+    def test_batch_that_built_a_program_is_no_latency_sample(self):
+        """A batch during which the matcher's build counter moved is left
+        out of the sizer's samples and of the breaker's latency budget;
+        an equally slow batch that built nothing still counts."""
+        calls = []
+
+        class BuildingMatcher:
+            builds = 0
+
+            def compile_events(self):
+                return self.builds
+
+            def pipeline_begin(self, lines, now):
+                return {"results": [ConsumeLineResult() for _ in lines]}
+
+            def pipeline_submit(self, state):
+                if state["results"] and calls == []:
+                    self.builds += 1  # first batch pays for a compile
+
+            def pipeline_collect(self, state):
+                pass
+
+            def pipeline_finish(self, state, now):
+                return state["results"], 0
+
+            def note_device_outcome(self, elapsed_s, ok, compiled=False):
+                calls.append(compiled)
+
+        m = BuildingMatcher()
+        sched = PipelineScheduler(lambda: m, encode_workers=0)
+        sched.start()
+        for _ in range(2):
+            sched.submit(["a b c d e f g"] * 10)
+            assert sched.flush(10)
+        sched.stop()
+        assert calls == [True, False]
+        # only the second batch reached the sizer's stage EWMAs
+        assert sched._sizer.stage_ewma_ms["device"] is not None
+
+        real, _, _ = make_matcher(matcher_latency_budget_ms=1.0,
+                                  breaker_failure_threshold=1)
+        real.note_device_outcome(5.0, ok=True, compiled=True)
+        assert real.budget_trips == 0 and real.breaker.state == CLOSED
+        real.note_device_outcome(5.0, ok=True)
+        assert real.budget_trips == 1 and real.breaker.state == OPEN
+
+
 # ---------------------------------------------------------------------------
 # soak (excluded from tier-1: -m 'not slow')
 # ---------------------------------------------------------------------------
