@@ -1,0 +1,8 @@
+"""1 - union of device op intervals over the traced span."""
+
+
+def read(ctx):
+    tr = ctx["trace"]
+    if not tr or not tr["window_s"]:
+        return None
+    return 100.0 * (1.0 - tr["busy_s"] / tr["window_s"])
