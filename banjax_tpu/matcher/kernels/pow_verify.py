@@ -13,9 +13,11 @@ per micro-batch.
 Layout follows fused_match_window.py: 2-D refs ([16, B] message words
 in, [1, B] zero-bit counts out), batch padded to the 128-wide TPU lane
 so every shape is static, and a cached pallas_call builder per (B,
-interpret).  All arithmetic is uint32 with wrapping adds; rotr is the
-two-shift form and clz is a portable bit-length cascade (no lax.clz —
-see /opt/skills/guides/pallas_guide.md on lowering portability).
+interpret).  All arithmetic is uint32 with wrapping adds.  The 64 rounds
+are two rolled loops (0-15 over the message, 16-63 extending a rolling
+16-word schedule in place) over VMEM scratch, constants in SMEM: XLA's
+CPU compiler does not finish the unrolled straight-line graph, and the
+same body runs interpreted on the CPU and through Mosaic on the chip.
 
 ``pow_selftest`` proves the kernel against hashlib + the pure-Python
 count_zero_bits_from_left before the verifier routes real traffic to
@@ -41,7 +43,8 @@ LANE = 128  # TPU lane width — batch dim padded to a multiple of this
 _H0 = (0x6A09E667, 0xBB67AE85, 0x3C6EF372, 0xA54FF53A,
        0x510E527F, 0x9B05688C, 0x1F83D9AB, 0x5BE0CD19)
 
-_K = (
+# int32 bit patterns: the kernel reads them as SMEM scalars, which are signed
+_K = np.asarray((
     0x428A2F98, 0x71374491, 0xB5C0FBCF, 0xE9B5DBA5,
     0x3956C25B, 0x59F111F1, 0x923F82A4, 0xAB1C5ED5,
     0xD807AA98, 0x12835B01, 0x243185BE, 0x550C7DC3,
@@ -58,58 +61,51 @@ _K = (
     0x391C0CB3, 0x4ED8AA4A, 0x5B9CCA4F, 0x682E6FF3,
     0x748F82EE, 0x78A5636F, 0x84C87814, 0x8CC70208,
     0x90BEFFFA, 0xA4506CEB, 0xBEF9A3F7, 0xC67178F2,
-)
+), np.uint32).view(np.int32)
 
 
 def _rotr(x, n: int):
+    return (x >> np.uint32(n)) | (x << np.uint32(32 - n))
+
+
+def _pow_kernel(k_ref, msg_ref, out_ref, w_ref, s_ref):
+    import jax
     import jax.numpy as jnp
+    from jax.experimental import pallas as pl
 
-    return (x >> jnp.uint32(n)) | (x << jnp.uint32(32 - n))
+    w_ref[...] = msg_ref[...]
+    for j, v in enumerate(_H0):
+        s_ref[j : j + 1, :] = jnp.full((1, s_ref.shape[1]), v, jnp.uint32)
 
+    def w_row(i):
+        return w_ref[pl.ds(i & 15, 1), :]
 
-def _clz32(x):
-    """Leading zeros of a [1, B] uint32 via a bit-length cascade."""
-    import jax.numpy as jnp
-
-    bl = jnp.zeros(x.shape, jnp.int32)
-    y = x
-    for shift in (16, 8, 4, 2, 1):
-        cond = (y >> jnp.uint32(shift)) > jnp.uint32(0)
-        bl = bl + jnp.where(cond, shift, 0).astype(jnp.int32)
-        y = jnp.where(cond, y >> jnp.uint32(shift), y)
-    bl = bl + (y > jnp.uint32(0)).astype(jnp.int32)
-    return jnp.int32(32) - bl
-
-
-def _pow_kernel(msg_ref, out_ref):
-    import jax.numpy as jnp
-
-    # rolling 16-word schedule keeps VMEM at 16 rows, not 64
-    w = [msg_ref[i : i + 1, :] for i in range(16)]
-    a, b, c, d, e, f, g, h = (jnp.full_like(w[0], jnp.uint32(v)) for v in _H0)
-    for i in range(64):
-        if i < 16:
-            wi = w[i]
-        else:
-            w15 = w[(i - 15) % 16]
-            w2 = w[(i - 2) % 16]
-            s0 = _rotr(w15, 7) ^ _rotr(w15, 18) ^ (w15 >> jnp.uint32(3))
-            s1 = _rotr(w2, 17) ^ _rotr(w2, 19) ^ (w2 >> jnp.uint32(10))
-            wi = w[i % 16] + s0 + w[(i - 7) % 16] + s1
-            w[i % 16] = wi
+    def sha_round(i, carry):
+        a, b, c, d, e, f, g, h = (s_ref[j : j + 1, :] for j in range(8))
         ch = (e & f) ^ (~e & g)
         t1 = h + (_rotr(e, 6) ^ _rotr(e, 11) ^ _rotr(e, 25)) + ch \
-            + jnp.uint32(_K[i]) + wi
+            + k_ref[i].astype(jnp.uint32) + w_row(i)
         maj = (a & b) ^ (a & c) ^ (b & c)
         t2 = (_rotr(a, 2) ^ _rotr(a, 13) ^ _rotr(a, 22)) + maj
-        a, b, c, d, e, f, g, h = t1 + t2, a, b, c, d + t1, e, f, g
+        for j, x in enumerate((t1 + t2, a, b, c, d + t1, e, f, g)):
+            s_ref[j : j + 1, :] = x
+        return carry
 
-    digest = [x + jnp.uint32(v)
-              for x, v in zip((a, b, c, d, e, f, g, h), _H0)]
-    total = jnp.zeros(digest[0].shape, jnp.int32)
-    live = jnp.ones(digest[0].shape, jnp.bool_)
-    for word in digest:
-        total = total + jnp.where(live, _clz32(word), 0)
+    def extend_schedule_then_round(i, carry):
+        w15, w2 = w_row(i - 15), w_row(i - 2)
+        s0 = _rotr(w15, 7) ^ _rotr(w15, 18) ^ (w15 >> jnp.uint32(3))
+        s1 = _rotr(w2, 17) ^ _rotr(w2, 19) ^ (w2 >> jnp.uint32(10))
+        w_ref[pl.ds(i & 15, 1), :] = w_row(i) + s0 + w_row(i - 7) + s1
+        return sha_round(i, carry)
+
+    jax.lax.fori_loop(0, 16, sha_round, 0)
+    jax.lax.fori_loop(16, 64, extend_schedule_then_round, 0)
+
+    total = jnp.zeros((1, s_ref.shape[1]), jnp.int32)
+    live = jnp.ones(total.shape, jnp.bool_)
+    for j, v in enumerate(_H0):
+        word = s_ref[j : j + 1, :] + jnp.uint32(v)
+        total = total + jnp.where(live, jax.lax.clz(word).astype(jnp.int32), 0)
         live = live & (word == jnp.uint32(0))
     out_ref[0:1, :] = total
 
@@ -117,14 +113,23 @@ def _pow_kernel(msg_ref, out_ref):
 @functools.lru_cache(maxsize=16)
 def _pow_call(batch: int, interpret: bool):
     import jax
-    import jax.numpy as jnp
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
-    return pl.pallas_call(
+    call = pl.pallas_call(
         _pow_kernel,
-        out_shape=jax.ShapeDtypeStruct((1, batch), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((1, batch), np.int32),
+        in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.VMEM),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((16, batch), np.uint32),
+            pltpu.VMEM((8, batch), np.uint32),
+        ],
         interpret=interpret,
     )
+    return functools.partial(call, _K)
 
 
 def pack_pow_messages(payloads: Sequence[bytes]) -> Tuple[np.ndarray, int]:
@@ -134,7 +139,6 @@ def pack_pow_messages(payloads: Sequence[bytes]) -> Tuple[np.ndarray, int]:
     sliced off."""
     n = len(payloads)
     padded = max(LANE, -(-n // LANE) * LANE)
-    words = np.zeros((16, padded), dtype=np.uint32)
     buf = np.zeros((padded, 64), dtype=np.uint8)
     for j, payload in enumerate(payloads):
         if len(payload) != POW_MESSAGE_BYTES:
@@ -143,14 +147,13 @@ def pack_pow_messages(payloads: Sequence[bytes]) -> Tuple[np.ndarray, int]:
                 f"got {len(payload)}"
             )
         buf[j, :POW_MESSAGE_BYTES] = np.frombuffer(payload, np.uint8)
-    words[:, :] = (
+    words = (
         buf.reshape(padded, 16, 4)
         .astype(np.uint32)
         .transpose(1, 0, 2)
         @ np.asarray([1 << 24, 1 << 16, 1 << 8, 1], np.uint32)
     )
     words[13, :] = _PAD_WORD_80
-    words[14, :] = 0
     words[15, :] = _LEN_BITS
     return words, n
 
@@ -161,9 +164,7 @@ def leading_zero_bits_batch(
     """Leading-zero-bit counts of sha256(payload) for each 52-byte
     payload, one kernel dispatch."""
     words, n = pack_pow_messages(payloads)
-    import jax.numpy as jnp
-
-    out = _pow_call(words.shape[1], bool(interpret))(jnp.asarray(words))
+    out = _pow_call(words.shape[1], bool(interpret))(words)
     return np.asarray(out)[0, :n]
 
 
@@ -186,8 +187,7 @@ def pow_selftest(interpret: bool = None) -> None:
         rng.integers(0, 256, POW_MESSAGE_BYTES, np.uint8).tobytes()
         for _ in range(24)
     ]
-    # force easy leading-zero structure into some lanes so the clz
-    # cascade's word-boundary handling is actually exercised
+    # the all-zero message is also what the padding lanes hash
     payloads.append(b"\x00" * POW_MESSAGE_BYTES)
     payloads.append(b"\x00" * 51 + b"\x01")
     got = leading_zero_bits_batch(payloads, interpret=interpret)

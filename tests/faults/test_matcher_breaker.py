@@ -92,10 +92,16 @@ def test_breaker_trip_fallback_healthz_and_recovery(app_factory):
 
     # 5. disarm + recovery window (0.05 s in the fixture): the half-open
     #    probe batch runs the device path again and closes the breaker
+    #    The tailer feeds the /healthz requests' own log lines through the
+    #    same breaker from its thread: its probe can be the one in flight
+    #    when ours asks, and ours then rides the fallback — so send batches
+    #    until a probe of either has closed it.
     failpoints.disarm("matcher.device")
-    time.sleep(0.08)
-    results = app._consume_lines(_lines(3))
-    assert all(not r.error for r in results)
+    deadline = time.monotonic() + 5.0
+    while matcher.breaker.state != CLOSED and time.monotonic() < deadline:
+        time.sleep(0.08)
+        results = app._consume_lines(_lines(3))
+        assert all(not r.error for r in results)
     assert matcher.breaker.state == CLOSED
     code, snap = _healthz()
     assert code == 200

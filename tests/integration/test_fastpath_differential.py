@@ -78,7 +78,9 @@ def _raw_request(ip, path="/", host=HOST, cookie=None, method="GET"):
 # fresh randomness on both sides of the diff: minted session values
 # (echoed into a header and a Set-Cookie) and challenge payloads
 _MASKS = (
-    (re.compile(rb"(X-Deflect-Session: )(\S+)"), rb"\1MASKED"),
+    # to the end of the line: a '+' of the cookie's base64 is echoed as a
+    # space (the reference's second QueryUnescape, decision_chain.py)
+    (re.compile(rb"(X-Deflect-Session: )([^\r\n]+)"), rb"\1MASKED"),
     (re.compile(rb"(deflect_session=)([^;\r\n]+)"), rb"\1MASKED"),
     (re.compile(rb"(deflect_challenge3=)([^;\r\n]+)"), rb"\1MASKED"),
     (re.compile(rb"[A-Za-z0-9+/=]{40,}"), rb"MASKEDB64"),

@@ -108,14 +108,20 @@ from tests.perf.test_baseline_ladder import _access_log_lines
 
 yaml_text = open(sys.argv[2]).read()
 cfg = config_from_yaml_text(yaml_text)
-m = CpuMatcher(cfg, MockBanner(), StaticDecisionLists(cfg), RegexRateLimitStates())
 now = time.time()
 n = int(sys.argv[3])
 lines = _access_log_lines(n, now, n_ips=64)
-t0 = time.perf_counter()
-for line in lines:  # the reference is line-at-a-time by design
-    m.consume_line(line, now)
-print(json.dumps({"elapsed": time.perf_counter() - t0}))
+
+def measure():
+    m = CpuMatcher(cfg, MockBanner(), StaticDecisionLists(cfg), RegexRateLimitStates())
+    t0 = time.perf_counter()
+    for line in lines:  # the reference is line-at-a-time by design
+        m.consume_line(line, now)
+    return time.perf_counter() - t0
+
+# best of three: the tier-1 neighbours' XLA compiles take this loop's core
+# for a second at a time; a real 3x regression fails all three
+print(json.dumps({"elapsed": min(measure() for _ in range(3))}))
 """
 
 

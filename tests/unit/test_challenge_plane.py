@@ -55,9 +55,32 @@ def test_kernel_matches_hashlib_across_lane_padding_shapes(batch):
     assert [int(b) for b in got] == [_ref_bits(p) for p in payloads]
 
 
+# "banjax-pr26-pow-" + a counter, zero-filled to 52 bytes: found once by
+# counting up (2^34 digests for the last); hashlib is the check here
+_PAYLOAD_HEAD_BY_ZERO_BITS = {
+    0: "62616e6a61782d707232362d706f772d0000000000000000",
+    8: "62616e6a61782d707232362d706f772d0000000062010000",
+    31: "62616e6a61782d707232362d706f772d01000000cc5010b4",
+    32: "62616e6a61782d707232362d706f772d0000000097cc260e",
+    33: "62616e6a61782d707232362d706f772d0100000000a8820001",
+}
+
+
+@pytest.mark.parametrize("bits", sorted(_PAYLOAD_HEAD_BY_ZERO_BITS))
+def test_kernel_counts_known_zero_bits_across_the_word_boundary(bits):
+    """Digests with a known count on both sides of the first digest
+    word's end: the rolled rounds' digest words and the live-word masking
+    of the count are checked against values, not only random payloads
+    (whose counts rarely pass 8)."""
+    head = bytes.fromhex(_PAYLOAD_HEAD_BY_ZERO_BITS[bits])
+    payload = head.ljust(POW_MESSAGE_BYTES, b"\x00")
+    assert _ref_bits(payload) == bits
+    got = leading_zero_bits_batch([payload], interpret=True)
+    assert got.tolist() == [bits]
+
+
 def test_kernel_degenerate_payloads():
-    """All-zero and all-ones payloads plus near-misses — the clz cascade
-    and the live-digest masking have no branch untested."""
+    """All-zero and all-ones payloads plus near-misses."""
     payloads = [
         b"\x00" * POW_MESSAGE_BYTES,
         b"\xff" * POW_MESSAGE_BYTES,
