@@ -28,8 +28,9 @@ to 0 (not 1) on exceed, FirstTime/OutsideInterval/InsideInterval match
 types, and seen_ip = "the IP had any state before this event".
 
 IP slots are assigned host-side (dict + LRU); evicting a slot queues a
-device-side row clear that runs in the next maintenance step, so the device
-never needs a host round-trip mid-batch. Eviction is LOSSLESS: a host-side
+device-side generation bump that runs in the next maintenance step (one
+element per evicted slot, whatever the rule count), so the device never
+needs a host round-trip mid-batch. Eviction is LOSSLESS: a host-side
 shadow (updated from each batch's event-final states, which the scan
 computes anyway) holds every (ip, rule) counter, and a re-admitted IP's
 rows are scattered back onto the device before its next events — beyond
@@ -59,6 +60,16 @@ from banjax_tpu.decisions.rate_limit import (
 _NS_PER_S = 1_000_000_000
 
 _MIN_ROW_BUCKET = 64
+_MIN_MAINT_BUCKET = 256
+
+
+def _bucket(n: int, floor: int) -> int:
+    """`floor` doubled until it holds n: operand lengths are trace keys of
+    the jitted steps, so they come from a bounded set of classes."""
+    b = floor
+    while b < n:
+        b <<= 1
+    return b
 
 
 def _bucket_rows(n: int) -> int:
@@ -66,10 +77,7 @@ def _bucket_rows(n: int) -> int:
     batch arrays' shapes as trace keys, so unbucketed sizes would compile a
     fresh segmented-scan program per distinct B (unbounded jit-cache growth
     in the hot path). Pad rows carry bits=0 and so produce no events."""
-    b = _MIN_ROW_BUCKET
-    while b < n:
-        b <<= 1
-    return b
+    return _bucket(n, _MIN_ROW_BUCKET)
 
 
 def split_ns(ts_ns) -> Tuple[np.ndarray, np.ndarray]:
@@ -94,12 +102,21 @@ def _pair_sub(a_s, a_ns, b_s, b_ns):
 
 @dataclasses.dataclass
 class DeviceWindowState:
-    """The donated device arrays (flat key = slot * n_rules + rule)."""
+    """The donated device arrays (flat key = slot * n_rules + rule).
+
+    State exists for a key iff `key_gen[key] == slot_gen[key // n_rules]`:
+    a write stamps the key with its slot's generation, and evicting a slot
+    bumps the one `slot_gen` entry, which invalidates all n_rules keys of
+    the slot at once.  A fresh table is slot_gen 1 / key_gen 0.  Both are
+    int32 on purpose: a stale key must never read as valid again, and a
+    slot that turns over every few seconds would wrap a 16-bit generation
+    within days, an int32 one in centuries."""
 
     hits: jnp.ndarray      # [cap * R] int32
     start_s: jnp.ndarray   # [cap * R] int32
     start_ns: jnp.ndarray  # [cap * R] int32
-    valid: jnp.ndarray     # [cap * R] bool — state exists for this key
+    key_gen: jnp.ndarray   # [cap * R] int32 — slot generation at last write
+    slot_gen: jnp.ndarray  # [cap] int32 — bumped at every eviction
     ip_seen: jnp.ndarray   # [cap] bool — slot has any state (seen_ip flag)
 
 
@@ -172,7 +189,6 @@ def _apply_core(
     the single-kernel path passes the Pallas scan from
     kernels/fused_match_window.py; None keeps the XLA lax.scan."""
     cap_r = state.hits.shape[0]
-    valid = state.valid
     ip_seen = state.ip_seen
 
     fire = (bits != 0) & active_table[host_idx]
@@ -189,6 +205,7 @@ def _apply_core(
     # 2. stable sort by key (ties keep row-major order)
     order = jnp.lexsort((seq, key))
     key_s = key[order]
+    slot_s = slot[order]
     lines_s = lines[order]
     rules_s = jnp.where(key_s >= cap_r, jnp.int32(0), rules[order])
     e_ts_s = ts_s[jnp.maximum(lines_s, 0)]
@@ -210,7 +227,8 @@ def _apply_core(
     g_hits = state.hits[jnp.minimum(key_s, cap_r - 1)]
     g_ss = state.start_s[jnp.minimum(key_s, cap_r - 1)]
     g_sns = state.start_ns[jnp.minimum(key_s, cap_r - 1)]
-    g_valid = valid[jnp.minimum(key_s, cap_r - 1)] & ~pad_s
+    gen_s = state.slot_gen[slot_s]
+    g_valid = (state.key_gen[jnp.minimum(key_s, cap_r - 1)] == gen_s) & ~pad_s
 
     lim_e = limits[rules_s]
     ivs_e = iv_s[rules_s]
@@ -239,11 +257,12 @@ def _apply_core(
     hits = state.hits.at[wb_key].set(f_hits, mode="drop")
     start_s = state.start_s.at[wb_key].set(f_ss, mode="drop")
     start_ns = state.start_ns.at[wb_key].set(f_sns, mode="drop")
-    valid = valid.at[wb_key].set(True, mode="drop")
+    key_gen = state.key_gen.at[wb_key].set(gen_s, mode="drop")
     ip_seen = ip_seen.at[seen_idx].set(True, mode="drop")
 
     new_state = DeviceWindowState(
-        hits=hits, start_s=start_s, start_ns=start_ns, valid=valid, ip_seen=ip_seen
+        hits=hits, start_s=start_s, start_ns=start_ns, key_gen=key_gen,
+        slot_gen=state.slot_gen, ip_seen=ip_seen,
     )
     out = {
         "line": lines_s,
@@ -276,33 +295,44 @@ def _apply_step(state, bits, active_table, host_idx, slot_ids, ts_s, ts_ns,
 @functools.partial(jax.jit, donate_argnums=(0,))
 def _maintenance_step(
     state: DeviceWindowState,
-    ev_keys: jnp.ndarray,   # [Ke] int32 flat keys to invalidate (cap_r = none)
-    ev_slots: jnp.ndarray,  # [K] int32 slots to clear seen flag (cap = none)
+    ev_slots: jnp.ndarray,  # [K] int32 evicted slots (cap = none)
+    r_slots: jnp.ndarray,   # [K] int32 slots to mark seen (cap = none)
     r_keys: jnp.ndarray,    # [Kr] int32 flat keys to restore (cap_r = none)
     r_hits: jnp.ndarray,    # [Kr] int32
     r_ss: jnp.ndarray,      # [Kr] int32
     r_sns: jnp.ndarray,     # [Kr] int32
-    r_slots: jnp.ndarray,   # [K2] int32 slots to mark seen (cap = none)
 ):
     """Evictions THEN restores, in one dispatch: a slot can be evicted and
     immediately reassigned+restored between two apply steps, so the order
-    within this step is what keeps the restored state from being cleared."""
-    valid = state.valid.at[ev_keys].set(False, mode="drop")
+    within this step is what keeps the restored state from being cleared —
+    the restored keys are stamped with the generation AFTER the bump.
+
+    Cost: two [K] scatters for the K evicted slots (the bump invalidates
+    every rule's key of a slot without touching one of them) and four [Kr]
+    scatters for the Kr restored keys; nothing here is sized by n_rules.
+    A slot evicted twice between two steps appears twice in `ev_slots`
+    and is bumped twice, which is as good as once."""
+    cap = state.slot_gen.shape[0]
+    n_rules = state.key_gen.shape[0] // cap
+    slot_gen = state.slot_gen.at[ev_slots].add(1, mode="drop")
     ip_seen = state.ip_seen.at[ev_slots].set(False, mode="drop")
     hits = state.hits.at[r_keys].set(r_hits, mode="drop")
     start_s = state.start_s.at[r_keys].set(r_ss, mode="drop")
     start_ns = state.start_ns.at[r_keys].set(r_sns, mode="drop")
-    valid = valid.at[r_keys].set(True, mode="drop")
+    r_gen = slot_gen[jnp.minimum(r_keys // n_rules, cap - 1)]
+    key_gen = state.key_gen.at[r_keys].set(r_gen, mode="drop")
     ip_seen = ip_seen.at[r_slots].set(True, mode="drop")
     return DeviceWindowState(
-        hits=hits, start_s=start_s, start_ns=start_ns, valid=valid,
-        ip_seen=ip_seen,
+        hits=hits, start_s=start_s, start_ns=start_ns, key_gen=key_gen,
+        slot_gen=slot_gen, ip_seen=ip_seen,
     )
 
 
 jax.tree_util.register_dataclass(
     DeviceWindowState,
-    data_fields=["hits", "start_s", "start_ns", "valid", "ip_seen"],
+    data_fields=[
+        "hits", "start_s", "start_ns", "key_gen", "slot_gen", "ip_seen",
+    ],
     meta_fields=[],
 )
 
@@ -326,10 +356,10 @@ class DeviceWindows:
     pulling only the requested slots back from the device.
     """
 
-    # auto-size memory budget: device state is 13 bytes per (slot, rule)
-    # (3x int32 + valid bool) plus [capacity] ip_seen; cap the flat arrays
-    # well under the v5e-1's 16 GB HBM so the matcher never squeezes the
-    # kernels' working set
+    # auto-size memory budget: device state is 16 bytes per (slot, rule)
+    # (hits, start_s, start_ns and key_gen, int32 each) plus 5 per slot
+    # (slot_gen, ip_seen); cap the flat arrays well under the v5e-1's
+    # 16 GB HBM so the matcher never squeezes the kernels' working set
     AUTO_START_CAPACITY = 16384
     AUTO_MEM_BUDGET_BYTES = 2 << 30
 
@@ -353,7 +383,7 @@ class DeviceWindows:
             # ceiling and the start size (the 256-slot floor just keeps the
             # table functional); the start never exceeds the budget
             self.max_capacity = max(
-                256, int(self.AUTO_MEM_BUDGET_BYTES // (13 * self.n_rules))
+                256, int(self.AUTO_MEM_BUDGET_BYTES // (16 * self.n_rules))
             )
             capacity = min(self.AUTO_START_CAPACITY, self.max_capacity)
         else:
@@ -447,6 +477,11 @@ class DeviceWindows:
         # eviction only costs performance (a restore on re-admission), never
         # correctness; this counter surfaces the capacity pressure
         self.eviction_count = 0
+        # maintenance dispatches, and the int32 elements handed to the
+        # device by them (padding included): elems / evictions says whether
+        # the step stayed O(evicted slots) — a few, not a multiple of n_rules
+        self.maintenance_steps = 0
+        self.maintenance_elems = 0
         # Host shadow of the device counters: ip → (rule_id → (hits, s, ns)),
         # both dicts in first-event insertion order — exactly the reference
         # host dict's shape (rate_limit.go:37-78, which never forgets).
@@ -465,7 +500,8 @@ class DeviceWindows:
             hits=jnp.zeros((cap_r,), dtype=jnp.int32),
             start_s=jnp.zeros((cap_r,), dtype=jnp.int32),
             start_ns=jnp.zeros((cap_r,), dtype=jnp.int32),
-            valid=jnp.zeros((cap_r,), dtype=jnp.bool_),
+            key_gen=jnp.zeros((cap_r,), dtype=jnp.int32),
+            slot_gen=jnp.ones((self.capacity,), dtype=jnp.int32),
             ip_seen=jnp.zeros((self.capacity,), dtype=jnp.bool_),
         )
 
@@ -822,7 +858,8 @@ class DeviceWindows:
 
     def _grow_locked(self, new_capacity: int) -> None:
         """Double the slot table in place (auto-size): pad the flat device
-        arrays with zeros and free-list the new high slots. Existing slot
+        arrays (zeros; `slot_gen` with its fresh value 1, so every new key
+        reads invalid) and free-list the new high slots. Existing slot
         ids, pending evictions/restores, and the shadow are untouched; the
         only cost is one recompile of the apply programs at the new state
         shape (geometric growth bounds that to ~log2(max/start) compiles
@@ -839,7 +876,8 @@ class DeviceWindows:
             start_ns=jnp.concatenate(
                 [s.start_ns, jnp.zeros(pad_r, jnp.int32)]
             ),
-            valid=jnp.concatenate([s.valid, jnp.zeros(pad_r, jnp.bool_)]),
+            key_gen=jnp.concatenate([s.key_gen, jnp.zeros(pad_r, jnp.int32)]),
+            slot_gen=jnp.concatenate([s.slot_gen, jnp.ones(add, jnp.int32)]),
             ip_seen=jnp.concatenate(
                 [s.ip_seen, jnp.zeros(add, jnp.bool_)]
             ),
@@ -1056,8 +1094,12 @@ class DeviceWindows:
 
     def _run_maintenance_locked(self) -> None:
         """Drain queued evictions + restores into the device state (caller
-        holds the lock). Sizes bucket to powers of two so the jit cache
-        stays bounded; padded entries scatter out of range and drop."""
+        holds the lock).  What goes to the device is one int32 per evicted
+        slot and per restored slot, and four per restored key — never a
+        per-(slot, rule) expansion.  The slot operands and the restore
+        operands are padded to a power of two each, on their own (restores
+        are sparse beside evictions), so the jit cache stays bounded;
+        padded entries scatter out of range and drop."""
         if not self._pending_evict and not self._pending_restore:
             return
         cap_r = self.capacity * self.n_rules
@@ -1066,13 +1108,6 @@ class DeviceWindows:
         self._pending_evict = []
         self._pending_restore = []
 
-        ev_keys_np = (
-            (np.asarray(pend_ev, dtype=np.int64)[:, None] * self.n_rules
-             + np.arange(self.n_rules, dtype=np.int64)[None, :]).ravel()
-            .astype(np.int32)
-            if pend_ev else np.empty(0, dtype=np.int32)
-        )
-        ev_slots_np = np.asarray(pend_ev, dtype=np.int32)
         r_keys: List[int] = []
         r_hits: List[int] = []
         r_ss: List[int] = []
@@ -1101,21 +1136,18 @@ class DeviceWindows:
             arr[: len(vals)] = vals
             return jnp.asarray(arr)
 
-        kk = 256  # pow2 bucket: bounded jit-cache, padded entries drop
-        while kk < max(len(ev_keys_np), len(r_keys)):
-            kk <<= 1
-        ks = 256
-        while ks < max(len(ev_slots_np), len(r_slots)):
-            ks <<= 1
+        ks = _bucket(max(len(pend_ev), len(r_slots)), _MIN_MAINT_BUCKET)
+        kr = _bucket(len(r_keys), _MIN_MAINT_BUCKET)
+        self.maintenance_steps += 1
+        self.maintenance_elems += 2 * ks + 4 * kr
         self._state = _maintenance_step(
             self._state,
-            _pad(ev_keys_np, cap_r, kk),
-            _pad(ev_slots_np, self.capacity, ks),
-            _pad(r_keys, cap_r, kk),
-            _pad(r_hits, 0, kk),
-            _pad(r_ss, 0, kk),
-            _pad(r_sns, 0, kk),
+            _pad(pend_ev, self.capacity, ks),
             _pad(r_slots, self.capacity, ks),
+            _pad(r_keys, cap_r, kr),
+            _pad(r_hits, 0, kr),
+            _pad(r_ss, 0, kr),
+            _pad(r_sns, 0, kr),
         )
 
     # ---- refused-row host apply (cold-tier path) ----

@@ -123,6 +123,15 @@ FAMILIES: List[Family] = [
            prom="banjax_device_windows_evictions_total"),
     Family(GAUGE, "evictions in this reporting interval (line-only delta)",
            line_key="DeviceWindowsEvictionsPerInterval"),
+    Family(COUNTER, "device window maintenance dispatches (evictions and "
+           "restores drained into the device state)",
+           line_key="DeviceWindowsMaintenanceSteps",
+           prom="banjax_device_windows_maintenance_steps_total"),
+    Family(COUNTER, "int32 elements handed to the device by maintenance "
+           "dispatches, padding included (divide by evictions: single "
+           "digits while the step is O(evicted slots))",
+           line_key="DeviceWindowsMaintenanceElems",
+           prom="banjax_device_windows_maintenance_elems_total"),
     Family(COUNTER, "device window capacity grows",
            line_key="DeviceWindowsGrows",
            prom="banjax_device_windows_grows_total"),

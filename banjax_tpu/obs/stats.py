@@ -112,6 +112,14 @@ class MatcherStats:
             # single read: an eviction landing between two reads must not
             # be dropped from the next interval's delta
             out["DeviceWindowsEvictions"] = device_windows.eviction_count
+            # elems / evictions: single digits while the maintenance step
+            # is O(evicted slots) (matcher/windows.py)
+            out["DeviceWindowsMaintenanceSteps"] = getattr(
+                device_windows, "maintenance_steps", 0
+            )
+            out["DeviceWindowsMaintenanceElems"] = getattr(
+                device_windows, "maintenance_elems", 0
+            )
             out["DeviceWindowsGrows"] = getattr(device_windows, "grow_count", 0)
             # which slot-assignment path is live: the native C manager
             # (native/slotmgr.c) or the Python dict+LRU fallback/oracle

@@ -20,6 +20,7 @@ from banjax_tpu.decisions.rate_limit import (
 from banjax_tpu.decisions.static_lists import StaticDecisionLists
 from banjax_tpu.fabric.peer import PeerUnavailable
 from banjax_tpu.httpapi import server as server_mod
+from banjax_tpu.obs import provenance
 from banjax_tpu.obs.exposition import parse_text_format
 from banjax_tpu.obs.fleet import FleetScraper, capture_fleet
 from banjax_tpu.obs.flightrec import FlightRecorder
@@ -138,6 +139,9 @@ def test_metrics_fleet_404_when_scraper_absent():
 
 
 def test_explain_proxy_dead_owner_falls_back_local_flagged():
+    # the ledger is process-wide: start from an empty one, or a record an
+    # earlier file of this worker left for 9.9.9.9 is the "local answer"
+    provenance.configure(enabled=True)
     cfg = config_from_yaml_text(RULES_YAML)
     svc = FakeFabricService("w0", owner="w1", fail=True)
     status, text = _get(

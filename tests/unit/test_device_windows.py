@@ -3,6 +3,7 @@
 harness for the device path — here for the window counters of
 /root/reference/internal/rate_limit.go:37-78)."""
 
+import functools
 import random
 import re
 
@@ -566,3 +567,219 @@ def test_warm_tier_drop_keeps_shadow_entry():
     assert dw.warm_dropped > 0, "tiny tier never reported drop pressure"
     # every dropped spill fell back to the shadow (lossless)
     assert dw.warm_spills + len(dw._shadow) >= n - 2
+
+
+# ------------------------------------------------- validity by generation
+
+
+def _device_view(dw):
+    """→ (valid, hits) as [capacity, n_rules] host arrays: a key holds
+    state iff its generation equals its slot's."""
+    st = dw._state
+    shape = (dw.capacity, dw.n_rules)
+    key_gen = np.asarray(st.key_gen).reshape(shape)
+    slot_gen = np.asarray(st.slot_gen)
+    return key_gen == slot_gen[:, None], np.asarray(st.hits).reshape(shape)
+
+
+def _hitter(dw, n_rules):
+    active = np.ones((1, n_rules), dtype=bool)
+
+    def hit(ip, t, bits):
+        slots = dw.slots_for_ips([ip])
+        ts_s, ts_ns = split_ns(np.array([t], dtype=np.int64))
+        return dw.apply_bitmap(
+            np.array([bits], dtype=np.uint8), slots, ts_s, ts_ns,
+            active, np.zeros(1, dtype=np.int32),
+        )
+
+    return hit
+
+
+@pytest.mark.parametrize("n_rules", [3, 1000])
+def test_maintenance_operands_do_not_scale_with_rules(n_rules, monkeypatch):
+    """Evicting K slots hands the device K-sized operands whatever the
+    rule count: one generation bump per slot, no per-(slot, rule) keys."""
+    from banjax_tpu.matcher import windows as W
+
+    rules = [make_rule(f"r{i}", 30.0, 100) for i in range(n_rules)]
+    cap, k_evict = 512, 300
+    dw = DeviceWindows(rules, capacity=cap)
+    active = np.ones((1, n_rules), dtype=bool)
+    base = 1_700_000_000 * NS
+
+    def batch(prefix, n, t):
+        bits = np.zeros((n, n_rules), dtype=np.uint8)
+        bits[:, 0] = 1
+        slots = dw.slots_for_ips([f"{prefix}.{i}" for i in range(n)])
+        ts_s, ts_ns = split_ns(np.full(n, t, dtype=np.int64))
+        return dw.apply_bitmap(bits, slots, ts_s, ts_ns, active,
+                               np.zeros(n, dtype=np.int32))
+
+    batch("a", cap, base)                  # fills the table
+    seen = []
+    real = W._maintenance_step
+
+    def spy(state, *operands):
+        seen.append([int(o.shape[0]) for o in operands])
+        return real(state, *operands)
+
+    monkeypatch.setattr(W, "_maintenance_step", spy)
+    events = batch("b", k_evict, base + 1)  # evicts k_evict slots at once
+    assert dw.eviction_count == k_evict
+    assert all(int(e.match_type) == 0 and not e.seen_ip for e in events)
+    # slots: 300 -> 512 (evicted, restored-seen); restored keys: 0 -> 256
+    assert seen == [[512, 512, 256, 256, 256, 256]]
+    assert dw.maintenance_steps == 1
+    assert dw.maintenance_elems == 2 * 512 + 4 * 256
+    valid, _ = _device_view(dw)
+    assert valid.sum() == cap               # one live key a slot, no more
+
+
+@pytest.mark.parametrize("new_owner", ["fresh", "shadow", "warm"])
+def test_reclaimed_slot_never_shows_previous_owner(new_owner):
+    """A slot evicted and claimed by another IP in ONE maintenance step:
+    the previous owner's counters are gone for every rule, also for the
+    rules the new owner does not touch or bring back — whether the new
+    owner is new, or restored from the shadow or the warm tier with a
+    different rule subset."""
+    rules = [make_rule("r0", 60.0, 100), make_rule("r1", 60.0, 100)]
+    dw = DeviceWindows(rules, capacity=2,
+                       warm_tier_enabled=(new_owner == "warm"),
+                       warm_tier_capacity=64)
+    hit = _hitter(dw, 2)
+    base = 1_700_000_000 * NS
+    if new_owner != "fresh":
+        hit("C", base, [0, 1])             # C holds r1 only
+        hit("f1", base + 1, [1, 0])
+        hit("f2", base + 2, [1, 0])        # evicts C: shadow or warm tier
+        assert ("C" in dw._shadow) == (new_owner == "shadow")
+    hit("A", base + 3, [1, 1])
+    hit("A", base + 4, [1, 1])             # A: r0=2, r1=2
+    hit("B", base + 5, [1, 0])
+    slot_a = dw.slot_for_ip("A")           # a lookup makes A most recent...
+    hit("B", base + 6, [1, 0])             # ...and this makes it LRU again
+
+    steps = dw.maintenance_steps
+    slots = dw.slots_for_ips(["C"])        # evicts A, claims its slot
+    assert int(slots[0]) == slot_a
+    with dw._lock:
+        dw._run_maintenance_locked()       # evict + claim: one step
+    assert dw.maintenance_steps == steps + 1
+    valid, hits = _device_view(dw)
+    if new_owner == "fresh":
+        assert not valid[slot_a].any()
+    else:
+        assert valid[slot_a].tolist() == [False, True]
+        assert hits[slot_a, 1] == 1        # C's own r1, not A's 2
+    ts_s, ts_ns = split_ns(np.array([base + 7], dtype=np.int64))
+    ev = dw.apply_bitmap(np.array([[1, 1]], dtype=np.uint8), slots, ts_s,
+                         ts_ns, np.ones((1, 2), dtype=bool),
+                         np.zeros(1, dtype=np.int32))
+    by_rule = {e.rule_id: int(e.match_type) for e in ev}
+    # r0: first time for C in every variant (A's r0=2 must not show)
+    assert by_rule[0] == 0
+    assert by_rule[1] == (0 if new_owner == "fresh" else 2)
+    got = {r: s.num_hits for r, s in dw.get("C")[0].items()}
+    assert got == {"r0": 1, "r1": 1 if new_owner == "fresh" else 2}
+    # A's state rests where evictions put it, intact
+    assert {r: s.num_hits for r, s in dw.get("A")[0].items()} == {
+        "r0": 2, "r1": 2}
+
+
+@pytest.mark.parametrize("native", [True, False])
+def test_evict_restore_evict_keeps_last_owner_only(native):
+    """One slot, two IPs alternating: every step evicts one and restores
+    the other; after each, the device holds the current owner's keys and
+    nothing of the other's."""
+    rules = [make_rule("r0", 60.0, 100), make_rule("r1", 60.0, 100)]
+    dw = DeviceWindows(rules, capacity=1, native_slotmgr=native)
+    hit = _hitter(dw, 2)
+    base = 1_700_000_000 * NS
+
+    def device():
+        valid, hits = _device_view(dw)
+        return [int(h) if v else None for v, h in zip(valid[0], hits[0])]
+
+    hit("A", base, [1, 0])
+    hit("A", base + 1, [1, 0])
+    assert device() == [2, None]
+    (e,) = hit("B", base + 2, [0, 1])      # evict A
+    assert int(e.match_type) == 0 and not e.seen_ip
+    assert device() == [None, 1]
+    (e,) = hit("A", base + 3, [0, 1])      # evict B, restore A (r0=2)
+    assert int(e.match_type) == 0 and e.seen_ip
+    assert device() == [2, 1]              # A's r0 and A's new r1
+    (e,) = hit("B", base + 4, [1, 0])      # evict A, restore B (r1=1)
+    assert int(e.match_type) == 0 and e.seen_ip
+    assert device() == [1, 1]              # B's r0=1 — not A's 2 + 1
+    (e,) = hit("B", base + 5, [0, 1])
+    assert int(e.match_type) == 2          # B's own r1 came back
+    assert device() == [1, 2]
+    assert {r: s.num_hits for r, s in dw.get("A")[0].items()} == {
+        "r0": 2, "r1": 1}
+    assert np.asarray(dw._state.slot_gen).tolist() == [1 + dw.eviction_count]
+
+
+@pytest.mark.parametrize("n_rules", [1, 3])
+def test_grow_keeps_live_keys_valid_and_new_keys_invalid(n_rules):
+    rules = [make_rule(f"r{i}", 60.0, 100) for i in range(n_rules)]
+    dw = DeviceWindows(rules, capacity=2)
+    hit = _hitter(dw, n_rules)
+    base = 1_700_000_000 * NS
+    first = [1] + [0] * (n_rules - 1)
+    hit("A", base, [1] * n_rules)
+    hit("B", base + 1, first)
+    hit("C", base + 2, first)              # evicts A: slot_gen moves off 1
+    before_valid, before_hits = _device_view(dw)
+    assert before_valid.sum() == 2
+    with dw._lock:
+        dw._grow_locked(4)
+    valid, hits = _device_view(dw)
+    assert (valid[:2] == before_valid).all()
+    assert (hits[:2] == before_hits).all()
+    assert not valid[2:].any()
+    assert np.asarray(dw._state.slot_gen)[2:].tolist() == [1, 1]
+    assert not np.asarray(dw._state.key_gen)[2 * n_rules:].any()
+    (e,) = hit("B", base + 3, first)       # a live key still counts on
+    assert int(e.match_type) == 2
+    ev = hit("D", base + 4, [1] * n_rules)  # a new slot starts empty
+    assert all(int(e.match_type) == 0 for e in ev)
+
+
+@pytest.mark.parametrize("gate", [False, True])
+def test_gate_passes_generations_through(gate):
+    """gate=False drops every state write: the donated state comes back
+    bit-identical, generations included; gate=True stamps the written
+    keys with their slot's generation and leaves slot_gen alone."""
+    import jax
+    import jax.numpy as jnp
+
+    from banjax_tpu.matcher import windows as W
+
+    rules = [make_rule("r0", 60.0, 100), make_rule("r1", 60.0, 100)]
+    dw = DeviceWindows(rules, capacity=2)
+    hit = _hitter(dw, 2)
+    base = 1_700_000_000 * NS
+    hit("A", base, [1, 0])
+    hit("B", base + 1, [0, 1])
+    hit("C", base + 2, [1, 0])             # evicts A: generations differ
+    before = jax.tree_util.tree_map(np.asarray, dw._state)
+    ts_s, ts_ns = split_ns(np.array([base + 3, base + 3], dtype=np.int64))
+    new_state, out = jax.jit(
+        functools.partial(W._apply_core, n_rules=2, max_events=8)
+    )(
+        dw._state, jnp.ones((2, 2), jnp.uint8), jnp.ones((1, 2), bool),
+        jnp.zeros(2, jnp.int32), jnp.array([0, 1], jnp.int32),
+        jnp.asarray(ts_s), jnp.asarray(ts_ns), dw._limits, dw._iv_s,
+        dw._iv_ns, gate=jnp.bool_(gate),
+    )
+    after = jax.tree_util.tree_map(np.asarray, new_state)
+    assert (after.slot_gen == before.slot_gen).all()
+    assert (np.asarray(out["rule"]) >= 0).sum() == 4   # events either way
+    if not gate:
+        for name in ("hits", "start_s", "start_ns", "key_gen", "ip_seen"):
+            assert (getattr(after, name) == getattr(before, name)).all(), name
+    else:
+        assert (after.key_gen.reshape(2, 2)
+                == after.slot_gen[:, None]).all()

@@ -131,6 +131,35 @@ def test_metrics_families_all_declared_and_parse(loaded_system):
     assert samples["banjax_health_status"] == 1  # degraded component
 
 
+@pytest.mark.parametrize("line_key, family, attr", [
+    ("DeviceWindowsEvictions", "banjax_device_windows_evictions_total",
+     "eviction_count"),
+    ("DeviceWindowsMaintenanceSteps",
+     "banjax_device_windows_maintenance_steps_total", "maintenance_steps"),
+    ("DeviceWindowsMaintenanceElems",
+     "banjax_device_windows_maintenance_elems_total", "maintenance_elems"),
+])
+def test_device_windows_counters_on_line_and_metrics(
+    loaded_system, line_key, family, attr
+):
+    """The eviction counter and the two maintenance counters beside it
+    (elems / evictions says whether the step stayed O(evicted slots)):
+    on the 29 s line, declared, and exposed as counters with the same
+    value the windows object holds."""
+    m, sched, health, sup = loaded_system
+    want = getattr(m.device_windows, attr)
+    line = _full_line(m, sched, health, sup)
+    assert line[line_key] == want
+    assert registry.is_declared_line_key(line_key)
+    assert registry.PROM_FAMILIES[family].kind == registry.COUNTER
+    fams = parse_text_format(render_prometheus(
+        DynamicDecisionLists(start_sweeper=False), RegexRateLimitStates(),
+        FailedChallengeRateLimitStates(), matcher=m,
+    ))
+    assert fams[family]["type"] == registry.COUNTER
+    assert [s[2] for s in fams[family]["samples"]] == [want]
+
+
 def test_breaker_state_is_one_hot(loaded_system):
     m, sched, health, sup = loaded_system
     text = render_prometheus(

@@ -69,6 +69,9 @@ def test_matcher_keys_are_additive():
     assert line["DeviceWindowsCapacity"] == m.device_windows.capacity > 0
     assert line["DeviceWindowsEvictions"] == 0
     assert line["DeviceWindowsEvictionsPerInterval"] == 0
+    # nothing evicted or restored yet: no maintenance dispatch, no operand
+    assert line["DeviceWindowsMaintenanceSteps"] == 0
+    assert line["DeviceWindowsMaintenanceElems"] == 0
     assert line["DeviceWindowsGrows"] == 0
     # the lines/sec window resets per snapshot
     line2 = _line(m)
