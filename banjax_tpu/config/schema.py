@@ -162,10 +162,15 @@ class Config:
     matcher_window_capacity: int = 0  # IP slots; 0 = auto-size
     # two-stage literal prefilter (matcher/prefilter.py): bit-identical
     # output, auto-disabled for rulesets with too few filterable rules.
-    # cand_frac sizes the candidate capacity as a fraction of the batch:
-    # a batch whose stage-1 hit rate exceeds it falls back to the
-    # single-stage matcher (correct but slower) — raise it for rulesets
-    # whose factors fire often on benign traffic
+    # cand_frac sizes the candidate capacity, as a fraction of the batch,
+    # for the rules that keep the candidate gate: a chunk whose stage-1
+    # hit rate exceeds it replays through the single-stage matcher
+    # (correct but slower; banjax_fused_overflows_total{cause="candidates"}
+    # counts them) — raise it for rules whose literal factor fires often
+    # on benign traffic.  Anchored literals such as `^GET` take no
+    # candidate slot: the plan routes them as always-columns of stage 1 by
+    # itself (prefilter._stage1_decides), so the shipped default rules
+    # need nothing set here
     matcher_prefilter: bool = True
     matcher_prefilter_cand_frac: float = 0.125
     # multi-device mesh (parallel/mesh.py): shard the line batch over `dp`

@@ -109,6 +109,7 @@ class Banner(BannerInterface):
         netlink_writer=None,
     ):
         self.decision_lists = decision_lists
+        self.regex_ban_records = 0  # ban-log lines written by log_regex_ban
         self._ban_log = ban_log_file
         self._ban_log_temp = ban_log_file_temp
         self._ipset = ipset_instance
@@ -164,6 +165,7 @@ class Banner(BannerInterface):
             disable_logging=disable_logging,
         )
         self._write(line, disable_logging)
+        self.regex_ban_records += 1
 
     def log_failed_challenge_ban(
         self, config: Config, ip: str, challenge_type: str, host: str, path: str,

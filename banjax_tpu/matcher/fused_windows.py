@@ -59,7 +59,7 @@ import dataclasses
 import functools
 import logging
 import threading
-from typing import List, Optional
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -68,8 +68,7 @@ import numpy as np
 from banjax_tpu.matcher import windows as W
 from banjax_tpu.obs import trace
 from banjax_tpu.matcher.prefilter import FusedPrefilter
-from banjax_tpu.matcher.windows import DeviceWindows, WindowEvent
-from banjax_tpu.decisions.rate_limit import RateLimitMatchType
+from banjax_tpu.matcher.windows import DeviceWindows, EventBatch
 
 log = logging.getLogger(__name__)
 
@@ -93,6 +92,7 @@ class _Pend:
     Bp: int
     K: int
     P: int
+    E: int                 # window-event capacity of the chunk's program
     state: str = "submitted"
     flags: Optional[np.ndarray] = None     # [4] after resolve
     events_buf: object = None              # program B's buffer, or (single-
@@ -121,7 +121,7 @@ class _Pend:
 class FusedWindowsResult:
     """Outcome of one collected chunk."""
 
-    events: List[WindowEvent]
+    events: EventBatch
     matched_pairs: Optional[np.ndarray]   # int32 caller_row * R8 + bit col
     always_bits: Optional[np.ndarray]     # [B, na8] packed always-rule bits
 
@@ -181,6 +181,12 @@ class FusedWindowsPipeline:
         self.sk_chunks = 0          # single-kernel chunks committed
         self.sk_fallbacks = 0       # routed to the classic fallback
         self.sk_d2h_bytes_total = 0  # the one-pull d2h witness
+        # fused dispatches that committed nothing, by what overflowed:
+        # the chunk's own candidates / (row, rule) pairs / window events,
+        # or `chain` — gated by an overflowing predecessor's chain scalar
+        self.overflow_causes = {
+            "candidates": 0, "pairs": 0, "events": 0, "chain": 0,
+        }
         plan = prefilter.plan
         self._f_idx = jnp.asarray(plan.f_idx, dtype=jnp.int32)
         self._a_idx = jnp.asarray(plan.a_idx, dtype=jnp.int32)
@@ -211,14 +217,12 @@ class FusedWindowsPipeline:
             return hit
         pf = self.pf
         plan = pf.plan
-        block, K = pf.capacities(Bp)
+        block, K, P, E = pf.program_capacities(Bp)
         core = pf._match_core(Bp, L_p, K, block)
-        P = pf.pair_capacity(Bp, K)
-        n_rules, n_filt = self.n_rules, plan.stage2.n_rules
+        n_rules, n_filt = self.n_rules, pf._n_filt
         n_always = plan.n_always
         f_idx, a_idx = self._f_idx, self._a_idx
         aw, ae = self._aw, self._ae
-        max_events = self.windows.max_events
         active_table = self.active_table
         shifts = jnp.asarray(_SHIFTS, dtype=jnp.int32)
 
@@ -232,11 +236,12 @@ class FusedWindowsPipeline:
             # per-candidate form for the bitmap assembly below.
             pairs, n_pairs, pair_bits = pf.pairs_from_core(c, K, P)
             # dense caller-order bitmap, assembled on device
-            m2 = pair_bits[:, :n_filt].astype(jnp.uint8)         # [K, n_filt]
-            filt = jnp.zeros((Bp + 1, n_filt), dtype=jnp.uint8)
-            filt = filt.at[c["idx_caller_k"]].set(m2)[:Bp]       # row Bp = dump
             bits = jnp.zeros((Bp, n_rules), dtype=jnp.uint8)
-            bits = bits.at[:, f_idx].set(filt)
+            if n_filt:
+                m2 = pair_bits[:, :n_filt].astype(jnp.uint8)     # [K, n_filt]
+                filt = jnp.zeros((Bp + 1, n_filt), dtype=jnp.uint8)
+                filt = filt.at[c["idx_caller_k"]].set(m2)[:Bp]   # row Bp = dump
+                bits = bits.at[:, f_idx].set(filt)
             ab = None
             if n_always:
                 ab = c["ab_caller"] | aw[None, :]
@@ -254,7 +259,7 @@ class FusedWindowsPipeline:
             n_events = fire.sum(dtype=jnp.int32)
             ok = (
                 (c["n_cand"] <= K) & (n_pairs <= P)
-                & (n_events <= max_events)
+                & (n_events <= E)
             )
             flags = jnp.stack([
                 ok.astype(jnp.int32), c["n_cand"], n_pairs, n_events,
@@ -273,8 +278,8 @@ class FusedWindowsPipeline:
                 )
             return jnp.concatenate(parts), bits
 
-        self._match_fns[key] = (match, K, P)
-        return match, K, P
+        self._match_fns[key] = (match, K, P, E)
+        return match, K, P, E
 
     # ---- single-kernel program: match + window commit in ONE dispatch ----
 
@@ -288,14 +293,14 @@ class FusedWindowsPipeline:
             return hit
         from banjax_tpu.matcher.kernels import fused_match_window as fmw
 
-        fn, K, P = fmw.build_single_program(
+        hit = fmw.build_single_program(
             self.pf, self.windows, self.active_table, self.n_rules,
             Bp, L_p, f_idx=self._f_idx, a_idx=self._a_idx,
             aw=self._aw, ae=self._ae,
             scan_fn=fmw.window_scan(self._scan_interpret),
         )
-        self._match_fns[key] = (fn, K, P)
-        return fn, K, P
+        self._match_fns[key] = hit
+        return hit
 
     def _submit_single(self, combined, Bp: int, L_p: int, B: int,
                        slots_p, ts_s_p, ts_ns_p, host_idx_p,
@@ -307,7 +312,7 @@ class FusedWindowsPipeline:
         (evictions/restores) drains first, exactly as the two-program
         resolve did, and the state-chain order == seq order because both
         are taken inside the same critical section."""
-        fn, K, P = self._single_prog(Bp, L_p)
+        fn, K, P, E = self._single_prog(Bp, L_p)
         live_p = np.zeros(Bp, dtype=np.uint8)
         live_p[:B] = 1 if live is None else np.asarray(live, dtype=np.uint8)
         wnd = self.windows
@@ -341,7 +346,7 @@ class FusedWindowsPipeline:
             seq=seq, sparse_buf=buf, bits_dev=bits_dev,
             slots=slots_p,  # caller overwrites with the unpadded view
             ts_s=ts_s_p, ts_ns=ts_ns_p, host_idx=host_idx_p,
-            B=B, Bp=Bp, K=K, P=P,
+            B=B, Bp=Bp, K=K, P=P, E=E,
             # the whole h2d for the chunk: encoded classes + per-row
             # window metadata + the live mask + the chain scalar — still
             # no dense [B, n_rules] bitmap
@@ -350,13 +355,12 @@ class FusedWindowsPipeline:
 
     # ---- program B: window apply on a device-resident bitmap ----
 
-    def _apply_prog(self, Bp: int):
+    def _apply_prog(self, Bp: int, max_events: int):
         hit = self._apply_fns.get(Bp)
         if hit is not None:
             return hit
         wnd = self.windows
         n_rules = self.n_rules
-        max_events = wnd.max_events
         limits, iv_s, iv_ns = wnd._limits, wnd._iv_s, wnd._iv_ns
         active_table = self.active_table
         shifts = jnp.asarray(_SHIFTS, dtype=jnp.int32)
@@ -434,7 +438,7 @@ class FusedWindowsPipeline:
             p.slots = np.asarray(slots)
             self._sketch_update(p)
             return p
-        match, K, P = self._match_prog(Bp, L_p)
+        match, K, P, E = self._match_prog(Bp, L_p)
         sparse_buf, bits_dev = match(
             jnp.asarray(combined), jnp.int32(B), jnp.asarray(host_idx_p)
         )
@@ -450,7 +454,7 @@ class FusedWindowsPipeline:
             slots=np.asarray(slots),
             ts_s=pad(ts_s).astype(np.int32),
             ts_ns=pad(ts_ns).astype(np.int32),
-            host_idx=host_idx_p, B=B, Bp=Bp, K=K, P=P,
+            host_idx=host_idx_p, B=B, Bp=Bp, K=K, P=P, E=E,
             # the whole host→device traffic for this chunk: the encoded
             # class array + the per-row window metadata — crucially NOT a
             # dense [B, n_rules] bitmap
@@ -563,7 +567,7 @@ class FusedWindowsPipeline:
         else:
             p.always_bits = None
         n_pairs = int(flags[2])
-        if n_pairs <= P:
+        if n_pairs <= P and P:
             live_pairs = pairs[:n_pairs]
             rows_idx = live_pairs // R8
             cols = live_pairs - rows_idx * R8
@@ -573,10 +577,27 @@ class FusedWindowsPipeline:
             # with it directly)
             keep = (
                 (rows_idx >= 0) & (rows_idx < p.B)
-                & (cols < self.pf.plan.stage2.n_rules)
+                & (cols < self.pf._n_filt)
             )
             p.matched_pairs = live_pairs[keep]
         return off
+
+    def _overflow(self, p: _Pend) -> "PipelineOverflow":
+        """Count a chunk that committed nothing by its cause and build
+        the exception that sends it to the classic fallback."""
+        _, n_cand, n_pairs, n_events = (int(x) for x in p.flags)
+        if n_cand > p.K:
+            cause = "candidates"
+        elif n_pairs > p.P:
+            cause = "pairs"
+        elif n_events > p.E:
+            cause = "events"
+        else:
+            cause = "chain"
+        self.overflow_causes[cause] += 1
+        p.state = "overflow"
+        self.fallback_batches += 1
+        return PipelineOverflow(candidate_overflow=cause == "candidates")
 
     def _resolve_single(self, p: _Pend) -> None:
         """Single-kernel resolve: a PURE d2h pull — the commit already
@@ -592,12 +613,8 @@ class FusedWindowsPipeline:
             off = self._decode_head(p, buf)
             flags = p.flags
             if not flags[0]:
-                p.state = "overflow"
-                self.fallback_batches += 1
                 self.sk_fallbacks += 1
-                raise PipelineOverflow(
-                    candidate_overflow=int(flags[1]) > p.K
-                )
+                raise self._overflow(p)
             p.events_buf = buf
             p.events_off = off
             p.state = "resolved"
@@ -639,14 +656,10 @@ class FusedWindowsPipeline:
             self._decode_head(p, buf)
             flags = p.flags
             if not flags[0]:
-                p.state = "overflow"
-                self.fallback_batches += 1
-                raise PipelineOverflow(
-                    candidate_overflow=int(flags[1]) > p.K
-                )
+                raise self._overflow(p)
 
             wnd = self.windows
-            apply = self._apply_prog(p.Bp)
+            apply = self._apply_prog(p.Bp, p.E)
             slots_p = p.slots.astype(np.int32)
             if p.Bp != p.B:
                 slots_p = np.concatenate(
@@ -723,7 +736,7 @@ class FusedWindowsPipeline:
                 buf = np.asarray(p.events_buf)
                 p.d2h_bytes += buf.nbytes
                 off = 0
-            me = wnd.max_events
+            me = p.E
 
             def take_i32(n):
                 nonlocal off
@@ -742,36 +755,22 @@ class FusedWindowsPipeline:
             ev_exc = buf[off : off + me]; off += me
             ev_seen = buf[off : off + me]; off += me
 
+            # events arrive in key-sorted (scan) order; reference order is
+            # (line, rule_id) ascending — per-site ids precede global
             live = np.flatnonzero(ev_rule >= 0)
-            events = [
-                WindowEvent(
-                    line=int(ev_line[k]),
-                    rule_id=int(ev_rule[k]),
-                    match_type=RateLimitMatchType(int(ev_mtype[k])),
-                    exceeded=bool(ev_exc[k]),
-                    seen_ip=bool(ev_seen[k]),
-                )
-                for k in live
-            ]
-            # shadow update mirrors _apply_bitmap_inner: (line, rule) order
-            # so dict INSERTION order matches the reference's
-            # first-matched-event order (format_states parity); last write
-            # per (ip, rule) is still the chronologically-final state.
+            live = live[np.lexsort((ev_rule[live], ev_line[live]))]
+            events = EventBatch(
+                line=ev_line[live], rule=ev_rule[live],
+                match_type=ev_mtype[live], exceeded=ev_exc[live] != 0,
+                seen_ip=ev_seen[live] != 0,
+            )
             # Collect order == apply order, so concurrent chunks can't
-            # interleave stale values.
-            from collections import OrderedDict
-
-            shorder = np.lexsort((ev_rule[live], ev_line[live]))
+            # interleave stale values in the shadow.
             with wnd._lock:
-                for k in live[shorder]:
-                    ip = wnd._slot_ip.get(int(p.slots[int(ev_line[k])]))
-                    if ip is None:
-                        continue
-                    od = wnd._shadow.setdefault(ip, OrderedDict())
-                    od[int(ev_rule[k])] = (
-                        int(ev_hits[k]), int(ev_ss[k]), int(ev_sns[k])
-                    )
-            events.sort(key=lambda e: (e.line, e.rule_id))
+                wnd._absorb_events_locked(
+                    p.slots, events.line, events.rule, ev_hits[live],
+                    ev_ss[live], ev_sns[live],
+                )
             p.state = "done"
             return FusedWindowsResult(
                 events=events, matched_pairs=p.matched_pairs,

@@ -209,7 +209,7 @@ def build_single_program(
     """One jitted device program: match core + dense bitmap assembly +
     live mask + overflow/chain gate + window commit + compact output.
 
-    Returns (fn, K, P) where
+    Returns (fn, K, P, E) where
       fn(state, chain_ok, combined, n_real, host_idx, slots, ts_s,
          ts_ns, live) -> (new_state, chain_ok_out, buf, bits_dev)
     with `state` donated (the HBM-resident window arrays mutate in
@@ -224,13 +224,13 @@ def build_single_program(
     The layouts of the head and the event tail are byte-identical to
     program A's and program B's buffers respectively, so the host decode
     is shared with the two-program path."""
-    block, K = pf.capacities(Bp)
+    # the event ceiling follows the rows and the ruleset (always-columns
+    # can fire on every row), not a constant of the window table
+    block, K, P, max_events = pf.program_capacities(Bp)
     core = pf._match_core(Bp, L_p, K, block)
-    P = pf.pair_capacity(Bp, K)
     plan = pf.plan
     n_always = plan.n_always
-    n_filt = plan.stage2.n_rules
-    max_events = windows.max_events
+    n_filt = pf._n_filt
     limits, iv_s, iv_ns = windows._limits, windows._iv_s, windows._iv_ns
     active_table = jnp.asarray(active_table)
     shifts = jnp.asarray(_SHIFTS, dtype=jnp.int32)
@@ -241,11 +241,12 @@ def build_single_program(
         c = core(combined)
         pairs, n_pairs, pair_bits = pf.pairs_from_core(c, K, P)
         # dense caller-order bitmap, assembled on device (as program A)
-        m2 = pair_bits[:, :n_filt].astype(jnp.uint8)          # [K, n_filt]
-        filt = jnp.zeros((Bp + 1, n_filt), dtype=jnp.uint8)
-        filt = filt.at[c["idx_caller_k"]].set(m2)[:Bp]        # row Bp = dump
         bits = jnp.zeros((Bp, n_rules), dtype=jnp.uint8)
-        bits = bits.at[:, f_idx].set(filt)
+        if n_filt:
+            m2 = pair_bits[:, :n_filt].astype(jnp.uint8)      # [K, n_filt]
+            filt = jnp.zeros((Bp + 1, n_filt), dtype=jnp.uint8)
+            filt = filt.at[c["idx_caller_k"]].set(m2)[:Bp]    # row Bp = dump
+            bits = bits.at[:, f_idx].set(filt)
         ab = None
         if n_always:
             ab = c["ab_caller"] | aw[None, :]
@@ -296,4 +297,4 @@ def build_single_program(
         parts.append(ev["seen_ip"].astype(jnp.uint8))
         return new_state, ok.astype(jnp.int32), jnp.concatenate(parts), bits
 
-    return single, K, P
+    return single, K, P, max_events

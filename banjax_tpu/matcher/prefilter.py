@@ -53,6 +53,7 @@ from banjax_tpu.matcher.rulec import (
 log = logging.getLogger(__name__)
 
 _MIN_BUCKET = 64
+_MAX_EVENT_CAPACITY = 1 << 18
 
 
 @dataclasses.dataclass
@@ -64,9 +65,11 @@ class PrefilterPlan:
     n_always: int                # first n_always stage-1 columns are rules...
     a_idx: np.ndarray            # ...these original rule ids
     n_factors: int               # remaining stage-1 columns are factors
-    stage2: CompiledRules        # filterable rules
+    stage2: Optional[CompiledRules]  # filterable rules; None when every
+    #                                  device rule is an always-column
     f_idx: np.ndarray            # stage-2 column -> original rule id
     unsupported: Dict[int, str]  # rule id -> reason (host regex fallback)
+    n_decided: int = 0           # always-columns routed by _stage1_decides
 
 
 def gate_masks(plan: "PrefilterPlan", prep=None):
@@ -180,6 +183,21 @@ def _merge_factors(
     return [tuple(Pos(c) for c in cs) for cs in out]
 
 
+def _stage1_decides(prog: RuleProgram, factors: List[Tuple]) -> bool:
+    """True for an anchored literal (`^GET`): every branch IS its factor,
+    so the rule costs stage 1 the words its factor would, and stage 2
+    would only re-check the anchor on whatever lines carry the literal —
+    with `^GET`, most of the batch, which the compaction has no capacity
+    for and should not have.  Such a rule runs as an always-column of
+    stage 1; everything else keeps the two-stage economy."""
+    return all(
+        br.anchored_start
+        and len(br.positions) == len(f)
+        and not any(p.loop for p in br.positions)
+        for br, f in zip(prog.branches, factors)
+    )
+
+
 def build_plan(
     patterns: Sequence[str],
     min_factor_len: int = 3,
@@ -191,8 +209,10 @@ def build_plan(
     factor_sel_max: float = 1e-5,
 ) -> Optional[PrefilterPlan]:
     """Split `patterns` into the two-stage plan, or None when the ruleset
-    doesn't profit (too few filterable rules — the two-pass overhead would
-    outweigh the narrower stage 1).
+    doesn't profit (too few rules with a usable factor — the two-pass
+    overhead would outweigh the narrower stage 1).  Rules that stage 1
+    decides by itself (_stage1_decides) are always-columns; when no rule
+    is left to filter, the plan is stage 1 alone and `stage2` is None.
 
     `byte_classes` = (byte_to_class, n_classes) of the full single-stage
     ruleset: both stage tensors are then packed against that shared byte
@@ -211,6 +231,7 @@ def build_plan(
     distinct_factors: Dict[Tuple, Tuple] = {}
     always_ids: List[int] = []
     filt_ids: List[int] = []
+    n_decided = 0  # always-columns by _stage1_decides, not for want of a factor
     for i, prog in enumerate(programs):
         if prog is None:
             continue  # host regex fallback, not on device at all
@@ -219,6 +240,10 @@ def build_plan(
         )
         if factors is None:
             always_ids.append(i)
+            continue
+        if _stage1_decides(prog, factors):
+            always_ids.append(i)
+            n_decided += 1
             continue
         filt_ids.append(i)
         for f in factors:
@@ -231,10 +256,10 @@ def build_plan(
     factor_progs = [factor_program(f) for f in merged]
 
     n_device = len(always_ids) + len(filt_ids)
-    if (
-        n_device == 0
-        or not factor_progs
-        or len(filt_ids) < n_device * min_filterable_fraction
+    # a rule stage 1 decides alone costs stage 1 what its factor would, so
+    # it counts with the filterable rules, not against them
+    if n_device == 0 or (
+        len(filt_ids) + n_decided < max(1, n_device * min_filterable_fraction)
     ):
         return None
 
@@ -250,13 +275,13 @@ def build_plan(
     # stage2_shards=rp pins the word slabs to a mesh's rule-parallel axis
     s2 = pack_programs(
         stage2_programs, n_shards=stage2_shards, byte_classes=byte_classes
-    )
+    ) if stage2_programs else None
     log.info(
         "prefilter plan: %d always + %d filterable rules, %d distinct "
         "factors in %d superimposed buckets; stage1 %d words, stage2 %d "
         "words",
         len(always_ids), len(filt_ids), len(distinct_factors),
-        len(factor_progs), s1.n_words, s2.n_words,
+        len(factor_progs), s1.n_words, s2.n_words if s2 is not None else 0,
     )
     return PrefilterPlan(
         n_rules=len(patterns),
@@ -267,6 +292,7 @@ def build_plan(
         stage2=s2,
         f_idx=np.asarray(filt_ids, dtype=np.int64),
         unsupported=unsupported,
+        n_decided=n_decided,
     )
 
 
@@ -286,15 +312,13 @@ class PrefilterMatcher:
         self.interpret = backend == "pallas-interpret"
         self._preps = {}
         if backend in ("pallas", "pallas-interpret"):
-            self._preps = {
-                "s1": nfa_match.prepare(plan.stage1),
-                "s2": nfa_match.prepare(plan.stage2),
-            }
+            self._preps = {"s1": nfa_match.prepare(plan.stage1)}
+            if plan.stage2 is not None:
+                self._preps["s2"] = nfa_match.prepare(plan.stage2)
         else:
-            self._params = {
-                "s1": nfa_jax.match_params(plan.stage1),
-                "s2": nfa_jax.match_params(plan.stage2),
-            }
+            self._params = {"s1": nfa_jax.match_params(plan.stage1)}
+            if plan.stage2 is not None:
+                self._params["s2"] = nfa_jax.match_params(plan.stage2)
 
     def _run_stage(self, which: str, compiled: CompiledRules,
                    cls_ids: np.ndarray, lens: np.ndarray) -> np.ndarray:
@@ -349,7 +373,7 @@ class PrefilterMatcher:
             bits[np.ix_(rows, plan.a_idx)] = s1[:, : plan.n_always]
 
         cand_local = np.flatnonzero(s1[:, plan.n_always :].any(axis=1))
-        if cand_local.size:
+        if cand_local.size and plan.stage2 is not None:
             cand_rows = rows[cand_local]
             cls2 = classify_bytes(
                 plan.stage2, bytes_mat[cand_rows], lens[cand_rows]
@@ -415,8 +439,14 @@ class FusedPrefilter:
         """Chunking is the CALLER's job: submit() compiles one device
         program for exactly the batch shape it is handed (TpuMatcher
         chunks by its matcher_batch_lines before submitting)."""
-        if plan.stage1.n_classes != plan.stage2.n_classes:
+        if (
+            plan.stage2 is not None
+            and plan.stage1.n_classes != plan.stage2.n_classes
+        ):
             raise ValueError("fused plan requires shared byte classes")
+        # rules stage 2 filters; 0 = the plan is stage 1 alone (every
+        # device rule is an always-column) and no candidate is compacted
+        self._n_filt = plan.stage2.n_rules if plan.stage2 is not None else 0
         self.plan = plan
         self.backend = backend
         self.interpret = backend == "pallas-interpret"
@@ -424,20 +454,18 @@ class FusedPrefilter:
         self.pair_frac = pair_frac
         self._pallas = backend in ("pallas", "pallas-interpret")
         if self._pallas:
-            self._preps = {
-                "s1": nfa_match.prepare(plan.stage1),
-                "s2": nfa_match.prepare(plan.stage2),
-            }
+            self._preps = {"s1": nfa_match.prepare(plan.stage1)}
+            if self._n_filt:
+                self._preps["s2"] = nfa_match.prepare(plan.stage2)
             # block 512 × cols 32 is the VMEM sweet spot on v5e: wider
             # blocks OOM the 16 MB scoped-vmem limit once the per-plane dot
             # transients and the double-buffered out block are counted
             self._block = block_b or (8 if self.interpret else 512)
             self._cols = cols or (8 if self.interpret else 32)
         else:
-            self._params = {
-                "s1": nfa_jax.match_params(plan.stage1),
-                "s2": nfa_jax.match_params(plan.stage2),
-            }
+            self._params = {"s1": nfa_jax.match_params(plan.stage1)}
+            if self._n_filt:
+                self._params["s2"] = nfa_jax.match_params(plan.stage2)
             self._block = block_b or 8
             self._cols = cols or 8
         self._fns = {}
@@ -466,7 +494,7 @@ class FusedPrefilter:
         # host-static flags for always-rules (applied after decode)
         self._a_always = np.asarray(s1.always_match[: plan.n_always], dtype=bool)
         self._a_empty = np.asarray(s1.empty_only[: plan.n_always], dtype=bool)
-        self._nf8 = -(-plan.stage2.n_rules // 8)
+        self._nf8 = -(-self._n_filt // 8)
         self._na8 = -(-plan.n_always // 8) if plan.n_always else 0
 
     # ---- device program ----
@@ -505,7 +533,7 @@ class FusedPrefilter:
                 pack=True, cols=self._cols,
             )
         params = self._params["s2"]
-        n_filt = self.plan.stage2.n_rules
+        n_filt = self._n_filt
 
         def xla_fn(cls_t, lens):
             return nfa_jax.match_batch_packed(params, cls_t.T, lens, n_filt)
@@ -580,8 +608,11 @@ class FusedPrefilter:
         return combined, Bp, L_p
 
     def capacities(self, B: int):
-        """(block, K candidate slots) for a batch."""
+        """(block, K candidate slots) for a batch; K is 0 when the plan
+        has no rule to filter."""
         block = self._block_for(B)
+        if not self._n_filt:
+            return block, 0
         K = min(B, max(block, -(-int(B * self.cand_frac) // block) * block))
         return block, K
 
@@ -595,7 +626,22 @@ class FusedPrefilter:
                 "the int32 (row, rule) pair encoding — lower "
                 "matcher_batch_lines"
             )
-        return min(max(128, int(B * self.pair_frac)), K * self.plan.stage2.n_rules)
+        return min(max(128, int(B * self.pair_frac)), K * self._n_filt)
+
+    def event_capacity(self, B: int, P: int) -> int:
+        """Window-event slots for a batch of B rows: every always-column
+        can fire on every row, and the filtered rules fire at most once per
+        (row, rule) pair, of which there are at most P — so a chunk that
+        fits its pairs fits its events.  The cap bounds the sort and the
+        event pull for a ruleset made of rules with no factor at all; past
+        it the chunk overflows to the classic split, slower, never wrong."""
+        return min(_MAX_EVENT_CAPACITY, max(128, B * self.plan.n_always + P))
+
+    def program_capacities(self, B: int):
+        """(block, K, P, E) of the fused match+window program for B rows."""
+        block, K = self.capacities(B)
+        P = self.pair_capacity(B, K)
+        return block, K, P, self.event_capacity(B, P)
 
     def pairs_from_core(self, c, K: int, P: int):
         """The sparse (row, rule) pair extraction shared by the plain fused
@@ -604,6 +650,8 @@ class FusedPrefilter:
         -1 beyond n_pairs. Returns (pairs [P] int32, n_pairs, bits [K, R8])
         — `bits` is the unpacked MSB-first bit tensor so callers needing
         the per-candidate dense form don't unpack m2p twice."""
+        if not self._n_filt:
+            return jnp.zeros((0,), dtype=jnp.int32), jnp.int32(0), None
         R8 = self._nf8 * 8
         bits = (
             (c["m2p"][:, :, None] >> (7 - jnp.arange(8, dtype=jnp.int32))) & 1
@@ -613,7 +661,7 @@ class FusedPrefilter:
         # set (otherwise a stray pad bit inflates n_pairs toward spurious
         # PrefilterOverflow)
         bits = jnp.where(
-            jnp.arange(R8, dtype=jnp.int32) < self.plan.stage2.n_rules,
+            jnp.arange(R8, dtype=jnp.int32) < self._n_filt,
             bits, 0,
         )
         n_pairs = jnp.sum(bits, dtype=jnp.int32)
@@ -637,7 +685,7 @@ class FusedPrefilter:
         order."""
         plan = self.plan
         f1 = self._stage1_raw(B, L_p, block)
-        f2 = self._stage2(K, L_p, min(block, K))
+        f2 = self._stage2(K, L_p, min(block, K)) if K else None
         n_always = plan.n_always
         fmask = self._fmask
         a_word, a_mask, a_rule = self._a_word, self._a_mask, self._a_rule
@@ -658,6 +706,18 @@ class FusedPrefilter:
             lens = jnp.take(lens_raw, order)
             cls_t = jnp.take(cls_rows, order, axis=0).T          # [L_p, B]
             acc1 = f1(cls_t, lens)                               # [W1, B]
+            ab_caller = None
+            if n_always:
+                sel = (acc1[a_word, :] & a_mask[:, None]) != 0   # [n_abr, B]
+                ab = jnp.zeros((n_always, acc1.shape[1]), dtype=jnp.uint8)
+                ab = ab.at[a_rule].max(sel.astype(jnp.uint8))
+                ab_caller = jnp.zeros_like(ab.T).at[order].set(ab.T)
+            if f2 is None:
+                return {
+                    "lens_raw": lens_raw, "n_cand": jnp.int32(0),
+                    "m2p": None, "idx_caller_k": None,
+                    "ab_caller": ab_caller,
+                }
             cand = (acc1 & fmask[:, None]).max(axis=0) > 0       # [B]
             n_cand = jnp.sum(cand.astype(jnp.int32))
             (idx,) = jnp.nonzero(cand, size=K, fill_value=0)     # [K] ascending
@@ -671,12 +731,6 @@ class FusedPrefilter:
             idx_caller_k = jnp.where(
                 valid, jnp.take(order, idx), jnp.int32(B)
             )
-            ab_caller = None
-            if n_always:
-                sel = (acc1[a_word, :] & a_mask[:, None]) != 0   # [n_abr, B]
-                ab = jnp.zeros((n_always, acc1.shape[1]), dtype=jnp.uint8)
-                ab = ab.at[a_rule].max(sel.astype(jnp.uint8))
-                ab_caller = jnp.zeros_like(ab.T).at[order].set(ab.T)
             return {
                 "lens_raw": lens_raw, "n_cand": n_cand, "m2p": m2p,
                 "idx_caller_k": idx_caller_k, "ab_caller": ab_caller,
@@ -778,8 +832,7 @@ class FusedPrefilter:
             live = pairs[:n_pairs]
             rows_idx, cols = live // R8, live % R8
             keep = (
-                (rows_idx >= 0) & (rows_idx < B)
-                & (cols < plan.stage2.n_rules)
+                (rows_idx >= 0) & (rows_idx < B) & (cols < self._n_filt)
             )
             bits[rows_idx[keep], plan.f_idx[cols[keep]]] = 1
         if plan.n_always:

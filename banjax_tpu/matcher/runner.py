@@ -34,6 +34,7 @@ import numpy as np
 from banjax_tpu.config.schema import Config, RegexWithRate
 from banjax_tpu.matcher.kernels import nfa_match as pallas_nfa
 from banjax_tpu.decisions.rate_limit import (
+    RateLimitMatchType,
     RateLimitResult,
     RegexRateLimitStates,
 )
@@ -117,6 +118,9 @@ class TpuMatcher(Matcher):
         # often one fell back to the classic replay mid-pipeline
         self.pipelined_fused_chunks = 0
         self.pipelined_fused_fallbacks = 0
+        # host wall seconds inside the drain's `effector-replay` spans
+        # (event decode + shadow absorb + Banner replay of committed chunks)
+        self.effector_replay_s = 0.0
         # pipeline_fused=false restores the PR 2 behavior: the split
         # protocol always takes the classic bitmap path
         self._pipeline_fused = bool(getattr(config, "pipeline_fused", True))
@@ -246,7 +250,15 @@ class TpuMatcher(Matcher):
         self.traffic_sketch = None
         self._slot_admission = False
         self._admission_min_estimate = 1
-        self._host_row: Dict[str, int] = {}
+        # hosts with rules of their own or named in a hosts_to_skip get a
+        # row of the per-host tables; every other host shares row 0
+        hosts = sorted(
+            set(self._per_site_idx)
+            | {h for _, r in self._entries for h in r.hosts_to_skip}
+        )
+        self._host_row: Dict[str, int] = {
+            h: i + 1 for i, h in enumerate(hosts)
+        }
         if getattr(config, "matcher_device_windows", False):
             from banjax_tpu.matcher.windows import DeviceWindows
 
@@ -263,11 +275,6 @@ class TpuMatcher(Matcher):
             # (per-site rules of that host + global rules), minus
             # hosts_to_skip — the per-site-then-global loop of
             # regex_rate_limiter.go:175-211 as a device mask
-            hosts = sorted(
-                set(self._per_site_idx)
-                | {h for _, r in self._entries for h in r.hosts_to_skip}
-            )
-            self._host_row = {h: i + 1 for i, h in enumerate(hosts)}
             n_rules = len(self._entries)
             table = np.zeros((len(hosts) + 1, max(1, n_rules)), dtype=bool)
             for row_host, row in [(None, 0)] + list(self._host_row.items()):
@@ -357,6 +364,9 @@ class TpuMatcher(Matcher):
                     self._note_downgrade(
                         f"mesh prefilter plan failed ({e}); single-stage"
                     )
+                if mesh_plan is not None and mesh_plan.stage2 is None:
+                    # stage 1 decides every rule: nothing to shard over rp
+                    mesh_plan = None
 
             # block granularity only matters for the compiled kernel; the
             # XLA/interpret bodies shouldn't pad every batch to dp*128 rows
@@ -452,6 +462,22 @@ class TpuMatcher(Matcher):
         # whole ruleset per line (regex_rate_limiter.go:175-211 order)
         self._rule_pos_cache: Dict[str, Dict[int, int]] = {}
         self._global_pos = {int(x): k for k, x in enumerate(self._global_idx)}
+        # the same order as tables over (host row, rule id), for the
+        # columnar replay: rule ids ascending IS per-site-then-global
+        # order (per-site ids precede global ids in self._entries), so a
+        # row's results are its applicable matched ids, ascending.  Row 0
+        # is every host without per-site rules or a hosts_to_skip entry.
+        n_ent = max(1, len(self._entries))
+        self._applies = np.zeros((len(self._host_row) + 1, n_ent), dtype=bool)
+        self._skips = np.zeros_like(self._applies)
+        for row_host, row in [(None, 0)] + list(self._host_row.items()):
+            for idx in (
+                self._per_site_idx.get(row_host, []) if row_host else []
+            ) + self._global_idx:
+                self._applies[row, idx] = True
+                if row_host and self._entries[idx][1].hosts_to_skip.get(row_host):
+                    self._skips[row, idx] = True
+        self._rule_names = [r.rule for _, r in self._entries]
 
         # fully-fused matcher+windows pipeline: one device dispatch per
         # batch when both the fused prefilter and device windows are on and
@@ -588,6 +614,12 @@ class TpuMatcher(Matcher):
             "fused_protocol": protocol,
             "prefilter": self._prefilter is not None or (
                 mm is not None and mm.plan is not None
+            ),
+            # the plan's own selection: rules stage 1 decides by itself
+            # (prefilter._stage1_decides) among its always-columns
+            "stage1_decided_rules": (
+                self._prefilter.plan.n_decided
+                if self._prefilter is not None else None
             ),
             "mesh_shape": (
                 dict(self._mesh.shape) if self._mesh is not None else None
@@ -1232,6 +1264,7 @@ class TpuMatcher(Matcher):
                     self.note_device_outcome(0.0, ok=False)
                 finally:
                     self.stats.note_xfer(pend.h2d_bytes, pend.d2h_bytes)
+            self.effector_replay_s += time.perf_counter() - t0
             if overlapped:
                 # the d2h-overlap witness: this collect+replay wall time
                 # ran while a later chunk's B was in flight
@@ -1363,6 +1396,7 @@ class TpuMatcher(Matcher):
                 self._mark_chunk_error(e, chunk_stale, results)
                 self.note_device_outcome(0.0, ok=False)
                 continue
+            t0 = time.perf_counter()
             with trace.span("effector-replay", args={"row0": e["row0"]}):
                 try:
                     res = fw.collect(pend)
@@ -1381,6 +1415,7 @@ class TpuMatcher(Matcher):
                     self.note_device_outcome(0.0, ok=False)
                 finally:
                     self.stats.note_xfer(pend.h2d_bytes, pend.d2h_bytes)
+            self.effector_replay_s += time.perf_counter() - t0
         return n_stale
 
     def _mark_chunk_error(self, e, chunk_stale, results) -> None:
@@ -1934,104 +1969,99 @@ class TpuMatcher(Matcher):
                 bits = bits * live[:, None].astype(np.uint8)
         self._replay_window_events(e["work"], bits, None, events, results)
 
-    def _sparse_row_sets(self, n, sparse):
-        """Per-row matched rule-id sets from the pipeline's sparse result
-        ((row, rule) pairs: caller_row * R8 + packed stage-2 bit column)."""
+    def _matched_pairs(self, n, bits, sparse):
+        """(row, rule id) of every regex match of a chunk, as two int
+        arrays — from the pipeline's sparse result ((row, rule) pairs:
+        caller_row * R8 + packed stage-2 bit column, plus the packed
+        always-column bits) or from a dense [n, n_rules] bitmap."""
+        if sparse is None:
+            rows, rids = np.nonzero(bits[:n])
+            return rows.astype(np.int64), rids.astype(np.int64)
         matched_pairs, always_bits = sparse
-        plan = self._prefilter.plan
-        row_ids: Dict[int, set] = {}
+        pf = self._prefilter
+        plan = pf.plan
+        rows_l, rids_l = [], []
         if matched_pairs is not None and len(matched_pairs):
-            R8 = self._prefilter._nf8 * 8
+            R8 = pf._nf8 * 8
             rows_idx, cols = matched_pairs // R8, matched_pairs % R8
-            ok = cols < plan.stage2.n_rules
-            for row, rid in zip(rows_idx[ok], plan.f_idx[cols[ok]]):
-                row_ids.setdefault(int(row), set()).add(int(rid))
+            ok = cols < pf._n_filt
+            rows_l.append(rows_idx[ok].astype(np.int64))
+            rids_l.append(plan.f_idx[cols[ok]])
         if always_bits is not None and plan.n_always:
             ab = np.unpackbits(
                 always_bits[:n], axis=1, count=plan.n_always
             )
-            for row, col in zip(*np.nonzero(ab)):
-                row_ids.setdefault(int(row), set()).add(
-                    int(plan.a_idx[col])
-                )
-        return row_ids
+            rows, cols = np.nonzero(ab)
+            rows_l.append(rows.astype(np.int64))
+            rids_l.append(plan.a_idx[cols])
+        if not rows_l:
+            z = np.zeros(0, dtype=np.int64)
+            return z, z
+        return np.concatenate(rows_l), np.concatenate(rids_l)
 
     def _replay_window_events(
         self, work, bits, sparse, events, results, live_rows=None
     ) -> None:
-        """Replay window events + match bookkeeping into ConsumeLineResults
-        (per-site-then-global rule order, Banner per exceeded event) —
-        shared by the classic bitmap path and the fused pipeline.
-        `live_rows` (bool [n]) skips rows the drain-time staleness check
-        dropped: their bits were masked out of the window apply, so no
-        event exists for them and no effect may fire."""
-        evmap = {(e.line, e.rule_id): e for e in events}
-        if self.traffic_sketch is not None and events:
+        """Replay one applied chunk — shared by the classic bitmap path
+        and the fused pipeline.  Two parts, and only the first costs per
+        event:
+
+          * effects, one iteration per EXCEEDED event in reference order
+            ((line, rule id) ascending = per-site-then-global): Banner
+            ban + ban-log line + provenance.  With the default rules every
+            log line is a window event and about three in a thousand of
+            those exceed (the window restarts at 0 on an exceed); the
+            others have no effect to replay.
+          * per-line ConsumeLineResults: owed to `results` as a deferred
+            fill over the chunk's arrays (LazyResults.defer), built when
+            a caller reads a line's `rule_results` — the sync entry
+            point's callers and the tests do, the streaming drain does
+            not.  The fill keeps arrays only, never `work`: the sync
+            path's parse buffers are reused by the next batch.
+
+        `live_rows` (bool [n]) skips rows the staleness check dropped:
+        their bits were masked out of the window apply, so no event
+        exists for them and no result is owed."""
+        if self.traffic_sketch is not None and len(events):
             # per-rule match pressure, counted where every fired window
             # event already lands (fused commit, overflow fallback and
             # classic apply all replay through here) — exact even when a
             # chunk's device bitmap overflowed
             try:
-                self.traffic_sketch.note_rule_events(
-                    e.rule_id for e in events
-                )
+                self.traffic_sketch.note_rule_events(events.rule)
             except Exception:  # noqa: BLE001 — sketch is passive
                 log.exception("traffic sketch rule-pressure update failed")
-        if sparse is not None:
-            row_ids = self._sparse_row_sets(len(work), sparse)
-            row_iter = sorted(row_ids)
-        else:
-            row_any = bits.any(axis=1)
-            row_iter = (r for r in range(len(work)) if row_any[r])
-        if live_rows is not None:
-            row_iter = (r for r in row_iter if live_rows[r])
-        for row in row_iter:
+        exc = np.flatnonzero(events.exceeded)
+        for row, idx in zip(
+            events.line[exc].tolist(), events.rule[exc].tolist()
+        ):
             i, p = work[row]
-            # per-site-then-global ORDER via a position dict over the few
-            # matched ids — scanning the full rule-order array per row is
-            # O(n_rules) and dominated the replay at 1k-rule scale
-            pos = self._rule_pos(p.host)
-            if sparse is not None:
-                ids = row_ids[row]
-            else:
-                ids = np.nonzero(bits[row])[0].tolist()
-            matched = sorted(
-                (x for x in ids if x in pos), key=pos.__getitem__
-            )
+            rule = self._entries[idx][1]
             try:
-                for idx in matched:
-                    _, rule = self._entries[idx]
-                    result = RuleResult(rule_name=rule.rule, regex_match=True)
-                    if rule.hosts_to_skip.get(p.host):
-                        result.skip_host = True
-                        results[i].rule_results.append(result)
-                        continue
-                    result.skip_host = False
-                    e = evmap[(row, idx)]
-                    result.seen_ip = e.seen_ip
-                    result.rate_limit_result = RateLimitResult(
-                        match_type=e.match_type, exceeded=e.exceeded
-                    )
-                    if e.exceeded:
-                        self.banner.ban_or_challenge_ip(
-                            self.config, p.ip, rule.decision, p.host
-                        )
-                        self.banner.log_regex_ban(
-                            self.config, p.timestamp_ns / 1e9, p.ip,
-                            rule.rule, p.rest, rule.decision,
-                        )
-                        # fixed-window semantics: the ban fires the hit
-                        # after the threshold; the ambient drain span
-                        # supplies the admitting batch's trace id
-                        provenance.record(
-                            provenance.SOURCE_RATE_LIMIT, p.ip,
-                            rule.decision, rule=rule.rule, rule_index=idx,
-                            hits=rule.hits_per_interval + 1,
-                        )
-                    results[i].rule_results.append(result)
+                self.banner.ban_or_challenge_ip(
+                    self.config, p.ip, rule.decision, p.host
+                )
+                self.banner.log_regex_ban(
+                    self.config, p.timestamp_ns / 1e9, p.ip,
+                    rule.rule, p.rest, rule.decision,
+                )
+                # fixed-window semantics: the ban fires the hit after the
+                # threshold; the ambient drain span supplies the admitting
+                # batch's trace id
+                provenance.record(
+                    provenance.SOURCE_RATE_LIMIT, p.ip,
+                    rule.decision, rule=rule.rule, rule_index=idx,
+                    hits=rule.hits_per_interval + 1,
+                )
             except Exception:  # noqa: BLE001 — a failing effector loses one line, not the batch
                 log.exception("error applying rules to log line")
                 results[i].error = True
+
+        results.defer(_LineResultsFill(
+            self, len(work), bits, sparse, events, live_rows,
+            orig=work.orig_rows(),
+            hrow=work.host_idx(self._host_row) if self._host_row else None,
+        ))
 
     def _apply_device_windows(self, work, bits, results) -> None:
         """Classic device window path: apply_bitmap per batch, then replay
@@ -2279,6 +2309,64 @@ class TpuMatcher(Matcher):
                 rule=rule.rule, hits=rule.hits_per_interval + 1,
             )
         return result
+
+
+_MATCH_TYPES = tuple(RateLimitMatchType)
+
+
+class _LineResultsFill:
+    """The per-line results one replayed chunk owes (see
+    TpuMatcher._replay_window_events): built from the chunk's arrays when
+    a result is first read.  A line's results are its matched rules that
+    apply to its host, rule ids ascending (= per-site-then-global order);
+    one that is not skipped for the host fired exactly one window event."""
+
+    __slots__ = ("m", "n", "bits", "sparse", "ev", "live", "orig", "hrow")
+
+    def __init__(self, m, n, bits, sparse, ev, live, orig, hrow):
+        self.m, self.n, self.bits, self.sparse = m, n, bits, sparse
+        self.ev, self.live, self.orig, self.hrow = ev, live, orig, hrow
+
+    def __call__(self, results) -> None:
+        m, ev = self.m, self.ev
+        m_row, m_rid = m._matched_pairs(self.n, self.bits, self.sparse)
+        if self.live is not None:
+            keep = np.asarray(self.live, dtype=bool)[m_row]
+            m_row, m_rid = m_row[keep], m_rid[keep]
+        hrow = (
+            np.zeros(len(m_row), dtype=np.int64) if self.hrow is None
+            else self.hrow[m_row]
+        )
+        keep = m._applies[hrow, m_rid]
+        m_row, m_rid, hrow = m_row[keep], m_rid[keep], hrow[keep]
+        order = np.lexsort((m_rid, m_row))
+        m_row, m_rid, hrow = m_row[order], m_rid[order], hrow[order]
+        n_ent = m._applies.shape[1]
+        ev_at = np.searchsorted(
+            ev.line.astype(np.int64) * n_ent + ev.rule, m_row * n_ent + m_rid
+        )
+        names = m._rule_names
+        mtype = ev.match_type.tolist()
+        exceeded = ev.exceeded.tolist()
+        seen = ev.seen_ip.tolist()
+        for i, idx, skip, k in zip(
+            np.asarray(self.orig)[m_row].tolist(), m_rid.tolist(),
+            m._skips[hrow, m_rid].tolist(), ev_at.tolist(),
+        ):
+            if skip:
+                rr = RuleResult(
+                    rule_name=names[idx], regex_match=True, skip_host=True
+                )
+            else:
+                rr = RuleResult(
+                    rule_name=names[idx], regex_match=True, skip_host=False,
+                    seen_ip=seen[k],
+                    rate_limit_result=RateLimitResult(
+                        match_type=_MATCH_TYPES[mtype[k]],
+                        exceeded=exceeded[k],
+                    ),
+                )
+            results.owed(i).append(rr)
 
 
 def _bucket(n: int, cap: int) -> int:

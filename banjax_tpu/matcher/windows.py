@@ -180,7 +180,7 @@ def _apply_core(
     """The traceable window-apply body — composable inside a larger jit
     (the fused matcher+windows pipeline) as well as the standalone
     _apply_step below. Caller guarantees evictions/restores already ran
-    (_maintenance_step). `gate` supports overflow handling under buffer
+    (_run_maintenance_locked). `gate` supports overflow handling under buffer
     donation: when False, all scatters drop (indices pushed out of range)
     so the donated state passes through bit-identical and the caller can
     rerun the batch through the splitting path — no state copy needed.
@@ -292,39 +292,48 @@ def _apply_step(state, bits, active_table, host_idx, slot_ids, ts_s, ts_ns,
     )
 
 
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _maintenance_step(
-    state: DeviceWindowState,
-    ev_slots: jnp.ndarray,  # [K] int32 evicted slots (cap = none)
-    r_slots: jnp.ndarray,   # [K] int32 slots to mark seen (cap = none)
-    r_keys: jnp.ndarray,    # [Kr] int32 flat keys to restore (cap_r = none)
-    r_hits: jnp.ndarray,    # [Kr] int32
-    r_ss: jnp.ndarray,      # [Kr] int32
-    r_sns: jnp.ndarray,     # [Kr] int32
-):
-    """Evictions THEN restores, in one dispatch: a slot can be evicted and
-    immediately reassigned+restored between two apply steps, so the order
-    within this step is what keeps the restored state from being cleared —
-    the restored keys are stamped with the generation AFTER the bump.
+# restored keys go to the device in chunks of this many: ONE program,
+# whatever a batch brings back.  A class per power of two (as the evicted
+# slots have) is a program per class, and with rules that fire on every
+# line a batch restores hundreds of addresses, so the classes it reaches
+# differ from batch to batch and the rare ones would be built minutes into
+# a run, each build a stall of the whole pipeline.
+_RESTORE_CHUNK = 1024
 
-    Cost: two [K] scatters for the K evicted slots (the bump invalidates
-    every rule's key of a slot without touching one of them) and four [Kr]
-    scatters for the Kr restored keys; nothing here is sized by n_rules.
-    A slot evicted twice between two steps appears twice in `ev_slots`
-    and is bumped twice, which is as good as once."""
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _evict_step(state: DeviceWindowState, ev_slots: jnp.ndarray):
+    """Evict K slots ([K] int32, cap = none): two [K] scatters — the
+    generation bump invalidates every rule's key of a slot without
+    touching one of them; nothing here is sized by n_rules.  A slot
+    evicted twice between two steps appears twice and is bumped twice,
+    which is as good as once."""
+    return dataclasses.replace(
+        state,
+        slot_gen=state.slot_gen.at[ev_slots].add(1, mode="drop"),
+        ip_seen=state.ip_seen.at[ev_slots].set(False, mode="drop"),
+    )
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _restore_step(state: DeviceWindowState, rows: jnp.ndarray):
+    """Restore Kr keys: five [Kr] scatters.  `rows` is [5, Kr] int32 —
+    each key's slot (cap = none), flat key (cap * n_rules = none), hits,
+    start_s, start_ns — one operand, so one host-to-device transfer.
+    Dispatched AFTER the evict step of the same maintenance run: a slot
+    can be evicted and at once reassigned and restored between two apply
+    steps, and the restored keys are stamped with the generation after
+    the bump."""
+    r_slots, r_keys, r_hits, r_ss, r_sns = rows
     cap = state.slot_gen.shape[0]
-    n_rules = state.key_gen.shape[0] // cap
-    slot_gen = state.slot_gen.at[ev_slots].add(1, mode="drop")
-    ip_seen = state.ip_seen.at[ev_slots].set(False, mode="drop")
-    hits = state.hits.at[r_keys].set(r_hits, mode="drop")
-    start_s = state.start_s.at[r_keys].set(r_ss, mode="drop")
-    start_ns = state.start_ns.at[r_keys].set(r_sns, mode="drop")
-    r_gen = slot_gen[jnp.minimum(r_keys // n_rules, cap - 1)]
-    key_gen = state.key_gen.at[r_keys].set(r_gen, mode="drop")
-    ip_seen = ip_seen.at[r_slots].set(True, mode="drop")
-    return DeviceWindowState(
-        hits=hits, start_s=start_s, start_ns=start_ns, key_gen=key_gen,
-        slot_gen=slot_gen, ip_seen=ip_seen,
+    r_gen = state.slot_gen[jnp.minimum(r_slots, cap - 1)]
+    return dataclasses.replace(
+        state,
+        hits=state.hits.at[r_keys].set(r_hits, mode="drop"),
+        start_s=state.start_s.at[r_keys].set(r_ss, mode="drop"),
+        start_ns=state.start_ns.at[r_keys].set(r_sns, mode="drop"),
+        key_gen=state.key_gen.at[r_keys].set(r_gen, mode="drop"),
+        ip_seen=state.ip_seen.at[r_slots].set(True, mode="drop"),
     )
 
 
@@ -346,6 +355,49 @@ class WindowEvent:
     match_type: RateLimitMatchType
     exceeded: bool
     seen_ip: bool
+
+
+@dataclasses.dataclass
+class EventBatch:
+    """One chunk's applied window transitions as arrays, in reference
+    order ((line, rule_id) ascending).  At one event per log line a
+    Python object per event is what the drain thread spends its time on,
+    so the fused path keeps events columnar: the replay touches only the
+    `exceeded` ones, and per-line results are built from these arrays
+    when something reads them.  Iterating yields WindowEvents."""
+
+    line: np.ndarray        # int32 [n]
+    rule: np.ndarray        # int32 [n]
+    match_type: np.ndarray  # uint8 [n]
+    exceeded: np.ndarray    # bool [n]
+    seen_ip: np.ndarray     # bool [n]
+
+    @classmethod
+    def concat(cls, a: "EventBatch", b: "EventBatch", b_line0: int):
+        """`a` then `b`, `b`'s lines shifted by `b_line0` (two halves of
+        one batch applied in order)."""
+        return cls(
+            line=np.concatenate([a.line, b.line + np.int32(b_line0)]),
+            rule=np.concatenate([a.rule, b.rule]),
+            match_type=np.concatenate([a.match_type, b.match_type]),
+            exceeded=np.concatenate([a.exceeded, b.exceeded]),
+            seen_ip=np.concatenate([a.seen_ip, b.seen_ip]),
+        )
+
+    def __len__(self) -> int:
+        return len(self.line)
+
+    def __getitem__(self, k: int) -> WindowEvent:
+        return WindowEvent(
+            line=int(self.line[k]),
+            rule_id=int(self.rule[k]),
+            match_type=RateLimitMatchType(int(self.match_type[k])),
+            exceeded=bool(self.exceeded[k]),
+            seen_ip=bool(self.seen_ip[k]),
+        )
+
+    def __iter__(self):
+        return (self[k] for k in range(len(self.line)))
 
 
 class DeviceWindows:
@@ -482,6 +534,8 @@ class DeviceWindows:
         # the step stayed O(evicted slots) — a few, not a multiple of n_rules
         self.maintenance_steps = 0
         self.maintenance_elems = 0
+        # window events committed by device applies (fused or classic)
+        self.device_events = 0
         # Host shadow of the device counters: ip → (rule_id → (hits, s, ns)),
         # both dicts in first-event insertion order — exactly the reference
         # host dict's shape (rate_limit.go:37-78, which never forgets).
@@ -984,7 +1038,7 @@ class DeviceWindows:
         ts_ns: np.ndarray,
         active_table,              # [H, R] bool (device-resident, cached by caller)
         host_idx: np.ndarray,      # [B] int32 — row of active_table per line
-    ) -> List[WindowEvent]:
+    ) -> EventBatch:
         """Apply one batch; returns the events in reference order.
 
         The event count is checked BEFORE any state mutation; a batch with
@@ -1001,7 +1055,7 @@ class DeviceWindows:
 
     def _apply_bitmap_inner(
         self, bits, slot_ids, ts_s, ts_ns, active_table, host_idx
-    ) -> List[WindowEvent]:
+    ) -> EventBatch:
         bits = jnp.asarray(bits)
         active_table = jnp.asarray(active_table)
         host_idx = np.asarray(host_idx, dtype=np.int32)
@@ -1028,9 +1082,7 @@ class DeviceWindows:
                 bits[mid:B], slot_ids[mid:B], ts_s[mid:B], ts_ns[mid:B],
                 active_table, host_idx[mid:B],
             )
-            for e in ev2:
-                e.line += mid
-            return ev1 + ev2
+            return EventBatch.concat(ev1, ev2, mid)
 
         with self._lock:
             self._run_maintenance_locked()
@@ -1063,42 +1115,56 @@ class DeviceWindows:
             f_hits = np.asarray(out["hits"])
             f_ss = np.asarray(out["start_s"])
             f_sns = np.asarray(out["start_ns"])
+            # reference order: by (line, rule_id) — per-site ids precede global
             live = np.flatnonzero(rule >= 0)
-            # shadow update in (line, rule) order — the reference's
-            # processing order — so dict INSERTION order matches the host
-            # path's first-matched-event order (format_states parity; slot
-            # numbering follows batch appearance, which can differ). Each
-            # (ip, rule)'s last write is still its chronologically-last
-            # event, i.e. the segment-final state written on device.
-            order = np.lexsort((rule[live], line[live]))
-            for k in live[order]:
-                ip = self._slot_ip.get(int(slot_ids[int(line[k])]))
-                if ip is None:  # unreachable while the batch is pinned
-                    continue
-                od = self._shadow.setdefault(ip, OrderedDict())
-                od[int(rule[k])] = (int(f_hits[k]), int(f_ss[k]), int(f_sns[k]))
-
-        events = [
-            WindowEvent(
-                line=int(line[k]),
-                rule_id=int(rule[k]),
-                match_type=RateLimitMatchType(int(mtype[k])),
-                exceeded=bool(exceeded[k]),
-                seen_ip=bool(seen[k]),
+            live = live[np.lexsort((rule[live], line[live]))]
+            self._absorb_events_locked(
+                slot_ids, line[live], rule[live], f_hits[live], f_ss[live],
+                f_sns[live],
             )
-            for k in live
-        ]
-        # reference order: by (line, rule_id) — per-site ids precede global
-        events.sort(key=lambda e: (e.line, e.rule_id))
-        return events
+        return EventBatch(
+            line=line[live], rule=rule[live],
+            match_type=mtype[live].astype(np.uint8),
+            exceeded=exceeded[live] != 0, seen_ip=seen[live] != 0,
+        )
+
+    def _absorb_events_locked(
+        self, slot_ids, line, rule, hits, ss, sns
+    ) -> None:
+        """Fold one applied chunk's per-event final counter states into
+        the host shadow (caller holds the lock; the arrays hold live
+        events only, in (line, rule) order).  That is the reference's
+        processing order, so dict INSERTION order matches the host path's
+        first-matched-event order (format_states parity; slot numbering
+        follows batch appearance, which can differ).  Each (ip, rule)'s
+        last write is still its chronologically-last event, i.e. the
+        segment-final state written on device.  One dict store per event
+        and nothing else: at one event per log line this loop is the
+        drain thread's floor."""
+        self.device_events += len(line)
+        if not len(line):
+            return
+        slot_ip = self._slot_ip
+        shadow = self._shadow
+        for slot, rid, h, s, ns in zip(
+            np.asarray(slot_ids)[line].tolist(), rule.tolist(),
+            hits.tolist(), ss.tolist(), sns.tolist(),
+        ):
+            ip = slot_ip.get(slot)
+            if ip is None:  # unreachable while the batch is pinned
+                continue
+            od = shadow.get(ip)
+            if od is None:
+                od = shadow[ip] = OrderedDict()
+            od[rid] = (h, s, ns)
 
     def _run_maintenance_locked(self) -> None:
-        """Drain queued evictions + restores into the device state (caller
-        holds the lock).  What goes to the device is one int32 per evicted
-        slot and per restored slot, and four per restored key — never a
-        per-(slot, rule) expansion.  The slot operands and the restore
-        operands are padded to a power of two each, on their own (restores
-        are sparse beside evictions), so the jit cache stays bounded;
+        """Drain queued evictions, then restores, into the device state
+        (caller holds the lock).  What goes to the device is two int32 per
+        evicted slot and five per restored key — never a per-(slot, rule)
+        expansion.  Evicted slots are padded to a power of two (five
+        classes up to a 4,096-line batch), restored keys go in chunks of
+        _RESTORE_CHUNK (one program), so the jit cache stays bounded;
         padded entries scatter out of range and drop."""
         if not self._pending_evict and not self._pending_restore:
             return
@@ -1108,11 +1174,7 @@ class DeviceWindows:
         self._pending_evict = []
         self._pending_restore = []
 
-        r_keys: List[int] = []
-        r_hits: List[int] = []
-        r_ss: List[int] = []
-        r_sns: List[int] = []
-        r_slots: List[int] = []
+        restored: List[Tuple[int, int, int, int, int]] = []
         for slot, ip in pend_rs:
             if self._slot_ip.get(slot) != ip:
                 # stale restore: the slot was re-evicted (and possibly
@@ -1123,38 +1185,32 @@ class DeviceWindows:
             od = self._shadow.get(ip)
             if not od:
                 continue
-            r_slots.append(slot)
             base = slot * self.n_rules
             for rid, (h, s, ns) in od.items():
-                r_keys.append(base + rid)
-                r_hits.append(h)
-                r_ss.append(s)
-                r_sns.append(ns)
+                restored.append((slot, base + rid, h, s, ns))
 
-        def _pad(vals, fill, k):
-            arr = np.full((k,), fill, dtype=np.int32)
-            arr[: len(vals)] = vals
-            return jnp.asarray(arr)
-
-        ks = _bucket(max(len(pend_ev), len(r_slots)), _MIN_MAINT_BUCKET)
-        kr = _bucket(len(r_keys), _MIN_MAINT_BUCKET)
         self.maintenance_steps += 1
-        self.maintenance_elems += 2 * ks + 4 * kr
-        self._state = _maintenance_step(
-            self._state,
-            _pad(pend_ev, self.capacity, ks),
-            _pad(r_slots, self.capacity, ks),
-            _pad(r_keys, cap_r, kr),
-            _pad(r_hits, 0, kr),
-            _pad(r_ss, 0, kr),
-            _pad(r_sns, 0, kr),
-        )
+        if pend_ev:
+            ks = _bucket(len(pend_ev), _MIN_MAINT_BUCKET)
+            self.maintenance_elems += 2 * ks
+            ev_slots = np.full((ks,), self.capacity, dtype=np.int32)
+            ev_slots[: len(pend_ev)] = pend_ev
+            self._state = _evict_step(self._state, jnp.asarray(ev_slots))
+        kr = _RESTORE_CHUNK
+        for c in range(0, len(restored), kr):
+            part = restored[c : c + kr]
+            self.maintenance_elems += 5 * kr
+            rows = np.zeros((5, kr), dtype=np.int32)
+            rows[0] = self.capacity
+            rows[1] = cap_r
+            rows[:, : len(part)] = np.asarray(part, dtype=np.int32).T
+            self._state = _restore_step(self._state, jnp.asarray(rows))
 
     # ---- refused-row host apply (cold-tier path) ----
 
     def apply_host_events(
         self, events: Sequence[Tuple[int, int, str, int]]
-    ) -> List[WindowEvent]:
+    ) -> EventBatch:
         """Window transitions for REFUSED rows — the slot-admission
         gate's classic per-line path.  `events` is a list of
         (row, rule_id, ip, ts_ns), pre-sorted by (row, rule_id)
@@ -1174,9 +1230,9 @@ class DeviceWindows:
         therefore ADMITTED next batch (admission rule 2), which bounds
         the ban delay to the single batch in which the sketch estimate
         first crossed the threshold."""
-        out: List[WindowEvent] = []
-        if not events:
-            return out
+        mtypes: List[int] = []
+        exceeds: List[bool] = []
+        seens: List[bool] = []
         with self._lock:
             touched: "Dict[str, OrderedDict]" = {}
             warm = self._warm
@@ -1212,12 +1268,9 @@ class DeviceWindows:
                     s1, n1 = s0, n0
                 exceeded = h1 > int(self._limits_np[rid])
                 od[rid] = (0 if exceeded else h1, s1, n1)
-                mtype = 0 if not have else (1 if outside else 2)
-                out.append(WindowEvent(
-                    line=int(row), rule_id=int(rid),
-                    match_type=RateLimitMatchType(mtype),
-                    exceeded=bool(exceeded), seen_ip=seen,
-                ))
+                mtypes.append(0 if not have else (1 if outside else 2))
+                exceeds.append(exceeded)
+                seens.append(seen)
             now_ns = time.time_ns()
             for ip, od in touched.items():
                 if warm is not None:
@@ -1230,7 +1283,14 @@ class DeviceWindows:
                         continue
                 # warm off (or the put dropped): the shadow is the home
                 self._shadow[ip] = od
-        return out
+        n = len(events)
+        return EventBatch(
+            line=np.fromiter((e[0] for e in events), np.int32, n),
+            rule=np.fromiter((e[1] for e in events), np.int32, n),
+            match_type=np.asarray(mtypes, dtype=np.uint8),
+            exceeded=np.asarray(exceeds, dtype=bool),
+            seen_ip=np.asarray(seens, dtype=bool),
+        )
 
     # ---- introspection parity with RegexRateLimitStates ----
     # The host shadow (updated from every batch's event-final states) is the

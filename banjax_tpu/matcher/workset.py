@@ -27,7 +27,41 @@ from typing import Dict, List, Tuple
 
 import numpy as np
 
+from banjax_tpu.matcher.api import ConsumeLineResult
 from banjax_tpu.matcher.encode import ParsedLine
+
+
+class _LineResult(ConsumeLineResult):
+    """A ConsumeLineResult of a LazyResults: `error`/`old_line`/
+    `exempted` are set when they are decided, `rule_results` may still be
+    owed by a replayed chunk (LazyResults.defer) and is filled the first
+    time anything reads it."""
+
+    def __init__(self, owner: "LazyResults"):
+        self.error = self.old_line = self.exempted = False
+        self._owner = owner
+        self._rr: list = []
+
+    @property
+    def rule_results(self) -> list:
+        if self._owner._deferred:
+            self._owner.flush()
+        return self._rr
+
+    @rule_results.setter
+    def rule_results(self, value: list) -> None:
+        self._rr = value
+
+    def __eq__(self, other):
+        if not isinstance(other, ConsumeLineResult):
+            return NotImplemented
+        return (
+            self.error, self.old_line, self.exempted, self.rule_results
+        ) == (
+            other.error, other.old_line, other.exempted, other.rule_results
+        )
+
+    __hash__ = None
 
 
 class LazyResults:
@@ -35,13 +69,29 @@ class LazyResults:
     on first access. consume_lines must return one result per line, but
     production (cli._consume_lines) only reads them in debug mode — eager
     construction of 65k dataclasses per batch costs more than the whole
-    vectorized gate."""
+    vectorized gate.  The same goes for the entries' `rule_results`: a
+    replayed chunk hands in a fill (`defer`) that runs when one is read."""
 
-    __slots__ = ("_items", "_n_set")
+    __slots__ = ("_items", "_n_set", "_deferred")
 
     def __init__(self, n: int):
         self._items = [None] * n
         self._n_set = 0
+        self._deferred: list = []
+
+    def defer(self, fill) -> None:
+        """`fill(results)` will append the rule results it owes through
+        `owed(i)`; fills run in the order they were handed in."""
+        self._deferred.append(fill)
+
+    def flush(self) -> None:
+        fills, self._deferred = self._deferred, []
+        for fill in fills:
+            fill(self)
+
+    def owed(self, i: int) -> list:
+        """Entry i's rule_results list, for a fill to append to."""
+        return self[i]._rr
 
     def __len__(self) -> int:
         return len(self._items)
@@ -51,9 +101,7 @@ class LazyResults:
             return [self[k] for k in range(*i.indices(len(self._items)))]
         r = self._items[i]
         if r is None:
-            from banjax_tpu.matcher.api import ConsumeLineResult
-
-            r = self._items[i] = ConsumeLineResult()
+            r = self._items[i] = _LineResult(self)
             self._n_set += 1
         return r
 
@@ -71,6 +119,7 @@ class LazyResults:
         dst = self._items
         for i, r in enumerate(other._items):
             if r is not None:
+                r._owner = self
                 dst[row0 + i] = r
         self._n_set += other._n_set
 
@@ -174,6 +223,10 @@ class NativeWork:
         ips_u = self.ips_u
         return [ips_u[j] for j in present.tolist()], inv
 
+    def orig_rows(self) -> np.ndarray:
+        """Original line index per row (the results vector's index)."""
+        return self.rows
+
     def host_idx(self, host_row: Dict[str, int]) -> np.ndarray:
         tbl = np.asarray(
             [host_row.get(h, 0) for h in self.hosts_u], dtype=np.int32
@@ -200,6 +253,9 @@ class ListWork(list):
                 uniq[p.ip] = j
             inv[k] = j
         return list(uniq), inv
+
+    def orig_rows(self) -> np.ndarray:
+        return np.fromiter((i for i, _ in self), np.int64, len(self))
 
     def host_idx(self, host_row: Dict[str, int]) -> np.ndarray:
         return np.asarray(
@@ -303,6 +359,12 @@ class CompositeWork:
                 remap[j] = g
             invs.append(remap[np.asarray(inv, dtype=np.int64)])
         return strings, np.concatenate(invs)
+
+    def orig_rows(self) -> np.ndarray:
+        return np.concatenate([
+            np.asarray(w.orig_rows(), dtype=np.int64) + off
+            for w, off in zip(self.parts, self.offsets)
+        ])
 
     def host_idx(self, host_row: Dict[str, int]) -> np.ndarray:
         return np.concatenate([w.host_idx(host_row) for w in self.parts])

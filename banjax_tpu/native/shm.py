@@ -380,11 +380,19 @@ class ShmWarmTier:
             self._map_base()
             if lib.wt_check(self._base_ptr) < 0:
                 raise RuntimeError(f"shm segment {name} is not a wt table")
-        # scratch output arrays reused by take/get (max_rules is small)
+        # scratch arrays reused by put/take/get, with their C pointers:
+        # with rules that fire on every line each eviction is a put, so
+        # the per-call array and pointer construction would be the cost
         self._rid = np.zeros(self.max_rules, dtype=np.int32)
         self._hits = np.zeros(self.max_rules, dtype=np.int32)
         self._ss = np.zeros(self.max_rules, dtype=np.int64)
         self._sns = np.zeros(self.max_rules, dtype=np.int64)
+        i32p = ctypes.POINTER(ctypes.c_int32)
+        i64p = ctypes.POINTER(ctypes.c_int64)
+        self._ptrs = (
+            self._rid.ctypes.data_as(i32p), self._hits.ctypes.data_as(i32p),
+            self._ss.ctypes.data_as(i64p), self._sns.ctypes.data_as(i64p),
+        )
 
     @property
     def name(self) -> str:
@@ -400,16 +408,13 @@ class ShmWarmTier:
             return False
         key = _wt_key(ip)
         n = min(len(entries), self.max_rules)
-        i32p = ctypes.POINTER(ctypes.c_int32)
-        i64p = ctypes.POINTER(ctypes.c_int64)
-        rid = np.fromiter((e[0] for e in entries), np.int32, count=len(entries))
-        hits = np.fromiter((e[1] for e in entries), np.int32, count=len(entries))
-        ss = np.fromiter((e[2] for e in entries), np.int64, count=len(entries))
-        sns = np.fromiter((e[3] for e in entries), np.int64, count=len(entries))
+        rid, hits, ss, sns = zip(*entries[:n])
+        self._rid[:n] = rid
+        self._hits[:n] = hits
+        self._ss[:n] = ss
+        self._sns[:n] = sns
         rc = self._lib.wt_put(
-            base, key, len(key), now_ns, self.expiry_ns,
-            rid.ctypes.data_as(i32p), hits.ctypes.data_as(i32p),
-            ss.ctypes.data_as(i64p), sns.ctypes.data_as(i64p), n,
+            base, key, len(key), now_ns, self.expiry_ns, *self._ptrs, n,
         )
         return rc == 0
 
@@ -418,20 +423,13 @@ class ShmWarmTier:
         if base is None:
             return None
         key = _wt_key(ip)
-        i32p = ctypes.POINTER(ctypes.c_int32)
-        i64p = ctypes.POINTER(ctypes.c_int64)
-        n = int(fn(
-            base, key, len(key),
-            self._rid.ctypes.data_as(i32p), self._hits.ctypes.data_as(i32p),
-            self._ss.ctypes.data_as(i64p), self._sns.ctypes.data_as(i64p),
-        ))
+        n = int(fn(base, key, len(key), *self._ptrs))
         if n < 0:
             return None
-        return [
-            (int(self._rid[k]), int(self._hits[k]),
-             int(self._ss[k]), int(self._sns[k]))
-            for k in range(n)
-        ]
+        return list(zip(
+            self._rid[:n].tolist(), self._hits[:n].tolist(),
+            self._ss[:n].tolist(), self._sns[:n].tolist(),
+        ))
 
     def take(self, ip: str) -> Optional[WarmEntries]:
         """Refill read: the record is deleted (move semantics — the state

@@ -154,6 +154,14 @@ def render_prometheus(
         for s in _BREAKER_STATES:
             w.sample(fam, 1 if breaker_state == s else 0, {"state": s})
 
+    # fused overflows by cause (prom-only labeled counter; the unlabeled
+    # banjax_pipelined_fused_fallbacks_total keeps its shape beside it)
+    fw = getattr(matcher, "_fw_pipeline", None) if matcher else None
+    if fw is not None:
+        fam = registry.PROM_FAMILIES["banjax_fused_overflows_total"]
+        for cause, v in fw.overflow_causes.items():
+            w.sample(fam, v, {"cause": cause})
+
     # per-worker encode busy fractions (prom-only labeled gauge)
     if pipeline is not None:
         fracs = pipeline.stats.worker_busy_fractions()

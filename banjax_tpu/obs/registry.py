@@ -132,6 +132,10 @@ FAMILIES: List[Family] = [
            "digits while the step is O(evicted slots))",
            line_key="DeviceWindowsMaintenanceElems",
            prom="banjax_device_windows_maintenance_elems_total"),
+    Family(COUNTER, "window events ((line, rule) transitions) committed "
+           "by device applies, fused or classic",
+           line_key="DeviceWindowsEvents",
+           prom="banjax_device_windows_events_total"),
     Family(COUNTER, "device window capacity grows",
            line_key="DeviceWindowsGrows",
            prom="banjax_device_windows_grows_total"),
@@ -199,6 +203,19 @@ FAMILIES: List[Family] = [
     Family(COUNTER, "two-phase chunks replayed classically (overflow)",
            line_key="PipelinedFusedFallbacks",
            prom="banjax_pipelined_fused_fallbacks_total"),
+    Family(COUNTER, "fused dispatches that committed nothing and were "
+           "replayed classically, by what overflowed: the chunk's own "
+           "candidates, (row, rule) pairs or window events, or chain (gated "
+           "by an overflowing predecessor)",
+           prom="banjax_fused_overflows_total", labels=("cause",)),
+    Family(COUNTER, "host wall seconds inside the drain's effector-replay "
+           "spans (event decode, shadow absorb, Banner replay of committed "
+           "fused chunks)",
+           line_key="EffectorReplaySeconds",
+           prom="banjax_effector_replay_seconds_total"),
+    Family(COUNTER, "ban-log records the regex rate limiter wrote",
+           line_key="RegexBanRecords",
+           prom="banjax_regex_ban_records_total"),
     Family(GAUGE, "configured fused-drain resolve-ahead depth",
            line_key="DrainResolveAheadDepth",
            prom="banjax_drain_resolve_ahead_depth"),

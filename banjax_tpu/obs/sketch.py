@@ -337,9 +337,10 @@ class TrafficSketch:
         pressure accumulators — called from the Banner replay with the
         event list every path already decodes, so pressure is EXACT even
         for chunks whose device bitmap overflowed."""
-        ids = np.fromiter(
-            (int(r) for r in rule_ids), dtype=np.int64
-        )
+        if isinstance(rule_ids, np.ndarray):
+            ids = rule_ids.astype(np.int64)
+        else:
+            ids = np.fromiter((int(r) for r in rule_ids), dtype=np.int64)
         if not ids.size:
             return
         counts = np.bincount(

@@ -120,6 +120,9 @@ class MatcherStats:
             out["DeviceWindowsMaintenanceElems"] = getattr(
                 device_windows, "maintenance_elems", 0
             )
+            out["DeviceWindowsEvents"] = getattr(
+                device_windows, "device_events", 0
+            )
             out["DeviceWindowsGrows"] = getattr(device_windows, "grow_count", 0)
             # which slot-assignment path is live: the native C manager
             # (native/slotmgr.c) or the Python dict+LRU fallback/oracle
@@ -165,6 +168,11 @@ class MatcherStats:
                 )
             if getattr(matcher, "_prefilter", None) is not None:
                 out["PrefilterActive"] = True
+            records = getattr(
+                getattr(matcher, "banner", None), "regex_ban_records", None
+            )
+            if records is not None:
+                out["RegexBanRecords"] = records
             fw = getattr(matcher, "_fw_pipeline", None)
             if fw is not None:
                 out["PipelineFusedBatches"] = fw.fused_batches
@@ -177,6 +185,9 @@ class MatcherStats:
                 )
                 out["PipelinedFusedFallbacks"] = getattr(
                     matcher, "pipelined_fused_fallbacks", 0
+                )
+                out["EffectorReplaySeconds"] = round(
+                    getattr(matcher, "effector_replay_s", 0.0), 6
                 )
                 # depth-2 resolve-ahead drain: configured depth, and the
                 # EWMA wall time of event decode + replay that ran while

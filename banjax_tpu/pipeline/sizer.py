@@ -27,7 +27,15 @@ observed per-stage timings instead:
     next power of two can be strictly slower per line (measured: the
     1-core CI box degrades past 2048).  Blocked growth is retried after
     `_RETRY_BLOCKED` decisions so a stale measurement (e.g. one polluted
-    by a first-visit compile) cannot pin the size forever.
+    by a first-visit compile) cannot pin the size forever.  The verdict
+    "this bucket does not pay" is taken only on probation — the first
+    `_PROBATION` samples after GROWING into the bucket, from fresh
+    samples against the bucket just left.  Past it the size stands until
+    the budget says otherwise: held against the lower bucket's frozen
+    record for good, one slow batch (a collector pass, a neighbour on the
+    host) halves a size that had proven itself, and the way back is
+    blocked by the record that batch left (measured on the v5e: 5 s
+    slices at two thirds of the rate for 20 s of a 40 s window).
   * a bucket change resets the EWMA and requires `settle` fresh samples
     before the next move, so one noisy batch cannot oscillate the size.
 
@@ -47,6 +55,9 @@ _EFFICIENCY_SLACK = 1.05
 # decisions after which a blocked grow forgets the upper bucket's stale
 # per-line record and probes again
 _RETRY_BLOCKED = 50
+# samples after growing into a bucket during which the efficiency guard
+# may send it back
+_PROBATION = 8
 
 
 def _pow2_at_most(n: int) -> int:
@@ -93,6 +104,7 @@ class AdaptiveBatchSizer:
         # and how many grow decisions the upper bucket's record has blocked
         self._per_line_at: Dict[int, float] = {}
         self._blocked_grows = 0
+        self._on_probation = False  # grew into this bucket, verdict open
         # the first full batch after a bucket change pays that bucket's
         # one-time jit compile; learning from it would poison both the
         # latency EWMA and the per-line efficiency record
@@ -157,9 +169,10 @@ class AdaptiveBatchSizer:
                 self._bucket >>= 1
                 self._reset_locked()
             elif (
-                lower_pl is not None
+                self._on_probation
+                and self._samples_at_bucket <= _PROBATION
+                and lower_pl is not None
                 and cur_pl > lower_pl * _EFFICIENCY_SLACK
-                and self._bucket > self.min_batch
             ):
                 # latency fits, but this bucket is per-line WORSE than the
                 # one below: larger batches are not paying here — go back
@@ -179,11 +192,15 @@ class AdaptiveBatchSizer:
                     return
                 self._bucket <<= 1
                 self._reset_locked()
+                # judged on what it shows now, not on an old visit
+                self._per_line_at.pop(self._bucket, None)
+                self._on_probation = True
 
     def _reset_locked(self) -> None:
         self._total_ewma_ms = None
         self._samples_at_bucket = 0
         self._skip_first = True
+        self._on_probation = False
 
     def snapshot(self) -> Dict[str, object]:
         with self._lock:

@@ -618,20 +618,31 @@ def test_maintenance_operands_do_not_scale_with_rules(n_rules, monkeypatch):
 
     batch("a", cap, base)                  # fills the table
     seen = []
-    real = W._maintenance_step
 
-    def spy(state, *operands):
-        seen.append([int(o.shape[0]) for o in operands])
-        return real(state, *operands)
+    def spy(name):
+        real = getattr(W, name)
 
-    monkeypatch.setattr(W, "_maintenance_step", spy)
+        def step(state, *operands):
+            seen.append([name] + [list(o.shape) for o in operands])
+            return real(state, *operands)
+
+        monkeypatch.setattr(W, name, step)
+
+    spy("_evict_step")
+    spy("_restore_step")
     events = batch("b", k_evict, base + 1)  # evicts k_evict slots at once
     assert dw.eviction_count == k_evict
     assert all(int(e.match_type) == 0 and not e.seen_ip for e in events)
-    # slots: 300 -> 512 (evicted, restored-seen); restored keys: 0 -> 256
-    assert seen == [[512, 512, 256, 256, 256, 256]]
+    # slots: 300 -> 512 evicted; nothing came back, so no restore step
+    assert seen == [["_evict_step", [512]]]
     assert dw.maintenance_steps == 1
-    assert dw.maintenance_elems == 2 * 512 + 4 * 256
+    assert dw.maintenance_elems == 2 * 512
+    # the evicted addresses come back: their keys go in one fixed chunk
+    # whatever their number (one program), after the evictions they force
+    del seen[:]
+    batch("a", 40, base + 2)
+    assert seen == [["_evict_step", [256]],
+                    ["_restore_step", [5, W._RESTORE_CHUNK]]]
     valid, _ = _device_view(dw)
     assert valid.sum() == cap               # one live key a slot, no more
 

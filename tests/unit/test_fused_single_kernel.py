@@ -159,10 +159,15 @@ def test_threshold_fire_edges(offsets, hits):
 # ---------------------------------------------------------------------------
 
 
-def test_event_overflow_flag_routes_to_classic_fallback():
-    """More window events than max_events: the kernel's gate drops every
-    state write (the donated state passes through untouched) and the
-    chunk replays classically — output identical to the CPU oracle."""
+def test_event_overflow_flag_routes_to_classic_fallback(monkeypatch):
+    """More window events than the program's event capacity (which follows
+    rows x always-columns up to a cap; the cap is lowered here so that the
+    `.*` rule overflows it): the kernel's gate drops every state write (the
+    donated state passes through untouched) and the chunk replays
+    classically — output identical to the CPU oracle."""
+    from banjax_tpu.matcher import prefilter
+
+    monkeypatch.setattr(prefilter, "_MAX_EVENT_CAPACITY", 64)
     patterns = bench.generate_rules(30, seed=33) + [r".*"]
     now = time.time()
     rests = bench.generate_lines(256, patterns[:-1], seed=3, attack_rate=0.1)
@@ -181,6 +186,7 @@ def test_event_overflow_flag_routes_to_classic_fallback():
     assert [_key(a) for a in want] == [_key(b) for b in got]
     assert cb.bans == tb.bans
     assert tpu._fw_pipeline.sk_fallbacks > 0
+    assert tpu._fw_pipeline.overflow_causes["events"] > 0
 
 
 def test_candidate_overflow_flag_with_tight_slot_capacity():

@@ -99,9 +99,14 @@ def test_candidate_overflow_falls_back_identically():
     assert tpu._fw_pipeline.fallback_batches > 0
 
 
-def test_event_overflow_falls_back_identically():
-    """More window events than max_events: the gate drops every state
-    write, and the classic apply (which splits) replays the batch."""
+def test_event_overflow_falls_back_identically(monkeypatch):
+    """More window events than the program's event capacity (the cap on
+    rows x always-columns is lowered so `.*` overflows it): the gate drops
+    every state write, and the classic apply (which splits) replays the
+    batch."""
+    from banjax_tpu.matcher import prefilter
+
+    monkeypatch.setattr(prefilter, "_MAX_EVENT_CAPACITY", 64)
     patterns = bench.generate_rules(30, seed=33) + [r".*"]
     now = time.time()
     lines = _lines(patterns[:-1], 256, now, attack_rate=0.1)

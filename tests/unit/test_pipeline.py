@@ -149,6 +149,32 @@ class TestAdaptiveBatchSizer:
             s.observe(1024, {"device": 50.0})
         assert s.target() == 1024
 
+    def test_efficiency_guard_acts_only_on_probation(self):
+        """A bucket that proved itself against the one below is not sent
+        back by one slow batch later on: the lower bucket's frozen record
+        is no tripwire, only the budget shrinks a size past probation."""
+        from banjax_tpu.pipeline import sizer as sizer_mod
+
+        s = AdaptiveBatchSizer(250.0, min_batch=64, max_batch=2048,
+                               start_batch=1024)
+        for _ in range(4):
+            s.observe(1024, {"device": 35.0})
+        assert s.target() == 2048
+        # 2048 pays (0.030 ms/line against 0.034) and passes probation
+        for _ in range(sizer_mod._PROBATION + 2):
+            s.observe(2048, {"device": 62.0})
+        assert s.target() == 2048
+        # a collector pass: twice the time, far under the 250 ms budget
+        s.observe(2048, {"device": 124.0})
+        for _ in range(4):
+            s.observe(2048, {"device": 62.0})
+        assert s.target() == 2048
+        # the budget still rules
+        for _ in range(6):
+            if s.target() == 2048:
+                s.observe(2048, {"device": 400.0})
+        assert s.target() == 1024
+
     def test_efficiency_guard_allows_growth_when_upper_is_better(self):
         s = AdaptiveBatchSizer(500.0, min_batch=64, max_batch=8192,
                                start_batch=1024)

@@ -101,10 +101,39 @@ def test_fused_stage2_compiles(one_chip, prefilter):
 def test_window_scan_compiles(one_chip):
     from banjax_tpu.matcher.kernels import fused_match_window as fmw
 
-    for events in (4096, 10000):  # max_events at 1k and at 10k rules
+    # the event capacity follows rows x always-columns + pairs: 128-1,024
+    # with 1,000 sparse rules (rows 128-4,096), 10,000 at a 10k-rule scale
+    for events in (128, 256, 512, 1024, 4096, 10000):
         ep, tile = fmw._scan_tiling(events)
         call = fmw._scan_call(ep, tile, False)
         _compile(call, one_chip, *[((ep,), jnp.int32)] * 11)
+
+
+def test_default_rules_programs_compile(one_chip):
+    """The shipped default rules (two always-columns, one filtered rule):
+    stage 1, stage 2 and the window scan at every row bucket's sizes."""
+    from banjax_tpu.matcher.kernels import fused_match_window as fmw
+    from banjax_tpu.matcher.prefilter import FusedPrefilter, build_plan
+    from banjax_tpu.matcher.rulec import compile_rules
+
+    pats = ["^GET", "^POST", ".*challengeme.*"]
+    comp = compile_rules(pats, n_shards=1)
+    pf = FusedPrefilter(
+        build_plan(pats, byte_classes=(comp.byte_to_class, comp.n_classes)),
+        "pallas",
+    )
+    assert pf.plan.n_always == 2 and pf._n_filt == 1
+    for rows in (128, 512, 4096):
+        block, k = pf.capacities(rows)
+        _compile(pf._stage1_raw(rows, L_P, block), one_chip,
+                 ((L_P, rows), jnp.int32), ((rows,), jnp.int32))
+        _compile(pf._stage2(k, L_P, min(block, k)), one_chip,
+                 ((L_P, k), jnp.int32), ((k,), jnp.int32))
+        events = pf.event_capacity(rows, pf.pair_capacity(rows, k))
+        assert events == 2 * rows + min(k, max(128, rows // 4))
+        ep, tile = fmw._scan_tiling(events)
+        _compile(fmw._scan_call(ep, tile, False), one_chip,
+                 *[((ep,), jnp.int32)] * 11)
 
 
 def test_pow_sha256_compiles(one_chip):
