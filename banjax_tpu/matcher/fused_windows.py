@@ -417,7 +417,7 @@ class FusedWindowsPipeline:
         cls_ids = np.asarray(cls_ids, dtype=np.int32)
         lens = np.asarray(lens, dtype=np.int32)
         B = cls_ids.shape[0]
-        combined, Bp, L_p = pf._assemble(cls_ids, lens)
+        combined, Bp, L_p = pf._assemble(cls_ids, lens, self._match_fns)
 
         def pad(a, fill=0):
             a = np.asarray(a)

@@ -572,10 +572,11 @@ class FusedPrefilter:
             Bp <<= 1
         return Bp
 
-    def _assemble(self, cls_ids: np.ndarray, lens: np.ndarray):
+    def _assemble(self, cls_ids: np.ndarray, lens: np.ndarray, built=()):
         """→ (combined [Bp, 1 + L4|L_p] int32, Bp, L_p): the one-transfer
         input layout of _match_core (col 0 = lens; class ids packed 4 per
-        int32 when the partition fits uint8)."""
+        int32 when the partition fits uint8).  `built`: the (Bp, L_p) keys
+        the caller already holds a program for."""
         B = cls_ids.shape[0]
         Bp = self._row_bucket(max(1, B))
         block = self._block_for(Bp)
@@ -589,6 +590,17 @@ class FusedPrefilter:
             -(-cls_ids.shape[1] // cols) * cols,
             -(-max(1, max_len) // max(32, cols)) * max(32, cols),
         ))
+        if (Bp, L_p) not in built:
+            # a first use is seconds of Mosaic in the hot path, and a
+            # partial batch of a few short lines meets line-length
+            # classes no full batch ever does: the narrowest program
+            # already built for this row bucket holds the batch as well
+            # (columns past a line's length never count), so build only
+            # when none does
+            L_p = min(
+                (lp for bp, lp in built if bp == Bp and lp > L_p),
+                default=L_p,
+            )
         Lc = min(cls_ids.shape[1], L_p)
         if self._pack_input:
             L4 = -(-L_p // 4)
@@ -796,7 +808,7 @@ class FusedPrefilter:
         cls_ids = np.asarray(cls_ids, dtype=np.int32)
         lens = np.asarray(lens, dtype=np.int32)
         B = cls_ids.shape[0]
-        combined, Bp, L_p = self._assemble(cls_ids, lens)
+        combined, Bp, L_p = self._assemble(cls_ids, lens, self._fns)
         fn, K, P = self._fused(Bp, L_p)
         buf = fn(jnp.asarray(combined))
         try:

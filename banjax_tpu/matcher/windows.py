@@ -1020,6 +1020,16 @@ class DeviceWindows:
         return int(self._warm.dropped) if self._warm is not None else 0
 
     @property
+    def warm_probes(self) -> int:
+        # the C table's own counts; the Python fallback has no records
+        # apart from its dict and reports 0 for both
+        return int(getattr(self._warm, "probes", 0))
+
+    @property
+    def warm_record_reads(self) -> int:
+        return int(getattr(self._warm, "record_reads", 0))
+
+    @property
     def sketch_admission_fp_rate(self) -> float:
         """Of sketch-admitted slots whose tenure ENDED (evicted), the
         fraction that never matched any rule — the realized cost of

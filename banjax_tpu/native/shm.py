@@ -120,10 +120,10 @@ def _load() -> Optional[ctypes.CDLL]:
         lib.wt_init.argtypes = [vp, ctypes.c_int64, ctypes.c_int64]
         lib.wt_check.restype = ctypes.c_int64
         lib.wt_check.argtypes = [vp]
-        lib.wt_len.restype = ctypes.c_int64
-        lib.wt_len.argtypes = [vp]
-        lib.wt_dropped.restype = ctypes.c_int64
-        lib.wt_dropped.argtypes = [vp]
+        for fn in (lib.wt_max_rules, lib.wt_len, lib.wt_dropped,
+                   lib.wt_probes, lib.wt_record_reads):
+            fn.restype = ctypes.c_int64
+            fn.argtypes = [vp]
         lib.wt_clear.restype = None
         lib.wt_clear.argtypes = [vp]
         lib.wt_put.restype = ctypes.c_int64
@@ -312,7 +312,9 @@ class ShmFailedChallengeStates:
 # Two implementations with one interface:
 #   ShmWarmTier — the C table appended to shmstate.c (wt_*), backed by a
 #       shared-memory segment; O(1) probe-bounded put/take and a single
-#       batched membership call per admission check.
+#       batched membership call per admission check.  Probes walk a dense
+#       index of one 8-byte tag per position and read a record only where
+#       the tag is the key's, so an absent key costs no record page.
 #   PyWarmTier  — bounded-OrderedDict fallback when no C compiler is
 #       available; same steal-iff-expired / drop-and-count overflow
 #       policy, approximated globally instead of per probe window (it
@@ -326,6 +328,7 @@ class ShmFailedChallengeStates:
 WarmEntries = List[Tuple[int, int, int, int]]
 
 WT_KEY_MAX = 104
+WT_TAG_BYTES = 8
 WT_REC_HEADER_BYTES = 128
 WT_ENTRY_BYTES = 24
 
@@ -361,7 +364,7 @@ class ShmWarmTier:
         self.max_rules = max(1, int(max_rules))
         self.expiry_ns = int(expiry_ns)
         stride = WT_REC_HEADER_BYTES + self.max_rules * WT_ENTRY_BYTES
-        size = HEADER_BYTES + cap * stride
+        size = HEADER_BYTES + cap * (WT_TAG_BYTES + stride)
         if name is None:
             self._shm = shared_memory.SharedMemory(create=True, size=size)
             self.owner = True
@@ -378,8 +381,14 @@ class ShmWarmTier:
             except Exception:  # noqa: BLE001 — tracker internals shifted
                 pass
             self._map_base()
-            if lib.wt_check(self._base_ptr) < 0:
+            cap = int(lib.wt_check(self._base_ptr))
+            if cap < 0:
+                self.close()
                 raise RuntimeError(f"shm segment {name} is not a wt table")
+            # the segment's geometry, not the caller's guess: the scratch
+            # arrays below are what wt_take writes max_rules entries into
+            self.capacity = cap
+            self.max_rules = int(lib.wt_max_rules(self._base_ptr))
         # scratch arrays reused by put/take/get, with their C pointers:
         # with rules that fire on every line each eviction is a put, so
         # the per-call array and pointer construction would be the cost
@@ -481,14 +490,27 @@ class ShmWarmTier:
             out.append(raw.decode("utf-8", "surrogatepass"))
         return out
 
-    def __len__(self) -> int:
+    def _header(self, fn) -> int:
         base = self._base_ptr
-        return int(self._lib.wt_len(base)) if base is not None else 0
+        return int(fn(base)) if base is not None else 0
+
+    def __len__(self) -> int:
+        return self._header(self._lib.wt_len)
 
     @property
     def dropped(self) -> int:
-        base = self._base_ptr
-        return int(self._lib.wt_dropped(base)) if base is not None else 0
+        return self._header(self._lib.wt_dropped)
+
+    @property
+    def probes(self) -> int:
+        """Keys looked up by put/take/peek/contains_batch."""
+        return self._header(self._lib.wt_probes)
+
+    @property
+    def record_reads(self) -> int:
+        """Records whose memory those lookups read (the rest stopped in
+        the tag index)."""
+        return self._header(self._lib.wt_record_reads)
 
     def clear(self) -> None:
         base = self._base_ptr

@@ -310,12 +310,23 @@ int32_t fc_test_slot_owner(void *base, int64_t idx) {
  * order — the hot tier's shadow map is an OrderedDict and a refill
  * round-trip must hand back byte-identical state.
  *
- * Layout: one 128-byte wt_header, then capacity (power of two) records
- * of (128-byte record header + max_rules wt_entry).  Open addressing,
- * linear probe bounded at WT_MAX_PROBE.  Unlike the fc_* table above,
- * take() deletes — key_len -1 marks a tombstone (probes continue past
- * it; key search may still early-stop on a genuine empty because
- * inserts never skip one).
+ * Layout: one 128-byte wt_header, then capacity (power of two) 8-byte
+ * tags, then capacity records of (128-byte record header + max_rules
+ * wt_entry): 128 + 24/rule bytes a record, 24,128 at 1,000 rules.  The
+ * tag of a position says what its record holds: 0 = empty, 1 =
+ * tombstone, anything else = the key's 64-bit hash (0 and 1 moved to 2
+ * and 3).  Open addressing, linear probe bounded at WT_MAX_PROBE, and
+ * every probe walks the TAGS: a record's memory is read only where the
+ * tag equals the key's, and then its key is compared.  A lookup of an
+ * absent key therefore touches a cache line or two of the dense tag
+ * array and no record — at a 24 KB stride each record is a page of its
+ * own, and the first read of a never-written page of the mapping is a
+ * page fault that allocates and zeroes it.  Record pages exist only
+ * where a put wrote one.
+ *
+ * Unlike the fc_* table above, take() deletes — it leaves a tombstone
+ * tag (probes continue past it; key search may still early-stop on a
+ * genuine empty because inserts never skip one).
  *
  * Concurrency: NONE here by design.  The only caller is DeviceWindows,
  * which already serializes every slot/shadow mutation under its own
@@ -325,13 +336,18 @@ int32_t fc_test_slot_owner(void *base, int64_t idx) {
  * is older than the expiry horizon (an offender's record is refreshed
  * every spill, so live attackers are never the stalest-and-expired
  * victim); otherwise the new put is dropped and counted — bounded
- * memory, never silent.
+ * memory, never silent.  Only this path reads stamps, 64 records' worth.
+ *
+ * The header counts keys looked up (probes) and records whose memory a
+ * lookup read (record_reads); their quotient is the share of lookups
+ * that had to leave the tag array.
  */
 
-#define WT_MAGIC 0x626a787774303031LL /* "bjxwt001" */
+#define WT_MAGIC 0x626a787774303032LL /* "bjxwt002" — tag index */
 #define WT_MAX_PROBE 64
 #define WT_KEY_MAX 104
-#define WT_TOMBSTONE (-1)
+#define WT_TAG_EMPTY 0ULL
+#define WT_TAG_TOMBSTONE 1ULL
 
 typedef struct {
     int64_t magic;
@@ -339,11 +355,13 @@ typedef struct {
     int64_t max_rules; /* wt_entry slots per record */
     int64_t count;     /* live records */
     int64_t dropped;   /* puts lost to a full, unexpired probe window */
-    int64_t _pad[11];
+    int64_t probes;    /* keys looked up by put/take/get/contains_batch */
+    int64_t record_reads; /* records whose memory those lookups read */
+    int64_t _pad[9];
 } wt_header; /* 128 bytes */
 
 typedef struct {
-    int32_t key_len; /* 0 = empty, -1 = tombstone */
+    int32_t key_len;
     int32_t n_entries;
     int64_t stamp_ns; /* last-touch; the steal policy's staleness key */
     char key[WT_KEY_MAX];
@@ -361,9 +379,14 @@ static inline int64_t wt_stride(const wt_header *h) {
     return (int64_t)sizeof(wt_rec) + h->max_rules * (int64_t)sizeof(wt_entry);
 }
 
+static inline uint64_t *wt_tags(void *base) {
+    return (uint64_t *)((char *)base + sizeof(wt_header));
+}
+
 static inline wt_rec *wt_at(void *base, int64_t i) {
     wt_header *h = (wt_header *)base;
-    return (wt_rec *)((char *)base + sizeof(wt_header) + i * wt_stride(h));
+    return (wt_rec *)((char *)(wt_tags(base) + h->capacity) +
+                      i * wt_stride(h));
 }
 
 static inline wt_entry *wt_entries(wt_rec *r) {
@@ -379,6 +402,8 @@ int64_t wt_init(void *base, int64_t capacity, int64_t max_rules) {
     h->max_rules = max_rules;
     h->count = 0;
     h->dropped = 0;
+    h->probes = 0;
+    h->record_reads = 0;
     h->magic = WT_MAGIC;
     return 0;
 }
@@ -390,22 +415,30 @@ int64_t wt_check(void *base) {
     return h->capacity;
 }
 
+int64_t wt_max_rules(void *base) { return ((wt_header *)base)->max_rules; }
+
 int64_t wt_len(void *base) { return ((wt_header *)base)->count; }
 
 int64_t wt_dropped(void *base) { return ((wt_header *)base)->dropped; }
 
+int64_t wt_probes(void *base) { return ((wt_header *)base)->probes; }
+
+int64_t wt_record_reads(void *base) {
+    return ((wt_header *)base)->record_reads;
+}
+
 void wt_clear(void *base) {
     wt_header *h = (wt_header *)base;
-    for (int64_t i = 0; i < h->capacity; i++)
-        wt_at(base, i)->key_len = 0;
+    memset(wt_tags(base), 0, (size_t)h->capacity * sizeof(uint64_t));
     h->count = 0;
     h->dropped = 0;
 }
 
-static void wt_fill(wt_rec *r, const char *key, int32_t key_len,
-                    int64_t now_ns, const int32_t *rule_ids,
+static void wt_fill(void *base, int64_t idx, uint64_t tag, const char *key,
+                    int32_t key_len, int64_t now_ns, const int32_t *rule_ids,
                     const int32_t *hits, const int64_t *ss,
                     const int64_t *sns, int64_t n) {
+    wt_rec *r = wt_at(base, idx);
     memcpy(r->key, key, (size_t)key_len);
     r->key_len = key_len;
     r->stamp_ns = now_ns;
@@ -417,6 +450,47 @@ static void wt_fill(wt_rec *r, const char *key, int32_t key_len,
         e[k].start_s = ss[k];
         e[k].start_ns = sns[k];
     }
+    wt_tags(base)[idx] = tag;
+}
+
+static inline uint64_t wt_tag(uint64_t hash) {
+    return hash <= WT_TAG_TOMBSTONE ? hash + 2 : hash;
+}
+
+/* The one probe: walk the tags of key's window.  Returns the position
+ * of key's record, or -1.  *insert_at (when asked for) is the first
+ * tombstone-or-empty position of the window, -1 when all of it is
+ * live. */
+static int64_t wt_find(void *base, const char *key, int32_t key_len,
+                       uint64_t hash, int64_t *insert_at) {
+    wt_header *h = (wt_header *)base;
+    const uint64_t *tags = wt_tags(base);
+    uint64_t mask = (uint64_t)h->capacity - 1;
+    uint64_t tag = wt_tag(hash);
+    int64_t free_at = -1;
+    int64_t found = -1;
+    h->probes++;
+    for (int32_t p = 0; p < WT_MAX_PROBE; p++) {
+        int64_t idx = (int64_t)((hash + p) & mask);
+        uint64_t t = tags[idx];
+        if (t == tag) {
+            wt_rec *r = wt_at(base, idx);
+            h->record_reads++;
+            if (r->key_len == key_len &&
+                memcmp(r->key, key, (size_t)key_len) == 0) {
+                found = idx;
+                break;
+            }
+        } else if (t <= WT_TAG_TOMBSTONE) {
+            if (free_at < 0)
+                free_at = idx;
+            if (t == WT_TAG_EMPTY)
+                break; /* a key never lives past a genuine empty */
+        }
+    }
+    if (insert_at)
+        *insert_at = free_at;
+    return found;
 }
 
 /* Spill one IP's window vector.  Returns 0 (inserted/updated) or -1
@@ -430,117 +504,81 @@ int64_t wt_put(void *base, const char *key, int32_t key_len, int64_t now_ns,
         key_len = WT_KEY_MAX;
     if (n > h->max_rules)
         n = h->max_rules;
-    uint64_t mask = (uint64_t)h->capacity - 1;
-    uint64_t home = fc_hash(key, key_len) & mask;
-
-    int64_t insert_at = -1;  /* first tombstone-or-empty in the window */
-    int64_t stalest_at = -1;
-    int64_t stalest_ns = INT64_MAX;
-    for (int32_t p = 0; p < WT_MAX_PROBE; p++) {
-        int64_t idx = (int64_t)((home + p) & mask);
-        wt_rec *r = wt_at(base, idx);
-        if (r->key_len == 0) {
-            if (insert_at < 0)
-                insert_at = idx;
-            break; /* a key never lives past a genuine empty */
-        }
-        if (r->key_len == WT_TOMBSTONE) {
-            if (insert_at < 0)
-                insert_at = idx;
-            continue;
-        }
-        if (r->key_len == key_len &&
-            memcmp(r->key, key, (size_t)key_len) == 0) {
-            wt_fill(r, key, key_len, now_ns, rule_ids, hits, ss, sns, n);
-            return 0;
-        }
-        if (r->stamp_ns < stalest_ns) {
-            stalest_ns = r->stamp_ns;
-            stalest_at = idx;
-        }
-    }
-    if (insert_at >= 0) {
-        wt_fill(wt_at(base, insert_at), key, key_len, now_ns, rule_ids,
-                hits, ss, sns, n);
+    uint64_t hash = fc_hash(key, key_len);
+    int64_t insert_at;
+    int64_t at = wt_find(base, key, key_len, hash, &insert_at);
+    if (at < 0 && insert_at >= 0) {
+        at = insert_at;
         h->count++;
-        return 0;
     }
-    if (stalest_at >= 0 && now_ns - stalest_ns > expiry_ns) {
+    if (at < 0) {
+        /* all WT_MAX_PROBE positions hold other keys: find the stalest,
+         * the first of the window among equals */
+        uint64_t mask = (uint64_t)h->capacity - 1;
+        int64_t stalest_ns = INT64_MAX;
+        for (int32_t p = 0; p < WT_MAX_PROBE; p++) {
+            int64_t idx = (int64_t)((hash + p) & mask);
+            int64_t stamp = wt_at(base, idx)->stamp_ns;
+            if (stamp < stalest_ns) {
+                stalest_ns = stamp;
+                at = idx;
+            }
+        }
+        h->record_reads += WT_MAX_PROBE;
+        h->dropped++;
         /* steal: the victim's windows all expired, so losing its state
          * is semantically a restart-as-first-seen, like fc_apply */
-        wt_fill(wt_at(base, stalest_at), key, key_len, now_ns, rule_ids,
-                hits, ss, sns, n);
-        h->dropped++;
-        return 0;
+        if (at < 0 || now_ns - stalest_ns <= expiry_ns)
+            return -1;
     }
-    h->dropped++;
-    return -1;
+    wt_fill(base, at, wt_tag(hash), key, key_len, now_ns, rule_ids, hits, ss,
+            sns, n);
+    return 0;
 }
 
-/* Move semantics for refill: copy the record's entries out and delete
- * it.  Returns the entry count, or -1 when the key is absent. */
+static int64_t wt_copy_out(wt_rec *r, int32_t *rule_ids_out,
+                           int32_t *hits_out, int64_t *ss_out,
+                           int64_t *sns_out) {
+    int64_t n = r->n_entries;
+    wt_entry *e = wt_entries(r);
+    for (int64_t k = 0; k < n; k++) {
+        rule_ids_out[k] = e[k].rule_id;
+        hits_out[k] = e[k].hits;
+        ss_out[k] = e[k].start_s;
+        sns_out[k] = e[k].start_ns;
+    }
+    return n;
+}
+
+/* Non-deleting read (introspection: DeviceWindows.get / format_states
+ * must see warm-spilled state): copy the record's entries out.  Returns
+ * the entry count, or -1 when the key is absent. */
+int64_t wt_get(void *base, const char *key, int32_t key_len,
+               int32_t *rule_ids_out, int32_t *hits_out, int64_t *ss_out,
+               int64_t *sns_out) {
+    if (key_len > WT_KEY_MAX)
+        key_len = WT_KEY_MAX;
+    int64_t at = wt_find(base, key, key_len, fc_hash(key, key_len), NULL);
+    if (at < 0)
+        return -1;
+    return wt_copy_out(wt_at(base, at), rule_ids_out, hits_out, ss_out,
+                       sns_out);
+}
+
+/* Move semantics for refill: wt_get, then the record is deleted. */
 int64_t wt_take(void *base, const char *key, int32_t key_len,
                 int32_t *rule_ids_out, int32_t *hits_out, int64_t *ss_out,
                 int64_t *sns_out) {
     wt_header *h = (wt_header *)base;
     if (key_len > WT_KEY_MAX)
         key_len = WT_KEY_MAX;
-    uint64_t mask = (uint64_t)h->capacity - 1;
-    uint64_t home = fc_hash(key, key_len) & mask;
-    for (int32_t p = 0; p < WT_MAX_PROBE; p++) {
-        wt_rec *r = wt_at(base, (int64_t)((home + p) & mask));
-        if (r->key_len == 0)
-            return -1;
-        if (r->key_len == WT_TOMBSTONE)
-            continue;
-        if (r->key_len == key_len &&
-            memcmp(r->key, key, (size_t)key_len) == 0) {
-            int64_t n = r->n_entries;
-            wt_entry *e = wt_entries(r);
-            for (int64_t k = 0; k < n; k++) {
-                rule_ids_out[k] = e[k].rule_id;
-                hits_out[k] = e[k].hits;
-                ss_out[k] = e[k].start_s;
-                sns_out[k] = e[k].start_ns;
-            }
-            r->key_len = WT_TOMBSTONE;
-            h->count--;
-            return n;
-        }
-    }
-    return -1;
-}
-
-/* Non-deleting read (introspection: DeviceWindows.get / format_states
- * must see warm-spilled state).  Same contract as wt_take otherwise. */
-int64_t wt_get(void *base, const char *key, int32_t key_len,
-               int32_t *rule_ids_out, int32_t *hits_out, int64_t *ss_out,
-               int64_t *sns_out) {
-    wt_header *h = (wt_header *)base;
-    if (key_len > WT_KEY_MAX)
-        key_len = WT_KEY_MAX;
-    uint64_t mask = (uint64_t)h->capacity - 1;
-    uint64_t home = fc_hash(key, key_len) & mask;
-    for (int32_t p = 0; p < WT_MAX_PROBE; p++) {
-        wt_rec *r = wt_at(base, (int64_t)((home + p) & mask));
-        if (r->key_len == 0)
-            return -1;
-        if (r->key_len == WT_TOMBSTONE)
-            continue;
-        if (r->key_len == key_len &&
-            memcmp(r->key, key, (size_t)key_len) == 0) {
-            int64_t n = r->n_entries;
-            wt_entry *e = wt_entries(r);
-            for (int64_t k = 0; k < n; k++) {
-                rule_ids_out[k] = e[k].rule_id;
-                hits_out[k] = e[k].hits;
-                ss_out[k] = e[k].start_s;
-                sns_out[k] = e[k].start_ns;
-            }
-            return n;
-        }
-    }
-    return -1;
+    int64_t at = wt_find(base, key, key_len, fc_hash(key, key_len), NULL);
+    if (at < 0)
+        return -1;
+    wt_tags(base)[at] = WT_TAG_TOMBSTONE;
+    h->count--;
+    return wt_copy_out(wt_at(base, at), rule_ids_out, hits_out, ss_out,
+                       sns_out);
 }
 
 /* Copy live keys out (table order) for introspection.  keys_blob must
@@ -548,11 +586,12 @@ int64_t wt_get(void *base, const char *key, int32_t key_len,
 int64_t wt_snapshot_keys(void *base, char *keys_blob, int32_t *key_lens,
                          int64_t max_entries) {
     wt_header *h = (wt_header *)base;
+    const uint64_t *tags = wt_tags(base);
     int64_t n = 0;
     for (int64_t i = 0; i < h->capacity && n < max_entries; i++) {
-        wt_rec *r = wt_at(base, i);
-        if (r->key_len <= 0)
+        if (tags[i] <= WT_TAG_TOMBSTONE)
             continue;
+        wt_rec *r = wt_at(base, i);
         memcpy(keys_blob + n * WT_KEY_MAX, r->key, (size_t)r->key_len);
         key_lens[n] = r->key_len;
         n++;
@@ -566,28 +605,14 @@ int64_t wt_snapshot_keys(void *base, char *keys_blob, int32_t *key_lens,
 int64_t wt_contains_batch(void *base, const uint8_t *blob,
                           const int64_t *offs, const int64_t *lens,
                           int64_t n, uint8_t *out) {
-    wt_header *h = (wt_header *)base;
-    uint64_t mask = (uint64_t)h->capacity - 1;
     int64_t found = 0;
     for (int64_t i = 0; i < n; i++) {
         const char *key = (const char *)blob + offs[i];
         int32_t key_len = (int32_t)lens[i];
         if (key_len > WT_KEY_MAX)
             key_len = WT_KEY_MAX;
-        uint64_t home = fc_hash(key, key_len) & mask;
-        uint8_t hit = 0;
-        for (int32_t p = 0; p < WT_MAX_PROBE; p++) {
-            wt_rec *r = wt_at(base, (int64_t)((home + p) & mask));
-            if (r->key_len == 0)
-                break;
-            if (r->key_len == WT_TOMBSTONE)
-                continue;
-            if (r->key_len == key_len &&
-                memcmp(r->key, key, (size_t)key_len) == 0) {
-                hit = 1;
-                break;
-            }
-        }
+        uint8_t hit =
+            wt_find(base, key, key_len, fc_hash(key, key_len), NULL) >= 0;
         out[i] = hit;
         found += hit;
     }

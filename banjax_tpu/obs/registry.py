@@ -175,6 +175,14 @@ FAMILIES: List[Family] = [
            line_key="WarmTierOccupancy", prom="banjax_warm_tier_occupancy"),
     Family(GAUGE, "warm-tier entry capacity",
            line_key="WarmTierCapacity", prom="banjax_warm_tier_capacity"),
+    Family(COUNTER, "keys looked up in the warm tier (put, take, peek and "
+           "each key of a batched membership check)",
+           line_key="WarmTierProbes", prom="banjax_warm_tier_probes_total"),
+    Family(COUNTER, "warm-tier records whose memory a lookup read; the "
+           "rest stopped in the tag index (divide by probes: near refills "
+           "over misses while absent keys touch no record)",
+           line_key="WarmTierRecordReads",
+           prom="banjax_warm_tier_record_reads_total"),
     # ---- mesh ----
     Family(COUNTER, "sharded-mesh batches served by the fused two-stage path",
            line_key="MeshFusedBatches", prom="banjax_mesh_fused_batches_total"),

@@ -149,6 +149,8 @@ class MatcherStats:
                 out["WarmTierDropped"] = device_windows.warm_dropped
                 out["WarmTierOccupancy"] = device_windows.warm_occupancy
                 out["WarmTierCapacity"] = device_windows.warm_capacity
+                out["WarmTierProbes"] = device_windows.warm_probes
+                out["WarmTierRecordReads"] = device_windows.warm_record_reads
         if matcher is not None:
             mm = getattr(matcher, "_mesh_matcher", None)
             if mm is not None:

@@ -556,6 +556,11 @@ def test_mega_state_families_render_and_declare():
         assert scalars["banjax_warm_tier_dropped_total"] == 0
         assert scalars["banjax_warm_tier_occupancy"] > 0
         assert scalars["banjax_warm_tier_capacity"] == 1024
+        # the C table's own counts (the Python fallback reports 0 for both)
+        native = hasattr(dw._warm, "record_reads")
+        assert (scalars["banjax_warm_tier_probes_total"] > 0) == native
+        assert (scalars["banjax_warm_tier_record_reads_total"]
+                <= scalars["banjax_warm_tier_probes_total"])
         out = io.StringIO()
         write_metrics_line(
             out, DynamicDecisionLists(start_sweeper=False),
@@ -565,7 +570,8 @@ def test_mega_state_families_render_and_declare():
         for key in ("SlotRefusals", "SketchAdmissions",
                     "SketchAdmissionFpRate", "WarmTierSpills",
                     "WarmTierRefills", "WarmTierDropped",
-                    "WarmTierOccupancy", "WarmTierCapacity"):
+                    "WarmTierOccupancy", "WarmTierCapacity",
+                    "WarmTierProbes", "WarmTierRecordReads"):
             assert key in line, key
             assert registry.is_declared_line_key(key), key
         assert line["SlotRefusals"] >= 48

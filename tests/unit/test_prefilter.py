@@ -272,6 +272,44 @@ class TestFusedPrefilter:
         bits = fp.match_bits_encoded(cls_ids, lens)
         np.testing.assert_array_equal(bits, want)
 
+    @pytest.mark.parametrize("backend", ["xla", "pallas-interpret"])
+    def test_short_batch_rides_the_wider_program_already_built(self, backend):
+        """A partial batch of short lines meets a line-length class no full
+        batch does; a first use is a build in the hot path, so it runs on
+        the narrowest wider program of its row bucket — same bits — and a
+        new program is built only where none holds the batch."""
+        from banjax_tpu.matcher.prefilter import FusedPrefilter
+
+        import bench
+
+        patterns = bench.generate_rules(40, seed=21)
+        lines = bench.generate_lines(200, patterns, seed=22, attack_rate=0.3)
+        compiled, plan = self._plan(patterns)
+        assert plan is not None
+        fp = FusedPrefilter(plan, backend, cand_frac=1.0, pair_frac=1.0)
+        short = [ln[:20] for ln in lines[:50]]
+        cls_s, lens_s, _, want_s = self._oracle(compiled, plan, short)
+        np.testing.assert_array_equal(fp.match_bits_encoded(cls_s, lens_s),
+                                      want_s)
+        (key_short,) = fp._fns  # nothing wider existed: built as asked
+        cls_l, lens_l, _, want_l = self._oracle(compiled, plan, lines[:50])
+        np.testing.assert_array_equal(fp.match_bits_encoded(cls_l, lens_l),
+                                      want_l)
+        key_long = max(fp._fns, key=lambda k: k[1])
+        assert key_long[0] == key_short[0] and key_long[1] > key_short[1]
+        assert len(fp._fns) == 2
+        mid = [ln[:50] for ln in lines[50:100]]  # a class between the two
+        cls_m, lens_m, _, want_m = self._oracle(compiled, plan, mid)
+        assert key_short[1] < -(-int(lens_m.max()) // 32) * 32 < key_long[1]
+        np.testing.assert_array_equal(fp.match_bits_encoded(cls_m, lens_m),
+                                      want_m)
+        assert len(fp._fns) == 2  # rode key_long
+        _, Bp, L_p = fp._assemble(cls_m, lens_m, fp._fns)
+        assert (Bp, L_p) == key_long
+        np.testing.assert_array_equal(fp.match_bits_encoded(cls_s, lens_s),
+                                      want_s)
+        assert len(fp._fns) == 2  # an exact fit is still taken first
+
     def test_unpacked_input_path_parity(self):
         """The plain-int32 input layout (used when a byte partition doesn't
         fit uint8) must match the packed default bit-for-bit."""
