@@ -30,9 +30,7 @@ state was evicted AND spill-dropped AND who then returns in-window —
 every step of which is counted.  Dropped state always *under*-counts
 (the IP restarts fresh, exactly like a new oracle IP), so a drop can
 delay a ban, never conjure one out of a benign client within the
-oracle's window; BENCH_challenge.json banks the 1M-challenger storm row
-at ban precision/recall 1.0 vs the unbounded oracle with entries <=
-challenge_failure_state_max.
+oracle's window.
 
 Evictions under storm pressure notify the flight recorder (debounced in
 the recorder itself), so a forced storm leaves a loadable incident

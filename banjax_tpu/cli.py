@@ -127,8 +127,7 @@ class BanjaxApp:
             failpoints.arm_from_spec(self._failpoints_spec)
 
         # pipeline span tracing (obs/trace.py): off by default — the
-        # disabled tracer's no-op fast path keeps the hot path at ≤1%
-        # overhead (bench.py --trace-overhead); /debug/trace dumps the
+        # disabled tracer is a no-op fast path; /debug/trace dumps the
         # ring as Perfetto-loadable Chrome trace JSON when enabled
         trace.configure(
             enabled=getattr(config, "trace_enabled", False),

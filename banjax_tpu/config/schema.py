@@ -242,12 +242,6 @@ class Config:
     # a wedged device trips the breaker before the next burst; 0 = off
     # (the default — standalone tests run without a probe thread)
     matcher_probe_seconds: float = 0.0
-    # two-phase fused matcher+windows under the pipeline: program A
-    # (stateless match) dispatches ahead on the submit stage, the window
-    # commit (program B) runs at drain in admission order — no dense
-    # bitmap ever crosses the host boundary. false restores the PR 2
-    # classic-bitmap split protocol.
-    pipeline_fused: bool = True
     # route KafkaReader command messages through the pipeline's admission
     # buffer (same bounded-block/oldest-first-shed accounting as tailer
     # lines); only meaningful when pipeline_enabled is true
@@ -266,25 +260,6 @@ class Config:
     # back to the Python dict path when no C compiler is present; false
     # forces the dict path (the differential oracle).
     slotmgr_native: bool = True
-    # resolve-ahead depth for the fused drain commit: 2 dispatches chunk
-    # i+1's window program while chunk i's events decode, overlapping the
-    # fixed device->host pull instead of serializing the drain thread;
-    # 1 restores the serial drain.  A no-op on the single-kernel path
-    # (pallas_single_kernel below), which has no program-B dispatch left
-    # to overlap.
-    drain_resolve_depth: int = 2
-    # single-kernel fused match+window commit (matcher/kernels/
-    # fused_match_window.py): collapse the fused path's two device
-    # programs (A: stateless match, B: window commit) — and the ~65 ms
-    # host-side resolve pull between them — into ONE Pallas-anchored
-    # program whose overflow handling is gated in-kernel.  "auto"
-    # (default) turns it on when the window-scan kernel lowers for the
-    # backend (compiled Mosaic on TPU, interpret-mode on CPU — the CI
-    # path); "on" forces it (warns + falls back two-program if it can't
-    # lower); "off" pins the two-program path (the differential oracle).
-    # Note: on this path the 10 s staleness cutoff is enforced at device
-    # commit (submit) time instead of effector drain time.
-    pallas_single_kernel: str = "auto"
     # take-size bound for command batches in the pipeline's encode stage:
     # commands carry no device timing for the adaptive sizer, so a Kafka
     # command flood is chopped into batches of at most this many messages
@@ -296,8 +271,7 @@ class Config:
     # trace id carried through encode/submit/collect/drain; /debug/trace
     # dumps the ring as Chrome trace_event JSON (Perfetto-loadable).
     # Off by default — the disabled fast path is a single attribute
-    # check per call site (bench.py --trace-overhead banks the measured
-    # on/off delta).
+    # check per call site.
     trace_enabled: bool = False
     # span slots in the ring (oldest overwritten); ~120 bytes/slot
     trace_ring_size: int = 4096
@@ -319,8 +293,7 @@ class Config:
     # (static/ua list hit, fired rate-limit ban, Kafka command,
     # challenge failure, dynamic-list expiry) lands in a per-source
     # ring, queryable via GET /decisions/explain?ip=…  On by default:
-    # records fire only on decision events, not per log line (bench.py
-    # --provenance-overhead banks the measured on/off delta).
+    # records fire only on decision events, not per log line.
     provenance_enabled: bool = True
     provenance_ring_size: int = 2048
     # SLO burn-rate engine (obs/slo.py): multi-window (5 m / 1 h)
@@ -546,9 +519,8 @@ _SCALAR_KEYS = {
     "pipeline_enabled": bool, "pipeline_ring_size": int,
     "pipeline_latency_budget_ms": float, "pipeline_buffer_lines": int,
     "pipeline_max_block_ms": float, "matcher_probe_seconds": float,
-    "pipeline_fused": bool, "pipeline_kafka": bool,
+    "pipeline_kafka": bool,
     "encode_workers": int, "slotmgr_native": bool,
-    "drain_resolve_depth": int, "pallas_single_kernel": str,
     "pipeline_command_take_max": int,
     "trace_enabled": bool, "trace_ring_size": int,
     "trace_jax_annotations": bool, "admin_token": str,
@@ -717,16 +689,6 @@ def config_from_yaml_text(text: str, standalone_testing_default: bool = False) -
         raise ValueError(
             "config key encode_workers: expected -1 (auto), 0 (single-"
             f"thread) or a positive worker count, got {cfg.encode_workers}"
-        )
-    if cfg.drain_resolve_depth < 1:
-        raise ValueError(
-            "config key drain_resolve_depth: expected >= 1, got "
-            f"{cfg.drain_resolve_depth}"
-        )
-    if cfg.pallas_single_kernel not in ("auto", "on", "off"):
-        raise ValueError(
-            "config key pallas_single_kernel: expected auto|on|off, got "
-            f"{cfg.pallas_single_kernel!r}"
         )
     if cfg.pipeline_command_take_max < 1:
         raise ValueError(

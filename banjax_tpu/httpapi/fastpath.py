@@ -11,8 +11,8 @@ full `decision_for_nginx` walk.
 Byte-identity is the contract, not best-effort: a template response must
 equal `serialize_response(decision_for_nginx(...))` bit for bit (status
 line, header order, X-Accel-Redirect, cookies), and the differential
-suite (tests/integration/test_fastpath_differential.py) plus the bench
-witness (`bench.py --serve`) hold it there.  Anything the templates
+suite (tests/integration/test_fastpath_differential.py) holds it
+there.  Anything the templates
 cannot reproduce — password cookies, per-site static lists, sitewide
 sha-inv path exceptions, session-id entries, baskerville-disabled hosts
 — is an ELIGIBILITY miss, and the unchanged chain serves it.

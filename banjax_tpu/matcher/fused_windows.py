@@ -1,5 +1,5 @@
-"""Fused matcher + device-windows pipeline, split into two device programs
-so chunks can OVERLAP without ever reordering window updates.
+"""Fused matcher + device-windows pipeline: ONE device program per chunk
+does the match and the window commit, dispatched at submit.
 
 Why fused at all: with device windows on, the naive path round-trips the
 match bitmap through the host — the matcher pulls its sparse result down
@@ -7,56 +7,39 @@ match bitmap through the host — the matcher pulls its sparse result down
 [B, n_rules] bitmap, and apply_bitmap pushes those ~16 MB back up for the
 window scan. Here the dense caller-order bitmap never exists on the host.
 
-Why two programs (PERF.md "path to 5M" 3c): a single fused program forces
-strict chunk serialization — if chunk N overflows (its state writes gated
-off), its classic re-apply would land on the device stream AFTER an
-already-submitted chunk N+1, reordering window updates. Splitting fixes it:
+The program (kernels/fused_match_window.py): two-stage match
+(prefilter._match_core), dense caller-order bitmap assembly, the per-row
+live mask (the caller's staleness drop, an INPUT to submit), every
+overflow flag — candidate count, match-pair count, window-event count —
+and the window segmented scan (windows._apply_core, state donated) whose
+commit is gated IN the program on those flags and on a device-side chain
+scalar.  Output: one host buffer (flags ‖ (row, rule) match pairs ‖
+always-rule bits ‖ the fired-event records), pulled asynchronously, and
+the device-resident bitmap for the overflow replay.
 
-  program A — MATCH (stateless): two-stage match (prefilter._match_core),
-    dense caller-order bitmap assembly, and ALL overflow flags — candidate
-    count, match-pair count, and the window-event count (it takes
-    host_idx + active_table precisely so the event count is known before
-    any state is touched). Outputs: one sparse host buffer (flags ‖
-    (row, rule) match pairs ‖ always-rule bits) and the device-resident
-    bitmap. A dispatches freely, any number of chunks ahead.
+Order: submits are serialized under the windows lock, so device apply
+order == sequence order == log order.  A chunk that overflows commits
+nothing and its chain scalar poisons every already-dispatched successor
+(they commit nothing either): the caller replays each classically, in
+order (runner._pipeline_fallback_entry), and the chain reseeds once no
+chunk is outstanding.
 
-  program B — APPLY (window state donated): the window segmented scan
-    (windows._apply_core) over A's bitmap. B for chunk i is dispatched
-    only after chunk i's A-flags are known ok AND every earlier chunk's
-    apply (B or classic fallback) has completed its dispatch — so
-    device-stream order equals log order, always. Overflowing chunks never
-    dispatch B: the caller drains all earlier chunks, then replays through
-    the classic splitting path (state untouched, output identical).
-
-Both pulls (A's sparse buffer, B's event buffer) are async and overlap
-later chunks' compute, hiding the fixed d2h latency.
-
-Ordering machinery: submit() assigns a sequence number; resolve() and
-collect() each gate on it (resolve order = B dispatch order = device apply
-order; collect order = host-shadow write order). The shadow must absorb
-batches in device-apply order or an eviction could restore stale counters.
+Settlement: submit() assigns a sequence number and resolve() gates on it
+— chunk i's buffer is read only after every earlier chunk is settled
+(collected, replayed classically, or abandoned).  One gate carries both
+orders that matter: an overflowing chunk's classic replay lands on the
+device after every earlier chunk's, and the host shadow absorbs chunks in
+device-apply order (or an eviction could restore stale counters).
 
 Event order parity: bits are scattered into CALLER row order before the
 window apply, so the event compaction's row-major (line, rule) order — the
 reference's per-site-then-global processing order — is preserved exactly
 as in the classic path.
-
-Single-kernel mode (`pallas_single_kernel`, kernels/fused_match_window.py)
-collapses A+B into ONE program dispatched at submit: the window commit is
-gated IN-KERNEL on the overflow flags and on a device-side chain scalar
-(an overflow poisons every already-dispatched successor, which then
-replays classically in order), so the host decision between the programs
-— and with it the ~65 ms resolve pull — disappears.  submit() returns an
-already-final chunk; resolve() is a pure pull of the one combined buffer;
-staleness/abandon compose as a per-row live-mask INPUT to submit.  The
-two-program protocol below stays intact as the differential oracle and
-the fallback when the Pallas window-scan kernel can't lower.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import functools
 import logging
 import threading
 from typing import Optional
@@ -65,29 +48,24 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from banjax_tpu.matcher import windows as W
 from banjax_tpu.obs import trace
 from banjax_tpu.matcher.prefilter import FusedPrefilter
 from banjax_tpu.matcher.windows import DeviceWindows, EventBatch
 
 log = logging.getLogger(__name__)
 
-_SHIFTS = (0, 8, 16, 24)
-
 
 @dataclasses.dataclass
 class _Pend:
     """One chunk in flight. States: submitted → resolved → done, or
-    submitted → overflow → (caller fallback) → done."""
+    submitted → overflow → (caller fallback) → done; `failed` when it
+    died on the way."""
 
     seq: int
-    sparse_buf: object     # program A's buffer (async pull in flight);
-    #                        single-kernel mode: THE one combined buffer
-    bits_dev: object       # [Bp, n_rules] uint8 device-resident
+    sparse_buf: object     # THE one combined buffer (async pull in flight)
+    bits_dev: object       # [Bp, n_rules] uint8 device-resident: the
+    #                        overflow's classic replay takes its bitmap here
     slots: np.ndarray      # caller-order, pins held
-    ts_s: np.ndarray       # padded to Bp
-    ts_ns: np.ndarray      # padded to Bp
-    host_idx: np.ndarray   # padded to Bp
     B: int                 # real rows
     Bp: int
     K: int
@@ -95,10 +73,9 @@ class _Pend:
     E: int                 # window-event capacity of the chunk's program
     state: str = "submitted"
     flags: Optional[np.ndarray] = None     # [4] after resolve
-    events_buf: object = None              # program B's buffer, or (single-
-    #                                        kernel) the decoded host buffer
+    events_buf: object = None              # the decoded host buffer
     events_off: int = 0                    # event-record offset into it
-    # decoded at resolve (from the A pull)
+    # decoded at resolve
     matched_pairs: Optional[np.ndarray] = None
     always_bits: Optional[np.ndarray] = None
     # transfer accounting (obs/stats.py note_xfer): what this chunk moved
@@ -106,15 +83,13 @@ class _Pend:
     # the dense [B, n_rules] bitmap from h2d_bytes
     h2d_bytes: int = 0
     d2h_bytes: int = 0
-    # state-aware settlement: each order turn and the slot pins are
+    # state-aware settlement: the order turn and the slot pins are
     # released EXACTLY once no matter which combination of resolve/
     # collect/fallback_done/abandon settles the chunk (a submit-failure
     # abandon racing a teardown abort used to mark a turn dead twice,
-    # which could advance a counter past a live chunk's turn)
+    # which could advance the counter past a live chunk's turn)
     pins_released: bool = False
-    turns_freed: dict = dataclasses.field(
-        default_factory=lambda: {"_resolve_seq": False, "_collect_seq": False}
-    )
+    turn_freed: bool = False
 
 
 @dataclasses.dataclass
@@ -142,16 +117,17 @@ class PipelineOverflow(RuntimeError):
 
 class FusedWindowsPipeline:
     """Built by TpuMatcher when the fused prefilter and device windows are
-    both active and every rule is device-decidable.
+    both active, every rule is device-decidable and the window-scan
+    kernel passed its selftest.
 
-    Contract: submit in chunk order; resolve and collect each in that same
-    order (they gate on it). Pins are owned by the pipeline from submit()
-    until collect() completes — except after PipelineOverflow, where the
-    caller's fallback apply (which releases them) takes over, followed by
-    fallback_done() to release the order turns."""
+    Contract: submit in chunk order; resolve and collect in that same
+    order (resolve gates on it). Pins are owned by the pipeline from
+    submit() until collect() completes — except after PipelineOverflow,
+    where the caller's fallback apply (which releases them) takes over,
+    followed by fallback_done() to release the order turn."""
 
     def __init__(self, prefilter: FusedPrefilter, windows: DeviceWindows,
-                 active_table, n_rules: int, single_kernel: bool = False,
+                 active_table, n_rules: int,
                  scan_interpret: bool = True, traffic_sketch=None):
         self.pf = prefilter
         self.windows = windows
@@ -162,24 +138,15 @@ class FusedWindowsPipeline:
         # sketches as one more stateless array op — telemetry only, no
         # interaction with window state or results
         self._traffic_sketch = traffic_sketch
-        self._match_fns = {}
-        self._apply_fns = {}
-        # single-kernel mode (kernels/fused_match_window.py): submit
-        # dispatches ONE program doing match + window commit (state
-        # donated, overflow/chain gated in-kernel) and the chunk is final
-        # on return; resolve/collect become pure decodes of the one
-        # async-pulled buffer.  False = the two-program A/B protocol,
-        # which stays intact as the differential oracle and the fallback
-        # when the Pallas window-scan kernel can't lower.
-        self.single_kernel = bool(single_kernel)
+        self._progs = {}            # (Bp, L_p) → build_single_program's
         self._scan_interpret = bool(scan_interpret)
-        # device-side ok chain: each kernel's commit gates on its
+        # device-side ok chain: each program's commit gates on its
         # predecessor's ok scalar, so an overflow poisons every already-
         # dispatched successor WITHOUT a host round-trip; None = seed the
         # next submit with a fresh ok (no poisoned chunk outstanding)
         self._chain_ok = None
-        self.sk_chunks = 0          # single-kernel chunks committed
-        self.sk_fallbacks = 0       # routed to the classic fallback
+        self.fused_batches = 0      # chunks committed by the fused program
+        self.fallback_batches = 0   # routed to the classic fallback
         self.sk_d2h_bytes_total = 0  # the one-pull d2h witness
         # fused dispatches that committed nothing, by what overflowed:
         # the chunk's own candidates / (row, rule) pairs / window events,
@@ -197,98 +164,25 @@ class FusedWindowsPipeline:
         self._ae = jnp.asarray(
             np.asarray(plan.stage1.empty_only[:na], dtype=np.uint8)
         )
-        self.fused_batches = 0
-        self.fallback_batches = 0
         self._cv = threading.Condition()
         self._next_seq = 0      # assigned at submit
-        self._resolve_seq = 0   # B-dispatch order
-        self._collect_seq = 0   # shadow-write order
-        # turns of chunks that died before taking them (resolve failure,
-        # abandon): swept lazily when the counter reaches them — advancing
-        # out of turn would steal an earlier live chunk's turn
-        self._dead = {"_resolve_seq": set(), "_collect_seq": set()}
+        self._turn = 0          # the chunk whose settlement comes next
+        # turns of chunks that died before taking them (abandon): swept
+        # lazily when the counter reaches them — advancing out of turn
+        # would steal an earlier live chunk's turn
+        self._dead = set()
 
-    # ---- program A: stateless match + flags ----
+    # the SingleKernel* metric families read the same two counts
+    sk_chunks = property(lambda self: self.fused_batches)
+    sk_fallbacks = property(lambda self: self.fallback_batches)
 
-    def _match_prog(self, Bp: int, L_p: int):
-        key = (Bp, L_p)
-        hit = self._match_fns.get(key)
-        if hit is not None:
-            return hit
-        pf = self.pf
-        plan = pf.plan
-        block, K, P, E = pf.program_capacities(Bp)
-        core = pf._match_core(Bp, L_p, K, block)
-        n_rules, n_filt = self.n_rules, pf._n_filt
-        n_always = plan.n_always
-        f_idx, a_idx = self._f_idx, self._a_idx
-        aw, ae = self._aw, self._ae
-        active_table = self.active_table
-        shifts = jnp.asarray(_SHIFTS, dtype=jnp.int32)
-
-        @jax.jit
-        def match(combined, n_real, host_idx):
-            c = core(combined)
-            # sparse (row, rule) pair output — the shared encoding
-            # (prefilter.pairs_from_core): one int32 per set stage-2 bit
-            # instead of a packed row bitmap per matched line (~30x less
-            # d2h volume). pair_bits doubles as the dense
-            # per-candidate form for the bitmap assembly below.
-            pairs, n_pairs, pair_bits = pf.pairs_from_core(c, K, P)
-            # dense caller-order bitmap, assembled on device
-            bits = jnp.zeros((Bp, n_rules), dtype=jnp.uint8)
-            if n_filt:
-                m2 = pair_bits[:, :n_filt].astype(jnp.uint8)     # [K, n_filt]
-                filt = jnp.zeros((Bp + 1, n_filt), dtype=jnp.uint8)
-                filt = filt.at[c["idx_caller_k"]].set(m2)[:Bp]   # row Bp = dump
-                bits = bits.at[:, f_idx].set(filt)
-            ab = None
-            if n_always:
-                ab = c["ab_caller"] | aw[None, :]
-                empty = (c["lens_raw"] == 0).astype(jnp.uint8)[:, None]
-                ab = ab | (ae[None, :] * empty)
-                bits = bits.at[:, a_idx].set(ab)
-            # padding rows (row >= n_real) can still carry bits — e.g. an
-            # always_match rule's column is all-ones — and MUST NOT reach
-            # the window apply: their pad slot id belongs to a real IP
-            real = jax.lax.iota(jnp.int32, Bp) < n_real
-            bits = bits * real[:, None].astype(jnp.uint8)
-            # the window-event count, computed HERE so every overflow
-            # condition is known before any state is touched
-            fire = (bits != 0) & active_table[host_idx]
-            n_events = fire.sum(dtype=jnp.int32)
-            ok = (
-                (c["n_cand"] <= K) & (n_pairs <= P)
-                & (n_events <= E)
-            )
-            flags = jnp.stack([
-                ok.astype(jnp.int32), c["n_cand"], n_pairs, n_events,
-            ])
-            parts = [
-                ((flags[:, None] >> shifts[None, :]) & 0xFF)
-                .astype(jnp.uint8).reshape(-1),
-                ((pairs[:, None] >> shifts[None, :]) & 0xFF)
-                .astype(jnp.uint8).reshape(-1),
-            ]
-            if n_always:
-                # sparse rows cover only the filterable rules; replay
-                # bookkeeping needs the completed always-rule bits too
-                parts.append(
-                    jnp.packbits(ab.astype(jnp.bool_), axis=1).reshape(-1)
-                )
-            return jnp.concatenate(parts), bits
-
-        self._match_fns[key] = (match, K, P, E)
-        return match, K, P, E
-
-    # ---- single-kernel program: match + window commit in ONE dispatch ----
+    # ---- the program: match + window commit in ONE dispatch ----
 
     def _single_prog(self, Bp: int, L_p: int):
-        """The fused match+window program (single-kernel mode), cached in
-        the same per-(Bp, L_p) table as the two-program match — the modes
-        are exclusive per pipeline, so the cache never mixes kinds."""
+        """The fused match+window program for one (rows, line length)
+        bucket, built on first use."""
         key = (Bp, L_p)
-        hit = self._match_fns.get(key)
+        hit = self._progs.get(key)
         if hit is not None:
             return hit
         from banjax_tpu.matcher.kernels import fused_match_window as fmw
@@ -299,30 +193,52 @@ class FusedWindowsPipeline:
             aw=self._aw, ae=self._ae,
             scan_fn=fmw.window_scan(self._scan_interpret),
         )
-        self._match_fns[key] = hit
+        self._progs[key] = hit
         return hit
 
-    def _submit_single(self, combined, Bp: int, L_p: int, B: int,
-                       slots_p, ts_s_p, ts_ns_p, host_idx_p,
-                       live: Optional[np.ndarray]) -> _Pend:
-        """Dispatch the single fused program for one chunk: the window
-        state commit happens HERE (gated in-kernel on overflow and on the
+    # ---- host API (submit → resolve → collect, each in chunk order) ----
+
+    def submit(
+        self, cls_ids: np.ndarray, lens: np.ndarray, slots: np.ndarray,
+        ts_s: np.ndarray, ts_ns: np.ndarray, host_idx: np.ndarray,
+        live: Optional[np.ndarray] = None,
+    ) -> _Pend:
+        """Dispatch the fused program for one chunk (slot pins held by
+        the caller, ownership passes to the pipeline).  The window state
+        commit happens HERE (gated in the program on overflow and on the
         chain scalar), so the returned chunk is already final — its
-        resolve is a pure pull.  Runs under the windows lock: maintenance
-        (evictions/restores) drains first, exactly as the two-program
-        resolve did, and the state-chain order == seq order because both
-        are taken inside the same critical section."""
+        resolve is a pure pull — and any number of chunks may be
+        submitted ahead of their resolves.  `live` (bool [B], default
+        all-true) is the commit mask — the caller's staleness drop
+        composed as a program input.  The dispatch runs under the windows
+        lock: maintenance (evictions/restores) drains first, and the
+        state-chain order == seq order because both are taken inside the
+        same critical section."""
+        pf = self.pf
+        cls_ids = np.asarray(cls_ids, dtype=np.int32)
+        lens = np.asarray(lens, dtype=np.int32)
+        B = cls_ids.shape[0]
+        combined, Bp, L_p = pf._assemble(cls_ids, lens, self._progs)
+
+        def pad(a):
+            a = np.asarray(a, dtype=np.int32)
+            if Bp == len(a):
+                return a
+            return np.concatenate([a, np.zeros(Bp - len(a), dtype=np.int32)])
+
         fn, K, P, E = self._single_prog(Bp, L_p)
+        host_idx_p, slots_p = pad(host_idx), pad(slots)
+        ts_s_p, ts_ns_p = pad(ts_s), pad(ts_ns)
         live_p = np.zeros(Bp, dtype=np.uint8)
         live_p[:B] = 1 if live is None else np.asarray(live, dtype=np.uint8)
         wnd = self.windows
         with wnd._lock:
             with self._cv:
                 seq = self._next_seq
-                # quiescent chain reseed: every submitted chunk resolved
+                # quiescent chain reseed: every submitted chunk settled
                 # ⟹ every poisoned chunk's classic fallback has applied,
                 # so a fresh ok seed cannot reorder window updates
-                if seq == self._resolve_seq:
+                if seq == self._turn:
                     self._chain_ok = None
                 self._next_seq += 1
                 chain = self._chain_ok
@@ -342,123 +258,13 @@ class FusedWindowsPipeline:
             buf.copy_to_host_async()
         except AttributeError:
             pass
-        return _Pend(
+        p = _Pend(
             seq=seq, sparse_buf=buf, bits_dev=bits_dev,
-            slots=slots_p,  # caller overwrites with the unpadded view
-            ts_s=ts_s_p, ts_ns=ts_ns_p, host_idx=host_idx_p,
-            B=B, Bp=Bp, K=K, P=P, E=E,
+            slots=np.asarray(slots), B=B, Bp=Bp, K=K, P=P, E=E,
             # the whole h2d for the chunk: encoded classes + per-row
             # window metadata + the live mask + the chain scalar — still
             # no dense [B, n_rules] bitmap
             h2d_bytes=combined.nbytes + 4 * 3 * Bp + Bp + 4,
-        )
-
-    # ---- program B: window apply on a device-resident bitmap ----
-
-    def _apply_prog(self, Bp: int, max_events: int):
-        hit = self._apply_fns.get(Bp)
-        if hit is not None:
-            return hit
-        wnd = self.windows
-        n_rules = self.n_rules
-        limits, iv_s, iv_ns = wnd._limits, wnd._iv_s, wnd._iv_ns
-        active_table = self.active_table
-        shifts = jnp.asarray(_SHIFTS, dtype=jnp.int32)
-
-        @functools.partial(jax.jit, donate_argnums=(0,))
-        def apply(state, bits, slots, ts_s, ts_ns, host_idx, live):
-            # `live` gates rows that aged past the staleness cutoff while
-            # queued in the streaming pipeline: the deferred commit drops
-            # them HERE (a handful of bytes h2d) instead of re-uploading a
-            # row-filtered dense bitmap
-            bits = bits * live[:, None]
-            new_state, ev = W._apply_core(
-                state, bits, active_table, host_idx, slots, ts_s, ts_ns,
-                limits, iv_s, iv_ns,
-                n_rules=n_rules, max_events=max_events,
-            )
-            parts = [
-                ((ev["line"][:, None] >> shifts[None, :]) & 0xFF)
-                .astype(jnp.uint8).reshape(-1),
-                ((ev["rule"][:, None] >> shifts[None, :]) & 0xFF)
-                .astype(jnp.uint8).reshape(-1),
-                ((ev["hits"][:, None] >> shifts[None, :]) & 0xFF)
-                .astype(jnp.uint8).reshape(-1),
-                ((ev["start_s"][:, None] >> shifts[None, :]) & 0xFF)
-                .astype(jnp.uint8).reshape(-1),
-                ((ev["start_ns"][:, None] >> shifts[None, :]) & 0xFF)
-                .astype(jnp.uint8).reshape(-1),
-                ev["match_type"].astype(jnp.uint8),
-                ev["exceeded"].astype(jnp.uint8),
-                ev["seen_ip"].astype(jnp.uint8),
-            ]
-            return new_state, jnp.concatenate(parts)
-
-        self._apply_fns[Bp] = apply
-        return apply
-
-    # ---- host API (submit → resolve → collect, each in chunk order) ----
-
-    def submit(
-        self, cls_ids: np.ndarray, lens: np.ndarray, slots: np.ndarray,
-        ts_s: np.ndarray, ts_ns: np.ndarray, host_idx: np.ndarray,
-        live: Optional[np.ndarray] = None,
-    ) -> _Pend:
-        """Dispatch program A for one chunk (slot pins held by the caller,
-        ownership passes to the pipeline). Any number of chunks may be
-        submitted ahead of their resolves.
-
-        Single-kernel mode: the ONE fused program (match + window commit,
-        overflow/chain gated in-kernel) dispatches here instead and the
-        chunk returns already final; `live` (bool [B], default all-true)
-        is the commit mask — the caller's staleness/abandon drop composed
-        as a kernel input (the two-program path takes it at resolve)."""
-        pf = self.pf
-        cls_ids = np.asarray(cls_ids, dtype=np.int32)
-        lens = np.asarray(lens, dtype=np.int32)
-        B = cls_ids.shape[0]
-        combined, Bp, L_p = pf._assemble(cls_ids, lens, self._match_fns)
-
-        def pad(a, fill=0):
-            a = np.asarray(a)
-            if Bp == len(a):
-                return a
-            return np.concatenate(
-                [a, np.full(Bp - len(a), fill, dtype=a.dtype)]
-            )
-
-        host_idx_p = pad(host_idx).astype(np.int32)
-        if self.single_kernel:
-            p = self._submit_single(
-                combined, Bp, L_p, B,
-                pad(np.asarray(slots, dtype=np.int32)),
-                pad(ts_s).astype(np.int32), pad(ts_ns).astype(np.int32),
-                host_idx_p, live,
-            )
-            p.slots = np.asarray(slots)
-            self._sketch_update(p)
-            return p
-        match, K, P, E = self._match_prog(Bp, L_p)
-        sparse_buf, bits_dev = match(
-            jnp.asarray(combined), jnp.int32(B), jnp.asarray(host_idx_p)
-        )
-        try:
-            sparse_buf.copy_to_host_async()
-        except AttributeError:
-            pass
-        with self._cv:
-            seq = self._next_seq
-            self._next_seq += 1
-        p = _Pend(
-            seq=seq, sparse_buf=sparse_buf, bits_dev=bits_dev,
-            slots=np.asarray(slots),
-            ts_s=pad(ts_s).astype(np.int32),
-            ts_ns=pad(ts_ns).astype(np.int32),
-            host_idx=host_idx_p, B=B, Bp=Bp, K=K, P=P, E=E,
-            # the whole host→device traffic for this chunk: the encoded
-            # class array + the per-row window metadata — crucially NOT a
-            # dense [B, n_rules] bitmap
-            h2d_bytes=combined.nbytes + 4 * 3 * Bp,
         )
         self._sketch_update(p)
         return p
@@ -476,42 +282,40 @@ class FusedWindowsPipeline:
         except Exception:  # noqa: BLE001 — telemetry must never cost a chunk
             log.exception("traffic sketch update failed")
 
-    def _wait_turn(self, p: _Pend, attr: str) -> None:
+    def _wait_turn(self, p: _Pend) -> None:
         with self._cv:
-            if getattr(self, attr) == p.seq:
+            if self._turn == p.seq:
                 return
         # the drain thread blocking on an out-of-order turn is exactly
         # the stall a trace must show; the fast path above stays lock+
         # check only (the span records nothing when tracing is off)
-        with trace.span("turn-wait", args={"seq": p.seq, "gate": attr}):
+        with trace.span("turn-wait", args={"seq": p.seq}):
             with self._cv:
-                while getattr(self, attr) != p.seq:
+                while self._turn != p.seq:
                     self._cv.wait()
 
-    def _sweep_locked(self, attr: str, v: int) -> None:
-        dead = self._dead[attr]
-        while v in dead:
-            dead.discard(v)
+    def _sweep_locked(self, v: int) -> None:
+        while v in self._dead:
+            self._dead.discard(v)
             v += 1
-        setattr(self, attr, v)
+        self._turn = v
         self._cv.notify_all()
 
-    def _free_turn(self, p: _Pend, attr: str) -> None:
-        """Release one of p's order turns EXACTLY once (state-aware: a
-        chunk settled by two paths — e.g. a submit-failure abandon racing
-        a teardown abort — must not mark its turn dead twice, which
-        would leave a stale entry that could swallow a LATER chunk's
+    def _free_turn(self, p: _Pend) -> None:
+        """Release p's order turn EXACTLY once (state-aware: a chunk
+        settled by two paths — e.g. a submit-failure abandon racing a
+        teardown abort — must not mark its turn dead twice, which would
+        leave a stale entry that could swallow a LATER chunk's
         legitimate turn when seq numbers wrap past it)."""
         with self._cv:
-            if p.turns_freed[attr]:
+            if p.turn_freed:
                 return
-            p.turns_freed[attr] = True
-            cur = getattr(self, attr)
-            if cur == p.seq:
-                self._sweep_locked(attr, p.seq + 1)
+            p.turn_freed = True
+            if self._turn == p.seq:
+                self._sweep_locked(p.seq + 1)
             else:
-                self._dead[attr].add(p.seq)
-                self._sweep_locked(attr, cur)
+                self._dead.add(p.seq)
+                self._sweep_locked(self._turn)
 
     def _release_chunk_pins(self, p: _Pend) -> None:
         """Release p's slot pins exactly once.  Double release is the
@@ -525,30 +329,26 @@ class FusedWindowsPipeline:
         self.windows.release_pins(p.slots)
 
     def abandon(self, p: _Pend) -> None:
-        """Settle a chunk whose apply will never run (pipeline teardown,
-        a failed submit burst, or a fully-stale chunk at drain): release
-        its pins and both order turns, each exactly once (idempotent —
-        see _free_turn/_release_chunk_pins).  Two-program mode: program A
-        is stateless, so an abandoned chunk leaves no trace.  Single-
-        kernel mode: the commit already happened at submit, so abandon
-        only settles the host-side bookkeeping (teardown paths mark the
-        chunk's lines as errors)."""
+        """Settle a chunk whose events will never be collected (pipeline
+        teardown, a failed submit burst): release its pins and its order
+        turn, each exactly once (idempotent — see _free_turn/
+        _release_chunk_pins).  The commit already happened at submit, so
+        abandon only settles the host-side bookkeeping (teardown paths
+        mark the chunk's lines as errors)."""
         if p.state in ("done", "failed", "resolved"):
             return
         p.state = "failed"
         self._release_chunk_pins(p)
-        self._free_turn(p, "_resolve_seq")
-        self._free_turn(p, "_collect_seq")
+        self._free_turn(p)
 
     def idle(self) -> bool:
-        """True when no submitted chunk is awaiting its apply/collect."""
+        """True when no submitted chunk is awaiting its collect."""
         with self._cv:
-            return self._next_seq == self._collect_seq
+            return self._next_seq == self._turn
 
     def _decode_head(self, p: _Pend, buf: np.ndarray) -> int:
-        """Decode the match head (flags ‖ pairs ‖ always bits) shared
-        byte-for-byte by program A's buffer and the single-kernel buffer;
-        returns the offset just past it (the single-kernel event tail)."""
+        """Decode the match head (flags ‖ pairs ‖ always bits); returns
+        the offset just past it (the event tail)."""
         P = p.P
         R8 = self.pf._nf8 * 8
         flags = np.frombuffer(buf[:16].tobytes(), dtype="<i4")
@@ -599,143 +399,67 @@ class FusedWindowsPipeline:
         self.fallback_batches += 1
         return PipelineOverflow(candidate_overflow=cause == "candidates")
 
-    def _resolve_single(self, p: _Pend) -> None:
-        """Single-kernel resolve: a PURE d2h pull — the commit already
-        happened in-kernel at submit, so all that remains is forcing the
-        (async-copied) buffer and reading the flags word.  Not-ok chunks
-        (own overflow, or gated by a poisoned predecessor) take the
-        classic fallback exactly like a two-program overflow; the resolve
-        turn is held until fallback_done, so later chunks' replays stay
-        behind this chunk's classic apply."""
+    def resolve(self, p: _Pend) -> None:
+        """Order-gated: once every earlier chunk is settled, force chunk
+        p's (async-copied) buffer and read its flags word — a PURE d2h
+        pull, the commit already happened in the program at submit.  A
+        not-ok chunk (own overflow, or gated by a poisoned predecessor)
+        raises PipelineOverflow: the caller replays it classically and
+        calls fallback_done.  The turn is held until then — or, for an ok
+        chunk, until its collect — so later chunks' replays and shadow
+        writes stay behind this chunk's."""
+        self._wait_turn(p)
+        if p.state != "submitted":
+            return
         try:
             buf = np.asarray(p.sparse_buf)
             p.d2h_bytes += buf.nbytes
             off = self._decode_head(p, buf)
-            flags = p.flags
-            if not flags[0]:
-                self.sk_fallbacks += 1
+            if not p.flags[0]:
                 raise self._overflow(p)
             p.events_buf = buf
             p.events_off = off
             p.state = "resolved"
             self.fused_batches += 1
-            self.sk_chunks += 1
             self.sk_d2h_bytes_total += buf.nbytes
         except PipelineOverflow:
-            raise  # turns advance via fallback_done after the fallback
+            raise  # the turn advances via fallback_done after the fallback
         except Exception:
+            # the chunk is dead: free its turn (a stuck turn would
+            # deadlock every later resolve forever) and the pins
             p.state = "failed"
             self._release_chunk_pins(p)
-            self._free_turn(p, "_resolve_seq")
-            self._free_turn(p, "_collect_seq")
+            self._free_turn(p)
             raise
-        self._free_turn(p, "_resolve_seq")
-
-    def resolve(self, p: _Pend, live: Optional[np.ndarray] = None) -> None:
-        """Order-gated: decode chunk p's A-flags; when ok, dispatch program
-        B (the window apply) — B dispatches therefore happen strictly in
-        chunk order. `live` (bool [B], default all-true) gates rows out of
-        the window commit — the streaming pipeline's drain-time staleness
-        drop composed with the deferred apply. Raises PipelineOverflow when
-        the chunk must take the classic fallback; the resolve turn is NOT
-        advanced until the caller completes the fallback (fallback_done),
-        keeping later chunks' applies behind this chunk's.
-
-        Single-kernel mode: the commit already ran at submit (live was an
-        input there); this is a pure pull + flags check — `live` must be
-        None."""
-        self._wait_turn(p, "_resolve_seq")
-        if p.state != "submitted":
-            return
-        if self.single_kernel:
-            assert live is None, "single-kernel commit takes live at submit"
-            return self._resolve_single(p)
-        try:
-            buf = np.asarray(p.sparse_buf)
-            p.d2h_bytes += buf.nbytes
-            self._decode_head(p, buf)
-            flags = p.flags
-            if not flags[0]:
-                raise self._overflow(p)
-
-            wnd = self.windows
-            apply = self._apply_prog(p.Bp, p.E)
-            slots_p = p.slots.astype(np.int32)
-            if p.Bp != p.B:
-                slots_p = np.concatenate(
-                    [slots_p, np.zeros(p.Bp - p.B, dtype=np.int32)]
-                )
-            live_p = np.ones(p.Bp, dtype=np.uint8)
-            if live is not None:
-                live_p[: p.B] = np.asarray(live, dtype=np.uint8)
-            p.h2d_bytes += live_p.nbytes
-            with wnd._lock:
-                wnd._run_maintenance_locked()
-                new_state, ebuf = apply(
-                    wnd._state, p.bits_dev, jnp.asarray(slots_p),
-                    jnp.asarray(p.ts_s), jnp.asarray(p.ts_ns),
-                    jnp.asarray(p.host_idx), jnp.asarray(live_p),
-                )
-                wnd._state = new_state
-            try:
-                ebuf.copy_to_host_async()
-            except AttributeError:
-                pass
-            p.events_buf = ebuf
-            p.state = "resolved"
-            self.fused_batches += 1
-        except PipelineOverflow:
-            raise  # turns advance via fallback_done after the fallback
-        except Exception:
-            # the chunk is dead: free its order turns (a stuck turn would
-            # deadlock every later resolve/collect forever) and the pins.
-            # The resolve turn is held by this call (current == p.seq) so
-            # _free_turn advances it directly; the collect turn may still
-            # belong to an EARLIER uncollected chunk and sweeps lazily.
-            p.state = "failed"
-            self._release_chunk_pins(p)
-            self._free_turn(p, "_resolve_seq")
-            self._free_turn(p, "_collect_seq")
-            raise
-        self._free_turn(p, "_resolve_seq")
 
     def fallback_done(self, p: _Pend) -> None:
         """The caller's classic fallback for an overflowing chunk is fully
         applied (device + shadow + pins released by apply_bitmap): release
-        both order turns.  The pins are marked settled so a later abandon
+        the order turn.  The pins are marked settled so a later abandon
         (teardown racing the fallback) cannot release them a second time."""
         p.state = "done"
         p.pins_released = True  # apply_bitmap released them
-        self._free_turn(p, "_resolve_seq")
-        self._free_turn(p, "_collect_seq")
-        if self.single_kernel:
-            # quiescent chain reseed (see _submit_single): if no later
-            # chunk is outstanding, every poisoned chunk has now applied
-            # classically, so the next submit may start a fresh ok chain
-            with self._cv:
-                if self._next_seq == self._resolve_seq:
-                    self._chain_ok = None
+        self._free_turn(p)
+        # quiescent chain reseed (see submit): if no later chunk
+        # is outstanding, every poisoned chunk has now applied
+        # classically, so the next submit may start a fresh ok chain
+        with self._cv:
+            if self._next_seq == self._turn:
+                self._chain_ok = None
 
     def collect(self, p: _Pend) -> FusedWindowsResult:
-        """Order-gated on the collect turn: decode chunk p's window events,
-        absorb the final counter states into the host shadow, release the
-        pins. Only valid for resolved chunks (collect() resolves first on
-        the serial convenience path).  Single-kernel mode decodes the
-        event tail of the ONE buffer resolve already pulled (no second
-        d2h — the event layout is byte-identical to program B's)."""
+        """Decode chunk p's window events from the event tail of the ONE
+        buffer resolve pulled (no second d2h), absorb the final counter
+        states into the host shadow, release the pins and the turn. Only
+        valid for resolved chunks (collect() resolves first on the serial
+        convenience path)."""
         if p.state == "submitted":
             self.resolve(p)  # may raise PipelineOverflow to the caller
         assert p.state == "resolved", p.state
-        self._wait_turn(p, "_collect_seq")
         wnd = self.windows
         try:
-            if self.single_kernel:
-                buf = p.events_buf  # already host-side, pulled at resolve
-                off = p.events_off
-            else:
-                buf = np.asarray(p.events_buf)
-                p.d2h_bytes += buf.nbytes
-                off = 0
+            buf = p.events_buf  # already host-side, pulled at resolve
+            off = p.events_off
             me = p.E
 
             def take_i32(n):
@@ -764,8 +488,9 @@ class FusedWindowsPipeline:
                 match_type=ev_mtype[live], exceeded=ev_exc[live] != 0,
                 seen_ip=ev_seen[live] != 0,
             )
-            # Collect order == apply order, so concurrent chunks can't
-            # interleave stale values in the shadow.
+            # Collect order == apply order (the turn is held since
+            # resolve), so concurrent chunks can't interleave stale
+            # values in the shadow.
             with wnd._lock:
                 wnd._absorb_events_locked(
                     p.slots, events.line, events.rule, ev_hits[live],
@@ -778,4 +503,4 @@ class FusedWindowsPipeline:
             )
         finally:
             self._release_chunk_pins(p)
-            self._free_turn(p, "_collect_seq")
+            self._free_turn(p)

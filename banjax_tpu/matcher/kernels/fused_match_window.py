@@ -1,12 +1,12 @@
-"""Single-kernel fused match + window commit (ROADMAP item #1: kill the
-~65 ms resolve pull).
+"""Single-kernel fused match + window commit: one device program per
+chunk, one pull, no host decision between the match and the commit.
 
-The two-program fused path (matcher/fused_windows.py) splits every chunk
-into program A (stateless match + overflow flags) and program B (window
-commit) with a HOST decision between them: the drain thread pulls A's
-flags (a fixed d2h round trip), checks overflow, and only then
-dispatches B.  PRs 3-4 overlap that pull (resolve-ahead depth 2); this
-module removes it.  One device program per chunk does
+The classic protocol round-trips the match through the host: the drain
+pulls a bitmap (a fixed d2h round trip), rebuilds it dense and pushes it
+back for the window apply.  A fused path split into a stateless match
+program and a later commit program would still need a HOST decision
+between them — pull the flags, check overflow, only then dispatch the
+commit.  This module needs neither.  One device program per chunk does
 
     match (the two-stage Pallas NFA scan, prefilter._match_core)
       → dense caller-order bitmap + sparse (row, rule) pairs
@@ -23,15 +23,15 @@ and returns only a compact buffer — the [4] flags word ‖ sparse match
 pairs ‖ always-rule bits ‖ the fired-event records — plus the
 device-resident dense bitmap for the fallback.  The dense intermediate
 never crosses the host boundary, there is no inter-program host turn,
-and the drain's program-B dispatch disappears entirely: resolve becomes
-a pure d2h pull of a buffer whose async copy started at submit.
+and the drain dispatches nothing: resolve is a pure d2h pull of a
+buffer whose async copy started at submit.
 
-Ordering without the resolve turn: program A was stateless, so the
-two-program path could submit ahead and needed the resolve-turn
-machinery to serialize B dispatches.  Here the state commit happens at
-submit, and submits are already serialized (one device thread, chunks in
-admission order), so device apply order == log order by construction.
-The overflow hazard that forced the two-program split — chunk N
+Ordering: the state commit happens at submit, and submits are already
+serialized (one device thread, chunks in admission order, under the
+windows lock, which also drains eviction maintenance first), so device
+apply order == log order by construction, with no host turn between a
+chunk's match and its commit.
+The overflow hazard of committing at submit — chunk N
 overflows, its classic re-apply would land AFTER an already-dispatched
 chunk N+1 — is closed DEVICE-SIDE by the chain scalar: every kernel
 takes its predecessor's ok flag and gates its own commit on it, so an
@@ -46,8 +46,8 @@ calling the SAME `windows._window_step` the XLA lax.scan lowers, so the
 two paths cannot drift.  `interpret=True` runs it as plain JAX — the CI
 path; tests/unit/test_tpu_compile.py compiles it for a described v5e.
 `scan_selftest` proves the active lowering bit-identical to lax.scan at
-matcher construction — a failure downgrades the matcher to the
-two-program path (health-registry note).
+matcher construction — a failure leaves the matcher on the classic
+bitmap protocol (health-registry note).
 """
 
 from __future__ import annotations
@@ -170,7 +170,7 @@ def scan_selftest(interpret: bool, E: int = 64) -> None:
     elsewhere) reproduces the lax.scan recurrence bit-for-bit on a
     deterministic stimulus covering boundaries, pads, restarts and
     exceeds.  Raises on a lowering failure or any value mismatch — the
-    matcher then stays on the two-program path (graceful downgrade)."""
+    matcher then stays on the classic protocol (graceful downgrade)."""
     rng = np.random.default_rng(7)
     pad = np.zeros(E, dtype=bool)
     pad[-max(1, E // 8):] = True
@@ -221,9 +221,9 @@ def build_single_program(
       ‖ ev line/rule/hits/start_s/start_ns [5 × 4E]
       ‖ ev match_type/exceeded/seen_ip [3 × E]
 
-    The layouts of the head and the event tail are byte-identical to
-    program A's and program B's buffers respectively, so the host decode
-    is shared with the two-program path."""
+    The head (flags ‖ pairs ‖ always bits) and the event tail are laid
+    out back to back: fused_windows._decode_head reads the first and
+    FusedWindowsPipeline.collect the second."""
     # the event ceiling follows the rows and the ruleset (always-columns
     # can fire on every row), not a constant of the window table
     block, K, P, max_events = pf.program_capacities(Bp)
@@ -240,7 +240,7 @@ def build_single_program(
                ts_s, ts_ns, live):
         c = core(combined)
         pairs, n_pairs, pair_bits = pf.pairs_from_core(c, K, P)
-        # dense caller-order bitmap, assembled on device (as program A)
+        # dense caller-order bitmap, assembled on device
         bits = jnp.zeros((Bp, n_rules), dtype=jnp.uint8)
         if n_filt:
             m2 = pair_bits[:, :n_filt].astype(jnp.uint8)      # [K, n_filt]
@@ -258,7 +258,7 @@ def build_single_program(
         # the live mask composes staleness/abandon INTO the commit: a row
         # the caller dropped contributes no event and no state write (the
         # returned dense bitmap stays unmasked — the classic fallback
-        # applies its own mask, exactly like the two-program path)
+        # applies its own mask)
         bits_live = bits * live[:, None]
         fire = (bits_live != 0) & active_table[host_idx]
         n_events = fire.sum(dtype=jnp.int32)

@@ -414,8 +414,8 @@ class FusedPrefilter:
 
     The host-orchestrated PrefilterMatcher pays a device→host round trip
     plus a re-encode between the stages; on hardware that host work costs
-    ~20x the kernels themselves (BENCH r3 scratch: 19.7k lines/s fused-host
-    vs 497k single-stage). Here stage 1's candidate vector never leaves the
+    many times the kernels themselves.
+    Here stage 1's candidate vector never leaves the
     device: `nonzero(size=K)` compacts the candidate lines' already-resident
     class columns, stage 2 scans only those, and the per-stage bits scatter
     back into one packed [B, ceil(R/8)] bitmap. Requires a plan built with
@@ -657,7 +657,7 @@ class FusedPrefilter:
 
     def pairs_from_core(self, c, K: int, P: int):
         """The sparse (row, rule) pair extraction shared by the plain fused
-        program and the fused-windows program A: one int32 per set stage-2
+        program and the fused-windows program: one int32 per set stage-2
         bit, encoded caller_row * R8 + packed bit column (R8 = 8 * nf8),
         -1 beyond n_pairs. Returns (pairs [P] int32, n_pairs, bits [K, R8])
         — `bits` is the unpacked MSB-first bit tensor so callers needing

@@ -61,16 +61,8 @@ log = logging.getLogger(__name__)
 
 _MIN_BUCKET = 64
 
-# process-wide warn-once for the drain_resolve_depth/single-kernel no-op
-# (tests construct many matchers; one log line is the useful signal)
-_DEPTH_IGNORED_WARNED = False
-
 
 class TpuMatcher(Matcher):
-    # True when drain_resolve_depth > 1 is configured but the active
-    # single-kernel path makes it a no-op (SingleKernelDepthIgnored)
-    single_kernel_depth_ignored = False
-
     def __init__(
         self,
         config: Config,
@@ -113,29 +105,17 @@ class TpuMatcher(Matcher):
         # observable validation of the derived budget the ROADMAP carried
         # (banjax_matcher_budget_trips_total; feeds the SLO engine)
         self.budget_trips = 0
-        # two-phase fused chunks committed through the streaming pipeline
-        # (match dispatched at submit, window commit at drain) and how
-        # often one fell back to the classic replay mid-pipeline
+        # fused chunks (match + window commit in one program at submit)
+        # drained through the streaming pipeline, and how often one
+        # overflowed into the classic replay mid-pipeline
         self.pipelined_fused_chunks = 0
         self.pipelined_fused_fallbacks = 0
         # host wall seconds inside the drain's `effector-replay` spans
         # (event decode + shadow absorb + Banner replay of committed chunks)
         self.effector_replay_s = 0.0
-        # pipeline_fused=false restores the PR 2 behavior: the split
-        # protocol always takes the classic bitmap path
-        self._pipeline_fused = bool(getattr(config, "pipeline_fused", True))
-        # resolve-ahead depth for the fused drain commit: at depth d the
-        # drain keeps up to d-1 resolved chunks pending, so chunk i+1's
-        # window program (B) is on the device while chunk i's events
-        # decode/replay — the ~65 ms fixed d2h pull overlaps instead of
-        # serializing the drain thread.  1 restores the serial drain.
-        self._drain_resolve_depth = max(
-            1, int(getattr(config, "drain_resolve_depth", 2))
-        )
-        self.drain_resolve_overlap_ms_ewma: Optional[float] = None
         # batches whose device-window apply is deferred to their drain
         # turn (classic-pend fallbacks): while any is outstanding, the
-        # single-kernel path must not commit at submit (see
+        # fused path must not commit at submit (see
         # _single_kernel_ordered) or window updates would cross batches
         # out of admission order
         self._drain_window_lock = threading.Lock()
@@ -479,96 +459,64 @@ class TpuMatcher(Matcher):
                     self._skips[row, idx] = True
         self._rule_names = [r.rule for _, r in self._entries]
 
-        # fully-fused matcher+windows pipeline: one device dispatch per
-        # batch when both the fused prefilter and device windows are on and
-        # every rule is device-decidable (host-fallback rules need the
-        # classic bitmap path)
+        # fused matcher+windows pipeline: one device dispatch per chunk
+        # (match + window commit) when both the fused prefilter and device
+        # windows are on, every rule is device-decidable (host-fallback
+        # rules need the classic bitmap path) and the window-scan kernel
+        # lowers on this backend
         self._fw_pipeline = None
         if (
             self.device_windows is not None
             and self._prefilter is not None
             and not self._host_rule_idx
+            and self._resolve_single_kernel()
         ):
             from banjax_tpu.matcher.fused_windows import FusedWindowsPipeline
 
-            single, scan_interpret = self._resolve_single_kernel(config)
             self._fw_pipeline = FusedWindowsPipeline(
                 self._prefilter, self.device_windows, self._active_table,
-                self.compiled.n_rules, single_kernel=single,
-                scan_interpret=scan_interpret,
+                self.compiled.n_rules, scan_interpret=self._scan_interpret,
                 traffic_sketch=self.traffic_sketch,
             )
-            log.info(
-                "fused matcher+windows pipeline active (%s)",
-                "single-kernel" if single else "two-program",
-            )
+            log.info("fused matcher+windows pipeline active (single-kernel)")
         if self._health is not None:
             self._health.info = self.describe()
 
-    def _resolve_single_kernel(self, config) -> Tuple[bool, bool]:
-        """Resolve `pallas_single_kernel` for this backend: "auto" turns
-        the one-program fused path on whenever the Pallas window-scan
-        kernel lowers (compiled Mosaic on TPU, interpret-mode elsewhere —
-        the CI path), proven by a bit-exact selftest against the XLA
-        lax.scan.  A lowering/selftest failure downgrades gracefully to
-        the two-program path with a health-registry note, so a Mosaic
-        regression costs throughput, never correctness."""
-        sk_cfg = (getattr(config, "pallas_single_kernel", "auto") or "auto")
-        scan_interpret = bool(
+    def _resolve_single_kernel(self) -> bool:
+        """Whether the fused program may run here: the Pallas window-scan
+        kernel (compiled Mosaic on TPU, interpret-mode elsewhere — the CI
+        path) must reproduce the XLA lax.scan bit for bit in a selftest.
+        A lowering or selftest failure leaves the matcher on the classic
+        protocol — the same exact path an overflowing chunk takes — with
+        a health-registry note, so a Mosaic regression costs throughput,
+        never correctness."""
+        self._scan_interpret = bool(
             self._pallas_interpret or jax.default_backend() != "tpu"
         )
-        self._scan_interpret = scan_interpret
         comp = (
             self._health_registry.register("matcher-single-kernel")
             if self._health_registry is not None else None
         )
-        if sk_cfg == "off":
-            if comp is not None:
-                comp.ok("pallas_single_kernel: off (two-program path)")
-            return False, scan_interpret
         try:
             from banjax_tpu.matcher.kernels import fused_match_window
 
-            fused_match_window.scan_selftest(scan_interpret)
+            fused_match_window.scan_selftest(self._scan_interpret)
         except Exception as e:  # noqa: BLE001 — downgrade, never fail the matcher
             msg = (
                 f"single-kernel window-scan unavailable ({e}); "
-                "two-program fused path"
+                "classic bitmap protocol"
             )
-            self._note_downgrade(
-                msg, logging.WARNING if sk_cfg == "on" else logging.INFO
-            )
+            self._note_downgrade(msg, logging.WARNING)
             if comp is not None:
                 comp.degraded(msg)
-            return False, scan_interpret
-        # PR 7 silently ignored drain_resolve_depth on this path (the
-        # drain has no program-B dispatch left to overlap): surface the
-        # no-op as a warn-once + health note + SingleKernelDepthIgnored
-        # gauge instead of letting the knob look live
-        depth_note = ""
-        if self._drain_resolve_depth > 1:
-            self.single_kernel_depth_ignored = True
-            depth_note = (
-                f"; drain_resolve_depth={self._drain_resolve_depth} is a "
-                "no-op here (no program-B dispatch to overlap)"
-            )
-            global _DEPTH_IGNORED_WARNED
-            if not _DEPTH_IGNORED_WARNED:
-                _DEPTH_IGNORED_WARNED = True
-                log.warning(
-                    "drain_resolve_depth=%d is configured but the "
-                    "single-kernel fused path commits at submit — the "
-                    "resolve-ahead depth is a no-op (set "
-                    "pallas_single_kernel: off to use it, or drop the key)",
-                    self._drain_resolve_depth,
-                )
+            return False
         if comp is not None:
             comp.ok(
                 "single-kernel fused path active "
-                + ("(interpret scan)" if scan_interpret else "(compiled scan)")
-                + depth_note
+                + ("(interpret scan)" if self._scan_interpret
+                   else "(compiled scan)")
             )
-        return True, scan_interpret
+        return True
 
     def _note_downgrade(self, msg: str, level: int = logging.INFO) -> None:
         """An init-time step off the intended device path: logged as
@@ -595,10 +543,6 @@ class TpuMatcher(Matcher):
         else:
             backend = "pallas-interpret" if self._pallas_interpret else "pallas"
         nfa = "xla" if backend == "xla" else "pallas"
-        if fw is None:
-            protocol = "classic"
-        else:
-            protocol = "single-kernel" if fw.single_kernel else "two-program"
         return {
             "platform": dev.platform,
             "device_kind": dev.device_kind,
@@ -608,10 +552,9 @@ class TpuMatcher(Matcher):
                 backend == "pallas-interpret" if nfa == "pallas" else None
             ),
             "scan_interpret": (
-                bool(self._scan_interpret)
-                if fw is not None and fw.single_kernel else None
+                bool(self._scan_interpret) if fw is not None else None
             ),
-            "fused_protocol": protocol,
+            "fused_protocol": "classic" if fw is None else "single-kernel",
             "prefilter": self._prefilter is not None or (
                 mm is not None and mm.plan is not None
             ),
@@ -683,7 +626,7 @@ class TpuMatcher(Matcher):
     ) -> List[ConsumeLineResult]:
         """consume_lines with the fused single-dispatch path disabled —
         the streaming scheduler's generic drain uses this: a generic batch
-        drains on the drain thread while LATER batches' two-phase chunks
+        drains on the drain thread while LATER batches' fused chunks
         already hold fused-pipeline order turns, so an inline fused burst
         here would wait on turns that only release after this very drain
         completes (deadlock).  The classic bitmap path it takes instead is
@@ -908,18 +851,17 @@ class TpuMatcher(Matcher):
     #
     #   * classic bitmap — _match_bits_submit/collect, dense [B, n_rules]
     #     pulled to host, window apply (device or host) entirely at finish.
-    #   * fused two-phase (matcher/fused_windows.py) — when the fused
+    #   * fused (matcher/fused_windows.py) — when the fused
     #     matcher+windows pipeline is active and the batch has no
-    #     host-eval rows, submit dispatches program A (stateless match +
-    #     overflow flags) per chunk, any number of batches ahead; the
-    #     window commit (program B, state-donated segmented scan) is
-    #     DEFERRED to finish, where the drain thread dispatches it
-    #     strictly in admission order once each chunk's A-flags resolve.
-    #     The dense bitmap never crosses the host boundary — the ~16 MB
-    #     per-65k-batch re-upload the classic path pays is gone — and
-    #     drain-time staleness composes with the deferred commit as a
-    #     tiny per-row live mask.  Overflowing chunks replay classically
-    #     mid-pipeline (order turns held until the fallback applies).
+    #     host-eval rows, submit dispatches ONE program per chunk (match
+    #     + window commit, gated in the program on its overflow flags),
+    #     any number of batches ahead; finish pulls each chunk's buffer
+    #     in admission order and replays its events.  The dense bitmap
+    #     never crosses the host boundary — the ~16 MB per-65k-batch
+    #     re-upload the classic path pays is gone — and staleness is cut
+    #     at submit as a per-row live mask.  Overflowing chunks replay
+    #     classically mid-pipeline (the order turn held until the
+    #     fallback applies).
 
     def pipeline_begin(self, lines: Sequence[str], now: float) -> dict:
         """Encode stage: parse + gate + byte-class encode.  Fresh (non-
@@ -990,13 +932,9 @@ class TpuMatcher(Matcher):
         state = {
             "lines": lines, "results": results, "work": work,
             "pre": pre_encoded, "pend": None, "bits": None,
-            "fused": None,  # list of in-flight two-phase chunk entries
+            "fused": None,  # list of in-flight fused chunk entries
         }
-        if (
-            self._pipeline_fused
-            and self._fw_pipeline is not None
-            and len(work)
-        ):
+        if self._fw_pipeline is not None and len(work):
             if pre_encoded is None:
                 pre_encoded = encode_for_match(
                     self.compiled, [p.rest for _, p in work], self._max_len
@@ -1007,9 +945,9 @@ class TpuMatcher(Matcher):
         return state
 
     # the scheduler passes its now_fn() into pipeline_submit when this
-    # attribute is set — the single-kernel path commits window state at
-    # submit, so the staleness live mask is evaluated HERE (deterministic
-    # under an injected clock), not at drain
+    # attribute is set — the fused path commits window state at submit,
+    # so the staleness live mask is evaluated HERE (deterministic under
+    # an injected clock), not at drain
     pipeline_submit_takes_now = True
 
     def pipeline_submit(self, state: dict, now: Optional[float] = None) -> None:
@@ -1034,7 +972,7 @@ class TpuMatcher(Matcher):
         state["pend"] = self._match_bits_submit(state["work"], state["pre"])
         if self.device_windows is not None:
             # this batch's window apply happens at ITS drain turn: gate
-            # later single-kernel commits (which happen at submit, i.e.
+            # later fused commits (which happen at submit, i.e.
             # EARLIER than this batch's drain) until it completes, or
             # cross-batch window updates would reorder
             with self._drain_window_lock:
@@ -1046,50 +984,38 @@ class TpuMatcher(Matcher):
         batch still owes a drain-time window apply (a classic-pend
         fallback from slot refusal or host-eval rows).  While one is
         outstanding, this batch joins the classic path too — the single
-        drain thread then applies everything in admission order.  The
-        two-program mode commits at drain anyway, so it never gates."""
-        fw = self._fw_pipeline
-        if fw is None or not fw.single_kernel:
-            return True
+        drain thread then applies everything in admission order."""
         with self._drain_window_lock:
             return self._drain_window_batches == 0
 
     def _submit_fused_pipeline(self, state: dict,
                                now: Optional[float] = None) -> bool:
-        """Dispatch the device program(s) for every chunk of the batch.
-        Two-program mode dispatches program A (stateless match) per chunk;
-        single-kernel mode dispatches the ONE fused match+window program —
-        the chunk is final on return, and the 10 s staleness cutoff is
-        applied here as the kernel's live-mask input (`now`, from the
-        scheduler's clock; falls back to wall time on the direct-call
-        path).  Returns False — with every partial entry abandoned — when
-        slot allocation refuses, so the caller falls back to the classic
-        bitmap protocol for this batch.  Any other failure abandons the
-        entries and re-raises (the scheduler then drains the batch
-        generically; program A is stateless so nothing double-applies —
-        on the single-kernel path an already-committed chunk's generic
-        rerun can double-count window hits, never Banner effects)."""
+        """Dispatch the fused match+window program for every chunk of
+        the batch.  Each chunk is final on return, and the 10 s staleness
+        cutoff is applied here as the program's live-mask input (`now`,
+        from the scheduler's clock; falls back to wall time on the
+        direct-call path).  Returns False — with every partial entry
+        abandoned — when slot allocation refuses, so the caller falls
+        back to the classic bitmap protocol for this batch.  Any other
+        failure abandons the entries and re-raises (the scheduler then
+        drains the batch generically: an already-committed chunk's
+        generic rerun can double-count window hits, never Banner
+        effects)."""
         failpoints.check("matcher.device")
         work = state["work"]
         cls_ids, lens, _ = state["pre"]
-        fw = self._fw_pipeline
-        sk = fw.single_kernel
-        # one fused span replaces the program-a (submit) / program-b
-        # (drain) pair: match and window commit are one dispatch now
-        span_name = "program-ab-fused" if sk else "program-a"
-        if sk and now is None:
+        if now is None:
             now = time.time()
         entries = []
         try:
             for s in range(0, len(work), self._max_batch):
                 wc = work[s : s + self._max_batch]
                 live = stale = None
-                if sk:
-                    ages_s = now - wc.ts_array() / 1e9
-                    st = ages_s > OLD_LINE_CUTOFF_SECONDS
-                    if st.any():
-                        stale, live = st, ~st
-                with trace.span(span_name, args={"row0": s}):
+                ages_s = now - wc.ts_array() / 1e9
+                st = ages_s > OLD_LINE_CUTOFF_SECONDS
+                if st.any():
+                    stale, live = st, ~st
+                with trace.span("program-ab-fused", args={"row0": s}):
                     e = self._submit_pipeline_chunk(
                         wc,
                         cls_ids[s : s + self._max_batch],
@@ -1115,10 +1041,10 @@ class TpuMatcher(Matcher):
 
     def pipeline_collect(self, state: dict) -> None:
         if state.get("fused") is not None:
-            # wait for every chunk's A-program (compute only — the sparse
+            # wait for every chunk's program (compute only — the buffer's
             # pull is async and lands before resolve needs it); on failure
             # free the chunks' order turns and pins so the generic-drain
-            # rerun cannot deadlock later two-phase batches
+            # rerun cannot deadlock later fused batches
             try:
                 for e in state["fused"]:
                     buf = e["pend"].sparse_buf
@@ -1137,7 +1063,7 @@ class TpuMatcher(Matcher):
 
     def pipeline_abort(self, state: dict) -> None:
         """Settle a batch the drain stage will never finish (drain-stage
-        failure): free the two-phase chunks' order turns and slot pins so
+        failure): free the fused chunks' order turns and slot pins so
         later batches' resolves can't deadlock.  Idempotent."""
         entries = state.get("fused")
         state["fused"] = None
@@ -1170,13 +1096,10 @@ class TpuMatcher(Matcher):
         try:
             if not len(work):
                 return results, 0
-            if (
-                state.get("fused") is not None
-                and self._fw_pipeline.single_kernel
-            ):
-                # single-kernel chunks committed at submit (live mask =
-                # submit-time staleness): the drain is pure event pull +
-                # replay, no program-B dispatch, no drain-time re-cut
+            if state.get("fused") is not None:
+                # fused chunks committed at submit (live mask = submit-
+                # time staleness): the drain is pure event pull + replay,
+                # no drain-time re-cut
                 n_stale = self._finish_single_kernel(state, results)
                 self._note_health()
                 return results, n_stale
@@ -1189,11 +1112,6 @@ class TpuMatcher(Matcher):
                     r = results[i]
                     r.old_line = True
                     r.rule_results = []
-            if state.get("fused") is not None:
-                self._finish_fused_pipeline(state, stale, results)
-                self._note_health()
-                return results, n_stale
-            if stale.any():
                 keep = np.flatnonzero(~stale)
                 work = work.take(keep)
                 bits = bits[keep]
@@ -1211,142 +1129,13 @@ class TpuMatcher(Matcher):
                 len(state["lines"]), time.perf_counter() - t0
             )
 
-    def _finish_fused_pipeline(self, state, stale, results) -> None:
-        """Ordered window commit for the two-phase chunks, with depth-
-        `drain_resolve_depth` resolve-ahead: up to depth-1 RESOLVED
-        chunks stay pending while the next chunk's resolve dispatches its
-        window program (B) — so chunk i's event pull/decode/replay runs
-        while chunk i+1's B computes on the device, hiding the fixed d2h
-        latency the serial drain paid per chunk (ROADMAP PR 3 follow-up).
-
-        Ordering is untouched: resolve order == B dispatch order ==
-        device apply order (the pipeline's turn machinery enforces it),
-        and replay — hence ban-log byte order — still happens strictly
-        chunk-ascending because pending chunks drain before any later
-        chunk's fallback/replay emits an effect.  Staleness masks and the
-        overflow fallback compose exactly as at depth 1: a stale-masked
-        chunk resolves with its live mask, an overflowing chunk first
-        drains every pending replay, then replays classically.  A failed
-        chunk loses only its own lines — its order turns and pins are
-        freed either way (fused_windows' dead-turn sweep), so later
-        chunks and later batches keep draining."""
-        entries = state["fused"]
-        state["fused"] = None
-        fw = self._fw_pipeline
-        from banjax_tpu.matcher.fused_windows import PipelineOverflow
-
-        depth = self._drain_resolve_depth
-        pending: List[dict] = []  # resolved, replay deferred (≤ depth-1)
-
-        def collect_replay(e, overlapped: bool) -> None:
-            pend = e["pend"]
-            t0 = time.perf_counter()
-            # child of the scheduler's ambient `drain` span: event pull +
-            # decode + Banner replay for one committed chunk — the work
-            # the resolve-ahead hides behind the next chunk's program B
-            with trace.span("effector-replay",
-                            args={"row0": e["row0"],
-                                  "overlapped": overlapped}):
-                try:
-                    res = fw.collect(pend)
-                    self._replay_window_events(
-                        e["work"], None,
-                        (res.matched_pairs, res.always_bits),
-                        res.events, results, live_rows=e["live"],
-                    )
-                    self.pipelined_fused_chunks += 1
-                except Exception:  # noqa: BLE001 — collect released pins/turns in finally
-                    log.exception(
-                        "pipelined fused event collect failed; chunk lines "
-                        "marked error"
-                    )
-                    self._mark_chunk_error(e, e["chunk_stale"], results)
-                    self.note_device_outcome(0.0, ok=False)
-                finally:
-                    self.stats.note_xfer(pend.h2d_bytes, pend.d2h_bytes)
-            self.effector_replay_s += time.perf_counter() - t0
-            if overlapped:
-                # the d2h-overlap witness: this collect+replay wall time
-                # ran while a later chunk's B was in flight
-                ms = (time.perf_counter() - t0) * 1e3
-                prev = self.drain_resolve_overlap_ms_ewma
-                self.drain_resolve_overlap_ms_ewma = (
-                    ms if prev is None else prev + 0.3 * (ms - prev)
-                )
-
-        def drain_pending() -> None:
-            while pending:
-                collect_replay(pending.pop(0), overlapped=False)
-
-        for e in entries:
-            pend = e["pend"]
-            s = e["row0"]
-            n = len(e["work"])
-            chunk_stale = stale[s : s + n]
-            e["chunk_stale"] = chunk_stale
-            live = None
-            if chunk_stale.any():
-                if chunk_stale.all():
-                    # nothing to commit: freeing the turns without a B
-                    # dispatch matches the classic path's row removal
-                    fw.abandon(pend)
-                    continue
-                live = ~chunk_stale
-            e["live"] = live
-            try:
-                failpoints.check("matcher.resolve")
-                # program B (window commit) dispatch for this chunk, in
-                # admission order — child of the ambient `drain` span
-                with trace.span("program-b",
-                                args={"row0": s,
-                                      "masked": live is not None}):
-                    fw.resolve(pend, live=live)
-            except PipelineOverflow as ov:
-                # earlier chunks' effects must fire before this chunk's
-                # classic replay: drain the resolve-ahead window first
-                drain_pending()
-                trace.instant("fused-overflow-fallback", {"row0": s})
-                self.pipelined_fused_fallbacks += 1
-                try:
-                    self._pipeline_fallback_entry(e, ov, results, live=live)
-                except Exception:  # noqa: BLE001 — one chunk's loss, not the stream's
-                    log.exception(
-                        "pipelined fused overflow fallback failed; chunk "
-                        "lines marked error"
-                    )
-                    self._mark_chunk_error(e, chunk_stale, results)
-                    self.note_device_outcome(0.0, ok=False)
-                self.stats.note_xfer(pend.h2d_bytes, pend.d2h_bytes)
-                continue
-            except Exception:  # noqa: BLE001 — resolve frees turns/pins on its own errors
-                # an abort BEFORE resolve (the matcher.resolve failpoint)
-                # leaves the chunk submitted: settle its turns/pins here
-                # so the dead-turn sweep keeps later drains alive
-                if pend.state == "submitted":
-                    fw.abandon(pend)
-                drain_pending()
-                log.exception(
-                    "pipelined fused window commit failed; chunk lines "
-                    "marked error"
-                )
-                self._mark_chunk_error(e, chunk_stale, results)
-                self.note_device_outcome(0.0, ok=False)
-                continue
-            pending.append(e)
-            while len(pending) > depth - 1:
-                head = pending.pop(0)
-                collect_replay(head, overlapped=bool(pending))
-        drain_pending()
-
     def _finish_single_kernel(self, state, results) -> int:
-        """Ordered drain for single-kernel chunks: the window commit
-        already ran in-kernel at submit (the live mask carried the
-        submit-time 10 s staleness cut), so each chunk's drain is a pure
-        d2h pull (async since submit) + decode + Banner replay —
-        `drain_resolve_depth` is a no-op here because there is no
-        program-B dispatch left to overlap.  Overflow / chain-gated
-        chunks replay classically in chunk order via the existing
-        fallback (their kernel committed nothing — the in-kernel gate)."""
+        """Ordered drain for fused chunks: the window commit already ran
+        in the program at submit (the live mask carried the submit-time
+        10 s staleness cut), so each chunk's drain is a pure d2h pull
+        (async since submit) + decode + Banner replay.  Overflow /
+        chain-gated chunks replay classically in chunk order via the
+        fallback (their program committed nothing — its own gate)."""
         from banjax_tpu.matcher.fused_windows import PipelineOverflow
 
         entries = state["fused"]
@@ -1367,7 +1156,6 @@ class TpuMatcher(Matcher):
                 stale if stale is not None
                 else np.zeros(len(e["work"]), dtype=bool)
             )
-            e["chunk_stale"] = chunk_stale
             pend = e["pend"]
             try:
                 failpoints.check("matcher.resolve")
@@ -1761,101 +1549,64 @@ class TpuMatcher(Matcher):
             raise
 
     def _consume_via_pipeline(self, work, cls_ids, lens, results) -> None:
-        """Two-program fused path (matcher/fused_windows.py): program A
-        (stateless match + overflow flags) dispatches ahead; program B
-        (window apply) dispatches strictly in chunk order once each
-        chunk's flags resolve ok. Up to two chunks overlap: chunk N's
-        device→host pulls hide behind chunk N+1's match compute, and the
-        apply order — hence the reference's log order — is never violated,
-        even across overflow fallbacks (an overflowing chunk drains all
-        earlier chunks first, then replays classically before any later
-        apply dispatches)."""
+        """The sync entry's fused path (matcher/fused_windows.py): each
+        chunk's program commits at submit, and at most two chunks are in
+        flight — chunk N's device→host pull, decode and replay hide
+        behind chunk N+1's compute.  Chunks settle strictly oldest first,
+        so when one overflows every earlier chunk has already applied and
+        its classic replay lands before any later chunk's (those were
+        gated off by the chain scalar and replay classically too)."""
         failpoints.check("matcher.device")
         from banjax_tpu.matcher.fused_windows import PipelineOverflow
 
-        chunks = [
-            (work[s : s + self._max_batch],
-             cls_ids[s : s + self._max_batch],
-             lens[s : s + self._max_batch])
-            for s in range(0, max(1, len(work)), self._max_batch)
-        ]
         q: List[dict] = []  # in-flight entries, oldest first
 
-        def collect_replay(e):
-            res = self._fw_pipeline.collect(e["pend"])
-            sparse = (res.matched_pairs, res.always_bits)
+        def settle(e):
+            """Collect e and replay it, classically if it overflowed.
+            Pins and the order turn are released on every way out."""
+            try:
+                res = self._fw_pipeline.collect(e["pend"])
+            except PipelineOverflow as ov:
+                self._pipeline_fallback_entry(e, ov, results)
+                return
             self._replay_window_events(
-                e["work"], None, sparse, res.events, results
+                e["work"], None, (res.matched_pairs, res.always_bits),
+                res.events, results,
             )
 
-        def resolve_entry(e):
-            """Resolve e (dispatching its B apply); on overflow, drain
-            every earlier chunk first, then replay e classically. Returns
-            False when e was consumed by the fallback."""
-            try:
-                self._fw_pipeline.resolve(e["pend"])
-                return True
-            except PipelineOverflow as ov:
-                drained = False
-                try:
-                    while q and q[0] is not e:
-                        collect_replay(q.pop(0))
-                    drained = True
-                finally:
-                    if not drained:
-                        # the drain itself failed: free e's pins and order
-                        # turns so the error can't become a deadlock
-                        self.device_windows.release_pins(e["slots"])
-                        self._fw_pipeline.fallback_done(e["pend"])
-                        if q and q[0] is e:
-                            q.pop(0)
-                if q and q[0] is e:
-                    q.pop(0)
-                self._pipeline_fallback_entry(e, ov, results)
-                return False
-
-        def drain_all():
-            while q:
-                if q[-1]["pend"].state == "submitted":
-                    if not resolve_entry(q[-1]):
-                        continue
-                head = q.pop(0)
-                if head["pend"].state in ("failed", "done"):
-                    continue  # error/fallback paths already settled it
-                collect_replay(head)
-
         try:
-            for wc, cc, lc in chunks:
+            for s in range(0, max(1, len(work)), self._max_batch):
+                wc = work[s : s + self._max_batch]
+                cc = cls_ids[s : s + self._max_batch]
+                lc = lens[s : s + self._max_batch]
                 entry = self._submit_pipeline_chunk(wc, cc, lc)
                 if entry is None:
                     # slot allocation refused (more distinct IPs than
                     # free+unpinned slots): drain in-flight pins, then run
                     # this chunk through the splitting sync path
-                    drain_all()
+                    while q:
+                        settle(q.pop(0))
                     self._pipeline_chunk_sync(wc, cc, lc, results)
                     continue
                 q.append(entry)
-                if len(q) >= 2 and q[-2]["pend"].state == "submitted":
-                    # resolve the previous chunk → its B apply dispatches
-                    # while THIS chunk's match computes
-                    resolve_entry(q[-2])
-                if len(q) >= 3:
-                    collect_replay(q.pop(0))
-            drain_all()
+                if len(q) > 1:
+                    settle(q.pop(0))
+            while q:
+                settle(q.pop(0))
         except Exception:
-            # failures mid-burst: drain what we can so pins and the
-            # pipeline's order turns are not leaked for in-flight chunks
-            try:
-                drain_all()
-            except Exception:  # noqa: BLE001 — first error wins
-                log.exception("pipeline drain after failure also failed")
+            # failures mid-burst: settle what is still in flight so pins
+            # and the pipeline's order turns are not leaked
+            while q:
+                try:
+                    settle(q.pop(0))
+                except Exception:  # noqa: BLE001 — first error wins
+                    log.exception("pipeline drain after failure also failed")
             raise
 
     def _submit_pipeline_chunk(self, work, cls_ids, lens, live=None):
-        """Allocate slots + dispatch the chunk's device program (A, or
-        the single fused kernel — `live` is its commit mask); None when
-        slot allocation refuses. Pins transfer to the pipeline on
-        success."""
+        """Allocate slots + dispatch the chunk's fused program (`live`
+        is its commit mask); None when slot allocation refuses. Pins
+        transfer to the pipeline on success."""
         from banjax_tpu.matcher.windows import split_ns
 
         dw = self.device_windows
@@ -2069,8 +1820,8 @@ class TpuMatcher(Matcher):
 
         def make(bits_c):
             def apply_fn(work_c, slots, ts_s, ts_ns, host_idx, results_c):
-                # the dense-bitmap re-upload the fused two-phase path
-                # exists to eliminate: count it so the win is measurable
+                # the dense-bitmap re-upload the fused path exists to
+                # eliminate: count it so the win is measurable
                 if isinstance(bits_c, np.ndarray):
                     self.stats.note_xfer(h2d_bytes=bits_c.nbytes)
                 if self.traffic_sketch is not None:

@@ -15,8 +15,7 @@ Design constraints, in the trace recorder's mold (obs/trace.py):
   * **Off ≈ free.**  ``provenance_enabled`` gates every record path on a
     single attribute check.  On is the default (unlike tracing): records
     fire only on decision events — bans, list hits, expiries — which are
-    orders of magnitude rarer than log lines, and bench.py
-    ``--provenance-overhead`` banks the measured on/off delta.
+    orders of magnitude rarer than log lines.
   * **On = lock-cheap.**  One lock acquisition per record, a tuple store
     into a preallocated per-source ring (oldest overwritten), and one
     counter bump for the ``banjax_decision_inserts_total{source,

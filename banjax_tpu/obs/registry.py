@@ -205,10 +205,11 @@ FAMILIES: List[Family] = [
     Family(COUNTER, "fallback batches (fused overflow / pipeline generic "
            "drain)", line_key="PipelineFallbackBatches",
            prom="banjax_fused_fallback_batches_total"),
-    Family(COUNTER, "two-phase fused chunks committed via the pipeline",
+    Family(COUNTER, "fused chunks drained through the streaming pipeline",
            line_key="PipelinedFusedChunks",
            prom="banjax_pipelined_fused_chunks_total"),
-    Family(COUNTER, "two-phase chunks replayed classically (overflow)",
+    Family(COUNTER, "pipelined fused chunks replayed classically "
+           "(overflow)",
            line_key="PipelinedFusedFallbacks",
            prom="banjax_pipelined_fused_fallbacks_total"),
     Family(COUNTER, "fused dispatches that committed nothing and were "
@@ -224,12 +225,6 @@ FAMILIES: List[Family] = [
     Family(COUNTER, "ban-log records the regex rate limiter wrote",
            line_key="RegexBanRecords",
            prom="banjax_regex_ban_records_total"),
-    Family(GAUGE, "configured fused-drain resolve-ahead depth",
-           line_key="DrainResolveAheadDepth",
-           prom="banjax_drain_resolve_ahead_depth"),
-    Family(GAUGE, "EWMA event-decode+replay ms hidden behind the next "
-           "chunk's window program", line_key="DrainResolveOverlapMs",
-           prom="banjax_drain_resolve_overlap_ms"),
     # ---- single-kernel fused path (kernels/fused_match_window.py) ----
     Family(COUNTER, "chunks committed by the single-kernel fused "
            "match+window program (one dispatch, one pull)",
@@ -243,11 +238,6 @@ FAMILIES: List[Family] = [
            "one-pull witness: flags + pairs + events in ONE buffer)",
            line_key="SingleKernelD2hBytesPerBatch",
            prom="banjax_single_kernel_d2h_bytes_per_batch"),
-    Family(GAUGE, "1 when drain_resolve_depth > 1 is configured but the "
-           "single-kernel path makes it a no-op (no program-B dispatch "
-           "left to overlap)",
-           line_key="SingleKernelDepthIgnored",
-           prom="banjax_single_kernel_depth_ignored"),
     # ---- breaker / degraded mode ----
     Family(GAUGE, "circuit breaker state (one-hot by state label)",
            line_key="MatcherBreakerState",
@@ -278,7 +268,7 @@ FAMILIES: List[Family] = [
            prom="banjax_flightrec_incidents_total"),
     # ---- adversarial scenario harness (banjax_tpu/scenarios/) ----
     Family(COUNTER, "scenario-harness runs completed in this process "
-           "(bench --scenarios / the chaos soak)",
+           "(the chaos soak)",
            prom="banjax_scenario_runs_total"),
     Family(COUNTER, "chaos failpoint episodes injected across scenario "
            "runs", prom="banjax_scenario_injected_episodes_total"),

@@ -1,6 +1,6 @@
 """Matcher runtime counters for production observability (VERDICT r1 weak
-#8: a deployed instance must see the TPU subsystem's health, not just
-bench.py).
+#8: a deployed instance must see the TPU subsystem's health, not just a
+benchmark's).
 
 MatcherStats is a thread-safe accumulator every Matcher carries; the
 29-second metrics line (obs/metrics.py) snapshots it with ADDITIVE keys —
@@ -179,9 +179,9 @@ class MatcherStats:
             if fw is not None:
                 out["PipelineFusedBatches"] = fw.fused_batches
                 out["PipelineFallbackBatches"] = fw.fallback_batches
-                # two-phase (match-ahead, drain-commit) chunks driven by
-                # the streaming pipeline, and its overflow fallbacks —
-                # distinct from the sync-path counters above
+                # fused chunks driven by the streaming pipeline, and its
+                # overflow fallbacks — distinct from the sync-path
+                # counters above
                 out["PipelinedFusedChunks"] = getattr(
                     matcher, "pipelined_fused_chunks", 0
                 )
@@ -191,32 +191,12 @@ class MatcherStats:
                 out["EffectorReplaySeconds"] = round(
                     getattr(matcher, "effector_replay_s", 0.0), 6
                 )
-                # depth-2 resolve-ahead drain: configured depth, and the
-                # EWMA wall time of event decode + replay that ran while
-                # the NEXT chunk's window program was already in flight —
-                # the d2h latency the overlap is hiding
-                out["DrainResolveAheadDepth"] = getattr(
-                    matcher, "_drain_resolve_depth", 1
+                # one program, one pull per chunk
+                out["SingleKernelChunks"] = fw.sk_chunks
+                out["SingleKernelFallbacks"] = fw.sk_fallbacks
+                out["SingleKernelD2hBytesPerBatch"] = round(
+                    fw.sk_d2h_bytes_total / max(1, fw.sk_chunks), 1
                 )
-                out["DrainResolveOverlapMs"] = _r3(
-                    getattr(matcher, "drain_resolve_overlap_ms_ewma", None)
-                )
-                # single-kernel fused path: one program, one pull per
-                # chunk — the resolve-pull elimination is visible as
-                # SingleKernelChunks rising while DrainResolveOverlapMs
-                # stays unset (nothing left for depth-2 to hide)
-                if getattr(fw, "single_kernel", False):
-                    out["SingleKernelChunks"] = fw.sk_chunks
-                    out["SingleKernelFallbacks"] = fw.sk_fallbacks
-                    out["SingleKernelD2hBytesPerBatch"] = round(
-                        fw.sk_d2h_bytes_total / max(1, fw.sk_chunks), 1
-                    )
-                    # drain_resolve_depth configured but a no-op on this
-                    # path (PR 7 silent-ignore made observable)
-                    out["SingleKernelDepthIgnored"] = bool(
-                        getattr(matcher, "single_kernel_depth_ignored",
-                                False)
-                    )
             # traffic introspection plane (obs/sketch.py): the sampled
             # summary — pull() self-throttles to its sampling interval,
             # so line snapshots and scrapes share one compact d2h

@@ -2,20 +2,18 @@
 
 The reference banjax exposes a 29-second status line and nothing else;
 this reproduction has four overlapped pipeline stages, a fused
-two-program device path, sharded encode workers, and a resolve-ahead
-drain — none of it visible per-batch.  This module is the Dapper-style
-propagation layer: every admission batch gets a trace id at the
-scheduler's take-time and carries it through encode (per-shard child
-spans), submit (program-A dispatch, mesh shard submits), collect, and
-drain (program-B commit, effector replay), with breaker/fallback/shed
-events as instant annotations.
+device path and sharded encode workers — none of it visible per-batch.
+This module is the Dapper-style propagation layer: every admission
+batch gets a trace id at the scheduler's take-time and carries it
+through encode (per-shard child spans), submit (the fused program's
+dispatch, mesh shard submits), collect, and drain (effector replay),
+with breaker/fallback/shed events as instant annotations.
 
 Design constraints, in order:
 
   * **Off ≈ free.**  `trace_enabled` defaults false; every record path
     starts with one attribute check and returns a shared no-op object —
-    no allocation, no lock, no clock read.  bench.py --trace-overhead
-    banks the measured on/off delta (BENCH_trace_overhead.json).
+    no allocation, no lock, no clock read.
   * **On = lock-cheap.**  A completed span is one lock acquisition and
     a handful of stores into a preallocated ring (`trace_ring_size`
     slots, oldest overwritten).  Nothing is formatted or allocated per
@@ -24,8 +22,8 @@ Design constraints, in order:
     the encode thread and ends on the drain thread, so the root rides
     the batch object (`begin`/`end`), while single-thread stage spans
     use the context-manager form, which also maintains a thread-local
-    ambient parent — nested spans recorded inside the matcher (program
-    B, effector replay, mesh shard pulls) auto-parent without the
+    ambient parent — nested spans recorded inside the matcher (the
+    fused program, effector replay, mesh shard pulls) auto-parent without the
     matcher knowing about the scheduler's ids.
 
 Export: `export_chrome()` renders the ring as Chrome `trace_event`

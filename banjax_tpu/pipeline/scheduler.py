@@ -1,11 +1,9 @@
 """Streaming pipeline scheduler: overlapped tailer→device→effector batching.
 
-PERF.md's transport finding: the fused matcher classifies 2.57M lines/s
-device-resident but only ~135–206k end-to-end, because consume_lines is a
-synchronous submit→wait→collect loop and the ~65 ms fixed device→host
-latency is only hidden when overlapped with compute.  This module is the
-continuous-batching scheduler that closes that gap — the inference-serving
-pattern (SURVEY §7.2 M5) applied to log classification.
+consume_lines is a synchronous submit→wait→collect loop, and the fixed
+device→host latency is only hidden when overlapped with compute.  This
+module is the continuous-batching scheduler that closes that gap — the
+inference-serving pattern (SURVEY §7.2 M5) applied to log classification.
 
 Stages, one thread each::
 
@@ -27,25 +25,23 @@ in admission order, so per-(ip, rule) window updates and ban-log lines
 stay in log order across batch boundaries — byte-identical to the
 synchronous path (tests/differential/test_pipeline_differential.py).
 
-Fused two-phase mode: with device windows on, the split protocol drives
-the fused matcher+windows two-program path (matcher/fused_windows.py) —
-pipeline_submit dispatches program A (stateless match) ahead freely,
-and the window commit (program B) happens inside pipeline_finish on the
-drain thread, strictly in admission order.  The dense bitmap never
-crosses the host boundary (tests/differential/
+Fused path: with device windows on, the split protocol drives the fused
+matcher+windows pipeline (matcher/fused_windows.py) — match AND window
+commit are ONE device program per chunk, dispatched at the submit stage
+any number of batches ahead; the drain stage pulls each chunk's compact
+event buffer (async since submit) in admission order and replays it.
+The dense bitmap never crosses the host boundary (tests/differential/
 test_fused_pipeline_differential.py proves byte-identity and the h2d
-win).  Generic drains use consume_lines_serial so an inline fused burst
-can't deadlock against in-flight two-phase order turns.
-
-Single-kernel mode (`pallas_single_kernel`, the default where the Pallas
-window-scan kernel lowers): match AND window commit are ONE device
-program dispatched at the submit stage — the drain stage loses its
-program-B dispatch turn entirely and just pulls each chunk's compact
-event buffer (async since submit) in admission order.  Because the
-commit happens at submit, the 10 s staleness cutoff is evaluated there
-(the kernel's live-mask input), which is why the submit call below
-receives the scheduler clock; a matcher advertises this with
-`pipeline_submit_takes_now`.
+win).  Because the commit happens at submit, the 10 s staleness cutoff
+is evaluated there (the program's live-mask input), which is why the
+submit call below receives the scheduler clock; a matcher advertises
+this with `pipeline_submit_takes_now`.  A chunk that overflows commits
+nothing and replays through the classic bitmap protocol at its drain
+turn; batches the fused path cannot take (host-evaluated rules, a
+refused slot allocation, a failed scan selftest) ride the classic
+protocol end to end, with the staleness cut at drain.  Generic drains
+use consume_lines_serial so an inline fused burst can't deadlock against
+in-flight fused order turns.
 
 Kafka commands: submit_commands() admits command messages into the SAME
 buffer as tailer lines — shared bounded-block/oldest-first-shed
@@ -673,7 +669,7 @@ class PipelineScheduler:
     def _device_failure(self, batch: _Batch, stage: str = "device") -> None:
         trace.instant("device-failure", {"stage": stage},
                       trace_id=batch.trace_id)
-        # settle any two-phase chunks the failed batch already dispatched
+        # settle any fused chunks the failed batch already dispatched
         # (order turns + slot pins) before the generic rerun — idempotent
         abort = getattr(batch.matcher, "pipeline_abort", None)
         if abort is not None and batch.state is not None:
@@ -730,7 +726,7 @@ class PipelineScheduler:
                         # never a loss.  consume_lines_serial (when the
                         # matcher has it) keeps the fused single-dispatch
                         # burst out of the drain thread: its order turns
-                        # belong to the two-phase pipeline and an inline
+                        # belong to the fused pipeline and an inline
                         # burst here would deadlock behind in-flight later
                         # batches.
                         sp.note("fallback", "generic-drain")
@@ -755,7 +751,7 @@ class PipelineScheduler:
                     )
                     self.stats.note_drain_error(n)
                     if batch.state is not None:
-                        # free any two-phase order turns/pins the unfinished
+                        # free any fused order turns/pins the unfinished
                         # batch still holds — a leaked turn would deadlock
                         # every later fused drain
                         abort = getattr(batch.matcher, "pipeline_abort", None)

@@ -23,7 +23,7 @@ Instrumented sites in this tree (KNOWN_SITES):
   kafka.send       — KafkaWriter, before each transport send
   tailer.open      — LogTailer, every file open (start and rotation)
   matcher.device   — TpuMatcher, every device dispatch boundary
-  matcher.resolve  — fused two-phase resolve (turn-release abort path)
+  matcher.resolve  — fused chunk resolve (turn-release abort path)
   decision_chain   — decision_for_nginx entry (fail-open path)
   pipeline.encode  — pipeline scheduler, encode-stage boundary (a failing
                      batch drains generically; no loss)

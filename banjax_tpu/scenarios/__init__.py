@@ -1,7 +1,7 @@
 """Adversarial traffic-scenario harness (ROADMAP item 4).
 
-Every bench before this package replayed one uniform tailer-shaped feed;
-the reference's real workload is hostile — rotating-proxy botnets, slow
+A uniform tailer-shaped feed is the easy case; the reference's real
+workload is hostile — rotating-proxy botnets, slow
 drips under many user agents, Baskerville command floods, challenge
 storms, log rotation mid-burst.  This package turns those shapes into
 deterministic, oracle-checked evidence:
@@ -23,9 +23,9 @@ deterministic, oracle-checked evidence:
   * stats.py    — last-run summary the /metrics exposition renders as
                   the banjax_scenario_* families.
 
-Entry points: `bench.py --scenarios` banks one row per shape into
-BENCH_scenarios.json; `tests/soak/` runs a short seeded chaos pass in
-tier-1 and a long one behind `-m slow`.
+Entry points: `tests/soak/` runs a short seeded chaos pass in tier-1 and
+a long one behind `-m slow`; synth.py holds the seeded rule/line
+generators the smoke run and the tests share.
 """
 
 from banjax_tpu.scenarios.chaos import ChaosSchedule  # noqa: F401

@@ -18,7 +18,7 @@ What a run produces (ScenarioReport):
     virtual clock) and the final breached set;
   * structural invariants, each a named boolean:
       - accounting:      admitted == processed + shed + drain_errors
-      - no_leaked_turns: the fused two-phase pipeline is idle (every
+      - no_leaked_turns: the fused pipeline is idle (every
                          order turn settled)
       - no_leaked_pins:  zero outstanding device-window slot pins
       - commands_drained (when the shape carries commands, clean runs)
@@ -27,8 +27,7 @@ What a run produces (ScenarioReport):
     bundle per episode (when a recorder directory is given).
 
 The matcher is warmed with rule-neutral traffic before the measured
-feed so device-compile time lands outside the SLO/throughput window —
-the same discipline every bench mode uses.
+feed so device-compile time lands outside the SLO/throughput window.
 """
 
 from __future__ import annotations
@@ -117,7 +116,6 @@ def build_engine(
     rules_yaml: str,
     *,
     banner=None,
-    single_kernel: str = "auto",
     breaker_recovery_s: float = 0.5,
     latency_budget_ms: float = 180.0,
     buffer_lines: int = 131072,
@@ -138,7 +136,6 @@ def build_engine(
     cfg = config_from_yaml_text(rules_yaml)
     cfg.matcher = "tpu"
     cfg.matcher_device_windows = True
-    cfg.pallas_single_kernel = single_kernel
     cfg.breaker_recovery_seconds = breaker_recovery_s
     cfg.expiring_decision_ttl_seconds = 300
     if kafka_broker_port is not None:
@@ -176,7 +173,6 @@ class ScenarioReport:
     seed: int
     scale: float
     mode: str                      # "direct" | "tailer" | "kafka"
-    single_kernel: str
     n_lines: int
     n_commands: int
     feed_s: float
@@ -215,7 +211,6 @@ class ScenarioRunner:
         self,
         scenario: Scenario,
         *,
-        single_kernel: str = "auto",
         chaos=None,
         via_tailer: bool = False,
         tmp_dir: Optional[str] = None,
@@ -230,7 +225,6 @@ class ScenarioRunner:
         kafka_broker=None,
     ):
         self.scenario = scenario
-        self.single_kernel = single_kernel
         self.chaos = chaos
         self.via_tailer = via_tailer
         self.tmp_dir = tmp_dir
@@ -260,7 +254,6 @@ class ScenarioRunner:
 
         parts = build_engine(
             self.scenario.rules_yaml,
-            single_kernel=self.single_kernel,
             breaker_recovery_s=self.breaker_recovery_s,
             latency_budget_ms=self.latency_budget_ms,
             buffer_lines=self.buffer_lines,
@@ -785,7 +778,6 @@ class ScenarioRunner:
                 "tailer" if self.via_tailer
                 else "kafka" if self.kafka_broker is not None else "direct"
             ),
-            single_kernel=self.single_kernel,
             n_lines=n_lines,
             n_commands=n_cmds,
             feed_s=round(feed_s, 4),

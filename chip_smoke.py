@@ -6,7 +6,7 @@
 Drives the product through its own entry point — `BanjaxApp(config)` +
 `start_background()`, so the real tailer, pipeline scheduler, `TpuMatcher`,
 banner and fastserve run — at a size an operator would call real: 1,000
-rate-limit rules (`bench.generate_rules`, BASELINE.json config 3), 65,536
+rate-limit rules (`scenarios.synth.generate_rules`, BASELINE.json config 3), 65,536
 device window slots, four windows of 65,536 access-log lines with 2 % attack
 lines and more than 100,000 distinct client IPs (BASELINE.json config 4; more
 IPs than slots, so LRU spill and warm-tier refill are on the path).
@@ -93,15 +93,15 @@ def require(cond: bool, msg: str) -> None:
 
 
 def make_rules(n_rules: int, seed: int) -> list:
-    """`bench.generate_rules` patterns with rate limits an attacker in the
+    """`scenarios.synth.generate_rules` patterns with rate limits an attacker in the
     stream crosses: one rule in a hundred bans on the first hit (as the
     shipped config's demo rule does), the rest on the third hit inside
     five minutes; decisions alternate between the two whose effect
     /auth_request shows without root."""
-    import bench
+    from banjax_tpu.scenarios import synth
 
     rules = []
-    for i, regex in enumerate(bench.generate_rules(n_rules, seed)):
+    for i, regex in enumerate(synth.generate_rules(n_rules, seed)):
         instant = i % 100 == 7
         rules.append({
             "rule": f"smoke-{i:04d}",
@@ -131,7 +131,6 @@ def write_config(outdir: str, rules: list, sz: dict, **overrides) -> str:
         matcher_device_windows=True,
         matcher_window_capacity=sz["capacity"],
         matcher_prefilter=True,
-        pallas_single_kernel="auto",
         pipeline_enabled=True,
         http_workers=0,
         disable_kafka=True,
@@ -151,7 +150,7 @@ def _ip(base: int, i: int) -> str:
 
 
 class Traffic:
-    """(ip, rest) lines from `bench.generate_lines`: attack lines go to a
+    """(ip, rest) lines from `scenarios.synth.generate_lines`: attack lines go to a
     fixed small set of attacker IPs (a quarter of them send three
     quarters of the attack lines), benign lines to IPs drawn from a pool
     larger than the slot table.  The last `sleepers` attackers send only
@@ -160,9 +159,9 @@ class Traffic:
     is refilled when they return."""
 
     def __init__(self, patterns: list, sz: dict, seed: int, base: int):
-        import bench
+        from banjax_tpu.scenarios import synth
 
-        self._bench = bench
+        self._synth = synth
         self.patterns = patterns
         self.sz = sz
         self.rng = random.Random(seed * 7919 + base)
@@ -171,11 +170,11 @@ class Traffic:
             f"{base}.255.{250 + (i >> 8)}.{i & 255}"
             for i in range(sz["attackers"])
         ]
-        self.benign = set(bench.generate_lines(20000, [], seed=seed))
+        self.benign = set(synth.generate_lines(20000, [], seed=seed))
 
     def lines(self, n: int, seed: int, k: int = 0) -> list:
         """Window number k of a sequence (sleepers send when k % 3 == 0)."""
-        rests = self._bench.generate_lines(
+        rests = self._synth.generate_lines(
             n, self.patterns, seed=seed, attack_rate=ATTACK_RATE
         )
         rng, heavy = self.rng, self.sz["heavy_attackers"]
@@ -325,7 +324,7 @@ class Feeder:
         self.f = open(log_path, "a", encoding="utf-8")
         self.sent = 0
         self.old = self.errors = self.results = 0
-        # the scheduler's observer hook (tests and bench use it): every
+        # the scheduler's observer hook (tests and the benchmark use it): every
         # drained batch's per-line results, in admission order
         app.pipeline._on_results = self._observe
 

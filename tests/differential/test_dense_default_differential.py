@@ -107,7 +107,7 @@ def _build(cls, rules, **over):
     for k, v in {
         "matcher_device_windows": True, "matcher_window_capacity": 256,
         "matcher_batch_lines": BATCH, "matcher_max_line_len": 256,
-        "matcher_prefilter": True, "pallas_single_kernel": "auto",
+        "matcher_prefilter": True,
         "warm_tier_enabled": True, "warm_tier_capacity": 4096,
         "slot_admission_enabled": True, **over,
     }.items():
@@ -181,7 +181,7 @@ def test_dense_ruleset_commits_every_chunk_on_the_device(ruleset, entry):
 
     tpu, _, log = _build(TpuMatcher, rules)
     fw = tpu._fw_pipeline
-    assert fw is not None and fw.single_kernel
+    assert fw is not None
     assert tpu.describe()["downgrades"] == []
     if entry == "sync":
         results = []
@@ -227,7 +227,7 @@ def test_overflow_replay_at_this_density_equals_the_reference(
 
     tpu, _, log = _build(TpuMatcher, rules)
     fw = tpu._fw_pipeline
-    assert fw is not None and fw.single_kernel
+    assert fw is not None
     if entry == "sync":
         results = []
         for i in range(0, len(lines), BATCH):

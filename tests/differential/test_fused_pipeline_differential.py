@@ -1,17 +1,15 @@
-"""Pipelined FUSED two-phase path vs the synchronous fused path: byte
-identical under adversarial conditions (this PR's tentpole ordering
-contract).
+"""Pipelined FUSED path vs the synchronous fused path: byte identical
+under adversarial conditions (the pipeline's ordering contract).
 
-The streaming pipeline now drives the fused matcher+windows two-program
-path — program A (stateless match) dispatched ahead at the submit stage,
-the window commit (program B) deferred to the drain stage in admission
-order.  These tests prove the deferred commit changes NOTHING observable:
+The streaming pipeline drives the fused matcher+windows program — match
+and window commit in one dispatch at the submit stage, any number of
+batches ahead; the drain stage pulls and replays in admission order.
+These tests prove the overlap changes NOTHING observable:
 
   * adversarial batch churn with shared IPs crossing every batch/chunk
     boundary (window counters must accumulate in exact log order);
   * overflow chunks interleaved with ok chunks (the classic mid-pipeline
     replay, order turns held);
-  * drain-time staleness composed with the deferred commit (live mask);
   * breaker-OPEN mid-stream draining through the CPU reference matcher;
   * the h2d witness: the pipelined fused path must move FAR fewer bytes
     host→device than the classic bitmap path (no dense re-upload).
@@ -32,25 +30,34 @@ from banjax_tpu.effectors.banner import Banner
 from banjax_tpu.matcher.cpu_ref import CpuMatcher
 from banjax_tpu.matcher.runner import TpuMatcher
 from banjax_tpu.pipeline import PipelineScheduler
+from tests.classic_downgrade import scan_selftest_failing
 from tests.differential.test_pipeline_differential import ChurnSizer, _gen_lines
 from tests.differential.test_tpu_matcher import CONFIG_YAML, result_key
 
 
 def _build(matcher_cls, fused=True, **cfg_overrides):
+    """fused=False: the classic bitmap protocol, by the scan-selftest
+    downgrade (tests/classic_downgrade.py)."""
     config = config_from_yaml_text(CONFIG_YAML)
     config.matcher_device_windows = True
-    config.pipeline_fused = fused
     for k, v in cfg_overrides.items():
         setattr(config, k, v)
     states = RegexRateLimitStates()
     ban_log = io.StringIO()
     dyn = DynamicDecisionLists(start_sweeper=False)
     banner = Banner(dyn, ban_log, io.StringIO(), ipset_instance=None)
-    matcher = matcher_cls(config, banner, StaticDecisionLists(config), states)
+    with scan_selftest_failing(not fused):
+        matcher = matcher_cls(
+            config, banner, StaticDecisionLists(config), states
+        )
+    if matcher_cls is TpuMatcher:
+        assert (matcher._fw_pipeline is not None) == fused
     return matcher, states, dyn, ban_log
 
 
-def _run_pipelined(matcher, lines, now, sizer_seed=7, submit_seed=11):
+def _run_pipelined(matcher, lines, now, sizer_seed=7, submit_seed=11,
+                   tail=()):
+    """`tail`: lines submitted after the stream has drained whole."""
     collected = []
     lock = threading.Lock()
 
@@ -69,16 +76,19 @@ def _run_pipelined(matcher, lines, now, sizer_seed=7, submit_seed=11):
         sched.submit(lines[i : i + step])
         i += step
     assert sched.flush(180)
+    if tail:
+        sched.submit(list(tail))
+        assert sched.flush(180)
     sched.stop()
     pipe_lines = [l for ls, _ in collected for l in ls]
     pipe_results = [r for _, rs in collected for r in rs]
-    assert pipe_lines == lines, "admission order broken"
+    assert pipe_lines == lines + list(tail), "admission order broken"
     return pipe_results, sched
 
 
 def test_pipelined_fused_is_byte_identical_and_kills_dense_upload():
     """The tentpole acceptance: fused+pipelined output == sync fused ==
-    CPU reference (results, ban-log bytes, window state), the two-phase
+    CPU reference (results, ban-log bytes, window state), the fused
     path actually engaged, and the h2d byte counter shows the dense
     bitmap re-upload gone relative to the classic pipelined path."""
     now = time.time()
@@ -110,23 +120,23 @@ def test_pipelined_fused_is_byte_identical_and_kills_dense_upload():
     assert fused.device_windows.format_states() == \
         classic.device_windows.format_states()
 
-    # the two-phase path really ran (this stream has host-eval-free
+    # the fused path really ran (this stream has host-eval-free
     # batches; some batches legitimately take the classic path when a
     # garbage line defers)
-    assert fused.pipelined_fused_chunks > 0, "two-phase path never engaged"
-    assert classic.pipelined_fused_chunks == 0  # pipeline_fused=false honored
+    assert fused.pipelined_fused_chunks > 0, "fused path never engaged"
+    assert classic.pipelined_fused_chunks == 0
 
 
 def test_h2d_witness_dense_reupload_gone_at_rule_scale():
     """The fusion-win witness at a realistic rule count: the classic
     pipelined path re-uploads a dense [B, n_rules] bitmap for the drain
     commit (n_rules bytes per line — the ~16 MB/batch at 1k rules / 65k
-    lines); the two-phase path uploads only the encoded classes + a
+    lines); the fused path uploads only the encoded classes + a
     per-row live mask.  At 200 rules the classic h2d must exceed fused by
     roughly the bitmap's size."""
     import yaml as _yaml
 
-    from bench import generate_lines, generate_rules
+    from banjax_tpu.scenarios.synth import generate_lines, generate_rules
 
     patterns = generate_rules(200)
     rules_yaml = _yaml.safe_dump({
@@ -146,12 +156,12 @@ def test_h2d_witness_dense_reupload_gone_at_rule_scale():
     def run(fused_flag):
         config = config_from_yaml_text(rules_yaml)
         config.matcher_device_windows = True
-        config.pipeline_fused = fused_flag
         states = RegexRateLimitStates()
         dyn = DynamicDecisionLists(start_sweeper=False)
         banner = Banner(dyn, io.StringIO(), io.StringIO(), ipset_instance=None)
-        m = TpuMatcher(config, banner, StaticDecisionLists(config), states)
-        assert m._fw_pipeline is not None
+        with scan_selftest_failing(not fused_flag):
+            m = TpuMatcher(config, banner, StaticDecisionLists(config), states)
+        assert (m._fw_pipeline is not None) == fused_flag
         sched = PipelineScheduler(
             lambda: m, now_fn=lambda: now, min_batch=256, max_batch=256,
         )
@@ -178,10 +188,11 @@ def test_overflow_chunks_interleaved_with_ok_chunks():
     """Bursts of all-matching traffic (candidate overflow → classic
     mid-pipeline replay) interleaved with benign chunks: byte-identical,
     fallbacks counted, pins/turns never leak (the flush would hang).
-    Two-program path pinned — its resolve turns let benign chunks BEHIND
-    an overflow still commit fused, which the chunk-counter assertions
-    below encode; the single-kernel chain-gate composition of this shape
-    lives in tests/differential/test_single_kernel_differential.py."""
+    An overflow's chain scalar gates every chunk already dispatched
+    behind it, so how many benign chunks of the stream commit fused is a
+    matter of timing; the tail, submitted once the stream has drained,
+    starts a fresh chain and must commit fused (the phase-gap shape of
+    this lives in tests/differential/test_single_kernel_differential.py)."""
     now = time.time()
     rng = random.Random(3)
     lines = []
@@ -196,11 +207,17 @@ def test_overflow_chunks_interleaved_with_ok_chunks():
         else:
             lines += _gen_lines(40, now, seed=100 + burst)
 
-    sync, _, _, sync_log = _build(TpuMatcher, pallas_single_kernel="off")
-    sync_results = sync.consume_lines(lines, now_unix=now)
+    tail = [
+        f"{now:f} 6.6.6.{i} HEAD quiet.org HEAD /q{i} HTTP/1.1 ua -"
+        for i in range(16)
+    ]
 
-    pipe, _, _, pipe_log = _build(TpuMatcher, pallas_single_kernel="off")
-    pipe_results, _ = _run_pipelined(pipe, lines, now, sizer_seed=5)
+    sync, _, _, sync_log = _build(TpuMatcher)
+    sync_results = sync.consume_lines(lines + tail, now_unix=now)
+
+    pipe, _, _, pipe_log = _build(TpuMatcher)
+    pipe_results, _ = _run_pipelined(pipe, lines, now, sizer_seed=5,
+                                     tail=tail)
 
     assert [result_key(r) for r in pipe_results] == \
         [result_key(r) for r in sync_results]
@@ -236,7 +253,7 @@ def test_breaker_open_mid_stream_drains_via_cpu_reference():
         assert m.breaker.allow()
 
     # cand_frac 1.0: this mix matches often; give stage 2 full capacity
-    # so the phases commit through program B, not the overflow fallback
+    # so the phases commit in the fused program, not the overflow fallback
     sync, _, _, sync_log = _build(
         TpuMatcher, matcher_prefilter_cand_frac=1.0
     )
@@ -276,46 +293,12 @@ def test_breaker_open_mid_stream_drains_via_cpu_reference():
     assert pipe.device_windows.format_states() == \
         sync.device_windows.format_states()
     assert pipe.fallback_batches > 0  # phase 2 really took the CPU path
-    # phases 1/3 went through the two-phase path (commit or its counted
+    # phases 1/3 went through the fused path (commit or its counted
     # overflow fallback — this mix can still overflow the pair budget)
     assert pipe.pipelined_fused_chunks + pipe.pipelined_fused_fallbacks > 0
     snap = sched.snapshot()
     assert snap["PipelineProcessedLines"] == len(phase1) + len(phase2) + len(phase3)
     assert snap["PipelineShedLines"] == 0
-
-
-def test_drain_stale_composes_with_deferred_commit():
-    """Lines that age past the 10 s cutoff while queued are dropped at
-    the drain commit via the live mask: no window update, no Banner
-    effect, marked old_line — while fresh lines in the SAME chunk commit
-    normally.  (Two-program path pinned: the single-kernel path takes
-    the staleness cut at submit instead — see
-    tests/differential/test_single_kernel_differential.py.)"""
-    now = time.time()
-    m, states, _, ban_log = _build(TpuMatcher, pallas_single_kernel="off")
-    # 8 s old at encode (fresh), drained at now+3 → 11 s old → stale
-    old = [
-        f"{now - 8:f} 9.9.9.{i} GET per-site.com GET /blockme HTTP/1.1 ua -"
-        for i in range(5)
-    ]
-    fresh = [
-        f"{now:f} 8.8.8.{i} GET per-site.com GET /blockme HTTP/1.1 ua -"
-        for i in range(5)
-    ]
-    state = m.pipeline_begin(old + fresh, now)
-    assert state.get("fused_eligible")
-    m.pipeline_submit(state)
-    assert state.get("fused"), "two-phase entries missing"
-    m.pipeline_collect(state)
-    results, n_stale = m.pipeline_finish(state, now + 3)
-    assert n_stale == 5
-    assert all(r.old_line and not r.rule_results for r in results[:5])
-    assert all(not r.old_line and r.rule_results for r in results[5:])
-    # only the fresh IPs ever touched the device windows
-    view = m.device_windows.format_states()
-    assert "9.9.9.0" not in view and "8.8.8.0" in view
-    # instant-block rule fired for fresh lines only
-    assert ban_log.getvalue().count("instant block") == 5
 
 
 @pytest.mark.slow

@@ -13,9 +13,9 @@ oracles the pipeline suites use (CPU reference / sync TPU batch path):
   * the native slot manager — runs underneath both paths here (it is on
     by default); its dedicated parity fuzz lives in
     tests/unit/test_slotmgr.py;
-  * depth-2 resolve-ahead drain — multi-chunk fused batches drained
-    with the window commit of chunk i+1 dispatched while chunk i's
-    events decode, vs the serial depth-1 drain.
+  * multi-chunk fused batches — a 256-line take is several 64-line
+    chunks, each committed at submit and drained in order: overflow in
+    the middle of a batch and per-chunk staleness masks compose.
 """
 
 import io
@@ -231,31 +231,22 @@ def test_shard_boundary_rows_with_flags(small_shards):
     assert snap["EncodeShardedBatches"] > 0
 
 
-@pytest.mark.parametrize("depth", [1, 2, 3])
-def test_resolve_ahead_depth_byte_identical(small_shards, depth):
+def test_multichunk_fused_batches_byte_identical(small_shards):
     """Multi-chunk fused batches (matcher_batch_lines=64 under 256-line
-    takes) drained at resolve-ahead depth 1/2/3: byte-identical results,
-    ban-log bytes, and window state; the two-phase path engaged; at
-    depth >= 2 the overlap metric records that replay ran while the next
-    chunk's window program was in flight."""
+    takes): byte-identical results, ban-log bytes, and window state
+    against the sync entry; the fused path engaged."""
     now = time.time()
     lines = _gen_lines(1200, now, seed=31)
 
-    sync, _, _, sync_log = _build(
-        TpuMatcher, True, pallas_single_kernel="off"
-    )
+    sync, _, _, sync_log = _build(TpuMatcher, True)
     sync_results = sync.consume_lines(lines, now_unix=now)
 
     # cand_frac=1.0: small (64-line) chunks must not overflow the
-    # prefilter's candidate capacity — this test wants the two-phase
-    # commit, not the fallback (that composition is tested below).
-    # pallas_single_kernel=off: resolve-ahead is the TWO-PROGRAM drain's
-    # machinery (the single-kernel path has no program-B dispatch left
-    # to overlap, so the overlap metric legitimately stays unset there).
+    # prefilter's candidate capacity — this test wants the fused commit,
+    # not the fallback (that composition is tested below)
     par, _, _, par_log = _build(
         TpuMatcher, True,
-        matcher_batch_lines=64, drain_resolve_depth=depth,
-        matcher_prefilter_cand_frac=1.0, pallas_single_kernel="off",
+        matcher_batch_lines=64, matcher_prefilter_cand_frac=1.0,
     )
     par_results, _ = _run_pipelined(
         par, lines, now, workers=0, sizer=BigSizer(seed=7)
@@ -266,18 +257,14 @@ def test_resolve_ahead_depth_byte_identical(small_shards, depth):
     assert par_log.getvalue() == sync_log.getvalue()
     assert par.device_windows.format_states() == \
         sync.device_windows.format_states()
-    assert par.pipelined_fused_chunks > 0, "two-phase path never engaged"
-    if depth >= 2:
-        assert par.drain_resolve_overlap_ms_ewma is not None, (
-            "resolve-ahead never overlapped a replay"
-        )
+    assert par.pipelined_fused_chunks > 0, "fused path never engaged"
 
 
-def test_depth2_with_stale_and_overflow(small_shards):
-    """Staleness masks and overflow fallbacks composed with the depth-2
-    resolve-ahead: all-matching bursts (candidate overflow → classic
-    mid-pipeline replay) plus lines that age out in flight, vs the same
-    stream drained at depth 1."""
+def test_multichunk_overflow_mid_batch_byte_identical(small_shards):
+    """Overflow in the middle of a multi-chunk batch: all-matching bursts
+    (candidate overflow → classic mid-pipeline replay, and the chain
+    scalar gating the chunks of the SAME batch dispatched behind it)
+    between benign runs, vs the same stream through the sync entry."""
     now = time.time()
     lines = []
     for burst in range(20):
@@ -289,49 +276,44 @@ def test_depth2_with_stale_and_overflow(small_shards):
         else:
             lines += _gen_lines(40, now, seed=200 + burst)
 
-    d1, _, _, d1_log = _build(
-        TpuMatcher, True, matcher_batch_lines=64, drain_resolve_depth=1,
-        matcher_prefilter_cand_frac=0.5, pallas_single_kernel="off",
+    sync, _, _, sync_log = _build(
+        TpuMatcher, True, matcher_batch_lines=64,
+        matcher_prefilter_cand_frac=0.5,
     )
-    d1_results, _ = _run_pipelined(
-        d1, lines, now, workers=0, sizer=BigSizer(seed=3)
+    sync_results = sync.consume_lines(lines, now_unix=now)
+
+    par, _, _, par_log = _build(
+        TpuMatcher, True, matcher_batch_lines=64,
+        matcher_prefilter_cand_frac=0.5,
+    )
+    par_results, _ = _run_pipelined(
+        par, lines, now, workers=0, sizer=BigSizer(seed=3)
     )
 
-    d2, _, _, d2_log = _build(
-        TpuMatcher, True, matcher_batch_lines=64, drain_resolve_depth=2,
-        matcher_prefilter_cand_frac=0.5, pallas_single_kernel="off",
+    assert [result_key(r) for r in par_results] == \
+        [result_key(r) for r in sync_results]
+    assert par_log.getvalue() == sync_log.getvalue()
+    assert par.device_windows.format_states() == \
+        sync.device_windows.format_states()
+    assert par.pipelined_fused_fallbacks > 0, (
+        "overflow fallback never exercised"
     )
-    d2_results, _ = _run_pipelined(
-        d2, lines, now, workers=0, sizer=BigSizer(seed=3)
+    assert par._fw_pipeline.overflow_causes["chain"] > 0, (
+        "no chunk was gated by a predecessor's overflow"
     )
 
-    assert [result_key(r) for r in d2_results] == \
-        [result_key(r) for r in d1_results]
-    assert d2_log.getvalue() == d1_log.getvalue()
-    assert d2.device_windows.format_states() == \
-        d1.device_windows.format_states()
-    assert d2.pipelined_fused_fallbacks > 0, (
-        "overflow fallback never exercised under depth-2"
-    )
-    assert d2.pipelined_fused_chunks > 0
 
-
-def test_depth2_drain_stale_masks_per_chunk():
-    """Drain-time staleness under resolve-ahead: a multi-chunk batch
-    whose chunks are fully-stale (abandoned mid-window), mixed, and
-    fully-fresh — driven through the split protocol directly so the
-    drain happens 3 s after encode.  Per-chunk live masks must compose
-    with the deferred commits exactly as at depth 1."""
+def test_submit_stale_masks_per_chunk():
+    """Staleness at the commit of a multi-chunk batch: chunks that are
+    fully stale, mixed, and fully fresh — driven through the split
+    protocol directly so the submit happens 3 s after encode.  Each
+    chunk carries its own live mask into its program."""
     now = time.time()
-    # two-program path pinned: resolve-ahead + drain-time live masks are
-    # ITS machinery (the single-kernel path commits at submit and takes
-    # the staleness cut there)
     m, _, _, ban_log = _build(
         TpuMatcher, True,
-        matcher_batch_lines=64, drain_resolve_depth=2,
-        matcher_prefilter_cand_frac=1.0, pallas_single_kernel="off",
+        matcher_batch_lines=64, matcher_prefilter_cand_frac=1.0,
     )
-    # chunk 0: all stale at drain; chunk 1: half and half; chunk 2: fresh
+    # chunk 0: all stale at submit; chunk 1: half and half; chunk 2: fresh
     old = [
         f"{now - 8:f} 9.9.{i >> 8}.{i & 255} GET per-site.com GET /blockme HTTP/1.1 ua -"
         for i in range(96)
@@ -343,7 +325,7 @@ def test_depth2_drain_stale_masks_per_chunk():
     lines = old + fresh
     state = m.pipeline_begin(lines, now)
     assert state.get("fused_eligible")
-    m.pipeline_submit(state)
+    m.pipeline_submit(state, now=now + 3)
     assert state.get("fused") and len(state["fused"]) == 3
     m.pipeline_collect(state)
     results, n_stale = m.pipeline_finish(state, now + 3)
@@ -354,9 +336,9 @@ def test_depth2_drain_stale_masks_per_chunk():
     assert "9.9.0.0" not in view and "8.8.0.0" in view
     assert ban_log.getvalue().count("instant block") == 96
     # later batches still drain (no leaked order turns from the
-    # abandoned fully-stale chunk)
+    # fully-stale chunk)
     state2 = m.pipeline_begin(fresh, now)
-    m.pipeline_submit(state2)
+    m.pipeline_submit(state2, now=now)
     m.pipeline_collect(state2)
     results2, _ = m.pipeline_finish(state2, now)
     assert all(r.rule_results for r in results2)

@@ -1,6 +1,8 @@
 """Differential proofs for the mega-state tiering (README "Mega-state
 tiering"): the warm tier is a lossless state home and the slot-admission
-gate never changes WHAT gets banned, on BOTH fused device protocols.
+gate never changes WHAT gets banned, on the fused single kernel and on
+the classic bitmap protocol (the path a failing scan selftest or a
+host-evaluated rule takes: tests/classic_downgrade.py).
 
   * admission OFF + warm tier ON is byte-identical to the ungated
     engine (same ban-log bytes, same per-line result stream, same final
@@ -34,6 +36,7 @@ from banjax_tpu.decisions.static_lists import StaticDecisionLists
 from banjax_tpu.effectors.banner import Banner
 from banjax_tpu.matcher.runner import TpuMatcher
 from banjax_tpu.pipeline import PipelineScheduler
+from tests.classic_downgrade import scan_selftest_failing
 from tests.differential.test_pipeline_differential import ChurnSizer
 from tests.differential.test_tpu_matcher import CONFIG_YAML, result_key
 
@@ -105,13 +108,16 @@ def _build(admission, warm, single_kernel):
     config.slot_admission_min_estimate = MIN_EST
     config.warm_tier_enabled = warm
     config.warm_tier_capacity = 4096
-    config.pallas_single_kernel = "auto" if single_kernel else "off"
     states = RegexRateLimitStates()
     ban_log = io.StringIO()
     dyn = DynamicDecisionLists(start_sweeper=False)
     banner = Banner(dyn, ban_log, io.StringIO(), ipset_instance=None)
-    matcher = TpuMatcher(
-        config, banner, StaticDecisionLists(config), states
+    with scan_selftest_failing(not single_kernel):
+        matcher = TpuMatcher(
+            config, banner, StaticDecisionLists(config), states
+        )
+    assert matcher.describe()["fused_protocol"] == (
+        "single-kernel" if single_kernel else "classic"
     )
     return matcher, ban_log
 

@@ -1,12 +1,12 @@
-"""Single-kernel fused path vs the two-program oracle: byte-identical
-(this PR's tentpole contract).
+"""Single-kernel fused path vs the classic bitmap protocol: byte-identical.
 
-The single-kernel mode (pallas_single_kernel, kernels/
-fused_match_window.py) collapses the fused path's two device programs —
-and the host resolve between them — into one dispatch whose overflow
-handling is gated in-kernel and whose window commit happens at submit.
-These tests prove the collapse changes NOTHING observable: for the same
-stimulus, single-kernel == two-program == CPU reference on
+The fused path (kernels/fused_match_window.py) is one dispatch per chunk
+whose overflow handling is gated in-kernel and whose window commit
+happens at submit; the classic protocol pulls a dense bitmap and applies
+the windows at the drain.  The classic side is reached the way the
+product reaches it: a failing scan selftest (tests/classic_downgrade.py).
+These tests prove the two differ in NOTHING observable: for the same
+stimulus, single-kernel == classic == CPU reference on
 
   * the per-line result stream (victim/refusal sequences),
   * ban-log bytes,
@@ -31,6 +31,7 @@ from banjax_tpu.matcher.cpu_ref import CpuMatcher
 from banjax_tpu.matcher.runner import TpuMatcher
 from banjax_tpu.pipeline import PipelineScheduler
 from banjax_tpu.resilience import failpoints
+from tests.classic_downgrade import scan_selftest_failing
 from tests.differential.test_pipeline_differential import ChurnSizer, _gen_lines
 from tests.differential.test_tpu_matcher import CONFIG_YAML, result_key
 
@@ -56,11 +57,12 @@ def _build(matcher_cls, **cfg_overrides):
 
 
 def _pair(**cfg):
-    """(single-kernel matcher, two-program matcher) with identical cfg."""
-    sk = _build(TpuMatcher, pallas_single_kernel="on", **cfg)
-    tp = _build(TpuMatcher, pallas_single_kernel="off", **cfg)
-    assert sk[0]._fw_pipeline is not None and sk[0]._fw_pipeline.single_kernel
-    assert tp[0]._fw_pipeline is not None and not tp[0]._fw_pipeline.single_kernel
+    """(single-kernel matcher, classic-protocol matcher), identical cfg."""
+    sk = _build(TpuMatcher, **cfg)
+    with scan_selftest_failing():
+        tp = _build(TpuMatcher, **cfg)
+    assert sk[0].describe()["fused_protocol"] == "single-kernel"
+    assert tp[0].describe()["fused_protocol"] == "classic"
     return sk, tp
 
 
@@ -100,7 +102,7 @@ def _assert_identical(tag, a_results, b_results, a, b):
 
 def test_churn_stream_byte_identical_and_cpu_exact():
     """Adversarial batch churn with shared IPs crossing chunk boundaries
-    plus a CPU-reference anchor: single-kernel == two-program == CPU."""
+    plus a CPU-reference anchor: single-kernel == classic == CPU."""
     now = time.time()
     lines = _gen_lines(1500, now)
 
@@ -120,7 +122,7 @@ def test_churn_stream_byte_identical_and_cpu_exact():
 
 def test_eviction_churn_byte_identical():
     """Slot capacity far below the distinct-IP load: spill/restore churn
-    under both modes stays lossless and identical."""
+    under both protocols stays lossless and identical."""
     now = time.time()
     lines = _gen_lines(900, now, seed=19)
     sk, tp = _pair(matcher_window_capacity=16, matcher_batch_lines=64,
@@ -189,8 +191,8 @@ def test_mixed_path_batches_keep_window_order():
 
 def test_mid_pipeline_staleness_identical():
     """Lines fresh at encode but past the 10 s cutoff at commit: the
-    single-kernel path cuts at submit (live-mask input), the two-program
-    path at its drain resolve — same observable drop, same surviving
+    single-kernel path cuts at submit (live-mask input), the classic
+    protocol at its drain — same observable drop, same surviving
     commits, driven through the split protocol directly so both clocks
     are pinned to the same instant."""
     now = time.time()
@@ -224,7 +226,7 @@ def test_mid_pipeline_staleness_identical():
 
 def test_breaker_trip_mid_stream_identical():
     """Phase 2 runs with the breaker OPEN (CPU reference drain), then the
-    breaker recovers: both modes route the same batches to the same
+    breaker recovers: both protocols route the same batches to the same
     paths, so the streams stay identical end to end."""
     now = time.time()
     phase1 = _gen_lines(300, now, seed=41)

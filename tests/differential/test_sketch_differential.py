@@ -1,8 +1,8 @@
 """Differential proof that the traffic sketch is read-only telemetry:
 a pipelined run with the sketch enabled produces byte-identical
 ban-log / result-stream / window-state output to a run with it
-disabled, under adversarial batch churn, on BOTH fused device
-protocols — and the enabled run actually populated the sketch (the
+disabled, under adversarial batch churn, on the fused single kernel and
+on the classic bitmap protocol — and the enabled run actually populated the sketch (the
 non-vacuity witness, ISSUE 8)."""
 
 import io
@@ -19,6 +19,7 @@ from banjax_tpu.decisions.static_lists import StaticDecisionLists
 from banjax_tpu.effectors.banner import Banner
 from banjax_tpu.matcher.runner import TpuMatcher
 from banjax_tpu.pipeline import PipelineScheduler
+from tests.classic_downgrade import scan_selftest_failing
 from tests.differential.test_pipeline_differential import (
     ChurnSizer,
     _gen_lines,
@@ -30,13 +31,16 @@ def _build(sketch_on: bool, single_kernel: bool):
     config = config_from_yaml_text(CONFIG_YAML)
     config.matcher_device_windows = True
     config.traffic_sketch_enabled = sketch_on
-    config.pallas_single_kernel = "auto" if single_kernel else "off"
     states = RegexRateLimitStates()
     ban_log = io.StringIO()
     dyn = DynamicDecisionLists(start_sweeper=False)
     banner = Banner(dyn, ban_log, io.StringIO(), ipset_instance=None)
-    matcher = TpuMatcher(
-        config, banner, StaticDecisionLists(config), states
+    with scan_selftest_failing(not single_kernel):
+        matcher = TpuMatcher(
+            config, banner, StaticDecisionLists(config), states
+        )
+    assert matcher.describe()["fused_protocol"] == (
+        "single-kernel" if single_kernel else "classic"
     )
     return matcher, states, ban_log
 
@@ -79,8 +83,10 @@ def _run_pipelined(lines, now, seed, sketch_on, single_kernel):
 
 @pytest.mark.parametrize("single_kernel", [True, False])
 def test_sketch_on_off_byte_identical(single_kernel):
-    """Both fused device protocols: single-kernel (commit at submit —
-    where the sketch update rides) and the two-program oracle path."""
+    """Both device protocols: single-kernel (commit at submit — where
+    the sketch update rides) and the classic bitmap protocol (the sketch
+    folds at the drain's window apply), reached by the scan-selftest
+    downgrade."""
     now = time.time()
     lines = _gen_lines(1200, now)
 

@@ -115,11 +115,10 @@ def test_synthetic_run_records_all_five_stages_consistently():
             assert by_id[s["parent_id"]]["name"] == "admission", s
         if s["name"] == "encode-shard":
             assert by_id[s["parent_id"]]["name"] == "encode", s
-        if s["name"] in ("program-a", "program-ab-fused"):
-            # the single-kernel path's one fused span replaces the
-            # program-a/program-b pair; both belong to the submit stage
+        if s["name"] == "program-ab-fused":
+            # the fused program's dispatch belongs to the submit stage
             assert by_id[s["parent_id"]]["name"] == "submit", s
-        if s["name"] in ("program-b", "effector-replay"):
+        if s["name"] == "effector-replay":
             assert by_id[s["parent_id"]]["name"] == "drain", s
 
     # every traced batch has exactly one root whose stages share its id

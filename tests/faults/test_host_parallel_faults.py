@@ -126,7 +126,7 @@ def test_encode_shard_failpoint_every_batch_still_no_loss():
 
 
 def test_sharded_encode_with_device_windows_accounts():
-    """Sharded encode feeding the fused two-phase path under churny
+    """Sharded encode feeding the fused path under churny
     small batches: accounting holds and effects all fire."""
     m, banner = build(device_windows=True)
     failpoints.arm("pipeline.encode_shard", count=1)
@@ -136,15 +136,14 @@ def test_sharded_encode_with_device_windows_accounts():
     assert len(banner.regex_ban_logs) == len(lines)
 
 
-def test_resolve_ahead_abort_frees_turns():
-    """matcher.resolve armed mid-stream under the depth-2 drain: the
-    aborted chunk's lines are marked error, but its order turns are
-    swept (fused_windows dead-turn sweep) so every later chunk and batch
-    keeps draining — a leaked turn would hang the flush."""
+def test_resolve_abort_frees_turns():
+    """matcher.resolve armed mid-stream: the aborted chunk's lines are
+    marked error, but its order turn is swept (fused_windows dead-turn
+    sweep) so every later chunk and batch keeps draining — a leaked turn
+    would hang the flush."""
     m, banner = build(
         device_windows=True,
         matcher_batch_lines=64,
-        drain_resolve_depth=2,
         matcher_prefilter_cand_frac=1.0,
     )
     failpoints.arm("matcher.resolve", count=3)
@@ -161,14 +160,13 @@ def test_resolve_ahead_abort_frees_turns():
     assert m._fw_pipeline.idle()
 
 
-def test_resolve_ahead_abort_then_recovery_depth2():
+def test_resolve_abort_then_recovery():
     """After mid-pipeline resolve aborts, the SAME matcher keeps
-    committing two-phase chunks at depth 2 (turn counters advanced past
-    the dead seqs)."""
+    committing fused chunks (the turn counter advanced past the dead
+    seqs)."""
     m, _ = build(
         device_windows=True,
         matcher_batch_lines=64,
-        drain_resolve_depth=2,
         matcher_prefilter_cand_frac=1.0,
     )
     failpoints.arm("matcher.resolve", count=2)
@@ -179,7 +177,7 @@ def test_resolve_ahead_abort_then_recovery_depth2():
     assert_accounted(sched, sink, lines)
     assert all(not r.error for r in sink.results)
     assert m.pipelined_fused_chunks > before, (
-        "two-phase path did not recover after the aborts"
+        "fused path did not recover after the aborts"
     )
     assert m._fw_pipeline.idle()
 

@@ -175,12 +175,12 @@ def test_overload_shed_plus_collect_fault_still_accounts():
     assert sched.stats.shed_lines > 0
 
 
-class TestFusedTwoPhaseFaults:
+class TestFusedFaults:
     """The same no-silent-loss contract with the fused matcher+windows
-    two-phase path active (device windows on → program A at submit, the
-    window commit at drain).  The extra hazard class here is LEAKED ORDER
-    TURNS: a chunk whose apply never runs must free its resolve/collect
-    turns and slot pins, or every later fused drain deadlocks — which
+    path active (device windows on → match and window commit at submit,
+    pull and replay at drain).  The extra hazard class here is LEAKED
+    ORDER TURNS: a chunk whose events are never collected must free its
+    turn and slot pins, or every later fused drain deadlocks — which
     these streams would surface as a flush() timeout."""
 
     def test_fused_stream_accounts_and_engages(self):
@@ -188,7 +188,7 @@ class TestFusedTwoPhaseFaults:
         lines, sink, sched = run_stream(m)
         assert_accounted(sched, sink, lines)
         assert sched.stats.processed_lines == len(lines)
-        # the two-phase path ran (commit or counted overflow fallback)
+        # the fused path ran (commit or counted overflow fallback)
         assert m.pipelined_fused_chunks + m.pipelined_fused_fallbacks > 0
         assert len(banner.regex_ban_logs) == len(lines)
 
@@ -244,7 +244,7 @@ class TestFusedTwoPhaseFaults:
 
     def test_drain_failpoint_under_fused_path_frees_turns(self):
         """pipeline.drain fires before pipeline_finish: the batch's
-        two-phase chunks are settled by pipeline_abort — the stream after
+        fused chunks are settled by pipeline_abort — the stream after
         the failed batch still drains (no leaked turn deadlock)."""
         m, _ = build(device_windows=True)
         failpoints.arm("pipeline.drain", count=2)

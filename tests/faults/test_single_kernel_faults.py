@@ -1,14 +1,13 @@
 """Fault composition for the single-kernel fused path + the state-aware
 turn-release fix (fused_windows._free_turn / _release_chunk_pins).
 
-The bug being regression-locked: abandon() used to sweep dead turns for
-both _resolve_seq and _collect_seq unconditionally, so a chunk settled
-by two paths (a submit-failure abandon racing a teardown abort, or an
-abandon after fallback_done) could mark the same turn dead twice and
-double-release slot pins — the double pin release can free a pin held
-by a DIFFERENT in-flight chunk on the same slot.  Settlement is now
-tracked per chunk (pins_released / turns_freed) so every path is
-idempotent."""
+The bug being regression-locked: abandon() used to sweep dead turns
+unconditionally, so a chunk settled by two paths (a submit-failure
+abandon racing a teardown abort, or an abandon after fallback_done)
+could mark the same turn dead twice and double-release slot pins — the
+double pin release can free a pin held by a DIFFERENT in-flight chunk on
+the same slot.  Settlement is tracked per chunk (pins_released /
+turn_freed) so every path is idempotent."""
 
 import threading
 import time
@@ -72,25 +71,19 @@ def mixed_lines(now, n):
 def _quiescent(fw):
     """Every turn settled, no dead-turn residue, no leaked pins."""
     with fw._cv:
-        assert fw._next_seq == fw._resolve_seq == fw._collect_seq, (
-            fw._next_seq, fw._resolve_seq, fw._collect_seq,
-        )
-        assert not fw._dead["_resolve_seq"] and not fw._dead["_collect_seq"], (
-            fw._dead,
-        )
+        assert fw._next_seq == fw._turn, (fw._next_seq, fw._turn)
+        assert not fw._dead, fw._dead
     assert (fw.windows._pin_counts == 0).all()
 
 
-@pytest.mark.parametrize("single_kernel", ["on", "off"])
-def test_submit_failpoint_settles_turns_once(single_kernel):
+def test_submit_failpoint_settles_turns_once():
     """pipeline.submit fires mid-stream: the failed batch drains
     generically (classic path, no fused turns), LATER fused batches keep
     committing, and the turn counters/dead sets/pins settle exactly —
     the double-sweep would leave dead-set residue or negative-clamped
     pins behind."""
     now = time.time()
-    m, _ = make_matcher(pallas_single_kernel=single_kernel,
-                        matcher_prefilter_cand_frac=1.0)
+    m, _ = make_matcher(matcher_prefilter_cand_frac=1.0)
     collected = []
     lock = threading.Lock()
 
@@ -119,14 +112,13 @@ def test_submit_failpoint_settles_turns_once(single_kernel):
     _quiescent(m._fw_pipeline)
 
 
-@pytest.mark.parametrize("single_kernel", ["on", "off"])
-def test_double_abort_is_idempotent(single_kernel):
+def test_double_abort_is_idempotent():
     """pipeline_abort called twice on the same un-finished batch (a
     device-failure abort racing a drain-failure abort does exactly this)
     must settle each chunk's turns and pins once; a later batch then
     drains normally."""
     now = time.time()
-    m, _ = make_matcher(pallas_single_kernel=single_kernel)
+    m, _ = make_matcher()
     s1 = m.pipeline_begin(lines_at(now, 30), now)
     m.pipeline_submit(s1, now=now)
     entries = list(s1.get("fused") or [])
@@ -157,8 +149,7 @@ def test_abandon_after_fallback_cannot_double_release_pins():
     now = time.time()
     # cand_frac 1/64 + all-matching lines: every chunk overflows
     m, _ = make_matcher(
-        pallas_single_kernel="on", matcher_batch_lines=64,
-        matcher_prefilter_cand_frac=1.0 / 64,
+        matcher_batch_lines=64, matcher_prefilter_cand_frac=1.0 / 64,
     )
     lines = [
         f"{now:.6f} 5.5.5.{i % 7} GET h.com GET /attack{i} HTTP/1.1 ua -"
@@ -181,7 +172,7 @@ def test_resolve_failpoint_under_single_kernel_loses_only_its_chunk():
     only that chunk's lines as errors; later batches drain fine (turns
     freed by the state-aware settlement)."""
     now = time.time()
-    m, _ = make_matcher(pallas_single_kernel="on")
+    m, _ = make_matcher()
     failpoints.arm("matcher.resolve", count=1)
     s1 = m.pipeline_begin(lines_at(now, 20), now)
     m.pipeline_submit(s1, now=now)

@@ -2,9 +2,9 @@
 
 Five configs (BASELINE.md "Measurement ladder"), each timed and asserted
 against a conservative CPU floor so a perf regression fails CI instead of
-passing silently (VERDICT r1 weak #6). Full-scale numbers come from
-bench.py on the real chip; here the shapes are identical but line counts
-are CI-sized unless BANJAX_PERF_FULL=1.
+passing silently (VERDICT r1 weak #6). Numbers of the real chip come from
+`benchmark/run.py` (PERF_LEDGER.jsonl); here the shapes are identical but
+line counts are CI-sized unless BANJAX_PERF_FULL=1.
 
 Every config prints one JSON line {"config": N, "lines_per_sec": ...} so CI
 logs double as a coarse perf history.
@@ -32,8 +32,8 @@ FIXTURES = Path(__file__).resolve().parents[1] / "fixtures"
 # the r3 measured CPU numbers (42.9k / 10.3k / 3.8k / 2.9k / 2.4k) — loose
 # enough for ~3x CI-machine variance, tight enough that an accidental
 # per-line recompile or a lost vectorized replay path fails CI. TPU floors
-# apply when the attached backend is really a TPU (bench.py's ladder on
-# hardware): config 1 is the serial CPU reference either way.
+# apply when the attached backend is really a TPU: config 1 is the serial
+# CPU reference either way.
 # config1 is measured in a fresh subprocess (it was the one config whose
 # floor full-suite jit-cache/GC pressure could sink — isolation restores
 # the honest 14k floor instead of loosening it)
@@ -202,7 +202,7 @@ def test_config3_1k_rules_batch():
     the NFA compile + batch-match stress, via the production TpuMatcher."""
     import yaml as _yaml
 
-    from bench import generate_lines, generate_rules
+    from banjax_tpu.scenarios.synth import generate_lines, generate_rules
 
     patterns = generate_rules(1000)
     rules_yaml = _yaml.safe_dump({
@@ -218,7 +218,7 @@ def test_config3_1k_rules_batch():
     rests = generate_lines(n, patterns)
     lines = [f"{now:.6f} 10.0.{i % 256}.{(i >> 8) % 256} {r}"
              for i, r in enumerate(rests)]
-    # warm the jit caches before timing (compile time is reported by bench.py)
+    # warm the jit caches before timing
     m.consume_lines(lines[:256], now)
     _report(3, n, _drive(m, lines, now))
 

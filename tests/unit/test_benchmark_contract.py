@@ -1,0 +1,156 @@
+"""The benchmark's hold on the program, as a tier-1 test.
+
+`benchmark/` reads the product through names: `/metrics` families, host
+span names, keys of `TpuMatcher.describe()`, keys of the product config.
+A per-layer reader returns nothing where the program lacks the counter
+(PERF.md §3) and says so nowhere, so a refactor that renames one of these
+would blind a metric in silence.  This file reads `benchmark/` and
+`BENCHMARK.json`, edits nothing there, and fails here instead."""
+
+import glob
+import json
+import os
+import re
+import time
+
+import pytest
+import yaml
+
+from banjax_tpu.config.schema import config_from_yaml_text
+from banjax_tpu.decisions.rate_limit import RegexRateLimitStates
+from banjax_tpu.decisions.static_lists import StaticDecisionLists
+from banjax_tpu.matcher.runner import TpuMatcher
+from banjax_tpu.obs import registry, trace
+from banjax_tpu.pipeline import PipelineScheduler
+from tests.mock_banner import MockBanner
+
+_REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+_BENCH = os.path.join(_REPO, "benchmark")
+
+# `banjax_*` words in the benchmark's sources that are no metric: the
+# package's name and nginx's log format
+_NOT_METRICS = {"banjax_tpu", "banjax_format"}
+
+# product-config keys a configuration file may still carry though the
+# schema no longer knows them (PR 30 removed the three protocol options;
+# the loader ignores unknown keys, and the files may not be edited)
+_REMOVED_KEYS = {"pallas_single_kernel", "pipeline_fused", "drain_resolve_depth"}
+
+
+def _families_read():
+    names = set()
+    for sub in ("layers", "harness"):
+        for path in sorted(glob.glob(os.path.join(_BENCH, sub, "*.py"))):
+            with open(path, encoding="utf-8") as f:
+                names.update(re.findall(r"banjax_[a-z0-9_]+", f.read()))
+    return sorted(names - _NOT_METRICS)
+
+
+def _host_spans():
+    with open(os.path.join(_BENCH, "trace_names.json"), encoding="utf-8") as f:
+        return json.load(f)["host_spans"]
+
+
+def _configurations():
+    with open(os.path.join(_REPO, "BENCHMARK.json"), encoding="utf-8") as f:
+        return [(c["name"], c["file"]) for c in json.load(f)["configs"]]
+
+
+def _configuration(rel):
+    with open(os.path.join(_REPO, rel), encoding="utf-8") as f:
+        return json.load(f)
+
+
+def test_the_benchmark_reads_some_families():
+    assert len(_families_read()) >= 11
+
+
+@pytest.mark.parametrize("name", _families_read())
+def test_family_the_benchmark_reads_is_declared(name):
+    """Counters and gauges by their own name, a histogram by the name of
+    one of its samples."""
+    declared = {f.prom: f.kind for f in registry.FAMILIES if f.prom}
+    if name in declared:
+        return
+    base = re.sub(r"_(sum|count|bucket)$", "", name)
+    assert declared.get(base) == registry.HISTOGRAM, (
+        f"benchmark/ reads {name}, which obs/registry.py does not declare: "
+        "the reader would return nothing"
+    )
+
+
+_RULES = yaml.safe_dump({"regexes_with_rates": [{
+    "rule": "r", "regex": "GET /attack.*", "interval": 5,
+    "hits_per_interval": 2, "decision": "nginx_block",
+}]})
+
+
+def _matcher():
+    cfg = config_from_yaml_text(_RULES)
+    cfg.matcher_device_windows = True
+    return TpuMatcher(cfg, MockBanner(), StaticDecisionLists(cfg),
+                      RegexRateLimitStates())
+
+
+@pytest.fixture(scope="module")
+def span_names():
+    """Span names of one small traced stream through the scheduler and
+    the fused matcher."""
+    tracer = trace.configure(enabled=True, ring_size=4096)
+    try:
+        now = time.time()
+        m = _matcher()
+        sched = PipelineScheduler(lambda: m, now_fn=lambda: now)
+        sched.start()
+        # mostly benign, so the chunk commits fused and does not
+        # overflow the candidate capacity
+        sched.submit([
+            f"{now:.6f} 1.2.3.{i % 5} GET h.com GET "
+            f"/{'attack' if i % 13 == 0 else 'page'}{i} HTTP/1.1 ua -"
+            for i in range(40)
+        ])
+        assert sched.flush(120)
+        sched.stop()
+        assert m.pipelined_fused_chunks > 0
+        return {s["name"] for s in tracer.snapshot()}
+    finally:
+        trace.configure(enabled=False)
+
+
+@pytest.mark.parametrize(
+    "span", _host_spans() + ["program-ab-fused", "effector-replay"]
+)
+def test_host_span_the_benchmark_names_is_opened(span_names, span):
+    """`benchmark/trace_names.json` `host_spans`, and the two documented
+    spans of the fused path beside them."""
+    assert span in span_names, sorted(span_names)
+
+
+@pytest.mark.parametrize("name,rel", _configurations())
+def test_expect_keys_are_keys_of_describe(name, rel):
+    """`correct` compares the configuration's `expect` with
+    `/healthz`'s matcher info (`TpuMatcher.describe()`), and reads
+    `downgrades` beside it."""
+    d = _matcher().describe()
+    want = set(_configuration(rel)["expect"]) | {
+        "fused_protocol", "prefilter", "downgrades",
+    }
+    assert want <= set(d), sorted(want - set(d))
+    assert d["fused_protocol"] in ("single-kernel", "classic")
+
+
+@pytest.mark.parametrize("name,rel", _configurations())
+def test_product_config_keys_are_known_to_the_schema(name, rel):
+    """The loader ignores a key it does not know.  A configuration that
+    sets one would run the default in silence: only the three protocol
+    keys removed in PR 30 may be left over, and the value a file gives
+    them is the one behaviour that is left."""
+    pc = _configuration(rel)["product_config"]
+    cfg = config_from_yaml_text(yaml.safe_dump(pc))
+    unknown = {k for k in pc if not hasattr(cfg, k)}
+    assert unknown <= _REMOVED_KEYS, sorted(unknown - _REMOVED_KEYS)
+    assert pc.get("pallas_single_kernel", "auto") == "auto"
+    assert pc.get("pipeline_fused", True) is True
+    for k in pc:
+        if k not in unknown and not isinstance(pc[k], (dict, list)):
+            assert getattr(cfg, k) == pc[k], k

@@ -402,27 +402,6 @@ def test_traffic_families_render_and_declare(loaded_system):
         assert registry.is_declared_line_key(key), key
 
 
-def test_single_kernel_depth_ignored_on_line_and_metrics(loaded_system):
-    """The PR 7 silent-ignore satellite: drain_resolve_depth configured
-    (default 2) + single-kernel active => the gauge flags the no-op on
-    both surfaces."""
-    m, sched, health, sup = loaded_system
-    if not (m._fw_pipeline is not None and m._fw_pipeline.single_kernel):
-        pytest.skip("single-kernel path unavailable on this backend")
-    line = _full_line(m, sched, health, sup)
-    assert line["SingleKernelDepthIgnored"] is True
-    assert registry.is_declared_line_key("SingleKernelDepthIgnored")
-    text = render_prometheus(
-        DynamicDecisionLists(start_sweeper=False), RegexRateLimitStates(),
-        FailedChallengeRateLimitStates(), matcher=m,
-    )
-    fams = parse_text_format(text)
-    (v,) = [
-        s[2] for s in fams["banjax_single_kernel_depth_ignored"]["samples"]
-    ]
-    assert v == 1
-
-
 def test_challenge_families_render_and_declare():
     """The ISSUE 17 families: drive the real challenge plane — stateless
     issuance, an accepted device-path verification, a rejected one, and

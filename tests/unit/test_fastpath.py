@@ -1,9 +1,8 @@
 """AuthFastPath unit coverage: gates, memos, fail-open plumbing.
 
 Byte-identity against the chain is proven end to end by the integration
-differential (tests/integration/test_fastpath_differential.py) and the
-bench witness (`bench.py --serve`); this file pins the pieces those
-drive through: the eligibility gates and miss reasons, the per-
+differential (tests/integration/test_fastpath_differential.py); this
+file pins the pieces it drives through: the eligibility gates and miss reasons, the per-
 generation memo caches (session validation, QueryUnescape, global-list
 probes) and their bounds/invalidation, and the fail-open exits.
 """

@@ -1,12 +1,13 @@
 """Single-kernel fused match+window path (matcher/kernels/
-fused_match_window.py + the `single_kernel` dispatch mode of
-matcher/fused_windows.py), interpret-mode on CPU — tier-1.
+fused_match_window.py + matcher/fused_windows.py), interpret-mode on
+CPU — tier-1.
 
 Covers the kernel itself (the Pallas window-scan vs the lax.scan it must
 reproduce bit-for-bit), the threshold-fire edges of the fixed-window
 recurrence, the in-kernel overflow flag routing to the classic fallback,
 the submit-time live-mask staleness cut, chain reseeding after an
-overflow burst, and the config key's auto/on/off resolution."""
+overflow burst, the scan selftest's downgrade to the classic protocol,
+and config files that still carry the three removed protocol keys."""
 
 import time
 
@@ -16,7 +17,6 @@ import numpy as np
 import pytest
 import yaml
 
-import bench
 from banjax_tpu.config.schema import config_from_yaml_text
 from banjax_tpu.decisions.rate_limit import RegexRateLimitStates
 from banjax_tpu.decisions.static_lists import StaticDecisionLists
@@ -25,6 +25,7 @@ from banjax_tpu.matcher.cpu_ref import CpuMatcher
 from banjax_tpu.matcher.kernels import fused_match_window as fmw
 from banjax_tpu.matcher.runner import TpuMatcher
 from banjax_tpu.resilience.health import HealthRegistry, HealthStatus
+from banjax_tpu.scenarios import synth
 from tests.mock_banner import MockBanner
 
 
@@ -125,7 +126,7 @@ def _edge_pair(interval, hits):
     cpu, cb = _mk(CpuMatcher, y)
     tpu, tb = _mk(TpuMatcher, y, matcher_device_windows=True,
                   matcher_prefilter_cand_frac=1.0)
-    assert tpu._fw_pipeline is not None and tpu._fw_pipeline.single_kernel
+    assert tpu.describe()["fused_protocol"] == "single-kernel"
     return cpu, cb, tpu, tb
 
 
@@ -168,9 +169,9 @@ def test_event_overflow_flag_routes_to_classic_fallback(monkeypatch):
     from banjax_tpu.matcher import prefilter
 
     monkeypatch.setattr(prefilter, "_MAX_EVENT_CAPACITY", 64)
-    patterns = bench.generate_rules(30, seed=33) + [r".*"]
+    patterns = synth.generate_rules(30, seed=33) + [r".*"]
     now = time.time()
-    rests = bench.generate_lines(256, patterns[:-1], seed=3, attack_rate=0.1)
+    rests = synth.generate_lines(256, patterns[:-1], seed=3, attack_rate=0.1)
     lines = [
         f"{now + i * 0.0005:.6f} 10.9.{i % 24}.1 {r}"
         for i, r in enumerate(rests)
@@ -179,7 +180,7 @@ def test_event_overflow_flag_routes_to_classic_fallback(monkeypatch):
     cpu, cb = _mk(CpuMatcher, y)
     tpu, tb = _mk(TpuMatcher, y, matcher_device_windows=True,
                   matcher_batch_lines=256, matcher_prefilter_cand_frac=1.0)
-    assert tpu._fw_pipeline.single_kernel
+    assert tpu.describe()["fused_protocol"] == "single-kernel"
     tpu.device_windows.max_events = max(tpu.compiled.n_rules, 64)
     want = [cpu.consume_line(l, now + 1) for l in lines]
     got = tpu.consume_lines(lines, now + 1)
@@ -194,9 +195,9 @@ def test_candidate_overflow_flag_with_tight_slot_capacity():
     too small for the distinct-IP load (eviction churn + split retries):
     the overflow flag routes to the single-stage recompute and spill
     stays lossless — byte-identical to the oracle."""
-    patterns = bench.generate_rules(20, seed=36)
+    patterns = synth.generate_rules(20, seed=36)
     now = time.time()
-    rests = bench.generate_lines(300, patterns, seed=10, attack_rate=1.0)
+    rests = synth.generate_lines(300, patterns, seed=10, attack_rate=1.0)
     lines = [
         f"{now + i * 0.0005:.6f} 10.9.{i % 90}.1 {r}"
         for i, r in enumerate(rests)
@@ -208,7 +209,7 @@ def test_candidate_overflow_flag_with_tight_slot_capacity():
         matcher_batch_lines=64, matcher_prefilter_cand_frac=1.0 / 64,
         matcher_window_capacity=16,
     )
-    assert tpu._fw_pipeline.single_kernel
+    assert tpu.describe()["fused_protocol"] == "single-kernel"
     want = [cpu.consume_line(l, now + 1) for l in lines]
     got = tpu.consume_lines(lines, now + 1)
     assert [_key(a) for a in want] == [_key(b) for b in got]
@@ -229,7 +230,7 @@ def test_chain_reseeds_after_quiescence():
     tpu, _ = _mk(TpuMatcher, y, matcher_device_windows=True,
                  matcher_batch_lines=64,
                  matcher_prefilter_cand_frac=1.0 / 64)
-    assert tpu._fw_pipeline.single_kernel
+    assert tpu.describe()["fused_protocol"] == "single-kernel"
     flood = [
         f"{now:.6f} 7.7.7.{i % 9} POST h.com POST /x{i} HTTP/1.1 ua -"
         for i in range(128)
@@ -261,7 +262,7 @@ def test_live_mask_staleness_at_submit():
     y = _rules_yaml(patterns, hits=0, interval=1)
     m, banner = _mk(TpuMatcher, y, matcher_device_windows=True,
                     matcher_prefilter_cand_frac=1.0)
-    assert m._fw_pipeline.single_kernel
+    assert m.describe()["fused_protocol"] == "single-kernel"
     old = [
         f"{now - 8:.6f} 9.9.9.{i} GET h.com GET /blockme HTTP/1.1 ua -"
         for i in range(5)
@@ -320,48 +321,114 @@ def test_fully_stale_chunk_commits_nothing():
 # ---------------------------------------------------------------------------
 
 
-def test_config_auto_engages_on_cpu_and_off_pins_two_program():
+def test_single_kernel_engages_on_cpu():
     y = _rules_yaml([r"GET /a.*"])
     health = HealthRegistry()
-    auto, _ = _mk(TpuMatcher, y, health=health,
-                  matcher_device_windows=True)
-    assert auto._fw_pipeline is not None
-    assert auto._fw_pipeline.single_kernel  # auto: interpret on CPU
+    m, _ = _mk(TpuMatcher, y, health=health, matcher_device_windows=True)
+    assert m._fw_pipeline is not None  # the scan runs interpreted on CPU
+    assert m.describe()["fused_protocol"] == "single-kernel"
     comp = health.get("matcher-single-kernel")
     assert comp is not None
     assert comp.effective_status()[0] == HealthStatus.HEALTHY
 
-    off, _ = _mk(TpuMatcher, y, matcher_device_windows=True,
-                 pallas_single_kernel="off")
-    assert off._fw_pipeline is not None
-    assert not off._fw_pipeline.single_kernel
+
+def _boom(*a, **k):
+    raise RuntimeError("synthetic lowering failure")
 
 
 def test_downgrade_leaves_health_note(monkeypatch):
     """A window-scan kernel that cannot lower must downgrade to the
-    two-program path and leave a DEGRADED note on the health registry —
+    classic protocol and leave a DEGRADED note on the health registry —
     never fail matcher construction."""
-    from banjax_tpu.matcher.kernels import fused_match_window
-
-    def boom(*a, **k):
-        raise RuntimeError("synthetic lowering failure")
-
-    monkeypatch.setattr(fused_match_window, "scan_selftest", boom)
+    monkeypatch.setattr(fmw, "scan_selftest", _boom)
     y = _rules_yaml([r"GET /a.*"])
     health = HealthRegistry()
-    m, _ = _mk(TpuMatcher, y, health=health, matcher_device_windows=True,
-               pallas_single_kernel="on")
-    assert m._fw_pipeline is not None
-    assert not m._fw_pipeline.single_kernel
+    m, _ = _mk(TpuMatcher, y, health=health, matcher_device_windows=True)
+    assert m._fw_pipeline is None
     comp = health.get("matcher-single-kernel")
     status, detail, _ = comp.effective_status()
     assert status == HealthStatus.DEGRADED
-    assert "two-program" in detail
+    assert "classic" in detail
 
 
-def test_config_validation_rejects_bad_value():
-    with pytest.raises(ValueError, match="pallas_single_kernel"):
-        config_from_yaml_text("pallas_single_kernel: maybe\n")
+def _drive_split(m, lines, now, batch):
+    """The scheduler's four calls, one batch at a time."""
+    out = []
+    for s in range(0, len(lines), batch):
+        state = m.pipeline_begin(lines[s : s + batch], now)
+        m.pipeline_submit(state, now=now)
+        m.pipeline_collect(state)
+        results, n_stale = m.pipeline_finish(state, now)
+        assert n_stale == 0
+        out.extend(results)
+    return out
+
+
+@pytest.mark.parametrize("entry", ["sync", "pipelined"])
+def test_selftest_failure_runs_the_classic_protocol_exactly(monkeypatch, entry):
+    """Scan selftest fails → `fused_protocol: classic`, exactly one
+    degraded note, and the ban log equals CpuMatcher's — through the sync
+    entry and through the scheduler's split protocol (where the classic
+    batch's window apply waits for its drain turn)."""
+    monkeypatch.setattr(fmw, "scan_selftest", _boom)
+    patterns = synth.generate_rules(25, seed=41) + [r".*"]
+    now = time.time()
+    rests = synth.generate_lines(320, patterns[:-1], seed=5, attack_rate=0.3)
+    lines = [
+        f"{now + i * 0.0005:.6f} 10.9.{i % 24}.1 {r}"
+        for i, r in enumerate(rests)
+    ]
+    y = _rules_yaml(patterns)
+    cpu, cb = _mk(CpuMatcher, y)
+    tpu, tb = _mk(TpuMatcher, y, matcher_device_windows=True,
+                  matcher_batch_lines=64)
+    d = tpu.describe()
+    assert d["fused_protocol"] == "classic" and d["scan_interpret"] is None
+    assert len(d["downgrades"]) == 1 and "classic" in d["downgrades"][0]
+    want = [cpu.consume_line(l, now + 1) for l in lines]
+    if entry == "sync":
+        got = []
+        for s in range(0, len(lines), 128):
+            got.extend(tpu.consume_lines(lines[s : s + 128], now + 1))
+    else:
+        got = _drive_split(tpu, lines, now + 1, 128)
+    assert [_key(a) for a in want] == [_key(b) for b in got]
+    assert cb.bans == tb.bans and len(cb.bans) > 0
+    assert cb.regex_ban_logs == tb.regex_ban_logs
+    assert tpu.pipelined_fused_chunks == 0
+    assert cpu.rate_limit_states.format_states() == \
+        tpu.device_windows.format_states()
+
+
+@pytest.mark.parametrize("key,value", [
+    ("pallas_single_kernel", "auto"),
+    ("pipeline_fused", False),
+    ("drain_resolve_depth", 3),
+])
+def test_config_file_with_a_removed_protocol_key_runs_the_single_kernel(
+    key, value
+):
+    """The three protocol options are gone from the schema; a file that
+    still carries one (both benchmark configurations carry
+    `pallas_single_kernel: auto`) loads — the loader ignores keys it does
+    not know — and the matcher runs the one fused protocol."""
+    doc = yaml.safe_load(_rules_yaml([r"GET /blockme.*"], hits=0, interval=1))
+    doc[key] = value
+    cfg = config_from_yaml_text(yaml.safe_dump(doc))
+    assert not hasattr(cfg, key)
+    cfg.matcher_device_windows = True
+    banner = MockBanner()
+    m = TpuMatcher(cfg, banner, StaticDecisionLists(cfg),
+                   RegexRateLimitStates())
+    assert m.describe()["fused_protocol"] == "single-kernel"
+    now = time.time()
+    lines = [
+        f"{now:.6f} 8.8.8.{i} GET h.com GET /blockme HTTP/1.1 ua -"
+        for i in range(4)
+    ]
+    results = _drive_split(m, lines, now, 4)
+    assert all(r.rule_results for r in results)
+    assert m.pipelined_fused_chunks == 1 and len(banner.bans) == 4
 
 
 # ---------------------------------------------------------------------------

@@ -9,12 +9,12 @@ import numpy as np
 import pytest
 import yaml
 
-import bench
 from banjax_tpu.config.schema import config_from_yaml_text
 from banjax_tpu.decisions.rate_limit import RegexRateLimitStates
 from banjax_tpu.decisions.static_lists import StaticDecisionLists
 from banjax_tpu.matcher.cpu_ref import CpuMatcher
 from banjax_tpu.matcher.runner import TpuMatcher
+from banjax_tpu.scenarios import synth
 from tests.mock_banner import MockBanner
 
 
@@ -61,7 +61,7 @@ def _drive_pair(patterns, lines, now, **tpu_overrides):
 
 
 def _lines(patterns, n, now, attack_rate, n_ips=24, seed=3):
-    rests = bench.generate_lines(n, patterns, seed=seed,
+    rests = synth.generate_lines(n, patterns, seed=seed,
                                  attack_rate=attack_rate)
     return [
         f"{now + i * 0.0005:.6f} 10.9.{i % n_ips}.1 {r}"
@@ -70,7 +70,7 @@ def _lines(patterns, n, now, attack_rate, n_ips=24, seed=3):
 
 
 def test_pipeline_engages_and_matches_oracle():
-    patterns = bench.generate_rules(60, seed=31) + [r".*", r"^$"]
+    patterns = synth.generate_rules(60, seed=31) + [r".*", r"^$"]
     now = time.time()
     lines = _lines(patterns[:-2], 300, now, attack_rate=0.05) + [
         f"{now:.6f} 10.9.0.1 "  # empty rest: ^$ matches
@@ -88,7 +88,7 @@ def test_candidate_overflow_falls_back_identically():
     """All-matching traffic exceeds the candidate capacity: the pipeline's
     dense bitmap is incomplete, so the batch recomputes single-stage and
     replays classic — output still identical, state never corrupted."""
-    patterns = bench.generate_rules(40, seed=32)
+    patterns = synth.generate_rules(40, seed=32)
     now = time.time()
     lines = _lines(patterns, 200, now, attack_rate=1.0)
     tpu = _drive_pair(
@@ -107,7 +107,7 @@ def test_event_overflow_falls_back_identically(monkeypatch):
     from banjax_tpu.matcher import prefilter
 
     monkeypatch.setattr(prefilter, "_MAX_EVENT_CAPACITY", 64)
-    patterns = bench.generate_rules(30, seed=33) + [r".*"]
+    patterns = synth.generate_rules(30, seed=33) + [r".*"]
     now = time.time()
     lines = _lines(patterns[:-1], 256, now, attack_rate=0.1)
     y = _rules_yaml(patterns)
@@ -129,7 +129,7 @@ def test_multi_chunk_burst_pipelines_identically():
     """One consume_lines call larger than matcher_batch_lines goes through
     the cross-chunk pipelined submit path (chunk N+1 in flight while N
     collects) — output identical to the serial reference."""
-    patterns = bench.generate_rules(30, seed=35)
+    patterns = synth.generate_rules(30, seed=35)
     now = time.time()
     lines = _lines(patterns, 400, now, attack_rate=0.1, n_ips=40, seed=9)
     y = _rules_yaml(patterns)
@@ -148,7 +148,7 @@ def test_multi_chunk_burst_pipelines_identically():
 def test_multi_chunk_with_tight_slot_capacity():
     """Pipelined chunks + a slot capacity too small for two chunks' pins:
     the drain-and-retry path must keep output identical."""
-    patterns = bench.generate_rules(20, seed=36)
+    patterns = synth.generate_rules(20, seed=36)
     now = time.time()
     lines = _lines(patterns, 300, now, attack_rate=0.2, n_ips=90, seed=10)
     y = _rules_yaml(patterns)
@@ -170,14 +170,14 @@ def test_mixed_overflow_chunks_keep_apply_order():
     apply, with the same IPs hitting the same rules across chunks. Any
     out-of-order window application shifts which exact hit trips the
     limit — the oracle comparison catches one event of reordering."""
-    patterns = bench.generate_rules(25, seed=37)
+    patterns = synth.generate_rules(25, seed=37)
     now = time.time()
     # alternate benign-ish and attack-heavy 64-line stretches so chunk
     # overflow status flips mid-burst, all on a small shared IP pool
     lines = []
     for stretch in range(6):
         rate = 1.0 if stretch % 2 else 0.05
-        rests = bench.generate_lines(64, patterns, seed=40 + stretch,
+        rests = synth.generate_lines(64, patterns, seed=40 + stretch,
                                      attack_rate=rate)
         for i, r in enumerate(rests):
             k = len(lines)
@@ -202,7 +202,7 @@ def test_mixed_overflow_chunks_keep_apply_order():
 
 def test_pipeline_with_eviction_churn():
     """Slot eviction/spill/restore under the pipeline stays lossless."""
-    patterns = bench.generate_rules(25, seed=34)
+    patterns = synth.generate_rules(25, seed=34)
     now = time.time()
     lines = _lines(patterns, 400, now, attack_rate=0.3, n_ips=60, seed=8)
     tpu = _drive_pair(
@@ -280,11 +280,8 @@ def test_jit_program_variants_stay_bounded():
         tpu.consume_lines(lines, now + i)
     fw = tpu._fw_pipeline
     assert fw is not None
-    counts = {
-        "pipeline_match_programs": len(fw._match_fns),
-        "pipeline_apply_programs": len(fw._apply_fns),
-    }
+    counts = {"pipeline_programs": len(fw._progs)}
     if tpu._prefilter is not None:
         counts["prefilter_programs"] = len(tpu._prefilter._fns)
-    assert counts["pipeline_match_programs"] > 0  # the soak really compiled
+    assert counts["pipeline_programs"] > 0  # the soak really compiled
     assert all(v <= 8 for v in counts.values()), counts

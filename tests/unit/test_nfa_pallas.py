@@ -4,8 +4,9 @@ The Pallas kernel (banjax_tpu/matcher/kernels/nfa_match.py) must produce a
 match bitmap identical to nfa_jax.match_batch for any compiled ruleset —
 that invariant is what lets TpuMatcher switch device backends without any
 observable Decision change. Tests run the kernel in interpret mode (plain
-JAX on the CPU backend); the compiled TPU path is exercised by bench.py on
-real hardware.
+JAX on the CPU backend); tests/unit/test_tpu_compile.py compiles the
+kernel for a described v5e, and `chip_smoke.py` and the benchmark run it
+on the chip.
 """
 
 import random
@@ -245,8 +246,7 @@ class TestRunnerBackend:
 def test_word_align_32_and_128_agree(monkeypatch):
     """The sub-lane (32) and conservative lane (128) shard paddings produce
     identical match bitmaps — the padding is dead words only (interpret
-    mode; the compiled-Mosaic tiling of the 32-row slabs is verified on
-    hardware by bench.py's pallas parity assert)."""
+    mode)."""
     from banjax_tpu.matcher import rulec as rulec_mod
     from banjax_tpu.matcher.kernels import nfa_match as nm
 

@@ -141,15 +141,15 @@ def test_fused_mesh_prefilter_parity(backend, dp, rp):
     two-stage sharded matcher must be bit-identical to the single-stage
     sharded matcher (and to Python re) on a filterable ruleset, including
     always-rules and empty lines."""
-    import bench as _bench
+    from banjax_tpu.scenarios import synth
 
     from banjax_tpu.matcher.prefilter import build_plan
     from banjax_tpu.parallel.mesh import ShardedMatchBackend
 
     if len(jax.devices()) < dp * rp:
         pytest.skip("needs 8 virtual devices")
-    patterns = _bench.generate_rules(40, seed=5) + [r".*", r"^$"]
-    lines = _bench.generate_lines(64, patterns, seed=6, attack_rate=0.3) + [""]
+    patterns = synth.generate_rules(40, seed=5) + [r".*", r"^$"]
+    lines = synth.generate_lines(64, patterns, seed=6, attack_rate=0.3) + [""]
     compiled = compile_rules(patterns, n_shards=rp)
     plan = build_plan(
         patterns,
@@ -179,16 +179,16 @@ def test_fused_mesh_prefilter_parity(backend, dp, rp):
 def test_fused_mesh_overflow_falls_back():
     """Per-dp-shard candidate overflow reruns the batch single-stage —
     identical output, fallback counter ticks."""
-    import bench as _bench
+    from banjax_tpu.scenarios import synth
 
     from banjax_tpu.matcher.prefilter import build_plan
     from banjax_tpu.parallel.mesh import ShardedMatchBackend
 
     if len(jax.devices()) < 8:
         pytest.skip("needs 8 virtual devices")
-    patterns = _bench.generate_rules(30, seed=8)
+    patterns = synth.generate_rules(30, seed=8)
     # every line matches: candidates exceed any fractional capacity
-    lines = _bench.generate_lines(64, patterns, seed=9, attack_rate=1.0)
+    lines = synth.generate_lines(64, patterns, seed=9, attack_rate=1.0)
     rp = 2
     compiled = compile_rules(patterns, n_shards=rp)
     plan = build_plan(

@@ -21,6 +21,7 @@ from banjax_tpu.obs.stats import PipelineStats
 from banjax_tpu.pipeline import AdaptiveBatchSizer, PipelineScheduler
 from banjax_tpu.resilience import failpoints
 from banjax_tpu.resilience.breaker import CLOSED, OPEN
+from tests.classic_downgrade import scan_selftest_failing
 from tests.mock_banner import MockBanner
 
 RULES_YAML = r"""
@@ -243,12 +244,13 @@ class TestSplitProtocol:
     def test_stale_at_drain_time_is_dropped_and_counted(self, device_windows):
         now = time.time()
         lines = lines_at(now, 20)
-        # pallas_single_kernel=off: drop-at-DRAIN is the two-program/
-        # classic contract (the single-kernel path commits at submit and
-        # takes the cut there — tests/unit/test_fused_single_kernel.py)
-        m, states, banner = make_matcher(
-            device_windows, pallas_single_kernel="off"
-        )
+        # drop-at-DRAIN is the classic protocol's contract (the fused
+        # path commits at submit and takes the cut there —
+        # tests/unit/test_fused_single_kernel.py); with device windows the
+        # classic protocol is reached by the scan-selftest downgrade
+        with scan_selftest_failing():
+            m, states, banner = make_matcher(device_windows)
+        assert m.describe()["fused_protocol"] == "classic"
         state = m.pipeline_begin(lines, now)
         m.pipeline_submit(state)
         m.pipeline_collect(state)
@@ -290,14 +292,16 @@ class TestSplitProtocol:
         assert all(r.old_line for r in results)
 
 
-class TestFusedTwoPhaseSplit:
-    """The fused matcher+windows two-phase protocol under the split calls
-    (device windows on → submit dispatches program A, finish commits)."""
+class TestFusedSplit:
+    """The fused matcher+windows protocol under the split calls (device
+    windows on → submit dispatches and commits, finish pulls and
+    replays)."""
 
     def test_multi_chunk_batch_commits_in_order(self):
         """A batch wider than matcher_batch_lines splits into several
-        two-phase chunks; their B-applies commit strictly in chunk order
-        at finish — identical to the sync fused path."""
+        fused chunks; they commit strictly in chunk order at submit and
+        replay in that order at finish — identical to the sync fused
+        path."""
         now = time.time()
         # mixed traffic: mostly benign so the candidate gate holds
         lines = [
@@ -316,7 +320,7 @@ class TestFusedTwoPhaseSplit:
         state = m.pipeline_begin(lines, now)
         assert state.get("fused_eligible")
         m.pipeline_submit(state)
-        assert len(state["fused"]) > 1, "expected several two-phase chunks"
+        assert len(state["fused"]) > 1, "expected several fused chunks"
         m.pipeline_collect(state)
         got, n_stale = m.pipeline_finish(state, now)
         assert n_stale == 0
@@ -336,17 +340,6 @@ class TestFusedTwoPhaseSplit:
         assert sync_banner.regex_ban_logs == banner.regex_ban_logs
         assert sync_m.device_windows.format_states() == \
             m.device_windows.format_states()
-
-    def test_pipeline_fused_false_restores_classic_protocol(self):
-        now = time.time()
-        m, _, _ = make_matcher(device_windows=True, pipeline_fused=False)
-        state = m.pipeline_begin(lines_at(now, 20), now)
-        assert not state.get("fused_eligible")
-        m.pipeline_submit(state)
-        assert state.get("fused") is None and state["pend"] is not None
-        m.pipeline_collect(state)
-        results, _ = m.pipeline_finish(state, now)
-        assert m.pipelined_fused_chunks == 0
 
     def test_abort_frees_turns_for_later_batches(self):
         """pipeline_abort on an un-finished batch must free its order

@@ -89,10 +89,10 @@ class TestRequiredFactors:
 
 class TestTwoStageEquivalence:
     def _bench_rules_and_lines(self, n_rules=60, n_lines=500, seed=9):
-        import bench
+        from banjax_tpu.scenarios import synth
 
-        patterns = bench.generate_rules(n_rules, seed=seed)
-        lines = bench.generate_lines(n_lines, patterns, seed=seed + 1,
+        patterns = synth.generate_rules(n_rules, seed=seed)
+        lines = synth.generate_lines(n_lines, patterns, seed=seed + 1,
                                      attack_rate=0.3)
         return patterns, lines
 
@@ -246,10 +246,10 @@ class TestFusedPrefilter:
     def test_parity_with_single_stage(self, backend):
         from banjax_tpu.matcher.prefilter import FusedPrefilter
 
-        import bench
+        from banjax_tpu.scenarios import synth
 
-        patterns = bench.generate_rules(60, seed=9)
-        lines = bench.generate_lines(300, patterns, seed=10, attack_rate=0.3)
+        patterns = synth.generate_rules(60, seed=9)
+        lines = synth.generate_lines(300, patterns, seed=10, attack_rate=0.3)
         compiled, plan = self._plan(patterns)
         assert plan is not None
         cls_ids, lens, he, want = self._oracle(compiled, plan, lines)
@@ -280,10 +280,10 @@ class TestFusedPrefilter:
         new program is built only where none holds the batch."""
         from banjax_tpu.matcher.prefilter import FusedPrefilter
 
-        import bench
+        from banjax_tpu.scenarios import synth
 
-        patterns = bench.generate_rules(40, seed=21)
-        lines = bench.generate_lines(200, patterns, seed=22, attack_rate=0.3)
+        patterns = synth.generate_rules(40, seed=21)
+        lines = synth.generate_lines(200, patterns, seed=22, attack_rate=0.3)
         compiled, plan = self._plan(patterns)
         assert plan is not None
         fp = FusedPrefilter(plan, backend, cand_frac=1.0, pair_frac=1.0)
@@ -315,10 +315,10 @@ class TestFusedPrefilter:
         fit uint8) must match the packed default bit-for-bit."""
         from banjax_tpu.matcher.prefilter import FusedPrefilter
 
-        import bench
+        from banjax_tpu.scenarios import synth
 
-        patterns = bench.generate_rules(30, seed=12)
-        lines = bench.generate_lines(200, patterns, seed=13, attack_rate=0.2)
+        patterns = synth.generate_rules(30, seed=12)
+        lines = synth.generate_lines(200, patterns, seed=13, attack_rate=0.2)
         compiled, plan = self._plan(patterns)
         assert plan is not None
         cls_ids, lens, _, want = self._oracle(compiled, plan, lines)
@@ -350,14 +350,14 @@ class TestFusedPrefilter:
     def test_submit_collect_pipeline(self):
         from banjax_tpu.matcher.prefilter import FusedPrefilter
 
-        import bench
+        from banjax_tpu.scenarios import synth
 
-        patterns = bench.generate_rules(40, seed=3)
+        patterns = synth.generate_rules(40, seed=3)
         compiled, plan = self._plan(patterns)
         assert plan is not None
         fp = FusedPrefilter(plan, "xla", cand_frac=1.0, pair_frac=1.0)
         batches = [
-            bench.generate_lines(100, patterns, seed=s, attack_rate=0.2)
+            synth.generate_lines(100, patterns, seed=s, attack_rate=0.2)
             for s in (1, 2, 3)
         ]
         encoded = [self._oracle(compiled, plan, b) for b in batches]
@@ -484,9 +484,9 @@ class TestFactorMerging:
         plan = build_plan(patterns, min_filterable_fraction=0.4,
                           factor_merge=64, factor_sel_max=1e-3)
         assert plan is not None
-        import bench as _bench
+        from banjax_tpu.scenarios import synth
 
-        lines = _bench.generate_lines(512, patterns, seed=3,
+        lines = synth.generate_lines(512, patterns, seed=3,
                                       attack_rate=0.3)
         pf = PrefilterMatcher(plan, "xla", max_len=128, max_batch=256)
         bits, host_eval = pf.match_bits(lines)

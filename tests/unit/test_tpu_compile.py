@@ -49,9 +49,9 @@ def one_chip():
 
 @pytest.fixture(scope="module")
 def rules():
-    import bench
+    from banjax_tpu.scenarios import synth
 
-    return bench.generate_rules(N_RULES)
+    return synth.generate_rules(N_RULES)
 
 
 @pytest.fixture(scope="module")

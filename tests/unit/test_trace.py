@@ -54,7 +54,7 @@ def test_span_parenting_explicit_and_ambient(tracer):
 
 def test_ambient_span_without_parent_records_nothing(tracer):
     # library instrumentation (matcher/mesh) outside a traced batch
-    with tracer.span("program-b") as sp:
+    with tracer.span("program-ab-fused") as sp:
         assert sp is trace.NOOP_SPAN
     assert tracer.snapshot() == []
 

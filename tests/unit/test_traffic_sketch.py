@@ -2,8 +2,7 @@
 relative error fuzzed on skewed (Zipf) and all-distinct synthetic
 feeds against exact host-side counts, the conservative-estimate
 invariant, slot-table reassignment semantics, and the matcher-level
-sampling surface (pull throttle, /traffic summary shape, the
-SingleKernelDepthIgnored satellite)."""
+sampling surface (pull throttle, /traffic summary shape)."""
 
 import time
 
@@ -239,25 +238,6 @@ def test_matcher_sketch_sees_skewed_flood():
 def test_matcher_sketch_disabled_by_config():
     m, _ = _matcher(traffic_sketch_enabled=False)
     assert m.traffic_sketch is None
-
-
-def test_single_kernel_depth_ignored_gauge():
-    """The PR 7 silent-ignore surfaced: drain_resolve_depth > 1 with the
-    single-kernel path active flags SingleKernelDepthIgnored on the
-    snapshot (and the key is registry-declared)."""
-    m, _ = _matcher(drain_resolve_depth=3)
-    if not (m._fw_pipeline is not None and m._fw_pipeline.single_kernel):
-        pytest.skip("single-kernel path unavailable on this backend")
-    assert m.single_kernel_depth_ignored is True
-    snap = m.stats.peek(m.device_windows, m)
-    assert snap["SingleKernelDepthIgnored"] is True
-    assert registry.is_declared_line_key("SingleKernelDepthIgnored")
-    # depth 1 (the serial drain) is NOT a lie — nothing is ignored
-    m1, _ = _matcher(drain_resolve_depth=1)
-    assert m1.single_kernel_depth_ignored is False
-    assert m1.stats.peek(m1.device_windows, m1)[
-        "SingleKernelDepthIgnored"
-    ] is False
 
 
 def test_traffic_keys_on_snapshot_and_registry():
