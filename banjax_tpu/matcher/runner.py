@@ -22,6 +22,7 @@ flag named in BASELINE.json); CpuMatcher remains the default.
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import threading
 import time
@@ -113,6 +114,9 @@ class TpuMatcher(Matcher):
         # host wall seconds inside the drain's `effector-replay` spans
         # (event decode + shadow absorb + Banner replay of committed chunks)
         self.effector_replay_s = 0.0
+        # host wall seconds inside the submit stage's `submit-resolve`
+        # spans (the one pass over a batch's distinct addresses)
+        self.submit_resolve_s = 0.0
         # batches whose device-window apply is deferred to their drain
         # turn (classic-pend fallbacks): while any is outstanding, the
         # fused path must not commit at submit (see
@@ -953,20 +957,35 @@ class TpuMatcher(Matcher):
     def pipeline_submit(self, state: dict, now: Optional[float] = None) -> None:
         if not len(state["work"]):
             return
-        part = self._partition_admission(state["work"], state["pre"])
-        if part is not None:
-            state["work"], state["pre"], work_r, pre_r = part
-            # refused rows apply SYNCHRONOUSLY at submit: submits are
-            # sequential on the scheduler thread, so this batch's
-            # warm-tier writes land before the NEXT batch's admission
-            # probe/refill — a refused IP can never race its own state.
-            # (Their results ride state["results"] out at finish; the
-            # shrunk work keeps host_eval all-false, so fused
-            # eligibility computed at begin remains valid.)
-            self._consume_refused(work_r, pre_r, state["results"])
+        # a batch that commits as ONE fused chunk resolves its distinct
+        # addresses in one pass (gate and slots together); any other
+        # batch, and a batch whose pass failed, takes the per-step calls
+        if (
+            state.get("fused_eligible")
+            and self.device_windows is not None
+            and len(state["work"]) <= self._max_batch
+            and self._single_kernel_ordered()
+        ):
+            self._resolve_submit(state)
             if not len(state["work"]):
                 return
-        if state.get("fused_eligible") and self._single_kernel_ordered():
+        if not state.get("gated"):
+            part = self._partition_admission(state["work"], state["pre"])
+            if part is not None:
+                state["work"], state["pre"], work_r, pre_r = part
+                # refused rows apply SYNCHRONOUSLY at submit: submits are
+                # sequential on the scheduler thread, so this batch's
+                # warm-tier writes land before the NEXT batch's admission
+                # probe/refill — a refused IP can never race its own
+                # state.  (Their results ride state["results"] out at
+                # finish; the shrunk work keeps host_eval all-false, so
+                # fused eligibility computed at begin remains valid.)
+                self._consume_refused(work_r, pre_r, state["results"])
+                if not len(state["work"]):
+                    return
+        if "slots" in state or (
+            state.get("fused_eligible") and self._single_kernel_ordered()
+        ):
             if self._submit_fused_pipeline(state, now):
                 return
         state["pend"] = self._match_bits_submit(state["work"], state["pre"])
@@ -978,6 +997,90 @@ class TpuMatcher(Matcher):
             with self._drain_window_lock:
                 self._drain_window_batches += 1
             state["window_at_drain"] = True
+
+    @contextlib.contextmanager
+    def _resolving(self):
+        """A `submit-resolve` span, its wall added to the seconds that
+        `banjax_submit_resolve_seconds_total` exports."""
+        t0 = time.perf_counter()
+        try:
+            with trace.span("submit-resolve"):
+                yield
+        finally:
+            self.submit_resolve_s += time.perf_counter() - t0
+
+    def _resolve_submit(self, state: dict) -> None:
+        """The submit stage's one pass over the batch's distinct
+        addresses (DeviceWindows.resolve_addresses): the slot-admission
+        gate's verdict and the window slots from one encoding and one
+        probe of each table.  Leaves `state["gated"]`, and
+        `state["slots"]` — the admitted rows' slots, pinned, or None
+        when placement refused (the batch then goes the classic way, as
+        after a refusing slots_for_unique_ips).  Rows the gate refused
+        are split off and applied here, between the pass's probe and its
+        placement: exactly where _partition_admission applied them."""
+        dw = self.device_windows
+        sk = self.traffic_sketch
+        gate = self._slot_admission and sk is not None
+
+        def keep_slots(uinv):
+            state["slots"] = None
+            if res.slots is not None:
+                self._note_sketch_slots(uips, res)
+                state["slots"] = res.slots[uinv]
+
+        with self._resolving():
+            uips, uinv = state["work"].unique_ips()
+            counts = None
+            if gate and self._admission_min_estimate > 1:
+                counts = np.bincount(
+                    uinv, minlength=len(uips)
+                ).astype(np.int64)
+            try:
+                res = dw.resolve_addresses(
+                    uips, counts=counts,
+                    min_estimate=self._admission_min_estimate,
+                    sketch=sk, gate=gate,
+                )
+            except Exception:  # noqa: BLE001 — fail open: per-step calls
+                log.exception(
+                    "address resolution failed; batch takes the per-step "
+                    "gate and slot calls"
+                )
+                return
+            state["gated"] = True
+            if not len(res.refused):
+                keep_slots(uinv)
+                return
+        # a threshold of 2 or more only
+        sk.fold_refused(
+            [uips[i] for i in res.refused.tolist()],
+            counts[res.refused], hashes=res.refused_hashes,
+        )
+        state["work"], state["pre"], work_r, pre_r, adm = self._split_rows(
+            state["work"], state["pre"], res.admit[uinv]
+        )
+        self._consume_refused(work_r, pre_r, state["results"])
+        with self._resolving():
+            dw.place_resolved(res)
+            keep_slots(uinv[adm])
+
+    def _note_sketch_slots(self, uips, res) -> None:
+        """Refresh the sketch's slot→ip-hash table for a batch's distinct
+        assignments (scatters only CHANGED slots); a telemetry failure
+        must never cost the batch."""
+        if self.traffic_sketch is None:
+            return
+        try:
+            ips, slots, hashes = uips, res.slots, res.hashes
+            if len(res.refused):
+                adm = np.flatnonzero(res.admit)
+                ips = [uips[i] for i in adm.tolist()]
+                slots = slots[adm]
+                hashes = None if hashes is None else hashes[adm]
+            self.traffic_sketch.note_assignments(ips, slots, hashes=hashes)
+        except Exception:  # noqa: BLE001 — sketch is passive by contract
+            log.exception("traffic sketch slot-table refresh failed")
 
     def _single_kernel_ordered(self) -> bool:
         """Commit-at-submit is only order-safe while no EARLIER admitted
@@ -1001,13 +1104,22 @@ class TpuMatcher(Matcher):
         drains the batch generically: an already-committed chunk's
         generic rerun can double-count window hits, never Banner
         effects)."""
-        failpoints.check("matcher.device")
-        work = state["work"]
-        cls_ids, lens, _ = state["pre"]
-        if now is None:
-            now = time.time()
+        # slots the submit stage's pass already assigned (and pinned)
+        # for this, the batch's only chunk: the chunk's submit owns the
+        # pins from its call on, a failure before it gives them back
+        resolved = {}
+        if "slots" in state:
+            if state["slots"] is None:
+                del state["slots"]
+                return False
+            resolved = {"slots": state.pop("slots")}
         entries = []
         try:
+            failpoints.check("matcher.device")
+            work = state["work"]
+            cls_ids, lens, _ = state["pre"]
+            if now is None:
+                now = time.time()
             for s in range(0, len(work), self._max_batch):
                 wc = work[s : s + self._max_batch]
                 live = stale = None
@@ -1016,11 +1128,12 @@ class TpuMatcher(Matcher):
                 if st.any():
                     stale, live = st, ~st
                 with trace.span("program-ab-fused", args={"row0": s}):
+                    handed, resolved = resolved, {}
                     e = self._submit_pipeline_chunk(
                         wc,
                         cls_ids[s : s + self._max_batch],
                         lens[s : s + self._max_batch],
-                        live=live,
+                        live=live, **handed,
                     )
                 if e is None:
                     # more distinct IPs than free+unpinned slots (in-flight
@@ -1033,6 +1146,8 @@ class TpuMatcher(Matcher):
                 e["stale"] = stale
                 entries.append(e)
         except Exception:
+            if resolved:
+                self.device_windows.release_pins(resolved["slots"])
             for prev in entries:
                 self._fw_pipeline.abandon(prev["pend"])
             raise
@@ -1242,18 +1357,13 @@ class TpuMatcher(Matcher):
         gather back to row order. Pin/release semantics are unchanged —
         release_pins deduplicates slot ids either way."""
         uips, uinv = work.unique_ips()
-        uslots = self.device_windows.slots_for_unique_ips(uips)
-        if uslots is None:
+        res = self.device_windows.resolve_addresses(
+            uips, sketch=self.traffic_sketch
+        )
+        if res.slots is None:
             return None
-        if self.traffic_sketch is not None:
-            # refresh the sketch's slot→ip-hash table for this batch's
-            # distinct assignments (scatters only CHANGED slots); a
-            # telemetry failure must never cost the batch
-            try:
-                self.traffic_sketch.note_assignments(uips, uslots)
-            except Exception:  # noqa: BLE001 — sketch is passive by contract
-                log.exception("traffic sketch slot-table refresh failed")
-        return uslots[uinv]
+        self._note_sketch_slots(uips, res)
+        return res.slots[uinv]
 
     # ---- cold-tier slot admission (mega-state tiering) ----
 
@@ -1278,6 +1388,10 @@ class TpuMatcher(Matcher):
             or self.device_windows is None
             or self.traffic_sketch is None
             or not len(work)
+            # a distinct address of a batch has at least one row, so
+            # `estimate + rows >= 1` whatever the sketch says: with a rule
+            # that bans on the first hit the verdict is "admit", unasked
+            or self._admission_min_estimate <= 1
         ):
             return None
         try:
@@ -1300,19 +1414,24 @@ class TpuMatcher(Matcher):
                 counts[refused_u],
                 hashes=hashes[refused_u],
             )
-            row_mask = mask_u[uinv]
-            adm = np.flatnonzero(row_mask)
-            ref = np.flatnonzero(~row_mask)
-            work_a, work_r = work.take(adm), work.take(ref)
-            pre_a = pre_r = None
-            if pre_encoded is not None:
-                cls_ids, lens, host_eval = pre_encoded
-                pre_a = (cls_ids[adm], lens[adm], host_eval[adm])
-                pre_r = (cls_ids[ref], lens[ref], host_eval[ref])
-            return work_a, pre_a, work_r, pre_r
+            return self._split_rows(work, pre_encoded, mask_u[uinv])[:4]
         except Exception:  # noqa: BLE001 — the gate is an optimization; fail open
             log.exception("slot-admission gate failed; admitting batch")
             return None
+
+    @staticmethod
+    def _split_rows(work, pre_encoded, row_mask):
+        """Row-disjoint takes of a batch by the gate's per-row verdict:
+        (work_admitted, pre_admitted, work_refused, pre_refused, the
+        admitted rows' indices)."""
+        adm = np.flatnonzero(row_mask)
+        ref = np.flatnonzero(~row_mask)
+        pre_a = pre_r = None
+        if pre_encoded is not None:
+            cls_ids, lens, host_eval = pre_encoded
+            pre_a = (cls_ids[adm], lens[adm], host_eval[adm])
+            pre_r = (cls_ids[ref], lens[ref], host_eval[ref])
+        return work.take(adm), pre_a, work.take(ref), pre_r, adm
 
     def _consume_refused(self, work, pre_encoded, results) -> None:
         """Classic per-line path for slot-REFUSED rows: device-STATELESS
@@ -1603,14 +1722,17 @@ class TpuMatcher(Matcher):
                     log.exception("pipeline drain after failure also failed")
             raise
 
-    def _submit_pipeline_chunk(self, work, cls_ids, lens, live=None):
-        """Allocate slots + dispatch the chunk's fused program (`live`
-        is its commit mask); None when slot allocation refuses. Pins
-        transfer to the pipeline on success."""
+    def _submit_pipeline_chunk(self, work, cls_ids, lens, live=None,
+                               slots=None):
+        """Allocate slots (unless the caller's pass already has: `slots`,
+        pinned) + dispatch the chunk's fused program (`live` is its
+        commit mask); None when slot allocation refuses. Pins transfer
+        to the pipeline on success."""
         from banjax_tpu.matcher.windows import split_ns
 
         dw = self.device_windows
-        slots = self._slots_for_work(work)
+        if slots is None:
+            slots = self._slots_for_work(work)
         if slots is None:
             return None
         try:

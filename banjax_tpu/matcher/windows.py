@@ -400,6 +400,35 @@ class EventBatch:
         return (self[k] for k in range(len(self.line)))
 
 
+@dataclasses.dataclass
+class Resolution:
+    """What one pass over a batch's DISTINCT addresses found and did
+    (DeviceWindows.resolve_addresses).  Index arrays index the address
+    list the pass was given."""
+
+    ips: Sequence[str]
+    admit: np.ndarray              # bool [n] — the gate's verdict
+    refused: np.ndarray            # int64 [r] — addresses the gate refused
+    # uint32 [r] base hashes of the refused (what the sketch folds them
+    # under); None when the gate asked the sketch about nothing
+    refused_hashes: Optional[np.ndarray] = None
+    # int32 [n]: the slot of every admitted address once `placed`, -1 for
+    # a refused one; None when placement refused (every eviction
+    # candidate pinned: the caller splits the batch, as ever)
+    slots: Optional[np.ndarray] = None
+    placed: bool = False
+    # uint32 [n] base hashes of all addresses, from the pass's one
+    # encoding (the sketch's slot table); None off the native path
+    hashes: Optional[np.ndarray] = None
+    # the pass's working state between its probe and its placement
+    _enc: Optional[tuple] = None
+    _seq: int = 0
+    _miss_idx: Optional[np.ndarray] = None   # int64 [m], ascending
+    _in_shadow: Optional[np.ndarray] = None  # bool [m]
+    _in_warm: Optional[np.ndarray] = None    # bool [m]
+    _sketch_admitted: Optional[np.ndarray] = None  # int64, into ips
+
+
 class DeviceWindows:
     """Device-resident RegexRateLimitStates with host slot management.
 
@@ -497,6 +526,15 @@ class DeviceWindows:
         self.sketch_fp_count = 0
         self._sketch_pending: set = set()
         self._sketch_slots: Dict[int, bool] = {}
+        # the submit stage's address resolution (resolve_addresses):
+        # distinct addresses by what the pass found them to be, keys
+        # handed to each table by any caller (the pass, admission_mask),
+        # and batches whose gate verdict needed no sketch
+        self.resolve_outcomes: Dict[str, int] = dict.fromkeys(
+            ("hit", "shadow", "warm", "unseen", "refused"), 0
+        )
+        self.resolve_probes: Dict[str, int] = {"slots": 0, "warm": 0}
+        self.gate_derived_batches = 0
 
         self._slots: Dict[str, int] = {}  # ip → slot
         # batch-granular recency per slot (see slots_for_unique_ips)
@@ -619,9 +657,12 @@ class DeviceWindows:
         manager reproduces the argmin victim exactly, so the parity fuzz
         can compare slot ids verbatim)."""
         with self._lock:
-            self._batch_seq += 1
             if self._sm is not None:
-                return self._slots_unique_native_locked(ips)
+                # the pass with no gate: probe, then place every miss
+                res = self._probe_locked(ips, None, 1, None, False)
+                self._place_locked(res)
+                return res.slots
+            self._batch_seq += 1
             out = np.empty(len(ips), dtype=np.int32)
             misses: List[int] = []
             get = self._slots.get
@@ -709,6 +750,7 @@ class DeviceWindows:
                 admit = np.fromiter(
                     (ip in slots for ip in ips), dtype=bool, count=n
                 )
+            self.resolve_probes["slots"] += n
             unknown = np.flatnonzero(~admit)
             if len(unknown):
                 shadow = self._shadow
@@ -727,6 +769,7 @@ class DeviceWindows:
                 wm = self._warm.contains_batch(
                     [ips[int(i)] for i in unknown]
                 )
+                self.resolve_probes["warm"] += len(unknown)
                 admit[unknown[wm]] = True
                 unknown = unknown[~wm]
             if len(unknown):
@@ -751,16 +794,193 @@ class DeviceWindows:
                         self.slot_refusals += len(refused)
             return admit
 
-    def _slots_unique_native_locked(self, ips: Sequence[str]) -> Optional[np.ndarray]:
-        """slots_for_unique_ips via the native manager: one C lookup pass
-        (hits touched), the Python growth chain between passes, one C
-        placement pass (free stack, then exact-argmin eviction).  Python
-        work is O(misses + evictions) dict bookkeeping only."""
-        sm = self._sm
-        slots, miss_idx, ctx = sm.lookup_batch(
-            ips, self._batch_seq, self._last_used
+    def resolve_addresses(
+        self,
+        ips: Sequence[str],
+        counts: Optional[np.ndarray] = None,
+        min_estimate: int = 1,
+        sketch=None,
+        gate: bool = False,
+    ) -> Resolution:
+        """One pass over a batch's DISTINCT addresses: each is found hot
+        (slot assigned; recency stamped, as slots_for_unique_ips does),
+        in the host shadow, in the warm tier, or unseen; the slot-
+        admission gate (`gate`; admission_mask's rules, read from these
+        answers) gives its verdict; and the admitted misses are placed —
+        one encoding of the addresses, one probe of the slot table, one
+        of the warm tier over the misses alone.
+
+        The sketch is asked only where its answer can matter: about
+        unseen addresses, and not at all when `min_estimate` <= 1 (a
+        distinct address of a batch has at least one row, so `estimate +
+        rows >= 1` whatever the estimate).  `counts` are the per-address
+        row counts of the batch.  `sketch` also says that the caller
+        wants the addresses' base hashes (Resolution.hashes).
+
+        A refused address is a miss that is not passed to placement: it
+        leaves no recency stamp and claims no slot.  When any address is
+        refused the pass returns BEFORE placing (Resolution.placed
+        False): the caller applies the refused rows (apply_host_events
+        homes their state in the warm tier, as it did between
+        admission_mask and slots_for_unique_ips) and then calls
+        place_resolved.  With nothing refused — every batch of a ruleset
+        whose cheapest rule bans on the first hit — it is one call."""
+        if self._sm is None:
+            return self._resolve_dict(ips, counts, min_estimate, sketch, gate)
+        with self._lock:
+            res = self._probe_locked(ips, counts, min_estimate, sketch, gate)
+            if not len(res.refused):
+                self._place_locked(res)
+        return res
+
+    def place_resolved(self, res: Resolution) -> None:
+        """The placement a resolve_addresses with refused addresses left
+        open (after the caller applied the refused rows)."""
+        if res.placed:
+            return
+        if self._sm is None:
+            self._place_dict(res)
+            return
+        with self._lock:
+            self._place_locked(res)
+
+    def _resolve_dict(self, ips, counts, min_estimate, sketch, gate):
+        """resolve_addresses without the native manager: today's
+        per-step calls (admission_mask, then the dict loop), which are
+        also what the native pass is compared with."""
+        n = len(ips)
+        admit = np.ones(n, dtype=bool)
+        hashes = None
+        if gate and n:
+            if sketch is not None and min_estimate > 1:
+                hashes = sketch.base_hashes(ips)
+                est = sketch.estimate_ips(ips, hashes=hashes) + (
+                    1 if counts is None else counts
+                )
+                admit = self.admission_mask(
+                    ips, estimates=est, min_estimate=min_estimate,
+                    counts=counts,
+                )
+            else:
+                with self._lock:
+                    self.gate_derived_batches += 1
+        refused = np.flatnonzero(~admit)
+        res = Resolution(
+            ips=ips, admit=admit, refused=refused,
+            refused_hashes=None if hashes is None else hashes[refused],
         )
-        n_miss = len(miss_idx)
+        if not len(refused):
+            self._place_dict(res)
+        return res
+
+    def _place_dict(self, res: Resolution) -> None:
+        res.placed = True
+        if not len(res.refused):
+            res.slots = self.slots_for_unique_ips(res.ips)
+            return
+        adm = np.flatnonzero(res.admit)
+        got = self.slots_for_unique_ips([res.ips[i] for i in adm.tolist()])
+        if got is not None:
+            res.slots = np.full(len(res.ips), -1, dtype=np.int32)
+            res.slots[adm] = got
+
+    def _probe_locked(self, ips, counts, min_estimate, sketch, gate):
+        """The pass's first half (native manager; caller holds the lock):
+        encode once, look every address up in the slot table (hits
+        stamped), ask the shadow and the warm tier about the misses, and
+        read the gate's verdict from the answers."""
+        from banjax_tpu.native.slotmgr import crc32_spans, encode_ips
+
+        n = len(ips)
+        self._batch_seq += 1
+        seq = self._batch_seq
+        admit = np.ones(n, dtype=bool)
+        refused = np.empty(0, dtype=np.int64)
+        enc = encode_ips(ips)
+        slots, miss_idx, _ = self._sm.lookup_batch(
+            ips, seq, self._last_used, enc=enc
+        )
+        res = Resolution(
+            ips=ips, admit=admit, refused=refused, slots=slots,
+            _enc=enc, _seq=seq, _miss_idx=miss_idx,
+        )
+        if sketch is not None and n:
+            res.hashes = crc32_spans(enc)
+        m = len(miss_idx)
+        self.resolve_probes["slots"] += n
+        tally = self.resolve_outcomes
+        tally["hit"] += n - m
+        in_shadow = np.zeros(m, dtype=bool)
+        in_warm = np.zeros(m, dtype=bool)
+        asked_sketch = False
+        if m:
+            miss_ips = list(map(ips.__getitem__, miss_idx.tolist()))
+            shadow = self._shadow
+            if shadow:
+                in_shadow = np.fromiter(
+                    map(shadow.__contains__, miss_ips), dtype=bool, count=m
+                )
+            warm = self._warm
+            if warm is not None and len(warm):
+                ask = np.flatnonzero(~in_shadow)
+                if len(ask):
+                    at = miss_idx[ask]
+                    in_warm[ask] = warm.contains_batch(
+                        miss_ips if len(ask) == m
+                        else [miss_ips[k] for k in ask.tolist()],
+                        spans=(enc[0], enc[1][at], enc[2][at]),
+                    )
+                    self.resolve_probes["warm"] += len(ask)
+            unseen = miss_idx[~(in_shadow | in_warm)]
+            if gate and len(unseen) and sketch is not None \
+                    and min_estimate > 1:
+                # admission rule 3: an unseen address claims a slot iff
+                # the sketch plausibly puts it over the cheapest rule's
+                # threshold, this batch's rows included
+                asked_sketch = True
+                h_u = res.hashes[unseen]
+                rows = 1 if counts is None else np.asarray(counts)[unseen]
+                ok = sketch.estimate_ips(
+                    [ips[i] for i in unseen.tolist()], hashes=h_u
+                ) + rows >= min_estimate
+                res._sketch_admitted = unseen[ok]
+                self.sketch_admissions += int(ok.sum())
+                refused = unseen[~ok]
+                if len(refused):
+                    admit[refused] = False
+                    res.refused = refused
+                    res.refused_hashes = h_u[~ok]
+                    self.slot_refusals += int(
+                        len(refused) if counts is None
+                        else np.asarray(counts)[refused].sum()
+                    )
+            tally["shadow"] += int(in_shadow.sum())
+            tally["warm"] += int(in_warm.sum())
+            tally["unseen"] += len(unseen) - len(refused)
+            tally["refused"] += len(refused)
+        if gate and not asked_sketch:
+            self.gate_derived_batches += 1
+        res._in_shadow = in_shadow
+        res._in_warm = in_warm
+        return res
+
+    def _place_locked(self, res: Resolution) -> None:
+        """The pass's second half (caller holds the lock): the Python
+        growth chain, one C placement of the admitted misses (free stack,
+        then the oldest evictable slots by selection), the evicted
+        addresses' spills in one warm-tier call, the returning ones'
+        refills in one more, and the pins.  Python work is O(misses +
+        evictions) dict bookkeeping only."""
+        res.placed = True
+        sm = self._sm
+        slots = res.slots
+        place_idx = res._miss_idx
+        in_shadow, in_warm = res._in_shadow, res._in_warm
+        if len(res.refused):
+            keep = res.admit[place_idx]
+            place_idx = place_idx[keep]
+            in_shadow, in_warm = in_shadow[keep], in_warm[keep]
+        n_miss = len(place_idx)
         if n_miss:
             # replicate the dict path's per-miss doubling chain: grow
             # while the free pool cannot absorb the remaining misses and
@@ -785,54 +1005,72 @@ class DeviceWindows:
                 # dict path's grow-per-miss loop
                 self.grow_count += steps - 1
         placed_idx, evicted, ok = sm.place_misses(
-            ctx, slots, miss_idx, self._batch_seq, self._pin_counts,
+            res._enc, slots, place_idx, res._seq, self._pin_counts,
             self._last_used,
         )
         if len(evicted):
-            ev = [int(s) for s in evicted]
-            for s in ev:
-                self._note_eviction_locked(s, self._slot_ip.pop(s, None))
+            ev = evicted.tolist()
+            pop = self._slot_ip.pop
+            self._note_evictions_locked(ev, [pop(s, None) for s in ev])
             self._pending_evict.extend(ev)
             if self.eviction_count == 0:
                 self._warn_first_eviction()
             self.eviction_count += len(ev)
-        if len(placed_idx):
-            shadow = self._shadow
-            pend_restore = self._pending_restore
-            slot_ip = self._slot_ip
-            idx_l = placed_idx.tolist()
+        n_placed = len(placed_idx)
+        if n_placed:
+            ips = res.ips
             slot_l = slots[placed_idx].tolist()
-            ip_l = list(map(ips.__getitem__, idx_l))
+            ip_l = list(map(ips.__getitem__, placed_idx.tolist()))
             # C-speed mirror update: at the all-distinct-IP shape this
             # loop IS the residual host cost, so no per-entry Python
-            slot_ip.update(zip(slot_l, ip_l))
+            self._slot_ip.update(zip(slot_l, ip_l))
             pend_sketch = self._sketch_pending
-            if pend_sketch:
+            if pend_sketch:  # admitted by an admission_mask call
                 for slot, ip in zip(slot_l, ip_l):
                     if ip in pend_sketch:
                         pend_sketch.discard(ip)
                         self._sketch_slots[slot] = True
-            # warm membership in ONE C probe over the placed ips; takes
-            # only on hits — the all-distinct shape (misses everywhere)
-            # pays one batch call, not a per-ip round-trip
-            warm = self._warm
-            in_warm = (
-                warm.contains_batch(ip_l)
-                if warm is not None and len(warm) else None
-            )
-            if shadow or in_warm is not None:
-                for k, (slot, ip) in enumerate(zip(slot_l, ip_l)):
-                    if ip in shadow:
-                        # previously-evicted IP returns: counters re-enter
-                        # the device in the next maintenance step, BEFORE
-                        # any of this batch's events for it are applied
-                        pend_restore.append((slot, ip))
-                    elif in_warm is not None and in_warm[k]:
-                        self._refill_from_warm_locked(slot, ip)
+            # returning addresses, in placement order (placed_idx is a
+            # prefix of place_idx: placement goes in ip order and stops
+            # at a refusal): a shadow resident's counters re-enter the
+            # device in the next maintenance step, BEFORE any of this
+            # batch's events for it are applied; a warm resident's are
+            # taken back into the shadow first, all in one call
+            back_w = in_warm[:n_placed]
+            back = np.flatnonzero(in_shadow[:n_placed] | back_w)
+            if len(back):
+                w_pos = np.flatnonzero(back_w)
+                if len(w_pos):
+                    at = placed_idx[w_pos]
+                    enc = res._enc
+                    w_ips = [ip_l[k] for k in w_pos.tolist()]
+                    for ip, vec in zip(w_ips, self._warm.take_batch(
+                        w_ips, spans=(enc[0], enc[1][at], enc[2][at])
+                    )):
+                        if vec is not None:
+                            self._shadow[ip] = OrderedDict(vec)
+                            self.warm_refills += 1
+                shadow = self._shadow
+                pend_restore = self._pending_restore
+                for k in back.tolist():
+                    if ip_l[k] in shadow:
+                        pend_restore.append((slot_l[k], ip_l[k]))
+        sa = res._sketch_admitted
+        if sa is not None and len(sa):
+            # sketch-admitted tenures are FP-evaluated at eviction; one
+            # the placement did not reach waits in _sketch_pending for
+            # the caller's retry, as after admission_mask
+            for i, slot in zip(sa.tolist(), slots[sa].tolist()):
+                if slot >= 0:
+                    self._sketch_slots[slot] = True
+                else:
+                    self._sketch_pending.add(res.ips[i])
         if not ok:
-            return None  # every eviction candidate pinned — split
-        self._pin_counts[slots] += 1
-        return slots
+            res.slots = None  # every eviction candidate pinned — split
+            return
+        self._pin_counts[
+            slots[res.admit] if len(res.refused) else slots
+        ] += 1
 
     def _warn_first_eviction(self) -> None:
         import logging
@@ -894,6 +1132,34 @@ class DeviceWindows:
         if self._warm.put(ip, entries, time.time_ns()):
             del self._shadow[ip]
             self.warm_spills += 1
+
+    def _note_evictions_locked(
+        self, slots: List[int], ips: List[Optional[str]]
+    ) -> None:
+        """_note_eviction_locked for all of one placement's victims, in
+        eviction order, with their spills in ONE warm-tier call.  A put
+        the tier dropped leaves its entry in the shadow, as there."""
+        shadow = self._shadow
+        if self._sketch_slots:
+            took = self._sketch_slots.pop
+            for slot, ip in zip(slots, ips):
+                if took(slot, False):
+                    self.sketch_fp_evaluated += 1
+                    if ip is None or ip not in shadow:
+                        self.sketch_fp_count += 1
+        if self._warm is None:
+            return
+        held = [(ip, od) for ip, od in zip(ips, map(shadow.get, ips)) if od]
+        if not held:
+            return
+        spill_ips = [ip for ip, _ in held]
+        stored = self._warm.put_batch(
+            spill_ips, [od for _, od in held], time.time_ns()
+        )
+        for ip, landed in zip(spill_ips, stored.tolist()):
+            if landed:
+                del shadow[ip]
+                self.warm_spills += 1
 
     def _refill_from_warm_locked(self, slot: int, ip: str) -> bool:
         """Move one IP's window vector warm → shadow and queue the device

@@ -22,6 +22,7 @@ The interface both provide:
 
 from __future__ import annotations
 
+import itertools
 from collections import OrderedDict
 from typing import Dict, List, Tuple
 
@@ -344,21 +345,19 @@ class CompositeWork:
         return CompositeWork(parts, offsets)
 
     def unique_ips(self) -> Tuple[List[str], np.ndarray]:
-        merged: Dict[str, int] = {}
-        strings: List[str] = []
-        invs = []
-        for w in self.parts:
-            ips_u, inv = w.unique_ips()
-            remap = np.empty(len(ips_u), dtype=np.int64)
-            for j, s in enumerate(ips_u):
-                g = merged.get(s)
-                if g is None:
-                    g = len(strings)
-                    merged[s] = g
-                    strings.append(s)
-                remap[j] = g
-            invs.append(remap[np.asarray(inv, dtype=np.int64)])
-        return strings, np.concatenate(invs)
+        tables = [w.unique_ips() for w in self.parts]
+        # dict.fromkeys keeps each string where it is met first: shard
+        # order, then the shard's own first-appearance order
+        strings = list(dict.fromkeys(
+            itertools.chain.from_iterable(t[0] for t in tables)
+        ))
+        at = dict(zip(strings, range(len(strings)))).__getitem__
+        return strings, np.concatenate([
+            np.fromiter(map(at, ips_u), dtype=np.int64, count=len(ips_u))[
+                np.asarray(inv, dtype=np.int64)
+            ]
+            for ips_u, inv in tables
+        ])
 
     def orig_rows(self) -> np.ndarray:
         return np.concatenate([

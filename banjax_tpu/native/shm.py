@@ -15,6 +15,7 @@ single-process Python path.
 from __future__ import annotations
 
 import ctypes
+import itertools
 import logging
 import os
 import subprocess
@@ -146,6 +147,16 @@ def _load() -> Optional[ctypes.CDLL]:
         lib.wt_contains_batch.restype = ctypes.c_int64
         lib.wt_contains_batch.argtypes = [
             vp, u8p, i64p, i64p, ctypes.c_int64, u8p,
+        ]
+        lib.wt_put_batch.restype = ctypes.c_int64
+        lib.wt_put_batch.argtypes = [
+            vp, u8p, i64p, i64p, ctypes.c_int64, ctypes.c_int64,
+            ctypes.c_int64, i64p, i32p, i32p, i64p, i64p, u8p,
+        ]
+        lib.wt_take_batch.restype = ctypes.c_int64
+        lib.wt_take_batch.argtypes = [
+            vp, u8p, i64p, i64p, ctypes.c_int64, i32p, i32p, i32p, i64p,
+            i64p,
         ]
         _LIB = lib
         return _LIB
@@ -449,24 +460,128 @@ class ShmWarmTier:
         """Non-deleting read for introspection (get/format_states)."""
         return self._read(ip, self._lib.wt_get)
 
-    def contains_batch(self, ips) -> np.ndarray:
-        """bool [n] membership over a distinct-ip list — one C call."""
+    def _spans(self, ips, spans):
+        """(the arrays to keep alive over the call, their C pointers) for
+        the keys: the caller's spans of an encoding it already made
+        (`encode_ips` of a list these ips were taken from), else an
+        encoding of `ips`.  The empty address is keyed by one NUL byte,
+        the byte `encode_ips` ends every blob with; the C side cuts a
+        key at WT_KEY_MAX."""
+        from banjax_tpu.native.slotmgr import encode_ips
+
+        buf, offs, lens = encode_ips(ips) if spans is None else spans
+        empty = lens == 0
+        if empty.any():
+            offs = np.where(empty, buf.size - 1, offs)
+            lens = np.where(empty, 1, lens)
+        offs = np.ascontiguousarray(offs, dtype=np.int64)
+        lens = np.ascontiguousarray(lens, dtype=np.int64)
+        u8p = ctypes.POINTER(ctypes.c_uint8)
+        i64p = ctypes.POINTER(ctypes.c_int64)
+        return (buf, offs, lens), (
+            buf.ctypes.data_as(u8p), offs.ctypes.data_as(i64p),
+            lens.ctypes.data_as(i64p),
+        )
+
+    def contains_batch(self, ips, spans=None) -> np.ndarray:
+        """bool [n] membership over a distinct-ip list — one C call.
+        `spans`: (buf, offs, lens) of these ips inside an encoding the
+        caller already holds, so the batch is encoded once."""
         n = len(ips)
         out = np.zeros(n, dtype=np.uint8)
         base = self._base_ptr
         if n == 0 or base is None:
             return out.astype(bool)
-        from banjax_tpu.native.slotmgr import _encode_ips
-
-        blob, offs, lens = _encode_ips([ip if ip else "\x00" for ip in ips])
-        buf = np.frombuffer(blob, dtype=np.uint8)
-        u8p = ctypes.POINTER(ctypes.c_uint8)
-        i64p = ctypes.POINTER(ctypes.c_int64)
+        _keep, ptrs = self._spans(ips, spans)
         self._lib.wt_contains_batch(
-            base, buf.ctypes.data_as(u8p), offs.ctypes.data_as(i64p),
-            lens.ctypes.data_as(i64p), n, out.ctypes.data_as(u8p),
+            base, *ptrs, n,
+            out.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
         )
         return out.astype(bool)
+
+    def put_batch(self, ips, vectors, now_ns: int) -> np.ndarray:
+        """`put` for every (ip, vector) pair, in order, in one C call.
+        A vector is the hot tier's shadow value: a mapping rule_id ->
+        (num_hits, start_s, start_ns) in insertion order.  bool [n]:
+        False where the put was dropped (or the vector was empty)."""
+        n = len(ips)
+        stored = np.zeros(n, dtype=np.uint8)
+        base = self._base_ptr
+        if n == 0 or base is None:
+            return stored.astype(bool)
+        mr = self.max_rules
+        counts = np.fromiter(map(len, vectors), dtype=np.int64, count=n)
+        if (counts > mr).any():
+            vectors = [dict(list(v.items())[:mr]) for v in vectors]
+            np.minimum(counts, mr, out=counts)
+        live = np.flatnonzero(counts)
+        if live.size == 0:
+            return stored.astype(bool)
+        if live.size != n:  # `put` refuses an empty vector: so does this
+            ips = [ips[i] for i in live.tolist()]
+            vectors = [vectors[i] for i in live.tolist()]
+            counts = counts[live]
+        chain = itertools.chain.from_iterable
+        total = int(counts.sum())
+        rid = np.fromiter(chain(vectors), dtype=np.int32, count=total)
+        state = np.array(
+            list(chain(v.values() for v in vectors)), dtype=np.int64
+        ).reshape(total, 3)
+        hits = np.ascontiguousarray(state[:, 0], dtype=np.int32)
+        ss = np.ascontiguousarray(state[:, 1])
+        sns = np.ascontiguousarray(state[:, 2])
+        ent_offs = np.zeros(live.size + 1, dtype=np.int64)
+        np.cumsum(counts, out=ent_offs[1:])
+        i32p = ctypes.POINTER(ctypes.c_int32)
+        i64p = ctypes.POINTER(ctypes.c_int64)
+        got = np.zeros(live.size, dtype=np.uint8)
+        _keep, ptrs = self._spans(ips, None)
+        self._lib.wt_put_batch(
+            base, *ptrs, live.size, now_ns, self.expiry_ns,
+            ent_offs.ctypes.data_as(i64p), rid.ctypes.data_as(i32p),
+            hits.ctypes.data_as(i32p), ss.ctypes.data_as(i64p),
+            sns.ctypes.data_as(i64p),
+            got.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
+        )
+        stored[live] = got
+        return stored.astype(bool)
+
+    def take_batch(self, ips, spans=None) -> List[Optional[dict]]:
+        """`take` for every ip, in order, in one C call: each key's
+        vector as put_batch takes them (rule_id -> (num_hits, start_s,
+        start_ns), insertion order; the record is deleted), None where
+        the key is absent."""
+        n = len(ips)
+        base = self._base_ptr
+        if n == 0 or base is None:
+            return [None] * n
+        mr = self.max_rules
+        n_out = np.empty(n, dtype=np.int32)
+        rid = np.empty((n, mr), dtype=np.int32)
+        hits = np.empty((n, mr), dtype=np.int32)
+        ss = np.empty((n, mr), dtype=np.int64)
+        sns = np.empty((n, mr), dtype=np.int64)
+        i32p = ctypes.POINTER(ctypes.c_int32)
+        i64p = ctypes.POINTER(ctypes.c_int64)
+        _keep, ptrs = self._spans(ips, spans)
+        self._lib.wt_take_batch(
+            base, *ptrs, n, n_out.ctypes.data_as(i32p),
+            rid.ctypes.data_as(i32p), hits.ctypes.data_as(i32p),
+            ss.ctypes.data_as(i64p), sns.ctypes.data_as(i64p),
+        )
+        # the records' entries, compacted in record order, then cut
+        # back into one mapping a record: no per-record array work
+        held = np.arange(mr, dtype=np.int32)[None, :] < n_out[:, None]
+        rids = rid[held].tolist()
+        states = list(zip(
+            hits[held].tolist(), ss[held].tolist(), sns[held].tolist()
+        ))
+        ends = np.cumsum(np.maximum(n_out, 0)).tolist()
+        out: List[Optional[dict]] = [None] * n
+        for i in np.flatnonzero(n_out >= 0).tolist():
+            a, b = ends[i] - int(n_out[i]), ends[i]
+            out[i] = dict(zip(rids[a:b], states[a:b]))
+        return out
 
     def __contains__(self, ip: str) -> bool:
         return bool(self.contains_batch([ip])[0])
@@ -580,9 +695,24 @@ class PyWarmTier:
         v = self._d.get(ip)
         return None if v is None else v[1]
 
-    def contains_batch(self, ips) -> np.ndarray:
+    def contains_batch(self, ips, spans=None) -> np.ndarray:
         d = self._d
         return np.fromiter((ip in d for ip in ips), bool, count=len(ips))
+
+    def put_batch(self, ips, vectors, now_ns: int) -> np.ndarray:
+        return np.fromiter(
+            (self.put(
+                ip, [(rid, h, s, ns) for rid, (h, s, ns) in v.items()],
+                now_ns,
+            ) for ip, v in zip(ips, vectors)),
+            bool, count=len(ips),
+        )
+
+    def take_batch(self, ips, spans=None) -> List[Optional[dict]]:
+        return [
+            None if ent is None else {e[0]: e[1:] for e in ent}
+            for ent in map(self.take, ips)
+        ]
 
     def __contains__(self, ip: str) -> bool:
         return ip in self._d

@@ -618,3 +618,50 @@ int64_t wt_contains_batch(void *base, const uint8_t *blob,
     }
     return found;
 }
+
+/* One call a batch for the spills of a placement: wt_put for each of n
+ * records, in order.  Record i's key is blob[offs[i] : offs[i] + lens[i]]
+ * and its entries are [ent_offs[i], ent_offs[i + 1]) of the four entry
+ * arrays.  stored_out[i] = 1 where the put landed, 0 where it was
+ * dropped (the caller keeps a dropped record's state where it was).
+ * Returns the number stored. */
+int64_t wt_put_batch(void *base, const uint8_t *blob, const int64_t *offs,
+                     const int64_t *lens, int64_t n, int64_t now_ns,
+                     int64_t expiry_ns, const int64_t *ent_offs,
+                     const int32_t *rule_ids, const int32_t *hits,
+                     const int64_t *ss, const int64_t *sns,
+                     uint8_t *stored_out) {
+    int64_t stored = 0;
+    for (int64_t i = 0; i < n; i++) {
+        int64_t e0 = ent_offs[i];
+        int64_t rc = wt_put(base, (const char *)blob + offs[i],
+                            (int32_t)lens[i], now_ns, expiry_ns,
+                            rule_ids + e0, hits + e0, ss + e0, sns + e0,
+                            ent_offs[i + 1] - e0);
+        stored_out[i] = rc == 0;
+        stored += rc == 0;
+    }
+    return stored;
+}
+
+/* One call a batch for the refills of a placement: wt_take for each of n
+ * keys, in order.  Key i's entries land at [i * max_rules, ...) of the
+ * four output arrays (max_rules as wt_max_rules gives it) and
+ * n_out[i] is their count, -1 where the key is absent.  Returns the
+ * number of records taken. */
+int64_t wt_take_batch(void *base, const uint8_t *blob, const int64_t *offs,
+                      const int64_t *lens, int64_t n, int32_t *n_out,
+                      int32_t *rule_ids_out, int32_t *hits_out,
+                      int64_t *ss_out, int64_t *sns_out) {
+    int64_t stride = ((wt_header *)base)->max_rules;
+    int64_t taken = 0;
+    for (int64_t i = 0; i < n; i++) {
+        int64_t at = i * stride;
+        int64_t got = wt_take(base, (const char *)blob + offs[i],
+                              (int32_t)lens[i], rule_ids_out + at,
+                              hits_out + at, ss_out + at, sns_out + at);
+        n_out[i] = (int32_t)got;
+        taken += got >= 0;
+    }
+    return taken;
+}

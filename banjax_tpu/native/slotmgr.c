@@ -296,11 +296,64 @@ typedef struct {
     int32_t slot;
 } sm_cand;
 
+static inline int cand_lt(const sm_cand *x, const sm_cand *y) {
+    return x->lu != y->lu ? x->lu < y->lu : x->slot < y->slot;
+}
+
 static int cand_cmp(const void *a, const void *b) {
     const sm_cand *x = a, *y = b;
-    if (x->lu != y->lu)
-        return x->lu < y->lu ? -1 : 1;
-    return x->slot < y->slot ? -1 : (x->slot > y->slot ? 1 : 0);
+    return cand_lt(x, y) ? -1 : (cand_lt(y, x) ? 1 : 0);
+}
+
+/* Reorder c[0..n) so that c[0..k) are its k smallest (lu, slot), in no
+ * particular order (quickselect, median of three; the keys are distinct
+ * because the slots are). */
+static void cand_select(sm_cand *c, int64_t n, int64_t k) {
+    int64_t lo = 0, hi = n - 1;
+    while (lo < hi) {
+        int64_t mid = lo + (hi - lo) / 2;
+        sm_cand a = c[lo], b = c[mid], d = c[hi];
+        sm_cand pivot = cand_lt(&a, &b)
+                            ? (cand_lt(&b, &d) ? b : (cand_lt(&a, &d) ? d : a))
+                            : (cand_lt(&a, &d) ? a : (cand_lt(&b, &d) ? d : b));
+        int64_t i = lo, j = hi;
+        while (i <= j) {
+            while (cand_lt(&c[i], &pivot))
+                i++;
+            while (cand_lt(&pivot, &c[j]))
+                j--;
+            if (i <= j) {
+                sm_cand t = c[i];
+                c[i] = c[j];
+                c[j] = t;
+                i++;
+                j--;
+            }
+        }
+        /* c[lo..j] <= pivot <= c[i..hi]; whatever lies between is the pivot */
+        if (k - 1 <= j)
+            hi = j;
+        else if (k - 1 >= i)
+            lo = i;
+        else
+            return;
+    }
+}
+
+/* Put the next `want` candidates of the full (lu, slot) order behind the
+ * `sorted` already in order.  c[sorted..n) holds everything not yet
+ * ordered, all of it >= c[sorted - 1].  Returns the new sorted count. */
+static int64_t cand_extend(sm_cand *c, int64_t n, int64_t sorted,
+                           int64_t want) {
+    int64_t rest = n - sorted;
+    if (want > rest)
+        want = rest;
+    if (want <= 0)
+        return sorted;
+    if (want < rest)
+        cand_select(c + sorted, rest, want);
+    qsort(c + sorted, (size_t)want, sizeof(sm_cand), cand_cmp);
+    return sorted + want;
 }
 
 /* Pass 2: place every miss, in ip order.  Free slots pop first; at
@@ -309,7 +362,15 @@ static int cand_cmp(const void *a, const void *b) {
  * evictions performed, out_counts[1] = misses successfully placed.
  * Returns 0, or -1 when an eviction was needed and every candidate is
  * pinned/touched (earlier misses stay placed and MUST be bookkept by
- * the caller — the Python refusal's partial-state semantics). */
+ * the caller — the Python refusal's partial-state semantics).
+ *
+ * The victims are the full (last_used, slot) order's, taken by
+ * selection: the evictable slots are collected once, and only as many
+ * of the oldest as there are misses still to place get sorted — the
+ * work follows the misses, not the table.  Should re-validation skip a
+ * candidate, the next oldest are selected from the rest, down to the
+ * last evictable slot: a refusal means none is left, as with the full
+ * sort. */
 int64_t sm_place_misses(void *h, const uint8_t *blob, const int64_t *offs,
                         const int64_t *lens, int64_t seq,
                         const int32_t *pin_counts, int64_t *last_used,
@@ -318,7 +379,7 @@ int64_t sm_place_misses(void *h, const uint8_t *blob, const int64_t *offs,
                         int64_t *out_counts) {
     sm_t *sm = h;
     sm_cand *cand = NULL;
-    int64_t cand_n = 0, cand_i = 0, n_evict = 0, placed = 0;
+    int64_t cand_n = 0, cand_i = 0, cand_sorted = 0, n_evict = 0, placed = 0;
     int64_t rc = 0;
     for (int64_t m = 0; m < n_miss; m++) {
         int64_t i = miss_idx[m];
@@ -340,10 +401,15 @@ int64_t sm_place_misses(void *h, const uint8_t *blob, const int64_t *offs,
                         cand_n++;
                     }
                 }
-                qsort(cand, (size_t)cand_n, sizeof(sm_cand), cand_cmp);
             }
             slot = -1;
-            while (cand_i < cand_n) {
+            for (;;) {
+                if (cand_i == cand_sorted) {
+                    cand_sorted =
+                        cand_extend(cand, cand_n, cand_sorted, n_miss - m);
+                    if (cand_i == cand_sorted)
+                        break; /* no evictable slot left */
+                }
                 sm_cand c = cand[cand_i++];
                 /* re-validate: the slot may have been consumed by an
                  * earlier eviction or touched by an earlier placement */
@@ -390,4 +456,50 @@ int64_t sm_place_misses(void *h, const uint8_t *blob, const int64_t *offs,
     out_counts[0] = n_evict;
     out_counts[1] = placed;
     return rc;
+}
+
+/* Test hook: the full (last_used, slot) order of n candidates, built
+ * `chunk` at a time by the selection above — what placement does when it
+ * has to go on past its first selection.  Writes the slots in order. */
+void sm_test_select_order(const int64_t *lu, const int32_t *slot, int64_t n,
+                          int64_t chunk, int32_t *out) {
+    sm_cand *c = malloc(sizeof(sm_cand) * (size_t)(n > 0 ? n : 1));
+    if (!c)
+        return;
+    for (int64_t i = 0; i < n; i++) {
+        c[i].lu = lu[i];
+        c[i].slot = slot[i];
+    }
+    int64_t sorted = 0;
+    while (sorted < n)
+        sorted = cand_extend(c, n, sorted, chunk > 0 ? chunk : 1);
+    for (int64_t i = 0; i < n; i++)
+        out[i] = c[i].slot;
+    free(c);
+}
+
+/* zlib's CRC-32 of each span (the traffic sketch's base hash of a client
+ * address, obs/sketch.py hash_ip): the spans are the one encoding of a
+ * batch's distinct addresses, so the sketch hashes what the slot table
+ * and the warm tier are asked about, with no second walk in Python. */
+void sm_crc32_batch(const uint8_t *blob, const int64_t *offs,
+                    const int64_t *lens, int64_t n, uint32_t *out) {
+    static uint32_t table[256];
+    static int ready; /* benign race: every thread writes the same values */
+    if (!__atomic_load_n(&ready, __ATOMIC_ACQUIRE)) {
+        for (uint32_t i = 0; i < 256; i++) {
+            uint32_t c = i;
+            for (int k = 0; k < 8; k++)
+                c = (c & 1) ? 0xEDB88320u ^ (c >> 1) : c >> 1;
+            table[i] = c;
+        }
+        __atomic_store_n(&ready, 1, __ATOMIC_RELEASE);
+    }
+    for (int64_t i = 0; i < n; i++) {
+        const uint8_t *p = blob + offs[i];
+        uint32_t c = 0xFFFFFFFFu;
+        for (int64_t k = 0; k < lens[i]; k++)
+            c = table[(c ^ p[k]) & 0xFF] ^ (c >> 8);
+        out[i] = c ^ 0xFFFFFFFFu;
+    }
 }

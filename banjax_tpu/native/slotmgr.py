@@ -110,15 +110,30 @@ def _load() -> Optional[ctypes.CDLL]:
         lib.sm_contains_batch.argtypes = [
             ctypes.c_void_p, _u8p, _i64p, _i64p, ctypes.c_int64, _u8p,
         ]
+        lib.sm_crc32_batch.restype = None
+        lib.sm_crc32_batch.argtypes = [
+            _u8p, _i64p, _i64p, ctypes.c_int64,
+            ctypes.POINTER(ctypes.c_uint32),
+        ]
+        lib.sm_test_select_order.restype = None
+        lib.sm_test_select_order.argtypes = [
+            _i64p, _i32p, ctypes.c_int64, ctypes.c_int64, _i32p,
+        ]
         _LIB = lib
         log.info("native slotmgr loaded (%s)", so)
         return _LIB
 
 
-def _encode_ips(ips: Sequence[str]) -> Tuple[bytes, np.ndarray, np.ndarray]:
-    """One blob + (offset, length) spans for a distinct-ip list.  The
-    common all-ASCII case is one join + one encode; byte lengths equal
-    char lengths so the per-ip work is a C-speed map(len)."""
+def encode_ips(
+    ips: Sequence[str],
+) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """(buf uint8, offs int64 [n], lens int64 [n]): one blob + (offset,
+    length) spans for a distinct-ip list — THE encoding of a batch's
+    addresses, made once and handed to the slot table, the warm tier
+    and the sketch's hash alike.  The common all-ASCII case is one join
+    + one encode; byte lengths equal char lengths so the per-ip work is
+    a C-speed map(len).  The blob ends with one NUL past the last span:
+    the warm tier keys the empty address by that byte."""
     n = len(ips)
     joined = "".join(ips)
     blob = joined.encode("utf-8", "surrogatepass")
@@ -132,7 +147,36 @@ def _encode_ips(ips: Sequence[str]) -> Tuple[bytes, np.ndarray, np.ndarray]:
     offs = np.zeros(n, dtype=np.int64)
     if n > 1:
         np.cumsum(lens[:-1], out=offs[1:])
-    return blob, offs, lens
+    return np.frombuffer(blob + b"\x00", dtype=np.uint8), offs, lens
+
+
+def crc32_spans(enc) -> Optional[np.ndarray]:
+    """uint32 [n] zlib CRC-32 of every span of an `encode_ips` result —
+    obs/sketch.py's `hash_ip` of every address, in one C call.  None
+    when the native library is unavailable."""
+    lib = _load()
+    if lib is None:
+        return None
+    buf, offs, lens = enc
+    out = np.empty(len(offs), dtype=np.uint32)
+    if len(offs):
+        lib.sm_crc32_batch(
+            _P(buf, _u8p), _P(offs, _i64p), _P(lens, _i64p), len(offs),
+            out.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32)),
+        )
+    return out
+
+
+def select_order(lu: np.ndarray, slot: np.ndarray, chunk: int) -> np.ndarray:
+    """Test hook: the eviction order of candidates (last_used, slot) as
+    sm_place_misses' selection builds it, `chunk` at a time."""
+    lu = np.ascontiguousarray(lu, dtype=np.int64)
+    slot = np.ascontiguousarray(slot, dtype=np.int32)
+    out = np.empty(len(slot), dtype=np.int32)
+    _load().sm_test_select_order(
+        _P(lu, _i64p), _P(slot, _i32p), len(slot), chunk, _P(out, _i32p)
+    )
+    return out
 
 
 class SlotManager:
@@ -171,22 +215,21 @@ class SlotManager:
         self.capacity = new_capacity
 
     def lookup_batch(
-        self, ips: Sequence[str], batch_seq: int, last_used: np.ndarray
+        self, ips: Sequence[str], batch_seq: int, last_used: np.ndarray,
+        enc=None,
     ):
         """Pass 1 over a DISTINCT ip list: resolve hits (stamping their
         recency with batch_seq) and collect misses.  Returns (slots
         int32 [n] with -1 per miss, miss_idx int64 [m], ctx) — pass ctx
-        straight to place_misses.  The caller may grow the manager (and
-        its device arrays) between the two passes; the passes re-take
-        the array pointers, so reallocation in between is safe."""
+        straight to place_misses; it is `enc`, the caller's
+        `encode_ips(ips)`, when given.  The caller may grow the manager
+        (and its device arrays) between the two passes; the passes
+        re-take the array pointers, so reallocation in between is safe."""
         n = len(ips)
         slots = np.empty(n, dtype=np.int32)
         if n == 0:
             return slots, np.empty(0, np.int64), None
-        blob, offs, lens = _encode_ips(ips)
-        buf = np.frombuffer(blob, dtype=np.uint8) if blob else np.zeros(
-            1, dtype=np.uint8
-        )
+        buf, offs, lens = encode_ips(ips) if enc is None else enc
         miss_idx = np.empty(n, dtype=np.int64)
         n_miss = int(self._lib.sm_lookup_batch(
             self._h, _P(buf, _u8p), _P(offs, _i64p), _P(lens, _i64p), n,
@@ -233,10 +276,7 @@ class SlotManager:
         out = np.zeros(n, dtype=np.uint8)
         if n == 0:
             return out.astype(bool)
-        blob, offs, lens = _encode_ips(ips)
-        buf = np.frombuffer(blob, dtype=np.uint8) if blob else np.zeros(
-            1, dtype=np.uint8
-        )
+        buf, offs, lens = encode_ips(ips)
         self._lib.sm_contains_batch(
             self._h, _P(buf, _u8p), _P(offs, _i64p), _P(lens, _i64p), n,
             _P(out, _u8p),

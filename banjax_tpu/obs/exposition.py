@@ -162,6 +162,17 @@ def render_prometheus(
         for cause, v in fw.overflow_causes.items():
             w.sample(fam, v, {"cause": cause})
 
+    # the submit stage's address resolution: what the pass found, and
+    # the keys it handed to each table (prom-only labeled counters)
+    dw = getattr(matcher, "device_windows", None) if matcher else None
+    if dw is not None and hasattr(dw, "resolve_outcomes"):
+        fam = registry.PROM_FAMILIES["banjax_submit_resolve_addresses_total"]
+        for outcome, v in dw.resolve_outcomes.items():
+            w.sample(fam, v, {"outcome": outcome})
+        fam = registry.PROM_FAMILIES["banjax_submit_resolve_probes_total"]
+        for table, v in dw.resolve_probes.items():
+            w.sample(fam, v, {"table": table})
+
     # per-worker encode busy fractions (prom-only labeled gauge)
     if pipeline is not None:
         fracs = pipeline.stats.worker_busy_fractions()

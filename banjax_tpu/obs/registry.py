@@ -183,6 +183,28 @@ FAMILIES: List[Family] = [
            "over misses while absent keys touch no record)",
            line_key="WarmTierRecordReads",
            prom="banjax_warm_tier_record_reads_total"),
+    # ---- the submit stage's address resolution (matcher/windows.py) ----
+    Family(COUNTER, "distinct client addresses of submitted batches by "
+           "what the one resolving pass found: hit (slot assigned), "
+           "shadow or warm (state elsewhere, re-entering), unseen "
+           "(admitted with no state), refused (by the slot-admission gate)",
+           prom="banjax_submit_resolve_addresses_total",
+           labels=("outcome",)),
+    Family(COUNTER, "keys handed to the slot table and to the warm tier's "
+           "membership probe by the submit stage (per batch: distinct "
+           "addresses, and misses not in the shadow, once each)",
+           prom="banjax_submit_resolve_probes_total", labels=("table",)),
+    Family(COUNTER, "batches whose slot-admission verdict needed no sketch "
+           "estimate (threshold 1: a rule bans on the first hit; or no "
+           "unseen address)",
+           line_key="SubmitGateDerivedBatches",
+           prom="banjax_submit_gate_derived_batches_total"),
+    Family(COUNTER, "host wall seconds inside the submit stage's "
+           "submit-resolve spans (one pass over a batch's distinct "
+           "addresses: encode, probes, gate verdict, placement, spills "
+           "and refills)",
+           line_key="SubmitResolveSeconds",
+           prom="banjax_submit_resolve_seconds_total"),
     # ---- mesh ----
     Family(COUNTER, "sharded-mesh batches served by the fused two-stage path",
            line_key="MeshFusedBatches", prom="banjax_mesh_fused_batches_total"),

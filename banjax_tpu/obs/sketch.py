@@ -196,8 +196,14 @@ class TrafficSketch:
         # candidate heavy hitters: LRU of recently-seen distinct IPs and
         # their base hashes — the enumerable key set a count-min sketch
         # itself cannot provide.  A true heavy hitter recurs every batch,
-        # so it cannot age out of a bound >> topk.
-        self._candidates: "OrderedDict[str, int]" = OrderedDict()
+        # so it cannot age out of a bound >> topk.  Recency is written in
+        # bulk: a batch appends its (ips, hashes) to `_cand_log`, one
+        # store whatever its size, and the LRU is brought up to date from
+        # the log where somebody reads it (a pull) or the log has grown
+        # past a few times the bound — see _candidates_locked.
+        self._cand_lru: "OrderedDict[str, int]" = OrderedDict()
+        self._cand_log: List[tuple] = []
+        self._cand_log_len = 0
         self._update_fns: Dict[tuple, object] = {}
 
         self.lines_total = 0          # lines folded into the sketch
@@ -212,29 +218,63 @@ class TrafficSketch:
 
     # ---- host bookkeeping (slot table + candidates) ----
 
+    def _candidates_locked(self) -> "OrderedDict[str, int]":
+        """The candidate LRU with every logged batch folded in (caller
+        holds the lock): the `max_candidates` most recently seen distinct
+        IPs, oldest first, an IP's place given by the last batch that had
+        it and its position there — what a per-address move-to-end walk
+        of each batch, trimmed after each, leaves behind (a trimmed IP is
+        older than `max_candidates` others and stays so until seen
+        again).  Built in C-speed dict passes: newest first, a dict keeps
+        each IP where it is met first, i.e. at its last sighting."""
+        if self._cand_log:
+            newest_first: Dict[str, int] = {}
+            for ips, hashes in reversed(self._cand_log):
+                # update() leaves a key it already has where it is
+                newest_first.update(
+                    zip(reversed(ips), reversed(hashes.tolist()))
+                )
+                if len(newest_first) >= self.max_candidates:
+                    break
+            else:
+                lru = self._cand_lru
+                newest_first.update(
+                    zip(reversed(lru), reversed(lru.values()))
+                )
+            keep = list(newest_first.items())[: self.max_candidates]
+            self._cand_lru = OrderedDict(reversed(keep))
+            self._cand_log = []
+            self._cand_log_len = 0
+        return self._cand_lru
+
+    @property
+    def _candidates(self) -> "OrderedDict[str, int]":
+        with self._lock:
+            return self._candidates_locked()
+
     def note_assignments(
-        self, ips: Sequence[str], slots: np.ndarray
+        self, ips: Sequence[str], slots: np.ndarray,
+        hashes: Optional[np.ndarray] = None,
     ) -> None:
         """Refresh the slot→hash table for one batch's DISTINCT
         (ip, slot) pairs — the same unique tables the slot manager just
         walked.  Only slots whose owner changed scatter to the device;
-        a warm table uploads nothing."""
+        a warm table uploads nothing.  `hashes`: the addresses' base
+        hashes where the caller has them from its one encoding of the
+        batch (native/slotmgr.py crc32_spans); without them each address
+        is hashed here."""
         n = len(ips)
         if n == 0:
             return
         slots = np.asarray(slots, dtype=np.int64)
+        if hashes is None:
+            hashes = self.base_hashes(ips)
         with self._lock:
-            cand = self._candidates
-            hashes = np.empty(n, dtype=np.uint32)
-            for k, ip in enumerate(ips):
-                h = cand.get(ip)
-                if h is None:
-                    h = hash_ip(ip)
-                cand[ip] = h  # insert or refresh recency
-                cand.move_to_end(ip)
-                hashes[k] = h
-            while len(cand) > self.max_candidates:
-                cand.popitem(last=False)
+            # recency of the candidate set, in bulk (see __init__)
+            self._cand_log.append((ips, hashes))
+            self._cand_log_len += n
+            if self._cand_log_len > 4 * self.max_candidates:
+                self._candidates_locked()
 
             need = int(slots.max()) + 1
             if need > self._slot_hash_host.size:
@@ -261,8 +301,10 @@ class TrafficSketch:
                 idx[: len(ch_slots)] = ch_slots
                 val = np.zeros(kk, dtype=np.uint32)
                 val[: len(ch_hash)] = ch_hash
+                # the operands go in as they are: the call transfers
+                # them itself, one trip through the runtime, not three
                 self._slot_hash_dev = _scatter_hashes(
-                    self._slot_hash_dev, jnp.asarray(idx), jnp.asarray(val)
+                    self._slot_hash_dev, idx, val
                 )
 
     # ---- the per-chunk device update ----
@@ -389,10 +431,11 @@ class TrafficSketch:
             self._last_pull_mono = time.monotonic()
 
             top: List[dict] = []
-            if self._candidates:
-                ips = list(self._candidates)
+            cand = self._candidates_locked()
+            if cand:
+                ips = list(cand)
                 base = np.fromiter(
-                    self._candidates.values(), dtype=np.uint32, count=len(ips)
+                    cand.values(), dtype=np.uint32, count=len(ips)
                 )
                 est = None
                 for j in range(self.depth):
@@ -433,7 +476,7 @@ class TrafficSketch:
                     "depth": self.depth,
                     "width": self.width,
                     "hll_registers": self.m,
-                    "candidates": len(self._candidates),
+                    "candidates": len(cand),
                     "pull_count": self.pull_count,
                     "pull_bytes_total": self.pull_bytes_total,
                 },

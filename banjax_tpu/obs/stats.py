@@ -143,6 +143,13 @@ class MatcherStats:
                 out["SketchAdmissionFpRate"] = round(
                     device_windows.sketch_admission_fp_rate, 4
                 )
+            if hasattr(device_windows, "gate_derived_batches"):
+                out["SubmitGateDerivedBatches"] = (
+                    device_windows.gate_derived_batches
+                )
+                out["SubmitResolveSeconds"] = round(
+                    getattr(matcher, "submit_resolve_s", 0.0), 6
+                )
             if getattr(device_windows, "_warm", None) is not None:
                 out["WarmTierSpills"] = device_windows.warm_spills
                 out["WarmTierRefills"] = device_windows.warm_refills

@@ -118,12 +118,72 @@ def span_names():
 
 
 @pytest.mark.parametrize(
-    "span", _host_spans() + ["program-ab-fused", "effector-replay"]
+    "span",
+    _host_spans() + ["program-ab-fused", "effector-replay", "submit-resolve"],
 )
 def test_host_span_the_benchmark_names_is_opened(span_names, span):
-    """`benchmark/trace_names.json` `host_spans`, and the two documented
-    spans of the fused path beside them."""
+    """`benchmark/trace_names.json` `host_spans`, and the documented spans
+    of the fused path beside them: the program's dispatch, the drain's
+    replay, and the submit stage's one pass over a batch's addresses,
+    whose seconds `resolve_ms_per_kline` reads."""
     assert span in span_names, sorted(span_names)
+
+
+_RESOLVE_FAMILIES = {
+    "banjax_submit_resolve_addresses_total":
+        [{"outcome": o} for o in
+         ("hit", "shadow", "warm", "unseen", "refused")],
+    "banjax_submit_resolve_probes_total":
+        [{"table": "slots"}, {"table": "warm"}],
+    "banjax_submit_gate_derived_batches_total": [{}],
+    "banjax_submit_resolve_seconds_total": [{}],
+}
+
+
+@pytest.mark.parametrize("family", sorted(_RESOLVE_FAMILIES))
+def test_resolve_family_is_on_metrics_with_its_labels(family):
+    """The families of the submit stage's address resolution (ISSUE 32),
+    as the benchmark's own parser reads them off `/metrics`: declared,
+    exported with the labels the readers select by, and moving when a
+    stream went through the fused path."""
+    from banjax_tpu.decisions.dynamic_lists import DynamicDecisionLists
+    from banjax_tpu.decisions.rate_limit import (
+        FailedChallengeRateLimitStates,
+    )
+    from banjax_tpu.obs.exposition import render_prometheus
+    from benchmark.harness import prom
+
+    assert family in {f.prom for f in registry.FAMILIES}
+    now = time.time()
+    cfg = config_from_yaml_text(_RULES)
+    cfg.matcher_device_windows = True
+    cfg.slot_admission_enabled = True
+    cfg.warm_tier_enabled = True
+    cfg.warm_tier_capacity = 1024
+    m = TpuMatcher(cfg, MockBanner(), StaticDecisionLists(cfg),
+                   RegexRateLimitStates())
+    sched = PipelineScheduler(lambda: m, now_fn=lambda: now)
+    sched.start()
+    for k in range(3):
+        sched.submit([
+            f"{now:.6f} 1.2.{k}.{i % 7} GET h.com GET /page{i} HTTP/1.1 ua -"
+            for i in range(30)
+        ])
+        assert sched.flush(120)
+    sched.stop()
+    snap = prom.parse(render_prometheus(
+        DynamicDecisionLists(start_sweeper=False), RegexRateLimitStates(),
+        FailedChallengeRateLimitStates(), matcher=m,
+    ))
+    for labels in _RESOLVE_FAMILIES[family]:
+        assert prom.value(snap, family, **labels) is not None, labels
+    assert prom.value(snap, "banjax_submit_resolve_seconds_total") > 0
+    # threshold 3 here (hits_per_interval 2): the sketch is asked about
+    # the unseen addresses of a batch, so not every verdict is derived
+    distinct = prom.value(snap, "banjax_submit_resolve_addresses_total")
+    assert distinct == prom.value(
+        snap, "banjax_submit_resolve_probes_total", table="slots")
+    m.close()
 
 
 @pytest.mark.parametrize("name,rel", _configurations())
