@@ -358,12 +358,14 @@ class Config:
     # warm tier: on device-slot eviction the victim's per-rule window
     # vector spills into a shared-memory host table (native/shmstate.c
     # wt_*) instead of living in the unbounded Python shadow dict, and
-    # refills into a slot on re-admission.  A record is 128 bytes + 24
-    # per loaded rule, and every position has an 8-byte tag in a dense
-    # index that the probes walk.  The segment is mapped at capacity x
-    # (8 + record) bytes, but only the tags (8 MiB at 2^20) and the
-    # records that were written are ever resident: a lookup of an
-    # address the tier does not hold reads tags only.
+    # refills into a slot on re-admission.  A record is a chain of
+    # 256-byte blocks (128 bytes + 24 per counter the address HOLDS), and
+    # every position has an 8-byte tag in a dense index that the probes
+    # walk, and an 8-byte head.  The segment is mapped at capacity x (16
+    # + 256 x the blocks of a full record, at most 16) bytes, but only
+    # the tags and heads (16 MiB at 2^20) and the blocks that were
+    # written are ever resident: a lookup of an address the tier does not
+    # hold reads tags only.
     warm_tier_enabled: bool = False
     warm_tier_capacity: int = 1 << 20   # entries (rounded up to 2^n)
     # --- multi-host decision fabric (banjax_tpu/fabric/) ---

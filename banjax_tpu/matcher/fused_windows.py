@@ -415,6 +415,9 @@ class FusedWindowsPipeline:
             buf = np.asarray(p.sparse_buf)
             p.d2h_bytes += buf.nbytes
             off = self._decode_head(p, buf)
+            # the rows stage 2 scanned: the gate's candidates, as far
+            # as the program has room for them
+            self.pf.candidates_total += min(int(p.flags[1]), p.K)
             if not p.flags[0]:
                 raise self._overflow(p)
             p.events_buf = buf

@@ -496,6 +496,9 @@ class FusedPrefilter:
         self._a_empty = np.asarray(s1.empty_only[: plan.n_always], dtype=bool)
         self._nf8 = -(-self._n_filt // 8)
         self._na8 = -(-plan.n_always // 8) if plan.n_always else 0
+        # lines stage 1's gate passed on to stage 2, whichever program
+        # ran it (banjax_prefilter_candidates_total)
+        self.candidates_total = 0
 
     # ---- device program ----
 
@@ -834,6 +837,7 @@ class FusedPrefilter:
         # gap is the superimposition + factor false-positive cost that
         # stage 2 pays for). bench reports it as prefilter_gate_fraction.
         self.last_n_cand = n_cand
+        self.candidates_total += min(n_cand, K)
         if n_cand > K:
             raise PrefilterOverflow(f"{n_cand} candidates > capacity {K}")
         if n_pairs > P:

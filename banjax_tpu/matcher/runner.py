@@ -53,6 +53,7 @@ from banjax_tpu.matcher.workset import (
     unique_spans,
 )
 from banjax_tpu.matcher.rulec import compile_rules
+from banjax_tpu.matcher.rulecache import RuleCache
 from banjax_tpu.obs import flightrec, provenance, trace
 from banjax_tpu.resilience import failpoints
 from banjax_tpu.resilience.breaker import CLOSED, CircuitBreaker
@@ -172,8 +173,15 @@ class TpuMatcher(Matcher):
                 self._mesh_rp = rp
                 n_shards = rp
 
-        self.compiled = compile_rules(
-            [r.regex_string for _, r in self._entries], n_shards=n_shards
+        # the three compiled forms of the ruleset, loaded from beside the
+        # compile cache where an earlier start of this ruleset left them
+        # (matcher/rulecache.py; `rules_cache.seconds` / `.source` are
+        # banjax_rules_compile_seconds{source})
+        patterns = [r.regex_string for _, r in self._entries]
+        self.rules_cache = RuleCache(self._entries)
+        self.compiled = self.rules_cache.get(
+            f"single-{n_shards}",
+            lambda: compile_rules(patterns, n_shards=n_shards),
         )
         for i, reason in self.compiled.unsupported.items():
             log.info(
@@ -385,8 +393,8 @@ class TpuMatcher(Matcher):
                 # classes are shard-independent by rulec construction —
                 # encode uses self.compiled's table, so check the invariant
                 # rather than trust it
-                comp = compile_rules(
-                    [r.regex_string for _, r in self._entries], n_shards="auto"
+                comp = self.rules_cache.get(
+                    "slabs", lambda: compile_rules(patterns, n_shards="auto")
                 )
                 if not np.array_equal(
                     comp.byte_to_class, self.compiled.byte_to_class
@@ -410,12 +418,12 @@ class TpuMatcher(Matcher):
             from banjax_tpu.matcher.prefilter import FusedPrefilter, build_plan
 
             try:
-                plan = build_plan(
-                    [r.regex_string for _, r in self._entries],
+                plan = self.rules_cache.get("plan", lambda: build_plan(
+                    patterns,
                     byte_classes=(
                         self.compiled.byte_to_class, self.compiled.n_classes
                     ),
-                )
+                ))
             except Exception as e:  # noqa: BLE001 — a plan bug must not kill the matcher
                 log.exception("prefilter plan construction failed")
                 self._note_downgrade(

@@ -1261,6 +1261,10 @@ class DeviceWindows:
                 self._warm.clear()
             self._sketch_pending.clear()
             self._sketch_slots.clear()
+            # the old table goes before the new one is made: at 10,000
+            # rules x 65,536 slots one is 10.5 GB of a 16 GB chip
+            for leaf in jax.tree_util.tree_leaves(self._state):
+                leaf.delete()
             self._state = self._fresh_state()
 
     def __len__(self) -> int:
@@ -1284,6 +1288,18 @@ class DeviceWindows:
     @property
     def warm_dropped(self) -> int:
         return int(self._warm.dropped) if self._warm is not None else 0
+
+    @property
+    def warm_bytes_written(self) -> int:
+        """Bytes the tier's puts wrote (128 a record + 24 a counter + 8 a
+        further block, in either implementation)."""
+        return int(getattr(self._warm, "bytes_written", 0))
+
+    @property
+    def table_bytes(self) -> int:
+        """Device bytes of the window table: 16 a (slot, rule) — hits,
+        start_s, start_ns and key_gen, int32 each — and 5 a slot."""
+        return self.capacity * (16 * self.n_rules + 5)
 
     @property
     def warm_probes(self) -> int:

@@ -118,11 +118,14 @@ def _load() -> Optional[ctypes.CDLL]:
         lib.fc_test_slot_owner.argtypes = [vp, ctypes.c_int64]
         u8p = ctypes.POINTER(ctypes.c_uint8)
         lib.wt_init.restype = ctypes.c_int64
-        lib.wt_init.argtypes = [vp, ctypes.c_int64, ctypes.c_int64]
+        lib.wt_init.argtypes = [
+            vp, ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
+        ]
         lib.wt_check.restype = ctypes.c_int64
         lib.wt_check.argtypes = [vp]
         for fn in (lib.wt_max_rules, lib.wt_len, lib.wt_dropped,
-                   lib.wt_probes, lib.wt_record_reads):
+                   lib.wt_probes, lib.wt_record_reads, lib.wt_bytes_written,
+                   lib.wt_blocks_used):
             fn.restype = ctypes.c_int64
             fn.argtypes = [vp]
         lib.wt_clear.restype = None
@@ -340,8 +343,29 @@ WarmEntries = List[Tuple[int, int, int, int]]
 
 WT_KEY_MAX = 104
 WT_TAG_BYTES = 8
+WT_HEAD_BYTES = 8
 WT_REC_HEADER_BYTES = 128
 WT_ENTRY_BYTES = 24
+# a record is a chain of 256-byte blocks: the first holds the record
+# header and 5 entries, each further one 10 (wt_rec / wt_cont)
+WT_BLOCK_BYTES = 256
+WT_HEAD_ENTRIES = 5
+WT_CONT_ENTRIES = 10
+# the arena holds a full record for every position, at most this many
+# blocks a position (4 KiB: what a record of the fixed-stride layout
+# made resident, a page of its own); past it a put is dropped and counted
+WT_MAX_BLOCKS_PER_POSITION = 16
+
+
+def wt_record_blocks(n_entries: int) -> int:
+    """Blocks a record of `n_entries` counters takes."""
+    return 1 + -(-max(0, n_entries - WT_HEAD_ENTRIES) // WT_CONT_ENTRIES)
+
+
+def wt_record_bytes(n_entries: int) -> int:
+    """What a put of `n_entries` counters writes (wt_fill's count)."""
+    return (WT_REC_HEADER_BYTES + WT_ENTRY_BYTES * n_entries
+            + 8 * (wt_record_blocks(n_entries) - 1))
 
 
 def _wt_key(ip: str) -> bytes:
@@ -374,13 +398,16 @@ class ShmWarmTier:
         self.capacity = cap
         self.max_rules = max(1, int(max_rules))
         self.expiry_ns = int(expiry_ns)
-        stride = WT_REC_HEADER_BYTES + self.max_rules * WT_ENTRY_BYTES
-        size = HEADER_BYTES + cap * (WT_TAG_BYTES + stride)
+        n_blocks = cap * min(
+            wt_record_blocks(self.max_rules), WT_MAX_BLOCKS_PER_POSITION
+        )
+        size = (HEADER_BYTES + cap * (WT_TAG_BYTES + WT_HEAD_BYTES)
+                + n_blocks * WT_BLOCK_BYTES)
         if name is None:
             self._shm = shared_memory.SharedMemory(create=True, size=size)
             self.owner = True
             self._map_base()
-            if lib.wt_init(self._base_ptr, cap, self.max_rules) != 0:
+            if lib.wt_init(self._base_ptr, cap, self.max_rules, n_blocks) != 0:
                 raise ValueError(f"bad warm-tier geometry {cap}x{max_rules}")
         else:
             self._shm = shared_memory.SharedMemory(name=name)
@@ -555,12 +582,14 @@ class ShmWarmTier:
         base = self._base_ptr
         if n == 0 or base is None:
             return [None] * n
-        mr = self.max_rules
+        # room for n full records; the C side writes the entries one
+        # record after the other, so only what the records hold is touched
+        room = n * self.max_rules
         n_out = np.empty(n, dtype=np.int32)
-        rid = np.empty((n, mr), dtype=np.int32)
-        hits = np.empty((n, mr), dtype=np.int32)
-        ss = np.empty((n, mr), dtype=np.int64)
-        sns = np.empty((n, mr), dtype=np.int64)
+        rid = np.empty(room, dtype=np.int32)
+        hits = np.empty(room, dtype=np.int32)
+        ss = np.empty(room, dtype=np.int64)
+        sns = np.empty(room, dtype=np.int64)
         i32p = ctypes.POINTER(ctypes.c_int32)
         i64p = ctypes.POINTER(ctypes.c_int64)
         _keep, ptrs = self._spans(ips, spans)
@@ -569,14 +598,14 @@ class ShmWarmTier:
             rid.ctypes.data_as(i32p), hits.ctypes.data_as(i32p),
             ss.ctypes.data_as(i64p), sns.ctypes.data_as(i64p),
         )
-        # the records' entries, compacted in record order, then cut
-        # back into one mapping a record: no per-record array work
-        held = np.arange(mr, dtype=np.int32)[None, :] < n_out[:, None]
-        rids = rid[held].tolist()
-        states = list(zip(
-            hits[held].tolist(), ss[held].tolist(), sns[held].tolist()
-        ))
+        # the records' entries in record order, cut back into one
+        # mapping a record: no per-record array work
         ends = np.cumsum(np.maximum(n_out, 0)).tolist()
+        total = ends[-1]
+        rids = rid[:total].tolist()
+        states = list(zip(
+            hits[:total].tolist(), ss[:total].tolist(), sns[:total].tolist()
+        ))
         out: List[Optional[dict]] = [None] * n
         for i in np.flatnonzero(n_out >= 0).tolist():
             a, b = ends[i] - int(n_out[i]), ends[i]
@@ -627,6 +656,17 @@ class ShmWarmTier:
         the tag index)."""
         return self._header(self._lib.wt_record_reads)
 
+    @property
+    def bytes_written(self) -> int:
+        """Bytes puts wrote into the arena: 128 a record + 24 a counter
+        + 8 a further block (wt_record_bytes)."""
+        return self._header(self._lib.wt_bytes_written)
+
+    @property
+    def blocks_used(self) -> int:
+        """256-byte arena blocks live records hold."""
+        return self._header(self._lib.wt_blocks_used)
+
     def clear(self) -> None:
         base = self._base_ptr
         if base is not None:
@@ -663,6 +703,7 @@ class PyWarmTier:
         self.max_rules = max(1, int(max_rules))
         self.expiry_ns = int(expiry_ns)
         self._dropped = 0
+        self.bytes_written = 0  # as the C table counts them
         # ip -> (stamp_ns, entries); order = last-touch (stalest first)
         from collections import OrderedDict
 
@@ -675,6 +716,7 @@ class PyWarmTier:
         if ip in self._d:
             self._d[ip] = (now_ns, entries)
             self._d.move_to_end(ip)
+            self.bytes_written += wt_record_bytes(len(entries))
             return True
         if len(self._d) >= self.capacity:
             stale_ip, (stamp, _) = next(iter(self._d.items()))
@@ -685,6 +727,7 @@ class PyWarmTier:
                 self._dropped += 1
                 return False
         self._d[ip] = (now_ns, entries)
+        self.bytes_written += wt_record_bytes(len(entries))
         return True
 
     def take(self, ip: str) -> Optional[WarmEntries]:

@@ -311,22 +311,32 @@ int32_t fc_test_slot_owner(void *base, int64_t idx) {
  * round-trip must hand back byte-identical state.
  *
  * Layout: one 128-byte wt_header, then capacity (power of two) 8-byte
- * tags, then capacity records of (128-byte record header + max_rules
- * wt_entry): 128 + 24/rule bytes a record, 24,128 at 1,000 rules.  The
- * tag of a position says what its record holds: 0 = empty, 1 =
+ * tags, then capacity 8-byte heads, then an arena of n_blocks 256-byte
+ * blocks.  A record is a chain of blocks: its first holds the record
+ * header (key, stamp, entry count) and 5 entries, each further one 10
+ * entries, so a record takes the room the counters it holds need — 256
+ * bytes for an address with two counters, whatever the ruleset's size —
+ * and not a stride of 128 + 24 bytes per LOADED rule (24,128 bytes at
+ * 1,000 rules, 240,128 at 10,000, a 252 GB mapping at 2^20 positions).
+ * Blocks are all alike: one bump pointer and one free list, nothing to
+ * fragment.  The arena is sized by the caller (shm.py: what every
+ * position needs for a full record, at most 16 blocks a position); a put
+ * that finds it exhausted is dropped and counted like one that finds its
+ * probe window full.
+ *
+ * The tag of a position says what its record holds: 0 = empty, 1 =
  * tombstone, anything else = the key's 64-bit hash (0 and 1 moved to 2
- * and 3).  Open addressing, linear probe bounded at WT_MAX_PROBE, and
- * every probe walks the TAGS: a record's memory is read only where the
- * tag equals the key's, and then its key is compared.  A lookup of an
- * absent key therefore touches a cache line or two of the dense tag
- * array and no record — at a 24 KB stride each record is a page of its
- * own, and the first read of a never-written page of the mapping is a
- * page fault that allocates and zeroes it.  Record pages exist only
- * where a put wrote one.
+ * and 3); its head is the arena index of the record's first block.
+ * Open addressing, linear probe bounded at WT_MAX_PROBE, and every probe
+ * walks the TAGS: a record's memory is read only where the tag equals
+ * the key's, and then its key is compared.  A lookup of an absent key
+ * therefore touches a cache line or two of the dense tag array and no
+ * record, and no page of the arena exists before a put wrote it.
  *
  * Unlike the fc_* table above, take() deletes — it leaves a tombstone
  * tag (probes continue past it; key search may still early-stop on a
- * genuine empty because inserts never skip one).
+ * genuine empty because inserts never skip one) and gives the record's
+ * blocks back.
  *
  * Concurrency: NONE here by design.  The only caller is DeviceWindows,
  * which already serializes every slot/shadow mutation under its own
@@ -338,35 +348,37 @@ int32_t fc_test_slot_owner(void *base, int64_t idx) {
  * victim); otherwise the new put is dropped and counted — bounded
  * memory, never silent.  Only this path reads stamps, 64 records' worth.
  *
- * The header counts keys looked up (probes) and records whose memory a
- * lookup read (record_reads); their quotient is the share of lookups
- * that had to leave the tag array.
+ * The header counts keys looked up (probes), records whose memory a
+ * lookup read (record_reads; their quotient is the share of lookups
+ * that had to leave the tag array) and the bytes puts wrote into the
+ * arena (bytes_written: 128 a record + 24 an entry + 8 a further block).
  */
 
-#define WT_MAGIC 0x626a787774303032LL /* "bjxwt002" — tag index */
+#define WT_MAGIC 0x626a787774303033LL /* "bjxwt003" — block-chained records */
 #define WT_MAX_PROBE 64
 #define WT_KEY_MAX 104
 #define WT_TAG_EMPTY 0ULL
 #define WT_TAG_TOMBSTONE 1ULL
+#define WT_BLOCK 256
+#define WT_HEAD_ENTRIES 5
+#define WT_CONT_ENTRIES 10
 
 typedef struct {
     int64_t magic;
-    int64_t capacity;  /* records; power of two */
-    int64_t max_rules; /* wt_entry slots per record */
+    int64_t capacity;  /* positions; power of two */
+    int64_t max_rules; /* most entries a record may hold */
     int64_t count;     /* live records */
-    int64_t dropped;   /* puts lost to a full, unexpired probe window */
+    int64_t dropped;   /* puts lost to a full, unexpired probe window or
+                          to an exhausted arena */
     int64_t probes;    /* keys looked up by put/take/get/contains_batch */
     int64_t record_reads; /* records whose memory those lookups read */
-    int64_t _pad[9];
+    int64_t n_blocks;  /* arena size */
+    int64_t bump;      /* blocks ever handed out from the arena's end */
+    int64_t free_head; /* free list: block index + 1, 0 = empty */
+    int64_t free_count;
+    int64_t bytes_written;
+    int64_t _pad[4];
 } wt_header; /* 128 bytes */
-
-typedef struct {
-    int32_t key_len;
-    int32_t n_entries;
-    int64_t stamp_ns; /* last-touch; the steal policy's staleness key */
-    char key[WT_KEY_MAX];
-    int64_t _pad;
-} wt_rec; /* 128 bytes; followed in memory by max_rules wt_entry */
 
 typedef struct {
     int32_t rule_id;
@@ -375,35 +387,62 @@ typedef struct {
     int64_t start_ns;
 } wt_entry; /* 24 bytes */
 
-static inline int64_t wt_stride(const wt_header *h) {
-    return (int64_t)sizeof(wt_rec) + h->max_rules * (int64_t)sizeof(wt_entry);
-}
+/* every block begins with its chain link: block index + 1, 0 = last */
+typedef struct {
+    int64_t next;
+    int32_t key_len;
+    int32_t n_entries;
+    int64_t stamp_ns; /* last-touch; the steal policy's staleness key */
+    char key[WT_KEY_MAX];
+    wt_entry e[WT_HEAD_ENTRIES];
+    int64_t _pad;
+} wt_rec; /* one block: the record header and its first entries */
+
+typedef struct {
+    int64_t next;
+    wt_entry e[WT_CONT_ENTRIES];
+    int64_t _pad;
+} wt_cont; /* one block: ten further entries */
+
+_Static_assert(sizeof(wt_header) == 128, "wt_header is 128 bytes");
+_Static_assert(sizeof(wt_rec) == WT_BLOCK, "wt_rec is one block");
+_Static_assert(sizeof(wt_cont) == WT_BLOCK, "wt_cont is one block");
 
 static inline uint64_t *wt_tags(void *base) {
     return (uint64_t *)((char *)base + sizeof(wt_header));
 }
 
-static inline wt_rec *wt_at(void *base, int64_t i) {
+static inline int64_t *wt_heads(void *base) {
+    return (int64_t *)(wt_tags(base) + ((wt_header *)base)->capacity);
+}
+
+static inline char *wt_block(void *base, int64_t b) {
     wt_header *h = (wt_header *)base;
-    return (wt_rec *)((char *)(wt_tags(base) + h->capacity) +
-                      i * wt_stride(h));
+    return (char *)(wt_heads(base) + h->capacity) + b * WT_BLOCK;
 }
 
-static inline wt_entry *wt_entries(wt_rec *r) {
-    return (wt_entry *)((char *)r + sizeof(wt_rec));
+static inline wt_rec *wt_at(void *base, int64_t i) {
+    return (wt_rec *)wt_block(base, wt_heads(base)[i]);
 }
 
-int64_t wt_init(void *base, int64_t capacity, int64_t max_rules) {
+static inline int64_t wt_blocks_for(int64_t n) {
+    return n <= WT_HEAD_ENTRIES
+               ? 1
+               : 1 + (n - WT_HEAD_ENTRIES + WT_CONT_ENTRIES - 1) /
+                         WT_CONT_ENTRIES;
+}
+
+int64_t wt_init(void *base, int64_t capacity, int64_t max_rules,
+                int64_t n_blocks) {
     /* caller provides zeroed memory; capacity must be a power of 2 */
-    if (capacity <= 0 || (capacity & (capacity - 1)) || max_rules <= 0)
+    if (capacity <= 0 || (capacity & (capacity - 1)) || max_rules <= 0 ||
+        n_blocks <= 0)
         return -1;
     wt_header *h = (wt_header *)base;
+    memset(h, 0, sizeof(*h));
     h->capacity = capacity;
     h->max_rules = max_rules;
-    h->count = 0;
-    h->dropped = 0;
-    h->probes = 0;
-    h->record_reads = 0;
+    h->n_blocks = n_blocks;
     h->magic = WT_MAGIC;
     return 0;
 }
@@ -427,29 +466,97 @@ int64_t wt_record_reads(void *base) {
     return ((wt_header *)base)->record_reads;
 }
 
+int64_t wt_bytes_written(void *base) {
+    return ((wt_header *)base)->bytes_written;
+}
+
+/* blocks of the arena in use by live records */
+int64_t wt_blocks_used(void *base) {
+    wt_header *h = (wt_header *)base;
+    return h->bump - h->free_count;
+}
+
 void wt_clear(void *base) {
+    /* zeroes the tags and forgets the arena; walks no record */
     wt_header *h = (wt_header *)base;
     memset(wt_tags(base), 0, (size_t)h->capacity * sizeof(uint64_t));
     h->count = 0;
     h->dropped = 0;
+    h->bump = 0;
+    h->free_head = 0;
+    h->free_count = 0;
 }
 
-static void wt_fill(void *base, int64_t idx, uint64_t tag, const char *key,
-                    int32_t key_len, int64_t now_ns, const int32_t *rule_ids,
-                    const int32_t *hits, const int64_t *ss,
-                    const int64_t *sns, int64_t n) {
-    wt_rec *r = wt_at(base, idx);
+static int64_t wt_alloc(void *base) {
+    /* the caller has checked that a block is left */
+    wt_header *h = (wt_header *)base;
+    if (h->free_head) {
+        int64_t b = h->free_head - 1;
+        h->free_head = *(int64_t *)wt_block(base, b);
+        h->free_count--;
+        return b;
+    }
+    return h->bump++;
+}
+
+/* give the chain that starts at block index + 1 `link` back */
+static void wt_free_chain(void *base, int64_t link) {
+    wt_header *h = (wt_header *)base;
+    while (link) {
+        int64_t *b = (int64_t *)wt_block(base, link - 1);
+        int64_t next = *b;
+        *b = h->free_head;
+        h->free_head = link;
+        h->free_count++;
+        link = next;
+    }
+}
+
+/* Write key's record at position idx.  `first` is the first block of
+ * the chain the position already owns (its own or a stolen record's),
+ * -1 when it owns none; the chain is reused, lengthened or cut to the
+ * blocks n entries need (the caller has checked that they can be had). */
+static void wt_fill(void *base, int64_t idx, int64_t first, uint64_t tag,
+                    const char *key, int32_t key_len, int64_t now_ns,
+                    const int32_t *rule_ids, const int32_t *hits,
+                    const int64_t *ss, const int64_t *sns, int64_t n) {
+    wt_header *h = (wt_header *)base;
+    int64_t old = 0; /* link to the rest of the owned chain */
+    if (first < 0)
+        first = wt_alloc(base);
+    else
+        old = ((wt_rec *)wt_block(base, first))->next;
+    wt_rec *r = (wt_rec *)wt_block(base, first);
     memcpy(r->key, key, (size_t)key_len);
     r->key_len = key_len;
     r->stamp_ns = now_ns;
     r->n_entries = (int32_t)n;
-    wt_entry *e = wt_entries(r);
+    h->bytes_written += 128 + n * (int64_t)sizeof(wt_entry);
+    int64_t *link = &r->next;
+    wt_entry *e = r->e;
+    int64_t room = WT_HEAD_ENTRIES;
     for (int64_t k = 0; k < n; k++) {
-        e[k].rule_id = rule_ids[k];
-        e[k].hits = hits[k];
-        e[k].start_s = ss[k];
-        e[k].start_ns = sns[k];
+        if (room == 0) {
+            int64_t b = old ? old - 1 : wt_alloc(base);
+            wt_cont *c = (wt_cont *)wt_block(base, b);
+            if (old)
+                old = c->next;
+            *link = b + 1;
+            link = &c->next;
+            e = c->e;
+            room = WT_CONT_ENTRIES;
+            h->bytes_written += 8;
+        }
+        e->rule_id = rule_ids[k];
+        e->hits = hits[k];
+        e->start_s = ss[k];
+        e->start_ns = sns[k];
+        e++;
+        room--;
     }
+    *link = 0;
+    wt_free_chain(base, old);
+    wt_heads(base)[idx] = first;
     wt_tags(base)[idx] = tag;
 }
 
@@ -494,7 +601,8 @@ static int64_t wt_find(void *base, const char *key, int32_t key_len,
 }
 
 /* Spill one IP's window vector.  Returns 0 (inserted/updated) or -1
- * (dropped: probe window full of live records younger than expiry). */
+ * (dropped: probe window full of live records younger than expiry, or
+ * no block left in the arena). */
 int64_t wt_put(void *base, const char *key, int32_t key_len, int64_t now_ns,
                int64_t expiry_ns, const int32_t *rule_ids,
                const int32_t *hits, const int64_t *ss, const int64_t *sns,
@@ -506,12 +614,14 @@ int64_t wt_put(void *base, const char *key, int32_t key_len, int64_t now_ns,
         n = h->max_rules;
     uint64_t hash = fc_hash(key, key_len);
     int64_t insert_at;
-    int64_t at = wt_find(base, key, key_len, hash, &insert_at);
-    if (at < 0 && insert_at >= 0) {
+    int64_t own = wt_find(base, key, key_len, hash, &insert_at);
+    int64_t at = own;
+    int64_t first = -1; /* the chain position `at` already owns */
+    if (at >= 0) {
+        first = wt_heads(base)[at];
+    } else if (insert_at >= 0) {
         at = insert_at;
-        h->count++;
-    }
-    if (at < 0) {
+    } else {
         /* all WT_MAX_PROBE positions hold other keys: find the stalest,
          * the first of the window among equals */
         uint64_t mask = (uint64_t)h->capacity - 1;
@@ -530,22 +640,52 @@ int64_t wt_put(void *base, const char *key, int32_t key_len, int64_t now_ns,
          * is semantically a restart-as-first-seen, like fc_apply */
         if (at < 0 || now_ns - stalest_ns <= expiry_ns)
             return -1;
+        first = wt_heads(base)[at];
     }
-    wt_fill(base, at, wt_tag(hash), key, key_len, now_ns, rule_ids, hits, ss,
-            sns, n);
+    int64_t have =
+        first < 0 ? 0
+                  : wt_blocks_for(((wt_rec *)wt_block(base, first))->n_entries);
+    if (wt_blocks_for(n) - have > h->free_count + (h->n_blocks - h->bump)) {
+        /* the arena is exhausted.  The caller keeps a dropped record's
+         * state where it was, so an older copy of it must not stay here */
+        if (own >= 0) {
+            wt_tags(base)[own] = WT_TAG_TOMBSTONE;
+            h->count--;
+            wt_free_chain(base, first + 1);
+        }
+        /* a steal has counted its loss already: it is the put's now,
+         * and the victim stays */
+        if (own >= 0 || insert_at >= 0)
+            h->dropped++;
+        return -1;
+    }
+    if (own < 0 && insert_at >= 0)
+        h->count++;
+    wt_fill(base, at, first, wt_tag(hash), key, key_len, now_ns, rule_ids,
+            hits, ss, sns, n);
     return 0;
 }
 
-static int64_t wt_copy_out(wt_rec *r, int32_t *rule_ids_out,
+static int64_t wt_copy_out(void *base, wt_rec *r, int32_t *rule_ids_out,
                            int32_t *hits_out, int64_t *ss_out,
                            int64_t *sns_out) {
     int64_t n = r->n_entries;
-    wt_entry *e = wt_entries(r);
+    wt_entry *e = r->e;
+    int64_t room = WT_HEAD_ENTRIES;
+    int64_t link = r->next;
     for (int64_t k = 0; k < n; k++) {
-        rule_ids_out[k] = e[k].rule_id;
-        hits_out[k] = e[k].hits;
-        ss_out[k] = e[k].start_s;
-        sns_out[k] = e[k].start_ns;
+        if (room == 0) {
+            wt_cont *c = (wt_cont *)wt_block(base, link - 1);
+            link = c->next;
+            e = c->e;
+            room = WT_CONT_ENTRIES;
+        }
+        rule_ids_out[k] = e->rule_id;
+        hits_out[k] = e->hits;
+        ss_out[k] = e->start_s;
+        sns_out[k] = e->start_ns;
+        e++;
+        room--;
     }
     return n;
 }
@@ -561,7 +701,7 @@ int64_t wt_get(void *base, const char *key, int32_t key_len,
     int64_t at = wt_find(base, key, key_len, fc_hash(key, key_len), NULL);
     if (at < 0)
         return -1;
-    return wt_copy_out(wt_at(base, at), rule_ids_out, hits_out, ss_out,
+    return wt_copy_out(base, wt_at(base, at), rule_ids_out, hits_out, ss_out,
                        sns_out);
 }
 
@@ -577,8 +717,10 @@ int64_t wt_take(void *base, const char *key, int32_t key_len,
         return -1;
     wt_tags(base)[at] = WT_TAG_TOMBSTONE;
     h->count--;
-    return wt_copy_out(wt_at(base, at), rule_ids_out, hits_out, ss_out,
-                       sns_out);
+    int64_t n = wt_copy_out(base, wt_at(base, at), rule_ids_out, hits_out,
+                            ss_out, sns_out);
+    wt_free_chain(base, wt_heads(base)[at] + 1);
+    return n;
 }
 
 /* Copy live keys out (table order) for introspection.  keys_blob must
@@ -645,23 +787,25 @@ int64_t wt_put_batch(void *base, const uint8_t *blob, const int64_t *offs,
 }
 
 /* One call a batch for the refills of a placement: wt_take for each of n
- * keys, in order.  Key i's entries land at [i * max_rules, ...) of the
- * four output arrays (max_rules as wt_max_rules gives it) and
- * n_out[i] is their count, -1 where the key is absent.  Returns the
- * number of records taken. */
+ * keys, in order.  The records' entries land one record after the other
+ * in the four output arrays (which hold n * max_rules entries, the most
+ * n records can have); n_out[i] is record i's count, -1 where the key is
+ * absent.  Returns the number of records taken. */
 int64_t wt_take_batch(void *base, const uint8_t *blob, const int64_t *offs,
                       const int64_t *lens, int64_t n, int32_t *n_out,
                       int32_t *rule_ids_out, int32_t *hits_out,
                       int64_t *ss_out, int64_t *sns_out) {
-    int64_t stride = ((wt_header *)base)->max_rules;
+    int64_t at = 0;
     int64_t taken = 0;
     for (int64_t i = 0; i < n; i++) {
-        int64_t at = i * stride;
         int64_t got = wt_take(base, (const char *)blob + offs[i],
                               (int32_t)lens[i], rule_ids_out + at,
                               hits_out + at, ss_out + at, sns_out + at);
         n_out[i] = (int32_t)got;
-        taken += got >= 0;
+        if (got >= 0) {
+            at += got;
+            taken++;
+        }
     }
     return taken;
 }

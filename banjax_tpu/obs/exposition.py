@@ -162,6 +162,12 @@ def render_prometheus(
         for cause, v in fw.overflow_causes.items():
             w.sample(fam, v, {"cause": cause})
 
+    # what this start spent on its rules, labeled by how it got them
+    rc = getattr(matcher, "rules_cache", None) if matcher else None
+    if rc is not None:
+        w.sample(registry.PROM_FAMILIES["banjax_rules_compile_seconds"],
+                 round(rc.seconds, 6), {"source": rc.source})
+
     # the submit stage's address resolution: what the pass found, and
     # the keys it handed to each table (prom-only labeled counters)
     dw = getattr(matcher, "device_windows", None) if matcher else None

@@ -139,6 +139,10 @@ FAMILIES: List[Family] = [
     Family(COUNTER, "device window capacity grows",
            line_key="DeviceWindowsGrows",
            prom="banjax_device_windows_grows_total"),
+    Family(GAUGE, "device bytes of the window table: 16 a (slot, rule) "
+           "and 5 a slot (10.5 GB at 65,536 slots x 10,000 rules)",
+           line_key="DeviceWindowsTableBytes",
+           prom="banjax_device_windows_table_bytes"),
     Family(GAUGE, "1 when the native C slot manager is live, 0 on the "
            "Python dict path", line_key="SlotMgrNative",
            prom="banjax_slotmgr_native"),
@@ -183,6 +187,11 @@ FAMILIES: List[Family] = [
            "over misses while absent keys touch no record)",
            line_key="WarmTierRecordReads",
            prom="banjax_warm_tier_record_reads_total"),
+    Family(COUNTER, "bytes the warm tier's puts wrote: 128 a record + 24 "
+           "a counter + 8 a further 256-byte block (divide by spills: the "
+           "mean record, whatever the ruleset's size)",
+           line_key="WarmTierBytesWritten",
+           prom="banjax_warm_tier_bytes_written_total"),
     # ---- the submit stage's address resolution (matcher/windows.py) ----
     Family(COUNTER, "distinct client addresses of submitted batches by "
            "what the one resolving pass found: hit (slot assigned), "
@@ -220,6 +229,15 @@ FAMILIES: List[Family] = [
            prom="banjax_mesh_shard_merge_ms_max"),
     Family(GAUGE, "1 when the two-stage literal prefilter is active",
            line_key="PrefilterActive", prom="banjax_prefilter_active"),
+    Family(COUNTER, "lines stage 1's factor gate passed on to stage 2 "
+           "(the rows the full automaton scanned; divide by lines: the "
+           "candidate rate)",
+           line_key="PrefilterCandidates",
+           prom="banjax_prefilter_candidates_total"),
+    Family(GAUGE, "seconds this start spent on its rules' compiled forms, "
+           "by whether they were compiled or loaded from beside the "
+           "compile cache (matcher/rulecache.py)",
+           prom="banjax_rules_compile_seconds", labels=("source",)),
     # ---- fused matcher+windows ----
     Family(COUNTER, "sync-path fused matcher+windows batches",
            line_key="PipelineFusedBatches",

@@ -124,6 +124,9 @@ class MatcherStats:
                 device_windows, "device_events", 0
             )
             out["DeviceWindowsGrows"] = getattr(device_windows, "grow_count", 0)
+            out["DeviceWindowsTableBytes"] = getattr(
+                device_windows, "table_bytes", 0
+            )
             # which slot-assignment path is live: the native C manager
             # (native/slotmgr.c) or the Python dict+LRU fallback/oracle
             out["SlotMgrNative"] = bool(
@@ -158,6 +161,9 @@ class MatcherStats:
                 out["WarmTierCapacity"] = device_windows.warm_capacity
                 out["WarmTierProbes"] = device_windows.warm_probes
                 out["WarmTierRecordReads"] = device_windows.warm_record_reads
+                out["WarmTierBytesWritten"] = getattr(
+                    device_windows, "warm_bytes_written", 0
+                )
         if matcher is not None:
             mm = getattr(matcher, "_mesh_matcher", None)
             if mm is not None:
@@ -177,6 +183,9 @@ class MatcherStats:
                 )
             if getattr(matcher, "_prefilter", None) is not None:
                 out["PrefilterActive"] = True
+                out["PrefilterCandidates"] = getattr(
+                    matcher._prefilter, "candidates_total", 0
+                )
             records = getattr(
                 getattr(matcher, "banner", None), "regex_ban_records", None
             )

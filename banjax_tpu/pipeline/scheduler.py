@@ -111,8 +111,8 @@ def resolve_encode_workers(v: int) -> int:
 
 class _Batch:
     __slots__ = ("lines", "matcher", "state", "t_encode_ms", "t_device_ms",
-                 "t0_device", "kind", "trace_id", "root_span", "e2e",
-                 "builds0")
+                 "t_service_ms", "t0_device", "kind", "trace_id", "root_span",
+                 "e2e", "builds0")
 
     def __init__(self, lines: List[str], kind: str = "lines"):
         self.lines = lines      # log lines, or _Command items (kind="cmd")
@@ -120,6 +120,10 @@ class _Batch:
         self.state = None       # split-protocol state; None = generic drain
         self.t_encode_ms = 0.0
         self.t_device_ms = 0.0
+        # the device stage's service time: from this batch's submit, or
+        # from its predecessor's collect if that came later, to its own
+        # collect (the sizer's sample; 0 = the batch was not collected)
+        self.t_service_ms = 0.0
         self.t0_device = 0.0
         # the matcher's count of device programs built, taken when the
         # batch starts: a batch during which it moved paid for a compile
@@ -200,6 +204,7 @@ class PipelineScheduler:
         self._marks: deque = deque()
         self._cond = threading.Condition()
         self._inflight = 0
+        self._last_collect_done = 0.0  # device thread only
         self._last_activity = time.monotonic()
         self._ring = threading.Semaphore(ring_size)
         self._q_dev: "queue.Queue" = queue.Queue()
@@ -656,7 +661,18 @@ class PipelineScheduler:
             )
             self._device_failure(batch, "collect")
         else:
-            batch.t_device_ms += (time.perf_counter() - t0) * 1e3
+            done = time.perf_counter()
+            batch.t_device_ms += (done - t0) * 1e3
+            # with two batches in flight, submit + collect counts the
+            # predecessor's device time once (the collect waits for it)
+            # or twice (the submit waits too), whichever way host and
+            # device happen to be in step: a stage time of d or of 2d for
+            # hundreds of batches on end.  What the stage took for THIS
+            # batch once queueing is subtracted has one reading
+            batch.t_service_ms = (
+                done - max(self._last_collect_done, batch.t0_device)
+            ) * 1e3
+            self._last_collect_done = done
             self.stats.observe_device(batch.t_device_ms / 1e3)
             note = getattr(batch.matcher, "note_device_outcome", None)
             if note is not None:
@@ -789,7 +805,10 @@ class PipelineScheduler:
                     "drain": t_drain_ms,
                 }
                 if self._builds(batch) == batch.builds0:
-                    self._sizer.observe(n, stage_ms)
+                    self._sizer.observe(n, {
+                        **stage_ms,
+                        "device": batch.t_service_ms or batch.t_device_ms,
+                    })
                 # labeled per-stage duration histograms for /metrics —
                 # recorded per batch regardless of tracing (the trace ring
                 # is the sampled view, the histogram the complete one)

@@ -12,7 +12,11 @@ observed per-stage timings instead:
   * per-stage (encode / device / drain) per-batch timings feed EWMAs;
     the per-batch TOTAL — the latency a line sees from admission to
     effector drain once queueing is subtracted — is compared against
-    `pipeline_latency_budget_ms`;
+    `pipeline_latency_budget_ms`.  The device stage's part is its
+    service time (scheduler._collect: from the batch's submit, or its
+    predecessor's collect if later, to its own collect), so that a
+    device-bound pipeline with two batches in flight reads d a batch
+    and not d or 2d by turns;
   * AIMD within the buckets: comfortably under budget (below half) the
     bucket doubles, over budget it halves.  Extrapolating a target
     directly from per-line cost looks cleverer but deadlocks in the
@@ -37,7 +41,12 @@ observed per-stage timings instead:
     blocked by the record that batch left (measured on the v5e: 5 s
     slices at two thirds of the rate for 20 s of a 40 s window).
   * a bucket change resets the EWMA and requires `settle` fresh samples
-    before the next move, so one noisy batch cannot oscillate the size.
+    before the next move, and a sample enters the EWMA as twice its
+    current value at most, so one noisy batch cannot oscillate the size
+    (measured on the v5e at 10,000 rules: a 1,024-line batch runs at
+    170 ms of a 250 ms budget, one batch of 480 ms as a `/metrics`
+    scrape pulled the traffic sketch from a busy device halved the size,
+    and 512-line batches drain a quarter fewer lines a second).
 
 Thread-safety: observe()/target() take a lock; both are called from
 different pipeline stage threads.
@@ -45,8 +54,11 @@ different pipeline stage threads.
 
 from __future__ import annotations
 
+import logging
 import threading
 from typing import Dict, Optional
+
+log = logging.getLogger(__name__)
 
 _STAGES = ("encode", "device", "drain")
 # a bucket must be at least this much per-line worse than its lower
@@ -131,10 +143,11 @@ class AdaptiveBatchSizer:
         return self.command_max
 
     def observe(self, n_lines: int, stage_ms: Dict[str, float]) -> None:
-        """One drained batch's per-stage wall times (ms).  Batches far
-        below the current bucket (a trickle, not a full batch) update the
-        stage EWMAs for metrics but don't drive sizing — their latency
-        says nothing about the bucket's."""
+        """One drained batch's per-stage wall times (ms).  Batches of at
+        most half the current bucket (a trickle, or one cut for the
+        bucket below) and batches above it (cut for a bucket above)
+        update the stage EWMAs for metrics but don't drive sizing —
+        their latency says nothing about the bucket's."""
         total = float(sum(stage_ms.values()))
         with self._lock:
             for s, ms in stage_ms.items():
@@ -143,11 +156,26 @@ class AdaptiveBatchSizer:
                     ms if prev is None
                     else prev + self._alpha * (ms - prev)
                 )
-            if n_lines * 2 < self._bucket and total <= self.budget_ms:
+            if n_lines > self._bucket or (
+                n_lines * 2 <= self._bucket and total <= self.budget_ms
+            ):
+                # not a sample of this bucket: a trickle — or a batch the
+                # ring still held when the bucket changed, cut for the
+                # bucket just left.  Counted, the two that follow a
+                # doubling say "half a batch takes half the time" and
+                # double again; the two that follow a halving say the
+                # opposite (seen at 10,000 rules, where a batch is over
+                # the budget from 2,048 lines up: 512 to 4,096 and back)
                 return
             if self._skip_first:
                 self._skip_first = False
                 return
+            if self._total_ewma_ms is not None:
+                # one slow batch (a scrape that pulls from a busy device,
+                # a collector pass) counts as twice the running mean at
+                # most: alone it cannot put a bucket over the budget that
+                # runs at two thirds of it, two in a row can
+                total = min(total, 2.0 * self._total_ewma_ms)
             self._total_ewma_ms = (
                 total if self._total_ewma_ms is None
                 else self._total_ewma_ms
@@ -197,6 +225,14 @@ class AdaptiveBatchSizer:
                 self._on_probation = True
 
     def _reset_locked(self) -> None:
+        # the bucket has just changed (a handful of times in a process's
+        # life once it has settled): say why
+        log.info(
+            "batch target now %d lines (EWMA %.0f ms a batch over %d "
+            "samples, budget %.0f ms)", self._bucket,
+            self._total_ewma_ms or 0.0, self._samples_at_bucket,
+            self.budget_ms,
+        )
         self._total_ewma_ms = None
         self._samples_at_bucket = 0
         self._skip_first = True

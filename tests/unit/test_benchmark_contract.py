@@ -119,13 +119,15 @@ def span_names():
 
 @pytest.mark.parametrize(
     "span",
-    _host_spans() + ["program-ab-fused", "effector-replay", "submit-resolve"],
+    _host_spans() + ["program-ab-fused", "effector-replay", "submit-resolve",
+                     "rules-compile"],
 )
 def test_host_span_the_benchmark_names_is_opened(span_names, span):
     """`benchmark/trace_names.json` `host_spans`, and the documented spans
     of the fused path beside them: the program's dispatch, the drain's
-    replay, and the submit stage's one pass over a batch's addresses,
-    whose seconds `resolve_ms_per_kline` reads."""
+    replay, the submit stage's one pass over a batch's addresses, whose
+    seconds `resolve_ms_per_kline` reads, and the start's `rules-compile`
+    (ISSUE 33), whose seconds are `banjax_rules_compile_seconds`."""
     assert span in span_names, sorted(span_names)
 
 
@@ -184,6 +186,119 @@ def test_resolve_family_is_on_metrics_with_its_labels(family):
     assert distinct == prom.value(
         snap, "banjax_submit_resolve_probes_total", table="slots")
     m.close()
+
+
+_STRESS_FAMILIES = {
+    # family: (labels the reader or PERF.md selects by, its reader)
+    "banjax_prefilter_candidates_total":
+        ({}, ["stage2_candidates_per_kline", "match_stage2_roofline"]),
+    "banjax_warm_tier_bytes_written_total": ({}, ["warm_record_bytes_mean"]),
+    "banjax_device_windows_table_bytes": ({}, []),
+    "banjax_rules_compile_seconds": ({"source": "compiled"}, []),
+}
+
+
+@pytest.mark.parametrize("family", sorted(_STRESS_FAMILIES))
+def test_stress10k_family_is_on_metrics_and_its_reader_reads_it(family):
+    """The counters `upstream-stress10k` brought (ISSUE 33), off
+    `/metrics` through the benchmark's own parser, and through the
+    per-layer readers that take them: candidates stage 2 scanned, bytes
+    the warm tier wrote, the window table's bytes, the start's seconds
+    on its rules."""
+    from banjax_tpu.decisions.dynamic_lists import DynamicDecisionLists
+    from banjax_tpu.decisions.rate_limit import (
+        FailedChallengeRateLimitStates,
+    )
+    from banjax_tpu.obs.exposition import render_prometheus
+    from benchmark.harness import found, prom
+
+    labels, readers = _STRESS_FAMILIES[family]
+    assert family in {f.prom for f in registry.FAMILIES}
+    now = time.time()
+    cfg = config_from_yaml_text(_RULES)
+    cfg.matcher_device_windows = True
+    cfg.matcher_window_capacity = 256
+    cfg.warm_tier_enabled = True
+    cfg.warm_tier_capacity = 1024
+    m = TpuMatcher(cfg, MockBanner(), StaticDecisionLists(cfg),
+                   RegexRateLimitStates())
+
+    sched = PipelineScheduler(lambda: m, now_fn=lambda: now)
+
+    def scrape():
+        return prom.parse(render_prometheus(
+            DynamicDecisionLists(start_sweeper=False), RegexRateLimitStates(),
+            FailedChallengeRateLimitStates(), matcher=m, pipeline=sched,
+        ))
+
+    sched.start()
+    before = None
+    for k in range(8):   # 8 x 100 addresses through 256 slots: spills
+        sched.submit([
+            f"{now:.6f} 1.2.{k}.{i} GET h.com GET "
+            f"/{'attack' if i % 10 == 0 else 'page'}{i} HTTP/1.1 ua -"
+            for i in range(100)
+        ])
+        assert sched.flush(120)
+        before = before or scrape()
+    sched.stop()
+    snap = scrape()
+    assert prom.value(snap, family, **labels) is not None
+    # ten lines in a hundred carry the rule's factor, under the 16 the
+    # 128-row program has room for: none overflowed
+    assert prom.value(snap, "banjax_prefilter_candidates_total") == 8 * 10
+    spills = prom.value(snap, "banjax_warm_tier_spills_total")
+    assert spills >= 25
+    # every spilled address holds one counter: 128 + 24 bytes a record
+    assert prom.value(
+        snap, "banjax_warm_tier_bytes_written_total") == 152 * spills
+    assert prom.value(
+        snap, "banjax_device_windows_table_bytes") == 256 * (16 * 1 + 5)
+    assert 0 < prom.value(snap, "banjax_rules_compile_seconds") < 60
+    ctx = {"prom0": before, "prom1": snap, "trace": None, "trace_lines": 0,
+           "mean_len": 0.0}
+    want = {"stage2_candidates_per_kline": 100.0,
+            "warm_record_bytes_mean": 152.0, "match_stage2_roofline": None}
+    for name in readers:
+        assert found.module("layers", name).read(ctx) == want[name]
+    m.close()
+
+
+def test_stage2_readers_split_a_trace_by_nfa_words():
+    """`match_stage2_us_per_kline` and `match_stage2_roofline` take stage
+    2 to be the match-kernel launches with more NFA words than stage 1's
+    (trace_names.json `match_kernel_shapes`), and the work to be the
+    counted candidates at the span's mean length: a share of a roofline
+    never counts the padded columns."""
+    from benchmark.harness import found, roofline
+
+    def op(words, lines, seconds, launches):
+        return [f"%single.3 = u32[{words},{lines}]{{1,0}} custom-call("
+                f"s32[1]{{0}} %a, s32[256,{lines}]{{1,0}} %b, "
+                f"s32[1,{lines}]{{1,0}} %c, s8[{4 * words},128]{{1,0}} %d), "
+                'custom_call_target="tpu_custom_call"', seconds, launches]
+
+    trace_ = {"kernel_ops": {"match_kernel": [
+        op(480, 4096, 0.010, 20.0), op(26752, 512, 0.400, 20.0)]}}
+    ctx = {"trace": trace_, "trace_lines": 80_000, "mean_len": 150.0,
+           "prom0": {("banjax_prefilter_candidates_total", ()): 0.0,
+                     ("banjax_pipeline_processed_lines_total", ()): 0.0},
+           "prom1": {("banjax_prefilter_candidates_total", ()): 30_000.0,
+                     ("banjax_pipeline_processed_lines_total", ()): 1e6},
+           "device": {"kind": "TPU v5 lite"}}
+    us = found.module("layers", "match_stage2_us_per_kline")
+    assert us.read(ctx) == pytest.approx(0.400 * 1e9 / 80_000)
+    rf = found.module("layers", "match_stage2_roofline")
+    work = rf.stage2_work(0.03 * 80_000, 150.0, 20.0, 26752, 128)
+    assert work == roofline.match_kernel_work(
+        0.03 * 80_000 * 150.0, 0.03 * 80_000, 20.0, 26752, 128)
+    share = rf.read(ctx)
+    assert share == pytest.approx(
+        100 * work["int8_ops"] / 393e12 / 0.400) and 0 < share < 100
+    # one width only (a plan that is stage 1 alone), or no counter: silent
+    trace_["kernel_ops"]["match_kernel"].pop()
+    assert us.read(ctx) is None and rf.read(ctx) is None
+    assert rf.read({**ctx, "prom0": {}, "prom1": {}}) is None
 
 
 @pytest.mark.parametrize("name,rel", _configurations())

@@ -107,6 +107,50 @@ class TestAdaptiveBatchSizer:
             s.observe(8, {"device": 300.0})
         assert s.target() == 512
 
+    def test_batches_cut_for_the_bucket_just_left_are_no_samples(self):
+        """A ruleset wide enough that a batch is over the budget from
+        2,048 lines up (10,000 rules on one chip: ~118 ms a thousand
+        lines).  After a doubling the ring still holds batches cut for
+        the old bucket; taken as samples of the new one they say it takes
+        half the time, and the sizer doubles again."""
+        budget = 250.0
+
+        def ms(n):
+            return {"device": 0.118 * n}
+
+        s = AdaptiveBatchSizer(budget, max_batch=4096, start_batch=1024)
+        for _ in range(3):
+            s.observe(1024, ms(1024))           # 121 ms < half the budget
+        assert s.target() == 2048
+        for _ in range(3):
+            s.observe(1024, ms(1024))           # the ring's leftovers
+        assert s.target() == 2048               # was 4096
+        for _ in range(3):
+            s.observe(2048, ms(2048))           # 242 ms: fits, stays
+        assert s.target() == 2048
+        for _ in range(3):
+            s.observe(2048, ms(2048) | {"drain": 30.0})   # over: halve
+        assert s.target() == 1024
+        for _ in range(3):
+            s.observe(2048, ms(2048) | {"drain": 30.0})   # leftovers again
+        assert s.target() == 1024               # was 512
+        # an over-budget trickle is still evidence
+        for _ in range(3):
+            s.observe(8, {"device": 300.0})
+        assert s.target() == 512
+
+    def test_one_slow_batch_does_not_halve_a_size_two_in_a_row_do(self):
+        s = AdaptiveBatchSizer(250.0, max_batch=4096, start_batch=1024)
+        for _ in range(20):
+            s.observe(1024, {"device": 170.0})
+        s.observe(1024, {"device": 900.0})      # a scrape, a collector pass
+        assert s.target() == 1024               # 170 + 0.3 * 170 = 221
+        for _ in range(20):
+            s.observe(1024, {"device": 170.0})
+        for _ in range(2):
+            s.observe(1024, {"device": 900.0})  # 221, then 287: over
+        assert s.target() == 512
+
     def test_settle_prevents_single_sample_moves(self):
         s = AdaptiveBatchSizer(100.0, start_batch=1024, settle=3)
         s.observe(1024, {"device": 1.0})  # first full batch: compile, skipped
@@ -605,6 +649,47 @@ class TestStartUpSamples:
         assert real.budget_trips == 0 and real.breaker.state == CLOSED
         real.note_device_outcome(5.0, ok=True)
         assert real.budget_trips == 1 and real.breaker.state == OPEN
+
+    def test_device_sample_is_the_stage_s_service_time(self):
+        """A device that is the bound, two batches in flight: whether the
+        wait for the predecessor falls into this batch's submit, into its
+        collect or into both is an accident of timing (submit + collect
+        read d or 2d), so the sizer is given what the stage took for the
+        batch after its predecessor left it."""
+        seen = []
+
+        class DeviceBound:
+            """Every batch takes 40 ms of a device that runs one at a
+            time; submit waits for the predecessor (as a full dispatch
+            queue does), collect for the batch itself."""
+            free_at = 0.0
+
+            def pipeline_begin(self, lines, now):
+                return {"results": [ConsumeLineResult() for _ in lines]}
+
+            def pipeline_submit(self, state):
+                time.sleep(max(0.0, self.free_at - time.perf_counter()))
+                self.free_at = time.perf_counter() + 0.040
+                state["done_at"] = self.free_at
+
+            def pipeline_collect(self, state):
+                time.sleep(max(0.0, state["done_at"] - time.perf_counter()))
+
+            def pipeline_finish(self, state, now):
+                return state["results"], 0
+
+        m = DeviceBound()
+        sched = PipelineScheduler(lambda: m, encode_workers=0,
+                                  min_batch=64, max_batch=64)
+        observe = sched._sizer.observe
+        sched._sizer.observe = lambda n, ms: (seen.append(ms), observe(n, ms))
+        sched.start()
+        sched.submit(["a b c d e f g"] * (12 * 64))
+        assert sched.flush(20)
+        sched.stop()
+        service = [ms["device"] for ms in seen[2:]]
+        assert len(service) >= 8
+        assert all(30.0 <= d <= 60.0 for d in service), service
 
 
 # ---------------------------------------------------------------------------

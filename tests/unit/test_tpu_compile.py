@@ -142,3 +142,101 @@ def test_pow_sha256_compiles(one_chip):
     _compile(
         pow_verify._pow_call(256, False), one_chip, ((16, 256), jnp.uint32)
     )
+
+
+# ---- PR 33: upstream-stress10k, 10,000 rules x 65,536 slots on one chip ----
+
+STRESS_RULES = 10_000
+STRESS_SLOTS = 65_536
+HBM_BYTES = 15.75e9  # what a v5e's 16 GB leaves a program
+
+
+@pytest.fixture(scope="module")
+def stress_prefilter():
+    from banjax_tpu.matcher.prefilter import FusedPrefilter, build_plan
+    from banjax_tpu.matcher.rulec import compile_rules
+    from benchmark.rulesets import stress_distinct
+
+    pats = [r["regex"] for r in stress_distinct.build(STRESS_RULES, seed=7)]
+    comp = compile_rules(pats, n_shards=1)
+    plan = build_plan(pats, byte_classes=(comp.byte_to_class, comp.n_classes))
+    assert not plan.unsupported and plan.n_always == 0
+    # stage 2 in slabs the kernel's VMEM budget holds, a dozen at least
+    assert plan.stage2.n_shards >= 12 and plan.stage2.words_per_shard <= 512
+    return FusedPrefilter(plan, "pallas")
+
+
+def _stress_state(one_chip):
+    from banjax_tpu.matcher import windows as W
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    keys = (STRESS_SLOTS * STRESS_RULES,)
+    return sds, W.DeviceWindowState(
+        hits=sds(keys, jnp.int32), start_s=sds(keys, jnp.int32),
+        start_ns=sds(keys, jnp.int32), key_gen=sds(keys, jnp.int32),
+        slot_gen=sds((STRESS_SLOTS,), jnp.int32),
+        ip_seen=sds((STRESS_SLOTS,), jnp.bool_),
+    )
+
+
+def _fits_with_the_table_aliased(compiled):
+    m = compiled.memory_analysis()
+    table = 16 * STRESS_SLOTS * STRESS_RULES
+    # the 10.49 GB table is donated: the program writes it in place
+    assert m.alias_size_in_bytes >= table
+    peak = (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert table < peak < HBM_BYTES, m
+    # and one copy more would not fit
+    assert peak + table > HBM_BYTES
+    return m
+
+
+def test_stress10k_single_program_holds_the_table_in_place(
+    one_chip, stress_prefilter
+):
+    """The one fused program (match, dense bitmap, window commit) at the
+    full batch: 4,096 rows x 10,000 rules, 76 stage-2 slabs, the window
+    table an argument aliased to its output."""
+    import types
+
+    from banjax_tpu.matcher.kernels import fused_match_window as fmw
+
+    pf = stress_prefilter
+    sds, state = _stress_state(one_chip)
+    win = types.SimpleNamespace(
+        _limits=jnp.full((STRESS_RULES,), 2, jnp.int32),
+        _iv_s=jnp.full((STRESS_RULES,), 300, jnp.int32),
+        _iv_ns=jnp.zeros((STRESS_RULES,), jnp.int32),
+    )
+    fn, k, p, e = fmw.build_single_program(
+        pf, win, jnp.ones((1, STRESS_RULES), bool), STRESS_RULES, B, L_P,
+        f_idx=jnp.asarray(pf.plan.f_idx, jnp.int32),
+        a_idx=jnp.asarray(pf.plan.a_idx, jnp.int32), aw=None, ae=None,
+        scan_fn=fmw.window_scan(False),
+    )
+    assert (k, p, e) == (K, 1024, 1024)
+    # the (row, rule) pair encoding is int32: rows x packed rule columns
+    assert B * pf._nf8 * 8 < 2**31 // 50
+    vec = sds((B,), jnp.int32)
+    compiled = fn.lower(
+        state, sds((), jnp.int32), sds((B, 1 + L_P // 4), jnp.int32),
+        sds((), jnp.int32), vec, vec, vec, vec, sds((B,), jnp.uint8),
+    ).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+    m = _fits_with_the_table_aliased(compiled)
+    assert m.temp_size_in_bytes < 1e9
+
+
+def test_stress10k_maintenance_steps_hold_the_table_in_place(one_chip):
+    from banjax_tpu.matcher import windows as W
+
+    sds, state = _stress_state(one_chip)
+    for step, operand in (
+        (W._evict_step, sds((4096,), jnp.int32)),
+        (W._restore_step, sds((5, W._RESTORE_CHUNK), jnp.int32)),
+    ):
+        m = _fits_with_the_table_aliased(step.lower(state, operand).compile())
+        assert m.temp_size_in_bytes < 1e8

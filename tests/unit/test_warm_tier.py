@@ -210,8 +210,9 @@ def test_a_second_process_view_reads_what_the_first_wrote(tiers):
     assert a.probes == b.probes == 6  # one header
 
 
-@pytest.mark.parametrize("magic", [b"bjxwt001", b"bjxhsm02", b"\0" * 8],
-                         ids=["old-layout", "fc-table", "zeroed"])
+@pytest.mark.parametrize(
+    "magic", [b"bjxwt001", b"bjxwt002", b"bjxhsm02", b"\0" * 8],
+    ids=["old-layout", "fixed-stride-layout", "fc-table", "zeroed"])
 def test_a_segment_of_another_layout_is_refused(magic):
     if not shm.available():
         pytest.skip("native shmstate unavailable (no C compiler)")
@@ -232,8 +233,9 @@ def _segment_bytes(name: str):
 
 
 def test_absent_keys_read_no_record_at_the_deployed_geometry(tiers):
-    """2^20 positions x 1,000 rules is `crs1k-edge`'s table: 24,128-byte
-    records, a 25 GB mapping of which nothing is resident until written."""
+    """2^20 positions x 1,000 rules is `crs1k-edge`'s table: a 4.3 GB
+    mapping (16 blocks a position) of which nothing is resident until
+    written."""
     c = tiers(capacity=1 << 20, max_rules=1000, expiry_ns=1 << 60)
     absent = [f"172.{16 + (i >> 16)}.{(i >> 8) & 255}.{i & 255}"
               for i in range(20_000)]
@@ -266,3 +268,125 @@ def test_absent_keys_read_no_record_at_the_deployed_geometry(tiers):
     assert len(c) == 0 and c.keys() == []
     assert not c.contains_batch(present).any()
     assert c.record_reads == reads  # stale records behind empty tags are never read
+
+
+# ---- PR 33: a record takes the room its counters need ----
+
+
+def _vector(rng, n, max_rules):
+    rids = rng.sample(range(max_rules), n)  # insertion order, not sorted
+    return [(r, rng.randrange(1, 9), 1_790_000_000 + rng.randrange(10_000),
+             rng.randrange(1_000_000_000)) for r in rids]
+
+
+@pytest.mark.parametrize("n_entries", [1, 2, 5, 6, 15, 16, 400, 10_000])
+def test_round_trip_is_identical_at_ten_thousand_rules(tiers, n_entries):
+    """`upstream-stress10k`'s tier: max_rules 10,000.  Whatever a record
+    holds comes back entry for entry in insertion order, by every way of
+    reading it, and what a put writes follows the counters held."""
+    c = tiers(capacity=1 << 10, max_rules=10_000, expiry_ns=1 << 60)
+    rng = random.Random(n_entries)
+    ent = _vector(rng, n_entries, 10_000)
+    od = {r: (h, s, ns) for r, h, s, ns in ent}
+    assert c.put("198.51.100.7", ent, 5)
+    assert c.bytes_written == shm.wt_record_bytes(n_entries)
+    assert c.bytes_written <= 128 + 24 * n_entries + 8 * (n_entries // 10 + 1)
+    assert c.blocks_used == shm.wt_record_blocks(n_entries)
+    assert c.peek("198.51.100.7") == ent
+    assert c.take("198.51.100.7") == ent
+    assert c.blocks_used == 0 and len(c) == 0
+    # the batched forms, beside a record of another size
+    other = _vector(rng, 3, 10_000)
+    stored = c.put_batch(["198.51.100.7", "198.51.100.8"],
+                         [od, {r: (h, s, ns) for r, h, s, ns in other}], 6)
+    assert stored.all()
+    got = c.take_batch(["198.51.100.8", "203.0.113.1", "198.51.100.7"])
+    assert got[1] is None
+    assert list(got[2].items()) == list(od.items())
+    assert [(r, *v) for r, v in got[0].items()] == other
+    assert c.blocks_used == 0
+
+
+def test_mapping_and_resident_bytes_follow_the_counters_not_the_ruleset(tiers):
+    """The shipped capacity at 10,000 rules: the fixed-stride layout
+    mapped 2^20 x 240,136 bytes (252 GB) and gave every record a page of
+    its own; a block-chained record of two counters is 256 bytes."""
+    c = tiers(capacity=1 << 20, max_rules=10_000, expiry_ns=1 << 60)
+    assert c._shm.size <= (1 << 20) * (16 + 16 * 256) + 128 < 5 << 30
+    before = _segment_bytes(c.name)
+    n = 1 << 15
+    ips = [f"100.{64 + (i >> 16)}.{(i >> 8) & 255}.{i & 255}" for i in range(n)]
+    vecs = [{7: (1, 2, 3), 9_999: (2, 3, 4)}] * n
+    assert c.put_batch(ips, vecs, 1).all()
+    assert c.bytes_written == n * (128 + 2 * 24)
+    assert c.blocks_used == n
+    if before is not None:
+        # 8 MB of blocks and at most all 16 MB of tags and heads; a page
+        # a record, as the fixed stride had it, were 128 MB
+        assert _segment_bytes(c.name) - before <= n * 256 + (17 << 20)
+    assert (c.probes, c.record_reads) == (n, 0)  # fresh keys: tags only
+    assert all(list(v.items()) == list(vecs[0].items())
+               for v in c.take_batch(ips))
+    assert c.probes == 2 * n and n <= c.record_reads <= n + 4
+
+
+def test_an_update_reuses_lengthens_and_cuts_its_chain(tiers):
+    c = tiers(capacity=64, max_rules=200, expiry_ns=1 << 60)
+    rng = random.Random(5)
+    for n in (3, 47, 200, 12, 5, 6):
+        ent = _vector(rng, n, 200)
+        assert c.put("192.0.2.1", ent, n)
+        assert len(c) == 1
+        assert c.blocks_used == shm.wt_record_blocks(n)
+        assert c.peek("192.0.2.1") == ent
+    assert c.put("192.0.2.2", _vector(rng, 30, 200), 1)
+    assert c.blocks_used == 2 + shm.wt_record_blocks(30)
+    c.clear()
+    assert (len(c), c.blocks_used) == (0, 0)
+    assert c.put("192.0.2.1", ent, 1) and c.peek("192.0.2.1") == ent
+
+
+def test_an_exhausted_arena_drops_the_put_and_keeps_no_stale_copy(tiers):
+    """The arena holds 16 blocks a position: 64 positions x 16 = 1,024
+    blocks; a full record of 1,000 counters takes 101."""
+    c = tiers(capacity=64, max_rules=1000, expiry_ns=1 << 60)
+    full = [(r, 1, 2, 3) for r in range(1000)]
+    ips = [f"10.9.0.{i}" for i in range(12)]
+    stored = [c.put(ip, full, 1) for ip in ips]
+    assert stored == [True] * 10 + [False] * 2   # 10 x 101 = 1,010 blocks
+    assert (len(c), c.dropped, c.blocks_used) == (10, 2, 1010)
+    assert c.put("10.9.1.1", full[:100], 1)      # 11 blocks: they fit
+    assert not c.put("10.9.1.2", full[:100], 1)
+    # an update that cannot grow is dropped, and the older copy with it:
+    # the caller keeps the state where it was, so none may stay here
+    assert not c.put("10.9.1.1", full, 2)
+    assert "10.9.1.1" not in c and len(c) == 10
+    assert c.take(ips[0]) == full                # blocks come back
+    assert c.put("10.9.1.1", full, 3) and c.peek("10.9.1.1") == full
+
+
+def test_a_steal_that_finds_the_arena_exhausted_is_one_drop(tiers):
+    """64 positions are one probe window; each holds 16 blocks, so the
+    arena is full too.  A put that would steal an expired record and
+    cannot have the blocks it needs is dropped, the victim stays, and the
+    loss is counted once."""
+    c = tiers(capacity=64, max_rules=1000, expiry_ns=10)
+    sixteen_blocks = [(r, 1, 2, 3) for r in range(155)]
+    ips = [f"10.8.0.{i}" for i in range(64)]
+    assert all(c.put(ip, sixteen_blocks, 1) for ip in ips)
+    assert (len(c), c.dropped, c.blocks_used) == (64, 0, 1024)
+    assert not c.put("10.8.1.1", sixteen_blocks + [(999, 1, 2, 3)], 100)
+    assert (len(c), c.dropped, c.blocks_used) == (64, 1, 1024)
+    assert all(ip in c for ip in ips)
+    # the same blocks as its victim: the steal goes through, one loss more
+    assert c.put("10.8.1.1", sixteen_blocks, 100)
+    assert (len(c), c.dropped, c.blocks_used) == (64, 2, 1024)
+    assert c.peek("10.8.1.1") == sixteen_blocks
+
+
+def test_py_warm_tier_counts_the_same_bytes():
+    py = shm.PyWarmTier(capacity=64, max_rules=10_000)
+    assert py.put("a", [(1, 1, 2, 3)] * 2, 1)
+    assert py.put("b", [(r, 1, 2, 3) for r in range(17)], 1)
+    assert py.bytes_written == (128 + 48) + (128 + 17 * 24 + 2 * 8)
+    assert shm.wt_record_blocks(5) == 1 and shm.wt_record_blocks(6) == 2
