@@ -8,14 +8,18 @@ match bitmap through the host — the matcher pulls its sparse result down
 window scan. Here the dense caller-order bitmap never exists on the host.
 
 The program (kernels/fused_match_window.py): two-stage match
-(prefilter._match_core), dense caller-order bitmap assembly, the per-row
-live mask (the caller's staleness drop, an INPUT to submit), every
-overflow flag — candidate count, match-pair count, window-event count —
-and the window segmented scan (windows._apply_core, state donated) whose
-commit is gated IN the program on those flags and on a device-side chain
-scalar.  Output: one host buffer (flags ‖ (row, rule) match pairs ‖
-always-rule bits ‖ the fired-event records), pulled asynchronously, and
-the device-resident bitmap for the overflow replay.
+(prefilter._match_core), the sparse (row, rule) pairs read out of stage
+2's packed words, the window events listed straight from those pairs and
+the always-columns' bits — masked per event by the per-row live mask (the
+caller's staleness drop, an INPUT to submit), the real-row count and the
+host's active rules — every overflow flag (candidate count, match-pair
+count, window-event count) and the window segmented scan
+(windows._apply_events, state donated) whose commit is gated IN the
+program on those flags and on a device-side chain scalar.  Nothing in it
+reduces over rows x rules.  Output: one host buffer (flags ‖ (row, rule)
+match pairs ‖ always-rule bits ‖ the fired-event records), pulled
+asynchronously, and the device-resident dense caller-order bitmap, which
+only the overflow replay reads.
 
 Order: submits are serialized under the windows lock, so device apply
 order == sequence order == log order.  A chunk that overflows commits
@@ -31,10 +35,13 @@ orders that matter: an overflowing chunk's classic replay lands on the
 device after every earlier chunk's, and the host shadow absorbs chunks in
 device-apply order (or an eviction could restore stale counters).
 
-Event order parity: bits are scattered into CALLER row order before the
-window apply, so the event compaction's row-major (line, rule) order — the
-reference's per-site-then-global processing order — is preserved exactly
-as in the classic path.
+Event order parity: an event carries its CALLER row, and the window
+apply orders by the ordinal line * n_rules + rule — the reference's
+per-site-then-global (line, rule) processing order — so the order the
+events are listed in (always-columns first, then pairs in candidate-slot
+order) does not matter, exactly as a row-major compaction of the dense
+bitmap would not.  banjax_fused_event_feed_total{source} counts the
+committed events by which of the two lists they came from.
 """
 
 from __future__ import annotations
@@ -154,7 +161,12 @@ class FusedWindowsPipeline:
         self.overflow_causes = {
             "candidates": 0, "pairs": 0, "events": 0, "chain": 0,
         }
+        # window events committed fused, by where the program took them
+        # from: its (row, rule) pairs or its always-columns' set bits
+        self.event_feed = {"pairs": 0, "always": 0}
         plan = prefilter.plan
+        self._is_always = np.zeros(max(1, n_rules), dtype=bool)
+        self._is_always[np.asarray(plan.a_idx, dtype=np.int64)] = True
         self._f_idx = jnp.asarray(plan.f_idx, dtype=jnp.int32)
         self._a_idx = jnp.asarray(plan.a_idx, dtype=jnp.int32)
         na = plan.n_always
@@ -491,6 +503,9 @@ class FusedWindowsPipeline:
                 match_type=ev_mtype[live], exceeded=ev_exc[live] != 0,
                 seen_ip=ev_seen[live] != 0,
             )
+            n_always = int(np.count_nonzero(self._is_always[events.rule]))
+            self.event_feed["always"] += n_always
+            self.event_feed["pairs"] += len(events) - n_always
             # Collect order == apply order (the turn is held since
             # resolve), so concurrent chunks can't interleave stale
             # values in the shadow.

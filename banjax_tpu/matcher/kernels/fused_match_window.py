@@ -9,15 +9,22 @@ between them — pull the flags, check overflow, only then dispatch the
 commit.  This module needs neither.  One device program per chunk does
 
     match (the two-stage Pallas NFA scan, prefilter._match_core)
-      → dense caller-order bitmap + sparse (row, rule) pairs
-      → per-row live mask (staleness/abandon composed as an input)
+      → sparse (row, rule) pairs, extracted from stage 2's PACKED words
+        (prefilter.pairs_from_core), and the always-columns' bits
+      → the window events, listed from exactly those: one per pair, one
+        per set always-bit, each masked by gather with the per-row live
+        mask (staleness/abandon composed as an input), the real-row count
+        and the rules active for the line's host — no nonzero, sort or
+        sum over rows x rules anywhere
       → window-hit accumulation + threshold-fire against the HBM-resident
-        per-slot window state (windows._apply_core, state donated —
+        per-slot window state (windows._apply_events, state donated —
         tiles of it stage through VMEM inside the scan kernel below)
       → IN-KERNEL overflow gate: candidate / pair / event overflow (or a
         gated predecessor, see the chain scalar) drops every state write,
         so the donated state passes through bit-identical and the host
         replays the chunk through the existing classic fallback
+      → the dense caller-order bitmap, assembled by scatter for that
+        replay alone (windows._apply_core compacts ITS events out of it)
 
 and returns only a compact buffer — the [4] flags word ‖ sparse match
 pairs ‖ always-rule bits ‖ the fired-event records — plus the
@@ -145,7 +152,7 @@ def _scan_call(Ep: int, T: int, interpret: bool):
 
 
 def window_scan(interpret: bool):
-    """A `scan_fn` for windows._apply_core: same contract as the
+    """A `scan_fn` for windows._apply_events: same contract as the
     lax.scan over _window_step (the recurrence always starts from the
     zero carry, so `init` is ignored), lowered through the Pallas
     kernel above.  Events are padded to whole tiles with inert pad
@@ -206,8 +213,9 @@ def build_single_program(
     pf, windows, active_table, n_rules: int, Bp: int, L_p: int, *,
     f_idx, a_idx, aw, ae, scan_fn,
 ):
-    """One jitted device program: match core + dense bitmap assembly +
-    live mask + overflow/chain gate + window commit + compact output.
+    """One jitted device program: match core + event list from the pairs
+    and always-columns + overflow/chain gate + window commit + compact
+    output + the dense bitmap for the replay.
 
     Returns (fn, K, P, E) where
       fn(state, chain_ok, combined, n_real, host_idx, slots, ts_s,
@@ -231,6 +239,7 @@ def build_single_program(
     plan = pf.plan
     n_always = plan.n_always
     n_filt = pf._n_filt
+    R8 = pf._nf8 * 8
     limits, iv_s, iv_ns = windows._limits, windows._iv_s, windows._iv_ns
     active_table = jnp.asarray(active_table)
     shifts = jnp.asarray(_SHIFTS, dtype=jnp.int32)
@@ -255,13 +264,41 @@ def build_single_program(
             bits = bits.at[:, a_idx].set(ab)
         real = jax.lax.iota(jnp.int32, Bp) < n_real
         bits = bits * real[:, None].astype(jnp.uint8)
-        # the live mask composes staleness/abandon INTO the commit: a row
-        # the caller dropped contributes no event and no state write (the
-        # returned dense bitmap stays unmasked — the classic fallback
-        # applies its own mask)
-        bits_live = bits * live[:, None]
-        fire = (bits_live != 0) & active_table[host_idx]
-        n_events = fire.sum(dtype=jnp.int32)
+        # the window events, straight from what the match left: one per
+        # (row, rule) pair and one per set always-column bit — never a
+        # reduction over the dense rows x rules bitmap above, which leaves
+        # the program untouched as the classic replay's input
+        ev_line, ev_rule, ev_on = [], [], []
+        if n_always:
+            ev_line.append(jnp.repeat(jax.lax.iota(jnp.int32, Bp), n_always))
+            ev_rule.append(jnp.tile(a_idx, Bp))
+            ev_on.append((ab != 0).reshape(-1))
+        if n_filt:
+            on = pairs >= 0
+            row = jnp.where(on, pairs // R8, 0)
+            ev_line.append(row)
+            ev_rule.append(f_idx[jnp.where(on, pairs - row * R8, 0)])
+            ev_on.append(on)
+        line = jnp.concatenate(ev_line)
+        rule = jnp.concatenate(ev_rule)
+        # what the dense path multiplies into the bitmap, read per event:
+        # pad rows, the live mask (staleness/abandon composed INTO the
+        # commit: a row the caller dropped contributes no event and no
+        # state write) and the rules inactive for the line's host
+        on = (
+            jnp.concatenate(ev_on) & (line < n_real) & (live[line] != 0)
+            & active_table[host_idx[line], rule]
+        )
+        n_events = on.sum(dtype=jnp.int32)
+        if on.shape[0] > max_events:
+            # only under _MAX_EVENT_CAPACITY (rows x always-columns alone
+            # pass it): keep the first max_events that fire
+            (keep,) = jnp.nonzero(on, size=max_events, fill_value=0)
+            line, rule = line[keep], rule[keep]
+            on = jax.lax.iota(jnp.int32, max_events) < n_events
+        else:
+            short = max_events - on.shape[0]
+            line, rule, on = (jnp.pad(x, (0, short)) for x in (line, rule, on))
         self_ok = (
             (c["n_cand"] <= K) & (n_pairs <= P) & (n_events <= max_events)
         )
@@ -269,10 +306,9 @@ def build_single_program(
         # the submit chain) gates THIS commit too, keeping device apply
         # order == log order across the host's classic replays
         ok = self_ok & (chain_ok != 0)
-        new_state, ev = W._apply_core(
-            state, bits_live, active_table, host_idx, slots, ts_s, ts_ns,
-            limits, iv_s, iv_ns, n_rules=n_rules, max_events=max_events,
-            gate=ok, scan_fn=scan_fn,
+        new_state, ev = W._apply_events(
+            state, line, rule, on, slots, ts_s, ts_ns,
+            limits, iv_s, iv_ns, n_rules=n_rules, gate=ok, scan_fn=scan_fn,
         )
         flags = jnp.stack(
             [ok.astype(jnp.int32), c["n_cand"], n_pairs, n_events]

@@ -390,6 +390,14 @@ def _bucket(n: int, cap: int) -> int:
     return min(b, max(cap, _MIN_BUCKET))
 
 
+def _column_mask_words(n_cols: int, n_words: int) -> np.ndarray:
+    """[n_words] uint32 with the bit of every column < n_cols set, columns
+    numbered MSB-first through big-endian words (column 32 w + j is bit
+    31 - j of word w): what clears a packed row's pad bits."""
+    packed = np.packbits(np.arange(n_words * 32) < n_cols)
+    return packed.view(">u4").astype(np.uint32)
+
+
 class PrefilterOverflow(RuntimeError):
     """More stage-1 candidates than the fused pipeline's fixed capacity —
     the caller must rerun the batch through its single-stage path."""
@@ -662,14 +670,25 @@ class FusedPrefilter:
         """The sparse (row, rule) pair extraction shared by the plain fused
         program and the fused-windows program: one int32 per set stage-2
         bit, encoded caller_row * R8 + packed bit column (R8 = 8 * nf8),
-        -1 beyond n_pairs. Returns (pairs [P] int32, n_pairs, bits [K, R8])
-        — `bits` is the unpacked MSB-first bit tensor so callers needing
-        the per-candidate dense form don't unpack m2p twice."""
+        in (candidate slot, column) order, -1 beyond n_pairs.  Returns
+        (pairs [P] int32, n_pairs, bits [K, R8]) — `bits` is the unpacked
+        MSB-first bit tensor for callers that assemble the dense form;
+        nothing here reduces over it.
+
+        The set bits are found in the PACKED words: m2p read as big-endian
+        32-bit words (column 32 w + j is bit 31 - j of word w), a running
+        popcount over those K * ceil(nf8 / 4) words, and for each of the P
+        output slots a binary search for the word that holds its bit and
+        the bit's place inside that one word.  The unpacked tensor is 32
+        times the elements, nearly all zero, and a compaction over it was
+        the fused program's second longest operation at 10,000 rules."""
         if not self._n_filt:
             return jnp.zeros((0,), dtype=jnp.int32), jnp.int32(0), None
-        R8 = self._nf8 * 8
+        nf8 = self._nf8
+        R8 = nf8 * 8
+        m2p = c["m2p"]                                           # [K, nf8]
         bits = (
-            (c["m2p"][:, :, None] >> (7 - jnp.arange(8, dtype=jnp.int32))) & 1
+            (m2p[:, :, None] >> (7 - jnp.arange(8, dtype=jnp.int32))) & 1
         ).reshape(K, R8)
         # mask pad columns beyond the true rule count: n_pairs and the pair
         # stream must be bounded by n_rules even if a packer left a pad bit
@@ -679,13 +698,37 @@ class FusedPrefilter:
             jnp.arange(R8, dtype=jnp.int32) < self._n_filt,
             bits, 0,
         )
-        n_pairs = jnp.sum(bits, dtype=jnp.int32)
-        (flat,) = jnp.nonzero(bits.reshape(-1), size=P, fill_value=0)
-        k = flat // R8
-        col = flat - k * R8
+        nf32 = -(-nf8 // 4)
+        quads = jnp.pad(m2p, ((0, 0), (0, 4 * nf32 - nf8))).astype(jnp.uint32)
+        quads = quads.reshape(K, nf32, 4)
+        words = (
+            (quads[:, :, 0] << 24) | (quads[:, :, 1] << 16)
+            | (quads[:, :, 2] << 8) | quads[:, :, 3]
+        ) & jnp.asarray(_column_mask_words(self._n_filt, nf32))
+        words = words.reshape(-1)                                # [K * nf32]
+        counts = jax.lax.population_count(words).astype(jnp.int32)
+        upto = jnp.cumsum(counts)          # set bits up to and with a word
+        n_pairs = upto[-1]
+        slot = jax.lax.iota(jnp.int32, P)
+        # the word holding set bit number `slot`: the first whose running
+        # count passes it
+        w_idx = jnp.minimum(
+            jnp.searchsorted(upto, slot, side="right",
+                             method="scan_unrolled").astype(jnp.int32),
+            words.shape[0] - 1,
+        )
+        word = words[w_idx]
+        rank = slot - (upto[w_idx] - counts[w_idx])   # among the word's bits
+        # bits of the word at or left of place j, for every j: the place
+        # of the word's bit number `rank` is how many of them are <= rank
+        left = jax.lax.population_count(
+            word[:, None] >> (31 - jnp.arange(32, dtype=jnp.uint32))[None, :]
+        ).astype(jnp.int32)
+        place = jnp.sum(left <= rank[:, None], axis=1, dtype=jnp.int32)
+        k = w_idx // nf32
+        col = (w_idx - k * nf32) * 32 + place
         caller = jnp.take(c["idx_caller_k"], k)
-        live = jax.lax.iota(jnp.int32, P) < n_pairs
-        pairs = jnp.where(live, caller * R8 + col, -1)
+        pairs = jnp.where(slot < n_pairs, caller * R8 + col, -1)
         return pairs, n_pairs, bits
 
     def _match_core(self, B: int, L_p: int, K: int, block: int):

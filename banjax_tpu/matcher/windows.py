@@ -6,13 +6,17 @@ rate_limit.go:37-78). This module keeps the counters resident on the TPU as
 flat [capacity * n_rules] arrays and folds a whole batch of match events into
 them in one jitted step:
 
-  match bitmap [B, R]  (straight from the NFA kernel, never pulled to host)
-    → mask by per-host rule applicability / hosts_to_skip
-    → compact to an event list (line, rule) via fixed-capacity nonzero
-    → stable-sort by (slot, rule) key — row-major nonzero order IS the
-      reference's processing order (per-site rule ids precede global ids,
-      so (line, rule_id) ascending == the per-site-then-global loop of
-      regex_rate_limiter.go:175-211)
+  an event list (line, rule): the classic path compacts it out of a match
+    bitmap [B, R] (masked by per-host rule applicability / hosts_to_skip,
+    then a fixed-capacity nonzero: _apply_core); the fused program builds
+    it from the sparse (row, rule) pairs and always-column bits it already
+    holds, the same masks gathered per event, and never scans rows x rules
+    (kernels/fused_match_window.py) — both hand it to _apply_events
+    → stable-sort by (slot, rule) key, ties on the ordinal line * R + rule:
+      (line, rule) ascending IS the reference's processing order
+      (per-site rule ids precede global ids, so it equals the
+      per-site-then-global loop of regex_rate_limiter.go:175-211), whatever
+      order the events were listed in
     → one lax.scan over the sorted events: per segment, load the persistent
       (hits, start) state, replay the exact window transitions, flag
       exceeded events, write the segment's final state back
@@ -174,13 +178,50 @@ def _apply_core(
     *,
     n_rules: int,
     max_events: int,
+    gate=None,
+):
+    """The classic window apply: the events of a DENSE match bitmap, found
+    by a fixed-capacity nonzero over rows x rules in row-major (= reference
+    processing) order, handed to _apply_events.  _apply_step (apply_bitmap,
+    the overflow's replay) is its one caller; the fused program holds its
+    matches as sparse pairs already and feeds _apply_events itself
+    (kernels/fused_match_window.py).  Caller guarantees evictions/restores
+    already ran (_run_maintenance_locked)."""
+    fire = (bits != 0) & active_table[host_idx]
+    lines, rules = jnp.nonzero(
+        fire, size=max_events, fill_value=(jnp.int32(-1), jnp.int32(-1))
+    )
+    return _apply_events(
+        state, lines, rules, lines >= 0, slot_ids, ts_s, ts_ns,
+        limits, iv_s, iv_ns, n_rules=n_rules, gate=gate,
+    )
+
+
+def _apply_events(
+    state: DeviceWindowState,
+    lines: jnp.ndarray,        # [E] int32 line of each event
+    rules: jnp.ndarray,        # [E] int32 rule of each event
+    alive: jnp.ndarray,        # [E] bool — False = a pad, whatever it holds
+    slot_ids: jnp.ndarray,     # [B] int32 (slot per line)
+    ts_s: jnp.ndarray,         # [B] int32
+    ts_ns: jnp.ndarray,        # [B] int32
+    limits: jnp.ndarray,       # [R] int32 hits_per_interval
+    iv_s: jnp.ndarray,         # [R] int32 interval seconds part
+    iv_ns: jnp.ndarray,        # [R] int32 interval ns part
+    *,
+    n_rules: int,
     gate=None,                 # scalar bool: False drops EVERY state write
     scan_fn=None,              # None = lax.scan over _window_step
 ):
-    """The traceable window-apply body — composable inside a larger jit
-    (the fused matcher+windows pipeline) as well as the standalone
-    _apply_step below. Caller guarantees evictions/restores already ran
-    (_run_maintenance_locked). `gate` supports overflow handling under buffer
+    """The traceable window-apply body over an EVENT LIST: distinct
+    (line, rule) pairs in any order, pads anywhere.  Both extractions end
+    here — the dense bitmap's nonzero (_apply_core) and the fused program's
+    pairs and always-columns — so the window semantics live once.
+
+    The reference processes events in (line, rule) order, and that order
+    is carried by the ordinal line * n_rules + rule, not by an event's
+    position: the stable sort by (slot, rule) key breaks ties on it, and
+    seen_ip compares it.  `gate` supports overflow handling under buffer
     donation: when False, all scatters drop (indices pushed out of range)
     so the donated state passes through bit-identical and the caller can
     rerun the batch through the splitting path — no state copy needed.
@@ -189,20 +230,22 @@ def _apply_core(
     the single-kernel path passes the Pallas scan from
     kernels/fused_match_window.py; None keeps the XLA lax.scan."""
     cap_r = state.hits.shape[0]
+    n_slots = state.ip_seen.shape[0]
+    if slot_ids.shape[0] * n_rules >= 2**31:
+        raise ValueError(
+            f"batch {slot_ids.shape[0]} x {n_rules} rules overflows the "
+            "int32 (line, rule) event ordinal — lower matcher_batch_lines"
+        )
     ip_seen = state.ip_seen
 
-    fire = (bits != 0) & active_table[host_idx]
-
-    # 1. fixed-capacity compaction in row-major (= reference processing) order
-    lines, rules = jnp.nonzero(
-        fire, size=max_events, fill_value=(jnp.int32(-1), jnp.int32(-1))
-    )
-    pad = lines < 0
+    pad = ~alive
+    lines = jnp.where(pad, jnp.int32(-1), lines)
+    rules = jnp.where(pad, jnp.int32(-1), rules)
     slot = jnp.where(pad, jnp.int32(0), slot_ids[lines])
     key = jnp.where(pad, jnp.int32(cap_r), slot * n_rules + rules)  # pad sorts last
-    seq = jnp.arange(max_events, dtype=jnp.int32)
+    seq = lines * n_rules + rules  # the reference's processing order
 
-    # 2. stable sort by key (ties keep row-major order)
+    # 1. stable sort by key (ties keep (line, rule) order)
     order = jnp.lexsort((seq, key))
     key_s = key[order]
     slot_s = slot[order]
@@ -214,14 +257,15 @@ def _apply_core(
 
     # seen_ip: slot already seen on device, or an earlier event in this batch
     # touched the slot (reference: the per-IP dict exists, rate_limit.go:72-79)
-    first_seq = jnp.full((state.ip_seen.shape[0],), max_events, dtype=jnp.int32)
+    never = jnp.iinfo(jnp.int32).max
+    first_seq = jnp.full((n_slots,), never, dtype=jnp.int32)
     first_seq = first_seq.at[slot].min(
-        jnp.where(pad, max_events, seq), mode="drop"
+        jnp.where(pad, never, seq), mode="drop"
     )
     seen_ip_ev = ip_seen[slot] | (seq > first_seq[slot])  # post-eviction flags
     seen_ip_s = seen_ip_ev[order]
 
-    # 3. segment boundaries + persistent state gather per event
+    # 2. segment boundaries + persistent state gather per event
     prev_key = jnp.concatenate([jnp.full((1,), -1, dtype=key_s.dtype), key_s[:-1]])
     boundary = key_s != prev_key
     g_hits = state.hits[jnp.minimum(key_s, cap_r - 1)]
@@ -246,14 +290,14 @@ def _apply_core(
     else:
         f_hits, f_ss, f_sns, mtype, exceeded = scan_fn(init, xs)
 
-    # 4. write back each segment's final state (last event of each key)
+    # 3. write back each segment's final state (last event of each key)
     next_key = jnp.concatenate([key_s[1:], jnp.full((1,), -2, dtype=key_s.dtype)])
     is_last = (key_s != next_key) & ~pad_s
     wb_key = jnp.where(is_last, key_s, jnp.int32(cap_r))  # drop non-last
-    seen_idx = jnp.where(pad, state.ip_seen.shape[0], slot)
+    seen_idx = jnp.where(pad, n_slots, slot)
     if gate is not None:
         wb_key = jnp.where(gate, wb_key, jnp.int32(cap_r))
-        seen_idx = jnp.where(gate, seen_idx, state.ip_seen.shape[0])
+        seen_idx = jnp.where(gate, seen_idx, n_slots)
     hits = state.hits.at[wb_key].set(f_hits, mode="drop")
     start_s = state.start_s.at[wb_key].set(f_ss, mode="drop")
     start_ns = state.start_ns.at[wb_key].set(f_sns, mode="drop")

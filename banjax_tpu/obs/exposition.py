@@ -161,6 +161,9 @@ def render_prometheus(
         fam = registry.PROM_FAMILIES["banjax_fused_overflows_total"]
         for cause, v in fw.overflow_causes.items():
             w.sample(fam, v, {"cause": cause})
+        fam = registry.PROM_FAMILIES["banjax_fused_event_feed_total"]
+        for source, v in fw.event_feed.items():
+            w.sample(fam, v, {"source": source})
 
     # what this start spent on its rules, labeled by how it got them
     rc = getattr(matcher, "rules_cache", None) if matcher else None

@@ -257,6 +257,11 @@ FAMILIES: List[Family] = [
            "candidates, (row, rule) pairs or window events, or chain (gated "
            "by an overflowing predecessor)",
            prom="banjax_fused_overflows_total", labels=("cause",)),
+    Family(COUNTER, "window events committed by fused programs, by where "
+           "the program took the event from: a (row, rule) pair of the "
+           "filtered rules, or a set bit of an always-column (their sum is "
+           "the fused part of banjax_device_windows_events_total)",
+           prom="banjax_fused_event_feed_total", labels=("source",)),
     Family(COUNTER, "host wall seconds inside the drain's effector-replay "
            "spans (event decode, shadow absorb, Banner replay of committed "
            "fused chunks)",

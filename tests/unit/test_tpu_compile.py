@@ -194,12 +194,10 @@ def _fits_with_the_table_aliased(compiled):
     return m
 
 
-def test_stress10k_single_program_holds_the_table_in_place(
-    one_chip, stress_prefilter
-):
+@pytest.fixture(scope="module")
+def stress_single(one_chip, stress_prefilter):
     """The one fused program (match, dense bitmap, window commit) at the
-    full batch: 4,096 rows x 10,000 rules, 76 stage-2 slabs, the window
-    table an argument aliased to its output."""
+    full batch, compiled: 4,096 rows x 10,000 rules, 76 stage-2 slabs."""
     import types
 
     from banjax_tpu.matcher.kernels import fused_match_window as fmw
@@ -218,16 +216,72 @@ def test_stress10k_single_program_holds_the_table_in_place(
         scan_fn=fmw.window_scan(False),
     )
     assert (k, p, e) == (K, 1024, 1024)
-    # the (row, rule) pair encoding is int32: rows x packed rule columns
-    assert B * pf._nf8 * 8 < 2**31 // 50
     vec = sds((B,), jnp.int32)
-    compiled = fn.lower(
+    return fn.lower(
         state, sds((), jnp.int32), sds((B, 1 + L_P // 4), jnp.int32),
         sds((), jnp.int32), vec, vec, vec, vec, sds((B,), jnp.uint8),
     ).compile()
-    assert compiled.as_text().count("tpu_custom_call") >= 3
-    m = _fits_with_the_table_aliased(compiled)
+
+
+def test_stress10k_single_program_holds_the_table_in_place(
+    stress_single, stress_prefilter
+):
+    """The window table is an argument aliased to the program's output."""
+    # the (row, rule) pair encoding is int32: rows x packed rule columns
+    assert B * stress_prefilter._nf8 * 8 < 2**31 // 50
+    assert stress_single.as_text().count("tpu_custom_call") >= 3
+    m = _fits_with_the_table_aliased(stress_single)
     assert m.temp_size_in_bytes < 1e9
+
+
+def _ops_over(text: str, ops, dtype: str, sizes) -> list:
+    """Instructions of `text` (a compiled module) whose opcode is in `ops`
+    and whose result or an operand is a `dtype` array with an element
+    count in `sizes`.  Operand shapes are looked up by name inside the
+    instruction's own computation."""
+    import math
+    import re
+
+    shape_re = re.compile(rf"\b{dtype}\[([0-9,]+)\]")
+    inst_re = re.compile(
+        r"^\s*(?:ROOT )?(%[\w.\-]+) = (.*?) ([a-z][a-z\-]*)\((.*)$"
+    )
+
+    def hits(shape_text: str) -> bool:
+        return any(
+            math.prod(int(d) for d in dims.split(",")) in sizes
+            for dims in shape_re.findall(shape_text)
+        )
+
+    found = []
+    for comp in re.split(r"\n(?=(?:ENTRY )?%[\w.\-]+ \()", text):
+        insts = [m.groups() for m in map(inst_re.match, comp.splitlines()) if m]
+        shapes = {name: shape for name, shape, _, _ in insts}
+        for name, shape, op, rest in insts:
+            if op not in ops:
+                continue
+            operands = re.findall(r"%[\w.\-]+", rest.split("), ")[0])
+            if hits(shape) or any(hits(shapes.get(o, "")) for o in operands):
+                found.append(f"{name} = {shape} {op}")
+    return found
+
+
+def test_stress10k_single_program_reduces_over_no_rows_x_rules_array(
+    stress_single, stress_prefilter
+):
+    """The window events come from the pairs the program holds: nothing
+    in it scans, sorts or reduces an int32 array the size of the dense
+    match matrix (rows x rules) or of the unpacked candidate bits
+    (candidate slots x 8 nf8) — the two `nonzero`s that were 88 % of the
+    device's time at 10,000 rules (PR 34) cannot come back unnoticed."""
+    text = stress_single.as_text()
+    sizes = {B * STRESS_RULES, K * 8 * stress_prefilter._nf8}
+    ops = ("reduce-window", "sort", "fusion")
+    assert _ops_over(text, ops, "s32", sizes) == []
+    # the helper does see what it looks for: the dense bitmap the replay
+    # reads is still assembled, as bytes
+    assert _ops_over(text, ("fusion",), "u8", {B * STRESS_RULES})
+    assert "reduce-window" in text and " sort(" in text
 
 
 def test_stress10k_maintenance_steps_hold_the_table_in_place(one_chip):
