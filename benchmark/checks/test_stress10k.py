@@ -8,34 +8,18 @@ rehearsal with a returning address's warm-tier state thrown away has to
 fail the comparison, so what a ban after two refills rests on is held to
 the reference here as in the `crs1k` cells.
 
-The fault is `test_broken_path.py`'s `lost_state_on_refill`, put where a
-refill happens since PR 32: `DeviceWindows.resolve_addresses` takes the
-returning addresses' records in one `take_batch` and no longer calls
-`_refill_from_warm_locked`, which that file still patches (its case has
-found nothing to break since; PERF.md §7)."""
+The fault is `test_broken_path.py`'s `lost_state_on_refill`: the returning
+addresses' records thrown away at the warm tier's `take_batch`."""
 
 import json
 import os
 import sys
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 COMPARED = {"ban_records_missing", "ban_records_extra", "ips_out_of_order",
             "ban_keys_differing"}
-
-
-def lost_state_on_refill(monkeypatch):
-    from banjax_tpu.native import shm
-
-    n = {"calls": 0}
-    for cls in (shm.ShmWarmTier, shm.PyWarmTier):
-        def forgetful(self, ips, spans=None, _real=cls.take_batch):
-            got = _real(self, ips, spans)
-            n["calls"] += sum(v is not None for v in got)
-            return [None] * len(got)
-
-        monkeypatch.setattr(cls, "take_batch", forgetful)
-    return n
 
 
 def _rehearse(capsys, seed):
@@ -67,6 +51,8 @@ def test_sound_rehearsal_commits_every_chunk_fused(monkeypatch, capsys):
 
 
 def test_thrown_away_warm_tier_state_is_seen(monkeypatch, capsys):
+    from test_broken_path import lost_state_on_refill
+
     monkeypatch.setenv("JAX_PLATFORMS", "cpu")
     n = lost_state_on_refill(monkeypatch)
     result = _rehearse(capsys, "3333333334")

@@ -128,7 +128,7 @@ def run(cell, args, seconds, device, jax, t_process, say) -> int:
                  if args.trace else {})
         config_path = product_mod.write_config(workdir, config, rules, extra)
         prod = product_mod.Product(config_path, ctl)
-        warm_rests, _, _ = genproc.build_pools(rules, {"lines": {
+        warm_rests, _, _ = genproc.build_pools(rules, {**traffic, "lines": {
             **traffic["lines"], "benign_pool": 2048,
             "attack_pool": min(64, int(traffic["lines"].get("attack_pool", 0))),
         }}, stream.seed32(seed, 7))
@@ -242,22 +242,35 @@ def run(cell, args, seconds, device, jax, t_process, say) -> int:
         gen = np.load(gen_report)
         writes = gen[gen[:, 2] > 0]
         written = int(writes[:, 2].sum())
-        # a line not drained 10 s after the window closes has failed
+        # a line not drained 10 s after the window closes has failed.  With
+        # lines still in flight then, the comparison waits on for the
+        # pipeline to come to rest, a minute past the close at most: the
+        # drained batches (the reference's feed) and the ban log are read
+        # at one point, and a batch that drains between two reads would
+        # put its bans on one side only.  What drains late is late, not
+        # wrong: it is failed, and both sides have it
         target = c_go["processed"] + written + prod.tail_lines
-        while (prod.counters()["processed"] < target
-               and time.time() < t1 + DRAIN_WAIT_S):
-            time.sleep(0.02)
-        prod.app.pipeline.flush(max(0.1, t1 + DRAIN_WAIT_S - time.time()))
+
+        def at_rest(until: float) -> bool:
+            while (prod.counters()["processed"] < target
+                   and time.time() < until):
+                time.sleep(0.02)
+            return prod.app.pipeline.flush(max(0.1, until - time.time()))
+
+        prefix = f"{stream.IP_BASE}."
+        late_from = t1 + DRAIN_WAIT_S
+        rested = at_rest(late_from) or at_rest(t1 + 60.0)
         c_end = prod.counters()
-        peak = jax.devices()[0].memory_stats() or {}
         batches = prod.drained(t_go)
+        ban_lines = prod.ban_log(prefix)
+        peak = jax.devices()[0].memory_stats() or {}
 
         # ---- what the window measured
-        prefix = f"{stream.IP_BASE}."
         # a line dropped as too old was not served: it does not count
         drained_in_window = sum(len(b[2]) for b in batches if t0 <= b[0] < t1)
         too_old = sum(len(b[1]) - len(b[2]) for b in batches if b[0] >= t0)
-        gen_seen = sum(ln[18:21] == prefix for b in batches for ln in b[1])
+        gen_seen = sum(ln[18:21] == prefix for b in batches
+                       if b[0] <= late_from for ln in b[1])
         in_window = (writes[:, 0] >= t0) & (writes[:, 0] < t1)
         offered = int(writes[in_window, 2].sum())
         delta = {k: c_end[k] - c_go[k] for k in c_end}
@@ -343,7 +356,7 @@ def run(cell, args, seconds, device, jax, t_process, say) -> int:
         # the regex rate limiter's records are compared; what /auth_request
         # itself bans (failed challenges) is outside the guarantee and only
         # enters the answers expected of the probes
-        ban_log = [reference.product_record(x) for x in prod.ban_log(prefix)]
+        ban_log = [reference.product_record(x) for x in ban_lines]
         got = [x for x in ban_log if json.loads(x)["rule_type"] == "regex"]
         # the lines the product drained, in admission order, less those it
         # reported stale (they are in `failed`); lines it never drained are
@@ -369,13 +382,18 @@ def run(cell, args, seconds, device, jax, t_process, say) -> int:
                             lambda ip: ip.startswith(prefix),
                             int(config["reference"]["procs"]))
         cmp_ = reference.compare(got, ref["bans"])
+        site_rules = {r["rule"] for r in rules if r.get("_site")}
+        n_site = sum(json.loads(x)["trigger"] in site_rules
+                     for x in ref["bans"])
         say(f"reference: {ref['lines']} lines of the stream's client IPs "
             f"({ref['distinct']} distinct request strings) in "
-            f"{time.time() - t_ref:.1f} s; {len(ref['bans'])} ban records, "
+            f"{time.time() - t_ref:.1f} s; {len(ref['bans'])} ban records "
+            f"({n_site} of per-site rules), "
             f"product {len(got)} (+{len(other)} not of the regex limiter); "
             f"{ref['errors']} unparsable")
         for k in COMPARED:
             check(k, cmp_[k], 0)
+        check("lines_in_flight_at_comparison", int(not rested), 0)
         if cmp_["example_missing"] or cmp_["example_extra"]:
             say(f"  e.g. missing {cmp_['example_missing']} extra "
                 f"{cmp_['example_extra']}")
@@ -437,6 +455,7 @@ def run(cell, args, seconds, device, jax, t_process, say) -> int:
                   "checks_failed": [c[0] for c in checks if not c[3]]}
         if breakdown:
             result["breakdown"] = breakdown
+        compared = {c[0]: {"value": c[1], "limit": c[2]} for c in checks}
         if control:
             say(f"CONTROL: the same ban log against a reference whose limit "
                 f"of rule {control[1]!r} is one more than the product's; "
@@ -450,6 +469,9 @@ def run(cell, args, seconds, device, jax, t_process, say) -> int:
             result["control"] = {
                 "correct": all(c[3] for c in checks),
                 "checks_failed": [c[0] for c in checks if not c[3]]}
+        # every number compared beside its limit: last in the result's
+        # line, and the last lines on standard error
+        result["compared"] = compared
     except product_mod.NotReady as e:
         print(f"benchmark: set-up failed: {e}", file=sys.stderr)
         return 1
@@ -470,5 +492,9 @@ def run(cell, args, seconds, device, jax, t_process, say) -> int:
         if args.keep_log:
             os.makedirs(os.path.dirname(args.keep_log), exist_ok=True)
             shutil.copy("bench.log", args.keep_log)
+    for name, c in result["compared"].items():
+        print(f"compared {name}: {c['value']} (limit {c['limit']})",
+              file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(result), flush=True)
     return 0

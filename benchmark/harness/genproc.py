@@ -27,10 +27,17 @@ def build_pools(rules: list, traffic: dict, seed: int):
     per attack line)."""
     spec = traffic["lines"]
     cap = int(spec["max_rest_len"])
+    hosts = None
+    if traffic.get("hosts"):
+        hosts = lines.SiteHosts(rules, traffic["hosts"], seed)
+    elif any(r.get("_site") for r in rules):
+        raise SystemExit("the ruleset has per-site rules: the traffic file "
+                         "needs a `hosts` block")
     benign = lines.benign_pool(int(spec["benign_pool"]), spec["method_mix"],
-                               cap, seed)
+                               cap, seed, hosts)
     n_attack = int(spec.get("attack_pool", 0))
-    attack = lines.attack_pool(n_attack, rules, cap, seed) if n_attack else []
+    attack = (lines.attack_pool(n_attack, rules, cap, seed, hosts)
+              if n_attack else [])
     return (benign + [r for _, r in attack], len(benign),
             [i for i, _ in attack])
 

@@ -7,10 +7,21 @@ give a median near 150 bytes and about one line in twenty of 225 or more,
 capped so that nothing exceeds the product's `matcher_max_line_len`.
 Attack lines come from the ruleset's own recipes and each is verified
 against its rule with Python's `re`.  No import of the program, no JAX.
+
+Hosts.  Without a `hosts` block in the traffic file every line's host is
+one of the 16 `HOSTS`, equally likely.  With one (`{"draw": "zipf" |
+"uniform", "s": constant, "unprotected": n}`) the hosts are the ruleset's
+sites (the distinct `_site` values in the ruleset's order, rank 1 the most
+popular) followed by `n` generated names that have no rules of their own;
+a benign line draws its host by `draw`; an attack line written from a
+per-site rule's recipe goes to that rule's site (on any other host the
+rule does not apply), one written from a global rule draws like a benign
+line.
 """
 
 from __future__ import annotations
 
+import itertools
 import random
 import re
 import string
@@ -60,6 +71,8 @@ LEAVES = [
 QUERY_KEYS = ["q", "page", "sort", "ref", "utm_source", "utm_campaign",
               "id", "lang", "session", "cb", "filter", "from"]
 _ALNUM = string.ascii_lowercase + string.digits
+HOST_NAME = re.compile(r"[a-z.-]+\.(com|org|net)")  # what the recipes assume
+HOST_PREFIXES = ["", "", "", "www.", "cdn.", "m.", "news.", "blog."]
 
 
 def _word(rng: random.Random, lo: int, hi: int, chars: str = _ALNUM) -> str:
@@ -110,12 +123,47 @@ def _draw(rng: random.Random, mix: dict) -> str:
     return next(iter(mix))
 
 
-def benign_pool(n: int, method_mix: dict, cap: int, seed: int) -> list:
+class SiteHosts:
+    """The hosts of a traffic file's `hosts` block: the ruleset's sites,
+    then `unprotected` names made from the seed, 8 to 24 bytes as `HOSTS`
+    are, so line lengths stay where they are."""
+
+    def __init__(self, rules: list, spec: dict, seed: int):
+        self.names = list(dict.fromkeys(
+            r["_site"] for r in rules if r.get("_site")))
+        rng = random.Random(seed * 1_000_003 + 41)
+        n = len(self.names) + int(spec.get("unprotected", 0))
+        taken = set(self.names)
+        while len(self.names) < n:
+            name = (rng.choice(HOST_PREFIXES)
+                    + _word(rng, 4, 13, string.ascii_lowercase)
+                    + rng.choice([".com", ".org", ".net"]))
+            if name not in taken:
+                taken.add(name)
+                self.names.append(name)
+        bad = [h for h in self.names if not HOST_NAME.fullmatch(h)]
+        if bad or not self.names:
+            raise SystemExit(f"hosts: no host, or not a host name: {bad[:3]}")
+        if spec["draw"] == "zipf":
+            w = [r ** -float(spec["s"]) for r in range(1, n + 1)]
+        elif spec["draw"] == "uniform":
+            w = [1.0] * n
+        else:
+            raise SystemExit(f"unknown hosts.draw {spec['draw']!r}")
+        self.cdf = list(itertools.accumulate(w))
+
+    def draw(self, rng: random.Random) -> str:
+        return rng.choices(self.names, cum_weights=self.cdf)[0]
+
+
+def benign_pool(n: int, method_mix: dict, cap: int, seed: int,
+                hosts: SiteHosts | None = None) -> list:
     rng = random.Random(seed * 1_000_003 + 17)
     out = []
     for _ in range(n):
         out.append(_assemble(
-            _draw(rng, method_mix), rng.choice(HOSTS),
+            _draw(rng, method_mix),
+            hosts.draw(rng) if hosts else rng.choice(HOSTS),
             _path(rng, _path_len(rng)), rng.choice(USER_AGENTS), cap,
         ))
     return out
@@ -139,7 +187,8 @@ def _fill(template: str, rng: random.Random) -> str:
     return "".join(out)
 
 
-def attack_line(rule: dict, rng: random.Random, cap: int) -> str:
+def attack_line(rule: dict, rng: random.Random, cap: int,
+                hosts: SiteHosts | None = None) -> str:
     """One line that `rule["regex"]` matches, written from the rule's
     recipe at a realistic length and checked with `re`."""
     recipe = rule["_attack"]
@@ -156,10 +205,14 @@ def attack_line(rule: dict, rng: random.Random, cap: int) -> str:
             path += sep + f"{rng.choice(QUERY_KEYS)}={_word(rng, 3, 24)}"
     else:
         path = _path(rng, min(_path_len(rng), 80))
-    rest = f"{method} {rng.choice(HOSTS)} {method} {path} HTTP/1.1 {ua} -"
+    if hosts:  # a per-site rule applies on its own site only
+        host = short_host = rule.get("_site") or hosts.draw(rng)
+    else:
+        host, short_host = rng.choice(HOSTS), HOSTS[0]
+    rest = f"{method} {host} {method} {path} HTTP/1.1 {ua} -"
     if len(rest) > cap:  # rare: the same recipe again with the shortest UA
         ua = min(USER_AGENTS, key=len) + ua[ua.rfind(" "):] * ("ua" in recipe)
-        rest = f"{method} {HOSTS[0]} {method} {path} HTTP/1.1 {ua} -"
+        rest = f"{method} {short_host} {method} {path} HTTP/1.1 {ua} -"
     if re.search(rule["regex"], rest) is None:
         raise ValueError(
             f"recipe of {rule['rule']} wrote a line its regex "
@@ -167,7 +220,8 @@ def attack_line(rule: dict, rng: random.Random, cap: int) -> str:
     return rest
 
 
-def attack_pool(n: int, rules: list, cap: int, seed: int) -> list:
+def attack_pool(n: int, rules: list, cap: int, seed: int,
+                hosts: SiteHosts | None = None) -> list:
     """[(rule index, line)], rules drawn uniformly among those that have a
     recipe."""
     rng = random.Random(seed * 1_000_003 + 29)
@@ -177,7 +231,7 @@ def attack_pool(n: int, rules: list, cap: int, seed: int) -> list:
     out = []
     for _ in range(n):
         i = rng.choice(with_recipe)
-        out.append((i, attack_line(rules[i], rng, cap)))
+        out.append((i, attack_line(rules[i], rng, cap, hosts)))
     return out
 
 

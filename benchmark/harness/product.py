@@ -64,7 +64,17 @@ def write_config(workdir: str, config: dict, rules: list, extra: dict) -> str:
             raise SystemExit(f"deploy config has {key}; the reference has none")
     cfg.update(config["product_config"])
     cfg.update(extra)
-    cfg["regexes_with_rates"] = found.product_rules(rules)
+    # the product lays per-site rules first, so its column is not the
+    # harness's rule index (the generator's); nothing here assumes it is
+    per_site = {}
+    cfg["regexes_with_rates"] = []
+    for rule, record in zip(rules, found.product_rules(rules)):
+        if rule.get("_site"):
+            per_site.setdefault(rule["_site"], []).append(record)
+        else:
+            cfg["regexes_with_rates"].append(record)
+    if per_site:
+        cfg["per_site_regexes_with_rates"] = per_site
     path = os.path.join(workdir, "banjax-config.yaml")
     with open(path, "w", encoding="utf-8") as f:
         yaml.safe_dump(cfg, f, sort_keys=False)

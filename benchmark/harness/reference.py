@@ -7,20 +7,30 @@ import of the program, no JAX, nothing the program has made.
 Semantics held to (the configuration's guarantee):
   * `<epoch.frac> <ip> <rest>`, `rest` = `<method> <host> ...`; a line with
     fewer fields is an error and matches nothing;
-  * every rule's regex is searched (unanchored) in `rest`, rules in order;
+  * a line of host `h` (field 2 of `rest`) meets the rules whose `_site` is
+    `h`, in the ruleset's order, then the rules without a `_site` (the
+    global ones) in theirs: `per_site_regexes_with_rates[h]` before
+    `regexes_with_rates`, `regex_rate_limiter.go:175-211`; each rule's
+    regex is searched (unanchored) in `rest`, and a rule whose
+    `hosts_to_skip[h]` is set is left out on that host
+    (applyRegexToLog, `:216-269`; tests `regex_rate_limiter_test.go:77-296`);
   * fixed window per (ip, rule): restart (hits := 1) when
     `t - start > interval` in integer nanoseconds, else hits += 1; when
     `hits > hits_per_interval` the rule fires and hits := 0;
   * a firing writes one ban-log record taken from the line.
 `now` is held at each line's own stamp, so nothing is stale here; lines
 the product dropped as stale or shed are left out by the caller and
-counted as failed.  The configurations have no allow lists, per-site rules
-or `hosts_to_skip`, and `make()` refuses one that does.
+counted as failed.  Window state is per (ip, rule record); upstream keys it
+by the rule's name, so a ruleset in which two records share a name is
+refused.  The configurations have no allow lists, and
+`product.write_config` refuses a deployment that has.
 
-Cost.  Which rules a request string matches is a pure function of the
-string, so it is worked out once per distinct string (spawned children,
-off the chip's process), then the per-IP window logic runs over every
-followed line in order.  A cut, where one is ever needed, is by client IP and
+Cost.  The host is part of the request string, so which rules apply to a
+string and match it, and in what order, is a pure function of the string:
+it is worked out once per distinct string (spawned children, off the
+chip's process; only the string's own site's regexes and the global ones
+are searched), then the per-IP window logic runs over every followed line
+in order.  A cut, where one is ever needed, is by client IP and
 never by line: window state and bans are per IP.
 """
 
@@ -38,22 +48,57 @@ DECISION_STRING = {
 }
 
 
+def rule_order(rules: list) -> tuple:
+    """→ ({site: its rules' indices}, the global rules' indices), each in
+    the ruleset's order."""
+    by_site, global_ids = {}, []
+    for i, r in enumerate(rules):
+        if r.get("_site"):
+            by_site.setdefault(r["_site"], []).append(i)
+        else:
+            global_ids.append(i)
+    return by_site, global_ids
+
+
+def met(own: dict, everywhere: list, host) -> list:
+    """The rules a line of `host` meets, in order: its host's own first,
+    then the global ones (`regex_rate_limiter.go:175-193`, `:195-211`)."""
+    return own.get(host, []) + everywhere
+
+
 def _match_chunk(args):
-    """Child: rule indices matched by each request string."""
-    regexes, rests = args
-    compiled = [re.compile(r) for r in regexes]
-    return [tuple(i for i, rx in enumerate(compiled) if rx.search(s))
-            for s in rests]
+    """Child: for each request string, the indices of the rules that apply
+    to its host and match it, in the order they apply."""
+    rules, rests = args
+    by_site, global_ids = rule_order(rules)
+
+    def compiled(ids: list) -> list:
+        return [(i, re.compile(rules[i]["regex"]).search,
+                 rules[i].get("hosts_to_skip") or {}) for i in ids]
+
+    everywhere = compiled(global_ids)
+    own = {}  # a site's rules are compiled when the site is first seen
+    out = []
+    for s in rests:
+        words = s.split(" ", 2)
+        host = words[1] if len(words) == 3 else None
+        if host in by_site and host not in own:
+            own[host] = compiled(by_site[host])
+        out.append(tuple(i for i, search, skip in met(own, everywhere, host)
+                         if search(s) and not skip.get(host)))
+    return out
 
 
-def match_table(regexes: list, rests: list, procs: int) -> dict:
+def match_table(rules: list, rests: list, procs: int) -> dict:
+    rules = [{k: r.get(k) for k in ("regex", "_site", "hosts_to_skip")}
+             for r in rules]
     procs = max(1, min(procs, (os.cpu_count() or 2) - 1, len(rests) // 64 or 1))
     if procs == 1:
-        return dict(zip(rests, _match_chunk((regexes, rests))))
+        return dict(zip(rests, _match_chunk((rules, rests))))
     step = -(-len(rests) // (procs * 4))
     chunks = [rests[i:i + step] for i in range(0, len(rests), step)]
     with mp.get_context("spawn").Pool(procs) as pool:
-        parts = pool.map(_match_chunk, [(regexes, c) for c in chunks])
+        parts = pool.map(_match_chunk, [(rules, c) for c in chunks])
     return {s: m for c, p in zip(chunks, parts) for s, m in zip(c, p)}
 
 
@@ -80,9 +125,12 @@ def run(rules: list, lines, checked, procs: int = 8, table=None) -> dict:
     """`lines`: the log's lines in order; `checked(ip)` says whether an
     IP's lines are followed.  → {"bans": [record], "lines": n followed,
     "errors": n unparsable, "distinct": n distinct request strings,
-    "table": which rules each request string matches}.  `table`: that of
-    an earlier call over the same lines and the same regexes (the control
+    "table": which rules each request string meets and matches, in the
+    order it meets them}.  `table`: that of an earlier call over the same
+    lines and the same regexes, sites and `hosts_to_skip` (the control
     differs from the sound run in a limit only)."""
+    if len({r["rule"] for r in rules}) != len(rules):
+        raise SystemExit("reference: two rule records share a name")
     followed = []
     for line in lines:
         parts = line.split(" ", 2)
@@ -91,7 +139,7 @@ def run(rules: list, lines, checked, procs: int = 8, table=None) -> dict:
         followed.append(parts)
     distinct = list(dict.fromkeys(p[2] for p in followed))
     if table is None:
-        table = match_table([r["regex"] for r in rules], distinct, procs)
+        table = match_table(rules, distinct, procs)
     interval_ns = [int(r["interval"] * 1_000_000_000) for r in rules]
     limit = [int(r["hits_per_interval"]) for r in rules]
     state = {}  # (ip, rule index) -> [hits, window start ns]
