@@ -12,7 +12,10 @@ The program (kernels/fused_match_window.py): two-stage match
 2's packed words, the window events listed straight from those pairs and
 the always-columns' bits — masked per event by the per-row live mask (the
 caller's staleness drop, an INPUT to submit), the real-row count and the
-host's active rules — every overflow flag (candidate count, match-pair
+host's active rules; where the ruleset has rules of single sites, stage
+2's packed rows are ANDed with their host's packed active row before the
+pairs are counted, so a pattern many sites share yields one pair a line —
+every overflow flag (candidate count, match-pair
 count, window-event count) and the window segmented scan
 (windows._apply_events, state donated) whose commit is gated IN the
 program on those flags and on a device-side chain scalar.  Nothing in it
@@ -135,10 +138,15 @@ class FusedWindowsPipeline:
 
     def __init__(self, prefilter: FusedPrefilter, windows: DeviceWindows,
                  active_table, n_rules: int,
-                 scan_interpret: bool = True, traffic_sketch=None):
+                 scan_interpret: bool = True, traffic_sketch=None,
+                 skip_table=None):
         self.pf = prefilter
         self.windows = windows
         self.active_table = jnp.asarray(active_table)
+        # [hosts + 1, rules] like the active table: rules that apply on
+        # the host but are skipped there (hosts_to_skip) — no event, but
+        # a pair, because the drain owes the line a skip_host result
+        self.skip_table = skip_table
         self.n_rules = n_rules
         # traffic introspection (obs/sketch.py): every submitted chunk
         # folds into the device-resident count-min/HLL/rule-pressure
@@ -164,6 +172,10 @@ class FusedWindowsPipeline:
         # window events committed fused, by where the program took them
         # from: its (row, rule) pairs or its always-columns' set bits
         self.event_feed = {"pairs": 0, "always": 0}
+        # (row, rule) pairs the programs counted (flag n_pairs of every
+        # dispatch read, one that overflowed included): with rules of
+        # single sites, what the site mask left of stage 2's set bits
+        self.pairs_total = 0
         plan = prefilter.plan
         self._is_always = np.zeros(max(1, n_rules), dtype=bool)
         self._is_always[np.asarray(plan.a_idx, dtype=np.int64)] = True
@@ -204,6 +216,7 @@ class FusedWindowsPipeline:
             Bp, L_p, f_idx=self._f_idx, a_idx=self._a_idx,
             aw=self._aw, ae=self._ae,
             scan_fn=fmw.window_scan(self._scan_interpret),
+            skip_table=self.skip_table,
         )
         self._progs[key] = hit
         return hit
@@ -430,6 +443,7 @@ class FusedWindowsPipeline:
             # the rows stage 2 scanned: the gate's candidates, as far
             # as the program has room for them
             self.pf.candidates_total += min(int(p.flags[1]), p.K)
+            self.pairs_total += int(p.flags[2])
             if not p.flags[0]:
                 raise self._overflow(p)
             p.events_buf = buf

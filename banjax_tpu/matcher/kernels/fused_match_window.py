@@ -9,8 +9,8 @@ between them — pull the flags, check overflow, only then dispatch the
 commit.  This module needs neither.  One device program per chunk does
 
     match (the two-stage Pallas NFA scan, prefilter._match_core)
-      → sparse (row, rule) pairs, extracted from stage 2's PACKED words
-        (prefilter.pairs_from_core), and the always-columns' bits
+      → sparse (row, rule) pairs from stage 2's PACKED words, masked by
+        host first (prefilter.pairs_from_core), and the always-columns' bits
       → the window events, listed from exactly those: one per pair, one
         per set always-bit, each masked by gather with the per-row live
         mask (staleness/abandon composed as an input), the real-row count
@@ -67,7 +67,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from banjax_tpu.matcher import windows as W
+from banjax_tpu.matcher import sitemask, windows as W
 
 _SHIFTS = (0, 8, 16, 24)
 
@@ -211,7 +211,7 @@ def scan_selftest(interpret: bool, E: int = 64) -> None:
 
 def build_single_program(
     pf, windows, active_table, n_rules: int, Bp: int, L_p: int, *,
-    f_idx, a_idx, aw, ae, scan_fn,
+    f_idx, a_idx, aw, ae, scan_fn, skip_table=None,
 ):
     """One jitted device program: match core + event list from the pairs
     and always-columns + overflow/chain gate + window commit + compact
@@ -241,6 +241,13 @@ def build_single_program(
     n_filt = pf._n_filt
     R8 = pf._nf8 * 8
     limits, iv_s, iv_ns = windows._limits, windows._iv_s, windows._iv_ns
+    # rules of single sites: each candidate's packed row is ANDed with its
+    # host's packed active row BEFORE the pairs are counted and listed
+    # (matcher/sitemask.py); None for a ruleset of global rules, whose
+    # program is built without the gather
+    site_mask = sitemask.packed_rows(active_table, skip_table, f_idx)
+    if site_mask is not None:
+        site_mask = jnp.asarray(site_mask)                  # [hosts+1, nf8]
     active_table = jnp.asarray(active_table)
     shifts = jnp.asarray(_SHIFTS, dtype=jnp.int32)
 
@@ -248,7 +255,14 @@ def build_single_program(
     def single(state, chain_ok, combined, n_real, host_idx, slots,
                ts_s, ts_ns, live):
         c = core(combined)
-        pairs, n_pairs, pair_bits = pf.pairs_from_core(c, K, P)
+        keep = None
+        if site_mask is not None:
+            with jax.named_scope("site-mask"):
+                # an unused candidate slot names row Bp and holds no bits
+                keep = site_mask[
+                    host_idx[jnp.minimum(c["idx_caller_k"], Bp - 1)]
+                ]
+        pairs, n_pairs, pair_bits = pf.pairs_from_core(c, K, P, keep)
         # dense caller-order bitmap, assembled on device
         bits = jnp.zeros((Bp, n_rules), dtype=jnp.uint8)
         if n_filt:

@@ -666,7 +666,7 @@ class FusedPrefilter:
         P = self.pair_capacity(B, K)
         return block, K, P, self.event_capacity(B, P)
 
-    def pairs_from_core(self, c, K: int, P: int):
+    def pairs_from_core(self, c, K: int, P: int, keep=None):
         """The sparse (row, rule) pair extraction shared by the plain fused
         program and the fused-windows program: one int32 per set stage-2
         bit, encoded caller_row * R8 + packed bit column (R8 = 8 * nf8),
@@ -674,6 +674,11 @@ class FusedPrefilter:
         (pairs [P] int32, n_pairs, bits [K, R8]) — `bits` is the unpacked
         MSB-first bit tensor for callers that assemble the dense form;
         nothing here reduces over it.
+
+        `keep` ([K, nf8] uint8, packed as m2p is) leaves a bit out of the
+        pairs and their count where it is clear — the rules inactive for a
+        candidate's host (kernels/fused_match_window.py); `bits` stays
+        what stage 2 matched.
 
         The set bits are found in the PACKED words: m2p read as big-endian
         32-bit words (column 32 w + j is bit 31 - j of word w), a running
@@ -699,6 +704,9 @@ class FusedPrefilter:
             bits, 0,
         )
         nf32 = -(-nf8 // 4)
+        if keep is not None:
+            with jax.named_scope("site-mask"):
+                m2p = m2p & keep
         quads = jnp.pad(m2p, ((0, 0), (0, 4 * nf32 - nf8))).astype(jnp.uint32)
         quads = quads.reshape(K, nf32, 4)
         words = (

@@ -263,6 +263,9 @@ class TpuMatcher(Matcher):
                     config, "warm_tier_capacity", 1 << 20
                 ),
             )
+            self.device_windows.n_site_rules = (
+                len(self._entries) - len(self._global_idx)
+            )
             # active_table[h, rid]: rule rid applies to lines of host row h
             # (per-site rules of that host + global rules), minus
             # hosts_to_skip — the per-site-then-global loop of
@@ -489,6 +492,7 @@ class TpuMatcher(Matcher):
                 self._prefilter, self.device_windows, self._active_table,
                 self.compiled.n_rules, scan_interpret=self._scan_interpret,
                 traffic_sketch=self.traffic_sketch,
+                skip_table=self._skips,
             )
             log.info("fused matcher+windows pipeline active (single-kernel)")
         if self._health is not None:

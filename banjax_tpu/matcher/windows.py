@@ -618,6 +618,10 @@ class DeviceWindows:
         self.maintenance_elems = 0
         # window events committed by device applies (fused or classic)
         self.device_events = 0
+        # ... and those of them whose rule belongs to one site: rule ids
+        # below n_site_rules (the matcher lays per-site rules first)
+        self.n_site_rules = 0
+        self.site_events = 0
         # Host shadow of the device counters: ip → (rule_id → (hits, s, ns)),
         # both dicts in first-event insertion order — exactly the reference
         # host dict's shape (rate_limit.go:37-78, which never forgets).
@@ -1480,6 +1484,8 @@ class DeviceWindows:
         self.device_events += len(line)
         if not len(line):
             return
+        if self.n_site_rules:
+            self.site_events += int(np.count_nonzero(rule < self.n_site_rules))
         slot_ip = self._slot_ip
         shadow = self._shadow
         for slot, rid, h, s, ns in zip(

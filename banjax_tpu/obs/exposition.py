@@ -164,6 +164,8 @@ def render_prometheus(
         fam = registry.PROM_FAMILIES["banjax_fused_event_feed_total"]
         for source, v in fw.event_feed.items():
             w.sample(fam, v, {"source": source})
+        w.sample(registry.PROM_FAMILIES["banjax_fused_pairs_total"],
+                 fw.pairs_total)
 
     # what this start spent on its rules, labeled by how it got them
     rc = getattr(matcher, "rules_cache", None) if matcher else None
@@ -174,6 +176,10 @@ def render_prometheus(
     # the submit stage's address resolution: what the pass found, and
     # the keys it handed to each table (prom-only labeled counters)
     dw = getattr(matcher, "device_windows", None) if matcher else None
+    if dw is not None and hasattr(dw, "site_events"):
+        fam = registry.PROM_FAMILIES["banjax_window_events_total"]
+        w.sample(fam, dw.site_events, {"scope": "site"})
+        w.sample(fam, dw.device_events - dw.site_events, {"scope": "global"})
     if dw is not None and hasattr(dw, "resolve_outcomes"):
         fam = registry.PROM_FAMILIES["banjax_submit_resolve_addresses_total"]
         for outcome, v in dw.resolve_outcomes.items():

@@ -262,6 +262,15 @@ FAMILIES: List[Family] = [
            "filtered rules, or a set bit of an always-column (their sum is "
            "the fused part of banjax_device_windows_events_total)",
            prom="banjax_fused_event_feed_total", labels=("source",)),
+    Family(COUNTER, "(row, rule) pairs the fused programs counted after "
+           "the site mask (flag n_pairs of every dispatch read, overflowed "
+           "ones included; divide by lines: against the pair capacity of "
+           "250 a thousand rows)",
+           prom="banjax_fused_pairs_total"),
+    Family(COUNTER, "window events committed by device applies, by whether "
+           "the rule belongs to one site or is global (their sum is "
+           "banjax_device_windows_events_total)",
+           prom="banjax_window_events_total", labels=("scope",)),
     Family(COUNTER, "host wall seconds inside the drain's effector-replay "
            "spans (event decode, shadow absorb, Banner replay of committed "
            "fused chunks)",

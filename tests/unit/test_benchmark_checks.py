@@ -1,0 +1,43 @@
+"""The port-free cases of `benchmark/checks/`, counted by tier-1 (D20,
+owed since PR 35): the plain reference's per-site tables and its order
+control (`test_per_site.py`), and the cells' feeds and product
+configurations held to their SHA-256 constants (`test_cells_unmoved.py`).
+Imported, not copied: a case changed there is changed here.  The cases
+that start the product on port 8081 stay by-hand checks.
+
+`test_cells_unmoved.py` holds the four cells of PR 35 and says that they
+are every cell; it is a file of the benchmark and not this PR's to edit,
+so `multisite.botnet`, the fifth, is held here: constants computed on the
+tree that added it (PR 37), by that file's `digests`."""
+
+from benchmark.checks import test_cells_unmoved as unmoved
+from benchmark.checks.test_cells_unmoved import (  # noqa: F401
+    test_cell_is_fed_and_configured_as_at_the_parent,
+)
+from benchmark.checks.test_per_site import (  # noqa: F401
+    test_control_and_compare_take_per_site_records,
+    test_fixture_lines_go_where_their_rules_apply,
+    test_global_first_fails_the_order_table,
+    test_per_site_rules_need_a_hosts_block,
+    test_reference_global_rulesets_read_as_before,
+    test_reference_per_site_tables,
+    test_reference_refuses_two_records_of_one_name,
+)
+from benchmark.harness import found
+
+WHEN_ADDED = {
+    "multisite.botnet": {
+        "pools": "e1489a2bbd4ce6acc25c04653cbd37cb09508c52d5456b8c7dd4818aa12ee59a",
+        "stream": "da64aca6e16f59c7aa87234285507a7f240d97165f97a8d036cb2b64051f310e",
+        "config": "634178690492b27486ec9181192dae707924eabd709c175e460cc85a7930f840",
+    },
+}
+
+
+def test_multisite_botnet_is_fed_and_configured_as_when_added():
+    assert unmoved.digests("multisite.botnet") == WHEN_ADDED["multisite.botnet"]
+
+
+def test_every_cell_of_the_benchmark_is_held_here_or_there():
+    assert {w["name"] for w in found.benchmark_json()["workloads"]} \
+        == set(unmoved.AT_PARENT) | set(WHEN_ADDED)

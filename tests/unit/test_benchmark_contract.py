@@ -264,6 +264,84 @@ def test_stress10k_family_is_on_metrics_and_its_reader_reads_it(family):
     m.close()
 
 
+_MULTISITE_FAMILIES = {
+    # family: (label sets the readers select by, its readers)
+    "banjax_fused_pairs_total": ([{}], ["site_pairs_per_kline"]),
+    "banjax_window_events_total":
+        ([{"scope": "site"}, {"scope": "global"}], ["site_events_share"]),
+    "banjax_fused_overflows_total":
+        ([{"cause": "pairs"}], ["pairs_overflow_share"]),
+}
+
+
+@pytest.mark.parametrize("family", sorted(_MULTISITE_FAMILIES))
+def test_multisite_family_is_on_metrics_and_its_reader_reads_it(family):
+    """The counters `multisite-edge` brought (ISSUE 37), off `/metrics`
+    through the benchmark's own parser and through the three readers of
+    `multisite.botnet`: pairs the fused programs counted after the site
+    mask, window events by the scope of their rule, overflows by cause."""
+    from banjax_tpu.decisions.dynamic_lists import DynamicDecisionLists
+    from banjax_tpu.decisions.rate_limit import (
+        FailedChallengeRateLimitStates,
+    )
+    from banjax_tpu.obs.exposition import render_prometheus
+    from benchmark.harness import found, prom
+
+    label_sets, readers = _MULTISITE_FAMILIES[family]
+    assert family in {f.prom for f in registry.FAMILIES}
+    one = {"regex": "GET /attack.*", "interval": 5, "hits_per_interval": 2,
+           "decision": "nginx_block"}
+    cfg = config_from_yaml_text(yaml.safe_dump({
+        "regexes_with_rates": [{**one, "rule": "everywhere",
+                                "regex": "GET /probe.*"}],
+        "per_site_regexes_with_rates": {
+            f"s{k}.com": [{**one, "rule": f"s{k}"}] for k in range(4)},
+    }))
+    cfg.matcher_device_windows = True
+    m = TpuMatcher(cfg, MockBanner(), StaticDecisionLists(cfg),
+                   RegexRateLimitStates())
+    now = time.time()
+    sched = PipelineScheduler(lambda: m, now_fn=lambda: now)
+
+    def scrape():
+        return prom.parse(render_prometheus(
+            DynamicDecisionLists(start_sweeper=False), RegexRateLimitStates(),
+            FailedChallengeRateLimitStates(), matcher=m, pipeline=sched,
+        ))
+
+    sched.start()
+    before = scrape()
+    # of 100 lines, 8 fire their own site's rule, 1 carries the pattern on
+    # a host with no rule for it (no pair), 3 fire the global rule
+    sched.submit([
+        f"{now:.6f} 1.2.3.{i} GET "
+        f"{'other.com' if i % 50 == 0 else f's{i % 4}.com'} GET "
+        f"/{'attack' if i % 12 == 0 else 'probe' if i % 45 == 1 else 'page'}"
+        f"{i} HTTP/1.1 ua -"
+        for i in range(100)
+    ])
+    assert sched.flush(120)
+    sched.stop()
+    snap = scrape()
+    for labels in label_sets:
+        assert prom.value(snap, family, **labels) is not None, labels
+    assert prom.value(snap, "banjax_fused_pairs_total") == 11
+    assert prom.value(snap, "banjax_window_events_total", scope="site") == 8
+    assert prom.value(snap, "banjax_window_events_total", scope="global") == 3
+    assert prom.value(snap, "banjax_window_events_total") == prom.value(
+        snap, "banjax_device_windows_events_total")
+    ctx = {"prom0": before, "prom1": snap, "trace": None, "trace_lines": 0,
+           "mean_len": 0.0}
+    want = {"site_pairs_per_kline": 110.0, "pairs_overflow_share": 0.0,
+            "site_events_share": pytest.approx(100 * 8 / 11)}
+    for name in readers:
+        assert found.module("layers", name).read(ctx) == want[name]
+        # a program without the counter: the reader is silent
+        assert found.module("layers", name).read(
+            {**ctx, "prom0": {}, "prom1": {}}) is None
+    m.close()
+
+
 def test_stage2_readers_split_a_trace_by_nfa_words():
     """`match_stage2_us_per_kline` and `match_stage2_roofline` take stage
     2 to be the match-kernel launches with more NFA words than stage 1's

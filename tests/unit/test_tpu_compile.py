@@ -294,3 +294,79 @@ def test_stress10k_maintenance_steps_hold_the_table_in_place(one_chip):
     ):
         m = _fits_with_the_table_aliased(step.lower(state, operand).compile())
         assert m.temp_size_in_bytes < 1e8
+
+
+# ---- multisite-edge: 750 sites' rules, the site mask before the pairs ----
+
+MULTISITE_HOSTS = 751
+
+
+@pytest.fixture(scope="module")
+def multisite_single(one_chip):
+    """The fused program of `multisite-edge` at the full batch, compiled:
+    9,000 per-site columns (24 patterns, each on about 375 sites) before
+    1,000 global ones, a [751, 10000] active table, and the packed site
+    mask gathered by the candidates' hosts in front of the pairs."""
+    import types
+
+    import numpy as np
+
+    from banjax_tpu.matcher.kernels import fused_match_window as fmw
+    from banjax_tpu.matcher.prefilter import FusedPrefilter, build_plan
+    from banjax_tpu.matcher.rulec import compile_rules
+    from benchmark.harness import found
+
+    rules = found.ruleset(found.data("configs", "multisite-edge")["ruleset"])
+    own = [r for r in rules if r.get("_site")]       # the product's order
+    rules = own + [r for r in rules if not r.get("_site")]
+    assert len(rules) == STRESS_RULES and len(own) == 9000
+    pats = [r["regex"] for r in rules]
+    comp = compile_rules(pats, n_shards=1)
+    plan = build_plan(pats, byte_classes=(comp.byte_to_class, comp.n_classes))
+    assert not plan.unsupported and plan.n_always == 0
+    assert plan.stage2.words_per_shard <= 512
+    pf = FusedPrefilter(plan, "pallas")
+    row = {s: i + 1 for i, s in enumerate(sorted({r["_site"] for r in own}))}
+    active = np.zeros((MULTISITE_HOSTS, STRESS_RULES), bool)
+    active[:, len(own):] = True
+    for i, r in enumerate(own):
+        active[row[r["_site"]], i] = True
+    sds, state = _stress_state(one_chip)
+    win = types.SimpleNamespace(
+        _limits=jnp.full((STRESS_RULES,), 2, jnp.int32),
+        _iv_s=jnp.full((STRESS_RULES,), 300, jnp.int32),
+        _iv_ns=jnp.zeros((STRESS_RULES,), jnp.int32),
+    )
+    fn, k, p, e = fmw.build_single_program(
+        pf, win, active, STRESS_RULES, B, L_P,
+        f_idx=jnp.asarray(pf.plan.f_idx, jnp.int32),
+        a_idx=jnp.asarray(pf.plan.a_idx, jnp.int32), aw=None, ae=None,
+        scan_fn=fmw.window_scan(False), skip_table=np.zeros_like(active),
+    )
+    assert (k, p, e) == (K, 1024, 1024)   # no larger buffer for the sites
+    vec = sds((B,), jnp.int32)
+    return pf, fn.lower(
+        state, sds((), jnp.int32), sds((B, 1 + L_P // 4), jnp.int32),
+        sds((), jnp.int32), vec, vec, vec, vec, sds((B,), jnp.uint8),
+    ).compile()
+
+
+def test_multisite_single_program_masks_by_site_and_fits(multisite_single):
+    pf, compiled = multisite_single
+    text = compiled.as_text()
+    assert "site-mask" in text and text.count("tpu_custom_call") >= 3
+    m = _fits_with_the_table_aliased(compiled)
+    assert m.temp_size_in_bytes < 1e9
+    # the mask is a gather of packed rows and an AND: nothing in front of
+    # the pairs reduces over rows x rules or the unpacked candidate bits
+    sizes = {B * STRESS_RULES, K * 8 * pf._nf8}
+    assert _ops_over(text, ("reduce-window", "sort", "fusion"),
+                     "s32", sizes) == []
+
+
+def test_a_ruleset_of_global_rules_compiles_without_the_site_mask(
+    stress_single
+):
+    """One row in the active table (the four older cells): the program is
+    built as before, no gather by host in front of the pairs."""
+    assert "site-mask" not in stress_single.as_text()
