@@ -40,6 +40,16 @@ computes anyway) holds every (ip, rule) counter, and a re-admitted IP's
 rows are scattered back onto the device before its next events — beyond
 `matcher_window_capacity` distinct IPs the matcher degrades to slower,
 never to wrong (rate_limit.go:37-78 never forgets state, so neither do we).
+
+The shadow is one record per address — its counters in first-event order
+— that moves between three homes: with the address while it is resident,
+the warm tier once it is evicted, and a dict keyed by address where no
+tier takes it.  Two forms of the same logic: with the native libraries
+(`_sm is not None`) the residents' records live in a slot-indexed C
+mirror (native/shmstate.c sh_*) and absorb, spill, refill and the restore
+rows are one C call each over arrays; without them everything is the
+dict, which is also the oracle the parity tests hold the mirror to
+(tests/unit/test_shadow_mirror.py).
 """
 
 from __future__ import annotations
@@ -557,6 +567,14 @@ class DeviceWindows:
                 max_rules=self.n_rules,
                 expiry_ns=expiry,
             )
+        # the tier as the mirror can move records into and out of it in
+        # C; None with the Python tier (shm creation failed, or a test
+        # injects one), which takes them record by record
+        from banjax_tpu.native.shm import ShmWarmTier
+
+        self._warm_c = (
+            self._warm if isinstance(self._warm, ShmWarmTier) else None
+        )
         self.warm_spills = 0
         self.warm_refills = 0
         # Cold-tier admission bookkeeping (admission_mask): refused rows
@@ -595,14 +613,25 @@ class DeviceWindows:
         # (shadow updates and restores need the strings); _slots/_free
         # are dict-path-only.
         self._sm = None
+        # the residents' shadow records by slot (native/shmstate.c sh_*):
+        # there whenever the native manager is — the native placement
+        # hands it the victims' keys and spans, so neither goes alone
+        self._mirror = None
         self.slotmgr_native = False
         if native_slotmgr:
-            from banjax_tpu.native import slotmgr as _slotmgr
+            from banjax_tpu.native import shm as _shm, slotmgr as _slotmgr
 
-            self._sm = _slotmgr.create(capacity)
-            self.slotmgr_native = self._sm is not None
+            sm = _slotmgr.create(capacity)
+            mirror = None if sm is None else _shm.create_shadow_mirror(capacity)
+            if mirror is not None:
+                self._sm, self._mirror = sm, mirror
+                self.slotmgr_native = True
         self._pending_evict: List[int] = []
-        self._pending_restore: List[Tuple[int, str]] = []
+        # returning addresses whose counters re-enter the device at the
+        # next maintenance step.  Dict form: (slot, ip).  Native form:
+        # (slots int32 [k], stamps int64 [k]) a placement — the stamp
+        # names the record the restore was queued for
+        self._pending_restore: list = []
         # slots handed out by slots_for_ips stay pinned until the matching
         # apply_bitmap consumes them, so a second caller's allocation can
         # never evict a slot whose events are still in flight
@@ -631,7 +660,18 @@ class DeviceWindows:
         # for introspection (get/format_states/__len__) and the restore
         # source when an evicted IP is re-admitted. Memory is O(distinct
         # (ip, rule) pairs with events) — the reference's own asymptotic.
+        # With the mirror this dict is the home of NON-resident records
+        # that no tier took (warm tier off, a dropped put, a refused
+        # row's state), each with the sequence stamp its record carries
+        # in the mirror (_shadow_stamp), so format_states keeps one order.
         self._shadow: "Dict[str, OrderedDict]" = {}
+        self._shadow_stamp: Dict[str, int] = {}
+        # events absorbed and records spilled / refilled / restored, by
+        # the form that handled them: "dict" stays 0 where the mirror does
+        self.shadow_records: Dict[str, Dict[str, int]] = {
+            op: {"native": 0, "dict": 0}
+            for op in ("absorb", "spill", "refill", "restore")
+        }
         self._state = self._fresh_state()
 
     def _fresh_state(self) -> DeviceWindowState:
@@ -1052,14 +1092,16 @@ class DeviceWindows:
                 # logical doublings — keep the metric comparable with the
                 # dict path's grow-per-miss loop
                 self.grow_count += steps - 1
-        placed_idx, evicted, ok = sm.place_misses(
+        placed_idx, evicted, ev_keys, ok = sm.place_misses(
             res._enc, slots, place_idx, res._seq, self._pin_counts,
             self._last_used,
         )
         if len(evicted):
             ev = evicted.tolist()
             pop = self._slot_ip.pop
-            self._note_evictions_locked(ev, [pop(s, None) for s in ev])
+            self._note_evictions_locked(
+                evicted, [pop(s, None) for s in ev], ev_keys
+            )
             self._pending_evict.extend(ev)
             if self.eviction_count == 0:
                 self._warn_first_eviction()
@@ -1067,7 +1109,8 @@ class DeviceWindows:
         n_placed = len(placed_idx)
         if n_placed:
             ips = res.ips
-            slot_l = slots[placed_idx].tolist()
+            slot_a = slots[placed_idx]
+            slot_l = slot_a.tolist()
             ip_l = list(map(ips.__getitem__, placed_idx.tolist()))
             # C-speed mirror update: at the all-distinct-IP shape this
             # loop IS the residual host cost, so no per-entry Python
@@ -1080,29 +1123,25 @@ class DeviceWindows:
                         self._sketch_slots[slot] = True
             # returning addresses, in placement order (placed_idx is a
             # prefix of place_idx: placement goes in ip order and stops
-            # at a refusal): a shadow resident's counters re-enter the
-            # device in the next maintenance step, BEFORE any of this
-            # batch's events for it are applied; a warm resident's are
-            # taken back into the shadow first, all in one call
-            back_w = in_warm[:n_placed]
-            back = np.flatnonzero(in_shadow[:n_placed] | back_w)
+            # at a refusal): a warm resident's record moves from the
+            # tier into the mirror at its new slot, all in one call, and
+            # one waiting in the dict likewise; their counters re-enter
+            # the device in the next maintenance step, BEFORE any of
+            # this batch's events for them are applied
+            stamps = np.zeros(n_placed, dtype=np.int64)
+            w_pos = np.flatnonzero(in_warm[:n_placed])
+            if len(w_pos):
+                self._refill_locked(
+                    res._enc, placed_idx, slot_a, ip_l, w_pos, stamps
+                )
+            for k in np.flatnonzero(in_shadow[:n_placed]).tolist():
+                vec = self._shadow.pop(ip_l[k], None)
+                stamp = self._shadow_stamp.pop(ip_l[k], 0)
+                if vec:
+                    stamps[k] = self._mirror.install(slot_l[k], vec, stamp)
+            back = np.flatnonzero(stamps)
             if len(back):
-                w_pos = np.flatnonzero(back_w)
-                if len(w_pos):
-                    at = placed_idx[w_pos]
-                    enc = res._enc
-                    w_ips = [ip_l[k] for k in w_pos.tolist()]
-                    for ip, vec in zip(w_ips, self._warm.take_batch(
-                        w_ips, spans=(enc[0], enc[1][at], enc[2][at])
-                    )):
-                        if vec is not None:
-                            self._shadow[ip] = OrderedDict(vec)
-                            self.warm_refills += 1
-                shadow = self._shadow
-                pend_restore = self._pending_restore
-                for k in back.tolist():
-                    if ip_l[k] in shadow:
-                        pend_restore.append((slot_l[k], ip_l[k]))
+                self._pending_restore.append((slot_a[back], stamps[back]))
         sa = res._sketch_admitted
         if sa is not None and len(sa):
             # sketch-admitted tenures are FP-evaluated at eviction; one
@@ -1180,34 +1219,71 @@ class DeviceWindows:
         if self._warm.put(ip, entries, time.time_ns()):
             del self._shadow[ip]
             self.warm_spills += 1
+            self.shadow_records["spill"]["dict"] += 1
 
-    def _note_evictions_locked(
-        self, slots: List[int], ips: List[Optional[str]]
-    ) -> None:
-        """_note_eviction_locked for all of one placement's victims, in
-        eviction order, with their spills in ONE warm-tier call.  A put
-        the tier dropped leaves its entry in the shadow, as there."""
-        shadow = self._shadow
+    def _note_evictions_locked(self, slots: np.ndarray, ips, keys) -> None:
+        """_note_eviction_locked for all of one native placement's
+        victims (slots int64 [k], eviction order; `keys` = the slot
+        manager's evict_keys): the records of those that hold one move
+        from the mirror into the warm tier in ONE C call.  A record the
+        tier did not take — a dropped put, the warm tier off, a tier
+        that is not the C table — moves into the dict, keyed by its
+        address: the state stays on the host, as ever."""
+        status = self._mirror.spill(
+            self._warm_c, slots, keys, time.time_ns()
+        )
         if self._sketch_slots:
             took = self._sketch_slots.pop
-            for slot, ip in zip(slots, ips):
+            for slot, held in zip(slots.tolist(), status.tolist()):
                 if took(slot, False):
                     self.sketch_fp_evaluated += 1
-                    if ip is None or ip not in shadow:
+                    if not held:
                         self.sketch_fp_count += 1
-        if self._warm is None:
+        landed = int(np.count_nonzero(status == 1))
+        self.warm_spills += landed
+        self.shadow_records["spill"]["native"] += landed
+        left = np.flatnonzero(status == 2)
+        if not len(left):
             return
-        held = [(ip, od) for ip, od in zip(ips, map(shadow.get, ips)) if od]
-        if not held:
-            return
-        spill_ips = [ip for ip, _ in held]
-        stored = self._warm.put_batch(
-            spill_ips, [od for _, od in held], time.time_ns()
-        )
-        for ip, landed in zip(spill_ips, stored.tolist()):
-            if landed:
-                del shadow[ip]
+        warm = self._warm if self._warm_c is None else None  # a Python tier
+        stamps, vecs = self._mirror.export(slots[left], drop=True)
+        for k, stamp, vec in zip(left.tolist(), stamps, vecs):
+            ip = ips[k]
+            if ip is None:
+                continue
+            if warm is not None and warm.put(
+                ip, [(r, h, s, ns) for r, (h, s, ns) in vec.items()],
+                time.time_ns(),
+            ):
                 self.warm_spills += 1
+                self.shadow_records["spill"]["dict"] += 1
+                continue
+            self._shadow[ip] = vec
+            self._shadow_stamp[ip] = stamp
+
+    def _refill_locked(self, enc, placed_idx, slot_a, ip_l, w_pos, stamps):
+        """The warm residents among one native placement's addresses
+        (positions w_pos of the placed) take their records back: tier →
+        mirror at the new slot in ONE C call; stamps[w_pos] names the
+        records made (0 where the tier had none after all)."""
+        if self._warm_c is None:  # no C table to move records out of
+            for k in w_pos.tolist():
+                ent = self._warm.take(ip_l[k])
+                if ent is not None:
+                    stamps[k] = self._mirror.install(
+                        int(slot_a[k]),
+                        OrderedDict((r, (h, s, ns)) for r, h, s, ns in ent),
+                    )
+            path = "dict"
+        else:
+            at = placed_idx[w_pos]
+            stamps[w_pos] = self._mirror.refill(
+                self._warm_c, slot_a[w_pos], (enc[0], enc[1][at], enc[2][at])
+            )
+            path = "native"
+        got = int(np.count_nonzero(stamps[w_pos]))
+        self.warm_refills += got
+        self.shadow_records["refill"][path] += got
 
     def _refill_from_warm_locked(self, slot: int, ip: str) -> bool:
         """Move one IP's window vector warm → shadow and queue the device
@@ -1222,13 +1298,15 @@ class DeviceWindows:
         )
         self._pending_restore.append((slot, ip))
         self.warm_refills += 1
+        self.shadow_records["refill"]["dict"] += 1
         return True
 
     def _grow_locked(self, new_capacity: int) -> None:
         """Double the slot table in place (auto-size): pad the flat device
         arrays (zeros; `slot_gen` with its fresh value 1, so every new key
         reads invalid) and free-list the new high slots. Existing slot
-        ids, pending evictions/restores, and the shadow are untouched; the
+        ids, pending evictions/restores, and the shadow (the mirror gets
+        empty rows for the new slots) are untouched; the
         only cost is one recompile of the apply programs at the new state
         shape (geometric growth bounds that to ~log2(max/start) compiles
         over the process lifetime)."""
@@ -1255,6 +1333,7 @@ class DeviceWindows:
         # native manager's free stack replicates the same order)
         if self._sm is not None:
             self._sm.grow(new_capacity)
+            self._mirror.grow(new_capacity)
         else:
             self._free = (
                 list(range(new_capacity - 1, old_cap - 1, -1)) + self._free
@@ -1297,8 +1376,10 @@ class DeviceWindows:
             self._slots.clear()
             self._slot_ip.clear()
             self._shadow.clear()
+            self._shadow_stamp.clear()
             if self._sm is not None:
                 self._sm.clear()
+                self._mirror.clear()
             else:
                 self._free = list(range(self.capacity - 1, -1, -1))
             self._pending_evict = []
@@ -1317,11 +1398,13 @@ class DeviceWindows:
 
     def __len__(self) -> int:
         # parity with RegexRateLimitStates.__len__: IPs with any state —
-        # including evicted ones (the reference never forgets; warm and
-        # shadow populations are disjoint by construction)
+        # including evicted ones (the reference never forgets; the
+        # mirror's, the dict's and the warm tier's populations are
+        # disjoint by construction)
         with self._lock:
             warm = len(self._warm) if self._warm is not None else 0
-            return len(self._shadow) + warm
+            held = len(self._mirror) if self._mirror is not None else 0
+            return held + len(self._shadow) + warm
 
     # ---- tier gauges (obs/stats.py snapshot surface) ----
 
@@ -1474,20 +1557,26 @@ class DeviceWindows:
         """Fold one applied chunk's per-event final counter states into
         the host shadow (caller holds the lock; the arrays hold live
         events only, in (line, rule) order).  That is the reference's
-        processing order, so dict INSERTION order matches the host path's
-        first-matched-event order (format_states parity; slot numbering
-        follows batch appearance, which can differ).  Each (ip, rule)'s
-        last write is still its chronologically-last event, i.e. the
-        segment-final state written on device.  One dict store per event
-        and nothing else: at one event per log line this loop is the
-        drain thread's floor."""
+        processing order, so a record's counters and the records
+        themselves keep the host path's first-matched-event order
+        (format_states parity; slot numbering follows batch appearance,
+        which can differ).  Each (ip, rule)'s last write is still its
+        chronologically-last event, i.e. the segment-final state written
+        on device.  Native form: one C call, the slot is the key.  Dict
+        form: one dict store per event."""
         self.device_events += len(line)
         if not len(line):
             return
         if self.n_site_rules:
             self.site_events += int(np.count_nonzero(rule < self.n_site_rules))
+        if self._mirror is not None:
+            self.shadow_records["absorb"]["native"] += self._mirror.absorb(
+                slot_ids, self._last_used, line, rule, hits, ss, sns
+            )
+            return
         slot_ip = self._slot_ip
         shadow = self._shadow
+        taken = 0
         for slot, rid, h, s, ns in zip(
             np.asarray(slot_ids)[line].tolist(), rule.tolist(),
             hits.tolist(), ss.tolist(), sns.tolist(),
@@ -1499,6 +1588,8 @@ class DeviceWindows:
             if od is None:
                 od = shadow[ip] = OrderedDict()
             od[rid] = (h, s, ns)
+            taken += 1
+        self.shadow_records["absorb"]["dict"] += taken
 
     def _run_maintenance_locked(self) -> None:
         """Drain queued evictions, then restores, into the device state
@@ -1510,27 +1601,10 @@ class DeviceWindows:
         padded entries scatter out of range and drop."""
         if not self._pending_evict and not self._pending_restore:
             return
-        cap_r = self.capacity * self.n_rules
         pend_ev = self._pending_evict
         pend_rs = self._pending_restore
         self._pending_evict = []
         self._pending_restore = []
-
-        restored: List[Tuple[int, int, int, int, int]] = []
-        for slot, ip in pend_rs:
-            if self._slot_ip.get(slot) != ip:
-                # stale restore: the slot was re-evicted (and possibly
-                # reassigned to a DIFFERENT ip) after this restore was
-                # queued — scattering the old ip's counters now would
-                # resurrect them into the new owner's rows
-                continue
-            od = self._shadow.get(ip)
-            if not od:
-                continue
-            base = slot * self.n_rules
-            for rid, (h, s, ns) in od.items():
-                restored.append((slot, base + rid, h, s, ns))
-
         self.maintenance_steps += 1
         if pend_ev:
             ks = _bucket(len(pend_ev), _MIN_MAINT_BUCKET)
@@ -1538,15 +1612,49 @@ class DeviceWindows:
             ev_slots = np.full((ks,), self.capacity, dtype=np.int32)
             ev_slots[: len(pend_ev)] = pend_ev
             self._state = _evict_step(self._state, jnp.asarray(ev_slots))
-        kr = _RESTORE_CHUNK
-        for c in range(0, len(restored), kr):
-            part = restored[c : c + kr]
-            self.maintenance_elems += 5 * kr
-            rows = np.zeros((5, kr), dtype=np.int32)
-            rows[0] = self.capacity
-            rows[1] = cap_r
-            rows[:, : len(part)] = np.asarray(part, dtype=np.int32).T
+        for rows in self._restore_rows_locked(pend_rs):
+            self.maintenance_elems += rows.size
             self._state = _restore_step(self._state, jnp.asarray(rows))
+
+    def _restore_rows_locked(self, pending) -> np.ndarray:
+        """int32 [c, 5, _RESTORE_CHUNK]: the counters of the queued
+        restores that are still live, as `_restore_step` takes them —
+        (slot, flat key, hits, start_s, start_ns) a column, padded out
+        of range.  Read NOW, at the maintenance step, not when the
+        restore was queued: an earlier in-flight chunk's absorb may have
+        landed in between.  A restore is stale once its slot was
+        re-evicted (and possibly reassigned to a DIFFERENT address) —
+        scattering the old address's counters would resurrect them into
+        the new owner's rows: the dict form knows by the slot's owner,
+        the mirror by the record's stamp."""
+        kr = _RESTORE_CHUNK
+        cap_r = self.capacity * self.n_rules
+        if self._mirror is not None:
+            if not pending:
+                return np.zeros((0, 5, kr), dtype=np.int32)
+            rows, records = self._mirror.restore_rows(
+                np.concatenate([p[0] for p in pending]),
+                np.concatenate([p[1] for p in pending]),
+                self.n_rules, kr, self.capacity, cap_r,
+            )
+            self.shadow_records["restore"]["native"] += records
+            return rows
+        restored: List[Tuple[int, int, int, int, int]] = []
+        for slot, ip in pending:
+            od = self._shadow.get(ip) if self._slot_ip.get(slot) == ip else None
+            if not od:
+                continue
+            self.shadow_records["restore"]["dict"] += 1
+            base = slot * self.n_rules
+            for rid, (h, s, ns) in od.items():
+                restored.append((slot, base + rid, h, s, ns))
+        rows = np.zeros((-(-len(restored) // kr), 5, kr), dtype=np.int32)
+        rows[:, 0] = self.capacity
+        rows[:, 1] = cap_r
+        for c in range(len(rows)):
+            part = restored[c * kr : (c + 1) * kr]
+            rows[c, :, : len(part)] = np.asarray(part, dtype=np.int32).T
+        return rows
 
     # ---- refused-row host apply (cold-tier path) ----
 
@@ -1621,10 +1729,15 @@ class DeviceWindows:
                     ]
                     if warm.put(ip, entries, now_ns):
                         self._shadow.pop(ip, None)
+                        self._shadow_stamp.pop(ip, None)
                         self.warm_spills += 1
                         continue
-                # warm off (or the put dropped): the shadow is the home
+                # warm off (or the put dropped): the shadow is the home —
+                # the dict, in either form: a refused address is not
+                # resident, so the mirror has no row for it
                 self._shadow[ip] = od
+                if self._mirror is not None and ip not in self._shadow_stamp:
+                    self._shadow_stamp[ip] = self._mirror.next_stamp()
         n = len(events)
         return EventBatch(
             line=np.fromiter((e[0] for e in events), np.int32, n),
@@ -1639,9 +1752,46 @@ class DeviceWindows:
     # authoritative introspection source: no device pull, and it includes
     # evicted IPs — the reference host dict never forgets, so neither do we.
 
+    def _shadow_rows_locked(self) -> List[Tuple[str, OrderedDict]]:
+        """Every record the host holds outside the warm tier, (ip,
+        rule_id -> (hits, start_s, start_ns)), in the order the dict
+        form's one dict has by insertion: addresses by their record's
+        first event (or its refill), counters by their first event.
+        The mirror keeps no such order per event; its records carry a
+        sequence stamp and are sorted here, at the read."""
+        if self._mirror is None:
+            return list(self._shadow.items())
+        live = self._mirror.live_slots()
+        stamps, vecs = self._mirror.export(live)
+        slot_ip = self._slot_ip
+        held = [
+            (stamp, slot_ip[slot], vec)
+            for stamp, slot, vec in zip(stamps, live.tolist(), vecs)
+            if slot in slot_ip
+        ]
+        held += [
+            (self._shadow_stamp.get(ip, 0), ip, od)
+            for ip, od in self._shadow.items()
+        ]
+        held.sort(key=lambda r: r[0])
+        return [(ip, od) for _, ip, od in held]
+
+    def shadow_items(self) -> "OrderedDict[str, OrderedDict]":
+        """The host shadow as one mapping, whichever form holds it (the
+        warm tier's records are not in it): what tests and debugging
+        read in place of the form's own structures."""
+        with self._lock:
+            return OrderedDict(self._shadow_rows_locked())
+
     def get(self, ip: str) -> Tuple[Dict[str, NumHitsAndIntervalStart], bool]:
         with self._lock:
-            od = self._shadow.get(ip)
+            od = None
+            if self._mirror is not None:  # resident: the slot's record
+                slot = int(self._sm.find_batch([ip])[0])
+                if slot >= 0:
+                    od = self._mirror.export([slot])[1][0]
+            if not od:
+                od = self._shadow.get(ip)
             if not od and self._warm is not None:
                 ent = self._warm.peek(ip)
                 if ent:
@@ -1659,7 +1809,9 @@ class DeviceWindows:
 
     def format_states(self) -> str:
         with self._lock:
-            rows = [(ip, list(od.items())) for ip, od in self._shadow.items()]
+            rows = [
+                (ip, list(od.items())) for ip, od in self._shadow_rows_locked()
+            ]
             if self._warm is not None and len(self._warm):
                 # warm-resident IPs are disjoint from the shadow (spill
                 # deletes the shadow entry), so this is a plain append

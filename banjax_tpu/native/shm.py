@@ -15,7 +15,6 @@ single-process Python path.
 from __future__ import annotations
 
 import ctypes
-import itertools
 import logging
 import os
 import subprocess
@@ -151,16 +150,43 @@ def _load() -> Optional[ctypes.CDLL]:
         lib.wt_contains_batch.argtypes = [
             vp, u8p, i64p, i64p, ctypes.c_int64, u8p,
         ]
-        lib.wt_put_batch.restype = ctypes.c_int64
-        lib.wt_put_batch.argtypes = [
-            vp, u8p, i64p, i64p, ctypes.c_int64, ctypes.c_int64,
-            ctypes.c_int64, i64p, i32p, i32p, i64p, i64p, u8p,
+        i64 = ctypes.c_int64
+        lib.sh_create.restype = vp
+        lib.sh_create.argtypes = [i64]
+        lib.sh_destroy.restype = None
+        lib.sh_destroy.argtypes = [vp]
+        lib.sh_clear.restype = None
+        lib.sh_clear.argtypes = [vp]
+        lib.sh_grow.restype = i64
+        lib.sh_grow.argtypes = [vp, i64]
+        lib.sh_records.restype = i64
+        lib.sh_records.argtypes = [vp]
+        lib.sh_next_stamp.restype = i64
+        lib.sh_next_stamp.argtypes = [vp]
+        lib.sh_absorb.restype = i64
+        lib.sh_absorb.argtypes = [
+            vp, i32p, i64, i64p, i32p, i32p, i32p, i32p, i32p, i64,
         ]
-        lib.wt_take_batch.restype = ctypes.c_int64
-        lib.wt_take_batch.argtypes = [
-            vp, u8p, i64p, i64p, ctypes.c_int64, i32p, i32p, i32p, i64p,
-            i64p,
+        lib.sh_install.restype = i64
+        lib.sh_install.argtypes = [vp, i64, i64, i32p, i32p, i64p, i64p, i64]
+        lib.sh_spill.restype = i64
+        lib.sh_spill.argtypes = [
+            vp, vp, i64p, i64, u8p, i32p, i64, i64, i64, u8p,
         ]
+        lib.sh_refill.restype = i64
+        lib.sh_refill.argtypes = [vp, vp, i32p, u8p, i64p, i64p, i64, i64p]
+        lib.sh_live_slots.restype = i64
+        lib.sh_live_slots.argtypes = [vp, i32p]
+        lib.sh_counts.restype = i64
+        lib.sh_counts.argtypes = [vp, i32p, i64, i32p, i64p]
+        lib.sh_export.restype = None
+        lib.sh_export.argtypes = [
+            vp, i32p, i64, i32p, i32p, i64p, i64p, ctypes.c_int32,
+        ]
+        lib.sh_restore_count.restype = i64
+        lib.sh_restore_count.argtypes = [vp, i32p, i64p, i64]
+        lib.sh_restore_rows.restype = i64
+        lib.sh_restore_rows.argtypes = [vp, i32p, i64p, i64, i64, i64, i32p]
         _LIB = lib
         return _LIB
 
@@ -526,92 +552,6 @@ class ShmWarmTier:
         )
         return out.astype(bool)
 
-    def put_batch(self, ips, vectors, now_ns: int) -> np.ndarray:
-        """`put` for every (ip, vector) pair, in order, in one C call.
-        A vector is the hot tier's shadow value: a mapping rule_id ->
-        (num_hits, start_s, start_ns) in insertion order.  bool [n]:
-        False where the put was dropped (or the vector was empty)."""
-        n = len(ips)
-        stored = np.zeros(n, dtype=np.uint8)
-        base = self._base_ptr
-        if n == 0 or base is None:
-            return stored.astype(bool)
-        mr = self.max_rules
-        counts = np.fromiter(map(len, vectors), dtype=np.int64, count=n)
-        if (counts > mr).any():
-            vectors = [dict(list(v.items())[:mr]) for v in vectors]
-            np.minimum(counts, mr, out=counts)
-        live = np.flatnonzero(counts)
-        if live.size == 0:
-            return stored.astype(bool)
-        if live.size != n:  # `put` refuses an empty vector: so does this
-            ips = [ips[i] for i in live.tolist()]
-            vectors = [vectors[i] for i in live.tolist()]
-            counts = counts[live]
-        chain = itertools.chain.from_iterable
-        total = int(counts.sum())
-        rid = np.fromiter(chain(vectors), dtype=np.int32, count=total)
-        state = np.array(
-            list(chain(v.values() for v in vectors)), dtype=np.int64
-        ).reshape(total, 3)
-        hits = np.ascontiguousarray(state[:, 0], dtype=np.int32)
-        ss = np.ascontiguousarray(state[:, 1])
-        sns = np.ascontiguousarray(state[:, 2])
-        ent_offs = np.zeros(live.size + 1, dtype=np.int64)
-        np.cumsum(counts, out=ent_offs[1:])
-        i32p = ctypes.POINTER(ctypes.c_int32)
-        i64p = ctypes.POINTER(ctypes.c_int64)
-        got = np.zeros(live.size, dtype=np.uint8)
-        _keep, ptrs = self._spans(ips, None)
-        self._lib.wt_put_batch(
-            base, *ptrs, live.size, now_ns, self.expiry_ns,
-            ent_offs.ctypes.data_as(i64p), rid.ctypes.data_as(i32p),
-            hits.ctypes.data_as(i32p), ss.ctypes.data_as(i64p),
-            sns.ctypes.data_as(i64p),
-            got.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
-        )
-        stored[live] = got
-        return stored.astype(bool)
-
-    def take_batch(self, ips, spans=None) -> List[Optional[dict]]:
-        """`take` for every ip, in order, in one C call: each key's
-        vector as put_batch takes them (rule_id -> (num_hits, start_s,
-        start_ns), insertion order; the record is deleted), None where
-        the key is absent."""
-        n = len(ips)
-        base = self._base_ptr
-        if n == 0 or base is None:
-            return [None] * n
-        # room for n full records; the C side writes the entries one
-        # record after the other, so only what the records hold is touched
-        room = n * self.max_rules
-        n_out = np.empty(n, dtype=np.int32)
-        rid = np.empty(room, dtype=np.int32)
-        hits = np.empty(room, dtype=np.int32)
-        ss = np.empty(room, dtype=np.int64)
-        sns = np.empty(room, dtype=np.int64)
-        i32p = ctypes.POINTER(ctypes.c_int32)
-        i64p = ctypes.POINTER(ctypes.c_int64)
-        _keep, ptrs = self._spans(ips, spans)
-        self._lib.wt_take_batch(
-            base, *ptrs, n, n_out.ctypes.data_as(i32p),
-            rid.ctypes.data_as(i32p), hits.ctypes.data_as(i32p),
-            ss.ctypes.data_as(i64p), sns.ctypes.data_as(i64p),
-        )
-        # the records' entries in record order, cut back into one
-        # mapping a record: no per-record array work
-        ends = np.cumsum(np.maximum(n_out, 0)).tolist()
-        total = ends[-1]
-        rids = rid[:total].tolist()
-        states = list(zip(
-            hits[:total].tolist(), ss[:total].tolist(), sns[:total].tolist()
-        ))
-        out: List[Optional[dict]] = [None] * n
-        for i in np.flatnonzero(n_out >= 0).tolist():
-            a, b = ends[i] - int(n_out[i]), ends[i]
-            out[i] = dict(zip(rids[a:b], states[a:b]))
-        return out
-
     def __contains__(self, ip: str) -> bool:
         return bool(self.contains_batch([ip])[0])
 
@@ -742,21 +682,6 @@ class PyWarmTier:
         d = self._d
         return np.fromiter((ip in d for ip in ips), bool, count=len(ips))
 
-    def put_batch(self, ips, vectors, now_ns: int) -> np.ndarray:
-        return np.fromiter(
-            (self.put(
-                ip, [(rid, h, s, ns) for rid, (h, s, ns) in v.items()],
-                now_ns,
-            ) for ip, v in zip(ips, vectors)),
-            bool, count=len(ips),
-        )
-
-    def take_batch(self, ips, spans=None) -> List[Optional[dict]]:
-        return [
-            None if ent is None else {e[0]: e[1:] for e in ent}
-            for ent in map(self.take, ips)
-        ]
-
     def __contains__(self, ip: str) -> bool:
         return ip in self._d
 
@@ -798,3 +723,196 @@ def create_warm_tier(
     return PyWarmTier(
         capacity=capacity, max_rules=max_rules, expiry_ns=expiry_ns
     )
+
+
+# ---------------------------------------------------------------------------
+# The host shadow's native form (sh_* in shmstate.c): the window counters
+# of RESIDENT addresses, a record per device slot in the warm tier's
+# block layout.  DeviceWindows moves records through it as arrays — one C
+# call a batch for the absorb of a chunk's events, the spill of a
+# placement's victims into the warm tier, the refill of its returning
+# addresses out of it, and the rows of the device restore — and makes no
+# Python object per event, per record or per counter.  The dict shadow
+# (matcher/windows.py, `_sm is None`) is the same logic in Python and the
+# oracle the parity tests compare this with.
+
+_PTR = {
+    np.dtype(t): ctypes.POINTER(c)
+    for t, c in ((np.int32, ctypes.c_int32), (np.int64, ctypes.c_int64),
+                 (np.uint8, ctypes.c_uint8))
+}
+
+
+def _p(a: np.ndarray):
+    """The C pointer of a contiguous array, typed by its dtype (an array
+    of another dtype than the call declares is refused by ctypes)."""
+    if not a.flags.c_contiguous:
+        raise ValueError("a native call needs a contiguous array")
+    return a.ctypes.data_as(_PTR[a.dtype])
+
+
+def _i32(a) -> np.ndarray:
+    return np.ascontiguousarray(a, dtype=np.int32)
+
+
+class ShadowMirror:
+    """Slot-indexed mirror of the device window counters.  Externally
+    locked by DeviceWindows, the slotmgr convention."""
+
+    def __init__(self, lib: ctypes.CDLL, handle: int):
+        self._lib = lib
+        self._h = handle
+
+    def close(self) -> None:
+        if self._h:
+            self._lib.sh_destroy(self._h)
+            self._h = None
+
+    def __del__(self):  # noqa: D105
+        try:
+            self.close()
+        except Exception:  # noqa: BLE001 — interpreter teardown
+            pass
+
+    def clear(self) -> None:
+        self._lib.sh_clear(self._h)
+
+    def grow(self, new_capacity: int) -> None:
+        if self._lib.sh_grow(self._h, new_capacity) != 0:
+            raise MemoryError("shadow mirror grow failed")
+
+    def __len__(self) -> int:
+        """Slots that hold a record."""
+        return int(self._lib.sh_records(self._h))
+
+    def next_stamp(self) -> int:
+        """A sequence stamp for a record the caller makes in its dict."""
+        return int(self._lib.sh_next_stamp(self._h))
+
+    def absorb(self, slot_of_line, last_used, line, rule, hits, ss, sns):
+        """Upsert a chunk's live events, given in (line, rule) order, into
+        their slots' records (sh_absorb); returns the events taken in."""
+        slot_of_line = _i32(slot_of_line)
+        got = self._lib.sh_absorb(
+            self._h, _p(slot_of_line), len(slot_of_line), _p(last_used),
+            _p(_i32(line)), _p(_i32(rule)), _p(_i32(hits)), _p(_i32(ss)),
+            _p(_i32(sns)), len(line),
+        )
+        if got < 0:
+            raise MemoryError("shadow mirror out of memory")
+        return int(got)
+
+    def spill(self, warm: "Optional[ShmWarmTier]", slots, keys, now_ns):
+        """Move the victims' records (slots int64 [n], eviction order)
+        into `warm` under their keys (`keys` = slotmgr's evict_keys: the
+        key bytes, one stride a victim, and their lengths).  uint8 [n]:
+        0 = the slot held no record, 1 = the put landed, 2 = the record
+        is still here and wants a home (the put was dropped, or there is
+        no C tier: `warm` None)."""
+        from banjax_tpu.native.slotmgr import EVICT_KEY_STRIDE
+
+        status = np.empty(len(slots), dtype=np.uint8)
+        if len(slots):
+            self._lib.sh_spill(
+                self._h, None if warm is None else warm._base_ptr,
+                _p(slots), len(slots), _p(keys[0]), _p(keys[1]),
+                EVICT_KEY_STRIDE, now_ns,
+                0 if warm is None else warm.expiry_ns, _p(status),
+            )
+        return status
+
+    def refill(self, warm: "ShmWarmTier", slots, spans) -> np.ndarray:
+        """Move the records of the keys `spans` (of an `encode_ips`) out
+        of `warm` into `slots` (int32 [n], placement order).  int64 [n]:
+        each new record's stamp, 0 where the tier had none."""
+        slots = _i32(slots)
+        stamps = np.zeros(len(slots), dtype=np.int64)
+        if len(slots) and warm._base_ptr is not None:
+            _keep, ptrs = warm._spans((), spans)
+            self._lib.sh_refill(
+                self._h, warm._base_ptr, _p(slots), *ptrs, len(slots),
+                _p(stamps),
+            )
+        return stamps
+
+    def install(self, slot: int, vector, stamp: int = 0) -> int:
+        """Make `slot`'s record from a dict-shadow vector (rule_id ->
+        (num_hits, start_s, start_ns), insertion order), under `stamp`
+        or a new one.  Returns the stamp; 0 for an empty vector."""
+        n = len(vector)
+        rid = np.fromiter(vector, dtype=np.int32, count=n)
+        state = np.array(list(vector.values()), dtype=np.int64).reshape(n, 3)
+        got = self._lib.sh_install(
+            self._h, slot, stamp, _p(rid), _p(_i32(state[:, 0])),
+            _p(np.ascontiguousarray(state[:, 1])),
+            _p(np.ascontiguousarray(state[:, 2])), n,
+        )
+        if got < 0:
+            raise MemoryError(f"shadow mirror: no record for slot {slot}")
+        return int(got)
+
+    def live_slots(self) -> np.ndarray:
+        """int32: every slot that holds a record, ascending."""
+        out = np.empty(len(self), dtype=np.int32)
+        self._lib.sh_live_slots(self._h, _p(out))
+        return out
+
+    def export(self, slots, drop: bool = False):
+        """(stamps, vectors) of the slots' records as the dict shadow
+        holds them (an OrderedDict rule_id -> (num_hits, start_s,
+        start_ns); None and stamp 0 where a slot has no record).  With
+        `drop` the records leave the mirror: the caller is their home.
+        Introspection and the paths with no C tier to go to; never a
+        cell's traffic."""
+        from collections import OrderedDict
+
+        slots = _i32(slots)
+        n = len(slots)
+        counts = np.empty(n, dtype=np.int32)
+        stamps = np.empty(n, dtype=np.int64)
+        total = self._lib.sh_counts(
+            self._h, _p(slots), n, _p(counts), _p(stamps)
+        )
+        rid = np.empty(total, dtype=np.int32)
+        hits = np.empty(total, dtype=np.int32)
+        ss = np.empty(total, dtype=np.int64)
+        sns = np.empty(total, dtype=np.int64)
+        self._lib.sh_export(
+            self._h, _p(slots), n, _p(rid), _p(hits), _p(ss), _p(sns),
+            int(drop),
+        )
+        rids = rid.tolist()
+        states = list(zip(hits.tolist(), ss.tolist(), sns.tolist()))
+        vectors, a = [], 0
+        for b in np.cumsum(counts).tolist():
+            vectors.append(
+                OrderedDict(zip(rids[a:b], states[a:b])) if b > a else None
+            )
+            a = b
+        return stamps.tolist(), vectors
+
+    def restore_rows(self, slots, stamps, n_rules, chunk, pad_slot, pad_key):
+        """(rows int32 [c, 5, chunk], records): `_restore_step`'s operand
+        for the queued restores (slots, stamps) still live — the slot
+        holds the record the restore was queued for — with today's
+        values, in chunks padded with (pad_slot, pad_key, 0, 0, 0)."""
+        slots = _i32(slots)
+        stamps = np.ascontiguousarray(stamps, dtype=np.int64)
+        args = (self._h, _p(slots), _p(stamps), len(slots))
+        total = self._lib.sh_restore_count(*args)
+        rows = np.zeros((-(-total // chunk), 5, chunk), dtype=np.int32)
+        rows[:, 0] = pad_slot
+        rows[:, 1] = pad_key
+        records = self._lib.sh_restore_rows(
+            *args, n_rules, chunk, _p(rows)
+        ) if total else 0
+        return rows, int(records)
+
+
+def create_shadow_mirror(capacity: int) -> Optional[ShadowMirror]:
+    """A ShadowMirror, or None when the native library is unavailable."""
+    lib = _load()
+    if lib is None:
+        return None
+    h = lib.sh_create(capacity)
+    return ShadowMirror(lib, h) if h else None

@@ -35,6 +35,7 @@
 #include <errno.h>
 #include <signal.h>
 #include <stdint.h>
+#include <stdlib.h>
 #include <string.h>
 #include <time.h>
 #include <unistd.h>
@@ -761,51 +762,440 @@ int64_t wt_contains_batch(void *base, const uint8_t *blob,
     return found;
 }
 
-/* One call a batch for the spills of a placement: wt_put for each of n
- * records, in order.  Record i's key is blob[offs[i] : offs[i] + lens[i]]
- * and its entries are [ent_offs[i], ent_offs[i + 1]) of the four entry
- * arrays.  stored_out[i] = 1 where the put landed, 0 where it was
- * dropped (the caller keeps a dropped record's state where it was).
- * Returns the number stored. */
-int64_t wt_put_batch(void *base, const uint8_t *blob, const int64_t *offs,
-                     const int64_t *lens, int64_t n, int64_t now_ns,
-                     int64_t expiry_ns, const int64_t *ent_offs,
-                     const int32_t *rule_ids, const int32_t *hits,
-                     const int64_t *ss, const int64_t *sns,
-                     uint8_t *stored_out) {
-    int64_t stored = 0;
-    for (int64_t i = 0; i < n; i++) {
-        int64_t e0 = ent_offs[i];
-        int64_t rc = wt_put(base, (const char *)blob + offs[i],
-                            (int32_t)lens[i], now_ns, expiry_ns,
-                            rule_ids + e0, hits + e0, ss + e0, sns + e0,
-                            ent_offs[i + 1] - e0);
-        stored_out[i] = rc == 0;
-        stored += rc == 0;
-    }
-    return stored;
+/* ------------------------------------------------------------------ *
+ * The host shadow's native form: a slot-indexed mirror of the device
+ * window counters of RESIDENT addresses (matcher/windows.py).
+ *
+ * The device table holds a counter per (slot, rule); the host keeps what
+ * every event wrote there, so that an eviction loses nothing.  For a
+ * resident address the slot IS the key: a record per slot, a chain of
+ * the warm tier's 256-byte blocks of ten 24-byte entries each (no dense
+ * [slots, rules] array: at 10,000 rules that would be the device table
+ * again), entries in first-event order.  Four calls move records as
+ * arrays, each one C call a batch:
+ *
+ *   sh_absorb        a chunk's event-final states, upserted by slot
+ *   sh_spill         victims' records -> the warm tier, under their keys
+ *   sh_refill        returning addresses' records <- the warm tier
+ *   sh_restore_rows  the pending slots' counters as _restore_step's rows
+ *
+ * A record carries a sequence stamp, drawn when it is made (a slot's
+ * first event, a refill) and kept when it moves to the caller's dict
+ * and back (sh_export / sh_install): format_states sorts by it at the
+ * read, and a queued restore names its record by it, so one that went
+ * stale (the slot evicted, perhaps re-assigned, since) restores nothing.
+ *
+ * Process-local memory (malloc), externally locked by DeviceWindows
+ * like everything else here.  Block indices, never pointers, are kept
+ * across calls: the arena doubles by realloc.
+ */
+
+typedef struct {
+    int64_t capacity; /* slots */
+    int64_t *head;    /* [capacity] first block + 1; 0 = no record */
+    int32_t *count;   /* [capacity] entries of the record */
+    int64_t *stamp;   /* [capacity] the record's sequence stamp */
+    int64_t records;
+    int64_t next_stamp;
+    wt_cont *blocks;
+    int64_t n_blocks;
+    int64_t bump;
+    int64_t free_head; /* block + 1; 0 = none */
+    /* a record in the four-array form wt_put / wt_take speak */
+    int32_t *s_rid;
+    int32_t *s_hits;
+    int64_t *s_ss;
+    int64_t *s_sns;
+    int64_t s_room;
+} sh_t;
+
+void sh_destroy(void *h) {
+    sh_t *m = h;
+    if (!m)
+        return;
+    free(m->head);
+    free(m->count);
+    free(m->stamp);
+    free(m->blocks);
+    free(m->s_rid);
+    free(m->s_hits);
+    free(m->s_ss);
+    free(m->s_sns);
+    free(m);
 }
 
-/* One call a batch for the refills of a placement: wt_take for each of n
- * keys, in order.  The records' entries land one record after the other
- * in the four output arrays (which hold n * max_rules entries, the most
- * n records can have); n_out[i] is record i's count, -1 where the key is
- * absent.  Returns the number of records taken. */
-int64_t wt_take_batch(void *base, const uint8_t *blob, const int64_t *offs,
-                      const int64_t *lens, int64_t n, int32_t *n_out,
-                      int32_t *rule_ids_out, int32_t *hits_out,
-                      int64_t *ss_out, int64_t *sns_out) {
-    int64_t at = 0;
-    int64_t taken = 0;
-    for (int64_t i = 0; i < n; i++) {
-        int64_t got = wt_take(base, (const char *)blob + offs[i],
-                              (int32_t)lens[i], rule_ids_out + at,
-                              hits_out + at, ss_out + at, sns_out + at);
-        n_out[i] = (int32_t)got;
-        if (got >= 0) {
-            at += got;
-            taken++;
+void *sh_create(int64_t capacity) {
+    if (capacity < 1)
+        return NULL;
+    sh_t *m = calloc(1, sizeof(sh_t));
+    if (!m)
+        return NULL;
+    m->capacity = capacity;
+    m->n_blocks = capacity < 1024 ? 1024 : capacity;
+    m->head = calloc((size_t)capacity, sizeof(int64_t));
+    m->count = calloc((size_t)capacity, sizeof(int32_t));
+    m->stamp = calloc((size_t)capacity, sizeof(int64_t));
+    m->blocks = malloc(sizeof(wt_cont) * (size_t)m->n_blocks);
+    if (!m->head || !m->count || !m->stamp || !m->blocks) {
+        sh_destroy(m);
+        return NULL;
+    }
+    return m;
+}
+
+void sh_clear(void *h) {
+    sh_t *m = h;
+    memset(m->head, 0, sizeof(int64_t) * (size_t)m->capacity);
+    memset(m->count, 0, sizeof(int32_t) * (size_t)m->capacity);
+    memset(m->stamp, 0, sizeof(int64_t) * (size_t)m->capacity);
+    m->records = 0;
+    m->next_stamp = 0;
+    m->bump = 0;
+    m->free_head = 0;
+}
+
+/* 0, or -1 on allocation failure (the mirror stays as it was) */
+int64_t sh_grow(void *h, int64_t new_capacity) {
+    sh_t *m = h;
+    int64_t add = new_capacity - m->capacity;
+    if (add <= 0)
+        return 0;
+    int64_t *hd = realloc(m->head, sizeof(int64_t) * (size_t)new_capacity);
+    if (!hd)
+        return -1;
+    m->head = hd;
+    int32_t *ct = realloc(m->count, sizeof(int32_t) * (size_t)new_capacity);
+    if (!ct)
+        return -1;
+    m->count = ct;
+    int64_t *st = realloc(m->stamp, sizeof(int64_t) * (size_t)new_capacity);
+    if (!st)
+        return -1;
+    m->stamp = st;
+    memset(m->head + m->capacity, 0, sizeof(int64_t) * (size_t)add);
+    memset(m->count + m->capacity, 0, sizeof(int32_t) * (size_t)add);
+    memset(m->stamp + m->capacity, 0, sizeof(int64_t) * (size_t)add);
+    m->capacity = new_capacity;
+    return 0;
+}
+
+int64_t sh_records(void *h) { return ((sh_t *)h)->records; }
+
+/* a stamp for a record the caller makes in its own dict */
+int64_t sh_next_stamp(void *h) { return ++((sh_t *)h)->next_stamp; }
+
+static int64_t sh_alloc(sh_t *m) {
+    if (m->free_head) {
+        int64_t b = m->free_head - 1;
+        m->free_head = m->blocks[b].next;
+        return b;
+    }
+    if (m->bump == m->n_blocks) {
+        wt_cont *nb =
+            realloc(m->blocks, sizeof(wt_cont) * (size_t)m->n_blocks * 2);
+        if (!nb)
+            return -1;
+        m->blocks = nb;
+        m->n_blocks *= 2;
+    }
+    return m->bump++;
+}
+
+static void sh_drop(sh_t *m, int64_t slot) {
+    int64_t link = m->head[slot];
+    if (!link)
+        return;
+    while (link) {
+        wt_cont *c = &m->blocks[link - 1];
+        int64_t next = c->next;
+        c->next = m->free_head;
+        m->free_head = link;
+        link = next;
+    }
+    m->head[slot] = 0;
+    m->count[slot] = 0;
+    m->stamp[slot] = 0;
+    m->records--;
+}
+
+/* Set rule's counter in slot's record; one new to the record goes to
+ * its end, and the first of a slot makes the record (the caller stamps
+ * it).  0, or -1 when no block could be had. */
+static int sh_upsert(sh_t *m, int64_t slot, int32_t rule, int32_t hits,
+                     int64_t ss, int64_t sns) {
+    int64_t n = m->count[slot];
+    int64_t left = n;
+    int64_t last = -1;
+    wt_entry *e = NULL;
+    for (int64_t link = m->head[slot]; link && !e;) {
+        wt_cont *c = &m->blocks[link - 1];
+        int64_t here = left < WT_CONT_ENTRIES ? left : WT_CONT_ENTRIES;
+        for (int64_t k = 0; k < here; k++)
+            if (c->e[k].rule_id == rule) {
+                e = &c->e[k];
+                break;
+            }
+        left -= here;
+        last = link - 1;
+        link = c->next;
+    }
+    if (!e) {
+        int64_t pos = n % WT_CONT_ENTRIES;
+        if (pos == 0) { /* no record yet, or its last block is full */
+            int64_t b = sh_alloc(m);
+            if (b < 0)
+                return -1;
+            m->blocks[b].next = 0;
+            if (last < 0) {
+                m->head[slot] = b + 1;
+                m->records++;
+            } else {
+                m->blocks[last].next = b + 1;
+            }
+            last = b;
         }
+        e = &m->blocks[last].e[pos];
+        e->rule_id = rule;
+        m->count[slot] = (int32_t)(n + 1);
+    }
+    e->hits = hits;
+    e->start_s = ss;
+    e->start_ns = sns;
+    return 0;
+}
+
+/* Fold one applied chunk's per-event final counter states in: event k
+ * belongs to slot_of_line[line[k]], and the events come in the
+ * reference's processing order ((line, rule) ascending), so a (slot,
+ * rule)'s last write is its segment-final state and new counters join
+ * their record in first-event order.  last_used (the slot table's
+ * recency array; 0 = the slot never had an owner since the last clear)
+ * drops an event whose slot has none — unreachable while the chunk is
+ * pinned.  Returns the events taken in, -1 when no block could be had. */
+int64_t sh_absorb(void *h, const int32_t *slot_of_line, int64_t n_lines,
+                  const int64_t *last_used, const int32_t *line,
+                  const int32_t *rule, const int32_t *hits,
+                  const int32_t *ss, const int32_t *sns, int64_t n) {
+    sh_t *m = h;
+    int64_t taken = 0;
+    for (int64_t k = 0; k < n; k++) {
+        if (line[k] < 0 || line[k] >= n_lines)
+            continue;
+        int64_t slot = slot_of_line[line[k]];
+        if (slot < 0 || slot >= m->capacity || last_used[slot] == 0)
+            continue;
+        if (sh_upsert(m, slot, rule[k], hits[k], ss[k], sns[k]) != 0)
+            return -1;
+        if (!m->stamp[slot]) /* the slot's first event made the record */
+            m->stamp[slot] = ++m->next_stamp;
+        taken++;
     }
     return taken;
+}
+
+static int sh_scratch(sh_t *m, int64_t n) {
+    if (n <= m->s_room)
+        return 0;
+    int64_t room = m->s_room ? m->s_room : 16;
+    while (room < n)
+        room *= 2;
+    int32_t *a = realloc(m->s_rid, sizeof(int32_t) * (size_t)room);
+    if (a)
+        m->s_rid = a;
+    int32_t *b = realloc(m->s_hits, sizeof(int32_t) * (size_t)room);
+    if (b)
+        m->s_hits = b;
+    int64_t *c = realloc(m->s_ss, sizeof(int64_t) * (size_t)room);
+    if (c)
+        m->s_ss = c;
+    int64_t *d = realloc(m->s_sns, sizeof(int64_t) * (size_t)room);
+    if (d)
+        m->s_sns = d;
+    if (!a || !b || !c || !d)
+        return -1;
+    m->s_room = room;
+    return 0;
+}
+
+/* slot's entries, in record order, into four arrays; returns their count */
+static int64_t sh_copy_out(sh_t *m, int64_t slot, int32_t *rid,
+                           int32_t *hits, int64_t *ss, int64_t *sns) {
+    int64_t n = m->count[slot];
+    int64_t link = m->head[slot];
+    for (int64_t k = 0; k < n; link = m->blocks[link - 1].next) {
+        wt_cont *c = &m->blocks[link - 1];
+        for (int64_t j = 0; j < WT_CONT_ENTRIES && k < n; j++, k++) {
+            rid[k] = c->e[j].rule_id;
+            hits[k] = c->e[j].hits;
+            ss[k] = c->e[j].start_s;
+            sns[k] = c->e[j].start_ns;
+        }
+    }
+    return n;
+}
+
+/* Make slot's record from n entries (whatever it held goes) under
+ * `stamp`, 0 = draw a new one.  Returns the stamp, 0 for an empty
+ * record (none is made), -1 when no block could be had. */
+int64_t sh_install(void *h, int64_t slot, int64_t stamp, const int32_t *rid,
+                   const int32_t *hits, const int64_t *ss,
+                   const int64_t *sns, int64_t n) {
+    sh_t *m = h;
+    if (slot < 0 || slot >= m->capacity)
+        return -1;
+    sh_drop(m, slot);
+    for (int64_t k = 0; k < n; k++)
+        if (sh_upsert(m, slot, rid[k], hits[k], ss[k], sns[k]) != 0) {
+            sh_drop(m, slot);
+            return -1;
+        }
+    if (!n)
+        return 0;
+    m->stamp[slot] = stamp ? stamp : ++m->next_stamp;
+    return m->stamp[slot];
+}
+
+/* The victims of one placement, in eviction order: each record goes to
+ * the warm tier under its key (keys + k * stride, key_lens[k]) and out
+ * of the mirror.  status_out[k]: 0 = the slot held no record, 1 = the
+ * put landed, 2 = it was dropped (or wt_base is NULL: no tier of this
+ * kind) and the record is still here — the caller gives it a home
+ * (sh_export with drop).  Returns the number landed. */
+int64_t sh_spill(void *h, void *wt_base, const int64_t *slots, int64_t n,
+                 const uint8_t *keys, const int32_t *key_lens,
+                 int64_t stride, int64_t now_ns, int64_t expiry_ns,
+                 uint8_t *status_out) {
+    sh_t *m = h;
+    int64_t landed = 0;
+    for (int64_t k = 0; k < n; k++) {
+        int64_t slot = slots[k];
+        int64_t cnt = m->count[slot];
+        if (!cnt) {
+            status_out[k] = 0;
+            continue;
+        }
+        status_out[k] = 2;
+        if (!wt_base || sh_scratch(m, cnt) != 0)
+            continue;
+        sh_copy_out(m, slot, m->s_rid, m->s_hits, m->s_ss, m->s_sns);
+        if (wt_put(wt_base, (const char *)keys + k * stride, key_lens[k],
+                   now_ns, expiry_ns, m->s_rid, m->s_hits, m->s_ss, m->s_sns,
+                   cnt) == 0) {
+            sh_drop(m, slot);
+            status_out[k] = 1;
+            landed++;
+        }
+    }
+    return landed;
+}
+
+/* The returning addresses of one placement, in placement order: key
+ * k's record (blob[offs[k] : offs[k] + lens[k]]) is taken out of the
+ * warm tier and made slots[k]'s, under a new stamp.  stamp_out[k] = that
+ * stamp, 0 where the tier had no record of the key.  Returns the number
+ * found. */
+int64_t sh_refill(void *h, void *wt_base, const int32_t *slots,
+                  const uint8_t *blob, const int64_t *offs,
+                  const int64_t *lens, int64_t n, int64_t *stamp_out) {
+    sh_t *m = h;
+    int64_t found = 0;
+    if (sh_scratch(m, ((wt_header *)wt_base)->max_rules) != 0)
+        n = 0;
+    for (int64_t k = 0; k < n; k++) {
+        int64_t got =
+            wt_take(wt_base, (const char *)blob + offs[k], (int32_t)lens[k],
+                    m->s_rid, m->s_hits, m->s_ss, m->s_sns);
+        int64_t stamp = got < 0 ? 0
+                                : sh_install(m, slots[k], 0, m->s_rid,
+                                             m->s_hits, m->s_ss, m->s_sns,
+                                             got);
+        stamp_out[k] = stamp > 0 ? stamp : 0;
+        found += got >= 0;
+    }
+    return found;
+}
+
+/* Every slot that holds a record, ascending; out holds `capacity` */
+int64_t sh_live_slots(void *h, int32_t *out) {
+    sh_t *m = h;
+    int64_t n = 0;
+    for (int64_t s = 0; s < m->capacity; s++)
+        if (m->count[s])
+            out[n++] = (int32_t)s;
+    return n;
+}
+
+/* counts_out[k], stamps_out[k] of slots[k]'s record (0, 0 = none);
+ * returns the entries of all of them together */
+int64_t sh_counts(void *h, const int32_t *slots, int64_t n,
+                  int32_t *counts_out, int64_t *stamps_out) {
+    sh_t *m = h;
+    int64_t total = 0;
+    for (int64_t k = 0; k < n; k++) {
+        counts_out[k] = m->count[slots[k]];
+        stamps_out[k] = m->stamp[slots[k]];
+        total += counts_out[k];
+    }
+    return total;
+}
+
+/* The records of slots[0..n), one after the other, into four arrays of
+ * sh_counts' total; with `drop` they leave the mirror (the caller is
+ * their home now). */
+void sh_export(void *h, const int32_t *slots, int64_t n, int32_t *rid,
+               int32_t *hits, int64_t *ss, int64_t *sns, int32_t drop) {
+    sh_t *m = h;
+    int64_t at = 0;
+    for (int64_t k = 0; k < n; k++) {
+        at += sh_copy_out(m, slots[k], rid + at, hits + at, ss + at, sns + at);
+        if (drop)
+            sh_drop(m, slots[k]);
+    }
+}
+
+/* A queued restore (slots[k], stamps[k]) is live while the slot still
+ * holds the record it was queued for.  The counters of the live ones,
+ * all together: what sh_restore_rows will write. */
+int64_t sh_restore_count(void *h, const int32_t *slots,
+                         const int64_t *stamps, int64_t n) {
+    sh_t *m = h;
+    int64_t total = 0;
+    for (int64_t k = 0; k < n; k++)
+        if (m->count[slots[k]] && m->stamp[slots[k]] == stamps[k])
+            total += m->count[slots[k]];
+    return total;
+}
+
+/* _restore_step's operand: counter i of the live queued restores, in
+ * queue and record order, is column i % chunk of the [5, chunk] block
+ * i / chunk of out — (slot, slot * n_rules + rule, hits, start_s,
+ * start_ns), the values of NOW: an absorb that landed after the refill
+ * is in them.  The caller has filled out with its pads.  Returns the
+ * records restored. */
+int64_t sh_restore_rows(void *h, const int32_t *slots, const int64_t *stamps,
+                        int64_t n, int64_t n_rules, int64_t chunk,
+                        int32_t *out) {
+    sh_t *m = h;
+    int64_t i = 0;
+    int64_t records = 0;
+    for (int64_t k = 0; k < n; k++) {
+        int64_t slot = slots[k];
+        int64_t cnt = m->count[slot];
+        if (!cnt || m->stamp[slot] != stamps[k])
+            continue;
+        records++;
+        int64_t link = m->head[slot];
+        for (int64_t j = 0; j < cnt; link = m->blocks[link - 1].next) {
+            wt_cont *c = &m->blocks[link - 1];
+            for (int64_t q = 0; q < WT_CONT_ENTRIES && j < cnt; q++, j++, i++) {
+                int32_t *col = out + (i / chunk) * 5 * chunk + i % chunk;
+                col[0] = (int32_t)slot;
+                col[chunk] = (int32_t)(slot * n_rules + c->e[q].rule_id);
+                col[2 * chunk] = c->e[q].hits;
+                col[3 * chunk] = (int32_t)c->e[q].start_s;
+                col[4 * chunk] = (int32_t)c->e[q].start_ns;
+            }
+        }
+    }
+    return records;
 }

@@ -43,6 +43,8 @@
 #include <stdlib.h>
 #include <string.h>
 
+#define SM_EVICT_KEY_STRIDE 104 /* shmstate.c WT_KEY_MAX */
+
 typedef struct {
     int64_t capacity;
     int64_t assigned;
@@ -259,36 +261,34 @@ int64_t sm_lookup_batch(void *h, const uint8_t *blob, const int64_t *offs,
     return n_miss;
 }
 
-/* Read-only membership probe over a distinct-ip blob: like pass 1 but
- * WITHOUT the recency stamp — the admission gate must not refresh an
- * IP's LRU position just for asking whether it is resident (a refused
- * batch would otherwise keep every probe victim warm).  Writes 0/1 per
- * ip; returns the number present. */
-int64_t sm_contains_batch(void *h, const uint8_t *blob, const int64_t *offs,
-                          const int64_t *lens, int64_t n, uint8_t *out) {
+/* Read-only probe over a distinct-ip blob: the slot of every ip, -1
+ * where it has none.  Like pass 1 but WITHOUT the recency stamp — the
+ * admission gate must not refresh an IP's LRU position just for asking
+ * whether it is resident (a refused batch would otherwise keep every
+ * probe victim warm), and introspection (DeviceWindows.get reads a
+ * resident address's record by its slot) must not either. */
+void sm_find_batch(void *h, const uint8_t *blob, const int64_t *offs,
+                   const int64_t *lens, int64_t n, int32_t *slots_out) {
     sm_t *sm = h;
     uint64_t mask = (uint64_t)sm->table_cap - 1;
-    int64_t found = 0;
     for (int64_t i = 0; i < n; i++) {
         const uint8_t *p = blob + offs[i];
         int64_t len = lens[i];
         uint64_t s = sm_hash(p, len) & mask;
-        uint8_t hit = 0;
+        int32_t slot = -1;
         for (;;) {
             int64_t v = sm->table[s];
             if (v == -1)
                 break;
             if (v >= 0 && sm->ip_len[v] == (int32_t)len &&
                 memcmp(sm->ip[v], p, (size_t)len) == 0) {
-                hit = 1;
+                slot = (int32_t)v;
                 break;
             }
             s = (s + 1) & mask;
         }
-        out[i] = hit;
-        found += hit;
+        slots_out[i] = slot;
     }
-    return found;
 }
 
 typedef struct {
@@ -358,7 +358,12 @@ static int64_t cand_extend(sm_cand *c, int64_t n, int64_t sorted,
 
 /* Pass 2: place every miss, in ip order.  Free slots pop first; at
  * capacity the minimum-(last_used, slot) assigned, unpinned, untouched
- * slot is evicted (evict_out records them in order).  out_counts[0] =
+ * slot is evicted (evict_out records them in order, and where
+ * evict_keys is given, victim k's address bytes go to evict_keys[k *
+ * SM_EVICT_KEY_STRIDE ...], cut at the stride — the warm tier's key
+ * length — with their count in evict_key_lens[k]; the empty address is
+ * one NUL byte, the tier's key for it: the spill of a victim's window
+ * record needs the key and nothing else of the string).  out_counts[0] =
  * evictions performed, out_counts[1] = misses successfully placed.
  * Returns 0, or -1 when an eviction was needed and every candidate is
  * pinned/touched (earlier misses stay placed and MUST be bookkept by
@@ -376,7 +381,8 @@ int64_t sm_place_misses(void *h, const uint8_t *blob, const int64_t *offs,
                         const int32_t *pin_counts, int64_t *last_used,
                         int32_t *slots_out, const int64_t *miss_idx,
                         int64_t n_miss, int64_t *evict_out,
-                        int64_t *out_counts) {
+                        int64_t *out_counts, uint8_t *evict_keys,
+                        int32_t *evict_key_lens) {
     sm_t *sm = h;
     sm_cand *cand = NULL;
     int64_t cand_n = 0, cand_i = 0, cand_sorted = 0, n_evict = 0, placed = 0;
@@ -422,6 +428,16 @@ int64_t sm_place_misses(void *h, const uint8_t *blob, const int64_t *offs,
             if (slot < 0) {
                 rc = -1;
                 break;
+            }
+            if (evict_keys) {
+                int32_t kl = sm->ip_len[slot];
+                if (kl > SM_EVICT_KEY_STRIDE)
+                    kl = SM_EVICT_KEY_STRIDE;
+                uint8_t *dst = evict_keys + n_evict * SM_EVICT_KEY_STRIDE;
+                memcpy(dst, sm->ip[slot], (size_t)kl);
+                if (kl == 0)
+                    dst[kl++] = 0;
+                evict_key_lens[n_evict] = kl;
             }
             free(sm->ip[slot]);
             sm->ip[slot] = NULL;

@@ -28,6 +28,10 @@ _LOCK = threading.Lock()
 _LIB: Optional[ctypes.CDLL] = None
 _TRIED = False
 
+# bytes a victim's key takes in place_misses' evict_keys (slotmgr.c
+# SM_EVICT_KEY_STRIDE = the warm tier's WT_KEY_MAX)
+EVICT_KEY_STRIDE = 104
+
 _u8p = ctypes.POINTER(ctypes.c_uint8)
 _i32p = ctypes.POINTER(ctypes.c_int32)
 _i64p = ctypes.POINTER(ctypes.c_int64)
@@ -105,10 +109,11 @@ def _load() -> Optional[ctypes.CDLL]:
         lib.sm_place_misses.argtypes = [
             ctypes.c_void_p, _u8p, _i64p, _i64p, ctypes.c_int64,
             _i32p, _i64p, _i32p, _i64p, ctypes.c_int64, _i64p, _i64p,
+            _u8p, _i32p,
         ]
-        lib.sm_contains_batch.restype = ctypes.c_int64
-        lib.sm_contains_batch.argtypes = [
-            ctypes.c_void_p, _u8p, _i64p, _i64p, ctypes.c_int64, _u8p,
+        lib.sm_find_batch.restype = None
+        lib.sm_find_batch.argtypes = [
+            ctypes.c_void_p, _u8p, _i64p, _i64p, ctypes.c_int64, _i32p,
         ]
         lib.sm_crc32_batch.restype = None
         lib.sm_crc32_batch.argtypes = [
@@ -249,39 +254,53 @@ class SlotManager:
     ):
         """Pass 2: place every miss, in ip order (free stack first, then
         minimum-(last_used, slot) eviction).  Returns (placed_miss_idx,
-        evict_slots, ok).  ok=False is the refusal (every eviction
-        candidate pinned): placements made BEFORE the refusal persist,
-        and placed_miss_idx/evict_slots report exactly those — the
-        caller must bookkeep them (slot->ip mirror, pending device
-        evictions) before splitting the batch, as in the Python path."""
+        evict_slots, evict_keys, ok).  ok=False is the refusal (every
+        eviction candidate pinned): placements made BEFORE the refusal
+        persist, and placed_miss_idx/evict_slots report exactly those —
+        the caller must bookkeep them (slot->ip mirror, pending device
+        evictions) before splitting the batch, as in the Python path.
+        evict_keys = (uint8 [k * EVICT_KEY_STRIDE], int32 [k]): the
+        victims' address bytes as the warm tier keys them, one stride a
+        victim, and their lengths."""
         n_miss = len(miss_idx)
         if n_miss == 0:
-            return np.empty(0, np.int64), np.empty(0, np.int64), True
+            none = np.empty(0, np.int64)
+            return none, none, (np.empty(0, np.uint8),
+                                np.empty(0, np.int32)), True
         buf, offs, lens = ctx
         evict = np.empty(n_miss, dtype=np.int64)
+        keys = np.empty(n_miss * EVICT_KEY_STRIDE, dtype=np.uint8)
+        key_lens = np.empty(n_miss, dtype=np.int32)
         counts = np.zeros(2, dtype=np.int64)
         rc = int(self._lib.sm_place_misses(
             self._h, _P(buf, _u8p), _P(offs, _i64p), _P(lens, _i64p),
             batch_seq, _P(pin_counts, _i32p), _P(last_used, _i64p),
             _P(slots, _i32p), _P(miss_idx, _i64p), n_miss,
-            _P(evict, _i64p), _P(counts, _i64p),
+            _P(evict, _i64p), _P(counts, _i64p), _P(keys, _u8p),
+            _P(key_lens, _i32p),
         ))
-        return miss_idx[: int(counts[1])], evict[: int(counts[0])], rc == 0
+        k = int(counts[0])
+        return (miss_idx[: int(counts[1])], evict[:k],
+                (keys, key_lens[:k]), rc == 0)
+
+    def find_batch(self, ips: Sequence[str]) -> np.ndarray:
+        """int32 [n]: the slot of every ip of a DISTINCT list, -1 where
+        it has none, with NO recency stamp."""
+        n = len(ips)
+        out = np.empty(n, dtype=np.int32)
+        if n:
+            buf, offs, lens = encode_ips(ips)
+            self._lib.sm_find_batch(
+                self._h, _P(buf, _u8p), _P(offs, _i64p), _P(lens, _i64p),
+                n, _P(out, _i32p),
+            )
+        return out
 
     def contains_batch(self, ips: Sequence[str]) -> np.ndarray:
         """bool [n] membership over a DISTINCT ip list, with NO recency
         stamp — the slot-admission gate's hot-tier check (a refused
         batch must not refresh its probe victims' LRU position)."""
-        n = len(ips)
-        out = np.zeros(n, dtype=np.uint8)
-        if n == 0:
-            return out.astype(bool)
-        buf, offs, lens = encode_ips(ips)
-        self._lib.sm_contains_batch(
-            self._h, _P(buf, _u8p), _P(offs, _i64p), _P(lens, _i64p), n,
-            _P(out, _u8p),
-        )
-        return out.astype(bool)
+        return self.find_batch(ips) >= 0
 
 
 def create(capacity: int) -> Optional[SlotManager]:

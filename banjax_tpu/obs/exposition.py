@@ -180,6 +180,11 @@ def render_prometheus(
         fam = registry.PROM_FAMILIES["banjax_window_events_total"]
         w.sample(fam, dw.site_events, {"scope": "site"})
         w.sample(fam, dw.device_events - dw.site_events, {"scope": "global"})
+    if dw is not None and hasattr(dw, "shadow_records"):
+        fam = registry.PROM_FAMILIES["banjax_shadow_records_total"]
+        for op, by_path in dw.shadow_records.items():
+            for path, v in by_path.items():
+                w.sample(fam, v, {"op": op, "path": path})
     if dw is not None and hasattr(dw, "resolve_outcomes"):
         fam = registry.PROM_FAMILIES["banjax_submit_resolve_addresses_total"]
         for outcome, v in dw.resolve_outcomes.items():

@@ -271,6 +271,15 @@ FAMILIES: List[Family] = [
            "the rule belongs to one site or is global (their sum is "
            "banjax_device_windows_events_total)",
            prom="banjax_window_events_total", labels=("scope",)),
+    Family(COUNTER, "what moved through the host shadow of the device "
+           "window counters (matcher/windows.py), by operation — absorb "
+           "(window events folded in), spill (records a placement's "
+           "victims sent to the warm tier), refill (records returning "
+           "addresses took back), restore (records whose counters "
+           "re-entered the device) — and by the form that handled it: "
+           "native (the slot-indexed C mirror) or dict (the Python form; 0 "
+           "wherever the native libraries loaded)",
+           prom="banjax_shadow_records_total", labels=("op", "path")),
     Family(COUNTER, "host wall seconds inside the drain's effector-replay "
            "spans (event decode, shadow absorb, Banner replay of committed "
            "fused chunks)",

@@ -342,6 +342,70 @@ def test_multisite_family_is_on_metrics_and_its_reader_reads_it(family):
     m.close()
 
 
+@pytest.fixture(scope="module")
+def shadow_scrape():
+    """`/metrics` after a stream whose addresses all fire the rule and
+    turn a 64-slot table over twice, so that events are absorbed, records
+    spill, come back and are restored — and the matcher's own tallies."""
+    from banjax_tpu.decisions.dynamic_lists import DynamicDecisionLists
+    from banjax_tpu.decisions.rate_limit import (
+        FailedChallengeRateLimitStates,
+    )
+    from banjax_tpu.obs.exposition import render_prometheus
+    from benchmark.harness import prom
+
+    cfg = config_from_yaml_text(_RULES)
+    cfg.matcher_device_windows = True
+    cfg.matcher_window_capacity = 64
+    cfg.warm_tier_enabled = True
+    cfg.warm_tier_capacity = 1024
+    m = TpuMatcher(cfg, MockBanner(), StaticDecisionLists(cfg),
+                   RegexRateLimitStates())
+    now = time.time()
+    sched = PipelineScheduler(lambda: m, now_fn=lambda: now)
+    sched.start()
+    for k in range(12):           # 4 x 48 addresses, three times round
+        sched.submit([
+            f"{now:.6f} 1.2.{k % 4}.{i} GET h.com GET /attack{i} HTTP/1.1 ua -"
+            for i in range(48)
+        ])
+        assert sched.flush(120)
+    sched.stop()
+    snap = prom.parse(render_prometheus(
+        DynamicDecisionLists(start_sweeper=False), RegexRateLimitStates(),
+        FailedChallengeRateLimitStates(), matcher=m,
+    ))
+    dw = m.device_windows
+    tallies = {"absorb": dw.device_events, "spill": dw.warm_spills,
+               "refill": dw.warm_refills, "native": dw.slotmgr_native}
+    m.close()
+    return snap, tallies
+
+
+@pytest.mark.parametrize("op", ["absorb", "spill", "refill", "restore"])
+def test_shadow_family_is_on_metrics_by_op_and_path(shadow_scrape, op):
+    """`banjax_shadow_records_total{op, path}` (ISSUE 38): what moved
+    through the host shadow, by the form that handled it.  Read by no
+    cell; a chip run's `/metrics` shows by it that no operation fell
+    back: with the native libraries loaded `path="dict"` reads 0, and the
+    native counts are the window events, the spills and the refills."""
+    from benchmark.harness import prom
+
+    snap, tallies = shadow_scrape
+    assert "banjax_shadow_records_total" in {f.prom for f in registry.FAMILIES}
+    mine, other = (("native", "dict") if tallies["native"]
+                   else ("dict", "native"))
+    got = prom.value(snap, "banjax_shadow_records_total", op=op, path=mine)
+    assert prom.value(
+        snap, "banjax_shadow_records_total", op=op, path=other) == 0
+    if op == "restore":
+        assert 0 < got <= tallies["refill"]
+    else:
+        assert got == tallies[op] > 0
+    assert tallies["absorb"] == prom.value(
+        snap, "banjax_device_windows_events_total")
+
+
 def test_stage2_readers_split_a_trace_by_nfa_words():
     """`match_stage2_us_per_kline` and `match_stage2_roofline` take stage
     2 to be the match-kernel launches with more NFA words than stage 1's

@@ -495,7 +495,7 @@ def test_warm_tier_round_trip_byte_identical():
     assert ent is not None
     got = {rules[rid].rule: (h, s * NS + ns) for rid, h, s, ns in ent}
     assert got == snap                  # the raw spilled vectors
-    assert "ip-a" not in dw._shadow     # warm is the home, not a copy
+    assert "ip-a" not in dw.shadow_items()     # warm is the home, not a copy
 
     hit("ip-a", base + 10, [1, 1])     # returns -> REFILL from warm;
     #                                    its slot claim evicts ip-b,
@@ -566,7 +566,7 @@ def test_warm_tier_drop_keeps_shadow_entry():
     assert spilled_or_kept == n - 2
     assert dw.warm_dropped > 0, "tiny tier never reported drop pressure"
     # every dropped spill fell back to the shadow (lossless)
-    assert dw.warm_spills + len(dw._shadow) >= n - 2
+    assert dw.warm_spills + len(dw.shadow_items()) >= n - 2
 
 
 # ------------------------------------------------- validity by generation
@@ -664,7 +664,7 @@ def test_reclaimed_slot_never_shows_previous_owner(new_owner):
         hit("C", base, [0, 1])             # C holds r1 only
         hit("f1", base + 1, [1, 0])
         hit("f2", base + 2, [1, 0])        # evicts C: shadow or warm tier
-        assert ("C" in dw._shadow) == (new_owner == "shadow")
+        assert ("C" in dw.shadow_items()) == (new_owner == "shadow")
     hit("A", base + 3, [1, 1])
     hit("A", base + 4, [1, 1])             # A: r0=2, r1=2
     hit("B", base + 5, [1, 0])

@@ -22,6 +22,7 @@ import pytest
 from banjax_tpu.matcher.windows import DeviceWindows
 from banjax_tpu.native import shm, slotmgr
 from banjax_tpu.obs.sketch import TrafficSketch, hash_ip
+from tests.shadow_access import plant, shadow
 from tests.unit.test_slotmgr import (
     assert_same_state,
     assert_same_warm_state,
@@ -144,7 +145,7 @@ def test_dropped_put_of_a_batch_keeps_its_shadow_entry():
         ips = [ip_of(10 * rnd + i) for i in range(4)]
         for w in (nat, ora):
             for ip in ips:
-                w._shadow.setdefault(ip, dict(vec))
+                plant(w, ip, vec)
         s = lockstep(nat, ora, ips, f"round {rnd}")
         nat.release_pins(s), ora.release_pins(s)
         assert_same_warm_state(nat, ora, f"round {rnd}")
@@ -152,7 +153,7 @@ def test_dropped_put_of_a_batch_keeps_its_shadow_entry():
     for rnd in range(5):  # every evicted vector is somewhere
         for i in range(4):
             ip = ip_of(10 * rnd + i)
-            assert nat._warm.peek(ip) is not None or ip in nat._shadow, ip
+            assert nat._warm.peek(ip) is not None or ip in shadow(nat), ip
 
 
 # ------------------------------------------ batched spill / refill calls
@@ -175,32 +176,59 @@ def _records(rng, n, max_rules):
     return out
 
 
+def _keys(ips):
+    """The keys as the slot manager hands a placement's victims over:
+    one stride an address, cut at it, the empty address one NUL."""
+    stride = slotmgr.EVICT_KEY_STRIDE
+    blob = np.zeros(len(ips) * stride, dtype=np.uint8)
+    lens = np.empty(len(ips), dtype=np.int32)
+    for k, ip in enumerate(ips):
+        key = shm._wt_key(ip)
+        blob[k * stride : k * stride + len(key)] = np.frombuffer(key, np.uint8)
+        lens[k] = len(key)
+    return blob, lens
+
+
 @pytest.mark.parametrize("capacity,seed", [(64, 1), (64, 2), (1024, 3)])
-def test_put_batch_and_take_batch_are_the_per_record_calls(capacity, seed):
+def test_spill_and_refill_of_the_mirror_are_the_per_record_calls(
+    capacity, seed
+):
     """Random records — repeated keys, the empty key, no entries, more
-    entries than the record holds, a table that overflows — through
-    wt_put_batch / wt_take_batch on one table and through put / take, one
-    call a record, on another: same result per record, same table."""
+    entries than the record holds, a table that overflows — out of a
+    mirror through sh_spill / back through sh_refill on one table and
+    through put / take, one call a record, on another: same result per
+    record, same table."""
     rng = random.Random(seed)
     mk = dict(capacity=capacity, max_rules=4, expiry_ns=10**15)
     a, b = shm.ShmWarmTier(**mk), shm.ShmWarmTier(**mk)
+    mirror = shm.create_shadow_mirror(64)
     try:
         for rnd in range(30):
             recs = _records(rng, rng.randrange(1, 40), 4)
             now = 1_000 + rnd
-            got = a.put_batch(
-                [ip for ip, _ in recs],
-                [OrderedDict((e[0], e[1:]) for e in ents) for _, ents in recs],
-                now,
-            )
+            slots = np.arange(len(recs), dtype=np.int64)
+            for k, (_, ents) in enumerate(recs):
+                mirror.install(k, OrderedDict((e[0], e[1:]) for e in ents))
+            held = len(mirror)
+            ips = [ip for ip, _ in recs]
+            status = mirror.spill(a, slots, _keys(ips), now)
             want = [b.put(ip, e, now) for ip, e in recs]
-            assert got.tolist() == want, rnd
+            assert (status == 1).tolist() == want, rnd
+            # a dropped put keeps its record in the mirror; no entries,
+            # no record
+            assert [st == 0 for st in status.tolist()] == \
+                [not e for _, e in recs], rnd
+            assert len(mirror) == held - sum(want), rnd
+            mirror.export(slots, drop=True)
             assert sorted(a.keys()) == sorted(b.keys()), rnd
             assert (len(a), a.dropped) == (len(b), b.dropped), rnd
             asked = [ip for ip, _ in rng.sample(recs, len(recs) // 4 + 1)]
             asked += [f"absent{rnd}", asked[0]]  # absent; a key twice
-            assert a.take_batch(asked) == \
-                [_vec(b.take(ip)) for ip in asked], rnd
+            to = np.arange(len(asked), dtype=np.int32)
+            stamps = mirror.refill(a, to, slotmgr.encode_ips(asked))
+            want = [_vec(b.take(ip)) for ip in asked]
+            assert mirror.export(to, drop=True)[1] == want, rnd
+            assert (stamps > 0).tolist() == [v is not None for v in want], rnd
             # the same spans out of a larger encoding, as the pass gives them
             more = [ip for ip, _ in recs]
             enc = slotmgr.encode_ips(["pad"] + more)
@@ -210,8 +238,11 @@ def test_put_batch_and_take_batch_are_the_per_record_calls(capacity, seed):
                 b.contains_batch(more).tolist(), rnd
             few = slice(0, None, 5)
             spans = tuple(x[few] if k else x for k, x in enumerate(spans))
-            assert a.take_batch(more[few], spans=spans) == \
+            to = np.arange(len(more[few]), dtype=np.int32)
+            mirror.refill(a, to, spans)
+            assert mirror.export(to, drop=True)[1] == \
                 [_vec(b.take(ip)) for ip in more[few]], rnd
+            assert len(mirror) == 0, rnd
         assert a.dropped > 0 or capacity > 64
     finally:
         for t in (a, b):
@@ -219,14 +250,30 @@ def test_put_batch_and_take_batch_are_the_per_record_calls(capacity, seed):
             t.unlink()
 
 
-def test_py_warm_tier_has_the_batch_calls_too():
+def test_a_python_tier_behind_the_mirror_moves_record_by_record():
+    """A tier that is not the C table (the fallback, or one a test
+    injects) has no arena the mirror could copy into: spills and refills
+    go through its put / take, one record each, counted as the dict
+    form's, and nothing is lost on the way."""
     py = shm.PyWarmTier(capacity=8, max_rules=4)
-    ents = [[(1, 2, 3, 4)], [], [(5, 6, 7, 8), (9, 1, 2, 3)]]
-    vecs = [{e[0]: e[1:] for e in ent} for ent in ents]
-    assert py.put_batch(["a", "b", "c"], vecs, 10).tolist() == \
-        [True, False, True]
-    assert py.take_batch(["c", "b", "a", "a"]) == \
-        [vecs[2], None, vecs[0], None]
+    dw = DeviceWindows([make_rule()], capacity=2, warm_tier=py)
+    assert dw.slotmgr_native
+    vecs = {ip_of(i): {0: (i + 1, 1_700_000_000 + i, 7 * i)} for i in range(2)}
+    s = dw.slots_for_unique_ips(list(vecs))
+    dw.release_pins(s)
+    for ip, vec in vecs.items():
+        plant(dw, ip, vec)
+    s = dw.slots_for_unique_ips([ip_of(8), ip_of(9)])   # evicts both
+    dw.release_pins(s)
+    assert {ip: _vec(py.peek(ip)) for ip in vecs} == vecs
+    assert not shadow(dw) and dw.warm_spills == 2
+    s = dw.slots_for_unique_ips([ip_of(1), ip_of(0)])   # and back
+    dw.release_pins(s)
+    assert dict(shadow(dw)) == {ip_of(1): vecs[ip_of(1)],
+                                ip_of(0): vecs[ip_of(0)]}
+    assert len(py) == 0 and dw.warm_refills == 2
+    assert dw.shadow_records["spill"] == {"native": 0, "dict": 2}
+    assert dw.shadow_records["refill"] == {"native": 0, "dict": 2}
 
 
 # ------------------------------------------------- the gate from the pass
@@ -238,7 +285,7 @@ def _seed_states(rng, wins, pool, step):
     ip = rng.choice(pool)
     vec = {0: (step + 1, 1_700_000_000 + step, step * 7)}
     for w in wins:
-        w._shadow.setdefault(ip, dict(vec))
+        plant(w, ip, vec)
 
 
 @pytest.mark.parametrize("threshold,seed", [

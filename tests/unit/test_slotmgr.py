@@ -19,6 +19,7 @@ import pytest
 from banjax_tpu.config.schema import Decision, RegexWithRate
 from banjax_tpu.matcher.windows import DeviceWindows
 from banjax_tpu.native import slotmgr
+from tests.shadow_access import pending_restore_slots, plant, shadow
 
 pytestmark = pytest.mark.skipif(
     slotmgr.create(8) is None,
@@ -51,7 +52,7 @@ def assert_same_state(nat: DeviceWindows, ora: DeviceWindows, ctx=""):
     assert nat.capacity == ora.capacity, ctx
     assert nat._slot_ip == ora._slot_ip, ctx
     assert nat._pending_evict == ora._pending_evict, ctx
-    assert nat._pending_restore == ora._pending_restore, ctx
+    assert pending_restore_slots(nat) == pending_restore_slots(ora), ctx
     assert nat.eviction_count == ora.eviction_count, ctx
     assert nat.grow_count == ora.grow_count, ctx
     assert nat.occupancy == ora.occupancy, ctx
@@ -146,13 +147,13 @@ def test_shadow_restore_trigger_parity():
     s = lockstep(nat, ora, [ip_of(0), ip_of(1)])
     nat.release_pins(s), ora.release_pins(s)
     for w in (nat, ora):  # counters spilled for ip 0, as apply would
-        w._shadow[ip_of(0)] = {}
+        plant(w, ip_of(0), {0: (1, 1_700_000_000, 0)})
     s = lockstep(nat, ora, [ip_of(2), ip_of(3)])  # evicts 0 and 1
     nat.release_pins(s), ora.release_pins(s)
     s = lockstep(nat, ora, [ip_of(0)])  # returns: restore fires
-    assert nat._pending_restore == ora._pending_restore
-    assert len(nat._pending_restore) == 1
-    assert nat._pending_restore[0][1] == ip_of(0)
+    assert pending_restore_slots(nat) == pending_restore_slots(ora)
+    assert len(pending_restore_slots(nat)) == 1
+    assert nat._slot_ip[pending_restore_slots(nat)[0]] == ip_of(0)
     nat.release_pins(s), ora.release_pins(s)
 
 
@@ -206,8 +207,8 @@ def test_parity_fuzz_eviction_churn(capacity, seed):
             nat.release_pins(h), ora.release_pins(h)
         if rng.random() < 0.15:
             ip = rng.choice(pool)
-            nat._shadow.setdefault(ip, {})
-            ora._shadow.setdefault(ip, {})
+            plant(nat, ip, {0: (step + 1, 1_700_000_000, 0)})
+            plant(ora, ip, {0: (step + 1, 1_700_000_000, 0)})
         if rng.random() < 0.02:
             held.clear()
             nat.clear(), ora.clear()
@@ -216,7 +217,7 @@ def test_parity_fuzz_eviction_churn(capacity, seed):
         nat.release_pins(h), ora.release_pins(h)
     assert_same_state(nat, ora, "final")
     assert nat.eviction_count > 0, "fuzz never churned an eviction"
-    assert nat._pending_restore or nat.eviction_count > 0
+    assert pending_restore_slots(nat) or nat.eviction_count > 0
 
 
 def test_parity_fuzz_autogrow_chain(monkeypatch):
@@ -261,7 +262,7 @@ def assert_same_warm_state(nat: DeviceWindows, ora: DeviceWindows, ctx=""):
     assert nat.warm_spills == ora.warm_spills, ctx
     assert nat.warm_refills == ora.warm_refills, ctx
     assert nat.warm_dropped == ora.warm_dropped, ctx
-    assert sorted(nat._shadow) == sorted(ora._shadow), ctx
+    assert sorted(shadow(nat)) == sorted(shadow(ora)), ctx
     nk, ok_ = sorted(nat._warm.keys()), sorted(ora._warm.keys())
     assert nk == ok_, ctx
     for ip in nk:
@@ -303,7 +304,7 @@ def test_parity_fuzz_warm_spill_hooks(capacity, seed):
             seeded += 1
             vec = {0: (seeded, 1_700_000_000 + seeded, seeded * 7)}
             for w in (nat, ora):
-                w._shadow.setdefault(ip, dict(vec))
+                plant(w, ip, vec)
         if rng.random() < 0.5:
             probe = rng.sample(pool, rng.randrange(1, capacity))
             est = np.zeros(len(probe), dtype=np.int64)
@@ -331,7 +332,7 @@ def test_warm_drop_keeps_shadow_in_both_modes():
     for i in range(n):
         ip = ip_of(i)
         for w in (nat, ora):
-            w._shadow.setdefault(ip, dict(vec))
+            plant(w, ip, vec)
         s = lockstep(nat, ora, [ip], f"fill {i}")
         nat.release_pins(s), ora.release_pins(s)
         assert_same_warm_state(nat, ora, f"fill {i}")
@@ -340,4 +341,4 @@ def test_warm_drop_keeps_shadow_in_both_modes():
     for i in range(n - 2):
         ip = ip_of(i)
         in_warm = nat._warm.peek(ip) is not None
-        assert in_warm or ip in nat._shadow, ip
+        assert in_warm or ip in shadow(nat), ip

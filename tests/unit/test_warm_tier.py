@@ -13,9 +13,10 @@ import random
 import struct
 from multiprocessing import shared_memory
 
+import numpy as np
 import pytest
 
-from banjax_tpu.native import shm
+from banjax_tpu.native import shm, slotmgr
 
 WINDOW = 64  # WT_MAX_PROBE
 
@@ -295,13 +296,22 @@ def test_round_trip_is_identical_at_ten_thousand_rules(tiers, n_entries):
     assert c.peek("198.51.100.7") == ent
     assert c.take("198.51.100.7") == ent
     assert c.blocks_used == 0 and len(c) == 0
-    # the batched forms, beside a record of another size
+    # through the mirror's batched moves, beside a record of another size
     other = _vector(rng, 3, 10_000)
-    stored = c.put_batch(["198.51.100.7", "198.51.100.8"],
-                         [od, {r: (h, s, ns) for r, h, s, ns in other}], 6)
-    assert stored.all()
-    got = c.take_batch(["198.51.100.8", "203.0.113.1", "198.51.100.7"])
-    assert got[1] is None
+    mirror = shm.create_shadow_mirror(4)
+    mirror.install(0, od)
+    mirror.install(1, {r: (h, s, ns) for r, h, s, ns in other})
+    keys = np.zeros(2 * 104, dtype=np.uint8)
+    keys[:12] = np.frombuffer(b"198.51.100.7", np.uint8)
+    keys[104:116] = np.frombuffer(b"198.51.100.8", np.uint8)
+    status = mirror.spill(c, np.arange(2, dtype=np.int64),
+                          (keys, np.full(2, 12, np.int32)), 6)
+    assert status.tolist() == [1, 1] and len(mirror) == 0
+    asked = ["198.51.100.8", "203.0.113.1", "198.51.100.7"]
+    stamps = mirror.refill(c, np.arange(3, dtype=np.int32),
+                           slotmgr.encode_ips(asked))
+    got = mirror.export(np.arange(3, dtype=np.int32))[1]
+    assert got[1] is None and (stamps > 0).tolist() == [True, False, True]
     assert list(got[2].items()) == list(od.items())
     assert [(r, *v) for r, v in got[0].items()] == other
     assert c.blocks_used == 0
@@ -316,8 +326,8 @@ def test_mapping_and_resident_bytes_follow_the_counters_not_the_ruleset(tiers):
     before = _segment_bytes(c.name)
     n = 1 << 15
     ips = [f"100.{64 + (i >> 16)}.{(i >> 8) & 255}.{i & 255}" for i in range(n)]
-    vecs = [{7: (1, 2, 3), 9_999: (2, 3, 4)}] * n
-    assert c.put_batch(ips, vecs, 1).all()
+    vec = [(7, 1, 2, 3), (9_999, 2, 3, 4)]
+    assert all(c.put(ip, vec, 1) for ip in ips)
     assert c.bytes_written == n * (128 + 2 * 24)
     assert c.blocks_used == n
     if before is not None:
@@ -325,8 +335,7 @@ def test_mapping_and_resident_bytes_follow_the_counters_not_the_ruleset(tiers):
         # a record, as the fixed stride had it, were 128 MB
         assert _segment_bytes(c.name) - before <= n * 256 + (17 << 20)
     assert (c.probes, c.record_reads) == (n, 0)  # fresh keys: tags only
-    assert all(list(v.items()) == list(vecs[0].items())
-               for v in c.take_batch(ips))
+    assert all(c.take(ip) == vec for ip in ips)
     assert c.probes == 2 * n and n <= c.record_reads <= n + 4
 
 
