@@ -196,10 +196,6 @@ class FusedWindowsPipeline:
         # would steal an earlier live chunk's turn
         self._dead = set()
 
-    # the SingleKernel* metric families read the same two counts
-    sk_chunks = property(lambda self: self.fused_batches)
-    sk_fallbacks = property(lambda self: self.fallback_batches)
-
     # ---- the program: match + window commit in ONE dispatch ----
 
     def _single_prog(self, Bp: int, L_p: int):
@@ -240,6 +236,7 @@ class FusedWindowsPipeline:
         state-chain order == seq order because both are taken inside the
         same critical section."""
         pf = self.pf
+        lap = trace.lap()  # the caller's `operands` phase runs on
         cls_ids = np.asarray(cls_ids, dtype=np.int32)
         lens = np.asarray(lens, dtype=np.int32)
         B = cls_ids.shape[0]
@@ -257,6 +254,7 @@ class FusedWindowsPipeline:
         live_p = np.zeros(Bp, dtype=np.uint8)
         live_p[:B] = 1 if live is None else np.asarray(live, dtype=np.uint8)
         wnd = self.windows
+        lap.mark("dispatch")
         with wnd._lock:
             with self._cv:
                 seq = self._next_seq
@@ -267,7 +265,9 @@ class FusedWindowsPipeline:
                     self._chain_ok = None
                 self._next_seq += 1
                 chain = self._chain_ok
+            lap.mark("maintenance")
             wnd._run_maintenance_locked()
+            lap.mark("dispatch")
             new_state, chain_out, buf, bits_dev = fn(
                 wnd._state,
                 chain if chain is not None else jnp.int32(1),
@@ -291,7 +291,9 @@ class FusedWindowsPipeline:
             # no dense [B, n_rules] bitmap
             h2d_bytes=combined.nbytes + 4 * 3 * Bp + Bp + 4,
         )
+        lap.mark("sketch")
         self._sketch_update(p)
+        lap.mark("other")
         return p
 
     def _sketch_update(self, p: _Pend) -> None:

@@ -1011,15 +1011,19 @@ class TpuMatcher(Matcher):
             state["window_at_drain"] = True
 
     @contextlib.contextmanager
-    def _resolving(self):
-        """A `submit-resolve` span, its wall added to the seconds that
-        `banjax_submit_resolve_seconds_total` exports."""
-        t0 = time.perf_counter()
-        try:
-            with trace.span("submit-resolve"):
+    def _resolving(self, lap):
+        """A `submit-resolve` span over a stretch of the pass.  Its wall,
+        which `banjax_submit_resolve_seconds_total` exports, lies between
+        the lap clock's mark that opens the stretch and the one that
+        closes it: the phase `pass` plus the sketch's note inside it."""
+        with trace.span("submit-resolve"):
+            lap.mark("pass")
+            t0 = lap.t
+            try:
                 yield
-        finally:
-            self.submit_resolve_s += time.perf_counter() - t0
+            finally:
+                lap.mark("other")
+                self.submit_resolve_s += lap.t - t0
 
     def _resolve_submit(self, state: dict) -> None:
         """The submit stage's one pass over the batch's distinct
@@ -1034,14 +1038,15 @@ class TpuMatcher(Matcher):
         dw = self.device_windows
         sk = self.traffic_sketch
         gate = self._slot_admission and sk is not None
+        lap = trace.lap()
 
         def keep_slots(uinv):
             state["slots"] = None
             if res.slots is not None:
-                self._note_sketch_slots(uips, res)
+                self._note_sketch_slots(uips, res, lap)
                 state["slots"] = res.slots[uinv]
 
-        with self._resolving():
+        with self._resolving(lap):
             uips, uinv = state["work"].unique_ips()
             counts = None
             if gate and self._admission_min_estimate > 1:
@@ -1065,6 +1070,7 @@ class TpuMatcher(Matcher):
                 keep_slots(uinv)
                 return
         # a threshold of 2 or more only
+        lap.mark("pass")
         sk.fold_refused(
             [uips[i] for i in res.refused.tolist()],
             counts[res.refused], hashes=res.refused_hashes,
@@ -1073,16 +1079,19 @@ class TpuMatcher(Matcher):
             state["work"], state["pre"], res.admit[uinv]
         )
         self._consume_refused(work_r, pre_r, state["results"])
-        with self._resolving():
+        lap.mark("other")
+        with self._resolving(lap):
             dw.place_resolved(res)
             keep_slots(uinv[adm])
 
-    def _note_sketch_slots(self, uips, res) -> None:
+    def _note_sketch_slots(self, uips, res, lap) -> None:
         """Refresh the sketch's slot→ip-hash table for a batch's distinct
         assignments (scatters only CHANGED slots); a telemetry failure
         must never cost the batch."""
         if self.traffic_sketch is None:
             return
+        was = lap.phase
+        lap.mark("sketch")
         try:
             ips, slots, hashes = uips, res.slots, res.hashes
             if len(res.refused):
@@ -1093,6 +1102,7 @@ class TpuMatcher(Matcher):
             self.traffic_sketch.note_assignments(ips, slots, hashes=hashes)
         except Exception:  # noqa: BLE001 — sketch is passive by contract
             log.exception("traffic sketch slot-table refresh failed")
+        lap.mark(was)
 
     def _single_kernel_ordered(self) -> bool:
         """Commit-at-submit is only order-safe while no EARLIER admitted
@@ -1132,13 +1142,17 @@ class TpuMatcher(Matcher):
             cls_ids, lens, _ = state["pre"]
             if now is None:
                 now = time.time()
+            lap = trace.lap()
             for s in range(0, len(work), self._max_batch):
+                lap.mark("operands", row0=s)
                 wc = work[s : s + self._max_batch]
                 live = stale = None
                 ages_s = now - wc.ts_array() / 1e9
                 st = ages_s > OLD_LINE_CUTOFF_SECONDS
                 if st.any():
                     stale, live = st, ~st
+                # a phase ends before a span opens over what follows it
+                lap.mark("other")
                 with trace.span("program-ab-fused", args={"row0": s}):
                     handed, resolved = resolved, {}
                     e = self._submit_pipeline_chunk(
@@ -1374,7 +1388,7 @@ class TpuMatcher(Matcher):
         )
         if res.slots is None:
             return None
-        self._note_sketch_slots(uips, res)
+        self._note_sketch_slots(uips, res, trace.lap())
         return res.slots[uinv]
 
     # ---- cold-tier slot admission (mega-state tiering) ----
@@ -1743,11 +1757,15 @@ class TpuMatcher(Matcher):
         from banjax_tpu.matcher.windows import split_ns
 
         dw = self.device_windows
+        lap = trace.lap()
         if slots is None:
+            lap.mark("pass")
             slots = self._slots_for_work(work)
         if slots is None:
+            lap.mark("other")
             return None
         try:
+            lap.mark("operands")
             ts_s, ts_ns = split_ns(work.ts_array())
             host_idx = work.host_idx(self._host_row)
             pend = self._fw_pipeline.submit(

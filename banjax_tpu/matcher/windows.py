@@ -70,6 +70,7 @@ from banjax_tpu.decisions.rate_limit import (
     NumHitsAndIntervalStart,
     RateLimitMatchType,
 )
+from banjax_tpu.obs import trace
 
 _NS_PER_S = 1_000_000_000
 
@@ -483,6 +484,41 @@ class Resolution:
     _sketch_admitted: Optional[np.ndarray] = None  # int64, into ips
 
 
+class _StageTimedLock:
+    """`DeviceWindows._lock`: a `threading.Lock` that counts what the
+    pipeline's stages waited for it.  An acquire that finds the lock free
+    costs one call more than the bare lock and reads no clock; one that
+    finds it held times its blocking acquire and books it under the stage
+    the waiting thread said it runs (`trace.stage_thread`: the
+    scheduler's device thread says `submit`, its drain thread `drain`; a
+    thread that said nothing is not counted) —
+    `banjax_windows_lock_wait_seconds_total{stage}` and
+    `banjax_windows_lock_contended_total{stage}`, whose quotient is the
+    mean wait of one contention.  The tallies are written by the thread
+    that has just taken the lock."""
+
+    __slots__ = ("_lock", "wait_s", "contended")
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.wait_s = {"submit": 0.0, "drain": 0.0}
+        self.contended = {"submit": 0, "drain": 0}
+
+    def __enter__(self) -> None:
+        if self._lock.acquire(False):
+            return
+        t0 = time.perf_counter()
+        self._lock.acquire()
+        waited = time.perf_counter() - t0
+        stage = trace.thread_stage()
+        if stage is not None:
+            self.wait_s[stage] = self.wait_s.get(stage, 0.0) + waited
+            self.contended[stage] = self.contended.get(stage, 0) + 1
+
+    def __exit__(self, *exc) -> None:
+        self._lock.release()
+
+
 class DeviceWindows:
     """Device-resident RegexRateLimitStates with host slot management.
 
@@ -528,7 +564,7 @@ class DeviceWindows:
         # a single line can fire every rule; max_events >= n_rules makes the
         # overflow split terminate at B=1
         self.max_events = max(max_events, self.n_rules)
-        self._lock = threading.Lock()
+        self._lock = _StageTimedLock()
 
         limits = np.zeros(self.n_rules, dtype=np.int32)
         iv_s = np.zeros(self.n_rules, dtype=np.int32)
@@ -1405,6 +1441,13 @@ class DeviceWindows:
             warm = len(self._warm) if self._warm is not None else 0
             held = len(self._mirror) if self._mirror is not None else 0
             return held + len(self._shadow) + warm
+
+    def lock_waits(self) -> Dict[str, Tuple[float, int]]:
+        """{stage: (seconds waited for the windows lock, acquires that
+        found it held)} — see _StageTimedLock."""
+        lk = self._lock
+        return {stage: (lk.wait_s[stage], lk.contended.get(stage, 0))
+                for stage in list(lk.wait_s)}
 
     # ---- tier gauges (obs/stats.py snapshot surface) ----
 

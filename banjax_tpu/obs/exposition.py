@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import math
 import re
+import time
 from typing import Dict, List, Optional, Tuple
 
 from banjax_tpu.obs import registry
@@ -93,6 +94,24 @@ class _Writer:
 
     def text(self) -> str:
         return "\n".join(self.lines) + "\n"
+
+
+def _thread_cpu_samples(w: "_Writer", thread_ids: Dict[str, list]) -> None:
+    """banjax_thread_cpu_seconds_total{thread}: each thread's own CPU
+    clock by its native id (the id Linux gives a thread's CPU clock: the
+    tid's complement shifted by 3, `| 6` = per thread, scheduler's
+    count), read here, at scrape time, never on the pipeline's path."""
+    fam = registry.PROM_FAMILIES["banjax_thread_cpu_seconds_total"]
+    for label, tids in thread_ids.items():
+        seconds = None
+        for tid in tids:
+            try:
+                cpu = time.clock_gettime((~tid << 3) | 6)
+            except (OSError, OverflowError):
+                continue
+            seconds = (seconds or 0.0) + cpu
+        if seconds is not None:
+            w.sample(fam, round(seconds, 6), {"thread": label})
 
 
 def render_prometheus(
@@ -193,8 +212,29 @@ def render_prometheus(
         for table, v in dw.resolve_probes.items():
             w.sample(fam, v, {"table": table})
 
-    # per-worker encode busy fractions (prom-only labeled gauge)
+    if dw is not None and hasattr(dw, "lock_waits"):
+        wait_fam = registry.PROM_FAMILIES[
+            "banjax_windows_lock_wait_seconds_total"]
+        n_fam = registry.PROM_FAMILIES["banjax_windows_lock_contended_total"]
+        for stage, (seconds, n) in dw.lock_waits().items():
+            w.sample(wait_fam, round(seconds, 6), {"stage": stage})
+            w.sample(n_fam, n, {"stage": stage})
+
+    # the submit stage from inside, the sizer's moves, and what each
+    # pipeline thread got of a core
     if pipeline is not None:
+        wall_by_phase, cpu_s = pipeline.submit_phase_seconds()
+        fam = registry.PROM_FAMILIES["banjax_submit_phase_seconds_total"]
+        for phase, seconds in wall_by_phase.items():
+            w.sample(fam, round(seconds, 6), {"phase": phase})
+        w.sample(registry.PROM_FAMILIES["banjax_submit_cpu_seconds_total"],
+                 round(cpu_s, 6))
+        fam = registry.PROM_FAMILIES[
+            "banjax_pipeline_batch_target_changes_total"]
+        for direction, n in pipeline.batch_target_changes().items():
+            w.sample(fam, n, {"direction": direction})
+        _thread_cpu_samples(w, pipeline.thread_ids())
+        # per-worker encode busy fractions (prom-only labeled gauge)
         fracs = pipeline.stats.worker_busy_fractions()
         if fracs:
             fam = registry.PROM_FAMILIES["banjax_encode_worker_busy_fraction"]

@@ -214,6 +214,39 @@ FAMILIES: List[Family] = [
            "and refills)",
            line_key="SubmitResolveSeconds",
            prom="banjax_submit_resolve_seconds_total"),
+    # ---- the submit stage from inside (obs/trace.py LapClock) ----
+    Family(COUNTER, "wall seconds of the pipeline's submit stage (from "
+           "the scheduler's start of a batch's device stage to the end of "
+           "pipeline_submit) by phase — pass (one pass over the batch's "
+           "distinct addresses), sketch (the traffic sketch's slot note "
+           "and update), operands (the fused program's inputs), "
+           "maintenance (evictions and restores in front of the "
+           "dispatch), dispatch (the fused program's call), other.  The "
+           "six sum to the submit part of "
+           "banjax_stage_duration_seconds_sum{stage=\"device\"}",
+           prom="banjax_submit_phase_seconds_total", labels=("phase",)),
+    Family(COUNTER, "CPU seconds the submitting thread ran inside the "
+           "submit stage (its own CPU clock, read where a batch's stage "
+           "starts and ends); the phases' wall less this is the time the "
+           "thread waited — for the interpreter, a lock, the device, a "
+           "core",
+           prom="banjax_submit_cpu_seconds_total"),
+    Family(COUNTER, "seconds the pipeline's stages waited for the device "
+           "windows' lock when it was held (an acquire that finds it free "
+           "is not timed), by the stage the waiting thread runs: submit "
+           "(pipeline-device), drain (pipeline-drain)",
+           prom="banjax_windows_lock_wait_seconds_total",
+           labels=("stage",)),
+    Family(COUNTER, "acquires of the device windows' lock that found it "
+           "held, by the stage the waiting thread runs; the wait seconds "
+           "over this count is the mean wait of one contention (many "
+           "short waits, or a few long ones behind a maintenance step)",
+           prom="banjax_windows_lock_contended_total", labels=("stage",)),
+    Family(COUNTER, "CPU seconds of the pipeline's threads, read from the "
+           "threads' own clocks at scrape time: pipeline-encode, "
+           "pipeline-encode-worker (the pool as one), pipeline-device, "
+           "pipeline-drain",
+           prom="banjax_thread_cpu_seconds_total", labels=("thread",)),
     # ---- mesh ----
     Family(COUNTER, "sharded-mesh batches served by the fused two-stage path",
            line_key="MeshFusedBatches", prom="banjax_mesh_fused_batches_total"),
@@ -289,14 +322,6 @@ FAMILIES: List[Family] = [
            line_key="RegexBanRecords",
            prom="banjax_regex_ban_records_total"),
     # ---- single-kernel fused path (kernels/fused_match_window.py) ----
-    Family(COUNTER, "chunks committed by the single-kernel fused "
-           "match+window program (one dispatch, one pull)",
-           line_key="SingleKernelChunks",
-           prom="banjax_single_kernel_chunks_total"),
-    Family(COUNTER, "single-kernel chunks routed to the classic replay "
-           "(in-kernel overflow or chain gate)",
-           line_key="SingleKernelFallbacks",
-           prom="banjax_single_kernel_fallbacks_total"),
     Family(GAUGE, "d2h bytes per committed single-kernel chunk (the "
            "one-pull witness: flags + pairs + events in ONE buffer)",
            line_key="SingleKernelD2hBytesPerBatch",
@@ -512,6 +537,11 @@ FAMILIES: List[Family] = [
     Family(GAUGE, "adaptive batch-size target (power-of-two bucket)",
            line_key="PipelineBatchTarget",
            prom="banjax_pipeline_batch_target"),
+    Family(COUNTER, "moves of the adaptive batch-size target, by "
+           "direction: up (doubled) or down (halved: over the budget, or "
+           "sent back by the efficiency guard)",
+           prom="banjax_pipeline_batch_target_changes_total",
+           labels=("direction",)),
     Family(GAUGE, "command-batch take bound",
            line_key="PipelineCommandBatchTarget",
            prom="banjax_pipeline_command_batch_target"),

@@ -208,10 +208,8 @@ class MatcherStats:
                     getattr(matcher, "effector_replay_s", 0.0), 6
                 )
                 # one program, one pull per chunk
-                out["SingleKernelChunks"] = fw.sk_chunks
-                out["SingleKernelFallbacks"] = fw.sk_fallbacks
                 out["SingleKernelD2hBytesPerBatch"] = round(
-                    fw.sk_d2h_bytes_total / max(1, fw.sk_chunks), 1
+                    fw.sk_d2h_bytes_total / max(1, fw.fused_batches), 1
                 )
             # traffic introspection plane (obs/sketch.py): the sampled
             # summary — pull() self-throttles to its sampling interval,

@@ -39,6 +39,15 @@ the XLA/TPU device timeline whenever a profiler session (the
 active; the annotations are no-ops otherwise.  The root batch span
 additionally wraps its submit stage in `StepTraceAnnotation` with the
 trace id as the step number, which Perfetto/xprof group per step.
+
+The submit stage from inside: `LapClock` splits the wall between the
+scheduler's `batch.t0_device` and the end of `pipeline_submit` into
+SUBMIT_PHASES, with the seconds its thread ran beside the seconds that
+passed.  The sums are exported whether tracing is on or off
+(`banjax_submit_phase_seconds_total{phase}`,
+`banjax_submit_cpu_seconds_total`); with tracing on
+each named phase is also a `submit-<phase>` child span of the batch's
+`submit` span, in the ring and through the JAX bridge.
 """
 
 from __future__ import annotations
@@ -53,6 +62,11 @@ DEFAULT_RING_SIZE = 4096
 
 # the five pipeline stage span names the acceptance test asserts on
 STAGES = ("admission", "encode", "encode-shard", "submit", "collect", "drain")
+
+# the submit stage's phases (LapClock); every second of the stage belongs
+# to exactly one, and `other` is what no mark names
+SUBMIT_PHASES = ("pass", "sketch", "operands", "maintenance", "dispatch",
+                 "other")
 
 
 class _NoopSpan:
@@ -172,6 +186,18 @@ class Tracer:
         t0_us = (span.t0 - self._epoch) * 1e6
         rec = (span.trace_id, span.span_id, span.parent_id, span.name,
                t0_us, dur_us, span._thread_name, span.args)
+        self._put(rec)
+
+    def record(self, name: str, parent: Span, t0: float, t1: float,
+               args: Optional[dict] = None) -> None:
+        """A finished child span of `parent` on this thread, from two
+        perf_counter stamps its caller already took (LapClock)."""
+        rec = (parent.trace_id, next(self._ids), parent.span_id, name,
+               (t0 - self._epoch) * 1e6, (t1 - t0) * 1e6,
+               parent._thread_name, args)
+        self._put(rec)
+
+    def _put(self, rec: tuple) -> None:
         with self._lock:
             self._ring[self._n % self.ring_size] = rec
             self._n += 1
@@ -208,9 +234,7 @@ class Tracer:
         t0_us = (time.perf_counter() - self._epoch) * 1e6
         rec = (trace_id, next(self._ids), 0, name, t0_us, None,
                threading.current_thread().name, dict(args) if args else None)
-        with self._lock:
-            self._ring[self._n % self.ring_size] = rec
-            self._n += 1
+        self._put(rec)
 
     def current_trace_id(self) -> int:
         """Trace id of the thread's ambient span (0 when none / off) —
@@ -335,6 +359,129 @@ class Tracer:
             )
         except Exception:  # noqa: BLE001
             return NOOP_SPAN
+
+
+# ---- the submit stage's lap clock -----------------------------------------
+
+
+class LapClock:
+    """The submit stage of one thread, split into SUBMIT_PHASES by marks.
+
+    One clock a thread, for the thread's life: `start(rows)` opens a
+    batch's stage, `mark(phase)` says what the thread does from now on,
+    and the wall (`time.perf_counter`) since the last mark goes to the
+    phase that was running.  No nesting: the phases partition the time
+    from a start to the last mark after it by construction, and `other`
+    is what runs before the first mark and after a `mark("other")`.
+    Beside the wall the stage has the seconds its thread ran, `cpu_s`:
+    the thread's own CPU clock (`time.thread_time`) read at a start and
+    at the end of the block that follows it, and nowhere between — it is
+    a system call, 17 us under load on a sandboxed kernel, and the
+    stage's thread sets the pipeline's rate.  Wall less cpu is the time
+    the thread did not run (waiting for the interpreter, a lock, the
+    device, a core).  `wall` and `cpu_s` are sums over every batch so
+    far, written by the clock's thread alone and read by whoever exports
+    them.
+
+    A mark costs one clock read and one attribute check, and allocates
+    nothing; it makes a span only between `under(parent)` and the end of
+    that block, where each named phase is a `submit-<phase>` child of
+    `parent` (never with tracing off).  A named phase must end — by the
+    next mark — before a context-manager span opened before it exits, so
+    that the JAX bridge's annotations nest."""
+
+    __slots__ = ("wall", "cpu_s", "phase", "t", "_c", "_rows", "_row0",
+                 "_parent", "_jax_ctx")
+
+    def __init__(self):
+        self.wall = dict.fromkeys(SUBMIT_PHASES, 0.0)
+        self.cpu_s = 0.0
+        self._parent = NOOP_SPAN
+        self._jax_ctx = None
+        self.start()
+
+    def start(self, rows: int = 0) -> float:
+        """A batch of `rows` lines enters the stage, in `other`; the wall
+        stamp that opens it."""
+        self.phase = "other"
+        self._rows = rows
+        self._row0 = None
+        self._c = time.thread_time()
+        self.t = time.perf_counter()  # the last mark's wall stamp
+        return self.t
+
+    def mark(self, phase: str, row0: Optional[int] = None) -> None:
+        t = time.perf_counter()
+        was = self.phase
+        self.wall[was] += t - self.t
+        if self._parent is not NOOP_SPAN:
+            self._span_edge(was, phase, t, row0)
+        self.phase = phase
+        self.t = t
+
+    def under(self, parent) -> "LapClock":
+        """`with clock.under(span):` — the named phases marked in the
+        block are child spans of `span` (the batch's `submit` span; the
+        shared no-op span when tracing is off), and the phase running at
+        its end ends with it."""
+        self._parent = parent
+        return self
+
+    def __enter__(self) -> "LapClock":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.mark("other")
+        self._parent = NOOP_SPAN
+        self.cpu_s += time.thread_time() - self._c
+
+    def _span_edge(self, was: str, phase: str, t: float, row0) -> None:
+        tracer = self._parent.tracer
+        if was != "other":
+            if self._jax_ctx is not None:
+                try:
+                    self._jax_ctx.__exit__(None, None, None)
+                except Exception:  # noqa: BLE001 — tracing must never raise
+                    pass
+                self._jax_ctx = None
+            args = {"rows": self._rows}
+            if self._row0 is not None:
+                args["row0"] = self._row0
+            tracer.record("submit-" + was, self._parent, self.t, t, args)
+        if row0 is not None:
+            self._row0 = row0
+        if phase != "other" and tracer.jax_annotations:
+            self._jax_ctx = tracer._enter_jax("submit-" + phase)
+
+
+# what a pipeline stage's thread says of itself as it starts: `.stage`,
+# the label its waits are counted under, and `.clock`, its lap clock
+_this_thread = threading.local()
+
+
+def stage_thread(stage: str, clock: Optional[LapClock] = None) -> None:
+    """Called by a pipeline stage's thread as it starts: `stage` is what
+    `thread_stage()` answers on it from now on, and `clock`, where the
+    stage has one, what `lap()` does."""
+    _this_thread.stage = stage
+    if clock is not None:
+        _this_thread.clock = clock
+
+
+def thread_stage() -> Optional[str]:
+    """The pipeline stage this thread said it runs; None on any other."""
+    return getattr(_this_thread, "stage", None)
+
+
+def lap() -> LapClock:
+    """This thread's lap clock: the one its stage handed over, or — the
+    sync entry, a direct call of the split protocol — one of the thread's
+    own, made at the first call, which nobody reads."""
+    try:
+        return _this_thread.clock
+    except AttributeError:
+        clock = _this_thread.clock = LapClock()
+        return clock
 
 
 # ---- process-wide tracer -------------------------------------------------

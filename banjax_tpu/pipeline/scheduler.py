@@ -211,6 +211,12 @@ class PipelineScheduler:
         self._q_drain: "queue.Queue" = queue.Queue()
         self._stop = threading.Event()
         self._threads: List[threading.Thread] = []
+        # the submit stage's phases, summed over this scheduler's life
+        # (the device thread marks, submit_phase_seconds reads)
+        self._lap = trace.LapClock()
+        # native ids of the encode pool's threads, each noted by the
+        # thread itself as it starts (thread_ids)
+        self._encode_worker_ids: List[int] = []
 
     @classmethod
     def from_config(cls, matcher_getter, config, health=None, on_results=None):
@@ -243,6 +249,9 @@ class PipelineScheduler:
             self._encode_pool = ThreadPoolExecutor(
                 max_workers=self.encode_workers,
                 thread_name_prefix="pipeline-encode-worker",
+                initializer=lambda: self._encode_worker_ids.append(
+                    threading.get_native_id()
+                ),
             )
         loops = [
             ("pipeline-encode", self._encode_loop),
@@ -272,6 +281,7 @@ class PipelineScheduler:
             # after the stage threads joined no new shard work can arrive
             self._encode_pool.shutdown(wait=True)
             self._encode_pool = None
+            self._encode_worker_ids = []
 
     def flush(self, timeout: float = 60.0) -> bool:
         """Block until every admitted line has drained (tests/bench)."""
@@ -562,6 +572,11 @@ class PipelineScheduler:
 
     def _device_loop(self) -> None:
         pending: deque = deque()  # submitted, awaiting collect (≤ 2)
+        # the stage from inside: the matcher's marks split the wall from
+        # a batch's t0_device to the end of its submit into phases
+        # (obs/trace.py LapClock)
+        lap = self._lap
+        trace.stage_thread("submit", lap)
         try:
             while True:
                 if pending:
@@ -596,13 +611,15 @@ class PipelineScheduler:
                         )
                         batch.state = None  # generic drain → CPU fallback
                     else:
-                        batch.t0_device = time.perf_counter()
+                        batch.t0_device = lap.start(len(batch.lines))
                         try:
                             failpoints.check("pipeline.submit")
                             with trace.span(
                                 "submit", batch.trace_id,
                                 parent=batch.root_span.span_id,
-                            ), trace.step_annotation(batch.trace_id):
+                            ) as sp, trace.step_annotation(
+                                batch.trace_id
+                            ), lap.under(sp):
                                 # matchers that commit state at submit
                                 # (the single-kernel fused path) take the
                                 # scheduler clock so the staleness cut
@@ -709,6 +726,7 @@ class PipelineScheduler:
     # ---- drain stage (admission order — the ordering contract) ----
 
     def _drain_loop(self) -> None:
+        trace.stage_thread("drain")
         while True:
             batch = self._q_drain.get()
             if batch is None:
@@ -860,6 +878,27 @@ class PipelineScheduler:
         out = self.stats.snapshot()
         out.update(self._sizer.snapshot())
         return self._live_gauges(out)
+
+    def thread_ids(self) -> dict:
+        """{label: native ids} of the pipeline's running threads — the
+        three stage threads by name and the encode pool as one label —
+        for whoever reads their clocks (obs/exposition.py, at scrape
+        time)."""
+        out = {t.name: [t.native_id] for t in self._threads
+               if t.name != "pipeline-probe" and t.native_id is not None}
+        if self._encode_worker_ids:
+            out["pipeline-encode-worker"] = list(self._encode_worker_ids)
+        return out
+
+    def submit_phase_seconds(self) -> tuple:
+        """({phase: wall seconds}, CPU seconds) of the submit stage over
+        every batch so far: the wall by phase, and what the device thread
+        ran of all of it."""
+        return dict(self._lap.wall), self._lap.cpu_s
+
+    def batch_target_changes(self) -> dict:
+        """{"up": n, "down": n}: moves of the sizer's batch target."""
+        return self._sizer.target_changes()
 
     def prom_snapshot(self) -> dict:
         """Non-destructive view for /metrics (obs/exposition.py): totals,

@@ -117,6 +117,8 @@ class AdaptiveBatchSizer:
         self._per_line_at: Dict[int, float] = {}
         self._blocked_grows = 0
         self._on_probation = False  # grew into this bucket, verdict open
+        # moves of the target — banjax_pipeline_batch_target_changes_total
+        self._changes = {"up": 0, "down": 0}
         # the first full batch after a bucket change pays that bucket's
         # one-time jit compile; learning from it would poison both the
         # latency EWMA and the per-line efficiency record
@@ -195,7 +197,7 @@ class AdaptiveBatchSizer:
             upper_pl = self._per_line_at.get(self._bucket << 1)
             if ewma > self.budget_ms and self._bucket > self.min_batch:
                 self._bucket >>= 1
-                self._reset_locked()
+                self._reset_locked("down")
             elif (
                 self._on_probation
                 and self._samples_at_bucket <= _PROBATION
@@ -205,7 +207,7 @@ class AdaptiveBatchSizer:
                 # latency fits, but this bucket is per-line WORSE than the
                 # one below: larger batches are not paying here — go back
                 self._bucket >>= 1
-                self._reset_locked()
+                self._reset_locked("down")
             elif ewma < self.budget_ms * 0.5 and self._bucket < self.max_batch:
                 if (
                     upper_pl is not None
@@ -219,14 +221,15 @@ class AdaptiveBatchSizer:
                         self._blocked_grows = 0
                     return
                 self._bucket <<= 1
-                self._reset_locked()
+                self._reset_locked("up")
                 # judged on what it shows now, not on an old visit
                 self._per_line_at.pop(self._bucket, None)
                 self._on_probation = True
 
-    def _reset_locked(self) -> None:
+    def _reset_locked(self, direction: str) -> None:
         # the bucket has just changed (a handful of times in a process's
-        # life once it has settled): say why
+        # life once it has settled): count it, and say why
+        self._changes[direction] += 1
         log.info(
             "batch target now %d lines (EWMA %.0f ms a batch over %d "
             "samples, budget %.0f ms)", self._bucket,
@@ -237,6 +240,11 @@ class AdaptiveBatchSizer:
         self._samples_at_bucket = 0
         self._skip_first = True
         self._on_probation = False
+
+    def target_changes(self) -> Dict[str, int]:
+        """{"up": n, "down": n}: how often the target has moved."""
+        with self._lock:
+            return dict(self._changes)
 
     def snapshot(self) -> Dict[str, object]:
         with self._lock:

@@ -607,8 +607,8 @@ def run_one_chip(args, sz: dict, outdir: str, jax) -> None:
             f"fallback_batches={matcher.fallback_batches} "
             f"pipelined_fused_chunks={matcher.pipelined_fused_chunks} "
             f"pipelined_fused_fallbacks={matcher.pipelined_fused_fallbacks} "
-            f"single_kernel_chunks={fw.sk_chunks if fw else 0} "
-            f"single_kernel_fallbacks={fw.sk_fallbacks if fw else 0} "
+            f"single_kernel_chunks={fw.fused_batches if fw else 0} "
+            f"single_kernel_fallbacks={fw.fallback_batches if fw else 0} "
             f"budget_trips={matcher.budget_trips} "
             f"generic_drains={st.fallback_batches} "
             f"breaker={matcher.breaker.state} "

@@ -117,7 +117,7 @@ def test_churn_stream_byte_identical_and_cpu_exact():
         assert result_key(c) == result_key(s), f"single-kernel diverged at {i}"
     _assert_identical("churn", sk_results, tp_results, sk, tp)
     assert sk[3].getvalue() == cpu_log.getvalue()
-    assert sk[0]._fw_pipeline.sk_chunks > 0, "single kernel never engaged"
+    assert sk[0]._fw_pipeline.fused_batches > 0, "single kernel never engaged"
 
 
 def test_eviction_churn_byte_identical():
@@ -156,8 +156,8 @@ def test_overflow_bursts_with_phase_gaps():
     tp_results, _ = _run_pipelined(tp[0], phases, {"now": now}, sizer_seed=5)
     _assert_identical("overflow", sk_results, tp_results, sk, tp)
     fw = sk[0]._fw_pipeline
-    assert fw.sk_fallbacks > 0, "overflow never hit the in-kernel gate"
-    assert fw.sk_chunks > 0, "chain never reseeded across phase gaps"
+    assert fw.fallback_batches > 0, "overflow never hit the in-kernel gate"
+    assert fw.fused_batches > 0, "chain never reseeded across phase gaps"
 
 
 def test_mixed_path_batches_keep_window_order():
@@ -183,7 +183,7 @@ def test_mixed_path_batches_keep_window_order():
     sk_results, _ = _run_pipelined(sk[0], [lines], {"now": now}, sizer_seed=21)
     tp_results, _ = _run_pipelined(tp[0], [lines], {"now": now}, sizer_seed=21)
     _assert_identical("mixed-path", sk_results, tp_results, sk, tp)
-    assert sk[0]._fw_pipeline.sk_chunks > 0
+    assert sk[0]._fw_pipeline.fused_batches > 0
     # the drain-apply gate fully released (no leaked slots)
     assert sk[0]._drain_window_batches == 0
     assert tp[0]._drain_window_batches == 0
@@ -266,7 +266,7 @@ def test_breaker_trip_mid_stream_identical():
     tp_results = run(tp[0])
     _assert_identical("breaker", sk_results, tp_results, sk, tp)
     assert sk[0].fallback_batches > 0  # phase 2 really took the CPU path
-    assert sk[0]._fw_pipeline.sk_chunks > 0
+    assert sk[0]._fw_pipeline.fused_batches > 0
 
 
 def test_mid_pipeline_abort_identical():
@@ -313,4 +313,4 @@ def test_mid_pipeline_abort_identical():
     sk_results = run(sk[0], seed=9)
     tp_results = run(tp[0], seed=9)
     _assert_identical("abort", sk_results, tp_results, sk, tp)
-    assert sk[0]._fw_pipeline.sk_chunks > 0
+    assert sk[0]._fw_pipeline.fused_batches > 0

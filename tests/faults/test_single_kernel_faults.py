@@ -160,7 +160,7 @@ def test_abandon_after_fallback_cannot_double_release_pins():
     entries = list(s["fused"])
     m.pipeline_collect(s)
     results, _ = m.pipeline_finish(s, now)  # overflow → classic fallback
-    assert m._fw_pipeline.sk_fallbacks > 0
+    assert m._fw_pipeline.fallback_batches > 0
     # teardown replays the settled entries through abandon: a no-op
     for e in entries:
         m._fw_pipeline.abandon(e["pend"])

@@ -120,14 +120,18 @@ def span_names():
 @pytest.mark.parametrize(
     "span",
     _host_spans() + ["program-ab-fused", "effector-replay", "submit-resolve",
-                     "rules-compile"],
+                     "rules-compile", "submit-pass", "submit-sketch",
+                     "submit-operands", "submit-maintenance",
+                     "submit-dispatch"],
 )
 def test_host_span_the_benchmark_names_is_opened(span_names, span):
     """`benchmark/trace_names.json` `host_spans`, and the documented spans
     of the fused path beside them: the program's dispatch, the drain's
     replay, the submit stage's one pass over a batch's addresses, whose
-    seconds `resolve_ms_per_kline` reads, and the start's `rules-compile`
-    (ISSUE 33), whose seconds are `banjax_rules_compile_seconds`."""
+    seconds `resolve_ms_per_kline` reads, the start's `rules-compile`
+    (ISSUE 33), whose seconds are `banjax_rules_compile_seconds`, and the
+    submit stage's five named phases (ISSUE 39), whose seconds are
+    `banjax_submit_phase_seconds_total`."""
     assert span in span_names, sorted(span_names)
 
 
@@ -404,6 +408,173 @@ def test_shadow_family_is_on_metrics_by_op_and_path(shadow_scrape, op):
         assert got == tallies[op] > 0
     assert tallies["absorb"] == prom.value(
         snap, "banjax_device_windows_events_total")
+
+
+@pytest.fixture(scope="module")
+def submit_scrapes():
+    """Two scrapes of `/metrics` around a stream through the scheduler
+    and the fused matcher, the second with the pipeline's threads still
+    running: the submit stage from inside (ISSUE 39)."""
+    from banjax_tpu.decisions.dynamic_lists import DynamicDecisionLists
+    from banjax_tpu.decisions.rate_limit import (
+        FailedChallengeRateLimitStates,
+    )
+    from banjax_tpu.obs.exposition import render_prometheus
+    from benchmark.harness import prom
+
+    cfg = config_from_yaml_text(_RULES)
+    cfg.matcher_device_windows = True
+    cfg.matcher_window_capacity = 256
+    cfg.warm_tier_enabled = True
+    cfg.warm_tier_capacity = 1024
+    m = TpuMatcher(cfg, MockBanner(), StaticDecisionLists(cfg),
+                   RegexRateLimitStates())
+    now = time.time()
+    sched = PipelineScheduler(lambda: m, now_fn=lambda: now)
+
+    def scrape():
+        return prom.parse(render_prometheus(
+            DynamicDecisionLists(start_sweeper=False), RegexRateLimitStates(),
+            FailedChallengeRateLimitStates(), matcher=m, pipeline=sched,
+        ))
+
+    sched.start()
+    before = scrape()
+    t0 = time.time()
+    for k in range(6):
+        sched.submit([
+            f"{now:.6f} 1.2.{k}.{i} GET h.com GET "
+            f"/{'attack' if i % 10 == 0 else 'page'}{i} HTTP/1.1 ua -"
+            for i in range(100)
+        ])
+        assert sched.flush(120)
+    after = scrape()
+    seconds = time.time() - t0
+    sched.stop()
+    m.close()
+    return before, after, seconds
+
+
+_PIPELINE_THREADS = ("pipeline-encode", "pipeline-device", "pipeline-drain")
+_SUBMIT_FAMILIES = {
+    "banjax_submit_phase_seconds_total":
+        [{"phase": p} for p in trace.SUBMIT_PHASES],
+    "banjax_submit_cpu_seconds_total": [{}],
+    "banjax_windows_lock_wait_seconds_total":
+        [{"stage": s} for s in ("submit", "drain")],
+    "banjax_windows_lock_contended_total":
+        [{"stage": s} for s in ("submit", "drain")],
+    "banjax_thread_cpu_seconds_total":
+        [{"thread": t} for t in _PIPELINE_THREADS],
+    "banjax_pipeline_batch_target_changes_total":
+        [{"direction": "up"}, {"direction": "down"}],
+}
+
+
+@pytest.mark.parametrize("family", sorted(_SUBMIT_FAMILIES))
+def test_submit_stage_family_is_on_metrics_with_its_labels(
+        submit_scrapes, family):
+    """The families ISSUE 39 brought, off `/metrics` through the
+    benchmark's own parser: declared, and exported with every label the
+    readers select by while the pipeline's threads run."""
+    from benchmark.harness import prom
+
+    _, snap, _ = submit_scrapes
+    assert family in {f.prom for f in registry.FAMILIES}
+    for labels in _SUBMIT_FAMILIES[family]:
+        assert prom.value(snap, family, **labels) is not None, labels
+    if family == "banjax_submit_phase_seconds_total":
+        for phase in trace.SUBMIT_PHASES:
+            if phase != "other":
+                assert prom.value(snap, family, phase=phase) > 0, phase
+    if family == "banjax_submit_cpu_seconds_total":
+        # the thread never ran longer than the wall its stage took
+        wall = prom.value(snap, "banjax_submit_phase_seconds_total")
+        assert 0 < prom.value(snap, family) <= wall + 1e-3
+    if family == "banjax_thread_cpu_seconds_total":
+        assert prom.value(snap, family, thread="pipeline-device") > 0
+
+
+def test_submit_phases_sum_to_the_device_stage(submit_scrapes):
+    """The lap clock's six phases partition the submit stage: their wall
+    is the scheduler's own `stage="device"` sum less the collects, which
+    on this stream are a few per cent of it at most."""
+    from benchmark.harness import prom
+
+    _, snap, _ = submit_scrapes
+    phases = prom.value(snap, "banjax_submit_phase_seconds_total")
+    stage = prom.value(snap, "banjax_stage_duration_seconds_sum",
+                       stage="device")
+    assert 0.95 * stage <= phases <= stage
+    assert prom.value(snap, "banjax_submit_phase_seconds_total",
+                      phase="other") < 0.10 * phases
+    # `resolve` keeps its extent: the pass and the sketch's note in it
+    resolve = prom.value(snap, "banjax_submit_resolve_seconds_total")
+    by = {p: prom.value(snap, "banjax_submit_phase_seconds_total",
+                        phase=p) for p in ("pass", "sketch")}
+    assert by["pass"] <= resolve + 1e-3 <= by["pass"] + by["sketch"] + 2e-3
+
+
+def _scrape_pair(family, label_sets, before, after):
+    """Two synthetic scrapes: every label set of `family` goes from
+    `before` to `after`, beside 10,000 lines drained."""
+    lines = ("banjax_pipeline_processed_lines_total", ())
+    p0, p1 = {lines: 5_000.0}, {lines: 15_000.0}
+    for labels in label_sets:
+        key = (family, tuple(sorted(labels.items())))
+        p0[key], p1[key] = before, after
+    return p0, p1
+
+
+_PHASE = "banjax_submit_phase_seconds_total"
+_NEW_READERS = {
+    # reader: (family, label sets the scrapes carry, before, after, reading)
+    **{f"submit_{p}_ms_per_kline":
+       (_PHASE, [{"phase": p}], 1.0, 1.5, 50.0)
+       for p in trace.SUBMIT_PHASES},
+    # six phases: 3 s more on the wall, 2.4 s of them on the thread's clock
+    "submit_wait_share":
+        (_PHASE, [{"phase": p} for p in trace.SUBMIT_PHASES],
+         1.0, 1.5, None),
+    "windows_lock_wait_ms_per_kline":
+        ("banjax_windows_lock_wait_seconds_total", [{"stage": "submit"}],
+         0.25, 0.5, 25.0),
+    "pipeline_cores_busy":
+        ("banjax_thread_cpu_seconds_total",
+         [{"thread": t} for t in _PIPELINE_THREADS], 5.0, 25.0, 1.5),
+    "batch_bucket_changes":
+        ("banjax_pipeline_batch_target_changes_total",
+         [{"direction": "up"}, {"direction": "down"}], 3.0, 4.0, 2.0),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_NEW_READERS))
+def test_submit_stage_reader_reads_its_family(submit_scrapes, name):
+    """Each of the ten per-layer readers PR 39 brought (ISSUE 39's
+    eleventh, `device_thread_runqueue_share`, waits for a host that keeps
+    a `schedstat`: PERF.md §7): listed in BENCHMARK.json for every cell,
+    reads its family from two synthetic scrapes 40 s apart, reads
+    something off a real pair, and is silent on a program without the
+    family."""
+    from benchmark.harness import found
+
+    with open(os.path.join(_REPO, "BENCHMARK.json"), encoding="utf-8") as f:
+        listed = {m["name"]: m for m in json.load(f)["per_layer"]}
+    entry = listed[name]
+    assert entry["moves"] == "lines_per_s" and "workloads" not in entry
+    family, label_sets, before, after, want = _NEW_READERS[name]
+    p0, p1 = _scrape_pair(family, label_sets, before, after)
+    if name == "submit_wait_share":
+        key = ("banjax_submit_cpu_seconds_total", ())
+        p0[key], p1[key] = 3.0, 5.4
+        want = pytest.approx(100 * (3.0 - 2.4) / 3.0)
+    reader = found.module("layers", name)
+    ctx = {"prom0": p0, "prom1": p1, "seconds": 40.0}
+    assert reader.read(ctx) == want
+    assert reader.read({**ctx, "prom0": {}, "prom1": {}}) is None
+    real0, real1, seconds = submit_scrapes
+    got = reader.read({"prom0": real0, "prom1": real1, "seconds": seconds})
+    assert got is not None and got >= 0
 
 
 def test_stage2_readers_split_a_trace_by_nfa_words():

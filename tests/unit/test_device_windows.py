@@ -288,6 +288,8 @@ def test_varying_batch_sizes_share_one_compile():
     dw = DeviceWindows(rules, capacity=64)
     active = np.ones((1, 1), dtype=bool)
     base = 1_700_000_000 * NS
+    # whatever an earlier test of this process built: counted from none
+    W._apply_step.clear_cache()
     count_before = W._apply_step._cache_size()
     for i, b in enumerate([1, 3, 5, 17, 33, 63, 64]):  # all bucket to 64
         bits = np.ones((b, 1), dtype=np.uint8)
@@ -794,3 +796,58 @@ def test_gate_passes_generations_through(gate):
     else:
         assert (after.key_gen.reshape(2, 2)
                 == after.slot_gen[:, None]).all()
+
+
+@pytest.mark.parametrize("stage", ["submit", None],
+                         ids=["stage-thread", "undeclared-thread"])
+def test_a_wait_for_the_windows_lock_is_counted_under_the_waiters_stage(stage):
+    """`DeviceWindows._lock` times only an acquire that finds it held, and
+    books the wait under the stage the waiting thread said it runs
+    (`trace.stage_thread`): the drain holds it for 50 ms, the submit
+    stage's thread asks meanwhile — one contention and 40-60 ms under
+    `submit`, nothing elsewhere; a waiting thread that declared no stage
+    is not counted, and an acquire that finds the lock free reads no
+    clock."""
+    import threading
+    import time
+
+    from banjax_tpu.obs import trace
+
+    dw = DeviceWindows([make_rule("r", 5, 2)], capacity=16)
+    holding, waited = threading.Event(), []
+
+    def drain():
+        trace.stage_thread("drain")
+        with dw._lock:
+            holding.set()
+            time.sleep(0.05)
+
+    def waiter():
+        if stage is not None:
+            trace.stage_thread(stage)
+        assert trace.thread_stage() == stage
+        assert holding.wait(10)
+        t0 = time.perf_counter()
+        with dw._lock:
+            waited.append(time.perf_counter() - t0)
+
+    threads = [threading.Thread(target=drain),
+               threading.Thread(target=waiter)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(10)
+        assert not t.is_alive()
+    with dw._lock:      # free: not timed, whoever asks
+        pass
+    got = dw.lock_waits()
+    assert waited[0] >= 0.040
+    assert set(got) == {"submit", "drain"} and got["drain"] == (0.0, 0)
+    if stage is None:
+        assert got["submit"] == (0.0, 0)
+        return
+    seconds, contentions = got["submit"]
+    assert contentions == 1 and 0.040 <= seconds <= 0.060
+    assert seconds <= waited[0]
+    # the operator's reading: the mean wait of one contention
+    assert seconds / contentions == pytest.approx(0.05, abs=0.01)

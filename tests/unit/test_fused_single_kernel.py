@@ -152,7 +152,7 @@ def test_threshold_fire_edges(offsets, hits):
     assert cb.bans == tb.bans and cb.regex_ban_logs == tb.regex_ban_logs
     assert cpu.rate_limit_states.format_states() == \
         tpu.device_windows.format_states()
-    assert tpu._fw_pipeline.sk_chunks > 0  # really took the single kernel
+    assert tpu._fw_pipeline.fused_batches > 0  # really took the single kernel
 
 
 # ---------------------------------------------------------------------------
@@ -186,7 +186,7 @@ def test_event_overflow_flag_routes_to_classic_fallback(monkeypatch):
     got = tpu.consume_lines(lines, now + 1)
     assert [_key(a) for a in want] == [_key(b) for b in got]
     assert cb.bans == tb.bans
-    assert tpu._fw_pipeline.sk_fallbacks > 0
+    assert tpu._fw_pipeline.fallback_batches > 0
     assert tpu._fw_pipeline.overflow_causes["events"] > 0
 
 
@@ -214,7 +214,7 @@ def test_candidate_overflow_flag_with_tight_slot_capacity():
     got = tpu.consume_lines(lines, now + 1)
     assert [_key(a) for a in want] == [_key(b) for b in got]
     assert cb.bans == tb.bans
-    assert tpu._fw_pipeline.sk_fallbacks > 0
+    assert tpu._fw_pipeline.fallback_batches > 0
     assert tpu.device_windows.eviction_count > 0
     assert cpu.rate_limit_states.format_states() == \
         tpu.device_windows.format_states()
@@ -236,14 +236,14 @@ def test_chain_reseeds_after_quiescence():
         for i in range(128)
     ]
     tpu.consume_lines(flood, now)  # every chunk overflows candidates
-    assert tpu._fw_pipeline.sk_fallbacks > 0
+    assert tpu._fw_pipeline.fallback_batches > 0
     benign = [
         f"{now:.6f} 8.8.8.{i % 9} GET h.com GET /quiet{i} HTTP/1.1 ua -"
         for i in range(64)
     ]
-    before = tpu._fw_pipeline.sk_chunks
+    before = tpu._fw_pipeline.fused_batches
     tpu.consume_lines(benign, now)  # quiescent start → fresh chain
-    assert tpu._fw_pipeline.sk_chunks > before, "chain never reseeded"
+    assert tpu._fw_pipeline.fused_batches > before, "chain never reseeded"
 
 
 # ---------------------------------------------------------------------------

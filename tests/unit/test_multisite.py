@@ -168,7 +168,13 @@ def test_the_cell_is_the_issues():
         "batch_lines_mean", "encode_ms_per_kline", "devstage_ms_per_kline",
         "fused_fallback_share", "builds_in_window", "evictions_per_kline",
         "drain_ms_per_kline", "device_idle_share", "site_pairs_per_kline",
-        "pairs_overflow_share", "site_events_share"])
+        "pairs_overflow_share", "site_events_share",
+        # the submit stage from inside (ISSUE 39): every cell
+        "submit_pass_ms_per_kline", "submit_sketch_ms_per_kline",
+        "submit_operands_ms_per_kline", "submit_maintenance_ms_per_kline",
+        "submit_dispatch_ms_per_kline", "submit_other_ms_per_kline",
+        "submit_wait_share", "windows_lock_wait_ms_per_kline",
+        "pipeline_cores_busy", "batch_bucket_changes"])
     pc = CONFIG["product_config"]
     assert {k: v for k, v in pc.items() if k != "config_version"} == {
         k: v for k, v in found.data("configs", "upstream-stress10k")[
