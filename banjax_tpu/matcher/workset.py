@@ -32,26 +32,68 @@ from banjax_tpu.matcher.api import ConsumeLineResult
 from banjax_tpu.matcher.encode import ParsedLine
 
 
+class _Row:
+    """What was written to one row of a LazyResults.  It knows neither
+    the vector nor its index, so a batch's rows and its vector die by
+    reference count, with nothing left for the cyclic collector."""
+
+    __slots__ = ("error", "old_line", "exempted", "rr")
+
+    def __init__(self):
+        self.error = self.old_line = self.exempted = False
+        self.rr = None  # the rule_results list, once something asks for it
+
+
 class _LineResult(ConsumeLineResult):
-    """A ConsumeLineResult of a LazyResults: `error`/`old_line`/
-    `exempted` are set when they are decided, `rule_results` may still be
+    """One row of a LazyResults, as a view: it holds the vector and the
+    row's index and no state of its own, the vector holds no view.  So
+    reading a flag of every row of a batch leaves no object behind — a
+    view dies when its reader drops it — and one that a reader keeps
+    stays true (it keeps its vector alive).  `rule_results` may still be
     owed by a replayed chunk (LazyResults.defer) and is filled the first
     time anything reads it."""
 
-    def __init__(self, owner: "LazyResults"):
-        self.error = self.old_line = self.exempted = False
+    def __init__(self, owner: "LazyResults", i: int):
         self._owner = owner
-        self._rr: list = []
+        self._i = i
+
+    @property
+    def error(self) -> bool:
+        row = self._owner._items[self._i]
+        return False if row is None else row.error
+
+    @error.setter
+    def error(self, value: bool) -> None:
+        self._owner._row(self._i).error = value
+
+    @property
+    def old_line(self) -> bool:
+        row = self._owner._items[self._i]
+        return False if row is None else row.old_line
+
+    @old_line.setter
+    def old_line(self, value: bool) -> None:
+        self._owner._row(self._i).old_line = value
+
+    @property
+    def exempted(self) -> bool:
+        row = self._owner._items[self._i]
+        return False if row is None else row.exempted
+
+    @exempted.setter
+    def exempted(self, value: bool) -> None:
+        self._owner._row(self._i).exempted = value
 
     @property
     def rule_results(self) -> list:
-        if self._owner._deferred:
-            self._owner.flush()
-        return self._rr
+        owner = self._owner
+        if owner._deferred:
+            owner.flush()
+        return owner.owed(self._i)
 
     @rule_results.setter
     def rule_results(self, value: list) -> None:
-        self._rr = value
+        self._owner._row(self._i).rr = value
 
     def __eq__(self, other):
         if not isinstance(other, ConsumeLineResult):
@@ -66,17 +108,20 @@ class _LineResult(ConsumeLineResult):
 
 
 class LazyResults:
-    """List-compatible ConsumeLineResult vector that materializes entries
-    on first access. consume_lines must return one result per line, but
-    production (cli._consume_lines) only reads them in debug mode — eager
-    construction of 65k dataclasses per batch costs more than the whole
-    vectorized gate.  The same goes for the entries' `rule_results`: a
-    replayed chunk hands in a fill (`defer`) that runs when one is read."""
+    """List-compatible ConsumeLineResult vector that keeps a record only
+    for the rows something wrote to. consume_lines must return one result
+    per line, but production (cli._consume_lines) only reads them in
+    debug mode, and the pipeline's observer reads a flag of each —
+    eager construction of 65k dataclasses per batch costs more than the
+    whole vectorized gate, and an object kept per row read is a survivor
+    of every young collection, which brings the collector's full passes
+    on.  The same goes for the entries' `rule_results`: a replayed chunk
+    hands in a fill (`defer`) that runs when one is read."""
 
     __slots__ = ("_items", "_n_set", "_deferred")
 
     def __init__(self, n: int):
-        self._items = [None] * n
+        self._items = [None] * n  # a _Row where something was written
         self._n_set = 0
         self._deferred: list = []
 
@@ -90,39 +135,50 @@ class LazyResults:
         for fill in fills:
             fill(self)
 
+    def _row(self, i: int) -> _Row:
+        row = self._items[i]
+        if row is None:
+            row = self._items[i] = _Row()
+            self._n_set += 1
+        return row
+
     def owed(self, i: int) -> list:
         """Entry i's rule_results list, for a fill to append to."""
-        return self[i]._rr
+        row = self._row(i)
+        if row.rr is None:
+            row.rr = []
+        return row.rr
 
     def __len__(self) -> int:
         return len(self._items)
 
     def __getitem__(self, i):
         if isinstance(i, slice):
-            return [self[k] for k in range(*i.indices(len(self._items)))]
-        r = self._items[i]
-        if r is None:
-            r = self._items[i] = _LineResult(self)
-            self._n_set += 1
-        return r
+            return [
+                _LineResult(self, k)
+                for k in range(*i.indices(len(self._items)))
+            ]
+        self._items[i]  # an index out of range raises as a list's does
+        return _LineResult(self, i)
 
     def __iter__(self):
-        for k in range(len(self._items)):
-            yield self[k]
+        n = len(self._items)
+        return map(_LineResult, itertools.repeat(self, n), range(n))
 
     def absorb(self, other: "LazyResults", row0: int) -> None:
-        """Copy `other`'s MATERIALIZED entries in at row offset `row0`
-        (the sharded-encode merge step); untouched rows stay lazy.  A
-        shard of clean traffic materializes nothing during the gate —
-        the counter makes that common case O(1) instead of a scan."""
+        """Take over what was written to `other`'s rows, at row offset
+        `row0` (the sharded-encode merge step); untouched rows stay
+        untouched.  A shard of clean traffic writes nothing during the
+        gate — the counter makes that common case O(1) instead of a
+        scan."""
         if other._n_set == 0:
             return
         dst = self._items
-        for i, r in enumerate(other._items):
-            if r is not None:
-                r._owner = self
-                dst[row0 + i] = r
-        self._n_set += other._n_set
+        for i, row in enumerate(other._items):
+            if row is not None:
+                if dst[row0 + i] is None:
+                    self._n_set += 1
+                dst[row0 + i] = row
 
 
 class LazyLine:

@@ -24,6 +24,8 @@ from typing import Optional
 
 import numpy as np
 
+from banjax_tpu.native.cptr import array_ptr
+
 log = logging.getLogger(__name__)
 
 FLAG_ERROR = 1
@@ -228,10 +230,8 @@ def parse_encode_batch(
     i64p = ctypes.POINTER(ctypes.c_int64)
     i32p = ctypes.POINTER(ctypes.c_int32)
 
-    def P(a, t):
-        return a.ctypes.data_as(t)
-
-    blob_ptr = buf.ctypes.data_as(u8p) if buf.size else ctypes.cast(
+    P = array_ptr
+    blob_ptr = array_ptr(buf, u8p) if buf.size else ctypes.cast(
         ctypes.c_char_p(b""), u8p
     )
     got = lib.fp_split_lines(blob_ptr, len(blob), P(starts, i64p), P(ends, i64p), n)
@@ -320,10 +320,10 @@ def dedup_spans(blob, offs, lens, scratch=None):
     i32p = ctypes.POINTER(ctypes.c_int32)
     tcap = len(s.table)
     n_uniq = lib.fp_dedup_spans(
-        buf.ctypes.data_as(u8p), len(blob),
-        offs.ctypes.data_as(i64p), lens.ctypes.data_as(i32p), n,
-        s.table.ctypes.data_as(i64p), tcap,
-        s.ids.ctypes.data_as(i64p), s.first.ctypes.data_as(i64p),
+        array_ptr(buf, u8p), len(blob),
+        array_ptr(offs, i64p), array_ptr(lens, i32p), n,
+        array_ptr(s.table, i64p), tcap,
+        array_ptr(s.ids, i64p), array_ptr(s.first, i64p),
     )
     # copies, NOT views: a second dedup with the same scratch (the gate
     # runs ip then host spans back to back) must not clobber the first

@@ -28,6 +28,7 @@ from typing import List, Optional, Tuple
 import numpy as np
 
 from banjax_tpu.decisions.rate_limit import RateLimitMatchType, RateLimitResult
+from banjax_tpu.native.cptr import array_ptr
 
 log = logging.getLogger(__name__)
 
@@ -294,8 +295,8 @@ class ShmFailedChallengeStates:
         i32p = ctypes.POINTER(ctypes.c_int32)
         i64p = ctypes.POINTER(ctypes.c_int64)
         n = self._lib.fc_snapshot(
-            self._base(), blob, key_lens.ctypes.data_as(i32p),
-            hits.ctypes.data_as(i32p), starts.ctypes.data_as(i64p), cap,
+            self._base(), blob, array_ptr(key_lens, i32p),
+            array_ptr(hits, i32p), array_ptr(starts, i64p), cap,
         )
         out = []
         for i in range(int(n)):
@@ -463,8 +464,8 @@ class ShmWarmTier:
         i32p = ctypes.POINTER(ctypes.c_int32)
         i64p = ctypes.POINTER(ctypes.c_int64)
         self._ptrs = (
-            self._rid.ctypes.data_as(i32p), self._hits.ctypes.data_as(i32p),
-            self._ss.ctypes.data_as(i64p), self._sns.ctypes.data_as(i64p),
+            array_ptr(self._rid, i32p), array_ptr(self._hits, i32p),
+            array_ptr(self._ss, i64p), array_ptr(self._sns, i64p),
         )
 
     @property
@@ -532,8 +533,8 @@ class ShmWarmTier:
         u8p = ctypes.POINTER(ctypes.c_uint8)
         i64p = ctypes.POINTER(ctypes.c_int64)
         return (buf, offs, lens), (
-            buf.ctypes.data_as(u8p), offs.ctypes.data_as(i64p),
-            lens.ctypes.data_as(i64p),
+            array_ptr(buf, u8p), array_ptr(offs, i64p),
+            array_ptr(lens, i64p),
         )
 
     def contains_batch(self, ips, spans=None) -> np.ndarray:
@@ -548,7 +549,7 @@ class ShmWarmTier:
         _keep, ptrs = self._spans(ips, spans)
         self._lib.wt_contains_batch(
             base, *ptrs, n,
-            out.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
+            array_ptr(out, ctypes.POINTER(ctypes.c_uint8)),
         )
         return out.astype(bool)
 
@@ -564,7 +565,7 @@ class ShmWarmTier:
         key_lens = np.zeros(cap, dtype=np.int32)
         i32p = ctypes.POINTER(ctypes.c_int32)
         n = int(self._lib.wt_snapshot_keys(
-            base, blob, key_lens.ctypes.data_as(i32p), cap
+            base, blob, array_ptr(key_lens, i32p), cap
         ))
         out = []
         for i in range(n):
@@ -748,7 +749,7 @@ def _p(a: np.ndarray):
     of another dtype than the call declares is refused by ctypes)."""
     if not a.flags.c_contiguous:
         raise ValueError("a native call needs a contiguous array")
-    return a.ctypes.data_as(_PTR[a.dtype])
+    return array_ptr(a, _PTR[a.dtype])
 
 
 def _i32(a) -> np.ndarray:

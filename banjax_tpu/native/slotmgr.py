@@ -21,6 +21,8 @@ from typing import Optional, Sequence, Tuple
 
 import numpy as np
 
+from banjax_tpu.native.cptr import array_ptr
+
 log = logging.getLogger(__name__)
 
 _SRC = os.path.join(os.path.dirname(__file__), "slotmgr.c")
@@ -37,8 +39,7 @@ _i32p = ctypes.POINTER(ctypes.c_int32)
 _i64p = ctypes.POINTER(ctypes.c_int64)
 
 
-def _P(a: np.ndarray, t):
-    return a.ctypes.data_as(t)
+_P = array_ptr
 
 
 def _so_path() -> str:
@@ -167,7 +168,7 @@ def crc32_spans(enc) -> Optional[np.ndarray]:
     if len(offs):
         lib.sm_crc32_batch(
             _P(buf, _u8p), _P(offs, _i64p), _P(lens, _i64p), len(offs),
-            out.ctypes.data_as(ctypes.POINTER(ctypes.c_uint32)),
+            array_ptr(out, ctypes.POINTER(ctypes.c_uint32)),
         )
     return out
 

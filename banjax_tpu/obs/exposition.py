@@ -220,8 +220,9 @@ def render_prometheus(
             w.sample(wait_fam, round(seconds, 6), {"stage": stage})
             w.sample(n_fam, n, {"stage": stage})
 
-    # the submit stage from inside, the sizer's moves, and what each
-    # pipeline thread got of a core
+    # the submit stage from inside, the sizer's moves, what each
+    # pipeline thread got of a core, and what the cyclic collector took
+    # from all of them
     if pipeline is not None:
         wall_by_phase, cpu_s = pipeline.submit_phase_seconds()
         fam = registry.PROM_FAMILIES["banjax_submit_phase_seconds_total"]
@@ -234,6 +235,17 @@ def render_prometheus(
         for direction, n in pipeline.batch_target_changes().items():
             w.sample(fam, n, {"direction": direction})
         _thread_cpu_samples(w, pipeline.thread_ids())
+        heap = pipeline.collector_stats()
+        for name, key in (
+            ("banjax_gc_collections_total", "collections"),
+            ("banjax_gc_pause_seconds_total", "pause_s"),
+            ("banjax_gc_collected_objects_total", "collected"),
+        ):
+            fam = registry.PROM_FAMILIES[name]
+            for generation, v in enumerate(heap[key]):
+                w.sample(fam, round(v, 6), {"generation": str(generation)})
+        w.sample(registry.PROM_FAMILIES["banjax_gc_frozen_objects"],
+                 heap["frozen"])
         # per-worker encode busy fractions (prom-only labeled gauge)
         fracs = pipeline.stats.worker_busy_fractions()
         if fracs:

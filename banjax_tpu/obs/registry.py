@@ -247,6 +247,24 @@ FAMILIES: List[Family] = [
            "pipeline-encode-worker (the pool as one), pipeline-device, "
            "pipeline-drain",
            prom="banjax_thread_cpu_seconds_total", labels=("thread",)),
+    # ---- the cyclic collector under the pipeline (pipeline/heap.py) ----
+    Family(COUNTER, "collections the cyclic garbage collector ran while "
+           "the pipeline was up, by generation (2 = a full pass over "
+           "everything not frozen)",
+           prom="banjax_gc_collections_total", labels=("generation",)),
+    Family(COUNTER, "seconds those collections took, by generation: every "
+           "thread of the process is stopped for them, so their sum over "
+           "a stretch of time is the share of it nothing ran",
+           prom="banjax_gc_pause_seconds_total", labels=("generation",)),
+    Family(COUNTER, "objects those collections freed (garbage that only "
+           "the collector could free: reference cycles), by generation",
+           prom="banjax_gc_collected_objects_total",
+           labels=("generation",)),
+    Family(GAUGE, "objects in the collector's permanent generation: the "
+           "start-up heap (JAX, the rules, the device programs), frozen "
+           "out of the full passes' reach once the matcher is built and "
+           "again once programs have stopped being built; 0 = not frozen",
+           prom="banjax_gc_frozen_objects"),
     # ---- mesh ----
     Family(COUNTER, "sharded-mesh batches served by the fused two-stage path",
            line_key="MeshFusedBatches", prom="banjax_mesh_fused_batches_total"),

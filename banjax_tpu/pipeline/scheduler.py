@@ -89,6 +89,7 @@ from typing import Callable, List, Optional, Sequence
 
 from banjax_tpu.obs import flightrec, trace
 from banjax_tpu.obs.stats import PipelineStats
+from banjax_tpu.pipeline.heap import HeapKeeper
 from banjax_tpu.pipeline.sizer import AdaptiveBatchSizer
 from banjax_tpu.resilience import failpoints
 from banjax_tpu.resilience.breaker import OPEN
@@ -214,6 +215,9 @@ class PipelineScheduler:
         # the submit stage's phases, summed over this scheduler's life
         # (the device thread marks, submit_phase_seconds reads)
         self._lap = trace.LapClock()
+        # the cyclic collector's pauses, counted, and the start-up heap
+        # kept out of its full passes (pipeline/heap.py)
+        self._heap = HeapKeeper()
         # native ids of the encode pool's threads, each noted by the
         # thread itself as it starts (thread_ids)
         self._encode_worker_ids: List[int] = []
@@ -243,6 +247,7 @@ class PipelineScheduler:
     # ---- lifecycle ----
 
     def start(self) -> None:
+        self._heap.start()
         if self.encode_workers > 0 and self._encode_pool is None:
             from concurrent.futures import ThreadPoolExecutor
 
@@ -282,6 +287,7 @@ class PipelineScheduler:
             self._encode_pool.shutdown(wait=True)
             self._encode_pool = None
             self._encode_worker_ids = []
+        self._heap.stop()
 
     def flush(self, timeout: float = 60.0) -> bool:
         """Block until every admitted line has drained (tests/bench)."""
@@ -473,9 +479,12 @@ class PipelineScheduler:
         # the getter builds the matcher on first use / hot reload (rule
         # compile, kernel self-tests): start-up, not this batch's encode
         matcher = self._matcher_getter()
-        t0 = time.perf_counter()
         batch.matcher = matcher
         batch.builds0 = self._builds(batch)
+        # start-up too: a new matcher, or programs that have just stopped
+        # being built, is where the heap is collected whole and frozen
+        self._heap.observe(matcher, batch.builds0)
+        t0 = time.perf_counter()
         breaker = getattr(matcher, "breaker", None)
         with trace.span("encode", batch.trace_id,
                         parent=batch.root_span.span_id) as sp:
@@ -895,6 +904,12 @@ class PipelineScheduler:
         every batch so far: the wall by phase, and what the device thread
         ran of all of it."""
         return dict(self._lap.wall), self._lap.cpu_s
+
+    def collector_stats(self) -> dict:
+        """The cyclic collector since start(): collections, seconds
+        paused and objects freed, each a list by generation, and the
+        objects frozen out of its reach now."""
+        return self._heap.snapshot()
 
     def batch_target_changes(self) -> dict:
         """{"up": n, "down": n}: moves of the sizer's batch target."""

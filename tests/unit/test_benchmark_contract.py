@@ -468,15 +468,20 @@ _SUBMIT_FAMILIES = {
         [{"thread": t} for t in _PIPELINE_THREADS],
     "banjax_pipeline_batch_target_changes_total":
         [{"direction": "up"}, {"direction": "down"}],
+    # the cyclic collector under the pipeline (ISSUE 40)
+    "banjax_gc_collections_total": [{"generation": g} for g in "012"],
+    "banjax_gc_pause_seconds_total": [{"generation": g} for g in "012"],
+    "banjax_gc_collected_objects_total": [{"generation": g} for g in "012"],
+    "banjax_gc_frozen_objects": [{}],
 }
 
 
 @pytest.mark.parametrize("family", sorted(_SUBMIT_FAMILIES))
 def test_submit_stage_family_is_on_metrics_with_its_labels(
         submit_scrapes, family):
-    """The families ISSUE 39 brought, off `/metrics` through the
-    benchmark's own parser: declared, and exported with every label the
-    readers select by while the pipeline's threads run."""
+    """The families ISSUE 39 and ISSUE 40 brought, off `/metrics` through
+    the benchmark's own parser, tracing off: declared, and exported with
+    every label the readers select by while the pipeline's threads run."""
     from benchmark.harness import prom
 
     _, snap, _ = submit_scrapes
@@ -493,6 +498,14 @@ def test_submit_stage_family_is_on_metrics_with_its_labels(
         assert 0 < prom.value(snap, family) <= wall + 1e-3
     if family == "banjax_thread_cpu_seconds_total":
         assert prom.value(snap, family, thread="pipeline-device") > 0
+    if family == "banjax_gc_collections_total":
+        # the freeze at the first matcher collects the heap whole first
+        assert prom.value(snap, family, generation="2") >= 1
+        assert prom.value(
+            snap, "banjax_gc_pause_seconds_total", generation="2") > 0
+    if family == "banjax_gc_frozen_objects":
+        assert not trace.enabled()
+        assert prom.value(snap, family) > 10_000
 
 
 def test_submit_phases_sum_to_the_device_stage(submit_scrapes):
@@ -545,6 +558,10 @@ _NEW_READERS = {
     "batch_bucket_changes":
         ("banjax_pipeline_batch_target_changes_total",
          [{"direction": "up"}, {"direction": "down"}], 3.0, 4.0, 2.0),
+    # ISSUE 40: all three generations' pauses, 1.5 s over 10,000 lines
+    "gc_pause_ms_per_kline":
+        ("banjax_gc_pause_seconds_total",
+         [{"generation": g} for g in "012"], 1.0, 1.5, 150.0),
 }
 
 
@@ -552,7 +569,8 @@ _NEW_READERS = {
 def test_submit_stage_reader_reads_its_family(submit_scrapes, name):
     """Each of the ten per-layer readers PR 39 brought (ISSUE 39's
     eleventh, `device_thread_runqueue_share`, waits for a host that keeps
-    a `schedstat`: PERF.md §7): listed in BENCHMARK.json for every cell,
+    a `schedstat`: PERF.md §7) and PR 40's `gc_pause_ms_per_kline`:
+    listed in BENCHMARK.json for every cell,
     reads its family from two synthetic scrapes 40 s apart, reads
     something off a real pair, and is silent on a program without the
     family."""

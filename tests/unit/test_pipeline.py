@@ -7,8 +7,10 @@ Everything here runs on the CPU backend (tests/conftest.py pins
 JAX_PLATFORMS=cpu) — tier-1 marker hygiene for the pipeline suite.
 """
 
+import gc
 import threading
 import time
+import weakref
 
 import pytest
 
@@ -17,8 +19,10 @@ from banjax_tpu.decisions.rate_limit import RegexRateLimitStates
 from banjax_tpu.decisions.static_lists import StaticDecisionLists
 from banjax_tpu.matcher.api import ConsumeLineResult
 from banjax_tpu.matcher.runner import TpuMatcher
+from banjax_tpu.obs import trace
 from banjax_tpu.obs.stats import PipelineStats
 from banjax_tpu.pipeline import AdaptiveBatchSizer, PipelineScheduler
+from banjax_tpu.pipeline import heap as heap_mod
 from banjax_tpu.resilience import failpoints
 from banjax_tpu.resilience.breaker import CLOSED, OPEN
 from tests.classic_downgrade import scan_selftest_failing
@@ -690,6 +694,137 @@ class TestStartUpSamples:
         service = [ms["device"] for ms in seen[2:]]
         assert len(service) >= 8
         assert all(30.0 <= d <= 60.0 for d in service), service
+
+
+# ---------------------------------------------------------------------------
+# the cyclic collector under the pipeline (pipeline/heap.py, ISSUE 40)
+# ---------------------------------------------------------------------------
+
+
+class _BuildingMatcher:
+    """A generic matcher with a build counter the test moves, and a
+    reference cycle: only the collector can free one."""
+
+    def __init__(self):
+        self.builds = 0
+        self.me = self
+
+    def compile_events(self):
+        return self.builds
+
+    def consume_lines(self, lines, now_unix=None):
+        return [ConsumeLineResult() for _ in lines]
+
+
+class TestCollector:
+    @pytest.fixture(autouse=True)
+    def _thawed(self):
+        yield
+        gc.unfreeze()  # whatever a failing test left frozen
+
+    @staticmethod
+    def _one_batch(sched):
+        sched.submit(["a b c d e f g"] * 10)
+        assert sched.flush(10)
+
+    def test_an_observer_that_reads_every_row_leaves_no_garbage(self):
+        """What the benchmark's observer and chip_smoke's do: read a flag
+        of every row of every drained batch.  Fifty batches through the
+        fused CPU pipeline, and what only a collection could free stays
+        under 50 objects a batch (at PR 39: two a line, 512 a batch)."""
+        m, _, _ = make_matcher(device_windows=True,
+                               matcher_window_capacity=256)
+        now = time.time()
+        seen = []
+
+        def observer(lines, results):
+            seen.append(sum(r.old_line for r in results))
+
+        sched = PipelineScheduler(lambda: m, on_results=observer,
+                                  now_fn=lambda: now, max_batch=256)
+        sched.start()
+        for _ in range(2):  # programs built, the heap frozen
+            sched.submit(lines_at(now, 256))
+            assert sched.flush(120)
+        gc.collect()
+        freed0 = sum(sched.collector_stats()["collected"])
+        batches0 = sched.stats.batches
+        for k in range(50):
+            sched.submit(lines_at(now, 256, path=f"/p{k}"))
+            assert sched.flush(60)
+        gc.collect()
+        stats = sched.collector_stats()
+        batches = sched.stats.batches - batches0
+        sched.stop()
+        m.close()
+        assert batches >= 50 and len(seen) >= 52 and not any(seen)
+        assert sum(stats["collected"]) - freed0 < 50 * batches
+        assert stats["collections"][2] >= 2 and stats["pause_s"][2] > 0
+        assert stats["frozen"] > 0
+
+    def test_freezes_at_the_first_matcher_and_again_once_builds_stand_still(
+            self, monkeypatch):
+        monkeypatch.setattr(heap_mod, "_SETTLE_S", 0.05)
+        m = _BuildingMatcher()
+        sched = PipelineScheduler(lambda: m)
+        gc.unfreeze()
+        sched.start()
+        assert sched.collector_stats()["frozen"] == 0
+        self._one_batch(sched)
+        frozen = sched.collector_stats()["frozen"]
+        assert frozen > 1000
+        ballast = [[i] for i in range(5000)]  # allocated after the freeze
+        m.builds = 3                          # a program was built
+        self._one_batch(sched)
+        self._one_batch(sched)                # still for 0 s: not yet
+        assert sched.collector_stats()["frozen"] <= frozen + 100
+        time.sleep(0.06)
+        self._one_batch(sched)                # stood still: frozen again
+        assert sched.collector_stats()["frozen"] >= frozen + 5000
+        time.sleep(0.06)
+        calls = []
+        monkeypatch.setattr(heap_mod.gc, "freeze", lambda: calls.append(1))
+        self._one_batch(sched)                # nothing moved: not again
+        assert calls == []
+        monkeypatch.undo()
+        sched.stop()
+        assert gc.get_freeze_count() == 0     # stopping gives the heap back
+        assert sched._heap._on_gc not in gc.callbacks
+        del ballast
+
+    def test_a_hot_reload_lets_the_old_matcher_be_collected(self):
+        holder = [_BuildingMatcher()]
+        sched = PipelineScheduler(lambda: holder[0])
+        sched.start()
+        self._one_batch(sched)
+        assert sched.collector_stats()["frozen"] > 0
+        old = weakref.ref(holder[0])
+        holder[0] = _BuildingMatcher()        # the app's _current_matcher
+        self._one_batch(sched)
+        gc.collect()
+        assert old() is None
+        sched.stop()
+
+    def test_a_slow_full_pass_is_an_event_of_the_thread_it_ran_on(
+            self, monkeypatch):
+        monkeypatch.setattr(heap_mod, "_SLOW_PASS_S", 0.0)
+        trace.configure(enabled=True, ring_size=64)
+        try:
+            keeper = heap_mod.HeapKeeper()
+            keeper.start()
+            t = threading.Thread(target=gc.collect, name="collects-here")
+            t.start()
+            t.join(10)
+            gc.collect(0)                     # a young one: counted only
+            keeper.stop()
+            events = [e for e in trace.get_tracer().snapshot()
+                      if e["name"] == "gc-pass"]
+        finally:
+            trace.configure(enabled=False)
+        assert [e["thread"] for e in events] == ["collects-here"]
+        assert events[0]["args"]["ms"] >= 0
+        assert keeper.collections[2] == 1 and keeper.collections[0] == 1
+        assert keeper.pause_s[2] > 0
 
 
 # ---------------------------------------------------------------------------

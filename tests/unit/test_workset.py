@@ -3,6 +3,8 @@ interface both the native and fallback gates provide (NativeWork/ListWork/
 LazyResults/LazyLine). The differential suite covers these end-to-end; here
 the interface contracts are pinned in isolation."""
 
+import gc
+
 import numpy as np
 import pytest
 
@@ -105,6 +107,134 @@ def test_lazy_results_materialize_on_access():
     assert r[1].error and not r[2].error
     assert [x.error for x in r] == [False, True, False, False]
     assert [x.error for x in r[1:3]] == [True, False]
+
+
+@pytest.fixture()
+def collector_off():
+    """The test's body with the automatic collector off and nothing left
+    over from earlier tests: what `gc.collect()` then returns is what the
+    body left that only a collection can free."""
+    gc.collect()
+    gc.disable()
+    try:
+        yield
+    finally:
+        gc.enable()
+
+
+def _read_flags(results):
+    """error | old_line << 1 | exempted << 2 of every row (a list of
+    tuples would be 4,096 objects the collector tracks, of the test's)."""
+    return [x.error + 2 * x.old_line + 4 * x.exempted for x in results]
+
+
+def _owes_row_7(results):
+    results.owed(7).append("rr7")
+
+
+def _plain(n):
+    r = LazyResults(n)
+    r.defer(_owes_row_7)
+    r[5].old_line = True
+    return r, None
+
+
+def _absorbed_from_two_shards(n):
+    half = n // 2
+    a, b = LazyResults(half), LazyResults(n - half)
+    a[5].old_line = True
+    b[1].error = True
+    kept = b[1]  # a reader that kept a row of a shard across the merge
+    r = LazyResults(n)
+    r.absorb(a, 0)
+    r.absorb(b, half)
+    r.defer(_owes_row_7)
+    assert r[half + 1].error and not r[half].error
+    return r, kept
+
+
+def _after_a_rule_results_read(n):
+    r, _ = _plain(n)
+    assert r[7].rule_results == ["rr7"]     # ran the fill
+    assert r[8].rule_results == []          # and made a list for a clean row
+    return r, None
+
+
+@pytest.mark.parametrize(
+    "build",
+    [_plain, _absorbed_from_two_shards, _after_a_rule_results_read],
+)
+def test_reading_every_rows_flags_leaves_the_collector_nothing(
+        collector_off, build):
+    """What the pipeline's observer does with every batch (ISSUE 40): a
+    4,096-row vector, a fill deferred, the three flags of every row read,
+    the vector dropped.  No reference cycle, so nothing waits for a
+    collection; and while the vector lives the rows read left no object
+    behind, so none is a survivor that brings a full pass nearer."""
+    before = len(gc.get_objects())
+    r, kept = build(4096)
+    flags = _read_flags(r)
+    assert flags[5] == 2 and len(flags) == 4096
+    assert sum(flags) == (2 if kept is None else 3)
+    # alive: the vector, its list, the list of fills, the written rows
+    # with their lists, `flags` — not one object a row
+    assert len(gc.get_objects()) - before < 64
+    del r, kept, flags
+    assert gc.collect() == 0
+
+
+def test_a_line_result_outlives_its_vector_and_still_answers(collector_off):
+    r = LazyResults(16)
+    r.defer(_owes_row_7)
+    r[3].exempted = True
+    kept = [r[3], r[7], r[9]]
+    it = iter(r)
+    first = next(it)
+    del r, it
+    assert kept[0].exempted and not kept[0].error
+    assert kept[1].rule_results == ["rr7"]  # the fill still runs
+    assert kept[2].rule_results == [] and not kept[2].old_line
+    kept[2].error = True                    # and a write still lands
+    assert kept[2].error and not first.error
+    assert kept[0] != kept[2]
+    del kept, first
+    assert gc.collect() == 0
+
+
+def test_lazy_results_rows_are_one_row_whoever_reads_them():
+    """A row has one state however many views of it are out: a write
+    through one shows in all, slices and negative indices included."""
+    r = LazyResults(4)
+    a, b = r[2], r[2]
+    a.error = True
+    b.rule_results.append("x")
+    assert b.error and a.rule_results == ["x"] and a == b
+    assert r[-2].error and r[1:3][1].rule_results == ["x"]
+    r[2].rule_results = []
+    assert a.rule_results == []
+    with pytest.raises(IndexError):
+        r[4]
+
+
+def test_array_ptr_points_at_the_array_and_leaves_no_cycle(collector_off):
+    """`native/cptr.array_ptr` against numpy's `data_as`, which leaves a
+    `c_void_p` and a dict in a cycle at every call."""
+    import ctypes
+
+    from banjax_tpu.native.cptr import array_ptr
+
+    a = np.arange(5, dtype=np.int32)
+    i32p = ctypes.POINTER(ctypes.c_int32)
+    for _ in range(10):
+        p = array_ptr(a, i32p)
+    assert [p[k] for k in range(5)] == [0, 1, 2, 3, 4]
+    p[1] = 7
+    assert a[1] == 7
+    assert ctypes.addressof(p.contents) == a.ctypes.data
+    del p
+    assert gc.collect() == 0
+    q = array_ptr(np.arange(3, dtype=np.int64), ctypes.POINTER(ctypes.c_int64))
+    assert q[2] == 2  # the pointer keeps a temporary alive
 
 
 def test_unique_spans_fallback_and_native_agree_on_nuls():
