@@ -166,11 +166,14 @@ class Config:
     # for the rules that keep the candidate gate: a chunk whose stage-1
     # hit rate exceeds it replays through the single-stage matcher
     # (correct but slower; banjax_fused_overflows_total{cause="candidates"}
-    # counts them) — raise it for rules whose literal factor fires often
-    # on benign traffic.  Anchored literals such as `^GET` take no
-    # candidate slot: the plan routes them as always-columns of stage 1 by
-    # itself (prefilter._stage1_decides), so the shipped default rules
-    # need nothing set here
+    # counts them, and the log line of one names the factor bucket that
+    # hit most rows and its rules) — raise it for rules whose literal
+    # factor fires often on benign traffic.  Anchored literals such as
+    # `^GET` and rules behind a gate of four bytes or fewer (`GET .* /`)
+    # take no candidate slot: the plan routes them as always-columns of
+    # stage 1 by itself (prefilter._stage1_decides, selectivity.weak_gate),
+    # so the shipped default rules and upstream's fixtures need nothing
+    # set here
     matcher_prefilter: bool = True
     matcher_prefilter_cand_frac: float = 0.125
     # multi-device mesh (parallel/mesh.py): shard the line batch over `dp`

@@ -393,6 +393,7 @@ class FusedWindowsPipeline:
             off += p.Bp * na8
         else:
             p.always_bits = None
+        self.pf.note_bucket_hits(p.B, buf)
         n_pairs = int(flags[2])
         if n_pairs <= P and P:
             live_pairs = pairs[:n_pairs]

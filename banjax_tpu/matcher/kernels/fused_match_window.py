@@ -68,8 +68,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from banjax_tpu.matcher import sitemask, windows as W
-
-_SHIFTS = (0, 8, 16, 24)
+from banjax_tpu.matcher.prefilter import le_bytes
 
 
 # ---- the Pallas window-scan kernel ----
@@ -228,6 +227,7 @@ def build_single_program(
       ‖ always-rule bits [Bp * na8]            (when the plan has any)
       ‖ ev line/rule/hits/start_s/start_ns [5 × 4E]
       ‖ ev match_type/exceeded/seen_ip [3 × E]
+      ‖ hits per factor bucket [4F]            (when the plan filters)
 
     The head (flags ‖ pairs ‖ always bits) and the event tail are laid
     out back to back: fused_windows._decode_head reads the first and
@@ -249,7 +249,6 @@ def build_single_program(
     if site_mask is not None:
         site_mask = jnp.asarray(site_mask)                  # [hosts+1, nf8]
     active_table = jnp.asarray(active_table)
-    shifts = jnp.asarray(_SHIFTS, dtype=jnp.int32)
 
     @functools.partial(jax.jit, donate_argnums=(0,))
     def single(state, chain_ok, combined, n_real, host_idx, slots,
@@ -327,24 +326,20 @@ def build_single_program(
         flags = jnp.stack(
             [ok.astype(jnp.int32), c["n_cand"], n_pairs, n_events]
         )
-        parts = [
-            ((flags[:, None] >> shifts[None, :]) & 0xFF)
-            .astype(jnp.uint8).reshape(-1),
-            ((pairs[:, None] >> shifts[None, :]) & 0xFF)
-            .astype(jnp.uint8).reshape(-1),
-        ]
+        parts = [le_bytes(flags), le_bytes(pairs)]
         if n_always:
             parts.append(
                 jnp.packbits(ab.astype(jnp.bool_), axis=1).reshape(-1)
             )
         for key in ("line", "rule", "hits", "start_s", "start_ns"):
-            parts.append(
-                ((ev[key][:, None] >> shifts[None, :]) & 0xFF)
-                .astype(jnp.uint8).reshape(-1)
-            )
+            parts.append(le_bytes(ev[key]))
         parts.append(ev["match_type"].astype(jnp.uint8))
         parts.append(ev["exceeded"].astype(jnp.uint8))
         parts.append(ev["seen_ip"].astype(jnp.uint8))
+        if c["bucket_hits"] is not None:
+            # last, where prefilter.bucket_hits_of reads them whatever
+            # the head and the event tail before them hold
+            parts.append(le_bytes(c["bucket_hits"]))
         return new_state, ok.astype(jnp.int32), jnp.concatenate(parts), bits
 
     return single, K, P, max_events

@@ -39,6 +39,7 @@ import numpy as np
 from banjax_tpu.matcher import nfa_jax
 from banjax_tpu.matcher.encode import classify_bytes, encode_lines
 from banjax_tpu.matcher.kernels import nfa_match
+from banjax_tpu.matcher.selectivity import weak_gate
 from banjax_tpu.matcher.rulec import (
     CompiledRules,
     Pos,
@@ -70,6 +71,32 @@ class PrefilterPlan:
     f_idx: np.ndarray            # stage-2 column -> original rule id
     unsupported: Dict[int, str]  # rule id -> reason (host regex fallback)
     n_decided: int = 0           # always-columns routed by _stage1_decides
+    # always-columns that HAVE a factor and run whole all the same: the
+    # factor is too weak to gate on (selectivity.weak_gate)
+    p_idx: np.ndarray = dataclasses.field(
+        default_factory=lambda: np.zeros(0, dtype=np.int64))
+    # which filterable rule gates on which factor bucket, as pairs
+    # (original rule id, bucket): a candidates overflow names the rules
+    # behind its hottest bucket (selectivity.hottest_bucket)
+    fb_rule: np.ndarray = dataclasses.field(
+        default_factory=lambda: np.zeros(0, dtype=np.int64))
+    fb_bucket: np.ndarray = dataclasses.field(
+        default_factory=lambda: np.zeros(0, dtype=np.int64))
+
+    def routes(self) -> Dict[str, int]:
+        """How many rules the plan runs by each route."""
+        n_prom = int(len(self.p_idx))
+        return {
+            "always": self.n_always - self.n_decided - n_prom,
+            "decided": self.n_decided,
+            "promoted": n_prom,
+            "filtered": int(len(self.f_idx)),
+            "host": len(self.unsupported),
+        }
+
+    def rules_of_bucket(self, bucket: int) -> np.ndarray:
+        """Original ids of the filterable rules that gate on `bucket`."""
+        return np.unique(self.fb_rule[self.fb_bucket == bucket])
 
 
 def gate_masks(plan: "PrefilterPlan", prep=None):
@@ -96,6 +123,24 @@ def gate_masks(plan: "PrefilterPlan", prep=None):
         acc_mask[~fac],
         branch_rule[~fac].astype(np.int32),
     )
+
+
+def bucket_masks(plan: "PrefilterPlan", prep=None):
+    """(word [n_factors] int32, mask [n_factors] uint32): where each factor
+    bucket's one accept bit lies in the raw accept words, in bucket order
+    (a factor automaton is one branch) — what the per-bucket hit counts
+    of _match_core read.  `prep` as in gate_masks."""
+    s1 = plan.stage1
+    acc_word = np.asarray(prep.acc_word if prep is not None else s1.acc_word)
+    acc_mask = np.asarray(s1.acc_mask, dtype=np.uint32)
+    branch_rule = np.asarray(s1.branch_rule)
+    word = np.zeros(plan.n_factors, dtype=np.int32)
+    mask = np.zeros(plan.n_factors, dtype=np.uint32)
+    fac = np.flatnonzero(branch_rule >= plan.n_always)
+    b = branch_rule[fac] - plan.n_always
+    word[b] = acc_word[fac]
+    mask[b] = acc_mask[fac]
+    return word, mask
 
 
 # Bytes that dominate real log-line traffic (lowercase, digits, and URL /
@@ -129,6 +174,7 @@ def _merge_factors(
     factors: List[Tuple],
     max_merge: int = 16,
     sel_max: float = 1e-5,
+    assign: Optional[Dict[Tuple, int]] = None,
 ) -> List[Tuple]:
     """Teddy-style factor superimposition: OR byte-similar *equal-length*
     factors position-wise into one shared automaton (Hyperscan's Teddy
@@ -152,8 +198,14 @@ def _merge_factors(
     general-workload guard: a bucket stops absorbing factors once its
     estimated per-start-offset benign hit probability (∏ _pos_prob)
     exceeds it (wide (?i) case-class merges hit this long before
-    max_merge)."""
+    max_merge).
+
+    `assign`, when given, is filled with each factor's bucket: its class
+    tuple -> the index of the returned automaton it went into."""
     if max_merge <= 1:
+        if assign is not None:
+            assign.update(
+                (tuple(p.cs for p in f), i) for i, f in enumerate(factors))
         return factors
 
     def sort_key(f):
@@ -174,10 +226,14 @@ def _merge_factors(
                 sel *= _pos_prob(c)
             if sel <= sel_max:
                 cur, cur_n = merged, cur_n + 1
+                if assign is not None:
+                    assign[tuple(cs_list)] = len(out)
                 continue
         if cur is not None:
             out.append(cur)
         cur, cur_n = cs_list, 1
+        if assign is not None:
+            assign[tuple(cs_list)] = len(out)
     if cur is not None:
         out.append(cur)
     return [tuple(Pos(c) for c in cs) for cs in out]
@@ -213,6 +269,9 @@ def build_plan(
     overhead would outweigh the narrower stage 1).  Rules that stage 1
     decides by itself (_stage1_decides) are always-columns; when no rule
     is left to filter, the plan is stage 1 alone and `stage2` is None.
+    So are the rules whose gate is too weak to filter on
+    (selectivity.weak_gate: `GET .* /`): always-columns with their whole
+    automaton, counted in `p_idx`.
 
     `byte_classes` = (byte_to_class, n_classes) of the full single-stage
     ruleset: both stage tensors are then packed against that shared byte
@@ -232,6 +291,8 @@ def build_plan(
     always_ids: List[int] = []
     filt_ids: List[int] = []
     n_decided = 0  # always-columns by _stage1_decides, not for want of a factor
+    promoted_ids: List[int] = []
+    rule_factors: Dict[int, List[Tuple]] = {}
     for i, prog in enumerate(programs):
         if prog is None:
             continue  # host regex fallback, not on device at all
@@ -245,21 +306,33 @@ def build_plan(
             always_ids.append(i)
             n_decided += 1
             continue
+        if weak_gate(prog, factors):
+            always_ids.append(i)
+            promoted_ids.append(i)
+            continue
         filt_ids.append(i)
+        rule_factors[i] = factors
         for f in factors:
             distinct_factors.setdefault(tuple(p.cs for p in f), f)
+    bucket_of: Dict[Tuple, int] = {}
     merged = _merge_factors(
         list(distinct_factors.values()),
         max_merge=factor_merge,
         sel_max=factor_sel_max,
+        assign=bucket_of,
     )
     factor_progs = [factor_program(f) for f in merged]
+    gates = sorted({
+        (i, bucket_of[tuple(p.cs for p in f)])
+        for i, factors in rule_factors.items() for f in factors
+    })
 
     n_device = len(always_ids) + len(filt_ids)
     # a rule stage 1 decides alone costs stage 1 what its factor would, so
     # it counts with the filterable rules, not against them
     if n_device == 0 or (
-        len(filt_ids) + n_decided < max(1, n_device * min_filterable_fraction)
+        len(filt_ids) + n_decided + len(promoted_ids)
+        < max(1, n_device * min_filterable_fraction)
     ):
         return None
 
@@ -277,11 +350,12 @@ def build_plan(
         stage2_programs, n_shards=stage2_shards, byte_classes=byte_classes
     ) if stage2_programs else None
     log.info(
-        "prefilter plan: %d always + %d filterable rules, %d distinct "
-        "factors in %d superimposed buckets; stage1 %d words, stage2 %d "
-        "words",
-        len(always_ids), len(filt_ids), len(distinct_factors),
-        len(factor_progs), s1.n_words, s2.n_words if s2 is not None else 0,
+        "prefilter plan: %d always (%d promoted) + %d filterable rules, %d "
+        "distinct factors in %d superimposed buckets; stage1 %d words, "
+        "stage2 %d words",
+        len(always_ids), len(promoted_ids), len(filt_ids),
+        len(distinct_factors), len(factor_progs), s1.n_words,
+        s2.n_words if s2 is not None else 0,
     )
     return PrefilterPlan(
         n_rules=len(patterns),
@@ -293,6 +367,9 @@ def build_plan(
         f_idx=np.asarray(filt_ids, dtype=np.int64),
         unsupported=unsupported,
         n_decided=n_decided,
+        p_idx=np.asarray(promoted_ids, dtype=np.int64),
+        fb_rule=np.asarray([g[0] for g in gates], dtype=np.int64),
+        fb_bucket=np.asarray([g[1] for g in gates], dtype=np.int64),
     )
 
 
@@ -398,6 +475,14 @@ def _column_mask_words(n_cols: int, n_words: int) -> np.ndarray:
     return packed.view(">u4").astype(np.uint32)
 
 
+def le_bytes(x):
+    """int32 array -> its little-endian bytes as a flat uint8 array (the
+    form every number takes in a fused program's one output buffer)."""
+    shifts = jnp.asarray([0, 8, 16, 24], dtype=jnp.int32)
+    return ((x.reshape(-1)[:, None] >> shifts[None, :]) & 0xFF).astype(
+        jnp.uint8).reshape(-1)
+
+
 class PrefilterOverflow(RuntimeError):
     """More stage-1 candidates than the fused pipeline's fixed capacity —
     the caller must rerun the batch through its single-stage path."""
@@ -499,6 +584,15 @@ class FusedPrefilter:
         self._a_word = jnp.asarray(a_word)
         self._a_mask = jnp.asarray(a_mask)
         self._a_rule = jnp.asarray(a_rule)
+        # each factor bucket's accept bit, for the per-bucket hit counts
+        # every batch returns beside its candidates (what a candidates
+        # overflow is explained from: selectivity.hottest_bucket)
+        b_word, b_mask = bucket_masks(
+            plan, self._preps["s1"] if self._pallas else None
+        )
+        self._b_word = jnp.asarray(b_word)
+        self._b_mask = jnp.asarray(b_mask)
+        self.n_buckets = plan.n_factors if self._n_filt else 0
         # host-static flags for always-rules (applied after decode)
         self._a_always = np.asarray(s1.always_match[: plan.n_always], dtype=bool)
         self._a_empty = np.asarray(s1.empty_only[: plan.n_always], dtype=bool)
@@ -507,6 +601,8 @@ class FusedPrefilter:
         # lines stage 1's gate passed on to stage 2, whichever program
         # ran it (banjax_prefilter_candidates_total)
         self.candidates_total = 0
+        # (rows, hits per factor bucket) of the last batch read back
+        self.last_bucket_hits: Optional[Tuple[int, np.ndarray]] = None
 
     # ---- device program ----
 
@@ -755,6 +851,7 @@ class FusedPrefilter:
         n_always = plan.n_always
         fmask = self._fmask
         a_word, a_mask, a_rule = self._a_word, self._a_mask, self._a_rule
+        b_word, b_mask = self._b_word, self._b_mask
         shifts = jnp.asarray([0, 8, 16, 24], dtype=jnp.int32)
         packed_in = self._pack_input
         L4 = -(-L_p // 4)
@@ -782,10 +879,17 @@ class FusedPrefilter:
                 return {
                     "lens_raw": lens_raw, "n_cand": jnp.int32(0),
                     "m2p": None, "idx_caller_k": None,
-                    "ab_caller": ab_caller,
+                    "ab_caller": ab_caller, "bucket_hits": None,
                 }
             cand = (acc1 & fmask[:, None]).max(axis=0) > 0       # [B]
             n_cand = jnp.sum(cand.astype(jnp.int32))
+            # rows each factor bucket hit: a few dozen rows of the accept
+            # words summed, beside a scan over every byte of every line
+            with jax.named_scope("bucket-hits"):
+                bucket_hits = jnp.sum(
+                    (acc1[b_word, :] & b_mask[:, None]) != 0,
+                    axis=1, dtype=jnp.int32,
+                )
             (idx,) = jnp.nonzero(cand, size=K, fill_value=0)     # [K] ascending
             valid = jax.lax.iota(jnp.int32, K) < n_cand
             cls2_t = jnp.take(cls_t, idx, axis=1)                # [L_p, K]
@@ -800,6 +904,7 @@ class FusedPrefilter:
             return {
                 "lens_raw": lens_raw, "n_cand": n_cand, "m2p": m2p,
                 "idx_caller_k": idx_caller_k, "ab_caller": ab_caller,
+                "bucket_hits": bucket_hits,
             }
 
         return core
@@ -812,7 +917,6 @@ class FusedPrefilter:
         block, K = self.capacities(B)
         core = self._match_core(B, L_p, K, block)
         n_always = self.plan.n_always
-        shifts = jnp.asarray([0, 8, 16, 24], dtype=jnp.int32)
         P = self.pair_capacity(B, K)
 
         @jax.jit
@@ -821,7 +925,7 @@ class FusedPrefilter:
             latency — see _match_core for the input layout) → one uint8
             buffer:
               n_cand[4] ‖ n_pairs[4] ‖ (row, rule) pairs [4P] ‖
-              always-rule bits [B * na8].
+              always-rule bits [B * na8] ‖ hits per factor bucket [4F].
             A single buffer = a single device→host pull, and a SMALL one:
             each set rule bit ships as one int32 (pairs_from_core) instead
             of a full ceil(R/8)-byte row bitmap per matched line (B/4 rows
@@ -831,18 +935,15 @@ class FusedPrefilter:
             work to K candidate lines."""
             c = core(cls_and_lens)
             pairs, n_pairs, _ = self.pairs_from_core(c, K, P)
-            parts = [
-                ((c["n_cand"][None] >> shifts) & 0xFF).astype(jnp.uint8),
-                ((n_pairs[None] >> shifts) & 0xFF).astype(jnp.uint8),
-                ((pairs[:, None] >> shifts[None, :]) & 0xFF)
-                .astype(jnp.uint8).reshape(-1),
-            ]
+            parts = [le_bytes(c["n_cand"]), le_bytes(n_pairs), le_bytes(pairs)]
             if n_always:
                 parts.append(
                     jnp.packbits(
                         c["ab_caller"].astype(jnp.bool_), axis=1
                     ).reshape(-1)
                 )
+            if c["bucket_hits"] is not None:
+                parts.append(le_bytes(c["bucket_hits"]))
             return jnp.concatenate(parts)
 
         self._fns[key] = (fused, K, P)
@@ -881,6 +982,7 @@ class FusedPrefilter:
         buf = np.asarray(p.buf)
         p.d2h_bytes += buf.nbytes
         K, P, B = p.K, p.P, p.B
+        self.note_bucket_hits(B, buf)
         R8 = self._nf8 * 8
         head = np.frombuffer(buf[:8].tobytes(), dtype="<i4")
         n_cand, n_pairs = int(head[0]), int(head[1])
@@ -904,13 +1006,23 @@ class FusedPrefilter:
             bits[rows_idx[keep], plan.f_idx[cols[keep]]] = 1
         if plan.n_always:
             off = 8 + 4 * P
-            ap = buf[off:].reshape(-1, self._na8)[:B]  # caller-order rows
+            ap = buf[off : len(buf) - 4 * self.n_buckets]
+            ap = ap.reshape(-1, self._na8)[:B]         # caller-order rows
             abits = np.unpackbits(ap, axis=1, count=plan.n_always)
             abits[:, self._a_always] = 1
             if self._a_empty.any():
                 abits[p.lens == 0] |= self._a_empty.astype(np.uint8)
             bits[:, plan.a_idx] = abits
         return bits
+
+    def note_bucket_hits(self, rows: int, buf: np.ndarray) -> None:
+        """Keep the per-bucket hit counts a program of this plan leaves
+        at the END of its one output buffer ([n_buckets] int32; nothing
+        for a plan that filters nothing) as `last_bucket_hits`."""
+        if self.n_buckets:
+            self.last_bucket_hits = (rows, np.frombuffer(
+                buf[len(buf) - 4 * self.n_buckets :].tobytes(), dtype="<i4"
+            ))
 
     def match_bits_encoded(
         self, cls_ids: np.ndarray, lens: np.ndarray

@@ -9,7 +9,8 @@ The results are pure functions of the ruleset, so they are kept as `.npz`
 files in `<compile cache>/banjax_rules/`, keyed by the ruleset's content
 (every rule's site, name, regex, interval, limit, decision and skipped
 hosts, in order), the packing's arguments and the compilers' own source: a
-changed regex or limit, or a changed `rulec.py` / `prefilter.py`, is
+changed regex or limit, or a changed `rulec.py` / `prefilter.py` /
+`selectivity.py`, is
 another key.  Arrays and numbers only: nothing is unpickled.
 
 No compile cache directory (a test, a library use), or a file that cannot
@@ -27,7 +28,7 @@ from typing import Callable, Optional
 
 import numpy as np
 
-from banjax_tpu.matcher import prefilter, rulec
+from banjax_tpu.matcher import prefilter, rulec, selectivity
 from banjax_tpu.obs import trace
 
 log = logging.getLogger(__name__)
@@ -37,7 +38,7 @@ _DIR_NAME = "banjax_rules"
 
 def _code_digest() -> str:
     h = hashlib.sha256()
-    for mod in (rulec, prefilter):
+    for mod in (rulec, prefilter, selectivity):
         with open(mod.__file__, "rb") as f:
             h.update(f.read())
     return h.hexdigest()

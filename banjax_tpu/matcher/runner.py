@@ -41,7 +41,7 @@ from banjax_tpu.decisions.rate_limit import (
 )
 from banjax_tpu.decisions.static_lists import StaticDecisionLists
 from banjax_tpu.effectors.banner import BannerInterface
-from banjax_tpu.matcher import compile_watch, nfa_jax
+from banjax_tpu.matcher import compile_watch, nfa_jax, selectivity
 from banjax_tpu.matcher.api import ConsumeLineResult, Matcher, RuleResult
 from banjax_tpu.matcher.cpu_ref import OLD_LINE_CUTOFF_SECONDS
 from banjax_tpu.matcher.encode import ParsedLine, encode_for_match, parse_line
@@ -417,6 +417,7 @@ class TpuMatcher(Matcher):
         # this matcher's byte classes, so the native parse's encode feeds
         # it directly and the whole two-stage pipeline is one device call.
         self._prefilter = None
+        self._overflow_logged_at = 0.0  # _log_hottest_bucket's last line
         if getattr(config, "matcher_prefilter", True) and self._mesh_matcher is None:
             from banjax_tpu.matcher.prefilter import FusedPrefilter, build_plan
 
@@ -578,6 +579,17 @@ class TpuMatcher(Matcher):
             # (prefilter._stage1_decides) among its always-columns
             "stage1_decided_rules": (
                 self._prefilter.plan.n_decided
+                if self._prefilter is not None else None
+            ),
+            # how many rules the plan runs by each route, and by name the
+            # ones it runs whole in stage 1 although they have a factor
+            # (too weak to gate on: selectivity.weak_gate)
+            "plan_routes": (
+                self._prefilter.plan.routes()
+                if self._prefilter is not None else None
+            ),
+            "plan_promoted": (
+                [self._rule_names[i] for i in self._prefilter.plan.p_idx]
                 if self._prefilter is not None else None
             ),
             "mesh_shape": (
@@ -1819,6 +1831,28 @@ class TpuMatcher(Matcher):
 
         self._with_window_slots(work, *make(cls_ids, lens), results)
 
+    def _log_hottest_bucket(self) -> None:
+        """Beside a candidates overflow: which factor bucket hit most
+        rows of the batch and the rules behind it, every 10 s at most."""
+        now = time.monotonic()
+        if now - self._overflow_logged_at < 10.0:
+            return
+        pf = self._prefilter
+        hot = selectivity.hottest_bucket(pf.plan, pf.last_bucket_hits)
+        if hot is None:
+            return
+        self._overflow_logged_at = now
+        bucket, share, rules = hot
+        names = [self._rule_names[i] for i in rules]
+        log.info(
+            "candidates overflow: factor bucket %d hit %.1f %% of the last "
+            "batch's rows, the compaction holds %.1f %% (rules %s%s); the "
+            "chunk replays single-stage",
+            bucket, 100.0 * share, 100.0 * pf.cand_frac,
+            ", ".join(repr(r) for r in names[:4]),
+            f" and {len(names) - 4} more" if len(names) > 4 else "",
+        )
+
     def _pipeline_fallback_entry(self, e, ov, results, live=None) -> None:
         """Classic replay of one overflowing chunk (shared by the sync and
         overlapped paths; caller guarantees all earlier chunks applied).
@@ -1830,6 +1864,7 @@ class TpuMatcher(Matcher):
         n = len(e["work"])
         try:
             if ov.candidate_overflow:
+                self._log_hottest_bucket()
                 # stage 2 never saw the excess lines: recompute full-NFA
                 bits = self._single_stage_bits(
                     n, e["cls"], e["lens"], np.zeros(n, dtype=bool),
@@ -2098,6 +2133,7 @@ class TpuMatcher(Matcher):
                 # adversarial all-matching traffic: rerun single-stage (the
                 # full-NFA path has no candidate capacity to overflow)
                 log.info("prefilter overflow (%s); batch reruns single-stage", e)
+                self._log_hottest_bucket()
                 bits = self._single_stage_bits(
                     n, cls_ids, lens, host_eval, device_rows
                 )

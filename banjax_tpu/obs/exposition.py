@@ -186,6 +186,19 @@ def render_prometheus(
         w.sample(registry.PROM_FAMILIES["banjax_fused_pairs_total"],
                  fw.pairs_total)
 
+    # the prefilter plan: its routes, and the factor bucket that hit
+    # most rows of the last batch read back
+    pf = getattr(matcher, "_prefilter", None) if matcher else None
+    if pf is not None:
+        from banjax_tpu.matcher.selectivity import hottest_bucket
+
+        fam = registry.PROM_FAMILIES["banjax_plan_rules"]
+        for route, v in pf.plan.routes().items():
+            w.sample(fam, v, {"route": route})
+        hot = hottest_bucket(pf.plan, pf.last_bucket_hits)
+        w.sample(registry.PROM_FAMILIES["banjax_plan_hottest_bucket_share"],
+                 round(hot[1], 6) if hot else 0)
+
     # what this start spent on its rules, labeled by how it got them
     rc = getattr(matcher, "rules_cache", None) if matcher else None
     if rc is not None:

@@ -318,6 +318,18 @@ FAMILIES: List[Family] = [
            "ones included; divide by lines: against the pair capacity of "
            "250 a thousand rows)",
            prom="banjax_fused_pairs_total"),
+    Family(GAUGE, "rules the prefilter plan runs, by route: always (no "
+           "factor to filter on), decided (stage 1 decides an anchored "
+           "literal alone), promoted (has a factor and runs whole in stage "
+           "1 all the same: a gate of four bytes or fewer in front of an "
+           "automaton of one word, `GET .* /`), filtered (behind a factor, "
+           "in stage 2), host (not lowerable; host regex)",
+           prom="banjax_plan_rules", labels=("route",)),
+    Family(GAUGE, "share of rows (0..1) that the hottest factor bucket hit "
+           "in the last batch read back; candidates overflow once the "
+           "buckets together pass matcher_prefilter_cand_frac, and the log "
+           "line of an overflow names this bucket's rules",
+           prom="banjax_plan_hottest_bucket_share"),
     Family(COUNTER, "window events committed by device applies, by whether "
            "the rule belongs to one site or is global (their sum is "
            "banjax_device_windows_events_total)",

@@ -254,13 +254,16 @@ def test_overflow_replay_at_this_density_equals_the_reference(
 
 def test_plan_routes_dense_rules_as_always_columns_by_itself():
     """`^GET`/`^POST` are anchored literals (stage 1 decides them);
-    `.*` has no factor; `.*challengeme.*` and the unanchored
-    `GET .*\\.php` keep their gate.  The event ceiling follows rows x
+    `.*` has no factor; `.*challengeme.*` keeps its gate.  The
+    unanchored `GET .*\\.php` gates on four bytes and fits one word: since
+    PR 41 it runs whole in stage 1 (selectivity.weak_gate), where PR 28
+    left it to overflow the candidates.  The event ceiling follows rows x
     always-columns, and a plan may have no stage 2 at all."""
     plan = build_plan([r["regex"] for r in DEFAULT_RULES + [EVERY_LINE]]
                       + [r"GET .*\.php"])
-    assert sorted(plan.a_idx.tolist()) == [0, 1, 3]
-    assert plan.f_idx.tolist() == [2, 4] and plan.n_decided == 2
+    assert sorted(plan.a_idx.tolist()) == [0, 1, 3, 4]
+    assert plan.f_idx.tolist() == [2] and plan.n_decided == 2
+    assert plan.p_idx.tolist() == [4]
     only = build_plan(["^GET", "^POST"])
     assert only.stage2 is None and only.n_always == 2
     # 1,000 sparse rules: nothing is routed, the plan is what it was

@@ -8,11 +8,21 @@ that start the product on port 8081 stay by-hand checks.
 `test_cells_unmoved.py` holds the four cells of PR 35 and says that they
 are every cell; it is a file of the benchmark and not this PR's to edit,
 so `multisite.botnet`, the fifth, is held here: constants computed on the
-tree that added it (PR 37), by that file's `digests`."""
+tree that added it (PR 37), by that file's `digests`; `capped1k.flood`,
+the sixth, likewise (PR 41), with the port-free cases of
+`test_capped1k.py`."""
+
+import pytest
 
 from benchmark.checks import test_cells_unmoved as unmoved
 from benchmark.checks.test_cells_unmoved import (  # noqa: F401
     test_cell_is_fed_and_configured_as_at_the_parent,
+)
+from benchmark.checks.test_capped1k import (  # noqa: F401
+    test_a_benign_get_meets_the_cap_and_nothing_else_of_the_front,
+    test_front_rules_are_the_fixtures_and_names_are_distinct,
+    test_the_control_with_the_cap_at_46_fails,
+    test_the_references_own_always_share_lies_in_the_band,
 )
 from benchmark.checks.test_per_site import (  # noqa: F401
     test_control_and_compare_take_per_site_records,
@@ -31,11 +41,17 @@ WHEN_ADDED = {
         "stream": "da64aca6e16f59c7aa87234285507a7f240d97165f97a8d036cb2b64051f310e",
         "config": "634178690492b27486ec9181192dae707924eabd709c175e460cc85a7930f840",
     },
+    "capped1k.flood": {
+        "pools": "123c8549962b391fe550c1d9abb1fd6ab41e7628b46f51b383082e5e34ae7226",
+        "stream": "8692c448f6d87dd7b590e5eb7b3770d273e911ebe3d75f422af6eea5ebdf527f",
+        "config": "3bc94f7d99daedba26577cd5302fe18d958585ea61e274f0d6ad0a268bed3728",
+    },
 }
 
 
-def test_multisite_botnet_is_fed_and_configured_as_when_added():
-    assert unmoved.digests("multisite.botnet") == WHEN_ADDED["multisite.botnet"]
+@pytest.mark.parametrize("cell", sorted(WHEN_ADDED))
+def test_cell_is_fed_and_configured_as_when_added(cell):
+    assert unmoved.digests(cell) == WHEN_ADDED[cell]
 
 
 def test_every_cell_of_the_benchmark_is_held_here_or_there():

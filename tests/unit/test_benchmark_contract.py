@@ -346,6 +346,109 @@ def test_multisite_family_is_on_metrics_and_its_reader_reads_it(family):
     m.close()
 
 
+_CAPPED_FAMILIES = {
+    # family: (label sets the readers select by, its readers)
+    "banjax_plan_rules":
+        ([{"route": r} for r in ("always", "decided", "promoted",
+                                 "filtered", "host")],
+         ["plan_promoted_rules"]),
+    "banjax_fused_overflows_total":
+        ([{"cause": "candidates"}], ["candidates_overflow_share"]),
+    "banjax_fused_event_feed_total":
+        ([{"source": "always"}, {"source": "pairs"}],
+         ["always_events_share"]),
+    "banjax_plan_hottest_bucket_share": ([{}], []),
+}
+
+
+@pytest.fixture(scope="module")
+def capped_scrapes():
+    """`/metrics` before and after 300 lines through the scheduler and a
+    matcher whose plan has one rule of each kind `capped1k-edge` has: the
+    rate cap (promoted), a challenge-all rule (always) and a signature
+    (filtered).  Tracing is off."""
+    from banjax_tpu.decisions.dynamic_lists import DynamicDecisionLists
+    from banjax_tpu.decisions.rate_limit import (
+        FailedChallengeRateLimitStates,
+    )
+    from banjax_tpu.obs.exposition import render_prometheus
+    from benchmark.harness import prom
+
+    assert not trace.enabled()
+    one = {"interval": 60, "hits_per_interval": 45, "decision": "nginx_block"}
+    cfg = config_from_yaml_text(yaml.safe_dump({"regexes_with_rates": [
+        {**one, "rule": "cap", "regex": "GET .* /"},
+        {**one, "rule": "all", "regex": ".*",
+         "hosts_to_skip": {"h.com": True}},
+        {**one, "rule": "sig", "regex": "GET /attack[0-9]+"},
+    ]}))
+    cfg.matcher_device_windows = True
+    m = TpuMatcher(cfg, MockBanner(), StaticDecisionLists(cfg),
+                   RegexRateLimitStates())
+    now = time.time()
+    sched = PipelineScheduler(lambda: m, now_fn=lambda: now)
+
+    def scrape():
+        return prom.parse(render_prometheus(
+            DynamicDecisionLists(start_sweeper=False), RegexRateLimitStates(),
+            FailedChallengeRateLimitStates(), matcher=m, pipeline=sched,
+        ))
+
+    sched.start()
+    before = scrape()
+    for k in range(3):
+        # of 100 lines, 80 are GETs (the cap fires), 10 of those carry the
+        # signature; 10 lines are of the one host `all` is not skipped on
+        sched.submit([
+            f"{now:.6f} 1.2.{k}.{i} {'GET' if i % 5 else 'POST'} "
+            f"{'other.org' if i % 10 == 3 else 'h.com'} "
+            f"{'GET' if i % 5 else 'POST'} "
+            f"/{'attack' if i % 10 == 1 else 'page'}{i} HTTP/1.1 ua -"
+            for i in range(100)
+        ])
+        assert sched.flush(120)
+    sched.stop()
+    after = scrape()
+    m.close()
+    return before, after
+
+
+@pytest.mark.parametrize("family", sorted(_CAPPED_FAMILIES))
+def test_capped1k_family_is_on_metrics_and_its_reader_reads_it(
+        capped_scrapes, family):
+    """The families `capped1k-edge` brought or gave their first reader
+    (ISSUE 41), off `/metrics` with tracing off through the benchmark's
+    own parser and through the three readers of `capped1k.flood`: the
+    plan's rules by route, overflows by cause, window events by the list
+    the program took them from."""
+    from benchmark.harness import found, prom
+
+    before, snap = capped_scrapes
+    label_sets, readers = _CAPPED_FAMILIES[family]
+    assert family in {f.prom for f in registry.FAMILIES}
+    for labels in label_sets:
+        assert prom.value(snap, family, **labels) is not None, labels
+    assert prom.value(snap, "banjax_plan_rules", route="promoted") == 1
+    assert prom.value(snap, "banjax_plan_rules", route="always") == 1
+    assert prom.value(snap, "banjax_plan_rules", route="filtered") == 1
+    assert prom.value(
+        snap, "banjax_fused_event_feed_total", source="always") == 3 * 90
+    assert prom.value(
+        snap, "banjax_fused_event_feed_total", source="pairs") == 3 * 10
+    # 10 lines in 100 carry the signature's factor
+    assert prom.value(
+        snap, "banjax_plan_hottest_bucket_share") == pytest.approx(0.1)
+    ctx = {"prom0": before, "prom1": snap, "trace": None, "trace_lines": 0,
+           "mean_len": 0.0}
+    want = {"plan_promoted_rules": 1.0, "candidates_overflow_share": 0.0,
+            "always_events_share": 90.0}
+    for name in readers:
+        assert found.module("layers", name).read(ctx) == want[name]
+        # a program without the family (the parent): the reader is silent
+        assert found.module("layers", name).read(
+            {**ctx, "prom0": {}, "prom1": {}}) is None
+
+
 @pytest.fixture(scope="module")
 def shadow_scrape():
     """`/metrics` after a stream whose addresses all fire the rule and

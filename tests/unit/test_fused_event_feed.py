@@ -143,7 +143,8 @@ class _Program:
                 np.asarray(getattr(ref_state, f)),
             ), f
         off = 16 + 4 * P + Bp * self.pf._na8
-        assert len(buf) == off + 23 * E
+        # head, event tail, then the hits of each factor bucket (PR 41)
+        assert len(buf) == off + 23 * E + 4 * self.pf.n_buckets
         alive = np.asarray(out["rule"]) >= 0
         assert flags[3] == alive.sum()
         for f in EV_FIELDS:
