@@ -16,7 +16,7 @@ from __future__ import annotations
 import dataclasses
 import threading
 import time
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 from banjax_tpu.decisions.model import Decision
 from banjax_tpu.obs import provenance
@@ -84,6 +84,19 @@ class DynamicDecisionLists:
         except Exception:  # noqa: BLE001
             self._note_mirror_error()
 
+    def _mirror_put_many(self, eds: List[ExpiringDecision]) -> None:
+        """A batch's inserts in their order, in one call of the table:
+        they share `expires` and `from_baskerville` (update_many)."""
+        if self._mirror is None or not eds:
+            return
+        try:
+            self._mirror.put_many(
+                [(ed.ip_address, int(ed.decision), ed.domain) for ed in eds],
+                eds[0].expires, eds[0].from_baskerville,
+            )
+        except Exception:  # noqa: BLE001
+            self._note_mirror_error()
+
     def _mirror_del(self, ip: str) -> None:
         if self._mirror is None:
             return
@@ -109,6 +122,24 @@ class DynamicDecisionLists:
         except Exception:  # noqa: BLE001
             pass
 
+    def _insert_locked(
+        self,
+        ip: str,
+        expires: float,
+        new_decision: Decision,
+        from_baskerville: bool,
+        domain: str,
+    ) -> Optional[ExpiringDecision]:
+        """Monotonic-severity insert (decision.go:404-439) → the entry it
+        made, None where the held decision is as severe or more."""
+        existing = self._by_ip.get(ip)
+        if existing is not None and new_decision <= existing.decision:
+            return None
+        ed = self._by_ip[ip] = ExpiringDecision(
+            new_decision, expires, ip, from_baskerville, domain
+        )
+        return ed
+
     def update(
         self,
         ip: str,
@@ -117,16 +148,32 @@ class DynamicDecisionLists:
         from_baskerville: bool,
         domain: str,
     ) -> None:
-        """Monotonic-severity insert (decision.go:404-439)."""
         with self._lock:
-            existing = self._by_ip.get(ip)
-            if existing is not None and new_decision <= existing.decision:
-                return
-            ed = ExpiringDecision(
-                new_decision, expires, ip, from_baskerville, domain
+            ed = self._insert_locked(
+                ip, expires, new_decision, from_baskerville, domain
             )
-            self._by_ip[ip] = ed
-            self._mirror_put(ed)
+            if ed is not None:
+                self._mirror_put(ed)
+
+    def update_many(
+        self,
+        items: Sequence[Tuple[str, Decision, str]],
+        expires: float,
+        from_baskerville: bool = False,
+    ) -> None:
+        """`update` for each (ip, decision, domain) in order, under one
+        hold of the lock: severity resolves as it does one by one, an
+        address that comes twice included; the mirror takes the inserts
+        that got through, in that order, in one call."""
+        with self._lock:
+            inserted = []
+            for ip, decision, domain in items:
+                ed = self._insert_locked(
+                    ip, expires, decision, from_baskerville, domain
+                )
+                if ed is not None:
+                    inserted.append(ed)
+            self._mirror_put_many(inserted)
 
     def update_by_session_id(
         self,

@@ -16,16 +16,22 @@ from __future__ import annotations
 
 import json
 import logging
+import math
 import threading
 import time
-from typing import List, Optional, TextIO
+from typing import Dict, List, NamedTuple, Optional, Sequence, TextIO, Tuple
 
 from banjax_tpu.config.schema import Config
 from banjax_tpu.decisions.dynamic_lists import DynamicDecisionLists
 from banjax_tpu.decisions.model import Decision
 from banjax_tpu.effectors.ipset import IpsetInstance
+from banjax_tpu.obs import provenance
 
 log = logging.getLogger(__name__)
+
+# json.dumps(..., separators=(",", ":")) builds an encoder like this one on
+# every call; the output is the same
+_ENCODE = json.JSONEncoder(separators=(",", ":")).encode
 
 # Field order matches the reference LogJson struct (iptables.go:164-177) so
 # the serialized lines are byte-identical.
@@ -43,7 +49,7 @@ def _log_json(
     number_of_fails: int,
     disable_logging: int,
 ) -> str:
-    return json.dumps(
+    return _ENCODE(
         {
             "path": path,
             "timestring": timestring,
@@ -57,8 +63,7 @@ def _log_json(
             "action": action,
             "number_of_fails": number_of_fails,
             "disable_logging": disable_logging,
-        },
-        separators=(",", ":"),
+        }
     )
 
 
@@ -67,8 +72,50 @@ def _format_ban_time(unix_seconds: float) -> str:
     return time.strftime("%Y-%m-%dT%H:%M:%S", time.localtime(unix_seconds))
 
 
+class RegexBan(NamedTuple):
+    """One exceeded window event of the regex rate limiter, as the banner
+    takes it: what the decision insert, the ban-log line and the
+    provenance record of one ban need between them."""
+
+    ip: str
+    host: str
+    decision: Decision
+    rule_name: str
+    rule_index: int
+    hits: int  # the window's count when the rule fired
+    log_time_unix: float
+    rest: str  # the log line after timestamp and address
+
+
 class BannerInterface:
     """iptables.go:117-126. Subclasses: Banner (real), MockBanner (tests)."""
+
+    def apply_regex_bans(
+        self, config: Config, records: Sequence[RegexBan],
+    ) -> List[Tuple[int, Exception]]:
+        """The exceeded events of one applied chunk, in reference order:
+        the decision insert, the ban-log line and the provenance record of
+        each.  → (index, what it raised) for every record whose effect
+        failed; the others are applied, once.  This body takes them one by
+        one through the three single-record calls; `Banner` takes the
+        chunk as one batch."""
+        failed = []
+        for k, r in enumerate(records):
+            try:
+                self.ban_or_challenge_ip(config, r.ip, r.decision, r.host)
+                self.log_regex_ban(
+                    config, r.log_time_unix, r.ip, r.rule_name, r.rest,
+                    r.decision,
+                )
+                # the ambient drain span supplies the admitting batch's
+                # trace id
+                provenance.record(
+                    provenance.SOURCE_RATE_LIMIT, r.ip, r.decision,
+                    rule=r.rule_name, rule_index=r.rule_index, hits=r.hits,
+                )
+            except Exception as e:  # noqa: BLE001 — a failing effector loses one line, not the batch
+                failed.append((k, e))
+        return failed
 
     def ban_or_challenge_ip(self, config: Config, ip: str, decision: Decision, domain: str) -> None:
         raise NotImplementedError
@@ -109,7 +156,10 @@ class Banner(BannerInterface):
         netlink_writer=None,
     ):
         self.decision_lists = decision_lists
-        self.regex_ban_records = 0  # ban-log lines written by log_regex_ban
+        self.regex_ban_records = 0  # ban-log lines of the regex rate limiter
+        self.regex_ban_batches = 0  # calls of apply_regex_bans
+        # one `write` and `flush` of a ban-log file each, by file
+        self.ban_log_writes: Dict[str, int] = {"main": 0, "temp": 0}
         self._ban_log = ban_log_file
         self._ban_log_temp = ban_log_file_temp
         self._ipset = ipset_instance
@@ -123,6 +173,55 @@ class Banner(BannerInterface):
     def ipset_batching(self) -> bool:
         return self.netlink_writer is not None and self._ipset is not None
 
+    def apply_regex_bans(
+        self, config: Config, records: Sequence[RegexBan],
+    ) -> List[Tuple[int, Exception]]:
+        """One pass a step over the whole chunk, so that what gives the
+        interpreter up or takes a lock — the decision lists' lock and the
+        mirror's native call, the write and the flush of a ban-log file,
+        the ledger's locks — happens once a chunk and not once a record.
+        Lists, files and ledger end as the one-by-one loop leaves them,
+        and every line is written and flushed before this returns."""
+        self.regex_ban_batches += 1
+        if log.isEnabledFor(logging.INFO):
+            for r in records:
+                log.info("BANNER: ban_or_challenge_ip %s %s", r.ip, r.decision)
+        expires = time.time() + config.expiring_decision_ttl_seconds
+        self.decision_lists.update_many(
+            [(r.ip, r.decision, r.host) for r in records], expires
+        )
+        failed: Dict[int, Exception] = {}
+        by_target: Tuple[list, list] = ([], [])  # main, temp: (index, line)
+        timestrings: Dict[int, str] = {}
+        for k, r in enumerate(records):
+            try:
+                if r.decision == Decision.IPTABLES_BLOCK:
+                    _ban_ip(config, r.ip, self)
+                built = self._regex_ban_line(
+                    config, r.log_time_unix, r.ip, r.rule_name, r.rest,
+                    r.decision, timestrings,
+                )
+            except Exception as e:  # noqa: BLE001 — a failing effector loses one line, not the batch
+                failed[k] = e
+                continue
+            if built is not None:
+                by_target[built[1]].append((k, built[0]))
+        for disable_logging, lines in enumerate(by_target):
+            if not lines:
+                continue
+            try:
+                self._write([line for _, line in lines], disable_logging)
+            except Exception as e:  # noqa: BLE001 — the lines of this write, not the batch
+                failed.update((k, e) for k, _ in lines)
+            else:
+                self.regex_ban_records += len(lines)
+
+        provenance.record_many(provenance.SOURCE_RATE_LIMIT, [
+            (r.ip, r.decision, r.rule_name, r.rule_index, r.hits)
+            for k, r in enumerate(records) if k not in failed
+        ])
+        return sorted(failed.items())
+
     def ban_or_challenge_ip(self, config: Config, ip: str, decision: Decision, domain: str) -> None:
         """iptables.go:273-294."""
         log.info("BANNER: ban_or_challenge_ip %s %s", ip, decision)
@@ -135,24 +234,42 @@ class Banner(BannerInterface):
         self, config: Config, log_time_unix: float, ip: str, rule_name: str,
         log_line_rest: str, decision: Decision,
     ) -> None:
-        """iptables.go:179-228.
+        built = self._regex_ban_line(
+            config, log_time_unix, ip, rule_name, log_line_rest, decision, {}
+        )
+        if built is not None:
+            self._write([built[0]], built[1])
+            self.regex_ban_records += 1
+
+    def _regex_ban_line(
+        self, config: Config, log_time_unix: float, ip: str, rule_name: str,
+        log_line_rest: str, decision: Decision, timestrings: Dict[int, str],
+    ) -> Optional[Tuple[str, int]]:
+        """iptables.go:179-228 → (the ban-log line, disable_logging); None
+        for a line of fewer than six words.
 
         log_line_rest looks like: `GET localhost:8081 GET /x HTTP/1.1 agent`
         words: [method, host, method, path, proto, ua(+ optional | status)].
-        """
+        `timestrings` keeps the time string of each whole second for the
+        caller's other records."""
         words = log_line_rest.split(" ", 5)
         if len(words) < 6:
             log.warning("log_regex_ban: not enough words")
-            return
+            return None
 
         disable_logging = 1 if config.disable_logging.get(words[1]) else 0
         # the nginx banjax_format appends "| <status>" after the UA for some
         # rules; keep only what's left of the first vertical bar
         client_ua = words[5].split("|", 1)[0].strip()
+        # localtime() rounds down too
+        second = math.floor(log_time_unix)
+        timestring = timestrings.get(second)
+        if timestring is None:
+            timestring = timestrings[second] = _format_ban_time(second)
 
         line = _log_json(
             path=words[3],
-            timestring=_format_ban_time(log_time_unix),
+            timestring=timestring,
             trigger=rule_name,
             client_ua=client_ua,
             client_ip=ip,
@@ -164,8 +281,7 @@ class Banner(BannerInterface):
             number_of_fails=1,
             disable_logging=disable_logging,
         )
-        self._write(line, disable_logging)
-        self.regex_ban_records += 1
+        return line, disable_logging
 
     def log_failed_challenge_ban(
         self, config: Config, ip: str, challenge_type: str, host: str, path: str,
@@ -188,13 +304,18 @@ class Banner(BannerInterface):
             number_of_fails=too_many_failed_challenges_threshold,
             disable_logging=disable_logging,
         )
-        self._write(line, disable_logging)
+        self._write([line], disable_logging)
 
-    def _write(self, line: str, disable_logging: int) -> None:
-        target = self._ban_log_temp if disable_logging == 1 else self._ban_log
+    def _write(self, lines: List[str], disable_logging: int) -> None:
+        """One write and one flush of the file these lines belong in."""
+        name, target = (
+            ("temp", self._ban_log_temp) if disable_logging == 1
+            else ("main", self._ban_log)
+        )
         with self._log_lock:
-            target.write(line + "\n")
+            target.write("\n".join(lines) + "\n")
             target.flush()
+            self.ban_log_writes[name] += 1
 
     def ipset_add(self, config: Config, ip: str) -> None:
         if self._ipset is None:

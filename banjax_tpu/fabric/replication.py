@@ -96,6 +96,14 @@ class ReplicatingBanner:
         self.inner.ban_or_challenge_ip(config, ip, decision, domain)
         self.replicator.publish(ip, decision, domain)
 
+    def apply_regex_bans(self, config, records):
+        """A chunk's bans as one batch through the inner banner, then
+        each decision out to the fabric, in the records' order."""
+        failed = self.inner.apply_regex_bans(config, records)
+        for r in records:
+            self.replicator.publish(r.ip, r.decision, r.host)
+        return failed
+
     def __getattr__(self, name: str) -> Any:
         # everything else (regex-ban logging, ipset ops) is host-local
         return getattr(self.inner, name)

@@ -40,7 +40,7 @@ from banjax_tpu.decisions.rate_limit import (
     RegexRateLimitStates,
 )
 from banjax_tpu.decisions.static_lists import StaticDecisionLists
-from banjax_tpu.effectors.banner import BannerInterface
+from banjax_tpu.effectors.banner import BannerInterface, RegexBan
 from banjax_tpu.matcher import compile_watch, nfa_jax, selectivity
 from banjax_tpu.matcher.api import ConsumeLineResult, Matcher, RuleResult
 from banjax_tpu.matcher.cpu_ref import OLD_LINE_CUTOFF_SECONDS
@@ -54,7 +54,7 @@ from banjax_tpu.matcher.workset import (
 )
 from banjax_tpu.matcher.rulec import compile_rules
 from banjax_tpu.matcher.rulecache import RuleCache
-from banjax_tpu.obs import flightrec, provenance, trace
+from banjax_tpu.obs import flightrec, trace
 from banjax_tpu.resilience import failpoints
 from banjax_tpu.resilience.breaker import CLOSED, CircuitBreaker
 from banjax_tpu.resilience.health import HealthRegistry, HealthStatus
@@ -1944,12 +1944,14 @@ class TpuMatcher(Matcher):
         and the fused pipeline.  Two parts, and only the first costs per
         event:
 
-          * effects, one iteration per EXCEEDED event in reference order
-            ((line, rule id) ascending = per-site-then-global): Banner
-            ban + ban-log line + provenance.  With the default rules every
-            log line is a window event and about three in a thousand of
-            those exceed (the window restarts at 0 on an exceed); the
-            others have no effect to replay.
+          * effects, one record per EXCEEDED event in reference order
+            ((line, rule id) ascending = per-site-then-global), and the
+            chunk's records to the banner as one batch
+            (`apply_regex_bans`): ban + ban-log line + provenance, every
+            line written and flushed before this returns.  With the
+            default rules every log line is a window event and about
+            three in a thousand of those exceed (the window restarts at
+            0 on an exceed); the others have no effect to replay.
           * per-line ConsumeLineResults: owed to `results` as a deferred
             fill over the chunk's arrays (LazyResults.defer), built when
             a caller reads a line's `rule_results` — the sync entry
@@ -1970,30 +1972,28 @@ class TpuMatcher(Matcher):
             except Exception:  # noqa: BLE001 — sketch is passive
                 log.exception("traffic sketch rule-pressure update failed")
         exc = np.flatnonzero(events.exceeded)
-        for row, idx in zip(
-            events.line[exc].tolist(), events.rule[exc].tolist()
-        ):
-            i, p = work[row]
-            rule = self._entries[idx][1]
-            try:
-                self.banner.ban_or_challenge_ip(
-                    self.config, p.ip, rule.decision, p.host
-                )
-                self.banner.log_regex_ban(
-                    self.config, p.timestamp_ns / 1e9, p.ip,
-                    rule.rule, p.rest, rule.decision,
-                )
+        if exc.size:
+            # a line is built only for the rows that exceeded
+            lines = work.lines_at(events.line[exc])
+            entries = self._entries
+            records = []
+            for (_, p), idx in zip(lines, events.rule[exc].tolist()):
+                rule = entries[idx][1]
                 # fixed-window semantics: the ban fires the hit after the
-                # threshold; the ambient drain span supplies the admitting
-                # batch's trace id
-                provenance.record(
-                    provenance.SOURCE_RATE_LIMIT, p.ip,
-                    rule.decision, rule=rule.rule, rule_index=idx,
-                    hits=rule.hits_per_interval + 1,
+                # threshold
+                records.append(RegexBan(
+                    p.ip, p.host, rule.decision, rule.rule, idx,
+                    rule.hits_per_interval + 1, p.timestamp_ns / 1e9, p.rest,
+                ))
+            try:
+                failed = self.banner.apply_regex_bans(self.config, records)
+            except Exception as e:  # noqa: BLE001 — the chunk's effects are not tried again: a ban may not be inserted or logged twice
+                failed = [(k, e) for k in range(len(records))]
+            for k, e in failed:
+                log.error(
+                    "error applying rules to log line", exc_info=e
                 )
-            except Exception:  # noqa: BLE001 — a failing effector loses one line, not the batch
-                log.exception("error applying rules to log line")
-                results[i].error = True
+                results[lines[k][0]].error = True
 
         results.defer(_LineResultsFill(
             self, len(work), bits, sparse, events, live_rows,
@@ -2239,14 +2239,12 @@ class TpuMatcher(Matcher):
         result.seen_ip = seen_ip
         result.rate_limit_result = rate_limit_result
         if rate_limit_result.exceeded:
-            self.banner.ban_or_challenge_ip(self.config, p.ip, rule.decision, p.host)
-            self.banner.log_regex_ban(
-                self.config, p.timestamp_ns / 1e9, p.ip, rule.rule, p.rest, rule.decision
-            )
-            provenance.record(
-                provenance.SOURCE_RATE_LIMIT, p.ip, rule.decision,
-                rule=rule.rule, hits=rule.hits_per_interval + 1,
-            )
+            # a batch of one; what it raises is the caller's, as before
+            for _, e in self.banner.apply_regex_bans(self.config, [RegexBan(
+                p.ip, p.host, rule.decision, rule.rule, -1,
+                rule.hits_per_interval + 1, p.timestamp_ns / 1e9, p.rest,
+            )]):
+                raise e
         return result
 
 

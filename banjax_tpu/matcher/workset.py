@@ -257,6 +257,24 @@ class NativeWork:
         for k in range(len(self.rows)):
             yield self[k]
 
+    def lines_at(self, ks) -> list:
+        """[self[k] for k in ks] off one pass over the columns: the
+        replay's route to the few rows of a chunk that have an effect."""
+        ks = np.asarray(ks, dtype=np.int64)
+        ips_u, hosts_u, nb, deferred = (
+            self.ips_u, self.hosts_u, self.nb, self.defer_map
+        )
+        out = []
+        for nbrow, ip_j, host_j, ts in zip(
+            self.rows[ks].tolist(), self.ip_inv[ks].tolist(),
+            self.host_inv[ks].tolist(), self.ts_ns[ks].tolist(),
+        ):
+            p = deferred.get(nbrow)
+            if p is None:
+                p = LazyLine(nb, nbrow, ips_u[ip_j], hosts_u[host_j], ts)
+            out.append((nbrow, p))
+        return out
+
     def take(self, idx) -> "NativeWork":
         """Arbitrary-row subset (index array) — same table-sharing
         semantics as slicing; the pipeline's drain-time staleness filter
@@ -335,9 +353,12 @@ class ListWork(list):
             return ListWork(super().__getitem__(k))
         return super().__getitem__(k)
 
+    def lines_at(self, ks) -> list:
+        return [list.__getitem__(self, int(k)) for k in ks]
+
     def take(self, idx) -> "ListWork":
         """Arbitrary-row subset (index array) — NativeWork.take parity."""
-        return ListWork(list.__getitem__(self, int(i)) for i in idx)
+        return ListWork(self.lines_at(idx))
 
 
 class CompositeWork:
@@ -383,6 +404,22 @@ class CompositeWork:
             off = self.offsets[j]
             for i, p in w:
                 yield off + i, p
+
+    def lines_at(self, ks) -> list:
+        """[self[k] for k in ks]: one search for all of them, then each
+        part's own route for the rows that fall in it."""
+        ks = np.asarray(ks, dtype=np.int64)
+        part = np.searchsorted(self._starts, ks, side="right") - 1
+        out: list = [None] * len(ks)
+        for j in np.unique(part).tolist():
+            at = np.flatnonzero(part == j)
+            off = self.offsets[j]
+            for k, (i, p) in zip(
+                at.tolist(),
+                self.parts[j].lines_at(ks[at] - int(self._starts[j])),
+            ):
+                out[k] = (off + i, p)
+        return out
 
     def take(self, idx) -> "CompositeWork | ListWork":
         idx = np.asarray(idx, dtype=np.int64)

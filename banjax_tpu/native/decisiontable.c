@@ -249,6 +249,27 @@ int32_t dt_put(void *base, const char *key, int32_t key_len,
     return 0;
 }
 
+/* dt_put for n entries in their order, which share flags, expiry and
+ * now: entry i's key is keys[key_offs[i] .. key_offs[i+1]), its domain
+ * (hashed here as dt_site_hash does; empty = 0) doms[dom_offs[i] ..
+ * dom_offs[i+1]).  Each takes the writer lock for itself, as one by one.
+ * Returns how many were stored. */
+int32_t dt_put_many(void *base, int32_t n, const char *keys,
+                    const int32_t *key_offs, const char *doms,
+                    const int32_t *dom_offs, const uint8_t *decisions,
+                    int32_t flags, double expires, double now_s) {
+    int32_t stored = 0;
+    for (int32_t i = 0; i < n; i++) {
+        int32_t dom_len = dom_offs[i + 1] - dom_offs[i];
+        uint32_t site_hash =
+            dom_len > 0 ? dt_site_hash(doms + dom_offs[i], dom_len) : 0;
+        if (dt_put(base, keys + key_offs[i], key_offs[i + 1] - key_offs[i],
+                   decisions[i], flags, site_hash, expires, now_s) == 0)
+            stored++;
+    }
+    return stored;
+}
+
 /* Lock-free lookup.  Returns 0 on hit (outputs filled), -1 on miss,
  * -2 on a torn-read fault (reader retry budget exhausted — fall open). */
 int32_t dt_get(void *base, const char *key, int32_t key_len,

@@ -23,8 +23,13 @@ import subprocess
 import sysconfig
 import tempfile
 import threading
+import time
 from multiprocessing import shared_memory
-from typing import Optional, Tuple
+from typing import List, Optional, Sequence, Tuple
+
+import numpy as np
+
+from banjax_tpu.native.cptr import array_ptr
 
 log = logging.getLogger(__name__)
 
@@ -39,6 +44,9 @@ HEADER_BYTES = 128
 MAX_PROBE = 64
 
 FLAG_FROM_BASKERVILLE = 0x01
+
+_I32P = ctypes.POINTER(ctypes.c_int32)
+_U8P = ctypes.POINTER(ctypes.c_uint8)
 
 
 def _so_path() -> str:
@@ -99,6 +107,11 @@ def _load() -> Optional[ctypes.CDLL]:
             ctypes.c_int32, ctypes.c_uint32, ctypes.c_double,
             ctypes.c_double,
         ]
+        lib.dt_put_many.restype = ctypes.c_int32
+        lib.dt_put_many.argtypes = [
+            vp, ctypes.c_int32, ctypes.c_char_p, _I32P, ctypes.c_char_p,
+            _I32P, _U8P, ctypes.c_int32, ctypes.c_double, ctypes.c_double,
+        ]
         lib.dt_get.restype = ctypes.c_int32
         lib.dt_get.argtypes = [
             vp, ctypes.c_char_p, ctypes.c_int32, u8p, u8p, u32p, dp,
@@ -138,6 +151,13 @@ def _round_pow2(capacity: int) -> int:
     while cap < max(2, capacity):
         cap *= 2
     return cap
+
+
+def _offsets(parts: List[bytes]) -> np.ndarray:
+    """int32 [n + 1]: where each part starts in their join, and its end."""
+    offs = np.zeros(len(parts) + 1, dtype=np.int32)
+    np.cumsum([len(b) for b in parts], out=offs[1:])
+    return offs
 
 
 def _key(ip: str) -> bytes:
@@ -207,13 +227,36 @@ class ShmDecisionTable:
         dk = domain.encode("utf-8", "surrogatepass")
         site_hash = self._lib.dt_site_hash(dk, len(dk)) if dk else 0
         if now is None:
-            import time
-
             now = time.time()
         return self._lib.dt_put(
             base, key, len(key), int(decision), flags, site_hash,
             float(expires), float(now),
         ) == 0
+
+    def put_many(self, entries: Sequence[Tuple[str, int, str]],
+                 expires: float, from_baskerville: bool = False,
+                 now: Optional[float] = None) -> int:
+        """`put` for each (ip, decision, domain) in order, in one native
+        call over packed arrays → how many were stored."""
+        base = self._base_ptr
+        if base is None or not entries:
+            return 0
+        keys = [_key(ip) for ip, _, _ in entries]
+        doms = [d.encode("utf-8", "surrogatepass") for _, _, d in entries]
+        key_offs = _offsets(keys)
+        dom_offs = _offsets(doms)
+        decisions = np.fromiter(
+            (d for _, d, _ in entries), dtype=np.uint8, count=len(entries)
+        )
+        if now is None:
+            now = time.time()
+        return int(self._lib.dt_put_many(
+            base, len(entries), b"".join(keys), array_ptr(key_offs, _I32P),
+            b"".join(doms), array_ptr(dom_offs, _I32P),
+            array_ptr(decisions, _U8P),
+            FLAG_FROM_BASKERVILLE if from_baskerville else 0,
+            float(expires), float(now),
+        ))
 
     def get(self, ip: str) -> Optional[Tuple[int, float, bool]]:
         """(decision, expires, from_baskerville) or None — a torn-read
@@ -345,6 +388,14 @@ class PyDecisionTable:
             self._entries[ip] = (int(decision), float(expires),
                                  bool(from_baskerville))
             return True
+
+    def put_many(self, entries: Sequence[Tuple[str, int, str]],
+                 expires: float, from_baskerville: bool = False,
+                 now: Optional[float] = None) -> int:
+        return sum(
+            self.put(ip, decision, expires, from_baskerville, domain, now)
+            for ip, decision, domain in entries
+        )
 
     def get(self, ip: str) -> Optional[Tuple[int, float, bool]]:
         with self._lock:

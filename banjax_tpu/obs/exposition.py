@@ -186,6 +186,16 @@ def render_prometheus(
         w.sample(registry.PROM_FAMILIES["banjax_fused_pairs_total"],
                  fw.pairs_total)
 
+    # ban-log writes by file: with banjax_regex_ban_records_total,
+    # records a write
+    writes = getattr(
+        getattr(matcher, "banner", None), "ban_log_writes", None
+    ) if matcher else None
+    if writes is not None:
+        fam = registry.PROM_FAMILIES["banjax_ban_log_writes_total"]
+        for target, v in writes.items():
+            w.sample(fam, v, {"target": target})
+
     # the prefilter plan: its routes, and the factor bucket that hit
     # most rows of the last batch read back
     pf = getattr(matcher, "_prefilter", None) if matcher else None

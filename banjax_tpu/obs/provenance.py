@@ -16,9 +16,10 @@ Design constraints, in the trace recorder's mold (obs/trace.py):
     single attribute check.  On is the default (unlike tracing): records
     fire only on decision events — bans, list hits, expiries — which are
     orders of magnitude rarer than log lines.
-  * **On = lock-cheap.**  One lock acquisition per record, a tuple store
-    into a preallocated per-source ring (oldest overwritten), and one
-    counter bump for the ``banjax_decision_inserts_total{source,
+  * **On = lock-cheap.**  One lock acquisition per record — or per
+    applied chunk, whose bans arrive as one ``record_many`` — a tuple
+    store into a preallocated per-source ring (oldest overwritten), and
+    one counter bump for the ``banjax_decision_inserts_total{source,
     decision}`` family.  Nothing is formatted per record; ``explain()``
     pays the formatting cost at query time.
   * **Passive by construction.**  Recording reads its inputs and writes
@@ -48,7 +49,7 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 from banjax_tpu.obs import trace
 
@@ -108,33 +109,54 @@ class ProvenanceLedger:
         ``trace_id`` defaults to the ambient span's trace id — a ban
         fired on a traced pipeline drain thread is attributed to the
         admitting batch with no plumbing at the call site."""
-        if not self.enabled:
+        self.record_many(
+            source, ((ip, decision, rule, rule_index, hits),), trace_id
+        )
+
+    def record_many(
+        self, source: str,
+        items: Sequence[Tuple[str, object, str, int, Optional[int]]],
+        trace_id: Optional[int] = None,
+    ) -> None:
+        """`record` for each (ip, decision, rule, rule_index, hits) in
+        order — the decisions one applied chunk inserted: one ambient
+        trace id, one pair of timestamps, one hold of the source ring's
+        lock and one of the counter lock for all of them."""
+        if not self.enabled or not items:
             return
         if source not in self._rings:
             source = SOURCE_STATIC  # never raise from a record path
         if trace_id is None:
             trace_id = trace.current_trace_id()
-        decision_s = str(decision)
-        origin_node, origin_trace = "", 0
+        trace_id = int(trace_id)
+        t_mono, t_wall = time.monotonic(), time.time()
         resolver = _origin_resolver
-        if resolver is not None:
-            try:
-                origin = resolver(ip)
-                if origin:
-                    origin_node, origin_trace = str(origin[0]), int(origin[1])
-            except Exception:  # resolution must never break a record path
-                pass
-        rec = (ip, decision_s, source, rule, int(rule_index), hits,
-               int(trace_id), time.monotonic(), time.time(),
-               origin_node, origin_trace)
-        lock = self._locks[source]
-        with lock:
+        recs = []
+        for ip, decision, rule, rule_index, hits in items:
+            origin_node, origin_trace = "", 0
+            if resolver is not None:
+                try:
+                    origin = resolver(ip)
+                    if origin:
+                        origin_node = str(origin[0])
+                        origin_trace = int(origin[1])
+                except Exception:  # resolution must never break a record path
+                    pass
+            recs.append((ip, str(decision), source, rule, int(rule_index),
+                         hits, trace_id, t_mono, t_wall,
+                         origin_node, origin_trace))
+        ring, size = self._rings[source], self.ring_size
+        with self._locks[source]:
             n = self._ns[source]
-            self._rings[source][n % self.ring_size] = rec
-            self._ns[source] = n + 1
-        key = (source, decision_s)
+            for rec in recs:
+                ring[n % size] = rec
+                n += 1
+            self._ns[source] = n
+        counters = self._counters
         with self._counter_lock:
-            self._counters[key] = self._counters.get(key, 0) + 1
+            for rec in recs:
+                key = (source, rec[1])
+                counters[key] = counters.get(key, 0) + 1
 
     # ---- queries ----
 
@@ -240,3 +262,11 @@ def record(source: str, ip: str, decision, rule: str = "",
            rule_index: int = -1, hits: Optional[int] = None,
            trace_id: Optional[int] = None) -> None:
     _ledger.record(source, ip, decision, rule, rule_index, hits, trace_id)
+
+
+def record_many(
+    source: str,
+    items: Sequence[Tuple[str, object, str, int, Optional[int]]],
+    trace_id: Optional[int] = None,
+) -> None:
+    _ledger.record_many(source, items, trace_id)

@@ -351,6 +351,15 @@ FAMILIES: List[Family] = [
     Family(COUNTER, "ban-log records the regex rate limiter wrote",
            line_key="RegexBanRecords",
            prom="banjax_regex_ban_records_total"),
+    Family(COUNTER, "batches of regex bans the banner applied: the exceeded "
+           "window events of one applied chunk, or one record from the host "
+           "window pass (effectors/banner.py apply_regex_bans)",
+           line_key="BannerBatches", prom="banjax_banner_batches_total"),
+    Family(COUNTER, "writes of a ban-log file, each one write and one flush "
+           "of every line a batch or a single-record call had for that file "
+           "(main, or temp for hosts under disable_logging); "
+           "banjax_regex_ban_records_total over this is records a write",
+           prom="banjax_ban_log_writes_total", labels=("target",)),
     # ---- single-kernel fused path (kernels/fused_match_window.py) ----
     Family(GAUGE, "d2h bytes per committed single-kernel chunk (the "
            "one-pull witness: flags + pairs + events in ONE buffer)",

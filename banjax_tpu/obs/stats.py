@@ -186,11 +186,14 @@ class MatcherStats:
                 out["PrefilterCandidates"] = getattr(
                     matcher._prefilter, "candidates_total", 0
                 )
-            records = getattr(
-                getattr(matcher, "banner", None), "regex_ban_records", None
-            )
-            if records is not None:
-                out["RegexBanRecords"] = records
+            banner = getattr(matcher, "banner", None)
+            for key, attr in (
+                ("RegexBanRecords", "regex_ban_records"),
+                ("BannerBatches", "regex_ban_batches"),
+            ):
+                n = getattr(banner, attr, None)
+                if n is not None:
+                    out[key] = n
             fw = getattr(matcher, "_fw_pipeline", None)
             if fw is not None:
                 out["PipelineFusedBatches"] = fw.fused_batches

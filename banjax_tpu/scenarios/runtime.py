@@ -45,6 +45,7 @@ from banjax_tpu.decisions.rate_limit import (
     RegexRateLimitStates,
 )
 from banjax_tpu.decisions.static_lists import StaticDecisionLists
+from banjax_tpu.effectors.banner import BannerInterface
 from banjax_tpu.obs import flightrec as flightrec_mod
 from banjax_tpu.scenarios import oracle as oracle_mod
 from banjax_tpu.scenarios import stats as scen_stats
@@ -61,7 +62,7 @@ from banjax_tpu.scenarios.shapes import (
 _WARM_IP = "9.254.254.254"  # outside every shape's IP space
 
 
-class RecordingBanner:
+class RecordingBanner(BannerInterface):
     """Effect sink for scenario runs: records (ip, rule) ban events and
     decisions instead of touching ipset/dynamic lists — the same role as
     tests' MockBanner, local so the harness has no test-tree import."""

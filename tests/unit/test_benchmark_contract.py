@@ -450,6 +450,100 @@ def test_capped1k_family_is_on_metrics_and_its_reader_reads_it(
 
 
 @pytest.fixture(scope="module")
+def banner_scrapes():
+    """`/metrics` before and after three batches through the scheduler,
+    the fused matcher and the real banner: every line of each batch
+    crosses an instant rule, one host in four is under `disable_logging`.
+    Tracing is off."""
+    import io
+
+    from banjax_tpu.decisions.dynamic_lists import DynamicDecisionLists
+    from banjax_tpu.decisions.rate_limit import (
+        FailedChallengeRateLimitStates,
+    )
+    from banjax_tpu.effectors.banner import Banner
+    from banjax_tpu.obs.exposition import render_prometheus
+    from benchmark.harness import prom
+
+    assert not trace.enabled()
+    cfg = config_from_yaml_text(yaml.safe_dump({
+        "regexes_with_rates": [{
+            "rule": "instant", "regex": ".*blockme.*", "interval": 1,
+            "hits_per_interval": 0, "decision": "nginx_block"}],
+        "disable_logging": {"quiet.org": True}}))
+    cfg.matcher_device_windows = True
+    lists = DynamicDecisionLists(start_sweeper=False)
+    banner = Banner(lists, io.StringIO(), io.StringIO(), ipset_instance=None)
+    m = TpuMatcher(cfg, banner, StaticDecisionLists(cfg),
+                   RegexRateLimitStates())
+    now = time.time()
+    sched = PipelineScheduler(lambda: m, now_fn=lambda: now)
+
+    def scrape():
+        return prom.parse(render_prometheus(
+            lists, RegexRateLimitStates(), FailedChallengeRateLimitStates(),
+            matcher=m, pipeline=sched,
+        ))
+
+    sched.start()
+    before = scrape()
+    for k in range(3):
+        sched.submit([
+            f"{now:.6f} 1.2.{k}.{i} GET {'quiet.org' if i % 4 == 1 else 'h.com'}"
+            f" GET /blockme{i} HTTP/1.1 ua -" for i in range(40)
+        ])
+        assert sched.flush(120)
+    sched.stop()
+    after = scrape()
+    m.close()
+    return before, after
+
+
+@pytest.mark.parametrize("family,labels", [
+    ("banjax_ban_log_writes_total", {"target": "main"}),
+    ("banjax_ban_log_writes_total", {"target": "temp"}),
+    ("banjax_banner_batches_total", {}),
+    ("banjax_regex_ban_records_total", {}),
+])
+def test_banner_family_is_on_metrics_with_tracing_off(
+        banner_scrapes, family, labels):
+    """The families of the banner's batch entry (ISSUE 42): a write of a
+    ban-log file and a batch are counted where they happen and exported
+    with tracing off; with the records they give records a write."""
+    from benchmark.harness import prom
+
+    before, snap = banner_scrapes
+    assert family in {f.prom for f in registry.FAMILIES}
+    assert prom.value(before, family, **labels) == 0
+    want = {"banjax_regex_ban_records_total": 120,
+            "banjax_banner_batches_total": 3,
+            "banjax_ban_log_writes_total": 3}[family]
+    # one batch an applied chunk, and one write a file a batch
+    assert prom.value(snap, family, **labels) == want
+    assert prom.value(snap, "banjax_pipeline_batches_total") == 3
+
+
+@pytest.mark.parametrize("scrapes,want", [
+    ("pair", pytest.approx(1e3 * 6 / 120)),
+    ("empty", None),        # a program without the family: the parent
+    ("no_lines", None),     # nothing drained between the scrapes
+])
+def test_ban_log_writes_reader(banner_scrapes, scrapes, want):
+    from benchmark.harness import found
+
+    before, snap = banner_scrapes
+    prom0, prom1 = {"pair": (before, snap), "empty": ({}, {}),
+                    "no_lines": (snap, snap)}[scrapes]
+    ctx = {"prom0": prom0, "prom1": prom1, "trace": None, "trace_lines": 0,
+           "mean_len": 0.0}
+    assert found.module("layers", "ban_log_writes_per_kline").read(ctx) == want
+    if scrapes == "pair":
+        # every line is a record here: the per-record path would read 1,000
+        assert found.module("layers", "ban_records_per_kline").read(ctx) \
+            == pytest.approx(1e3)
+
+
+@pytest.fixture(scope="module")
 def shadow_scrape():
     """`/metrics` after a stream whose addresses all fire the rule and
     turn a 64-slot table over twice, so that events are absorbed, records

@@ -99,6 +99,12 @@ def test_rehearsal_stream_through_the_product_equals_the_plain_reference():
     assert m.pipelined_fused_fallbacks == 0 and m.fallback_batches == 0
     assert sum(m._fw_pipeline.overflow_causes.values()) == 0
     assert m.device_windows.eviction_count > 50
+    # a chunk's records reach the ban log as one batch: at most one write
+    # of each of the two files an applied chunk
+    writes = sum(m.banner.ban_log_writes.values())
+    assert 0 < writes <= 2 * m.pipelined_fused_chunks
+    assert m.banner.regex_ban_batches <= m.pipelined_fused_chunks
+    assert m.banner.regex_ban_records > 10 * writes
     # the hottest factor bucket of the last batch is far under what the
     # compaction holds: the cap's `GET ` is no factor of stage 1
     hot = selectivity.hottest_bucket(m._prefilter.plan,

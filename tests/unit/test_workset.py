@@ -83,6 +83,30 @@ def test_native_work_defer_map_overrides(nb):
     assert i == 2 and got is p
 
 
+@pytest.mark.parametrize("rows", [[], [7], [0, 3, 3, 5], [6, 1, 7, 0]])
+def test_native_work_lines_at_is_indexing_row_by_row(nb, rows):
+    """The replay's route to the few rows of a chunk that have an effect
+    (PR 42): one pass over the columns, a line per asked row."""
+    from banjax_tpu.matcher.workset import CompositeWork
+
+    sentinel = ParsedLine(timestamp_ns=5, ip="9.9.9.9", host="d", rest="r")
+    w = _work_from(nb)
+    w.defer_map[3] = sentinel
+    want = [w[k] for k in rows]
+    got = w.lines_at(np.asarray(rows, dtype=np.int32))
+    key = lambda pair: (  # noqa: E731
+        pair[0], pair[1].ip, pair[1].host, pair[1].timestamp_ns, pair[1].rest)
+    assert list(map(key, got)) == list(map(key, want))
+    assert all(p is sentinel for i, p in got if i == 3)
+    assert all(isinstance(p, LazyLine) for i, p in got if i != 3)
+    # a subset keeps its own numbering, and two shards the batch's
+    sub = w.take(np.asarray([1, 3, 6]))
+    assert [i for i, _ in sub.lines_at([2, 0])] == [6, 1]
+    both = CompositeWork([w, sub], [0, 8])
+    assert list(map(key, both.lines_at([9, 2, 10]))) \
+        == list(map(key, [both[9], both[2], both[10]]))
+
+
 def test_list_work_interface():
     mk = lambda ip, host, ts: ParsedLine(
         timestamp_ns=ts, ip=ip, host=host, rest="r"
