@@ -13,7 +13,11 @@ per-rule to the CPU path.
 Extra keys beyond the reference (all optional, default to reference behavior):
   matcher:              "cpu" (default, Go-semantics reference path) or "tpu"
   matcher_batch_lines:  device batch size for the TPU matcher
-  matcher_max_line_len: padded line length for the TPU matcher
+  matcher_max_line_len: the TPU matcher's SHORT width: columns of the
+                        host's class matrix and of the fused program's
+                        first operand; a longer line (up to 8,192 bytes)
+                        travels in one of two wider ones
+                        (matcher/longrows.py)
 """
 
 from __future__ import annotations

@@ -59,6 +59,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from banjax_tpu.obs import trace
+from banjax_tpu.matcher import longrows
 from banjax_tpu.matcher.prefilter import FusedPrefilter
 from banjax_tpu.matcher.windows import DeviceWindows, EventBatch
 
@@ -81,6 +82,7 @@ class _Pend:
     K: int
     P: int
     E: int                 # window-event capacity of the chunk's program
+    KL: tuple = ()         # the long operands of the chunk's program
     state: str = "submitted"
     flags: Optional[np.ndarray] = None     # [4] after resolve
     events_buf: object = None              # the decoded host buffer
@@ -154,6 +156,14 @@ class FusedWindowsPipeline:
         # interaction with window state or results
         self._traffic_sketch = traffic_sketch
         self._progs = {}            # (Bp, L_p) → build_single_program's
+        # (Bp, L_p) → the program that takes a long operand beside the
+        # short one, and whether this process has met a line over the
+        # short width.  From the first such line on every chunk dispatches
+        # a program of this table (real traffic has one in every chunk,
+        # and a warm-up then builds what the stream will use); a process
+        # that never meets one builds and dispatches none of them
+        self._progs_long = {}
+        self.long_rows_seen = False
         self._scan_interpret = bool(scan_interpret)
         # device-side ok chain: each program's commit gates on its
         # predecessor's ok scalar, so an overflow poisons every already-
@@ -165,9 +175,13 @@ class FusedWindowsPipeline:
         self.sk_d2h_bytes_total = 0  # the one-pull d2h witness
         # fused dispatches that committed nothing, by what overflowed:
         # the chunk's own candidates / (row, rule) pairs / window events,
-        # or `chain` — gated by an overflowing predecessor's chain scalar
+        # or `chain` — gated by an overflowing predecessor's chain scalar;
+        # and `long_rows`, the caller's count: batches it cut into
+        # smaller chunks because a chunk held more lines over the short
+        # width than its long operand has room for (nothing replayed)
         self.overflow_causes = {
             "candidates": 0, "pairs": 0, "events": 0, "chain": 0,
+            "long_rows": 0,
         }
         # window events committed fused, by where the program took them
         # from: its (row, rule) pairs or its always-columns' set bits
@@ -176,6 +190,10 @@ class FusedWindowsPipeline:
         # dispatch read, one that overflowed included): with rules of
         # single sites, what the site mask left of stage 2's set bits
         self.pairs_total = 0
+        # long rows stage 1's gate passed on to stage 2, and their bytes
+        # (what the second stage-2 launch scanned)
+        self.long_candidates = 0
+        self.long_candidate_bytes = 0
         plan = prefilter.plan
         self._is_always = np.zeros(max(1, n_rules), dtype=bool)
         self._is_always[np.asarray(plan.a_idx, dtype=np.int64)] = True
@@ -198,11 +216,13 @@ class FusedWindowsPipeline:
 
     # ---- the program: match + window commit in ONE dispatch ----
 
-    def _single_prog(self, Bp: int, L_p: int):
+    def _single_prog(self, Bp: int, L_p: int, KL: tuple = ()):
         """The fused match+window program for one (rows, line length)
-        bucket, built on first use."""
+        bucket, built on first use; with `KL` (longrows.operands'
+        pairs), the one that takes the chunk's long rows beside."""
         key = (Bp, L_p)
-        hit = self._progs.get(key)
+        progs = self._progs_long if KL else self._progs
+        hit = progs.get(key)
         if hit is not None:
             return hit
         from banjax_tpu.matcher.kernels import fused_match_window as fmw
@@ -212,9 +232,9 @@ class FusedWindowsPipeline:
             Bp, L_p, f_idx=self._f_idx, a_idx=self._a_idx,
             aw=self._aw, ae=self._ae,
             scan_fn=fmw.window_scan(self._scan_interpret),
-            skip_table=self.skip_table,
+            skip_table=self.skip_table, KL=KL,
         )
-        self._progs[key] = hit
+        progs[key] = hit
         return hit
 
     # ---- host API (submit → resolve → collect, each in chunk order) ----
@@ -222,7 +242,7 @@ class FusedWindowsPipeline:
     def submit(
         self, cls_ids: np.ndarray, lens: np.ndarray, slots: np.ndarray,
         ts_s: np.ndarray, ts_ns: np.ndarray, host_idx: np.ndarray,
-        live: Optional[np.ndarray] = None,
+        live: Optional[np.ndarray] = None, long_rows=None,
     ) -> _Pend:
         """Dispatch the fused program for one chunk (slot pins held by
         the caller, ownership passes to the pipeline).  The window state
@@ -231,7 +251,10 @@ class FusedWindowsPipeline:
         resolve is a pure pull — and any number of chunks may be
         submitted ahead of their resolves.  `live` (bool [B], default
         all-true) is the commit mask — the caller's staleness drop
-        composed as a program input.  The dispatch runs under the windows
+        composed as a program input.  `long_rows` = (rows, lens, class
+        ids back to back) of the chunk's lines over the short width, no
+        more of a width than longrows.operands has room for (the caller's
+        check): rows whose `lens` entry is 0.  The dispatch runs under the windows
         lock: maintenance (evictions/restores) drains first, and the
         state-chain order == seq order because both are taken inside the
         same critical section."""
@@ -240,7 +263,17 @@ class FusedWindowsPipeline:
         cls_ids = np.asarray(cls_ids, dtype=np.int32)
         lens = np.asarray(lens, dtype=np.int32)
         B = cls_ids.shape[0]
-        combined, Bp, L_p = pf._assemble(cls_ids, lens, self._progs)
+        if long_rows is not None and len(long_rows[0]):
+            self.long_rows_seen = True
+        KL = long_op = ()
+        if self.long_rows_seen:
+            combined, Bp, L_p = pf._assemble(
+                cls_ids, lens, self._progs_long, full_width=True)
+            KL = longrows.operands(pf, Bp)
+            long_op = tuple(map(jnp.asarray, longrows.assemble(
+                pf, KL, long_rows, pad_row=Bp)))
+        else:
+            combined, Bp, L_p = pf._assemble(cls_ids, lens, self._progs)
 
         def pad(a):
             a = np.asarray(a, dtype=np.int32)
@@ -248,7 +281,7 @@ class FusedWindowsPipeline:
                 return a
             return np.concatenate([a, np.zeros(Bp - len(a), dtype=np.int32)])
 
-        fn, K, P, E = self._single_prog(Bp, L_p)
+        fn, K, P, E = self._single_prog(Bp, L_p, KL)
         host_idx_p, slots_p = pad(host_idx), pad(slots)
         ts_s_p, ts_ns_p = pad(ts_s), pad(ts_ns)
         live_p = np.zeros(Bp, dtype=np.uint8)
@@ -274,7 +307,7 @@ class FusedWindowsPipeline:
                 jnp.asarray(combined), jnp.int32(B),
                 jnp.asarray(host_idx_p), jnp.asarray(slots_p),
                 jnp.asarray(ts_s_p), jnp.asarray(ts_ns_p),
-                jnp.asarray(live_p),
+                jnp.asarray(live_p), *long_op,
             )
             wnd._state = new_state
             with self._cv:
@@ -285,11 +318,12 @@ class FusedWindowsPipeline:
             pass
         p = _Pend(
             seq=seq, sparse_buf=buf, bits_dev=bits_dev,
-            slots=np.asarray(slots), B=B, Bp=Bp, K=K, P=P, E=E,
+            slots=np.asarray(slots), B=B, Bp=Bp, K=K, P=P, E=E, KL=KL,
             # the whole h2d for the chunk: encoded classes + per-row
             # window metadata + the live mask + the chain scalar — still
             # no dense [B, n_rules] bitmap
-            h2d_bytes=combined.nbytes + 4 * 3 * Bp + Bp + 4,
+            h2d_bytes=combined.nbytes + 4 * 3 * Bp + Bp + 4
+            + sum(x.nbytes for x in long_op),
         )
         lap.mark("sketch")
         self._sketch_update(p)
@@ -447,6 +481,13 @@ class FusedWindowsPipeline:
             # as the program has room for them
             self.pf.candidates_total += min(int(p.flags[1]), p.K)
             self.pairs_total += int(p.flags[2])
+            if p.KL and p.K:
+                at = len(buf) - 4 * self.pf.n_buckets - 8
+                rows, nbytes = np.frombuffer(
+                    buf[at : at + 8].tobytes(), dtype="<i4")
+                self.pf.candidates_total += int(rows)
+                self.long_candidates += int(rows)
+                self.long_candidate_bytes += int(nbytes)
             if not p.flags[0]:
                 raise self._overflow(p)
             p.events_buf = buf

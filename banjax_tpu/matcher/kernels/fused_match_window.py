@@ -210,7 +210,7 @@ def scan_selftest(interpret: bool, E: int = 64) -> None:
 
 def build_single_program(
     pf, windows, active_table, n_rules: int, Bp: int, L_p: int, *,
-    f_idx, a_idx, aw, ae, scan_fn, skip_table=None,
+    f_idx, a_idx, aw, ae, scan_fn, skip_table=None, KL: tuple = (),
 ):
     """One jitted device program: match core + event list from the pairs
     and always-columns + overflow/chain gate + window commit + compact
@@ -220,13 +220,18 @@ def build_single_program(
       fn(state, chain_ok, combined, n_real, host_idx, slots, ts_s,
          ts_ns, live) -> (new_state, chain_ok_out, buf, bits_dev)
     with `state` donated (the HBM-resident window arrays mutate in
-    place) and `buf` the single uint8 pull:
+    place) and `buf` the single uint8 pull.  With `KL` (longrows.operands'
+    pairs) `fn` takes one more argument for each, last: the chunk's long
+    rows as longrows.assemble lays them out (prefilter._match_core scans
+    and merges them), and nothing else of the program or its output
+    changes:
 
       flags[4 × i32: ok, n_cand, n_pairs, n_events]
       ‖ (row, rule) pairs [4P]
       ‖ always-rule bits [Bp * na8]            (when the plan has any)
       ‖ ev line/rule/hits/start_s/start_ns [5 × 4E]
       ‖ ev match_type/exceeded/seen_ip [3 × E]
+      ‖ long rows stage 2 scanned, their bytes [2 × 4]   (KL, filters)
       ‖ hits per factor bucket [4F]            (when the plan filters)
 
     The head (flags ‖ pairs ‖ always bits) and the event tail are laid
@@ -235,7 +240,9 @@ def build_single_program(
     # the event ceiling follows the rows and the ruleset (always-columns
     # can fire on every row), not a constant of the window table
     block, K, P, max_events = pf.program_capacities(Bp)
-    core = pf._match_core(Bp, L_p, K, block)
+    core = pf._match_core(Bp, L_p, K, block, KL)
+    # stage 2's rows: the K candidate slots, then every long operand's
+    Kx = K + sum(rows for _, rows in KL) if K else 0
     plan = pf.plan
     n_always = plan.n_always
     n_filt = pf._n_filt
@@ -252,8 +259,8 @@ def build_single_program(
 
     @functools.partial(jax.jit, donate_argnums=(0,))
     def single(state, chain_ok, combined, n_real, host_idx, slots,
-               ts_s, ts_ns, live):
-        c = core(combined)
+               ts_s, ts_ns, live, *long_ops):
+        c = core(combined, *long_ops)
         keep = None
         if site_mask is not None:
             with jax.named_scope("site-mask"):
@@ -261,7 +268,7 @@ def build_single_program(
                 keep = site_mask[
                     host_idx[jnp.minimum(c["idx_caller_k"], Bp - 1)]
                 ]
-        pairs, n_pairs, pair_bits = pf.pairs_from_core(c, K, P, keep)
+        pairs, n_pairs, pair_bits = pf.pairs_from_core(c, Kx, P, keep)
         # dense caller-order bitmap, assembled on device
         bits = jnp.zeros((Bp, n_rules), dtype=jnp.uint8)
         if n_filt:
@@ -336,6 +343,10 @@ def build_single_program(
         parts.append(ev["match_type"].astype(jnp.uint8))
         parts.append(ev["exceeded"].astype(jnp.uint8))
         parts.append(ev["seen_ip"].astype(jnp.uint8))
+        if c.get("long_cand") is not None:
+            # behind the event tail, in front of the bucket hits: the
+            # long rows stage 2 scanned and their bytes
+            parts.append(le_bytes(c["long_cand"]))
         if c["bucket_hits"] is not None:
             # last, where prefilter.bucket_hits_of reads them whatever
             # the head and the event tail before them hold

@@ -679,11 +679,14 @@ class FusedPrefilter:
             Bp <<= 1
         return Bp
 
-    def _assemble(self, cls_ids: np.ndarray, lens: np.ndarray, built=()):
+    def _assemble(self, cls_ids: np.ndarray, lens: np.ndarray, built=(),
+                  full_width: bool = False):
         """→ (combined [Bp, 1 + L4|L_p] int32, Bp, L_p): the one-transfer
         input layout of _match_core (col 0 = lens; class ids packed 4 per
         int32 when the partition fits uint8).  `built`: the (Bp, L_p) keys
-        the caller already holds a program for."""
+        the caller already holds a program for.  `full_width`: L_p is the
+        matrix's own width whatever the batch's longest row (the programs
+        that take a long operand exist at that one short width)."""
         B = cls_ids.shape[0]
         Bp = self._row_bucket(max(1, B))
         block = self._block_for(Bp)
@@ -692,12 +695,13 @@ class FusedPrefilter:
         # them) — no pow2 rounding, which would scan up to 2x the bytes on
         # every batch
         cols = self._cols
-        max_len = int(lens.max()) if B else 0
+        max_len = cls_ids.shape[1] if full_width else (
+            int(lens.max()) if B else 0)
         L_p = max(cols, min(
             -(-cls_ids.shape[1] // cols) * cols,
             -(-max(1, max_len) // max(32, cols)) * max(32, cols),
         ))
-        if (Bp, L_p) not in built:
+        if (Bp, L_p) not in built and not full_width:
             # a first use is seconds of Mosaic in the hot path, and a
             # partial batch of a few short lines meets line-length
             # classes no full batch ever does: the narrowest program
@@ -835,7 +839,8 @@ class FusedPrefilter:
         pairs = jnp.where(slot < n_pairs, caller * R8 + col, -1)
         return pairs, n_pairs, bits
 
-    def _match_core(self, B: int, L_p: int, K: int, block: int):
+    def _match_core(self, B: int, L_p: int, K: int, block: int,
+                    KL: tuple = ()):
         """The traceable two-stage match body, shared by the sparse-output
         fused program and the fused matcher+windows pipeline
         (matcher/fused_windows.py). Input: [B, 1 + L4|L_p] int32 combined
@@ -844,7 +849,21 @@ class FusedPrefilter:
         intermediate a consumer needs: the candidate count, the stage-2
         packed rows with their caller-row mapping (feed pairs_from_core
         for the sparse output), and the always-rule bits in caller row
-        order."""
+        order.
+
+        `KL` (longrows.operands' (width, rows) pairs): the core takes one
+        more operand for each, the chunk's LONG rows of up to that width
+        (longrows.assemble: [rows, ...], the lines over the short width,
+        which ride the first operand as empty rows), scans it with one more
+        launch of each stage's
+        kernel at (rows, width), and merges what it finds at the rows' own
+        caller indices: the always-columns' bits into `ab_caller`, their
+        true lengths into `lens_raw`, and their stage-2 rows BEHIND the K
+        candidate slots — `m2p` and `idx_caller_k` are then K + the
+        operands' rows long, and everything downstream (site mask, pairs,
+        events, the dense bitmap) sees a long row as any other.  Stage 2
+        scans every long row stage 1's gate passes; there is no
+        compaction to overflow."""
         plan = self.plan
         f1 = self._stage1_raw(B, L_p, block)
         f2 = self._stage2(K, L_p, min(block, K)) if K else None
@@ -854,27 +873,82 @@ class FusedPrefilter:
         b_word, b_mask = self._b_word, self._b_mask
         shifts = jnp.asarray([0, 8, 16, 24], dtype=jnp.int32)
         packed_in = self._pack_input
-        L4 = -(-L_p // 4)
 
-        def core(cls_and_lens):
+        def unpack(op, skip: int, width: int):
+            """[rows, width] class ids of an operand whose first `skip`
+            columns are not class ids."""
+            if not packed_in:
+                return op[:, skip : skip + width]
+            w4 = -(-width // 4)
+            words = op[:, skip : skip + w4]
+            return (
+                (words[:, :, None] >> shifts[None, None, :]) & 0xFF
+            ).reshape(words.shape[0], w4 * 4)[:, :width]
+
+        def always_bits(acc, order):
+            """[rows, n_always] uint8 in operand order from raw accepts
+            in scan order."""
+            sel = (acc[a_word, :] & a_mask[:, None]) != 0        # [n_abr, rows]
+            ab = jnp.zeros((n_always, acc.shape[1]), dtype=jnp.uint8)
+            ab = ab.at[a_rule].max(sel.astype(jnp.uint8))
+            return jnp.zeros_like(ab.T).at[order].set(ab.T)
+
+        def long_launch(width: int, n: int):
+            """The scan of one long operand ([n, ...] at `width`) and
+            its merge into the core's result `c` (see _match_core)."""
+            block_l = self._block_for(n)
+            f1_l = self._stage1_raw(n, width, block_l)
+            f2_l = self._stage2(n, width, block_l) if K else None
+
+            def merge(c, long_op):
+                lens_op, row_op = long_op[:, 0], long_op[:, 1]   # [n]
+                order = jnp.argsort(lens_op)
+                lens = jnp.take(lens_op, order)
+                rows_o = jnp.take(row_op, order)  # caller rows; B = no row
+                cls_t = jnp.take(
+                    unpack(long_op, 2, width), order, axis=0).T
+                with jax.named_scope("long-rows"):
+                    acc1 = f1_l(cls_t, lens)                     # [W1, n]
+                    c["lens_raw"] = c["lens_raw"].at[row_op].set(
+                        lens_op, mode="drop")
+                    if n_always:
+                        c["ab_caller"] = c["ab_caller"].at[row_op].set(
+                            always_bits(acc1, order), mode="drop")
+                    if f2_l is None:
+                        return c
+                    cand = (acc1 & fmask[:, None]).max(axis=0) > 0   # [n]
+                    c["bucket_hits"] = c["bucket_hits"] + jnp.sum(
+                        (acc1[b_word, :] & b_mask[:, None]) != 0,
+                        axis=1, dtype=jnp.int32,
+                    )
+                    lens2 = jnp.where(cand, lens, 0)
+                    # what stage 2 scanned of them: rows, bytes
+                    c["long_cand"] = c.get("long_cand", 0) + jnp.stack([
+                        jnp.sum(cand, dtype=jnp.int32),
+                        jnp.sum(lens2, dtype=jnp.int32),
+                    ])
+                    m2p = f2_l(cls_t, lens2) & (
+                        cand[:, None] * jnp.uint8(0xFF))
+                c["m2p"] = jnp.concatenate([c["m2p"], m2p])
+                c["idx_caller_k"] = jnp.concatenate([
+                    c["idx_caller_k"], jnp.where(cand, rows_o, jnp.int32(B)),
+                ])
+                return c
+
+            return merge
+
+        merges = [long_launch(width, rows) for width, rows in KL]
+
+        def short_rows(cls_and_lens):
             lens_raw = cls_and_lens[:, 0]                        # [B]
-            if packed_in:
-                words = cls_and_lens[:, 1 : 1 + L4]              # [B, L4]
-                cls_rows = (
-                    (words[:, :, None] >> shifts[None, None, :]) & 0xFF
-                ).reshape(words.shape[0], L4 * 4)[:, :L_p]
-            else:
-                cls_rows = cls_and_lens[:, 1 : 1 + L_p]          # [B, L_p]
+            cls_rows = unpack(cls_and_lens, 1, L_p)              # [B, L_p]
             order = jnp.argsort(lens_raw)                        # ascending
             lens = jnp.take(lens_raw, order)
             cls_t = jnp.take(cls_rows, order, axis=0).T          # [L_p, B]
             acc1 = f1(cls_t, lens)                               # [W1, B]
             ab_caller = None
             if n_always:
-                sel = (acc1[a_word, :] & a_mask[:, None]) != 0   # [n_abr, B]
-                ab = jnp.zeros((n_always, acc1.shape[1]), dtype=jnp.uint8)
-                ab = ab.at[a_rule].max(sel.astype(jnp.uint8))
-                ab_caller = jnp.zeros_like(ab.T).at[order].set(ab.T)
+                ab_caller = always_bits(acc1, order)
             if f2 is None:
                 return {
                     "lens_raw": lens_raw, "n_cand": jnp.int32(0),
@@ -906,6 +980,12 @@ class FusedPrefilter:
                 "idx_caller_k": idx_caller_k, "ab_caller": ab_caller,
                 "bucket_hits": bucket_hits,
             }
+
+        def core(cls_and_lens, *long_ops):
+            c = short_rows(cls_and_lens)
+            for merge, long_op in zip(merges, long_ops):
+                c = merge(c, long_op)
+            return c
 
         return core
 

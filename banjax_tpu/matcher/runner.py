@@ -12,8 +12,9 @@ Pipeline per batch (SURVEY.md §7.1 / BASELINE.json north star):
 
 The device decides only the regex-match bitmap — the O(lines × rules) hot
 loop of /root/reference/internal/regex_rate_limiter.go:234. Rule/line cases
-the device can't decide exactly (rules rulec can't lower; non-ASCII or
-over-length lines) fall back to host `re` per rule or per line, so the
+the device can't decide exactly (rules rulec can't lower; lines with a
+byte over 0x7F or past 8,192 bytes — matcher/longrows.py) fall back to
+host `re` per rule or per line, so the
 observable Decision stream is byte-identical to CpuMatcher for any input.
 
 Selected by `matcher: tpu` in banjax-config.yaml (the Matcher interface
@@ -41,10 +42,11 @@ from banjax_tpu.decisions.rate_limit import (
 )
 from banjax_tpu.decisions.static_lists import StaticDecisionLists
 from banjax_tpu.effectors.banner import BannerInterface, RegexBan
-from banjax_tpu.matcher import compile_watch, nfa_jax, selectivity
+from banjax_tpu.matcher import compile_watch, longrows, nfa_jax, selectivity
 from banjax_tpu.matcher.api import ConsumeLineResult, Matcher, RuleResult
 from banjax_tpu.matcher.cpu_ref import OLD_LINE_CUTOFF_SECONDS
 from banjax_tpu.matcher.encode import ParsedLine, encode_for_match, parse_line
+from banjax_tpu.matcher.longrows import LONG_WIDTH
 from banjax_tpu.matcher.workset import (
     CompositeWork,
     LazyResults,
@@ -112,6 +114,14 @@ class TpuMatcher(Matcher):
         # overflowed into the classic replay mid-pipeline
         self.pipelined_fused_chunks = 0
         self.pipelined_fused_fallbacks = 0
+        # lines over the short width (matcher_max_line_len) that the fused
+        # program's long operand can decide, and their bytes, counted
+        # where a batch is encoded; and the batches that could not go
+        # fused for a line's sake, by cause: a byte over 0x7F, or a length
+        # past LONG_WIDTH
+        self.long_lines = 0
+        self.long_line_bytes = 0
+        self.unfused_batches = {"line_length": 0, "non_ascii": 0}
         # host wall seconds inside the drain's `effector-replay` spans
         # (event decode + shadow absorb + Banner replay of committed chunks)
         self.effector_replay_s = 0.0
@@ -192,6 +202,12 @@ class TpuMatcher(Matcher):
             i for i in range(len(self._entries)) if not self.compiled.device_ok[i]
         ]
         self._params = nfa_jax.match_params(self.compiled)
+        # a byte's class as the long operand carries it (one byte a class
+        # id wherever the fused program packs its input)
+        self._class_of_byte = np.asarray(
+            self.compiled.byte_to_class[:256],
+            dtype=np.uint8 if self.compiled.n_classes <= 256 else np.int32,
+        )
         self._max_len = config.matcher_max_line_len
         self._max_batch = max(_MIN_BUCKET, config.matcher_batch_lines)
 
@@ -810,21 +826,18 @@ class TpuMatcher(Matcher):
         # 2a. fully-fused pipeline: match + window apply in ONE device
         #     dispatch (matcher/fused_windows.py) — no dense bitmap ever
         #     crosses the host boundary. Eligible when every rule is
-        #     device-decidable and no line in the batch needs host eval.
+        #     device-decidable and every row is the device's to decide:
+        #     in the short matrix, or a long row its chunk's operand holds
+        #     (_fused_rows_ok).
         if (
             fused_ok
             and self.device_windows is not None
             and self._fw_pipeline is not None
         ):
-            if pre_encoded is not None:
-                cls_ids, lens, host_eval = pre_encoded
-            else:
-                cls_ids, lens, host_eval = encode_for_match(
-                    self.compiled, [p.rest for _, p in work], self._max_len
-                )
-                pre_encoded = (cls_ids, lens, host_eval)
-            if not host_eval.any():
-                self._consume_via_pipeline(work, cls_ids, lens, results)
+            if pre_encoded is None:
+                pre_encoded = self._encode_work(work)
+            if self._fused_rows_ok(pre_encoded):
+                self._consume_via_pipeline(work, pre_encoded, results)
                 return results
 
         # 2b. device match bitmap for all matchable lines
@@ -880,8 +893,10 @@ class TpuMatcher(Matcher):
     #   * classic bitmap — _match_bits_submit/collect, dense [B, n_rules]
     #     pulled to host, window apply (device or host) entirely at finish.
     #   * fused (matcher/fused_windows.py) — when the fused
-    #     matcher+windows pipeline is active and the batch has no
-    #     host-eval rows, submit dispatches ONE program per chunk (match
+    #     matcher+windows pipeline is active and every row of the batch
+    #     is the device's to decide (_fused_rows_ok: a line over the
+    #     short width rides its chunk's long operand), submit dispatches
+    #     ONE program per chunk (match
     #     + window commit, gated in the program on its overflow flags),
     #     any number of batches ahead; finish pulls each chunk's buffer
     #     in admission order and replays its events.  The dense bitmap
@@ -951,8 +966,9 @@ class TpuMatcher(Matcher):
             # correctness first, the fast path needs every shard native
             pre_encoded = None
             if native_pre:
-                pre_encoded = tuple(
-                    np.concatenate([p[k] for p in pres]) for k in range(3)
+                pre_encoded = (
+                    *(np.concatenate([p[k] for p in pres]) for k in range(3)),
+                    _long_len_of_shards(pres),
                 )
         return self._pipeline_state(lines, results, work, pre_encoded)
 
@@ -964,13 +980,78 @@ class TpuMatcher(Matcher):
         }
         if self._fw_pipeline is not None and len(work):
             if pre_encoded is None:
-                pre_encoded = encode_for_match(
-                    self.compiled, [p.rest for _, p in work], self._max_len
-                )
-                state["pre"] = pre_encoded
-            if not pre_encoded[2].any():  # no host-eval rows in the batch
+                pre_encoded = state["pre"] = self._encode_work(work)
+            if self._fused_rows_ok(pre_encoded):
                 state["fused_eligible"] = True
         return state
+
+    def _encode_work(self, work) -> tuple:
+        """The Python encode of a work batch, as the native gate leaves
+        it: (cls_ids [n, max_len], lens, host_eval, long_len) — the last
+        as longrows.long_lens has it, or None for a batch whose every row
+        the dense matrix holds (the rule; nothing then looks at it)."""
+        rests = [p.rest for _, p in work]
+        pre = encode_for_match(self.compiled, rests, self._max_len)
+        if not pre[2].any():
+            return (*pre, None)
+        return (*pre, longrows.long_lens(rests, pre[2]))
+
+    def _fused_rows_ok(self, pre_encoded) -> bool:
+        """Whether every row of an encoded batch is the fused program's
+        to decide.  A row of the short matrix is, and so is a LONG row
+        (ASCII, over the short width, within LONG_WIDTH: it travels in its
+        chunk's long operand, _fused_chunks); a row with a byte over 0x7F
+        or past LONG_WIDTH is the host's, and one such row takes its batch
+        the classic way, counted by cause.  Counts the batch's long rows
+        on the way."""
+        host_eval, long_len = pre_encoded[2], pre_encoded[3]
+        if long_len is None:
+            return True
+        is_long = long_len > 0
+        self.long_lines += int(is_long.sum())
+        self.long_line_bytes += int(long_len[is_long].sum())
+        if (host_eval & ~is_long).any():
+            cause = "line_length" if (long_len < 0).any() else "non_ascii"
+            self.unfused_batches[cause] += 1
+            return False
+        return True
+
+    def _fused_chunks(self, pre_encoded) -> List[Tuple[int, int]]:
+        """[(start, stop)]: the rows of an encoded batch as fused chunks,
+        matcher_batch_lines rows each — and fewer (halved until it fits)
+        where a chunk would hold more long rows of a width than its
+        operand of that width has room for (longrows.operands).  A line's
+        length so never takes a batch off the fused path: a burst of long
+        lines costs more, smaller dispatches.  More chunks than the
+        batch's size alone asks for is a cut batch (_count_cut)."""
+        n, mb = len(pre_encoded[1]), self._max_batch
+        if pre_encoded[3] is None:
+            return [(s, min(n, s + mb)) for s in range(0, n, mb)]
+        pf = self._prefilter
+        which = longrows.operand_of(pre_encoded[3])
+        # long rows of each operand's width before every row
+        upto = [
+            np.concatenate([[0], np.cumsum(which == j)])
+            for j in range(len(longrows.LONG_WIDTHS))
+        ]
+        out, s = [], 0
+        while s < n:
+            size = min(mb, n - s)
+            while any(
+                u[s + size] - u[s] > rows for u, (_, rows) in zip(
+                    upto, longrows.operands(pf, pf._row_bucket(size)))
+            ):
+                size = max(1, size // 2)
+            out.append((s, s + size))
+            s += size
+        return out
+
+    def _count_cut(self, chunks, n: int) -> None:
+        """Count a batch of `n` rows that is dispatched as `chunks`
+        (_fused_chunks') if its long rows cut it (overflow cause
+        `long_rows`; nothing replays)."""
+        if len(chunks) > -(-n // self._max_batch):
+            self._fw_pipeline.overflow_causes["long_rows"] += 1
 
     # the scheduler passes its now_fn() into pipeline_submit when this
     # attribute is set — the fused path commits window state at submit,
@@ -987,7 +1068,7 @@ class TpuMatcher(Matcher):
         if (
             state.get("fused_eligible")
             and self.device_windows is not None
-            and len(state["work"]) <= self._max_batch
+            and len(self._fused_chunks(state["pre"])) == 1
             and self._single_kernel_ordered()
         ):
             self._resolve_submit(state)
@@ -1002,8 +1083,9 @@ class TpuMatcher(Matcher):
                 # warm-tier writes land before the NEXT batch's admission
                 # probe/refill — a refused IP can never race its own
                 # state.  (Their results ride state["results"] out at
-                # finish; the shrunk work keeps host_eval all-false, so
-                # fused eligibility computed at begin remains valid.)
+                # finish; the shrunk work has no row the batch did not
+                # have, so fused eligibility computed at begin remains
+                # valid, and its chunks are cut anew at dispatch.)
                 self._consume_refused(work_r, pre_r, state["results"])
                 if not len(state["work"]):
                     return
@@ -1119,7 +1201,8 @@ class TpuMatcher(Matcher):
     def _single_kernel_ordered(self) -> bool:
         """Commit-at-submit is only order-safe while no EARLIER admitted
         batch still owes a drain-time window apply (a classic-pend
-        fallback from slot refusal or host-eval rows).  While one is
+        fallback from slot refusal or a row only the host's `re`
+        decides).  While one is
         outstanding, this batch joins the classic path too — the single
         drain thread then applies everything in admission order."""
         with self._drain_window_lock:
@@ -1131,33 +1214,54 @@ class TpuMatcher(Matcher):
         the batch.  Each chunk is final on return, and the 10 s staleness
         cutoff is applied here as the program's live-mask input (`now`,
         from the scheduler's clock; falls back to wall time on the
-        direct-call path).  Returns False — with every partial entry
-        abandoned — when slot allocation refuses, so the caller falls
+        direct-call path).  Returns False — nothing dispatched — when
+        slot allocation refuses, so the caller falls
         back to the classic bitmap protocol for this batch.  Any other
         failure abandons the entries and re-raises (the scheduler then
         drains the batch generically: an already-committed chunk's
         generic rerun can double-count window hits, never Banner
         effects)."""
-        # slots the submit stage's pass already assigned (and pinned)
-        # for this, the batch's only chunk: the chunk's submit owns the
-        # pins from its call on, a failure before it gives them back
-        resolved = {}
-        if "slots" in state:
-            if state["slots"] is None:
-                del state["slots"]
-                return False
-            resolved = {"slots": state.pop("slots")}
+        # every chunk's slots, pinned, before any chunk is dispatched: a
+        # chunk commits at its dispatch, so a batch that went the classic
+        # way after one had would count that chunk's hits twice.  One
+        # chunk (the rule, unless long rows cut the batch): the submit
+        # stage's pass has them (`state["slots"]`), or the chunk's own
+        # submit gets them (None here).  Pins not yet handed to a chunk's
+        # submit are given back on every way out
+        placed: list = []
         entries = []
         try:
             failpoints.check("matcher.device")
             work = state["work"]
-            cls_ids, lens, _ = state["pre"]
+            pre = state["pre"]
             if now is None:
                 now = time.time()
             lap = trace.lap()
-            for s in range(0, len(work), self._max_batch):
+            chunks = self._fused_chunks(pre)
+            if "slots" in state:
+                placed = [state.pop("slots")]
+                if placed[0] is None:
+                    return False  # placement refused
+                if len(chunks) > 1:
+                    # the pass placed the batch as ONE chunk, and the rows
+                    # it split off since left a smaller one, with a
+                    # smaller long operand: placed again, chunk by chunk
+                    self.device_windows.release_pins(placed.pop())
+            elif len(chunks) == 1:
+                placed = [None]
+            if len(chunks) > 1:
+                lap.mark("pass")
+                for s, stop in chunks:
+                    placed.append(self._slots_for_work(work[s:stop]))
+                    if placed[-1] is None:
+                        # more distinct IPs than free+unpinned slots
+                        lap.mark("other")
+                        return False
+            self._count_cut(chunks, len(work))
+            placed.reverse()
+            for s, stop in chunks:
                 lap.mark("operands", row0=s)
-                wc = work[s : s + self._max_batch]
+                wc = work[s:stop]
                 live = stale = None
                 ages_s = now - wc.ts_array() / 1e9
                 st = ages_s > OLD_LINE_CUTOFF_SECONDS
@@ -1166,29 +1270,28 @@ class TpuMatcher(Matcher):
                 # a phase ends before a span opens over what follows it
                 lap.mark("other")
                 with trace.span("program-ab-fused", args={"row0": s}):
-                    handed, resolved = resolved, {}
                     e = self._submit_pipeline_chunk(
-                        wc,
-                        cls_ids[s : s + self._max_batch],
-                        lens[s : s + self._max_batch],
-                        live=live, **handed,
+                        wc, _rows_of(pre, slice(s, stop)),
+                        live=live, slots=placed.pop(),
                     )
                 if e is None:
-                    # more distinct IPs than free+unpinned slots (in-flight
-                    # batches hold pins until their drains): classic path
-                    for prev in entries:
-                        self._fw_pipeline.abandon(prev["pend"])
+                    # the batch's one chunk, its own placement refused
+                    # (in-flight batches hold pins until their drains):
+                    # nothing is committed, classic path
+                    assert not entries
                     return False
                 e["row0"] = s
                 e["live"] = live
                 e["stale"] = stale
                 entries.append(e)
         except Exception:
-            if resolved:
-                self.device_windows.release_pins(resolved["slots"])
             for prev in entries:
                 self._fw_pipeline.abandon(prev["pend"])
             raise
+        finally:
+            for slots in placed:
+                if slots is not None:
+                    self.device_windows.release_pins(slots)
         state["fused"] = entries
         return True
 
@@ -1466,9 +1569,8 @@ class TpuMatcher(Matcher):
         ref = np.flatnonzero(~row_mask)
         pre_a = pre_r = None
         if pre_encoded is not None:
-            cls_ids, lens, host_eval = pre_encoded
-            pre_a = (cls_ids[adm], lens[adm], host_eval[adm])
-            pre_r = (cls_ids[ref], lens[ref], host_eval[ref])
+            pre_a = _rows_of(pre_encoded, adm)
+            pre_r = _rows_of(pre_encoded, ref)
         return work.take(adm), pre_a, work.take(ref), pre_r, adm
 
     def _consume_refused(self, work, pre_encoded, results) -> None:
@@ -1651,19 +1753,30 @@ class TpuMatcher(Matcher):
             cls_ids = nb.cls_ids[rows]
             lens = nb.lens[rows]
         host_eval = (flags[rows] & native.FLAG_HOST_EVAL) != 0
+        long_len = None
+        if host_eval.any():
+            # beside host_eval, as longrows.long_lens has it: a LONG row's
+            # length, -1 for a row past LONG_WIDTH, 0 for every other row
+            rest_len = nb.rest_len[:n][rows]
+            long_len = np.where(
+                (flags[rows] & native.FLAG_LONG) != 0, rest_len,
+                np.where(host_eval & (rest_len > LONG_WIDTH), -1, 0),
+            ).astype(np.int32)
         if deferred.any():
             # deferred rows were Python-parsed: encode them the Python way
             # into the same arrays
             d_idx = np.flatnonzero(deferred)
-            d_cls, d_lens, d_he = encode_for_match(
-                self.compiled,
-                [work[int(k)][1].rest for k in d_idx],
-                self._max_len,
+            d_cls, d_lens, d_he, d_long = self._encode_work(
+                [work[int(k)] for k in d_idx]
             )
             cls_ids[d_idx] = d_cls
             lens[d_idx] = d_lens
             host_eval[d_idx] = d_he
-        return work, (cls_ids, lens, host_eval)
+            if d_long is not None:
+                if long_len is None:
+                    long_len = np.zeros(len(lens), dtype=np.int32)
+                long_len[d_idx] = d_long
+        return work, (cls_ids, lens, host_eval, long_len)
 
     def _with_window_slots(self, work, split, apply_fn, results) -> None:
         """Shared scaffolding for every device-windows consume path: slot
@@ -1705,7 +1818,7 @@ class TpuMatcher(Matcher):
                 dw.release_pins(slots)
             raise
 
-    def _consume_via_pipeline(self, work, cls_ids, lens, results) -> None:
+    def _consume_via_pipeline(self, work, pre, results) -> None:
         """The sync entry's fused path (matcher/fused_windows.py): each
         chunk's program commits at submit, and at most two chunks are in
         flight — chunk N's device→host pull, decode and replay hide
@@ -1732,18 +1845,19 @@ class TpuMatcher(Matcher):
             )
 
         try:
-            for s in range(0, max(1, len(work)), self._max_batch):
-                wc = work[s : s + self._max_batch]
-                cc = cls_ids[s : s + self._max_batch]
-                lc = lens[s : s + self._max_batch]
-                entry = self._submit_pipeline_chunk(wc, cc, lc)
+            chunks = self._fused_chunks(pre)
+            self._count_cut(chunks, len(work))
+            for s, stop in chunks:
+                wc = work[s:stop]
+                pc = _rows_of(pre, slice(s, stop))
+                entry = self._submit_pipeline_chunk(wc, pc)
                 if entry is None:
                     # slot allocation refused (more distinct IPs than
                     # free+unpinned slots): drain in-flight pins, then run
                     # this chunk through the splitting sync path
                     while q:
                         settle(q.pop(0))
-                    self._pipeline_chunk_sync(wc, cc, lc, results)
+                    self._pipeline_chunk_sync(wc, pc, results)
                     continue
                 q.append(entry)
                 if len(q) > 1:
@@ -1760,12 +1874,27 @@ class TpuMatcher(Matcher):
                     log.exception("pipeline drain after failure also failed")
             raise
 
-    def _submit_pipeline_chunk(self, work, cls_ids, lens, live=None,
-                               slots=None):
+    def _long_rows_of(self, work, long_len):
+        """A chunk's long rows as FusedWindowsPipeline.submit takes them:
+        (rows, lens, their class ids back to back) — gathered from the
+        parse blob here, the one place the host touches their bytes after
+        the parse; None for a chunk without one.  The chunk is one of
+        _fused_chunks', so its long operand has room for them."""
+        if long_len is None:
+            return None
+        ks = np.flatnonzero(long_len > 0)
+        if not ks.size:
+            return None
+        with trace.span("long-rows", args={"rows": int(ks.size)}):
+            flat, lens = work.rest_bytes(ks)
+            return ks.astype(np.int32), lens, self._class_of_byte[flat]
+
+    def _submit_pipeline_chunk(self, work, pre, live=None, slots=None):
         """Allocate slots (unless the caller's pass already has: `slots`,
         pinned) + dispatch the chunk's fused program (`live` is its
-        commit mask); None when slot allocation refuses. Pins transfer
-        to the pipeline on success."""
+        commit mask) over `pre`, the chunk's rows of the encoded batch
+        (one of _fused_chunks'); None when slot allocation refuses. Pins
+        transfer to the pipeline on success."""
         from banjax_tpu.matcher.windows import split_ns
 
         dw = self.device_windows
@@ -1781,36 +1910,44 @@ class TpuMatcher(Matcher):
             ts_s, ts_ns = split_ns(work.ts_array())
             host_idx = work.host_idx(self._host_row)
             pend = self._fw_pipeline.submit(
-                cls_ids, lens, slots, ts_s, ts_ns, host_idx, live=live
+                pre[0], pre[1], slots, ts_s, ts_ns, host_idx, live=live,
+                long_rows=self._long_rows_of(work, pre[3]),
             )
         except Exception:
             dw.release_pins(slots)
             raise
         return {
-            "work": work, "cls": cls_ids, "lens": lens, "slots": slots,
+            "work": work, "pre": pre, "slots": slots,
             "ts_s": ts_s, "ts_ns": ts_ns, "host_idx": host_idx,
             "pend": pend,
         }
 
-    def _pipeline_chunk_sync(self, work, cls_ids, lens, results) -> None:
+    def _pipeline_chunk_sync(self, work, pre, results) -> None:
         """Non-overlapped fallback for a chunk whose slot allocation
         refused even with nothing in flight: the shared splitting
         scaffolding recursively halves until allocations fit, running each
-        piece submit→collect serially."""
+        piece submit→collect serially.  A piece with more long rows than
+        ITS operand holds (a half has half the room) goes back through
+        _consume_via_pipeline, which cuts it to fit."""
         from banjax_tpu.matcher.fused_windows import PipelineOverflow
 
-        def make(cls_c, lens_c):
+        def make(pre_c):
             def apply_fn(work_c, slots, ts_s, ts_ns, host_idx, results_c):
                 dw = self.device_windows
+                if len(self._fused_chunks(pre_c)) > 1:
+                    dw.release_pins(slots)
+                    self._consume_via_pipeline(work_c, pre_c, results_c)
+                    return
                 try:
                     pend = self._fw_pipeline.submit(
-                        cls_c, lens_c, slots, ts_s, ts_ns, host_idx
+                        pre_c[0], pre_c[1], slots, ts_s, ts_ns, host_idx,
+                        long_rows=self._long_rows_of(work_c, pre_c[3]),
                     )
                 except Exception:
                     dw.release_pins(slots)
                     raise
                 e = {
-                    "work": work_c, "cls": cls_c, "lens": lens_c,
+                    "work": work_c, "pre": pre_c,
                     "slots": slots, "ts_s": ts_s, "ts_ns": ts_ns,
                     "host_idx": host_idx, "pend": pend,
                 }
@@ -1825,11 +1962,11 @@ class TpuMatcher(Matcher):
                 )
 
             def split(lo, hi):
-                return make(cls_c[lo:hi], lens_c[lo:hi])
+                return make(_rows_of(pre_c, slice(lo, hi)))
 
             return split, apply_fn
 
-        self._with_window_slots(work, *make(cls_ids, lens), results)
+        self._with_window_slots(work, *make(pre), results)
 
     def _log_hottest_bucket(self) -> None:
         """Beside a candidates overflow: which factor bucket hit most
@@ -1866,9 +2003,16 @@ class TpuMatcher(Matcher):
             if ov.candidate_overflow:
                 self._log_hottest_bucket()
                 # stage 2 never saw the excess lines: recompute full-NFA
+                # (the chunk's long rows, empty in the short matrix, by
+                # the host's `re`: the classic way, exact and slow)
+                cls_ids, lens, host_eval, _ = e["pre"]
                 bits = self._single_stage_bits(
-                    n, e["cls"], e["lens"], np.zeros(n, dtype=bool),
-                    np.arange(n),
+                    n, cls_ids, lens, host_eval, np.flatnonzero(~host_eval),
+                )
+                work = e["work"]
+                self._host_eval_bits(
+                    bits, np.flatnonzero(host_eval),
+                    lambda row: work[row][1].rest,
                 )
                 if live is not None:
                     bits = bits * live[:, None].astype(np.uint8)
@@ -2061,9 +2205,9 @@ class TpuMatcher(Matcher):
             None if pre_encoded is not None
             else [p.rest for _, p in work]
         )
-        cls_ids, lens, host_eval = pre_encoded or encode_for_match(
+        cls_ids, lens, host_eval = (pre_encoded or encode_for_match(
             self.compiled, rests, self._max_len
-        )
+        ))[:3]
         device_rows = np.flatnonzero(~host_eval)
         pend = {
             "n": n, "work": work, "rests": rests, "cls": cls_ids,
@@ -2106,7 +2250,8 @@ class TpuMatcher(Matcher):
 
     def _match_bits_collect(self, pend: dict) -> np.ndarray:
         """Force the submitted match to a host [N, n_rules] bitmap and run
-        the host fallback passes (over-length lines; unlowerable rules)."""
+        the host fallback passes (lines the dense matrix does not hold;
+        unlowerable rules)."""
         n = pend["n"]
         work, rests = pend["work"], pend["rests"]
         cls_ids, lens = pend["cls"], pend["lens"]
@@ -2148,11 +2293,7 @@ class TpuMatcher(Matcher):
             bits = self._single_stage_collect(n, pend["chunks"])
 
         # host fallback: whole lines the device can't decide
-        for row in np.flatnonzero(host_eval):
-            rest = rest_of(int(row))
-            for idx, (_, rule) in enumerate(self._entries):
-                if rule.regex.search(rest) is not None:
-                    bits[row, idx] = 1
+        self._host_eval_bits(bits, np.flatnonzero(host_eval), rest_of)
         # host fallback: rules the compiler couldn't lower
         for idx in self._host_rule_idx:
             rule = self._entries[idx][1]
@@ -2160,6 +2301,15 @@ class TpuMatcher(Matcher):
                 if rule.regex.search(rest_of(int(row))) is not None:
                     bits[row, idx] = 1
         return bits
+
+    def _host_eval_bits(self, bits, rows, rest_of) -> None:
+        """Decide `rows` of a batch with the host's `re`, every rule over
+        the whole request string, into `bits`."""
+        for row in rows.tolist():
+            rest = rest_of(row)
+            for idx, (_, rule) in enumerate(self._entries):
+                if rule.regex.search(rest) is not None:
+                    bits[row, idx] = 1
 
     def _single_stage_submit(self, cls_ids, lens, device_rows) -> list:
         """Dispatch the full-NFA match per max_batch chunk; the returned
@@ -2312,3 +2462,20 @@ def _bucket(n: int, cap: int) -> int:
     while b < n:
         b <<= 1
     return min(b, max(cap, _MIN_BUCKET))
+
+
+def _rows_of(pre_encoded: tuple, idx) -> tuple:
+    """Rows `idx` (a slice or an index array) of an encoded batch
+    (TpuMatcher._encode_work's tuple)."""
+    return tuple(None if a is None else a[idx] for a in pre_encoded)
+
+
+def _long_len_of_shards(pres) -> Optional[np.ndarray]:
+    """The fourth array of a batch encoded in shards: None where no shard
+    has a row over the short width."""
+    if all(p[3] is None for p in pres):
+        return None
+    return np.concatenate([
+        np.zeros(len(p[1]), dtype=np.int32) if p[3] is None else p[3]
+        for p in pres
+    ])

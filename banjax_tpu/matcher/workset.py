@@ -17,7 +17,8 @@ end-to-end wall). This module keeps the batch COLUMNAR end to end:
 The interface both provide:
   len(work); work[int] -> (orig_index, line); work[slice] -> same kind;
   iteration over (orig_index, line); unique_ips() -> (list[str], inverse);
-  host_idx(host_row) -> np.int32 per row; ts_array() -> np.int64 per row.
+  host_idx(host_row) -> np.int32 per row; ts_array() -> np.int64 per row;
+  rest_bytes(ks) -> the rows' regex haystacks as bytes, back to back.
 """
 
 from __future__ import annotations
@@ -313,6 +314,34 @@ class NativeWork:
     def ts_array(self) -> np.ndarray:
         return self.ts_ns
 
+    def rest_bytes(self, ks) -> Tuple[np.ndarray, np.ndarray]:
+        """(uint8 flat, int32 lens): the `rest` of rows `ks` as bytes,
+        one after the other — slices of the parse blob joined, no string
+        per row (the long rows' way to the device: a few dozen rows a
+        chunk, for which this beats an index array a byte)."""
+        ks = np.asarray(ks, dtype=np.int64)
+        nbrows = self.rows[ks]
+        if self.defer_map and np.isin(
+            nbrows, np.fromiter(self.defer_map, np.int64, len(self.defer_map))
+        ).any():
+            return _rest_bytes_of_lines(self.lines_at(ks))
+        blob = self.nb.blob
+        off = self.nb.rest_off[nbrows].tolist()
+        ln = self.nb.rest_len[nbrows].tolist()
+        return _flat([blob[o : o + n] for o, n in zip(off, ln)])
+
+
+def _flat(raws) -> Tuple[np.ndarray, np.ndarray]:
+    return (
+        np.frombuffer(b"".join(raws), dtype=np.uint8),
+        np.fromiter(map(len, raws), dtype=np.int32, count=len(raws)),
+    )
+
+
+def _rest_bytes_of_lines(lines) -> Tuple[np.ndarray, np.ndarray]:
+    """rest_bytes over materialized (index, line) pairs."""
+    return _flat([p.rest.encode("utf-8", "surrogatepass") for _, p in lines])
+
 
 class ListWork(list):
     """The [(orig_index, ParsedLine)] fallback path (python parse / no
@@ -359,6 +388,9 @@ class ListWork(list):
     def take(self, idx) -> "ListWork":
         """Arbitrary-row subset (index array) — NativeWork.take parity."""
         return ListWork(self.lines_at(idx))
+
+    def rest_bytes(self, ks) -> Tuple[np.ndarray, np.ndarray]:
+        return _rest_bytes_of_lines(self.lines_at(ks))
 
 
 class CompositeWork:
@@ -463,6 +495,21 @@ class CompositeWork:
 
     def ts_array(self) -> np.ndarray:
         return np.concatenate([w.ts_array() for w in self.parts])
+
+    def rest_bytes(self, ks) -> Tuple[np.ndarray, np.ndarray]:
+        """`ks` ascending, as every positional subset here."""
+        ks = np.asarray(ks, dtype=np.int64)
+        part = np.searchsorted(self._starts, ks, side="right") - 1
+        got = [
+            self.parts[j].rest_bytes(ks[part == j] - int(self._starts[j]))
+            for j in np.unique(part).tolist()
+        ]
+        if not got:
+            return np.zeros(0, dtype=np.uint8), np.zeros(0, dtype=np.int32)
+        return (
+            np.concatenate([g[0] for g in got]),
+            np.concatenate([g[1] for g in got]),
+        )
 
 
 def unique_spans(

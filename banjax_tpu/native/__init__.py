@@ -24,6 +24,7 @@ from typing import Optional
 
 import numpy as np
 
+from banjax_tpu.matcher.longrows import LONG_WIDTH
 from banjax_tpu.native.cptr import array_ptr
 
 log = logging.getLogger(__name__)
@@ -32,6 +33,7 @@ FLAG_ERROR = 1
 FLAG_OLD = 2
 FLAG_DEFER = 4
 FLAG_HOST_EVAL = 8
+FLAG_LONG = 16  # beside HOST_EVAL: ASCII and within longrows.LONG_WIDTH
 
 _SRC = os.path.join(os.path.dirname(__file__), "fastparse.c")
 _LOCK = threading.Lock()
@@ -91,7 +93,8 @@ def _load() -> Optional[ctypes.CDLL]:
         lib.fp_parse_encode.restype = ctypes.c_int64
         lib.fp_parse_encode.argtypes = [
             u8p, ctypes.c_int64, i64p, i64p, ctypes.c_int64,
-            i32p, ctypes.c_int32, ctypes.c_double, ctypes.c_double,
+            i32p, ctypes.c_int32, ctypes.c_int32, ctypes.c_double,
+            ctypes.c_double,
             i64p, u8p, i64p, i32p, i64p, i32p, i64p, i32p, i32p, i32p,
         ]
         lib.fp_dedup_spans.restype = ctypes.c_int64
@@ -162,7 +165,9 @@ class ParseScratch:
     """Reusable output buffers for parse_encode_batch.
 
     Fresh numpy allocations cost ~15 ms in page faults per 65k-line batch
-    (the [n, max_len] int32 class matrix alone is 33 MB); a caller that
+    (the [n, max_len] int32 class matrix alone is 33 MB at max_len 128;
+    its width is the SHORT width whatever the longest line — a longer
+    rest is flagged and left in the blob); a caller that
     parses batch after batch should own ONE scratch and pass it in. The
     returned ParsedBatch views alias the scratch — they are valid until
     the next parse_encode_batch call with the same scratch (the TpuMatcher
@@ -209,7 +214,10 @@ def parse_encode_batch(
     outputs alias the caller-owned buffers (see ParseScratch).
     `max_threads` caps the internal row-parallel fan-out — callers that
     are themselves one shard of a worker pool (the pipeline's sharded
-    encode) pass 1 so the pool's parallelism isn't multiplied."""
+    encode) pass 1 so the pool's parallelism isn't multiplied.
+    An ASCII rest over `max_len` and within longrows.LONG_WIDTH gets
+    FLAG_LONG beside FLAG_HOST_EVAL (its bytes stay in the blob:
+    `rest_off`)."""
     lib = _load()
     if lib is None:
         return None
@@ -249,7 +257,7 @@ def parse_encode_batch(
         lib.fp_parse_encode(
             blob_ptr, len(blob),
             P(s.starts[i0:], i64p), P(s.ends[i0:], i64p), cnt,
-            P(table, i32p), max_len, now_unix, old_cutoff,
+            P(table, i32p), max_len, LONG_WIDTH, now_unix, old_cutoff,
             P(s.ts_ns[i0:], i64p), P(s.flags[i0:], u8p),
             P(s.ip_off[i0:], i64p), P(s.ip_len[i0:], i32p),
             P(s.host_off[i0:], i64p), P(s.host_len[i0:], i32p),

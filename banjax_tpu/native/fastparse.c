@@ -32,6 +32,8 @@
 #define FLAG_OLD 2u       /* stale line (> cutoff seconds old)   */
 #define FLAG_DEFER 4u     /* caller must re-parse with Python    */
 #define FLAG_HOST_EVAL 8u /* rest too long / non-ASCII: host re  */
+#define FLAG_LONG 16u     /* with HOST_EVAL: ASCII, over max_len and
+                           * within long_len (the fused path's long rows) */
 
 /* Python float() accepts ASCII digits, one '.', exponent, sign; it also
  * accepts "_" digit separators and inf/nan words — those (and anything
@@ -193,6 +195,7 @@ int64_t fp_parse_encode(
     const int64_t *starts, const int64_t *ends, int64_t n_lines,
     const int32_t *byte_to_class, /* [256] */
     int32_t max_len,
+    int32_t long_len, /* 0: every over-length rest is the host's */
     double now_unix, double old_cutoff,
     /* outputs */
     int64_t *ts_ns_out, uint8_t *flags_out,
@@ -280,7 +283,16 @@ int64_t fp_parse_encode(
 
             /* encode rest: class 0 pad; non-ASCII or over-length -> host */
             if (restlen > (int64_t)max_len) {
+                /* not a row of the dense matrix; its bytes stay in the
+                 * blob, where the long operand's gather reads them */
                 r.flags |= FLAG_HOST_EVAL;
+                if (restlen <= (int64_t)long_len) {
+                    int64_t k = 0;
+                    while (k < restlen && rest[k] <= 0x7F)
+                        k++;
+                    if (k == restlen)
+                        r.flags |= FLAG_LONG;
+                }
             } else {
                 int64_t k;
                 for (k = 0; k < restlen; k++) {

@@ -185,6 +185,18 @@ def render_prometheus(
             w.sample(fam, v, {"source": source})
         w.sample(registry.PROM_FAMILIES["banjax_fused_pairs_total"],
                  fw.pairs_total)
+        w.sample(
+            registry.PROM_FAMILIES["banjax_matcher_long_candidates_total"],
+            fw.long_candidates)
+        w.sample(registry.PROM_FAMILIES[
+            "banjax_matcher_long_candidate_bytes_total"],
+            fw.long_candidate_bytes)
+
+    unfused = getattr(matcher, "unfused_batches", None) if matcher else None
+    if unfused is not None:
+        fam = registry.PROM_FAMILIES["banjax_matcher_unfused_batches_total"]
+        for cause, v in unfused.items():
+            w.sample(fam, v, {"cause": cause})
 
     # ban-log writes by file: with banjax_regex_ban_records_total,
     # records a write

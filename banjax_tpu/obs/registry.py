@@ -306,8 +306,31 @@ FAMILIES: List[Family] = [
     Family(COUNTER, "fused dispatches that committed nothing and were "
            "replayed classically, by what overflowed: the chunk's own "
            "candidates, (row, rule) pairs or window events, or chain (gated "
-           "by an overflowing predecessor)",
+           "by an overflowing predecessor); and long_rows: batches cut into "
+           "smaller chunks (nothing replayed) because a chunk held more "
+           "lines over the short width than its long operand has room for",
            prom="banjax_fused_overflows_total", labels=("cause",)),
+    Family(COUNTER, "lines over the short width (matcher_max_line_len) "
+           "that the fused program's long operand can decide: ASCII, at "
+           "most 8,192 bytes of request string; counted where a batch is "
+           "encoded (divide by lines: 3 % of a real access log)",
+           line_key="MatcherLongLines",
+           prom="banjax_matcher_long_lines_total"),
+    Family(COUNTER, "request-string bytes of the lines "
+           "banjax_matcher_long_lines_total counts",
+           line_key="MatcherLongLineBytes",
+           prom="banjax_matcher_long_line_bytes_total"),
+    Family(COUNTER, "long lines stage 1's gate passed on to stage 2 (the "
+           "rows the second long launch scanned)",
+           prom="banjax_matcher_long_candidates_total"),
+    Family(COUNTER, "request-string bytes of the lines "
+           "banjax_matcher_long_candidates_total counts",
+           prom="banjax_matcher_long_candidate_bytes_total"),
+    Family(COUNTER, "batches that went the classic way, whole, for one "
+           "line's sake, by cause: non_ascii (a byte over 0x7F), "
+           "line_length (a line past 8,192 bytes); no fused dispatch, so "
+           "banjax_pipelined_fused_fallbacks_total does not see them",
+           prom="banjax_matcher_unfused_batches_total", labels=("cause",)),
     Family(COUNTER, "window events committed by fused programs, by where "
            "the program took the event from: a (row, rule) pair of the "
            "filtered rules, or a set bit of an always-column (their sum is "

@@ -194,6 +194,13 @@ class MatcherStats:
                 n = getattr(banner, attr, None)
                 if n is not None:
                     out[key] = n
+            for key, attr in (
+                ("MatcherLongLines", "long_lines"),
+                ("MatcherLongLineBytes", "long_line_bytes"),
+            ):
+                n = getattr(matcher, attr, None)
+                if n is not None:
+                    out[key] = n
             fw = getattr(matcher, "_fw_pipeline", None)
             if fw is not None:
                 out["PipelineFusedBatches"] = fw.fused_batches

@@ -321,3 +321,73 @@ def test_repeated_fused_streams_accumulate_identically():
     assert pipe_log.getvalue() == sync_log.getvalue()
     assert pipe.device_windows.format_states() == \
         sync.device_windows.format_states()
+
+
+def _with_long_lines(lines, now, seed=3):
+    """Every 37th, 53rd and 71st line a long one: the per-site rule's
+    `blockme` behind 300-8,100 bytes of path (a match that begins past
+    the short width), a 400-byte POST (`POST .*`: an always-column), a
+    500-byte DELETE on the host that skips its rule."""
+    rng = random.Random(seed)
+
+    def pad(n):
+        return "".join(
+            rng.choice("abcdefghij0123456789/_=&+-") for _ in range(n))
+
+    lines = list(lines)
+    for i in range(0, len(lines), 37):
+        ip = f"1.2.{i % 4}.{i % 6}"
+        size = (300, 700, 2000, 8100)[i % 4]
+        lines[i] = (f"{now:f} {ip} GET per-site.com GET "
+                    f"/{pad(size)}/blockme HTTP/1.1 ua -")
+    for i in range(5, len(lines), 53):
+        ip = f"1.2.{i % 4}.{i % 6}"
+        lines[i] = (f"{now:f} {ip} POST example.com POST /{pad(400)} "
+                    "HTTP/1.1 ua -")
+    for i in range(9, len(lines), 71):
+        ip = f"1.2.{i % 4}.{i % 6}"
+        lines[i] = (f"{now:f} {ip} DELETE skipme.com DELETE /{pad(500)} "
+                    "HTTP/1.1 ua -")
+    return lines
+
+
+def test_long_lines_under_churn_are_byte_identical():
+    """Lines over the short width (matcher_max_line_len) ride the fused
+    program's long operand: under batch churn, with chunks that overflow
+    into the classic replay beside chunks that commit, the pipelined
+    fused output, the sync fused output and the classic protocol's all
+    equal the CPU reference — results, ban-log bytes, window state — and
+    no batch left the fused path for a line's length."""
+    now = time.time()
+    lines = _with_long_lines(_gen_lines(900, now), now)
+    n_long = sum(len(l.split(" ", 2)[-1]) > 256 for l in lines)
+    assert n_long >= 40
+
+    cpu, _, cpu_dyn, cpu_log = _build(CpuMatcher)
+    cpu_results = [cpu.consume_line(l, now_unix=now) for l in lines]
+    sync, _, _, sync_log = _build(TpuMatcher)
+    sync_results = sync.consume_lines(lines, now_unix=now)
+    fused, _, fused_dyn, fused_log = _build(TpuMatcher)
+    fused_results, _ = _run_pipelined(fused, lines, now)
+    classic, _, _, classic_log = _build(TpuMatcher, fused=False)
+    classic_results, _ = _run_pipelined(classic, lines, now)
+
+    for i, (c, s, f, k) in enumerate(zip(
+        cpu_results, sync_results, fused_results, classic_results
+    )):
+        assert result_key(c) == result_key(s), f"sync diverged at {i}"
+        assert result_key(c) == result_key(f), f"fused diverged at {i}"
+        assert result_key(c) == result_key(k), f"classic diverged at {i}"
+    assert fused_log.getvalue() == cpu_log.getvalue() == sync_log.getvalue()
+    assert classic_log.getvalue() == cpu_log.getvalue()
+    assert "per-site.com" in cpu_log.getvalue()
+    assert fused_dyn.metrics() == cpu_dyn.metrics()
+    assert fused.device_windows.format_states() == \
+        sync.device_windows.format_states() == \
+        classic.device_windows.format_states()
+    # the long rows were the device's in both fused entries
+    assert fused.long_lines >= n_long - 10 and sync.long_lines >= n_long - 10
+    assert fused.unfused_batches == sync.unfused_batches == {
+        "line_length": 0, "non_ascii": 0}
+    assert fused.pipelined_fused_chunks + fused.pipelined_fused_fallbacks > 0
+    assert fused._fw_pipeline.long_rows_seen

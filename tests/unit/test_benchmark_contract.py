@@ -829,6 +829,154 @@ def test_stage2_readers_split_a_trace_by_nfa_words():
     assert rf.read({**ctx, "prom0": {}, "prom1": {}}) is None
 
 
+_LONGLINE_FAMILIES = {
+    # family: (label sets the readers select by, its readers)
+    "banjax_matcher_long_lines_total":
+        ([{}], ["long_lines_share", "long_match_roofline"]),
+    "banjax_matcher_long_line_bytes_total": ([{}], ["long_match_roofline"]),
+    "banjax_matcher_long_candidates_total": ([{}], ["long_match_roofline"]),
+    "banjax_matcher_long_candidate_bytes_total":
+        ([{}], ["long_match_roofline"]),
+    "banjax_matcher_unfused_batches_total":
+        ([{"cause": "line_length"}, {"cause": "non_ascii"}],
+         ["unfused_batches_share"]),
+    "banjax_fused_overflows_total": ([{"cause": "long_rows"}], []),
+}
+
+
+@pytest.fixture(scope="module")
+def longline_scrapes():
+    """`/metrics` before and after three batches of 100 lines through the
+    scheduler: in each, five lines over the short width (one of them a
+    payload whose match begins past byte 256), and in the last a line
+    with a byte over 0x7F.  Tracing is off."""
+    from banjax_tpu.decisions.dynamic_lists import DynamicDecisionLists
+    from banjax_tpu.decisions.rate_limit import (
+        FailedChallengeRateLimitStates,
+    )
+    from banjax_tpu.obs.exposition import render_prometheus
+    from benchmark.harness import prom
+
+    assert not trace.enabled()
+    m = _matcher()
+    now = time.time()
+    sched = PipelineScheduler(lambda: m, now_fn=lambda: now)
+
+    def scrape():
+        return prom.parse(render_prometheus(
+            DynamicDecisionLists(start_sweeper=False), RegexRateLimitStates(),
+            FailedChallengeRateLimitStates(), matcher=m, pipeline=sched,
+        ))
+
+    def path(k, i):
+        if i % 20 == 7:
+            return "/" + "q=" * (150 + i) + ("/x GET /attack" if i == 7
+                                             else "")
+        return f"/caf\u00e9{i}" if (k, i) == (2, 50) else f"/page{i}"
+
+    sched.start()
+    before = scrape()
+    for k in range(3):
+        sched.submit([
+            f"{now:.6f} 1.2.{k}.{i} GET h.com GET {path(k, i)} HTTP/1.1 ua -"
+            for i in range(100)
+        ])
+        assert sched.flush(120)
+    sched.stop()
+    after = scrape()
+    m.close()
+    return before, after
+
+
+@pytest.mark.parametrize("family", sorted(_LONGLINE_FAMILIES))
+def test_longline_family_is_on_metrics_and_its_reader_reads_it(
+        longline_scrapes, family):
+    """The counters `longline1k-edge` brought (ISSUE 43), off `/metrics`
+    through the benchmark's own parser and through the readers of
+    `longline1k.flood`: lines over the short width and their bytes, the
+    ones stage 2 scanned, batches that went classic for one line's sake by
+    cause, and batches cut for want of room in a chunk's long operand."""
+    from benchmark.harness import found, prom
+
+    label_sets, readers = _LONGLINE_FAMILIES[family]
+    assert family in {f.prom for f in registry.FAMILIES}
+    before, after = longline_scrapes
+    for labels in label_sets:
+        assert prom.value(after, family, **labels) is not None, labels
+    assert prom.value(after, "banjax_matcher_long_lines_total") == 15
+    assert prom.value(after, "banjax_matcher_long_line_bytes_total") > 15 * 300
+    # the payload line carries the rule's factor past byte 256, in each
+    # of the two batches that went fused
+    assert prom.value(after, "banjax_matcher_long_candidates_total") == 2
+    assert prom.value(
+        after, "banjax_matcher_unfused_batches_total", cause="non_ascii") == 1
+    assert prom.value(
+        after, "banjax_matcher_unfused_batches_total",
+        cause="line_length") == 0
+    assert prom.value(
+        after, "banjax_fused_overflows_total", cause="long_rows") == 0
+    assert prom.value(after, "banjax_pipelined_fused_chunks_total") == 2
+    ctx = {"prom0": before, "prom1": after, "trace": None, "trace_lines": 0,
+           "mean_len": 0.0, "config": {"product_config":
+                                       {"matcher_max_line_len": 256}}}
+    want = {"long_lines_share": 5.0, "long_match_roofline": None,
+            "unfused_batches_share": pytest.approx(100 / 3)}
+    for name in readers:
+        assert found.module("layers", name).read(ctx) == want[name]
+        # a program without the counter: the reader is silent
+        assert found.module("layers", name).read(
+            {**ctx, "prom0": {}, "prom1": {}}) is None
+
+
+def test_long_match_readers_take_the_launches_over_the_short_width():
+    """`long_match_us_per_kline` and `long_match_roofline` take the long
+    launches to be the match-kernel launches whose padded line length
+    (trace_names.json `match_kernel_shapes`) is over the configuration's
+    `matcher_max_line_len`, both stages', and the work to be the long
+    lines' own bytes as the program counted them: a share of a roofline
+    never counts the padded rows x columns of a launch."""
+    from benchmark.harness import found, roofline
+
+    def op(words, lines, line_len, seconds, launches):
+        return [f"%long-rows.3 = u32[{words},{lines}]{{1,0}} custom-call("
+                f"s32[2]{{0}} %a, s32[{line_len},{lines}]{{1,0}} %b, "
+                f"s32[1,{lines}]{{1,0}} %c, s8[{4 * words},128]{{1,0}} %d), "
+                'custom_call_target="tpu_custom_call"', seconds, launches]
+
+    trace_ = {"kernel_ops": {"match_kernel": [
+        op(64, 4096, 256, 0.010, 20.0), op(2304, 512, 256, 0.030, 20.0),
+        op(64, 256, 1024, 0.005, 20.0), op(2304, 256, 1024, 0.050, 20.0),
+        op(64, 128, 8192, 0.015, 20.0), op(2304, 128, 8192, 0.150, 20.0)]}}
+    fam = "banjax_matcher_long_"
+    p1 = {(fam + "lines_total", ()): 31_000.0,
+          (fam + "line_bytes_total", ()): 13e6,
+          (fam + "candidates_total", ()): 2_000.0,
+          (fam + "candidate_bytes_total", ()): 4e6,
+          ("banjax_pipeline_processed_lines_total", ()): 1e6}
+    ctx = {"trace": trace_, "trace_lines": 80_000, "mean_len": 160.0,
+           "prom0": dict.fromkeys(p1, 0.0), "prom1": p1,
+           "device": {"kind": "TPU v5 lite"},
+           "config": {"product_config": {"matcher_max_line_len": 256}}}
+    us = found.module("layers", "long_match_us_per_kline")
+    assert us.read(ctx) == pytest.approx(0.220 * 1e9 / 80_000)
+    rf = found.module("layers", "long_match_roofline")
+    s1 = rf.long_work(13.0 * 80_000, 0.031 * 80_000, 40.0, 64, 128)
+    s2 = rf.long_work(4.0 * 80_000, 0.002 * 80_000, 40.0, 2304, 128)
+    assert s1 == roofline.match_kernel_work(
+        13.0 * 80_000, 0.031 * 80_000, 40.0, 64, 128)
+    share = rf.read(ctx)
+    assert share == pytest.approx(
+        100 * (s1["int8_ops"] + s2["int8_ops"]) / 393e12 / 0.220)
+    assert 0 < share < 5
+    # no launch over the short width (the other five configurations, or a
+    # program without the long operand), or no counter: silent
+    del trace_["kernel_ops"]["match_kernel"][2:]
+    assert us.read(ctx) is None and rf.read(ctx) is None
+    trace_["kernel_ops"]["match_kernel"].append(
+        op(64, 256, 8192, 0.020, 20.0))
+    assert rf.read({**ctx, "prom0": {}, "prom1": {}}) is None
+
+
 @pytest.mark.parametrize("name,rel", _configurations())
 def test_expect_keys_are_keys_of_describe(name, rel):
     """`correct` compares the configuration's `expect` with

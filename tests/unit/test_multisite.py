@@ -274,7 +274,8 @@ def test_rehearsal_ruleset_commits_fused_and_equals_the_reference(rehearsal):
     assert {k: cmp_[k] for k in COMPARED} == dict.fromkeys(COMPARED, 0)
     fw = m._fw_pipeline
     assert fw.overflow_causes == {
-        "candidates": 0, "pairs": 0, "events": 0, "chain": 0}
+        "candidates": 0, "pairs": 0, "events": 0, "chain": 0,
+        "long_rows": 0}
     assert m.pipelined_fused_chunks >= 20 and m.pipelined_fused_fallbacks == 0
     # about one pair a matching line: 5 % attack lines and the slow
     # attackers' — not 16 times that

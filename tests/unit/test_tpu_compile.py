@@ -98,6 +98,30 @@ def test_fused_stage2_compiles(one_chip, prefilter):
     _compile(fn, one_chip, ((L_P, K), jnp.int32), ((K,), jnp.int32))
 
 
+def test_long_rows_launches_compile(one_chip, prefilter):
+    """One more launch of each stage's kernel for each long operand, over
+    a chunk's lines past the short width: up to 1,024 bytes at a sixteenth
+    of the chunk's rows (256 of 4,096; one block of 128 lanes in the
+    smaller row buckets), up to 8,192 at one block."""
+    from banjax_tpu.matcher import longrows
+
+    assert longrows.LONG_WIDTHS == (1024, 8192)
+    assert [longrows.operands(prefilter, rows)
+            for rows in (128, 2048, 4096)] == [
+        ((1024, 128), (8192, 128)), ((1024, 128), (8192, 128)),
+        ((1024, 256), (8192, 128))]
+    launches = {op for b in (128, 4096)
+                for op in longrows.operands(prefilter, b)}
+    assert launches == {(1024, 128), (1024, 256), (8192, 128)}
+    for width, kl in sorted(launches):
+        block = prefilter._block_for(kl)
+        assert block == 128
+        _compile(prefilter._stage1_raw(kl, width, block),
+                 one_chip, ((width, kl), jnp.int32), ((kl,), jnp.int32))
+        _compile(prefilter._stage2(kl, width, block),
+                 one_chip, ((width, kl), jnp.int32), ((kl,), jnp.int32))
+
+
 def test_window_scan_compiles(one_chip):
     from banjax_tpu.matcher.kernels import fused_match_window as fmw
 
