@@ -50,7 +50,6 @@ committed events by which of the two lists they came from.
 from __future__ import annotations
 
 import dataclasses
-import logging
 import threading
 from typing import Optional
 
@@ -62,8 +61,6 @@ from banjax_tpu.obs import trace
 from banjax_tpu.matcher import longrows
 from banjax_tpu.matcher.prefilter import FusedPrefilter
 from banjax_tpu.matcher.windows import DeviceWindows, EventBatch
-
-log = logging.getLogger(__name__)
 
 
 @dataclasses.dataclass
@@ -151,9 +148,9 @@ class FusedWindowsPipeline:
         self.skip_table = skip_table
         self.n_rules = n_rules
         # traffic introspection (obs/sketch.py): every submitted chunk
-        # folds into the device-resident count-min/HLL/rule-pressure
-        # sketches as one more stateless array op — telemetry only, no
-        # interaction with window state or results
+        # folds into the device-resident count-min/HLL sketches inside
+        # its own program (built without the fold when this is None) —
+        # telemetry only, no interaction with window state or results
         self._traffic_sketch = traffic_sketch
         self._progs = {}            # (Bp, L_p) → build_single_program's
         # (Bp, L_p) → the program that takes a long operand beside the
@@ -232,7 +229,7 @@ class FusedWindowsPipeline:
             Bp, L_p, f_idx=self._f_idx, a_idx=self._a_idx,
             aw=self._aw, ae=self._ae,
             scan_fn=fmw.window_scan(self._scan_interpret),
-            skip_table=self.skip_table, KL=KL,
+            skip_table=self.skip_table, KL=KL, sketch=self._traffic_sketch,
         )
         progs[key] = hit
         return hit
@@ -243,6 +240,7 @@ class FusedWindowsPipeline:
         self, cls_ids: np.ndarray, lens: np.ndarray, slots: np.ndarray,
         ts_s: np.ndarray, ts_ns: np.ndarray, host_idx: np.ndarray,
         live: Optional[np.ndarray] = None, long_rows=None,
+        row_hashes: Optional[np.ndarray] = None,
     ) -> _Pend:
         """Dispatch the fused program for one chunk (slot pins held by
         the caller, ownership passes to the pipeline).  The window state
@@ -254,7 +252,11 @@ class FusedWindowsPipeline:
         composed as a program input.  `long_rows` = (rows, lens, class
         ids back to back) of the chunk's lines over the short width, no
         more of a width than longrows.operands has room for (the caller's
-        check): rows whose `lens` entry is 0.  The dispatch runs under the windows
+        check): rows whose `lens` entry is 0.  `row_hashes` (uint32 [B]):
+        each row's address hash, what the traffic sketch folds the chunk
+        under, in this dispatch — every real row, live or not, whatever
+        the program's gate says; not read without a sketch.  The dispatch
+        runs under the windows
         lock: maintenance (evictions/restores) drains first, and the
         state-chain order == seq order because both are taken inside the
         same critical section."""
@@ -286,6 +288,10 @@ class FusedWindowsPipeline:
         ts_s_p, ts_ns_p = pad(ts_s), pad(ts_ns)
         live_p = np.zeros(Bp, dtype=np.uint8)
         live_p[:B] = 1 if live is None else np.asarray(live, dtype=np.uint8)
+        sk = self._traffic_sketch
+        if sk is not None:
+            hashes_p = np.zeros(Bp, dtype=np.uint32)
+            hashes_p[:B] = row_hashes
         wnd = self.windows
         lap.mark("dispatch")
         with wnd._lock:
@@ -301,15 +307,28 @@ class FusedWindowsPipeline:
             lap.mark("maintenance")
             wnd._run_maintenance_locked()
             lap.mark("dispatch")
-            new_state, chain_out, buf, bits_dev = fn(
-                wnd._state,
+            operands = (
                 chain if chain is not None else jnp.int32(1),
                 jnp.asarray(combined), jnp.int32(B),
                 jnp.asarray(host_idx_p), jnp.asarray(slots_p),
                 jnp.asarray(ts_s_p), jnp.asarray(ts_ns_p),
-                jnp.asarray(live_p), *long_op,
+                jnp.asarray(live_p),
             )
-            wnd._state = new_state
+            if sk is None:
+                out = fn(wnd._state, *operands, *long_op)
+            else:
+                # the sketch's state lock inside the windows lock, held
+                # across the dispatch that donates both states; the
+                # hashes go in as they are (the call transfers them
+                # itself: no trip through the runtime of their own)
+                def run(sketch_state):
+                    *out, sketch_state = fn(
+                        wnd._state, sketch_state, *operands, hashes_p,
+                        *long_op)
+                    return sketch_state, out
+
+                out = sk.dispatch_fold(run, B, "fused")
+            wnd._state, chain_out, buf, bits_dev = out
             with self._cv:
                 self._chain_ok = chain_out
         try:
@@ -320,28 +339,15 @@ class FusedWindowsPipeline:
             seq=seq, sparse_buf=buf, bits_dev=bits_dev,
             slots=np.asarray(slots), B=B, Bp=Bp, K=K, P=P, E=E, KL=KL,
             # the whole h2d for the chunk: encoded classes + per-row
-            # window metadata + the live mask + the chain scalar — still
-            # no dense [B, n_rules] bitmap
+            # window metadata + the live mask + the chain scalar (+ the
+            # rows' hashes for the sketch) — still no dense [B, n_rules]
+            # bitmap
             h2d_bytes=combined.nbytes + 4 * 3 * Bp + Bp + 4
-            + sum(x.nbytes for x in long_op),
+            + sum(x.nbytes for x in long_op)
+            + (0 if sk is None else hashes_p.nbytes),
         )
-        lap.mark("sketch")
-        self._sketch_update(p)
         lap.mark("other")
         return p
-
-    def _sketch_update(self, p: _Pend) -> None:
-        """Fold one submitted chunk's rows into the count-min/HLL
-        sketches (keyed on the slot ids already bound for the device).
-        Unconditional at submit — an overflowed chunk's classic replay
-        does NOT re-fold, so each line counts exactly once on this
-        path."""
-        if self._traffic_sketch is None:
-            return
-        try:
-            self._traffic_sketch.update(p.slots, p.B)
-        except Exception:  # noqa: BLE001 — telemetry must never cost a chunk
-            log.exception("traffic sketch update failed")
 
     def _wait_turn(self, p: _Pend) -> None:
         with self._cv:

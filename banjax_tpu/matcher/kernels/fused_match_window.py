@@ -211,6 +211,7 @@ def scan_selftest(interpret: bool, E: int = 64) -> None:
 def build_single_program(
     pf, windows, active_table, n_rules: int, Bp: int, L_p: int, *,
     f_idx, a_idx, aw, ae, scan_fn, skip_table=None, KL: tuple = (),
+    sketch=None,
 ):
     """One jitted device program: match core + event list from the pairs
     and always-columns + overflow/chain gate + window commit + compact
@@ -224,7 +225,16 @@ def build_single_program(
     pairs) `fn` takes one more argument for each, last: the chunk's long
     rows as longrows.assemble lays them out (prefilter._match_core scans
     and merges them), and nothing else of the program or its output
-    changes:
+    changes.  With `sketch` (obs/sketch.py TrafficSketch) the program
+    carries the chunk's traffic-sketch fold as well:
+      fn(state, sketch_state, chain_ok, combined, n_real, host_idx, slots,
+         ts_s, ts_ns, live, row_hashes, *long operands)
+         -> (new_state, chain_ok_out, buf, bits_dev, new_sketch_state)
+    with `sketch_state` = (cm, hll) donated like the window state and
+    `row_hashes` u32[Bp], a row's address hash.  The fold is
+    unconditional — outside the overflow / chain gate and the live mask:
+    every row below n_real counts once, at its chunk's dispatch.  The
+    buffer's layout:
 
       flags[4 × i32: ok, n_cand, n_pairs, n_events]
       ‖ (row, rule) pairs [4P]
@@ -257,9 +267,8 @@ def build_single_program(
         site_mask = jnp.asarray(site_mask)                  # [hosts+1, nf8]
     active_table = jnp.asarray(active_table)
 
-    @functools.partial(jax.jit, donate_argnums=(0,))
-    def single(state, chain_ok, combined, n_real, host_idx, slots,
-               ts_s, ts_ns, live, *long_ops):
+    def match_and_commit(state, chain_ok, combined, n_real, host_idx, slots,
+                         ts_s, ts_ns, live, *long_ops):
         c = core(combined, *long_ops)
         keep = None
         if site_mask is not None:
@@ -352,5 +361,17 @@ def build_single_program(
             # the head and the event tail before them hold
             parts.append(le_bytes(c["bucket_hits"]))
         return new_state, ok.astype(jnp.int32), jnp.concatenate(parts), bits
+
+    if sketch is None:
+        single = jax.jit(match_and_commit, donate_argnums=(0,))
+        return single, K, P, max_events
+
+    @functools.partial(jax.jit, donate_argnums=(0, 1))
+    def single(state, sketch_state, chain_ok, combined, n_real, host_idx,
+               slots, ts_s, ts_ns, live, row_hashes, *long_ops):
+        out = match_and_commit(state, chain_ok, combined, n_real, host_idx,
+                               slots, ts_s, ts_ns, live, *long_ops)
+        with jax.named_scope("sketch-fold"):
+            return out + (sketch.fold(*sketch_state, row_hashes, n_real),)
 
     return single, K, P, max_events

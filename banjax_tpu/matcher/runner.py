@@ -1124,9 +1124,10 @@ class TpuMatcher(Matcher):
         addresses (DeviceWindows.resolve_addresses): the slot-admission
         gate's verdict and the window slots from one encoding and one
         probe of each table.  Leaves `state["gated"]`, and
-        `state["slots"]` — the admitted rows' slots, pinned, or None
-        when placement refused (the batch then goes the classic way, as
-        after a refusing slots_for_unique_ips).  Rows the gate refused
+        `state["slots"]` — the admitted rows' (slots, pinned; address
+        hashes for the traffic sketch, None without one), or None when
+        placement refused (the batch then goes the classic way, as after
+        a refusing slots_for_unique_ips).  Rows the gate refused
         are split off and applied here, between the pass's probe and its
         placement: exactly where _partition_admission applied them."""
         dw = self.device_windows
@@ -1137,8 +1138,10 @@ class TpuMatcher(Matcher):
         def keep_slots(uinv):
             state["slots"] = None
             if res.slots is not None:
-                self._note_sketch_slots(uips, res, lap)
-                state["slots"] = res.slots[uinv]
+                state["slots"] = (
+                    res.slots[uinv],
+                    self._sketch_row_hashes(uips, res, uinv, lap),
+                )
 
         with self._resolving(lap):
             uips, uinv = state["work"].unique_ips()
@@ -1178,25 +1181,31 @@ class TpuMatcher(Matcher):
             dw.place_resolved(res)
             keep_slots(uinv[adm])
 
-    def _note_sketch_slots(self, uips, res, lap) -> None:
-        """Refresh the sketch's slot→ip-hash table for a batch's distinct
-        assignments (scatters only CHANGED slots); a telemetry failure
-        must never cost the batch."""
-        if self.traffic_sketch is None:
-            return
+    def _sketch_row_hashes(self, uips, res, uinv, lap):
+        """What the traffic sketch takes of a resolved batch, timed as the
+        phase `sketch`: one append of the admitted distinct addresses to
+        its candidate log, and the rows' address hashes by one gather —
+        the operand a chunk's fold is keyed on (None without a sketch)."""
+        sk = self.traffic_sketch
+        if sk is None:
+            return None
         was = lap.phase
         lap.mark("sketch")
+        hashes = res.hashes
+        if hashes is None:  # the pass ran off the native path
+            hashes = sk.base_hashes(uips)
         try:
-            ips, slots, hashes = uips, res.slots, res.hashes
             if len(res.refused):
                 adm = np.flatnonzero(res.admit)
-                ips = [uips[i] for i in adm.tolist()]
-                slots = slots[adm]
-                hashes = None if hashes is None else hashes[adm]
-            self.traffic_sketch.note_assignments(ips, slots, hashes=hashes)
+                sk.note_assignments(
+                    [uips[i] for i in adm.tolist()], hashes[adm])
+            else:
+                sk.note_assignments(uips, hashes)
         except Exception:  # noqa: BLE001 — sketch is passive by contract
-            log.exception("traffic sketch slot-table refresh failed")
+            log.exception("traffic sketch candidate note failed")
+        row_hashes = hashes[uinv]
         lap.mark(was)
+        return row_hashes
 
     def _single_kernel_ordered(self) -> bool:
         """Commit-at-submit is only order-safe while no EARLIER admitted
@@ -1225,9 +1234,10 @@ class TpuMatcher(Matcher):
         # chunk commits at its dispatch, so a batch that went the classic
         # way after one had would count that chunk's hits twice.  One
         # chunk (the rule, unless long rows cut the batch): the submit
-        # stage's pass has them (`state["slots"]`), or the chunk's own
-        # submit gets them (None here).  Pins not yet handed to a chunk's
-        # submit are given back on every way out
+        # stage's pass has them (`state["slots"]`, with the rows' hashes
+        # beside), or the chunk's own submit gets them (None here).  Pins
+        # not yet handed to a chunk's submit are given back on every way
+        # out
         placed: list = []
         entries = []
         try:
@@ -1246,7 +1256,7 @@ class TpuMatcher(Matcher):
                     # the pass placed the batch as ONE chunk, and the rows
                     # it split off since left a smaller one, with a
                     # smaller long operand: placed again, chunk by chunk
-                    self.device_windows.release_pins(placed.pop())
+                    self.device_windows.release_pins(placed.pop()[0])
             elif len(chunks) == 1:
                 placed = [None]
             if len(chunks) > 1:
@@ -1272,7 +1282,7 @@ class TpuMatcher(Matcher):
                 with trace.span("program-ab-fused", args={"row0": s}):
                     e = self._submit_pipeline_chunk(
                         wc, _rows_of(pre, slice(s, stop)),
-                        live=live, slots=placed.pop(),
+                        live=live, placed=placed.pop(),
                     )
                 if e is None:
                     # the batch's one chunk, its own placement refused
@@ -1289,9 +1299,9 @@ class TpuMatcher(Matcher):
                 self._fw_pipeline.abandon(prev["pend"])
             raise
         finally:
-            for slots in placed:
-                if slots is not None:
-                    self.device_windows.release_pins(slots)
+            for left in placed:
+                if left is not None:
+                    self.device_windows.release_pins(left[0])
         state["fused"] = entries
         return True
 
@@ -1492,19 +1502,21 @@ class TpuMatcher(Matcher):
         self.note_device_outcome(time.perf_counter() - t0, ok=True)
         return self.breaker.state == CLOSED
 
-    def _slots_for_work(self, work) -> Optional[np.ndarray]:
-        """Window-slot ids for a work batch: one LRU decision + one pin
-        per DISTINCT ip (the unique tables the gate already built), then a
-        gather back to row order. Pin/release semantics are unchanged —
-        release_pins deduplicates slot ids either way."""
+    def _slots_for_work(self, work) -> Optional[tuple]:
+        """(window-slot ids, address hashes) for a work batch's rows: one
+        LRU decision + one pin per DISTINCT ip (the unique tables the
+        gate already built), then a gather back to row order; the hashes
+        are what the traffic sketch folds the rows under (None without
+        one).  None when placement refused.  Pin/release semantics are
+        unchanged — release_pins deduplicates slot ids either way."""
         uips, uinv = work.unique_ips()
         res = self.device_windows.resolve_addresses(
             uips, sketch=self.traffic_sketch
         )
         if res.slots is None:
             return None
-        self._note_sketch_slots(uips, res, trace.lap())
-        return res.slots[uinv]
+        return res.slots[uinv], self._sketch_row_hashes(
+            uips, res, uinv, trace.lap())
 
     # ---- cold-tier slot admission (mega-state tiering) ----
 
@@ -1782,16 +1794,18 @@ class TpuMatcher(Matcher):
         """Shared scaffolding for every device-windows consume path: slot
         allocation with recursive batch split when it refuses, per-line
         ts/host prep, and the pin-lifecycle contract. `apply_fn(work,
-        slots, ts_s, ts_ns, host_idx, results)` OWNS the pins from the
-        moment it is entered and must release them exactly once on every
-        path; any failure before that hand-off releases them here.
+        slots, row_hashes, ts_s, ts_ns, host_idx, results)` OWNS the pins
+        from the moment it is entered and must release them exactly once
+        on every path; any failure before that hand-off releases them
+        here.  `row_hashes`: the rows' address hashes, for the traffic
+        sketch's fold (None without a sketch).
         `split(lo, hi)` returns the work-aligned payload slices for a
         recursive half-batch."""
         from banjax_tpu.matcher.windows import split_ns
 
         dw = self.device_windows
-        slots = self._slots_for_work(work)
-        if slots is None:
+        placed = self._slots_for_work(work)
+        if placed is None:
             if len(work) <= 1:
                 log.error(
                     "device-windows slot allocation failed for a single "
@@ -1807,12 +1821,13 @@ class TpuMatcher(Matcher):
                 work[mid:], *split(mid, len(work)), results
             )
             return
+        slots, row_hashes = placed
         handed_off = False
         try:
             ts_s, ts_ns = split_ns(work.ts_array())
             host_idx = work.host_idx(self._host_row)
             handed_off = True
-            apply_fn(work, slots, ts_s, ts_ns, host_idx, results)
+            apply_fn(work, slots, row_hashes, ts_s, ts_ns, host_idx, results)
         except Exception:
             if not handed_off:
                 dw.release_pins(slots)
@@ -1889,22 +1904,23 @@ class TpuMatcher(Matcher):
             flat, lens = work.rest_bytes(ks)
             return ks.astype(np.int32), lens, self._class_of_byte[flat]
 
-    def _submit_pipeline_chunk(self, work, pre, live=None, slots=None):
-        """Allocate slots (unless the caller's pass already has: `slots`,
-        pinned) + dispatch the chunk's fused program (`live` is its
-        commit mask) over `pre`, the chunk's rows of the encoded batch
-        (one of _fused_chunks'); None when slot allocation refuses. Pins
-        transfer to the pipeline on success."""
+    def _submit_pipeline_chunk(self, work, pre, live=None, placed=None):
+        """Allocate slots (unless the caller's pass already has: `placed`,
+        _slots_for_work's pair, pinned) + dispatch the chunk's fused
+        program (`live` is its commit mask) over `pre`, the chunk's rows
+        of the encoded batch (one of _fused_chunks'); None when slot
+        allocation refuses. Pins transfer to the pipeline on success."""
         from banjax_tpu.matcher.windows import split_ns
 
         dw = self.device_windows
         lap = trace.lap()
-        if slots is None:
+        if placed is None:
             lap.mark("pass")
-            slots = self._slots_for_work(work)
-        if slots is None:
+            placed = self._slots_for_work(work)
+        if placed is None:
             lap.mark("other")
             return None
+        slots, row_hashes = placed
         try:
             lap.mark("operands")
             ts_s, ts_ns = split_ns(work.ts_array())
@@ -1912,6 +1928,7 @@ class TpuMatcher(Matcher):
             pend = self._fw_pipeline.submit(
                 pre[0], pre[1], slots, ts_s, ts_ns, host_idx, live=live,
                 long_rows=self._long_rows_of(work, pre[3]),
+                row_hashes=row_hashes,
             )
         except Exception:
             dw.release_pins(slots)
@@ -1932,7 +1949,8 @@ class TpuMatcher(Matcher):
         from banjax_tpu.matcher.fused_windows import PipelineOverflow
 
         def make(pre_c):
-            def apply_fn(work_c, slots, ts_s, ts_ns, host_idx, results_c):
+            def apply_fn(work_c, slots, row_hashes, ts_s, ts_ns, host_idx,
+                         results_c):
                 dw = self.device_windows
                 if len(self._fused_chunks(pre_c)) > 1:
                     dw.release_pins(slots)
@@ -1942,6 +1960,7 @@ class TpuMatcher(Matcher):
                     pend = self._fw_pipeline.submit(
                         pre_c[0], pre_c[1], slots, ts_s, ts_ns, host_idx,
                         long_rows=self._long_rows_of(work_c, pre_c[3]),
+                        row_hashes=row_hashes,
                     )
                 except Exception:
                     dw.release_pins(slots)
@@ -2150,16 +2169,18 @@ class TpuMatcher(Matcher):
         (shared scaffolding handles slot allocation/split/pin lifecycle)."""
 
         def make(bits_c):
-            def apply_fn(work_c, slots, ts_s, ts_ns, host_idx, results_c):
+            def apply_fn(work_c, slots, row_hashes, ts_s, ts_ns, host_idx,
+                         results_c):
                 # the dense-bitmap re-upload the fused path exists to
                 # eliminate: count it so the win is measurable
                 if isinstance(bits_c, np.ndarray):
                     self.stats.note_xfer(h2d_bytes=bits_c.nbytes)
                 if self.traffic_sketch is not None:
-                    # fold the chunk into the count-min/HLL sketches (the
-                    # fused paths do this at their device submit instead)
+                    # fold the chunk into the count-min/HLL sketches, as
+                    # a program of its own (a fused dispatch carries its
+                    # chunk's fold itself)
                     try:
-                        self.traffic_sketch.update(slots, len(work_c))
+                        self.traffic_sketch.update(row_hashes, len(work_c))
                     except Exception:  # noqa: BLE001 — sketch is passive
                         log.exception("traffic sketch update failed")
                 events = self.device_windows.apply_bitmap(

@@ -294,6 +294,9 @@ def render_prometheus(
     # 1k lines per scrape while idle
     sketch = getattr(matcher, "traffic_sketch", None) if matcher else None
     if sketch is not None:
+        fam = registry.PROM_FAMILIES["banjax_sketch_updates_total"]
+        for path, v in sketch.updates_by_path.items():
+            w.sample(fam, v, {"path": path})
         try:
             pressure = sketch.pull().get("rule_pressure", ())
         except Exception:  # noqa: BLE001 — telemetry must not break a scrape

@@ -218,8 +218,9 @@ FAMILIES: List[Family] = [
     Family(COUNTER, "wall seconds of the pipeline's submit stage (from "
            "the scheduler's start of a batch's device stage to the end of "
            "pipeline_submit) by phase — pass (one pass over the batch's "
-           "distinct addresses), sketch (the traffic sketch's slot note "
-           "and update), operands (the fused program's inputs), "
+           "distinct addresses), sketch (the rows' address hashes by one "
+           "gather and the candidate log's append: what the traffic "
+           "sketch takes outside the dispatch), operands (the fused program's inputs), "
            "maintenance (evictions and restores in front of the "
            "dispatch), dispatch (the fused program's call), other.  The "
            "six sum to the submit part of "
@@ -461,6 +462,11 @@ FAMILIES: List[Family] = [
     Family(COUNTER, "fired (line, rule) window events folded into the "
            "sketch, per rule — which rules absorb the flood",
            prom="banjax_traffic_rule_pressure", labels=("rule",)),
+    Family(COUNTER, "chunks folded into the device traffic sketch, by "
+           "the dispatch that carried the fold: fused (the chunk's own "
+           "match+window program) or standalone (a program of its own: "
+           "what is not dispatched fused)",
+           prom="banjax_sketch_updates_total", labels=("path",)),
     # ---- multi-host decision fabric (banjax_tpu/fabric/) ----
     Family(GAUGE, "1 when the labeled fabric peer is alive in this "
            "node's membership view, 0 after it is declared dead",

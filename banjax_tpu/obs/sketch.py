@@ -6,8 +6,8 @@ mid-flood, before any ban fires: who the heavy hitters are, how many
 distinct sources are active, and which rules are under pressure.
 
 Three classic streaming structures live as flat device arrays and fold
-every matcher chunk in-stream, as one more stateless array op next to
-the fused match+window dispatch (zero interaction with window state —
+every matcher chunk in-stream, inside the fused match+window dispatch
+(zero interaction with window state —
 the differential suite proves sketch-on == sketch-off on ban-log bytes,
 result stream and window state):
 
@@ -27,12 +27,14 @@ counting there is exact even for chunks whose device bitmap was
 incomplete (candidate overflow), at O(events) cost the replay already
 pays.
 
-Zero extra per-row h2d traffic: the update keys on the per-row window
-SLOT ids the fused path already uploads, gathered through a
-device-resident slot→ip-hash table that the host refreshes only for
-newly-assigned slots (`note_assignments`, fed from the same unique-IP
-tables the slot manager walks anyway).  In steady state — the slot table
-warm — a chunk's sketch update uploads nothing at all.
+Keyed on the rows' hashes: the submit stage's one pass over a batch's
+distinct addresses has each address's base hash (one C call over the
+encoding the slot manager walks anyway), so a row's hash is one gather,
+and a chunk's fold is a few more lines of the fused match+window program
+that is dispatched for the chunk anyway — one more per-row operand, no
+dispatch of its own (`fold`; kernels/fused_match_window.py).  What is
+not dispatched fused (the classic protocol's window apply) runs the same
+arithmetic as a program of its own (`update`).
 
 Pulls are PERIODIC, never per-batch: `pull()` is throttled by
 `traffic_sketch_pull_seconds` (one compact d2h of ~depth*width*4 +
@@ -49,7 +51,6 @@ from __future__ import annotations
 
 import functools
 import heapq
-import logging
 import math
 import threading
 import time
@@ -63,8 +64,6 @@ import numpy as np
 
 from banjax_tpu.obs import trace
 
-log = logging.getLogger(__name__)
-
 # xor seeds decorrelating the count-min rows (any fixed distinct values
 # work: the row hash is fmix32(ip_hash ^ seed_j));  the golden-ratio
 # constant seeds the independent HLL hash
@@ -73,7 +72,6 @@ _CM_SEEDS = (0x0000_0000, 0x7F4A_7C15, 0x94D0_49BB, 0xDE82_4AD5,
 _HLL_SEED = 0x9E37_79B9
 
 _MIN_ROW_BUCKET = 64
-_MIN_SLOT_TABLE = 1024
 
 
 def _bucket(n: int, floor: int) -> int:
@@ -106,6 +104,40 @@ def _fmix32_jnp(h):
     return h
 
 
+def _fold(seeds, hll_p: int, cm, hll, row_hashes, n_real):
+    """The fold's arithmetic, traceable: `row_hashes` uint32 [Bp], one
+    base hash a row, rows from `n_real` on masked; `cm` is depth
+    (= len(seeds)) rows of buckets back to back."""
+    depth = seeds.shape[0]
+    width = cm.shape[0] // depth
+    low_bits = 32 - hll_p
+    h = row_hashes
+    Bp = h.shape[0]
+    real = jax.lax.iota(jnp.int32, Bp) < n_real
+    inc = real.astype(jnp.int32)
+    # count-min: one bucket increment per row per line (scatter-add
+    # accumulates duplicate indices — repeated IPs in a batch land their
+    # full count)
+    hx = h[None, :] ^ seeds[:, None]                     # [depth, Bp]
+    col = (_fmix32_jnp(hx) % jnp.uint32(width)).astype(jnp.int32)
+    flat = col + jnp.arange(depth, dtype=jnp.int32)[:, None] * width
+    cm = cm.at[flat.reshape(-1)].add(
+        jnp.broadcast_to(inc[None, :], (depth, Bp)).reshape(-1)
+    )
+    # HLL: register = top p bits of an independent mix, rho = leading
+    # zeros of the remaining bits + 1 (bit-smear + popcount gives the MSB
+    # position exactly — no float log)
+    g = _fmix32_jnp(h ^ jnp.uint32(_HLL_SEED))
+    reg = (g >> jnp.uint32(low_bits)).astype(jnp.int32)
+    fill = g & jnp.uint32((1 << low_bits) - 1)
+    for s in (1, 2, 4, 8, 16):
+        fill = fill | (fill >> jnp.uint32(s))
+    msb_cnt = jax.lax.population_count(fill).astype(jnp.int32)
+    rho = low_bits - msb_cnt + 1
+    hll = hll.at[reg].max(jnp.where(real, rho, 0))
+    return cm, hll
+
+
 def hash_ip(ip: str) -> int:
     """The 32-bit base hash of one client-IP string (crc32 of the utf-8
     bytes).  Every derived hash — count-min rows, the HLL register pick
@@ -131,11 +163,13 @@ def hll_estimate(registers: np.ndarray) -> float:
 class TrafficSketch:
     """Device-resident traffic sketches with a host-side top-K view.
 
-    Thread-safe: `note_assignments` / `update` / `pull` may race from
-    the submit and drain threads; one lock serializes the donated-state
-    device dispatches and the host bookkeeping.  A sketch failure must
-    never cost a log line — callers wrap update hooks, and `pull`
-    degrades to the last cached summary.
+    Thread-safe, under three locks that guard unrelated things.  The
+    state lock (`_lock`) serializes the dispatches that donate
+    `(cm, hll)` — it is held ACROSS a fused dispatch, inside the windows
+    lock (order: windows lock, then this one; `pull` takes this one
+    alone) — and the pull.  The candidate log and the per-rule pressure
+    have a lock each, so that neither the submit stage's append nor the
+    drain's `note_rule_events` ever queues behind a dispatch.
     """
 
     def __init__(
@@ -166,6 +200,8 @@ class TrafficSketch:
         self._n_rules = max(1, len(self.rule_names))
 
         self._lock = threading.Lock()
+        self._rule_lock = threading.Lock()
+        self._cand_lock = threading.Lock()
         # donated device state: (cm [depth*width], hll [m])
         self._state = (
             jnp.zeros((self.depth * self.width,), dtype=jnp.int32),
@@ -188,45 +224,55 @@ class TrafficSketch:
         # this CACHE — the admission gate runs per batch and must never
         # force a d2h pull
         self._cm_cache: Optional[np.ndarray] = None
-        # slot → ip-hash table: device copy gathered by the update op
-        # (the per-row hashes are already on device once a slot is warm),
-        # host mirror diffed per batch so only CHANGED slots scatter up
-        self._slot_hash_dev = jnp.zeros((_MIN_SLOT_TABLE,), dtype=jnp.uint32)
-        self._slot_hash_host = np.zeros(_MIN_SLOT_TABLE, dtype=np.uint32)
         # candidate heavy hitters: LRU of recently-seen distinct IPs and
         # their base hashes — the enumerable key set a count-min sketch
         # itself cannot provide.  A true heavy hitter recurs every batch,
         # so it cannot age out of a bound >> topk.  Recency is written in
         # bulk: a batch appends its (ips, hashes) to `_cand_log`, one
         # store whatever its size, and the LRU is brought up to date from
-        # the log where somebody reads it (a pull) or the log has grown
-        # past a few times the bound — see _candidates_locked.
+        # the log where somebody reads it (a pull: _candidates_locked);
+        # where nobody does, the log is kept short by dropping batches
+        # in the hashes' domain, no string touched (_trim_log_locked).
         self._cand_lru: "OrderedDict[str, int]" = OrderedDict()
         self._cand_log: List[tuple] = []
         self._cand_log_len = 0
-        self._update_fns: Dict[tuple, object] = {}
+        # the fold, traceable: fold(cm, hll, row_hashes, n_real) ->
+        # (cm, hll).  Both dispatches trace this one function: the fused
+        # match+window program (kernels/fused_match_window.py) and the
+        # program of its own for what is not dispatched fused (jit keeps
+        # one executable a row bucket).  Neither closes over the sketch
+        fold = self.fold = functools.partial(
+            _fold,
+            jnp.asarray(np.asarray(_CM_SEEDS[: self.depth], dtype=np.uint32)),
+            self.hll_p,
+        )
+        self._standalone = jax.jit(
+            lambda state, row_hashes, n_real: fold(*state, row_hashes, n_real),
+            donate_argnums=(0,),
+        )
 
         self.lines_total = 0          # lines folded into the sketch
         self.update_count = 0
+        # chunks folded, by the dispatch that carried the fold
+        # (banjax_sketch_updates_total{path})
+        self.updates_by_path = {"fused": 0, "standalone": 0}
         self.pull_count = 0
         self.pull_bytes_total = 0
         self._last_pull_mono: Optional[float] = None
         self._summary: Optional[dict] = None
-        self._seeds = jnp.asarray(
-            np.asarray(_CM_SEEDS[: self.depth], dtype=np.uint32)
-        )
 
-    # ---- host bookkeeping (slot table + candidates) ----
+    # ---- host bookkeeping (candidates) ----
 
     def _candidates_locked(self) -> "OrderedDict[str, int]":
         """The candidate LRU with every logged batch folded in (caller
-        holds the lock): the `max_candidates` most recently seen distinct
-        IPs, oldest first, an IP's place given by the last batch that had
-        it and its position there — what a per-address move-to-end walk
-        of each batch, trimmed after each, leaves behind (a trimmed IP is
-        older than `max_candidates` others and stays so until seen
-        again).  Built in C-speed dict passes: newest first, a dict keeps
-        each IP where it is met first, i.e. at its last sighting."""
+        holds the candidate lock): the `max_candidates` most recently
+        seen distinct IPs, oldest first, an IP's place given by the last
+        batch that had it and its position there — what a per-address
+        move-to-end walk of each batch, trimmed after each, leaves behind
+        (a trimmed IP is older than `max_candidates` others and stays so
+        until seen again).  Built in C-speed dict passes: newest first, a
+        dict keeps each IP where it is met first, i.e. at its last
+        sighting."""
         if self._cand_log:
             newest_first: Dict[str, int] = {}
             for ips, hashes in reversed(self._cand_log):
@@ -247,132 +293,85 @@ class TrafficSketch:
             self._cand_log_len = 0
         return self._cand_lru
 
+    def _trim_log_locked(self) -> None:
+        """Bound the log where nobody reads it (caller holds the
+        candidate lock), in the hashes' domain: once the newest k batches
+        hold `max_candidates` distinct hashes — hence as many distinct
+        addresses — _candidates_locked's walk stops there, and the older
+        batches are dropped unread: numpy over the uint32 arrays the log
+        holds, no string touched.  Only a log that long with fewer
+        distinct hashes (few addresses, many small batches) is folded
+        the exact way here."""
+        log = self._cand_log
+        k = raw = 0
+        need = self.max_candidates
+        while k < len(log):
+            while k < len(log) and raw < need:
+                k += 1
+                raw += len(log[-k][1])
+            distinct = np.unique(np.concatenate([h for _, h in log[-k:]]))
+            if distinct.size >= self.max_candidates:
+                del log[:-k]
+                self._cand_log_len = raw
+                break
+            need = 2 * raw
+        if self._cand_log_len > 4 * self.max_candidates:
+            self._candidates_locked()
+
     @property
     def _candidates(self) -> "OrderedDict[str, int]":
-        with self._lock:
+        with self._cand_lock:
             return self._candidates_locked()
 
     def note_assignments(
-        self, ips: Sequence[str], slots: np.ndarray,
-        hashes: Optional[np.ndarray] = None,
+        self, ips: Sequence[str], hashes: Optional[np.ndarray] = None,
     ) -> None:
-        """Refresh the slot→hash table for one batch's DISTINCT
-        (ip, slot) pairs — the same unique tables the slot manager just
-        walked.  Only slots whose owner changed scatter to the device;
-        a warm table uploads nothing.  `hashes`: the addresses' base
-        hashes where the caller has them from its one encoding of the
-        batch (native/slotmgr.py crc32_spans); without them each address
-        is hashed here."""
+        """Remember one batch's DISTINCT addresses as heavy-hitter
+        candidates: one append to the candidate log.  `hashes`: the
+        addresses' base hashes where the caller has them from its one
+        encoding of the batch (native/slotmgr.py crc32_spans); without
+        them each address is hashed here."""
         n = len(ips)
         if n == 0:
             return
-        slots = np.asarray(slots, dtype=np.int64)
         if hashes is None:
             hashes = self.base_hashes(ips)
-        with self._lock:
-            # recency of the candidate set, in bulk (see __init__)
+        with self._cand_lock:
             self._cand_log.append((ips, hashes))
             self._cand_log_len += n
             if self._cand_log_len > 4 * self.max_candidates:
-                self._candidates_locked()
-
-            need = int(slots.max()) + 1
-            if need > self._slot_hash_host.size:
-                new_size = _bucket(need, _MIN_SLOT_TABLE)
-                grown = np.zeros(new_size, dtype=np.uint32)
-                grown[: self._slot_hash_host.size] = self._slot_hash_host
-                self._slot_hash_host = grown
-                self._slot_hash_dev = jnp.concatenate([
-                    self._slot_hash_dev,
-                    jnp.zeros(
-                        new_size - self._slot_hash_dev.shape[0],
-                        dtype=jnp.uint32,
-                    ),
-                ])
-            changed = self._slot_hash_host[slots] != hashes
-            if changed.any():
-                ch_slots = slots[changed]
-                ch_hash = hashes[changed]
-                self._slot_hash_host[ch_slots] = ch_hash
-                # pow2-bucketed scatter (padded entries index out of
-                # range and drop) so the jit cache stays bounded
-                kk = _bucket(len(ch_slots), 64)
-                idx = np.full(kk, self._slot_hash_host.size, dtype=np.int32)
-                idx[: len(ch_slots)] = ch_slots
-                val = np.zeros(kk, dtype=np.uint32)
-                val[: len(ch_hash)] = ch_hash
-                # the operands go in as they are: the call transfers
-                # them itself, one trip through the runtime, not three
-                self._slot_hash_dev = _scatter_hashes(
-                    self._slot_hash_dev, idx, val
-                )
+                self._trim_log_locked()
 
     # ---- the per-chunk device update ----
 
-    def _update_fn(self, Bp: int, cap: int):
-        key = (Bp, cap)
-        fn = self._update_fns.get(key)
-        if fn is not None:
-            return fn
-        depth, width, p = self.depth, self.width, self.hll_p
-        seeds = self._seeds
-        low_bits = 32 - p
-
-        @functools.partial(jax.jit, donate_argnums=(0,))
-        def update(state, slot_hash, slots, n_real):
-            cm, hll = state
-            h = slot_hash[slots]                         # [Bp] uint32
-            real = jax.lax.iota(jnp.int32, Bp) < n_real
-            inc = real.astype(jnp.int32)
-            # count-min: one bucket increment per row per line (scatter-
-            # add accumulates duplicate indices — repeated IPs in a batch
-            # land their full count)
-            hx = h[None, :] ^ seeds[:, None]             # [depth, Bp]
-            col = (_fmix32_jnp(hx) % jnp.uint32(width)).astype(jnp.int32)
-            flat = col + (
-                jnp.arange(depth, dtype=jnp.int32)[:, None] * width
-            )
-            cm = cm.at[flat.reshape(-1)].add(
-                jnp.broadcast_to(inc[None, :], (depth, Bp)).reshape(-1)
-            )
-            # HLL: register = top p bits of an independent mix, rho =
-            # leading zeros of the remaining bits + 1 (bit-smear +
-            # popcount gives the MSB position exactly — no float log)
-            g = _fmix32_jnp(h ^ jnp.uint32(_HLL_SEED))
-            reg = (g >> jnp.uint32(low_bits)).astype(jnp.int32)
-            w = g & jnp.uint32((1 << low_bits) - 1)
-            fill = w
-            for s in (1, 2, 4, 8, 16):
-                fill = fill | (fill >> jnp.uint32(s))
-            msb_cnt = jax.lax.population_count(fill).astype(jnp.int32)
-            rho = low_bits - msb_cnt + 1
-            hll = hll.at[reg].max(jnp.where(real, rho, 0))
-            return cm, hll
-
-        self._update_fns[key] = update
-        return update
-
-    def update(self, slots, n_real: int) -> None:
-        """Fold one chunk's rows into the count-min and HLL sketches:
-        `slots` per row (rows beyond `n_real` are masked; the row bucket
-        pads to a power of two so the jit cache stays bounded).  One
-        stateless donated-array dispatch; nothing is read back."""
-        slots_np = np.asarray(slots, dtype=np.int32)
-        Bp = _bucket(max(len(slots_np), 1), _MIN_ROW_BUCKET)
-        if len(slots_np) != Bp:
-            slots_np = np.concatenate(
-                [slots_np, np.zeros(Bp - len(slots_np), dtype=np.int32)]
-            )
-        n_real = min(int(n_real), Bp)
+    def dispatch_fold(self, run, n_real: int, path: str):
+        """One chunk's fold, dispatched by `run(state) -> (state', rest)`
+        under the state lock — the donated `(cm, hll)` goes in and its
+        successor is stored back before anyone else can dispatch or
+        pull.  Returns `rest`."""
         with self._lock:
-            cap = int(self._slot_hash_dev.shape[0])
-            fn = self._update_fn(Bp, cap)
-            self._state = fn(
-                self._state, self._slot_hash_dev, jnp.asarray(slots_np),
-                jnp.int32(n_real),
-            )
+            self._state, rest = run(self._state)
             self.lines_total += n_real
             self.update_count += 1
+            self.updates_by_path[path] += 1
+        return rest
+
+    def update(self, row_hashes, n_real: int) -> None:
+        """Fold one chunk's rows as a program of its own — what is not
+        dispatched fused: `row_hashes` per row (rows beyond `n_real` are
+        masked; the row bucket pads to a power of two so the jit cache
+        stays bounded).  One stateless donated-array dispatch, its
+        operands passed as they are (the call transfers them itself, one
+        trip through the runtime); nothing is read back."""
+        h = np.asarray(row_hashes, dtype=np.uint32)
+        Bp = _bucket(max(len(h), 1), _MIN_ROW_BUCKET)
+        if len(h) != Bp:
+            h = np.concatenate([h, np.zeros(Bp - len(h), dtype=np.uint32)])
+        n_real = min(int(n_real), Bp)
+        self.dispatch_fold(
+            lambda state: (self._standalone(state, h, np.int32(n_real)), None),
+            n_real, "standalone",
+        )
 
     def note_rule_events(self, rule_ids) -> None:
         """Fold fired (line, rule) window events into the per-rule
@@ -389,7 +388,7 @@ class TrafficSketch:
             ids[(ids >= 0) & (ids < self._n_rules)],
             minlength=self._n_rules,
         )
-        with self._lock:
+        with self._rule_lock:
             self._rule_hits += counts
 
     # ---- the periodic compact pull ----
@@ -424,14 +423,15 @@ class TrafficSketch:
                 hll = np.asarray(self._state[1])
             finally:
                 trace.end(sp)
-            rule_hits = self._rule_hits  # host-side, no pull needed
+            with self._rule_lock:
+                rule_hits = self._rule_hits.copy()  # host-side, no pull
             self._cm_cache = cm  # refresh the admission gate's cache
             self.pull_bytes_total += cm.nbytes + hll.nbytes
             self.pull_count += 1
             self._last_pull_mono = time.monotonic()
 
             top: List[dict] = []
-            cand = self._candidates_locked()
+            cand = self._candidates
             if cand:
                 ips = list(cand)
                 base = np.fromiter(
@@ -493,8 +493,7 @@ class TrafficSketch:
         """Point estimate for one IP from the LAST pulled count-min
         state (tests; /traffic debugging).  Conservative: >= the true
         count folded in before that pull."""
-        summary = self.pull()
-        del summary
+        self.pull()
         with self._lock:
             cm = np.asarray(self._state[0]).reshape(self.depth, self.width)
             cm_host = self._cm_host
@@ -583,8 +582,3 @@ class TrafficSketch:
         out = dict(self.pull(force=True))
         out["enabled"] = True
         return out
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _scatter_hashes(table, idx, val):
-    return table.at[idx].set(val, mode="drop")

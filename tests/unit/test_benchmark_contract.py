@@ -928,6 +928,40 @@ def test_longline_family_is_on_metrics_and_its_reader_reads_it(
             {**ctx, "prom0": {}, "prom1": {}}) is None
 
 
+def test_sketch_updates_family_is_on_metrics_and_its_reader_reads_it(
+        longline_scrapes):
+    """`banjax_sketch_updates_total{path}` (ISSUE 44), off `/metrics` with
+    tracing off and through `sketch_fused_share`: of the fixture's three
+    batches two commit fused and carry their fold, and the one a byte over
+    0x7F sends the classic way folds as a program of its own."""
+    from benchmark.harness import found, prom
+
+    family = "banjax_sketch_updates_total"
+    assert family in {f.prom for f in registry.FAMILIES}
+    before, after = longline_scrapes
+    assert prom.value(before, family, path="fused") == 0
+    assert prom.value(after, family, path="fused") == 2
+    assert prom.value(after, family, path="standalone") == 1
+    assert prom.value(after, "banjax_traffic_sketch_lines_total") == 300
+    reader = found.module("layers", "sketch_fused_share")
+    ctx = {"prom0": before, "prom1": after}
+    assert reader.read(ctx) == pytest.approx(200 / 3)
+    # the phase the fold left still has its family and its reader
+    assert prom.value(
+        after, "banjax_submit_phase_seconds_total", phase="sketch") > 0
+    assert found.module(
+        "layers", "submit_sketch_ms_per_kline").read(ctx) > 0
+    # a program without the counter (PR 44's parent), an idle window
+    assert reader.read({"prom0": {}, "prom1": {}}) is None
+    assert reader.read({"prom0": after, "prom1": after}) is None
+    entry = [m for m in found.benchmark_json()["per_layer"]
+             if m["name"] == "sketch_fused_share"]
+    assert entry == [{
+        "name": "sketch_fused_share", "unit": "%", "better": "higher",
+        "source": "program_counter", "layer": "device windows and tiers",
+        "moves": "lines_per_s"}]
+
+
 def test_long_match_readers_take_the_launches_over_the_short_width():
     """`long_match_us_per_kline` and `long_match_roofline` take the long
     launches to be the match-kernel launches whose padded line length

@@ -138,11 +138,11 @@ def test_bundle_traffic_section_from_sketch(tmp_path):
     hitters, cardinality and rule pressure as of the incident."""
     import numpy as np
 
-    from banjax_tpu.obs.sketch import TrafficSketch
+    from banjax_tpu.obs.sketch import TrafficSketch, hash_ip
 
     sk = TrafficSketch(["r0"], width=1024, pull_seconds=3600.0)
-    sk.note_assignments(["6.6.6.6"], np.asarray([0]))
-    sk.update(np.zeros(32, dtype=np.int32), 32)
+    sk.note_assignments(["6.6.6.6"])
+    sk.update(np.full(32, hash_ip("6.6.6.6"), dtype=np.uint32), 32)
     sk.note_rule_events([0, 0, 0])
     rec = _recorder(tmp_path, traffic_fn=sk.incident_snapshot)
     name = rec.notify("shed-burst", "flood")

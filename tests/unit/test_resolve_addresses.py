@@ -434,11 +434,10 @@ def test_candidates_written_in_bulk_are_the_per_address_lru(bound, seed):
     pool = [ip_of(i) for i in range(200)]
     for step in range(80):
         ips = rng.sample(pool, rng.randrange(1, 3 * bound // 2))
-        slots = np.asarray(rng.sample(range(4096), len(ips)))
         hashes = None
         if rng.random() < 0.5:
             hashes = slotmgr.crc32_spans(slotmgr.encode_ips(ips))
-        sk.note_assignments(ips, slots, hashes=hashes)
+        sk.note_assignments(ips, hashes=hashes)
         for ip in ips:
             ref[ip] = hash_ip(ip)
             ref.move_to_end(ip)
@@ -448,20 +447,6 @@ def test_candidates_written_in_bulk_are_the_per_address_lru(bound, seed):
             assert list(sk._candidates.items()) == list(ref.items()), step
     assert list(sk._candidates.items()) == list(ref.items())
     assert sk.pull(force=True)["sketch"]["candidates"] == len(ref)
-
-
-def test_a_hit_uploads_nothing_and_a_new_owner_rebinds_the_hash():
-    sk = TrafficSketch(["r"], width=64, depth=2)
-    ips = [ip_of(i) for i in range(10)]
-    slots = np.arange(10)
-    h = slotmgr.crc32_spans(slotmgr.encode_ips(ips))
-    sk.note_assignments(ips, slots, hashes=h)
-    before = sk._slot_hash_dev
-    sk.note_assignments(ips, slots, hashes=h)       # all hits
-    assert sk._slot_hash_dev is before
-    sk.note_assignments(["9.9.9.9"], np.asarray([3]))   # slot 3 changes hands
-    assert sk._slot_hash_host[3] == hash_ip("9.9.9.9")
-    assert int(np.asarray(sk._slot_hash_dev)[3]) == hash_ip("9.9.9.9")
 
 
 # ----------------------------------------------- through the submit stage

@@ -225,6 +225,7 @@ def stress_single(one_chip, stress_prefilter):
     import types
 
     from banjax_tpu.matcher.kernels import fused_match_window as fmw
+    from banjax_tpu.obs.sketch import TrafficSketch
 
     pf = stress_prefilter
     sds, state = _stress_state(one_chip)
@@ -233,17 +234,24 @@ def stress_single(one_chip, stress_prefilter):
         _iv_s=jnp.full((STRESS_RULES,), 300, jnp.int32),
         _iv_ns=jnp.zeros((STRESS_RULES,), jnp.int32),
     )
+    # as the product builds it by default: the chunk's traffic-sketch fold
+    # in the program, its (cm, hll) donated beside the window table
+    sketch = TrafficSketch(["r"])
     fn, k, p, e = fmw.build_single_program(
         pf, win, jnp.ones((1, STRESS_RULES), bool), STRESS_RULES, B, L_P,
         f_idx=jnp.asarray(pf.plan.f_idx, jnp.int32),
         a_idx=jnp.asarray(pf.plan.a_idx, jnp.int32), aw=None, ae=None,
-        scan_fn=fmw.window_scan(False),
+        scan_fn=fmw.window_scan(False), sketch=sketch,
     )
     assert (k, p, e) == (K, 1024, 1024)
     vec = sds((B,), jnp.int32)
+    sketch_state = (sds((sketch.depth * sketch.width,), jnp.int32),
+                    sds((sketch.m,), jnp.int32))
     return fn.lower(
-        state, sds((), jnp.int32), sds((B, 1 + L_P // 4), jnp.int32),
+        state, sketch_state, sds((), jnp.int32),
+        sds((B, 1 + L_P // 4), jnp.int32),
         sds((), jnp.int32), vec, vec, vec, vec, sds((B,), jnp.uint8),
+        sds((B,), jnp.uint32),
     ).compile()
 
 
@@ -256,6 +264,10 @@ def test_stress10k_single_program_holds_the_table_in_place(
     assert stress_single.as_text().count("tpu_custom_call") >= 3
     m = _fits_with_the_table_aliased(stress_single)
     assert m.temp_size_in_bytes < 1e9
+    # the sketch's state (count-min 4 x 8,192 and 4,096 HLL registers,
+    # int32) is written in place too
+    table = 16 * STRESS_SLOTS * STRESS_RULES
+    assert m.alias_size_in_bytes >= table + 4 * (4 * 8192 + 4096)
 
 
 def _ops_over(text: str, ops, dtype: str, sizes) -> list:

@@ -1,8 +1,8 @@
 """Traffic-sketch accuracy (ISSUE 8): count-min top-K recall and HLL
 relative error fuzzed on skewed (Zipf) and all-distinct synthetic
 feeds against exact host-side counts, the conservative-estimate
-invariant, slot-table reassignment semantics, and the matcher-level
-sampling surface (pull throttle, /traffic summary shape)."""
+invariant, two owners of one window slot counted apart, and the
+matcher-level sampling surface (pull throttle, /traffic summary shape)."""
 
 import time
 
@@ -42,21 +42,22 @@ def _sketch(**kw):
     return TrafficSketch(["heavy", "quiet"], **kw)
 
 
-def _feed_ids(sketch, ids, pool, slot_of, batch=1024):
+def _row_hashes(ips):
+    return np.asarray([hash_ip(ip) for ip in ips], dtype=np.uint32)
+
+
+def _feed_ids(sketch, ids, pool, batch=1024):
     """Stream integer ip-ids through the sketch the way the matcher
-    does: distinct (ip, slot) assignments per batch, then one row-level
-    update keyed on slots."""
+    does: a batch's distinct addresses noted as candidates, then one
+    row-level update keyed on the rows' address hashes."""
+    hashes = _row_hashes(pool)
     for s in range(0, len(ids), batch):
         chunk = ids[s : s + batch]
-        ips, uslots = [], []
-        for i in dict.fromkeys(chunk.tolist()):  # first-appearance order
-            if i not in slot_of:
-                slot_of[i] = len(slot_of)
-            ips.append(pool[i])
-            uslots.append(slot_of[i])
-        sketch.note_assignments(ips, np.asarray(uslots))
-        rows = np.asarray([slot_of[i] for i in chunk], dtype=np.int32)
-        sketch.update(rows, len(chunk))
+        distinct = list(dict.fromkeys(chunk.tolist()))  # first appearance
+        sketch.note_assignments(
+            [pool[i] for i in distinct], hashes[distinct]
+        )
+        sketch.update(hashes[chunk], len(chunk))
 
 
 def test_zipf_topk_recall_and_conservative_estimates():
@@ -70,7 +71,7 @@ def test_zipf_topk_recall_and_conservative_estimates():
     exact = np.bincount(ids, minlength=n_pool)
 
     sk = _sketch()
-    _feed_ids(sk, ids, pool, {})
+    _feed_ids(sk, ids, pool)
     summary = sk.pull(force=True)
     assert summary["lines_total"] == len(ids)
 
@@ -109,7 +110,7 @@ def test_all_distinct_hll_relative_error():
     pool = [f"203.{i >> 16 & 255}.{i >> 8 & 255}.{i & 255}" for i in range(n)]
     ids = np.arange(n)
     sk = _sketch()
-    _feed_ids(sk, ids, pool, {})
+    _feed_ids(sk, ids, pool)
     summary = sk.pull(force=True)
     est = summary["distinct_ips_estimate"]
     assert abs(est - n) / n < 0.15, f"HLL estimate {est} vs true {n}"
@@ -117,16 +118,17 @@ def test_all_distinct_hll_relative_error():
     assert summary["top"][0]["est_count"] <= 32
 
 
-def test_slot_reassignment_rebinds_the_hash():
-    """An evicted slot reassigned to a new IP must count for the NEW
-    IP: the slot->hash table refresh is what keeps sketch keys stable
-    across slot churn."""
+def test_two_owners_of_one_slot_are_counted_apart():
+    """A window slot evicted and handed to a new IP counts for the NEW
+    IP: a row is keyed on its own address's hash, whatever slot the
+    address holds (through the matcher, with real eviction churn:
+    test_sketch_fused.py)."""
     sk = _sketch(width=1024)
-    sk.note_assignments(["1.1.1.1"], np.asarray([0]))
-    sk.update(np.zeros(10, dtype=np.int32), 10)
-    # slot 0 evicted and handed to 2.2.2.2
-    sk.note_assignments(["2.2.2.2"], np.asarray([0]))
-    sk.update(np.zeros(5, dtype=np.int32), 5)
+    sk.note_assignments(["1.1.1.1"])
+    sk.update(_row_hashes(["1.1.1.1"] * 10), 10)
+    # its slot evicted and handed to 2.2.2.2
+    sk.note_assignments(["2.2.2.2"])
+    sk.update(_row_hashes(["2.2.2.2"] * 5), 5)
     assert sk.estimate_ip("1.1.1.1") >= 10
     assert sk.estimate_ip("2.2.2.2") >= 5
     # conservative but not conflated (different hashes, different buckets
@@ -137,8 +139,7 @@ def test_slot_reassignment_rebinds_the_hash():
 def test_candidate_lru_is_bounded():
     sk = _sketch(max_candidates=64)
     pool = [f"9.9.{i >> 8}.{i & 255}" for i in range(512)]
-    slot_of = {}
-    _feed_ids(sk, np.arange(512), pool, slot_of, batch=128)
+    _feed_ids(sk, np.arange(512), pool, batch=128)
     assert len(sk._candidates) <= 64
     # the most recent IPs are the ones retained
     assert pool[-1] in sk._candidates
@@ -158,11 +159,11 @@ def test_rule_pressure_is_exact_from_events():
 
 def test_pull_is_throttled_to_the_sampling_interval():
     sk = _sketch(pull_seconds=3600.0)
-    sk.note_assignments(["4.4.4.4"], np.asarray([0]))
-    sk.update(np.zeros(8, dtype=np.int32), 8)
+    sk.note_assignments(["4.4.4.4"])
+    sk.update(_row_hashes(["4.4.4.4"] * 8), 8)
     first = sk.pull()
     assert sk.pull_count == 1
-    sk.update(np.zeros(8, dtype=np.int32), 8)
+    sk.update(_row_hashes(["4.4.4.4"] * 8), 8)
     # within the interval: the cached summary is shared, no new d2h
     assert sk.pull() is first
     assert sk.pull_count == 1
@@ -263,7 +264,7 @@ def test_pull_records_a_trace_span():
     tracer = trace.configure(enabled=True, ring_size=64)
     try:
         sk = _sketch()
-        sk.update(np.zeros(4, dtype=np.int32), 4)
+        sk.update(np.zeros(4, dtype=np.uint32), 4)
         sk.pull(force=True)
         names = [s["name"] for s in tracer.snapshot()]
         assert "sketch-pull" in names
