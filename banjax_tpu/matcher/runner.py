@@ -1144,7 +1144,7 @@ class TpuMatcher(Matcher):
                 )
 
         with self._resolving(lap):
-            uips, uinv = state["work"].unique_ips()
+            uips, uinv = self._distinct_addresses(state["work"])
             counts = None
             if gate and self._admission_min_estimate > 1:
                 counts = np.bincount(
@@ -1180,6 +1180,17 @@ class TpuMatcher(Matcher):
         with self._resolving(lap):
             dw.place_resolved(res)
             keep_slots(uinv[adm])
+
+    def _distinct_addresses(self, work):
+        """(a batch's distinct addresses, the per-row inverse) for the
+        pass over them, in the form the work set holds them: byte spans
+        off the native parse (workset unique_ip_spans; what the native
+        slot manager works on), else strings (a Python parse, the dict
+        path)."""
+        got = None
+        if self.device_windows.slotmgr_native:
+            got = work.unique_ip_spans()
+        return work.unique_ips() if got is None else got
 
     def _sketch_row_hashes(self, uips, res, uinv, lap):
         """What the traffic sketch takes of a resolved batch, timed as the
@@ -1509,7 +1520,7 @@ class TpuMatcher(Matcher):
         are what the traffic sketch folds the rows under (None without
         one).  None when placement refused.  Pin/release semantics are
         unchanged — release_pins deduplicates slot ids either way."""
-        uips, uinv = work.unique_ips()
+        uips, uinv = self._distinct_addresses(work)
         res = self.device_windows.resolve_addresses(
             uips, sketch=self.traffic_sketch
         )
@@ -1662,16 +1673,21 @@ class TpuMatcher(Matcher):
             [r for r in cand if int(r) not in dset], dtype=np.int64
         ) if dset else cand
         text = nb.text()
-        ips_u, ip_inv_v = unique_spans(
-            nb.ip_off[vrows], nb.ip_len[vrows],
-            lambda k: nb.ip(int(vrows[k])),
+        ip_off, ip_len = nb.ip_off[vrows], nb.ip_len[vrows]
+        ips_u, ip_inv_v, ip_first = unique_spans(
+            ip_off, ip_len, lambda k: nb.ip(int(vrows[k])),
             blob=nb.blob, text=text, dedup_scratch=dedup_scratch,
         )
-        hosts_u, host_inv_v = unique_spans(
+        hosts_u, host_inv_v, _ = unique_spans(
             nb.host_off[vrows], nb.host_len[vrows],
             lambda k: nb.host(int(vrows[k])),
             blob=nb.blob, text=text, dedup_scratch=dedup_scratch,
         )
+        # the distinct addresses' key bytes where the parse blob holds
+        # them: what the submit stage's pass over them works on
+        span_buf = nb.blob
+        span_off = ip_off[ip_first].astype(np.int64)
+        span_len = ip_len[ip_first].astype(np.int64)
         ip_inv = np.empty(cand.size, dtype=np.int64)
         host_inv = np.empty(cand.size, dtype=np.int64)
         if dset:
@@ -1686,6 +1702,7 @@ class TpuMatcher(Matcher):
             host_inv[vmask] = host_inv_v
             iidx = {s: j for j, s in enumerate(ips_u)}
             hidx = {s: j for j, s in enumerate(hosts_u)}
+            patched: List[bytes] = []  # a Python-parsed address's bytes
             for r in darr.tolist():
                 p = defer_map[r]
                 # position of r in cand, or absent (errored/old defer rows)
@@ -1697,6 +1714,7 @@ class TpuMatcher(Matcher):
                     j = len(ips_u)
                     ips_u.append(p.ip)
                     iidx[p.ip] = j
+                    patched.append(p.ip.encode("utf-8", "surrogatepass"))
                 ip_inv[k] = j
                 j = hidx.get(p.host)
                 if j is None:
@@ -1704,6 +1722,13 @@ class TpuMatcher(Matcher):
                     hosts_u.append(p.host)
                     hidx[p.host] = j
                 host_inv[k] = j
+            if patched:
+                # ... lie behind the blob in a copy of it
+                lens_p = np.fromiter(map(len, patched), np.int64, len(patched))
+                offs_p = len(span_buf) + np.cumsum(lens_p) - lens_p
+                span_buf = b"".join([span_buf, *patched])
+                span_off = np.concatenate([span_off, offs_p])
+                span_len = np.concatenate([span_len, lens_p])
         else:
             ip_inv[:] = ip_inv_v
             host_inv[:] = host_inv_v
@@ -1753,6 +1778,7 @@ class TpuMatcher(Matcher):
         work = NativeWork(
             nb, rows, ips_u, ip_inv[keep], hosts_u, host_inv[keep],
             ts[rows], defer_map,
+            (np.frombuffer(span_buf, dtype=np.uint8), span_off, span_len),
         )
 
         deferred = (flags[rows] & native.FLAG_DEFER) != 0

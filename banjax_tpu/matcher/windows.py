@@ -459,7 +459,8 @@ class EventBatch:
 class Resolution:
     """What one pass over a batch's DISTINCT addresses found and did
     (DeviceWindows.resolve_addresses).  Index arrays index the address
-    list the pass was given."""
+    list the pass was given.  `ips` is that list as it came: strings, or
+    a slotmgr.AddressSpans, which makes strings when one is read."""
 
     ips: Sequence[str]
     admit: np.ndarray              # bool [n] — the gate's verdict
@@ -633,6 +634,8 @@ class DeviceWindows:
         )
         self.resolve_probes: Dict[str, int] = {"slots": 0, "warm": 0}
         self.gate_derived_batches = 0
+        # passes, by the form their addresses came in and were worked on
+        self.resolve_passes: Dict[str, int] = {"spans": 0, "strings": 0}
 
         self._slots: Dict[str, int] = {}  # ip → slot
         # batch-granular recency per slot (see slots_for_unique_ips)
@@ -645,9 +648,9 @@ class DeviceWindows:
         # runs as one C call per batch over the unique-IP array, with
         # exact Python-path parity (tests/unit/test_slotmgr.py).  The
         # dict loop below stays as the fallback (no C compiler) and the
-        # differential oracle.  _slot_ip mirrors slot→ip in BOTH modes
-        # (shadow updates and restores need the strings); _slots/_free
-        # are dict-path-only.
+        # differential oracle.  The manager is the one owner of slot→ip
+        # where it is there (`_sm.keys_of`); _slot_ip, _slots and _free
+        # are the dict path's and stay empty beside it.
         self._sm = None
         # the residents' shadow records by slot (native/shmstate.c sh_*):
         # there whenever the native manager is — the native placement
@@ -975,6 +978,8 @@ class DeviceWindows:
         n = len(ips)
         admit = np.ones(n, dtype=bool)
         hashes = None
+        with self._lock:
+            self.resolve_passes["strings"] += 1
         if gate and n:
             if sketch is not None and min_estimate > 1:
                 hashes = sketch.base_hashes(ips)
@@ -1010,17 +1015,29 @@ class DeviceWindows:
 
     def _probe_locked(self, ips, counts, min_estimate, sketch, gate):
         """The pass's first half (native manager; caller holds the lock):
-        encode once, look every address up in the slot table (hits
-        stamped), ask the shadow and the warm tier about the misses, and
-        read the gate's verdict from the answers."""
-        from banjax_tpu.native.slotmgr import crc32_spans, encode_ips
+        take the addresses' one encoding — the byte spans they came as
+        (AddressSpans), or one made of the strings here — look every
+        address up in the slot table (hits stamped), ask the shadow and
+        the warm tier about the misses, and read the gate's verdict from
+        the answers.  A string is made of an address only where a dict
+        is asked about it (a non-empty dict shadow, the sketch's estimate
+        of an unseen one)."""
+        from banjax_tpu.native.slotmgr import (
+            AddressSpans, crc32_spans, encode_ips,
+        )
 
         n = len(ips)
         self._batch_seq += 1
         seq = self._batch_seq
         admit = np.ones(n, dtype=bool)
         refused = np.empty(0, dtype=np.int64)
-        enc = encode_ips(ips)
+        hashes = None
+        if isinstance(ips, AddressSpans):
+            enc, hashes = ips.enc, ips.hashes
+            self.resolve_passes["spans"] += 1
+        else:
+            enc = encode_ips(ips)
+            self.resolve_passes["strings"] += 1
         slots, miss_idx, _ = self._sm.lookup_batch(
             ips, seq, self._last_used, enc=enc
         )
@@ -1029,7 +1046,7 @@ class DeviceWindows:
             _enc=enc, _seq=seq, _miss_idx=miss_idx,
         )
         if sketch is not None and n:
-            res.hashes = crc32_spans(enc)
+            res.hashes = crc32_spans(enc) if hashes is None else hashes
         m = len(miss_idx)
         self.resolve_probes["slots"] += n
         tally = self.resolve_outcomes
@@ -1038,11 +1055,11 @@ class DeviceWindows:
         in_warm = np.zeros(m, dtype=bool)
         asked_sketch = False
         if m:
-            miss_ips = list(map(ips.__getitem__, miss_idx.tolist()))
             shadow = self._shadow
             if shadow:
                 in_shadow = np.fromiter(
-                    map(shadow.__contains__, miss_ips), dtype=bool, count=m
+                    (ips[i] in shadow for i in miss_idx.tolist()),
+                    dtype=bool, count=m,
                 )
             warm = self._warm
             if warm is not None and len(warm):
@@ -1050,9 +1067,7 @@ class DeviceWindows:
                 if len(ask):
                     at = miss_idx[ask]
                     in_warm[ask] = warm.contains_batch(
-                        miss_ips if len(ask) == m
-                        else [miss_ips[k] for k in ask.tolist()],
-                        spans=(enc[0], enc[1][at], enc[2][at]),
+                        None, spans=(enc[0], enc[1][at], enc[2][at])
                     )
                     self.resolve_probes["warm"] += len(ask)
             unseen = miss_idx[~(in_shadow | in_warm)]
@@ -1091,10 +1106,10 @@ class DeviceWindows:
     def _place_locked(self, res: Resolution) -> None:
         """The pass's second half (caller holds the lock): the Python
         growth chain, one C placement of the admitted misses (free stack,
-        then the oldest evictable slots by selection), the evicted
-        addresses' spills in one warm-tier call, the returning ones'
-        refills in one more, and the pins.  Python work is O(misses +
-        evictions) dict bookkeeping only."""
+        then the oldest evictable slots off the manager's kept order), the
+        evicted addresses' spills in one warm-tier call, the returning ones'
+        refills in one more, and the pins.  No Python object is made per
+        address: slot -> address is the manager's to know."""
         res.placed = True
         sm = self._sm
         slots = res.slots
@@ -1111,7 +1126,7 @@ class DeviceWindows:
             # the ceiling allows — the same final capacity the
             # grow-on-empty loop reaches
             new_cap = self.capacity
-            free_cnt = new_cap - len(self._slot_ip)
+            free_cnt = new_cap - sm.assigned()
             steps = 0
             while (
                 free_cnt < n_miss
@@ -1133,29 +1148,20 @@ class DeviceWindows:
             self._last_used,
         )
         if len(evicted):
-            ev = evicted.tolist()
-            pop = self._slot_ip.pop
-            self._note_evictions_locked(
-                evicted, [pop(s, None) for s in ev], ev_keys
-            )
-            self._pending_evict.extend(ev)
+            self._note_evictions_locked(evicted, ev_keys)
+            self._pending_evict.extend(evicted.tolist())
             if self.eviction_count == 0:
                 self._warn_first_eviction()
-            self.eviction_count += len(ev)
+            self.eviction_count += len(evicted)
         n_placed = len(placed_idx)
         if n_placed:
             ips = res.ips
             slot_a = slots[placed_idx]
-            slot_l = slot_a.tolist()
-            ip_l = list(map(ips.__getitem__, placed_idx.tolist()))
-            # C-speed mirror update: at the all-distinct-IP shape this
-            # loop IS the residual host cost, so no per-entry Python
-            self._slot_ip.update(zip(slot_l, ip_l))
             pend_sketch = self._sketch_pending
             if pend_sketch:  # admitted by an admission_mask call
-                for slot, ip in zip(slot_l, ip_l):
-                    if ip in pend_sketch:
-                        pend_sketch.discard(ip)
+                for slot, i in zip(slot_a.tolist(), placed_idx.tolist()):
+                    if ips[i] in pend_sketch:
+                        pend_sketch.discard(ips[i])
                         self._sketch_slots[slot] = True
             # returning addresses, in placement order (placed_idx is a
             # prefix of place_idx: placement goes in ip order and stops
@@ -1168,13 +1174,15 @@ class DeviceWindows:
             w_pos = np.flatnonzero(in_warm[:n_placed])
             if len(w_pos):
                 self._refill_locked(
-                    res._enc, placed_idx, slot_a, ip_l, w_pos, stamps
+                    res._enc, placed_idx, slot_a, ips, w_pos, stamps
                 )
             for k in np.flatnonzero(in_shadow[:n_placed]).tolist():
-                vec = self._shadow.pop(ip_l[k], None)
-                stamp = self._shadow_stamp.pop(ip_l[k], 0)
+                ip = ips[int(placed_idx[k])]
+                vec = self._shadow.pop(ip, None)
+                stamp = self._shadow_stamp.pop(ip, 0)
                 if vec:
-                    stamps[k] = self._mirror.install(slot_l[k], vec, stamp)
+                    stamps[k] = self._mirror.install(
+                        int(slot_a[k]), vec, stamp)
             back = np.flatnonzero(stamps)
             if len(back):
                 self._pending_restore.append((slot_a[back], stamps[back]))
@@ -1257,14 +1265,15 @@ class DeviceWindows:
             self.warm_spills += 1
             self.shadow_records["spill"]["dict"] += 1
 
-    def _note_evictions_locked(self, slots: np.ndarray, ips, keys) -> None:
+    def _note_evictions_locked(self, slots: np.ndarray, keys) -> None:
         """_note_eviction_locked for all of one native placement's
         victims (slots int64 [k], eviction order; `keys` = the slot
         manager's evict_keys): the records of those that hold one move
         from the mirror into the warm tier in ONE C call.  A record the
         tier did not take — a dropped put, the warm tier off, a tier
         that is not the C table — moves into the dict, keyed by its
-        address: the state stays on the host, as ever."""
+        address, which is read off the manager's keys then: the state
+        stays on the host, as ever."""
         status = self._mirror.spill(
             self._warm_c, slots, keys, time.time_ns()
         )
@@ -1282,11 +1291,10 @@ class DeviceWindows:
         if not len(left):
             return
         warm = self._warm if self._warm_c is None else None  # a Python tier
+        ips = self._sm.evicted_keys(keys)
         stamps, vecs = self._mirror.export(slots[left], drop=True)
         for k, stamp, vec in zip(left.tolist(), stamps, vecs):
             ip = ips[k]
-            if ip is None:
-                continue
             if warm is not None and warm.put(
                 ip, [(r, h, s, ns) for r, (h, s, ns) in vec.items()],
                 time.time_ns(),
@@ -1297,14 +1305,14 @@ class DeviceWindows:
             self._shadow[ip] = vec
             self._shadow_stamp[ip] = stamp
 
-    def _refill_locked(self, enc, placed_idx, slot_a, ip_l, w_pos, stamps):
+    def _refill_locked(self, enc, placed_idx, slot_a, ips, w_pos, stamps):
         """The warm residents among one native placement's addresses
         (positions w_pos of the placed) take their records back: tier →
         mirror at the new slot in ONE C call; stamps[w_pos] names the
         records made (0 where the tier had none after all)."""
         if self._warm_c is None:  # no C table to move records out of
             for k in w_pos.tolist():
-                ent = self._warm.take(ip_l[k])
+                ent = self._warm.take(ips[int(placed_idx[k])])
                 if ent is not None:
                     stamps[k] = self._mirror.install(
                         int(slot_a[k]),
@@ -1402,9 +1410,26 @@ class DeviceWindows:
     def occupancy(self) -> int:
         """IP slots currently assigned (capacity-pressure gauge)."""
         with self._lock:
-            # _slot_ip mirrors assignments in both the native and dict
-            # modes; _slots is dict-mode-only
+            if self._sm is not None:
+                return self._sm.assigned()
             return len(self._slot_ip)
+
+    def slot_addresses(self) -> Dict[int, str]:
+        """slot -> address of every assigned slot, whichever form owns
+        the table: the native manager's keys, or the dict path's own
+        mirror.  Introspection; no window's."""
+        with self._lock:
+            if self._sm is None:
+                return dict(self._slot_ip)
+            slots = np.sort(self._sm.order())
+            return dict(zip(slots.tolist(), self._sm.keys_of(slots)))
+
+    @property
+    def eviction_scanned_slots(self) -> int:
+        """Slots the native placements read to find their victims (the
+        members of every run of the kept order they sorted included);
+        0 on the dict path, whose argmin reads the whole table."""
+        return self._sm.scanned() if self._sm is not None else 0
 
     def clear(self) -> None:
         """Hot-reload semantics: drop all counters (decision.go Clear analog)."""
@@ -1806,11 +1831,10 @@ class DeviceWindows:
             return list(self._shadow.items())
         live = self._mirror.live_slots()
         stamps, vecs = self._mirror.export(live)
-        slot_ip = self._slot_ip
         held = [
-            (stamp, slot_ip[slot], vec)
-            for stamp, slot, vec in zip(stamps, live.tolist(), vecs)
-            if slot in slot_ip
+            (stamp, ip, vec)
+            for stamp, ip, vec in zip(stamps, self._sm.keys_of(live), vecs)
+            if ip is not None
         ]
         held += [
             (self._shadow_stamp.get(ip, 0), ip, od)

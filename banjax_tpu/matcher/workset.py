@@ -17,6 +17,8 @@ end-to-end wall). This module keeps the batch COLUMNAR end to end:
 The interface both provide:
   len(work); work[int] -> (orig_index, line); work[slice] -> same kind;
   iteration over (orig_index, line); unique_ips() -> (list[str], inverse);
+  unique_ip_spans() -> the same as byte spans (slotmgr.AddressSpans,
+  inverse), or None from a work set that holds its addresses as strings;
   host_idx(host_row) -> np.int32 per row; ts_array() -> np.int64 per row;
   rest_bytes(ks) -> the rows' regex haystacks as bytes, back to back.
 """
@@ -31,6 +33,7 @@ import numpy as np
 
 from banjax_tpu.matcher.api import ConsumeLineResult
 from banjax_tpu.matcher.encode import ParsedLine
+from banjax_tpu.native.slotmgr import merge_spans
 
 
 class _Row:
@@ -216,15 +219,18 @@ class NativeWork:
     `rows` are indices into the parse batch (== original line indices);
     `ip_inv`/`host_inv` index the shared unique-string tables. Slicing
     shares the tables (compaction happens in unique_ips, where a stale
-    entry would otherwise leak a slot pin)."""
+    entry would otherwise leak a slot pin).  `ip_spans` is the key bytes
+    of `ips_u`, entry for entry, as (buf uint8, offs int64, lens int64):
+    what the submit stage's address pass works on (None: it takes the
+    strings)."""
 
     __slots__ = (
         "nb", "rows", "ips_u", "ip_inv", "hosts_u", "host_inv", "ts_ns",
-        "defer_map",
+        "defer_map", "ip_spans",
     )
 
     def __init__(self, nb, rows, ips_u, ip_inv, hosts_u, host_inv, ts_ns,
-                 defer_map):
+                 defer_map, ip_spans=None):
         self.nb = nb
         self.rows = rows                  # np.int64 [n] — nb/original rows
         self.ips_u: List[str] = ips_u
@@ -234,6 +240,7 @@ class NativeWork:
         self.ts_ns = ts_ns                # np.int64 [n]
         # python-parsed lines for FLAG_DEFER rows, keyed by nb row
         self.defer_map: Dict[int, ParsedLine] = defer_map
+        self.ip_spans = ip_spans
 
     def __len__(self) -> int:
         return len(self.rows)
@@ -243,7 +250,7 @@ class NativeWork:
             return NativeWork(
                 self.nb, self.rows[k], self.ips_u, self.ip_inv[k],
                 self.hosts_u, self.host_inv[k], self.ts_ns[k],
-                self.defer_map,
+                self.defer_map, self.ip_spans,
             )
         nbrow = int(self.rows[k])
         p = self.defer_map.get(nbrow)
@@ -284,8 +291,15 @@ class NativeWork:
         return NativeWork(
             self.nb, self.rows[idx], self.ips_u, self.ip_inv[idx],
             self.hosts_u, self.host_inv[idx], self.ts_ns[idx],
-            self.defer_map,
+            self.defer_map, self.ip_spans,
         )
+
+    def unique_ip_spans(self):
+        """unique_ips() by bytes: (AddressSpans, per-row inverse), the
+        same addresses in the same order, or None without the spans."""
+        if self.ip_spans is None:
+            return None
+        return merge_spans([(self.ip_spans, self.ip_inv)])
 
     def unique_ips(self) -> Tuple[List[str], np.ndarray]:
         """(distinct ips present in THIS view, per-row inverse). Compacts
@@ -357,6 +371,9 @@ class ListWork(list):
                 uniq[p.ip] = j
             inv[k] = j
         return list(uniq), inv
+
+    def unique_ip_spans(self):
+        return None  # a Python parse holds strings
 
     def orig_rows(self) -> np.ndarray:
         return np.fromiter((i for i, _ in self), np.int64, len(self))
@@ -484,6 +501,18 @@ class CompositeWork:
             for ips_u, inv in tables
         ])
 
+    def unique_ip_spans(self):
+        """unique_ips() by bytes: the shards' tables merged by one C
+        dedup over their spans, in the same order — (AddressSpans,
+        per-row inverse); None when a shard holds strings only."""
+        tables = []
+        for w in self.parts:
+            enc = getattr(w, "ip_spans", None)
+            if enc is None:
+                return None
+            tables.append((enc, w.ip_inv))
+        return merge_spans(tables)
+
     def orig_rows(self) -> np.ndarray:
         return np.concatenate([
             np.asarray(w.orig_rows(), dtype=np.int64) + off
@@ -516,7 +545,7 @@ def unique_spans(
     offs: np.ndarray, lens: np.ndarray, decode,
     blob: "bytes | None" = None, text: "str | None" = None,
     dedup_scratch=None,
-) -> Tuple[List[str], np.ndarray]:
+) -> Tuple[List[str], np.ndarray, np.ndarray]:
     """Distinct-string extraction over (offset, length) spans of a blob.
 
     Fast path (native lib + `blob`): C open-addressing dedup
@@ -525,10 +554,11 @@ def unique_spans(
     Fallback (native lib failed to load mid-flight — the gate itself only
     runs with it loaded, so this is belt-and-braces): exact per-row dict
     dedup over decoded strings, trivially correct and first-appearance
-    ordered. Returns (unique strings, per-row inverse)."""
+    ordered. Returns (unique strings, per-row inverse, the row each
+    string was first met in — where its bytes lie)."""
     n = len(offs)
     if n == 0:
-        return [], np.zeros(0, dtype=np.int64)
+        return [], np.zeros(0, dtype=np.int64), np.zeros(0, dtype=np.int64)
     if blob is not None:
         from banjax_tpu import native as _native
 
@@ -545,9 +575,10 @@ def unique_spans(
                 ]
             else:
                 strings = [decode(int(r)) for r in first]
-            return strings, ids
+            return strings, ids, first
     seen: Dict[str, int] = {}
     strings: List[str] = []
+    first_rows: List[int] = []
     inv = np.empty(n, dtype=np.int64)
     for r in range(n):
         s = decode(r)
@@ -555,6 +586,7 @@ def unique_spans(
         if j is None:
             j = len(strings)
             strings.append(s)
+            first_rows.append(r)
             seen[s] = j
         inv[r] = j
-    return strings, inv
+    return strings, inv, np.asarray(first_rows, dtype=np.int64)

@@ -540,8 +540,9 @@ class ShmWarmTier:
     def contains_batch(self, ips, spans=None) -> np.ndarray:
         """bool [n] membership over a distinct-ip list — one C call.
         `spans`: (buf, offs, lens) of these ips inside an encoding the
-        caller already holds, so the batch is encoded once."""
-        n = len(ips)
+        caller already holds, so the batch is encoded once (`ips` may
+        then be None: no string is read)."""
+        n = len(ips) if spans is None else len(spans[1])
         out = np.zeros(n, dtype=np.uint8)
         base = self._base_ptr
         if n == 0 or base is None:
@@ -680,6 +681,10 @@ class PyWarmTier:
         return None if v is None else v[1]
 
     def contains_batch(self, ips, spans=None) -> np.ndarray:
+        if ips is None:  # as the C tier: the caller's spans alone
+            from banjax_tpu.native.slotmgr import decode_spans
+
+            ips = decode_spans(spans)
         d = self._d
         return np.fromiter((ip in d for ip in ips), bool, count=len(ips))
 

@@ -19,11 +19,14 @@
  *     pop() order across _grow_locked calls.
  *   - eviction victim: minimum (last_used, slot) over assigned, unpinned
  *     slots not touched by THIS batch (last_used < seq) — exactly
- *     np.argmin's first-minimum tie-break.  The sorted candidate list is
- *     built once per batch and re-validated at consumption, which yields
- *     the same victim sequence as the per-miss argmin because nothing
- *     becomes MORE evictable mid-call (pins are frozen, recency only
- *     advances).
+ *     np.argmin's first-minimum tie-break.  The order is KEPT between
+ *     batches: the assigned slots are linked oldest stamp first as the
+ *     two passes stamp them, one run a batch sequence number, and a run
+ *     is put in slot order once, when the victims' walk reaches it.  The
+ *     walk yields the same victim sequence as the per-miss argmin because
+ *     nothing becomes MORE evictable mid-call (pins are frozen, recency
+ *     only advances): a pinned slot is passed where it is met and stays
+ *     in place, a placement goes to the tail.
  *   - refusal: when every candidate is pinned/touched, return -1 with
  *     earlier misses already placed — the Python loop's partial-state
  *     refusal, after which the caller splits the batch.
@@ -31,9 +34,11 @@
  * Recency (last_used, int64 per slot) and pin counts (int32 per slot)
  * stay in caller-owned numpy arrays shared by pointer, so the Python
  * side's vectorized pin release and introspection keep working
- * unchanged.  IP strings are malloc'd copies owned here; the Python
- * wrapper mirrors slot->ip only for misses/evictions (O(changes), not
- * O(ips)).
+ * unchanged; on this path ONLY the two passes write last_used (the kept
+ * order mirrors it).  This table is the one owner of slot -> address:
+ * the key bytes live in one slab, a stride a slot (a longer key keeps an
+ * allocation of its own), and whoever wants an address of a slot reads
+ * it here (sm_keys_of).
  *
  * Pure C ABI (no Python.h), loaded with ctypes — same convention as
  * fastparse.c.
@@ -44,12 +49,22 @@
 #include <string.h>
 
 #define SM_EVICT_KEY_STRIDE 104 /* shmstate.c WT_KEY_MAX */
+#define SM_KEY_STRIDE SM_EVICT_KEY_STRIDE /* a slot's bytes of the slab */
+
+typedef struct {
+    int64_t k; /* which of the call's victims */
+    uint8_t *p;
+    int32_t len;
+} sm_dead;
 
 typedef struct {
     int64_t capacity;
     int64_t assigned;
-    /* per-slot ip bytes (malloc'd); NULL = unassigned */
-    uint8_t **ip;
+    /* per-slot key bytes: slab[slot * SM_KEY_STRIDE ...] for a key that
+     * fits the stride, else long_key[slot] (malloc'd; the array itself is
+     * made when the first such key arrives); ip_len -1 = unassigned */
+    uint8_t *slab;
+    uint8_t **long_key;
     int32_t *ip_len;
     int64_t *tpos; /* slot -> its index in table (for O(1) delete) */
     /* open addressing, linear probe: value = slot, -1 empty, -2 tomb */
@@ -59,6 +74,19 @@ typedef struct {
     /* free stack: pop from free_slots[free_top - 1] */
     int32_t *free_slots;
     int64_t free_top;
+    /* the eviction order: every assigned slot, doubly linked, oldest
+     * stamp first.  Runs (slots of one last_used) with last_used <=
+     * sorted_lu are in slot order; a younger run is as it was stamped */
+    int32_t *prev, *next;
+    int32_t head, tail;
+    int64_t sorted_lu;
+    int32_t *run;    /* scratch [capacity]: one run while it is sorted */
+    int64_t scanned; /* slots the victims' walks read, sorted runs' too */
+    /* the last placement's victims whose key is longer than the stride
+     * evict_keys cuts at: kept whole until the next placement */
+    sm_dead *dead;
+    int64_t n_dead, dead_cap;
+    int64_t empty_victim; /* ... and which of them had the empty key, or -1 */
 } sm_t;
 
 static uint64_t sm_hash(const uint8_t *p, int64_t n) {
@@ -75,6 +103,27 @@ static int64_t pow2_at_least(int64_t n) {
     while (c < n)
         c <<= 1;
     return c;
+}
+
+static inline const uint8_t *sm_key(const sm_t *sm, int64_t slot) {
+    return sm->ip_len[slot] > SM_KEY_STRIDE
+               ? sm->long_key[slot]
+               : sm->slab + slot * SM_KEY_STRIDE;
+}
+
+/* the slot holding the key, -1 when none does */
+static inline int64_t sm_find(const sm_t *sm, const uint8_t *p, int64_t len) {
+    uint64_t mask = (uint64_t)sm->table_cap - 1;
+    uint64_t s = sm_hash(p, len) & mask;
+    for (;;) {
+        int64_t v = sm->table[s];
+        if (v == -1)
+            return -1;
+        if (v >= 0 && sm->ip_len[v] == (int32_t)len &&
+            memcmp(sm_key(sm, v), p, (size_t)len) == 0)
+            return v;
+        s = (s + 1) & mask;
+    }
 }
 
 /* insertion index for a key known to be ABSENT: first tombstone on the
@@ -94,7 +143,7 @@ static int64_t sm_insert_pos(const sm_t *sm, const uint8_t *p, int64_t len) {
 }
 
 static void sm_table_insert(sm_t *sm, int32_t slot) {
-    int64_t pos = sm_insert_pos(sm, sm->ip[slot], sm->ip_len[slot]);
+    int64_t pos = sm_insert_pos(sm, sm_key(sm, slot), sm->ip_len[slot]);
     if (sm->table[pos] == -2)
         sm->tombs--;
     sm->table[pos] = slot;
@@ -114,9 +163,54 @@ static int sm_table_rebuild(sm_t *sm, int64_t min_cap) {
         sm->table[i] = -1;
     sm->tombs = 0;
     for (int64_t s = 0; s < sm->capacity; s++)
-        if (sm->ip[s])
+        if (sm->ip_len[s] >= 0)
             sm_table_insert(sm, (int32_t)s);
     return 0;
+}
+
+static void sm_drop_dead(sm_t *sm) {
+    for (int64_t j = 0; j < sm->n_dead; j++)
+        free(sm->dead[j].p);
+    sm->n_dead = 0;
+    sm->empty_victim = -1;
+}
+
+/* every slot unassigned, the free stack popping 0, 1, 2, ... —
+ * list(range(cap-1, -1, -1)).pop() parity */
+static void sm_reset(sm_t *sm) {
+    for (int64_t s = 0; s < sm->capacity; s++) {
+        if (sm->ip_len[s] > SM_KEY_STRIDE)
+            free(sm->long_key[s]);
+        sm->ip_len[s] = -1;
+        sm->free_slots[s] = (int32_t)(sm->capacity - 1 - s);
+    }
+    sm->free_top = sm->capacity;
+    sm->assigned = 0;
+    sm->tombs = 0;
+    for (int64_t i = 0; i < sm->table_cap; i++)
+        sm->table[i] = -1;
+    sm->head = sm->tail = -1;
+    sm->sorted_lu = INT64_MIN;
+    sm_drop_dead(sm);
+}
+
+void sm_destroy(void *h) {
+    sm_t *sm = h;
+    if (!sm)
+        return;
+    if (sm->ip_len && sm->free_slots && sm->table)
+        sm_reset(sm);
+    free(sm->slab);
+    free(sm->long_key);
+    free(sm->ip_len);
+    free(sm->tpos);
+    free(sm->free_slots);
+    free(sm->table);
+    free(sm->prev);
+    free(sm->next);
+    free(sm->run);
+    free(sm->dead);
+    free(sm);
 }
 
 void *sm_create(int64_t capacity) {
@@ -126,90 +220,69 @@ void *sm_create(int64_t capacity) {
     if (!sm)
         return NULL;
     sm->capacity = capacity;
-    sm->ip = calloc((size_t)capacity, sizeof(uint8_t *));
-    sm->ip_len = calloc((size_t)capacity, sizeof(int32_t));
+    sm->slab = malloc((size_t)capacity * SM_KEY_STRIDE);
+    sm->ip_len = malloc(sizeof(int32_t) * (size_t)capacity);
     sm->tpos = calloc((size_t)capacity, sizeof(int64_t));
     sm->free_slots = malloc(sizeof(int32_t) * (size_t)capacity);
+    sm->prev = malloc(sizeof(int32_t) * (size_t)capacity);
+    sm->next = malloc(sizeof(int32_t) * (size_t)capacity);
+    sm->run = malloc(sizeof(int32_t) * (size_t)capacity);
     sm->table_cap = pow2_at_least(4 * capacity);
     sm->table = malloc(sizeof(int64_t) * (size_t)sm->table_cap);
-    if (!sm->ip || !sm->ip_len || !sm->tpos || !sm->free_slots || !sm->table) {
-        free(sm->ip);
-        free(sm->ip_len);
-        free(sm->tpos);
-        free(sm->free_slots);
-        free(sm->table);
-        free(sm);
+    if (!sm->slab || !sm->ip_len || !sm->tpos || !sm->free_slots ||
+        !sm->prev || !sm->next || !sm->run || !sm->table) {
+        sm->ip_len = NULL; /* nothing to reset */
+        sm_destroy(sm);
         return NULL;
     }
-    for (int64_t i = 0; i < sm->table_cap; i++)
-        sm->table[i] = -1;
-    /* pop order 0, 1, 2, ... — list(range(cap-1, -1, -1)).pop() parity */
-    for (int64_t i = 0; i < capacity; i++)
-        sm->free_slots[i] = (int32_t)(capacity - 1 - i);
-    sm->free_top = capacity;
+    for (int64_t s = 0; s < capacity; s++)
+        sm->ip_len[s] = -1;
+    sm_reset(sm);
     return sm;
 }
 
-void sm_destroy(void *h) {
-    sm_t *sm = h;
-    if (!sm)
-        return;
-    for (int64_t s = 0; s < sm->capacity; s++)
-        free(sm->ip[s]);
-    free(sm->ip);
-    free(sm->ip_len);
-    free(sm->tpos);
-    free(sm->free_slots);
-    free(sm->table);
-    free(sm);
-}
-
-void sm_clear(void *h) {
-    sm_t *sm = h;
-    for (int64_t s = 0; s < sm->capacity; s++) {
-        free(sm->ip[s]);
-        sm->ip[s] = NULL;
-    }
-    sm->assigned = 0;
-    sm->tombs = 0;
-    for (int64_t i = 0; i < sm->table_cap; i++)
-        sm->table[i] = -1;
-    for (int64_t i = 0; i < sm->capacity; i++)
-        sm->free_slots[i] = (int32_t)(sm->capacity - 1 - i);
-    sm->free_top = sm->capacity;
-}
+void sm_clear(void *h) { sm_reset(h); }
 
 int64_t sm_assigned(void *h) { return ((sm_t *)h)->assigned; }
 
 int64_t sm_free_count(void *h) { return ((sm_t *)h)->free_top; }
 
+/* slots the victims' walks have read since the manager was made
+ * (banjax_slot_eviction_scanned_slots_total) */
+int64_t sm_scanned(void *h) { return ((sm_t *)h)->scanned; }
+
 /* Extend to new_capacity.  New slots land at the BOTTOM of the free
  * stack (popped last, ascending) — matching the Python _grow_locked
- * free-list splice.  Returns 0 ok, -1 on allocation failure (manager
- * left at the old capacity, still consistent). */
+ * free-list splice; the kept order is untouched.  Returns 0 ok, -1 on
+ * allocation failure (manager left at the old capacity, still
+ * consistent). */
 int64_t sm_grow(void *h, int64_t new_capacity) {
     sm_t *sm = h;
     int64_t add = new_capacity - sm->capacity;
     if (add <= 0)
         return 0;
-    uint8_t **ip = realloc(sm->ip, sizeof(uint8_t *) * (size_t)new_capacity);
-    if (!ip)
-        return -1;
-    sm->ip = ip;
-    int32_t *il = realloc(sm->ip_len, sizeof(int32_t) * (size_t)new_capacity);
-    if (!il)
-        return -1;
-    sm->ip_len = il;
-    int64_t *tp = realloc(sm->tpos, sizeof(int64_t) * (size_t)new_capacity);
-    if (!tp)
-        return -1;
-    sm->tpos = tp;
-    int32_t *fs =
-        realloc(sm->free_slots, sizeof(int32_t) * (size_t)new_capacity);
-    if (!fs)
-        return -1;
-    sm->free_slots = fs;
-    memset(sm->ip + sm->capacity, 0, sizeof(uint8_t *) * (size_t)add);
+    size_t n = (size_t)new_capacity;
+#define SM_GROW(field, bytes)                                                 \
+    do {                                                                      \
+        void *p_ = realloc(sm->field, (bytes));                               \
+        if (!p_)                                                              \
+            return -1;                                                        \
+        sm->field = p_;                                                       \
+    } while (0)
+    SM_GROW(slab, n * SM_KEY_STRIDE);
+    if (sm->long_key) {
+        SM_GROW(long_key, sizeof(uint8_t *) * n);
+        memset(sm->long_key + sm->capacity, 0, sizeof(uint8_t *) * (size_t)add);
+    }
+    SM_GROW(ip_len, sizeof(int32_t) * n);
+    SM_GROW(tpos, sizeof(int64_t) * n);
+    SM_GROW(free_slots, sizeof(int32_t) * n);
+    SM_GROW(prev, sizeof(int32_t) * n);
+    SM_GROW(next, sizeof(int32_t) * n);
+    SM_GROW(run, sizeof(int32_t) * n);
+#undef SM_GROW
+    for (int64_t s = sm->capacity; s < new_capacity; s++)
+        sm->ip_len[s] = -1;
     memmove(sm->free_slots + add, sm->free_slots,
             sizeof(int32_t) * (size_t)sm->free_top);
     for (int64_t i = 0; i < add; i++)
@@ -223,6 +296,103 @@ int64_t sm_grow(void *h, int64_t new_capacity) {
     return 0;
 }
 
+/* ---- the kept eviction order ---- */
+
+static inline void order_unlink(sm_t *sm, int32_t s) {
+    int32_t p = sm->prev[s], n = sm->next[s];
+    if (p >= 0)
+        sm->next[p] = n;
+    else
+        sm->head = n;
+    if (n >= 0)
+        sm->prev[n] = p;
+    else
+        sm->tail = p;
+}
+
+/* link s behind `at` (-1: in front of everything) */
+static inline void order_link_after(sm_t *sm, int32_t s, int32_t at) {
+    int32_t n = at >= 0 ? sm->next[at] : sm->head;
+    sm->prev[s] = at;
+    sm->next[s] = n;
+    if (at >= 0)
+        sm->next[at] = s;
+    else
+        sm->head = s;
+    if (n >= 0)
+        sm->prev[n] = s;
+    else
+        sm->tail = s;
+}
+
+/* Stamp an unlinked slot with seq and give it its place.  The passes'
+ * sequence numbers only advance, so that is the tail; a number that goes
+ * back (no caller of the product's has one) is walked to its place in
+ * the full (last_used, slot) order, which keeps a sorted run sorted. */
+static inline void order_stamp(sm_t *sm, int32_t s, int64_t seq,
+                               int64_t *last_used) {
+    int32_t at = sm->tail;
+    last_used[s] = seq;
+    if (at >= 0 && (seq < last_used[at] || seq <= sm->sorted_lu))
+        while (at >= 0 && (last_used[at] > seq ||
+                           (last_used[at] == seq && at > s)))
+            at = sm->prev[at];
+    order_link_after(sm, s, at);
+}
+
+static int slot_cmp(const void *a, const void *b) {
+    int32_t x = *(const int32_t *)a, y = *(const int32_t *)b;
+    return (x > y) - (x < y);
+}
+
+/* Put the run that starts at `first` (the slots of its last_used) in
+ * slot order, where it lies.  Returns the run's new first slot. */
+static int32_t order_sort_run(sm_t *sm, int32_t first,
+                              const int64_t *last_used) {
+    int64_t lu = last_used[first], k = 0;
+    int32_t before = sm->prev[first], s = first;
+    while (s >= 0 && last_used[s] == lu) {
+        sm->run[k++] = s;
+        s = sm->next[s];
+    }
+    int32_t after = s;
+    sm->scanned += k;
+    sm->sorted_lu = lu;
+    if (k > 1)
+        qsort(sm->run, (size_t)k, sizeof(int32_t), slot_cmp);
+    int32_t at = before;
+    for (int64_t i = 0; i < k; i++) {
+        s = sm->run[i];
+        sm->prev[s] = at;
+        if (at >= 0)
+            sm->next[at] = s;
+        else
+            sm->head = s;
+        at = s;
+    }
+    sm->next[at] = after;
+    if (after >= 0)
+        sm->prev[after] = at;
+    else
+        sm->tail = at;
+    return sm->run[0];
+}
+
+/* The assigned slots in the kept order, oldest first; returns how many.
+ * With last_used every run is sorted on the way, so out is the full
+ * (last_used, slot) order of the table (tests compare it with the
+ * selection below); without, the order is read as it lies. */
+int64_t sm_order(void *h, const int64_t *last_used, int32_t *out) {
+    sm_t *sm = h;
+    int64_t n = 0;
+    for (int32_t s = sm->head; s >= 0; s = sm->next[s]) {
+        if (last_used && last_used[s] > sm->sorted_lu)
+            s = order_sort_run(sm, s, last_used);
+        out[n++] = s;
+    }
+    return n;
+}
+
 /* Pass 1: resolve every ip.  Hits get their slot in slots_out and their
  * recency stamped seq (the Python loop's vectorized hit touch); misses
  * get slots_out = -1 and their index appended to miss_idx_out.  Returns
@@ -232,30 +402,15 @@ int64_t sm_lookup_batch(void *h, const uint8_t *blob, const int64_t *offs,
                         int64_t *last_used, int32_t *slots_out,
                         int64_t *miss_idx_out) {
     sm_t *sm = h;
-    uint64_t mask = (uint64_t)sm->table_cap - 1;
     int64_t n_miss = 0;
     for (int64_t i = 0; i < n; i++) {
-        const uint8_t *p = blob + offs[i];
-        int64_t len = lens[i];
-        uint64_t s = sm_hash(p, len) & mask;
-        int64_t slot = -1;
-        for (;;) {
-            int64_t v = sm->table[s];
-            if (v == -1)
-                break;
-            if (v >= 0 && sm->ip_len[v] == (int32_t)len &&
-                memcmp(sm->ip[v], p, (size_t)len) == 0) {
-                slot = v;
-                break;
-            }
-            s = (s + 1) & mask;
-        }
-        if (slot >= 0) {
-            slots_out[i] = (int32_t)slot;
-            last_used[slot] = seq;
-        } else {
-            slots_out[i] = -1;
+        int64_t slot = sm_find(sm, blob + offs[i], lens[i]);
+        slots_out[i] = (int32_t)slot;
+        if (slot < 0) {
             miss_idx_out[n_miss++] = i;
+        } else if (last_used[slot] != seq) {
+            order_unlink(sm, (int32_t)slot);
+            order_stamp(sm, (int32_t)slot, seq, last_used);
         }
     }
     return n_miss;
@@ -270,26 +425,175 @@ int64_t sm_lookup_batch(void *h, const uint8_t *blob, const int64_t *offs,
 void sm_find_batch(void *h, const uint8_t *blob, const int64_t *offs,
                    const int64_t *lens, int64_t n, int32_t *slots_out) {
     sm_t *sm = h;
-    uint64_t mask = (uint64_t)sm->table_cap - 1;
+    for (int64_t i = 0; i < n; i++)
+        slots_out[i] = (int32_t)sm_find(sm, blob + offs[i], lens[i]);
+}
+
+/* The addresses of `slots`: lens_out[i] = the key's byte count, -1 for
+ * an unassigned (or out of range) slot, and the keys one after the other
+ * in buf while they fit cap.  Returns the bytes all of them take — a
+ * caller whose buf was too small asks again with that much.
+ * Introspection's route from a slot to its address; no window's. */
+int64_t sm_keys_of(void *h, const int32_t *slots, int64_t n,
+                   int32_t *lens_out, uint8_t *buf, int64_t cap) {
+    sm_t *sm = h;
+    int64_t total = 0;
     for (int64_t i = 0; i < n; i++) {
-        const uint8_t *p = blob + offs[i];
+        int64_t s = slots[i];
+        int32_t len = s >= 0 && s < sm->capacity ? sm->ip_len[s] : -1;
+        lens_out[i] = len;
+        if (len <= 0)
+            continue;
+        if (total + len <= cap)
+            memcpy(buf + total, sm_key(sm, s), (size_t)len);
+        total += len;
+    }
+    return total;
+}
+
+/* The last placement's victims whose key evict_keys cut: how many, and
+ * the j-th one's place among the victims, length and bytes (valid until
+ * the next placement, clear or destroy). */
+int64_t sm_dead_count(void *h) { return ((sm_t *)h)->n_dead; }
+
+/* ... and which victim's key was the empty address (evict_keys holds
+ * the warm tier's one NUL byte for it), -1 when none's */
+int64_t sm_empty_victim(void *h) { return ((sm_t *)h)->empty_victim; }
+
+const uint8_t *sm_dead_key(void *h, int64_t j, int64_t *k_out,
+                           int32_t *len_out) {
+    sm_dead *d = &((sm_t *)h)->dead[j];
+    *k_out = d->k;
+    *len_out = d->len;
+    return d->p;
+}
+
+/* Pass 2: place every miss, in ip order.  Free slots pop first; at
+ * capacity the minimum-(last_used, slot) assigned, unpinned, untouched
+ * slot is evicted (evict_out records them in order, and where
+ * evict_keys is given, victim k's address bytes go to evict_keys[k *
+ * SM_EVICT_KEY_STRIDE ...], cut at the stride — the warm tier's key
+ * length — with their count in evict_key_lens[k]; the empty address is
+ * one NUL byte, the tier's key for it: the spill of a victim's window
+ * record needs the key and nothing else of the string; a key that was
+ * cut stays whole behind sm_dead_key).  out_counts[0] = evictions
+ * performed, out_counts[1] = misses successfully placed.
+ * Returns 0, or -1 when an eviction was needed and every candidate is
+ * pinned/touched (earlier misses stay placed and MUST be bookkept by
+ * the caller — the Python refusal's partial-state semantics).
+ *
+ * The victims are the full (last_used, slot) order's, read off the kept
+ * order from its head: a run is sorted by slot when the walk reaches it
+ * (once — it only loses members after), a pinned slot is passed and
+ * stays where it is, and the first slot this batch touched ends the
+ * walk, since everything behind it was touched too: a refusal means no
+ * evictable slot is left, as with a sort of the whole table.  The work
+ * follows the misses, not the table: no allocation, no scan. */
+int64_t sm_place_misses(void *h, const uint8_t *blob, const int64_t *offs,
+                        const int64_t *lens, int64_t seq,
+                        const int32_t *pin_counts, int64_t *last_used,
+                        int32_t *slots_out, const int64_t *miss_idx,
+                        int64_t n_miss, int64_t *evict_out,
+                        int64_t *out_counts, uint8_t *evict_keys,
+                        int32_t *evict_key_lens) {
+    sm_t *sm = h;
+    int64_t n_evict = 0, placed = 0, rc = 0;
+    int32_t cur = sm->head; /* the walk's next slot */
+    sm_drop_dead(sm);
+    for (int64_t m = 0; m < n_miss; m++) {
+        int64_t i = miss_idx[m];
         int64_t len = lens[i];
-        uint64_t s = sm_hash(p, len) & mask;
-        int32_t slot = -1;
-        for (;;) {
-            int64_t v = sm->table[s];
-            if (v == -1)
-                break;
-            if (v >= 0 && sm->ip_len[v] == (int32_t)len &&
-                memcmp(sm->ip[v], p, (size_t)len) == 0) {
-                slot = (int32_t)v;
+        uint8_t *own = NULL;
+        if (len > SM_KEY_STRIDE) {
+            if (!sm->long_key)
+                sm->long_key = calloc((size_t)sm->capacity, sizeof(uint8_t *));
+            own = sm->long_key ? malloc((size_t)len) : NULL;
+            if (!own) {
+                rc = -1; /* nothing taken: the caller retries smaller */
                 break;
             }
-            s = (s + 1) & mask;
         }
+        int32_t slot = -1;
+        if (sm->free_top > 0) {
+            slot = sm->free_slots[--sm->free_top];
+        } else {
+            while (cur >= 0 && last_used[cur] < seq) {
+                if (last_used[cur] > sm->sorted_lu)
+                    cur = order_sort_run(sm, cur, last_used);
+                int32_t s = cur;
+                cur = sm->next[s];
+                sm->scanned++;
+                if (pin_counts[s] == 0) {
+                    slot = s;
+                    break;
+                }
+            }
+            if (slot < 0) {
+                free(own);
+                rc = -1;
+                break;
+            }
+            int32_t kl = sm->ip_len[slot];
+            if (kl > SM_EVICT_KEY_STRIDE) {
+                if (sm->n_dead == sm->dead_cap) {
+                    int64_t cap = sm->dead_cap ? 2 * sm->dead_cap : 8;
+                    sm_dead *d = realloc(sm->dead, sizeof(sm_dead) * (size_t)cap);
+                    if (d) {
+                        sm->dead = d;
+                        sm->dead_cap = cap;
+                    }
+                }
+                if (sm->n_dead < sm->dead_cap) {
+                    sm_dead *d = &sm->dead[sm->n_dead++];
+                    d->k = n_evict;
+                    d->p = sm->long_key[slot];
+                    d->len = kl;
+                } else {
+                    free(sm->long_key[slot]);
+                }
+                if (evict_keys)
+                    memcpy(evict_keys + n_evict * SM_EVICT_KEY_STRIDE,
+                           sm->long_key[slot], SM_EVICT_KEY_STRIDE);
+                sm->long_key[slot] = NULL;
+                kl = SM_EVICT_KEY_STRIDE;
+            } else if (evict_keys) {
+                uint8_t *dst = evict_keys + n_evict * SM_EVICT_KEY_STRIDE;
+                memcpy(dst, sm_key(sm, slot), (size_t)kl);
+                if (kl == 0) {
+                    dst[kl++] = 0;
+                    sm->empty_victim = n_evict;
+                }
+            }
+            if (evict_keys)
+                evict_key_lens[n_evict] = kl;
+            order_unlink(sm, slot);
+            sm->table[sm->tpos[slot]] = -2;
+            sm->tombs++;
+            sm->assigned--;
+            evict_out[n_evict++] = slot;
+        }
+        if (own) {
+            memcpy(own, blob + offs[i], (size_t)len);
+            sm->long_key[slot] = own;
+        } else {
+            memcpy(sm->slab + (int64_t)slot * SM_KEY_STRIDE, blob + offs[i],
+                   (size_t)len);
+        }
+        sm->ip_len[slot] = (int32_t)len;
+        if ((sm->assigned + sm->tombs) * 2 > sm->table_cap)
+            sm_table_rebuild(sm, sm->capacity);
+        sm_table_insert(sm, slot);
+        sm->assigned++;
+        order_stamp(sm, slot, seq, last_used);
         slots_out[i] = slot;
+        placed++;
     }
+    out_counts[0] = n_evict;
+    out_counts[1] = placed;
+    return rc;
 }
+
+/* ---- the selection the kept order is held to (tests only) ---- */
 
 typedef struct {
     int64_t lu;
@@ -356,124 +660,6 @@ static int64_t cand_extend(sm_cand *c, int64_t n, int64_t sorted,
     return sorted + want;
 }
 
-/* Pass 2: place every miss, in ip order.  Free slots pop first; at
- * capacity the minimum-(last_used, slot) assigned, unpinned, untouched
- * slot is evicted (evict_out records them in order, and where
- * evict_keys is given, victim k's address bytes go to evict_keys[k *
- * SM_EVICT_KEY_STRIDE ...], cut at the stride — the warm tier's key
- * length — with their count in evict_key_lens[k]; the empty address is
- * one NUL byte, the tier's key for it: the spill of a victim's window
- * record needs the key and nothing else of the string).  out_counts[0] =
- * evictions performed, out_counts[1] = misses successfully placed.
- * Returns 0, or -1 when an eviction was needed and every candidate is
- * pinned/touched (earlier misses stay placed and MUST be bookkept by
- * the caller — the Python refusal's partial-state semantics).
- *
- * The victims are the full (last_used, slot) order's, taken by
- * selection: the evictable slots are collected once, and only as many
- * of the oldest as there are misses still to place get sorted — the
- * work follows the misses, not the table.  Should re-validation skip a
- * candidate, the next oldest are selected from the rest, down to the
- * last evictable slot: a refusal means none is left, as with the full
- * sort. */
-int64_t sm_place_misses(void *h, const uint8_t *blob, const int64_t *offs,
-                        const int64_t *lens, int64_t seq,
-                        const int32_t *pin_counts, int64_t *last_used,
-                        int32_t *slots_out, const int64_t *miss_idx,
-                        int64_t n_miss, int64_t *evict_out,
-                        int64_t *out_counts, uint8_t *evict_keys,
-                        int32_t *evict_key_lens) {
-    sm_t *sm = h;
-    sm_cand *cand = NULL;
-    int64_t cand_n = 0, cand_i = 0, cand_sorted = 0, n_evict = 0, placed = 0;
-    int64_t rc = 0;
-    for (int64_t m = 0; m < n_miss; m++) {
-        int64_t i = miss_idx[m];
-        int32_t slot;
-        if (sm->free_top > 0) {
-            slot = sm->free_slots[--sm->free_top];
-        } else {
-            if (!cand) {
-                cand = malloc(sizeof(sm_cand) * (size_t)sm->capacity);
-                if (!cand) {
-                    rc = -1;
-                    break;
-                }
-                for (int64_t s2 = 0; s2 < sm->capacity; s2++) {
-                    if (sm->ip[s2] && pin_counts[s2] == 0 &&
-                        last_used[s2] < seq) {
-                        cand[cand_n].lu = last_used[s2];
-                        cand[cand_n].slot = (int32_t)s2;
-                        cand_n++;
-                    }
-                }
-            }
-            slot = -1;
-            for (;;) {
-                if (cand_i == cand_sorted) {
-                    cand_sorted =
-                        cand_extend(cand, cand_n, cand_sorted, n_miss - m);
-                    if (cand_i == cand_sorted)
-                        break; /* no evictable slot left */
-                }
-                sm_cand c = cand[cand_i++];
-                /* re-validate: the slot may have been consumed by an
-                 * earlier eviction or touched by an earlier placement */
-                if (!sm->ip[c.slot] || pin_counts[c.slot] != 0 ||
-                    last_used[c.slot] >= seq || last_used[c.slot] != c.lu)
-                    continue;
-                slot = c.slot;
-                break;
-            }
-            if (slot < 0) {
-                rc = -1;
-                break;
-            }
-            if (evict_keys) {
-                int32_t kl = sm->ip_len[slot];
-                if (kl > SM_EVICT_KEY_STRIDE)
-                    kl = SM_EVICT_KEY_STRIDE;
-                uint8_t *dst = evict_keys + n_evict * SM_EVICT_KEY_STRIDE;
-                memcpy(dst, sm->ip[slot], (size_t)kl);
-                if (kl == 0)
-                    dst[kl++] = 0;
-                evict_key_lens[n_evict] = kl;
-            }
-            free(sm->ip[slot]);
-            sm->ip[slot] = NULL;
-            sm->table[sm->tpos[slot]] = -2;
-            sm->tombs++;
-            sm->assigned--;
-            evict_out[n_evict++] = slot;
-        }
-        const uint8_t *p = blob + offs[i];
-        int64_t len = lens[i];
-        uint8_t *cp = malloc(len > 0 ? (size_t)len : 1);
-        if (!cp) {
-            /* undo nothing: the slot simply stays free/evicted; report
-             * refusal so the caller retries smaller */
-            if (sm->free_top < sm->capacity && sm->ip[slot] == NULL)
-                sm->free_slots[sm->free_top++] = slot;
-            rc = -1;
-            break;
-        }
-        memcpy(cp, p, (size_t)len);
-        sm->ip[slot] = cp;
-        sm->ip_len[slot] = (int32_t)len;
-        if ((sm->assigned + sm->tombs) * 2 > sm->table_cap)
-            sm_table_rebuild(sm, sm->capacity);
-        sm_table_insert(sm, slot);
-        sm->assigned++;
-        last_used[slot] = seq;
-        slots_out[i] = slot;
-        placed++;
-    }
-    free(cand);
-    out_counts[0] = n_evict;
-    out_counts[1] = placed;
-    return rc;
-}
-
 /* Test hook: the full (last_used, slot) order of n candidates, built
  * `chunk` at a time by the selection above — what placement does when it
  * has to go on past its first selection.  Writes the slots in order. */
@@ -494,28 +680,97 @@ void sm_test_select_order(const int64_t *lu, const int32_t *slot, int64_t n,
     free(c);
 }
 
+static uint32_t crc_table[256];
+static int crc_ready; /* benign race: every thread writes the same values */
+
+static void crc_init(void) {
+    if (__atomic_load_n(&crc_ready, __ATOMIC_ACQUIRE))
+        return;
+    for (uint32_t i = 0; i < 256; i++) {
+        uint32_t c = i;
+        for (int k = 0; k < 8; k++)
+            c = (c & 1) ? 0xEDB88320u ^ (c >> 1) : c >> 1;
+        crc_table[i] = c;
+    }
+    __atomic_store_n(&crc_ready, 1, __ATOMIC_RELEASE);
+}
+
+static inline uint32_t crc32_of(const uint8_t *p, int64_t len) {
+    uint32_t c = 0xFFFFFFFFu;
+    for (int64_t k = 0; k < len; k++)
+        c = crc_table[(c ^ p[k]) & 0xFF] ^ (c >> 8);
+    return c ^ 0xFFFFFFFFu;
+}
+
 /* zlib's CRC-32 of each span (the traffic sketch's base hash of a client
  * address, obs/sketch.py hash_ip): the spans are the one encoding of a
  * batch's distinct addresses, so the sketch hashes what the slot table
  * and the warm tier are asked about, with no second walk in Python. */
 void sm_crc32_batch(const uint8_t *blob, const int64_t *offs,
                     const int64_t *lens, int64_t n, uint32_t *out) {
-    static uint32_t table[256];
-    static int ready; /* benign race: every thread writes the same values */
-    if (!__atomic_load_n(&ready, __ATOMIC_ACQUIRE)) {
-        for (uint32_t i = 0; i < 256; i++) {
-            uint32_t c = i;
-            for (int k = 0; k < 8; k++)
-                c = (c & 1) ? 0xEDB88320u ^ (c >> 1) : c >> 1;
-            table[i] = c;
+    crc_init();
+    for (int64_t i = 0; i < n; i++)
+        out[i] = crc32_of(blob + offs[i], lens[i]);
+}
+
+/* One batch's distinct addresses from its shards' tables, by bytes.
+ * Part p has a table of table_n[p] distinct keys — spans (offs[p],
+ * lens[p]) of bufs[p] — and rows_n[p] rows, row r holding entry
+ * row_inv[p][r].  The merged table takes the keys in first-appearance
+ * order as the string merge has it (workset.CompositeWork.unique_ips):
+ * part after part, a part's entries in its table's order, those no row
+ * of the part holds left out.  Out: the merged keys back to back in
+ * out_buf (one NUL behind the last: the warm tier's key for the empty
+ * address), their spans and zlib CRC-32s (which the sketch folds under
+ * and the merge probes by), and out_inv, every row's merged index, part
+ * after part.  Scratch: table, table_cap a power of two >= twice the
+ * entries of all parts; map, as many int64 as the largest part's.
+ * Returns the merged count. */
+int64_t sm_merge_spans(int64_t n_parts, const uint8_t *const *bufs,
+                       const int64_t *const *offs, const int64_t *const *lens,
+                       const int64_t *table_n, const int64_t *const *row_inv,
+                       const int64_t *rows_n, int64_t *table,
+                       int64_t table_cap, int64_t *map, uint8_t *out_buf,
+                       int64_t *out_offs, int64_t *out_lens,
+                       uint32_t *out_hash, int64_t *out_inv) {
+    crc_init();
+    for (int64_t i = 0; i < table_cap; i++)
+        table[i] = -1;
+    uint64_t mask = (uint64_t)table_cap - 1;
+    int64_t n = 0, bytes = 0;
+    for (int64_t p = 0; p < n_parts; p++) {
+        const uint8_t *buf = bufs[p];
+        const int64_t *inv = row_inv[p];
+        for (int64_t j = 0; j < table_n[p]; j++)
+            map[j] = -1;
+        for (int64_t r = 0; r < rows_n[p]; r++)
+            map[inv[r]] = -2; /* some row holds it */
+        for (int64_t j = 0; j < table_n[p]; j++) {
+            if (map[j] != -2)
+                continue;
+            const uint8_t *key = buf + offs[p][j];
+            int64_t len = lens[p][j];
+            uint32_t hash = crc32_of(key, len);
+            uint64_t s = hash & mask;
+            int64_t id;
+            while ((id = table[s]) >= 0 &&
+                   !(out_hash[id] == hash && out_lens[id] == len &&
+                     memcmp(out_buf + out_offs[id], key, (size_t)len) == 0))
+                s = (s + 1) & mask;
+            if (id < 0) {
+                id = table[s] = n++;
+                memcpy(out_buf + bytes, key, (size_t)len);
+                out_offs[id] = bytes;
+                out_lens[id] = len;
+                out_hash[id] = hash;
+                bytes += len;
+            }
+            map[j] = id;
         }
-        __atomic_store_n(&ready, 1, __ATOMIC_RELEASE);
+        for (int64_t r = 0; r < rows_n[p]; r++)
+            out_inv[r] = map[inv[r]];
+        out_inv += rows_n[p];
     }
-    for (int64_t i = 0; i < n; i++) {
-        const uint8_t *p = blob + offs[i];
-        uint32_t c = 0xFFFFFFFFu;
-        for (int64_t k = 0; k < lens[i]; k++)
-            c = table[(c ^ p[k]) & 0xFF] ^ (c >> 8);
-        out[i] = c ^ 0xFFFFFFFFu;
-    }
+    out_buf[bytes] = 0;
+    return n;
 }

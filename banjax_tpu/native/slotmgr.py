@@ -35,8 +35,10 @@ _TRIED = False
 EVICT_KEY_STRIDE = 104
 
 _u8p = ctypes.POINTER(ctypes.c_uint8)
+_u32p = ctypes.POINTER(ctypes.c_uint32)
 _i32p = ctypes.POINTER(ctypes.c_int32)
 _i64p = ctypes.POINTER(ctypes.c_int64)
+_vpp = ctypes.POINTER(ctypes.c_void_p)
 
 
 _P = array_ptr
@@ -118,8 +120,29 @@ def _load() -> Optional[ctypes.CDLL]:
         ]
         lib.sm_crc32_batch.restype = None
         lib.sm_crc32_batch.argtypes = [
-            _u8p, _i64p, _i64p, ctypes.c_int64,
-            ctypes.POINTER(ctypes.c_uint32),
+            _u8p, _i64p, _i64p, ctypes.c_int64, _u32p,
+        ]
+        lib.sm_merge_spans.restype = ctypes.c_int64
+        lib.sm_merge_spans.argtypes = [
+            ctypes.c_int64, _vpp, _vpp, _vpp, _i64p, _vpp, _i64p,
+            _i64p, ctypes.c_int64, _i64p, _u8p, _i64p, _i64p, _u32p, _i64p,
+        ]
+        lib.sm_scanned.restype = ctypes.c_int64
+        lib.sm_scanned.argtypes = [ctypes.c_void_p]
+        lib.sm_order.restype = ctypes.c_int64
+        lib.sm_order.argtypes = [ctypes.c_void_p, _i64p, _i32p]
+        lib.sm_keys_of.restype = ctypes.c_int64
+        lib.sm_keys_of.argtypes = [
+            ctypes.c_void_p, _i32p, ctypes.c_int64, _i32p, _u8p,
+            ctypes.c_int64,
+        ]
+        lib.sm_dead_count.restype = ctypes.c_int64
+        lib.sm_dead_count.argtypes = [ctypes.c_void_p]
+        lib.sm_empty_victim.restype = ctypes.c_int64
+        lib.sm_empty_victim.argtypes = [ctypes.c_void_p]
+        lib.sm_dead_key.restype = ctypes.c_void_p
+        lib.sm_dead_key.argtypes = [
+            ctypes.c_void_p, ctypes.c_int64, _i64p, _i32p,
         ]
         lib.sm_test_select_order.restype = None
         lib.sm_test_select_order.argtypes = [
@@ -156,6 +179,112 @@ def encode_ips(
     return np.frombuffer(blob + b"\x00", dtype=np.uint8), offs, lens
 
 
+def _decode(raw: bytes) -> str:
+    return raw.decode("utf-8", "surrogatepass")
+
+
+def decode_spans(enc) -> list:
+    """The strings `encode_ips` would have made the spans from."""
+    buf, offs, lens = enc
+    raw = buf.tobytes()
+    spans = zip(offs.tolist(), lens.tolist())
+    if raw.isascii():  # byte offsets are character offsets: plain slices
+        text = raw.decode("ascii")
+        return [text[o : o + n] for o, n in spans]
+    return [_decode(raw[o : o + n]) for o, n in spans]
+
+
+class AddressSpans:
+    """A batch's DISTINCT addresses as byte spans — `enc` = (buf, offs,
+    lens) as `encode_ips` lays them out, `hashes` their zlib CRC-32s —
+    wearing the string list's interface: whatever reads a string of it
+    (the refused path, a dict shadow, the sketch's candidate walk) has
+    them made then, all at once and once; the pass itself hands the
+    arrays to the slot table, the warm tier and the sketch as they are."""
+
+    __slots__ = ("enc", "hashes", "_strings")
+
+    def __init__(self, enc, hashes):
+        self.enc = enc
+        self.hashes = hashes
+        self._strings = None
+
+    def strings(self) -> list:
+        if self._strings is None:
+            self._strings = decode_spans(self.enc)
+        return self._strings
+
+    def __len__(self) -> int:
+        return len(self.enc[1])
+
+    def __getitem__(self, i):
+        return self.strings()[i]
+
+    def __iter__(self):
+        return iter(self.strings())
+
+    def __reversed__(self):
+        return reversed(self.strings())
+
+
+def merge_spans(parts) -> Optional[tuple]:
+    """(AddressSpans, per-row inverse int64) of one batch from its
+    shards: `parts` = [(enc of the shard's distinct-address table, the
+    shard's rows -> that table), ...] in shard order.  One C dedup by
+    bytes, in the string merge's first-appearance order (shard order,
+    then the shard table's own; an entry no row holds is left out) —
+    slotmgr.c sm_merge_spans.  None when the native library is
+    unavailable."""
+    lib = _load()
+    if lib is None:
+        return None
+    k = len(parts)
+    encs = [
+        (np.ascontiguousarray(buf, dtype=np.uint8),
+         np.ascontiguousarray(offs, dtype=np.int64),
+         np.ascontiguousarray(lens, dtype=np.int64))
+        for (buf, offs, lens), _ in parts
+    ]
+    invs = [np.ascontiguousarray(inv, dtype=np.int64) for _, inv in parts]
+    for (buf, offs, lens), inv in zip(encs, invs):
+        # the C side reads what these say: hold them to their arrays
+        if len(offs) != len(lens) or (len(inv) and not (
+                0 <= int(inv.min()) and int(inv.max()) < len(offs))):
+            raise ValueError("merge_spans: a row outside its shard's table")
+        if len(offs) and not (
+                0 <= int(offs.min()) and 0 <= int(lens.min())
+                and int((offs + lens).max()) <= len(buf)):
+            raise ValueError("merge_spans: a span outside its shard's blob")
+    table_n = np.fromiter((len(enc[1]) for enc in encs), np.int64, k)
+    rows_n = np.fromiter(map(len, invs), np.int64, k)
+    total = int(table_n.sum())
+    n_bytes = sum(int(enc[2].sum()) for enc in encs)
+    table_cap = 64
+    while table_cap < 2 * total:
+        table_cap <<= 1
+    table = np.empty(table_cap, dtype=np.int64)
+    scratch = np.empty(max(int(table_n.max(initial=0)), 1), dtype=np.int64)
+    buf = np.empty(n_bytes + 1, dtype=np.uint8)
+    offs = np.empty(total, dtype=np.int64)
+    lens = np.empty(total, dtype=np.int64)
+    hashes = np.empty(total, dtype=np.uint32)
+    inverse = np.empty(int(rows_n.sum()), dtype=np.int64)
+
+    def ptrs(arrays):
+        return (ctypes.c_void_p * k)(*[a.ctypes.data for a in arrays])
+
+    n = int(lib.sm_merge_spans(
+        k, ptrs([enc[0] for enc in encs]), ptrs([enc[1] for enc in encs]),
+        ptrs([enc[2] for enc in encs]), _P(table_n, _i64p), ptrs(invs),
+        _P(rows_n, _i64p), _P(table, _i64p), table_cap, _P(scratch, _i64p),
+        _P(buf, _u8p), _P(offs, _i64p), _P(lens, _i64p), _P(hashes, _u32p),
+        _P(inverse, _i64p),
+    ))
+    # the blob ends with its NUL (a table entry no row holds left room)
+    used = int(offs[n - 1] + lens[n - 1]) + 1 if n else 1
+    return AddressSpans((buf[:used], offs[:n], lens[:n]), hashes[:n]), inverse
+
+
 def crc32_spans(enc) -> Optional[np.ndarray]:
     """uint32 [n] zlib CRC-32 of every span of an `encode_ips` result —
     obs/sketch.py's `hash_ip` of every address, in one C call.  None
@@ -168,7 +297,7 @@ def crc32_spans(enc) -> Optional[np.ndarray]:
     if len(offs):
         lib.sm_crc32_batch(
             _P(buf, _u8p), _P(offs, _i64p), _P(lens, _i64p), len(offs),
-            array_ptr(out, ctypes.POINTER(ctypes.c_uint32)),
+            _P(out, _u32p),
         )
     return out
 
@@ -214,6 +343,57 @@ class SlotManager:
 
     def free_count(self) -> int:
         return int(self._lib.sm_free_count(self._h))
+
+    def scanned(self) -> int:
+        """Slots the placements' victim walks have read, the members of
+        every run they sorted included."""
+        return int(self._lib.sm_scanned(self._h))
+
+    def order(self, last_used: Optional[np.ndarray] = None) -> np.ndarray:
+        """int32: the assigned slots in the kept eviction order, oldest
+        first.  With `last_used` every run is put in slot order on the
+        way: the table's full (last_used, slot) order."""
+        out = np.empty(self.assigned(), dtype=np.int32)
+        n = self._lib.sm_order(
+            self._h, None if last_used is None else _P(last_used, _i64p),
+            _P(out, _i32p),
+        )
+        return out[:n]
+
+    def keys_of(self, slots) -> list:
+        """The address of each slot, None for one that is unassigned —
+        this table is the one owner of slot -> address."""
+        slots = np.ascontiguousarray(slots, dtype=np.int32)
+        n = len(slots)
+        lens = np.empty(n, dtype=np.int32)
+        buf = np.empty(max(n, 1) * 48, dtype=np.uint8)
+        for _ in range(2):
+            total = int(self._lib.sm_keys_of(
+                self._h, _P(slots, _i32p), n, _P(lens, _i32p), _P(buf, _u8p),
+                len(buf),
+            ))
+            if total <= len(buf):
+                break
+            buf = np.empty(total, dtype=np.uint8)
+        held = np.maximum(lens, 0).astype(np.int64)
+        offs = np.zeros(n, dtype=np.int64)
+        if n > 1:
+            np.cumsum(held[:-1], out=offs[1:])
+        return [
+            s if ln >= 0 else None for s, ln in zip(
+                decode_spans((buf[:total], offs, held)), lens.tolist())
+        ]
+
+    def evicted_long_keys(self) -> list:
+        """[(k, key bytes)]: the last placement's victims whose key
+        `place_misses`' evict_keys cut at its stride, whole."""
+        out = []
+        k, ln = ctypes.c_int64(), ctypes.c_int32()
+        for j in range(int(self._lib.sm_dead_count(self._h))):
+            p = self._lib.sm_dead_key(
+                self._h, j, ctypes.byref(k), ctypes.byref(ln))
+            out.append((k.value, ctypes.string_at(p, ln.value)))
+        return out
 
     def grow(self, new_capacity: int) -> None:
         if self._lib.sm_grow(self._h, new_capacity) != 0:
@@ -262,7 +442,8 @@ class SlotManager:
         evictions) before splitting the batch, as in the Python path.
         evict_keys = (uint8 [k * EVICT_KEY_STRIDE], int32 [k]): the
         victims' address bytes as the warm tier keys them, one stride a
-        victim, and their lengths."""
+        victim, and their lengths (`evicted_keys` makes strings of
+        them)."""
         n_miss = len(miss_idx)
         if n_miss == 0:
             none = np.empty(0, np.int64)
@@ -283,6 +464,22 @@ class SlotManager:
         k = int(counts[0])
         return (miss_idx[: int(counts[1])], evict[:k],
                 (keys, key_lens[:k]), rc == 0)
+
+    def evicted_keys(self, evict_keys) -> list:
+        """The victims' addresses as strings, from the last placement's
+        evict_keys (a key the stride cut is read whole)."""
+        keys, key_lens = evict_keys
+        k = len(key_lens)
+        if not k:  # no victim: the placement may not have run at all
+            return []
+        offs = np.arange(k, dtype=np.int64) * EVICT_KEY_STRIDE
+        out = decode_spans((keys, offs, key_lens.astype(np.int64)))
+        empty = int(self._lib.sm_empty_victim(self._h))
+        if empty >= 0:  # it travels as the warm tier's key for it, one NUL
+            out[empty] = ""
+        for j, raw in self.evicted_long_keys():
+            out[j] = _decode(raw)
+        return out
 
     def find_batch(self, ips: Sequence[str]) -> np.ndarray:
         """int32 [n]: the slot of every ip of a DISTINCT list, -1 where

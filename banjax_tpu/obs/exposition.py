@@ -246,6 +246,13 @@ def render_prometheus(
         fam = registry.PROM_FAMILIES["banjax_submit_resolve_probes_total"]
         for table, v in dw.resolve_probes.items():
             w.sample(fam, v, {"table": table})
+    if dw is not None and hasattr(dw, "resolve_passes"):
+        fam = registry.PROM_FAMILIES["banjax_submit_resolve_passes_total"]
+        for form, v in dw.resolve_passes.items():
+            w.sample(fam, v, {"form": form})
+        w.sample(
+            registry.PROM_FAMILIES["banjax_slot_eviction_scanned_slots_total"],
+            dw.eviction_scanned_slots)
 
     if dw is not None and hasattr(dw, "lock_waits"):
         wait_fam = registry.PROM_FAMILIES[

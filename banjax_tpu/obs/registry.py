@@ -203,6 +203,18 @@ FAMILIES: List[Family] = [
            "membership probe by the submit stage (per batch: distinct "
            "addresses, and misses not in the shadow, once each)",
            prom="banjax_submit_resolve_probes_total", labels=("table",)),
+    Family(COUNTER, "passes over a batch's distinct addresses (the submit "
+           "stage's resolve, the sync entry's slot call), by the form the "
+           "addresses came in and were worked on: spans (the parse blob's "
+           "bytes, merged by bytes; no string made of an address) or "
+           "strings (encoded for the pass; the dict path's pass)",
+           prom="banjax_submit_resolve_passes_total", labels=("form",)),
+    Family(COUNTER, "slots the native slot manager's placements read to "
+           "find their eviction victims, the members of every run of the "
+           "kept (last_used, slot) order they sorted included: about the "
+           "victims and one batch's leftover run a batch — a scan of the "
+           "table would read its capacity a batch",
+           prom="banjax_slot_eviction_scanned_slots_total"),
     Family(COUNTER, "batches whose slot-admission verdict needed no sketch "
            "estimate (threshold 1: a rule bans on the first hit; or no "
            "unseen address)",

@@ -5,6 +5,8 @@ at the queued device restores through `pending_restore_slots`."""
 
 from collections import OrderedDict
 
+import numpy as np
+
 
 def shadow(dw):
     """ip -> (rule_id -> (hits, start_s, start_ns)), format_states order."""
@@ -35,3 +37,19 @@ def pending_restore_slots(dw):
     if dw._mirror is None:
         return [slot for slot, _ in dw._pending_restore]
     return [int(s) for part in dw._pending_restore for s in part[0]]
+
+
+def spans_of(ips):
+    """The distinct addresses `ips` as the byte spans a native parse's
+    work set hands the pass (slotmgr.AddressSpans), through the merge
+    that makes them there — each address its own row of one shard."""
+    from banjax_tpu.native import slotmgr
+
+    spans, inverse = slotmgr.merge_spans(
+        [(slotmgr.encode_ips(ips), np.arange(len(ips), dtype=np.int64))]
+    )
+    assert inverse.tolist() == list(range(len(ips)))
+    return spans
+
+
+FORMS = {"strings": None, "spans": spans_of}

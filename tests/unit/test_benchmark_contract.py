@@ -962,6 +962,52 @@ def test_sketch_updates_family_is_on_metrics_and_its_reader_reads_it(
         "moves": "lines_per_s"}]
 
 
+def test_address_pass_families_are_on_metrics_and_their_readers_read_them(
+        longline_scrapes):
+    """`banjax_submit_resolve_passes_total{form}` and
+    `banjax_slot_eviction_scanned_slots_total` (ISSUE 45), off `/metrics`
+    with tracing off and through `resolve_spans_share` and
+    `eviction_scan_slots_per_kline`: each of the fixture's three batches
+    is parsed natively and resolved in one pass over byte spans — the two
+    that commit fused at submit, the classic one at its drain — and none
+    evicts, so no placement had a victim to look for."""
+    from benchmark.harness import found, prom
+
+    passes = "banjax_submit_resolve_passes_total"
+    scanned = "banjax_slot_eviction_scanned_slots_total"
+    assert {passes, scanned} <= {f.prom for f in registry.FAMILIES}
+    before, after = longline_scrapes
+    assert prom.value(before, passes, form="spans") == 0
+    assert prom.value(after, passes, form="spans") == 3
+    assert prom.value(after, passes, form="strings") == 0
+    assert prom.value(after, scanned) == 0
+    ctx = {"prom0": before, "prom1": after}
+    share = found.module("layers", "resolve_spans_share")
+    scan = found.module("layers", "eviction_scan_slots_per_kline")
+    assert share.read(ctx) == 100.0
+    assert scan.read(ctx) == 0.0
+    # two passes that took strings beside the three: the share says so
+    mixed = dict(after)
+    mixed[(passes, (("form", "strings"),))] = 2.0
+    mixed[(scanned, ())] = 450.0
+    assert share.read({"prom0": before, "prom1": mixed}) == 60.0
+    assert scan.read({"prom0": before, "prom1": mixed}) == 1500.0
+    for reader in (share, scan):
+        # a program without the counter (PR 45's parent), an idle window
+        assert reader.read({"prom0": {}, "prom1": {}}) is None
+        assert reader.read({"prom0": after, "prom1": after}) is None
+    entries = [m for m in found.benchmark_json()["per_layer"]
+               if m["name"] in ("resolve_spans_share",
+                                "eviction_scan_slots_per_kline")]
+    assert entries == [
+        {"name": "resolve_spans_share", "unit": "%", "better": "higher",
+         "source": "program_counter", "layer": "device windows and tiers",
+         "moves": "lines_per_s"},
+        {"name": "eviction_scan_slots_per_kline", "unit": "count/kline",
+         "better": "lower", "source": "program_counter",
+         "layer": "device windows and tiers", "moves": "lines_per_s"}]
+
+
 def test_long_match_readers_take_the_launches_over_the_short_width():
     """`long_match_us_per_kline` and `long_match_roofline` take the long
     launches to be the match-kernel launches whose padded line length

@@ -179,7 +179,10 @@ def test_the_cell_is_the_issues():
         "gc_pause_ms_per_kline",
         # which dispatch carried the traffic sketch's fold (ISSUE 44):
         # every cell
-        "sketch_fused_share"])
+        "sketch_fused_share",
+        # the address pass's form and the victims' walk (ISSUE 45): every
+        # cell
+        "resolve_spans_share", "eviction_scan_slots_per_kline"])
     pc = CONFIG["product_config"]
     assert {k: v for k, v in pc.items() if k != "config_version"} == {
         k: v for k, v in found.data("configs", "upstream-stress10k")[

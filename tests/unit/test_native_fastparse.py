@@ -203,14 +203,14 @@ def test_unique_spans_fallback_matches_native():
     def decode(k):
         return blob[int(offs[k]) : int(offs[k]) + int(lens[k])].decode()
 
-    s_fallback, inv_fallback = unique_spans(offs, lens, decode)  # no blob
+    s_fallback, inv_fallback, _ = unique_spans(offs, lens, decode)  # no blob
     assert s_fallback == ["zz", "one", "two", "three"]
     assert inv_fallback.tolist() == [0, 1, 2, 1, 3, 2, 0, 1]
 
     from banjax_tpu import native
 
     if native.available():
-        s_nat, inv_nat = unique_spans(
+        s_nat, inv_nat, _ = unique_spans(
             offs, lens, decode, blob=blob, text=blob.decode()
         )
         assert s_nat == s_fallback

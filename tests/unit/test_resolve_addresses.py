@@ -23,9 +23,10 @@ from banjax_tpu.matcher.windows import DeviceWindows
 from banjax_tpu.native import shm, slotmgr
 from banjax_tpu.obs.sketch import TrafficSketch, hash_ip
 from tests.shadow_access import plant, shadow
-from tests.unit.test_slotmgr import (
+from tests.unit.test_slotmgr import (  # noqa: F401 — `form` is a fixture
     assert_same_state,
     assert_same_warm_state,
+    form,
     ip_of,
     lockstep,
     make_pair,
@@ -61,7 +62,7 @@ def test_selection_in_chunks_is_the_full_sort(n, chunk, seed):
 @pytest.mark.parametrize("capacity,seed,pin_share", [
     (256, 21, 0.0), (256, 22, 0.3), (512, 23, 0.6), (128, 24, 0.9),
 ])
-def test_parity_fuzz_selection_under_pins(capacity, seed, pin_share):
+def test_parity_fuzz_selection_under_pins(capacity, seed, pin_share, form):
     """Native against the dict path at capacities where the placement
     selects (evictable slots ≫ misses), with batches held in flight so
     that pins and this batch's own touches thin the candidates — down to
@@ -73,7 +74,7 @@ def test_parity_fuzz_selection_under_pins(capacity, seed, pin_share):
     refusals = 0
     for step in range(120):
         k = rng.randrange(1, capacity // 2)
-        s = lockstep(nat, ora, rng.sample(pool, k), f"step {step}")
+        s = lockstep(nat, ora, rng.sample(pool, k), f"step {step}", form=form)
         if s is None:
             refusals += 1
         elif rng.random() < pin_share:
@@ -94,8 +95,8 @@ def test_parity_fuzz_selection_under_pins(capacity, seed, pin_share):
 # ------------------------------------------------------------- edge shapes
 
 
-def _fill(nat, ora, n):
-    s = lockstep(nat, ora, [ip_of(i) for i in range(n)], "fill")
+def _fill(nat, ora, n, form=None):
+    s = lockstep(nat, ora, [ip_of(i) for i in range(n)], "fill", form=form)
     nat.release_pins(s), ora.release_pins(s)
 
 
@@ -103,9 +104,9 @@ def _fill(nat, ora, n):
     "one-address", "all-hits", "all-misses", "empty",
     "misses-over-free-and-evictable",
 ])
-def test_edge_shapes_match_the_dict_path(shape):
+def test_edge_shapes_match_the_dict_path(shape, form):
     nat, ora = make_pair(8)
-    _fill(nat, ora, 8)
+    _fill(nat, ora, 8, form)
     if shape == "one-address":
         batches = [[ip_of(3)], [ip_of(100)]]
     elif shape == "all-hits":
@@ -118,23 +119,24 @@ def test_edge_shapes_match_the_dict_path(shape):
         # five slots held by a batch in flight: three are evictable, the
         # batch brings one hit and four misses — refusal at the fourth,
         # with the first three placed on both sides
-        held = lockstep(nat, ora, [ip_of(i) for i in range(5)], "hold")
+        held = lockstep(
+            nat, ora, [ip_of(i) for i in range(5)], "hold", form=form)
         out = lockstep(
             nat, ora, [ip_of(6)] + [ip_of(200 + i) for i in range(4)],
-            "refusal",
+            "refusal", form=form,
         )
         assert out is None
         assert nat.eviction_count == 2  # slots 5 and 7; 6 is this batch's
         nat.release_pins(held), ora.release_pins(held)
         batches = [[ip_of(200 + i) for i in range(4)]]
     for k, ips in enumerate(batches):
-        s = lockstep(nat, ora, ips, f"{shape} {k}")
+        s = lockstep(nat, ora, ips, f"{shape} {k}", form=form)
         assert s is not None and len(s) == len(ips)
         nat.release_pins(s), ora.release_pins(s)
     assert_same_state(nat, ora, shape)
 
 
-def test_dropped_put_of_a_batch_keeps_its_shadow_entry():
+def test_dropped_put_of_a_batch_keeps_its_shadow_entry(form):
     """A warm tier whose probe window is full of live records drops the
     put; the batched spill leaves exactly those addresses in the shadow
     and deletes exactly the ones that landed, in lockstep with the dict
@@ -146,7 +148,7 @@ def test_dropped_put_of_a_batch_keeps_its_shadow_entry():
         for w in (nat, ora):
             for ip in ips:
                 plant(w, ip, vec)
-        s = lockstep(nat, ora, ips, f"round {rnd}")
+        s = lockstep(nat, ora, ips, f"round {rnd}", form=form)
         nat.release_pins(s), ora.release_pins(s)
         assert_same_warm_state(nat, ora, f"round {rnd}")
     assert nat.warm_dropped > 0 and nat.warm_spills > 0
@@ -250,7 +252,7 @@ def test_spill_and_refill_of_the_mirror_are_the_per_record_calls(
             t.unlink()
 
 
-def test_a_python_tier_behind_the_mirror_moves_record_by_record():
+def test_a_python_tier_behind_the_mirror_moves_record_by_record(form):
     """A tier that is not the C table (the fallback, or one a test
     injects) has no arena the mirror could copy into: spills and refills
     go through its put / take, one record each, counted as the dict
@@ -258,16 +260,22 @@ def test_a_python_tier_behind_the_mirror_moves_record_by_record():
     py = shm.PyWarmTier(capacity=8, max_rules=4)
     dw = DeviceWindows([make_rule()], capacity=2, warm_tier=py)
     assert dw.slotmgr_native
+
+    def slots_for(ips):
+        if form is None:
+            return dw.slots_for_unique_ips(ips)
+        return dw.resolve_addresses(form(ips)).slots
+
     vecs = {ip_of(i): {0: (i + 1, 1_700_000_000 + i, 7 * i)} for i in range(2)}
-    s = dw.slots_for_unique_ips(list(vecs))
+    s = slots_for(list(vecs))
     dw.release_pins(s)
     for ip, vec in vecs.items():
         plant(dw, ip, vec)
-    s = dw.slots_for_unique_ips([ip_of(8), ip_of(9)])   # evicts both
+    s = slots_for([ip_of(8), ip_of(9)])   # evicts both
     dw.release_pins(s)
     assert {ip: _vec(py.peek(ip)) for ip in vecs} == vecs
     assert not shadow(dw) and dw.warm_spills == 2
-    s = dw.slots_for_unique_ips([ip_of(1), ip_of(0)])   # and back
+    s = slots_for([ip_of(1), ip_of(0)])   # and back
     dw.release_pins(s)
     assert dict(shadow(dw)) == {ip_of(1): vecs[ip_of(1)],
                                 ip_of(0): vecs[ip_of(0)]}
@@ -291,7 +299,8 @@ def _seed_states(rng, wins, pool, step):
 @pytest.mark.parametrize("threshold,seed", [
     (1, 31), (1, 32), (2, 33), (2, 34), (5, 35), (5, 36),
 ])
-def test_gate_verdict_and_slots_equal_the_per_step_calls(threshold, seed):
+def test_gate_verdict_and_slots_equal_the_per_step_calls(
+        threshold, seed, form):
     """resolve_addresses on the native manager against admission_mask +
     slots_for_unique_ips on the dict path, over random batches with
     shadow and warm residents: the same verdict, the same slot for every
@@ -314,7 +323,8 @@ def test_gate_verdict_and_slots_equal_the_per_step_calls(threshold, seed):
             min_estimate=threshold, counts=counts,
         )
         res = nat.resolve_addresses(
-            ips, counts=counts, min_estimate=threshold, sketch=sk, gate=True
+            ips if form is None else form(ips), counts=counts,
+            min_estimate=threshold, sketch=sk, gate=True,
         )
         np.testing.assert_array_equal(res.admit, want, err_msg=f"step {step}")
         assert res.placed == (not len(res.refused))
@@ -339,7 +349,7 @@ def test_gate_verdict_and_slots_equal_the_per_step_calls(threshold, seed):
                 nat.release_pins(got), ora.release_pins(got)
         else:
             nat.clear(), ora.clear()  # every slot pinned: start over
-        hot = set(nat._slot_ip.values())
+        hot = set(nat.slot_addresses().values())
         assert not hot & set(refused), f"step {step}"
         assert_same_state(nat, ora, f"step {step}")
         assert_same_warm_state(nat, ora, f"step {step}")
@@ -365,7 +375,7 @@ def test_gate_verdict_and_slots_equal_the_per_step_calls(threshold, seed):
     assert tally["refused"] == refused_total
 
 
-def test_pass_with_no_gate_is_slots_for_unique_ips():
+def test_pass_with_no_gate_is_slots_for_unique_ips(form):
     """`gate=False` (slot admission off): every address is admitted and
     the pass is the slot assignment alone."""
     nat, ora = make_warm_pair(8)
@@ -373,7 +383,7 @@ def test_pass_with_no_gate_is_slots_for_unique_ips():
     pool = [ip_of(i) for i in range(40)]
     for step in range(60):
         ips = rng.sample(pool, rng.randrange(1, 8))
-        res = nat.resolve_addresses(ips)
+        res = nat.resolve_addresses(ips if form is None else form(ips))
         got = ora.slots_for_unique_ips(ips)
         assert res.admit.all() and res.placed and not len(res.refused)
         np.testing.assert_array_equal(res.slots, got)
@@ -383,7 +393,7 @@ def test_pass_with_no_gate_is_slots_for_unique_ips():
     assert nat.gate_derived_batches == 0
 
 
-def test_dict_path_resolves_through_the_per_step_calls():
+def test_dict_path_resolves_through_the_per_step_calls(form):
     """Without the native manager the same call gives the same answers
     (it is admission_mask and the dict loop, called in turn)."""
     sk = TrafficSketch(["r"], width=64, depth=2)
@@ -395,7 +405,8 @@ def test_dict_path_resolves_through_the_per_step_calls():
         ips = rng.sample(pool, rng.randrange(1, 8))
         counts = np.ones(len(ips), dtype=np.int64)
         ra = a.resolve_addresses(ips, counts, 3, sk, gate=True)
-        rb = b.resolve_addresses(ips, counts, 3, sk, gate=True)
+        rb = b.resolve_addresses(
+            ips if form is None else form(ips), counts, 3, sk, gate=True)
         np.testing.assert_array_equal(ra.admit, rb.admit)
         if len(ra.refused):
             sk.fold_refused([ips[i] for i in ra.refused.tolist()],
@@ -590,7 +601,7 @@ def test_threshold_over_one_still_asks_and_refuses(threshold):
     assert dw.gate_derived_batches < batches
     assert sorted(logn.splitlines()) == sorted(log1.splitlines())
     assert resn == res1
-    hot = set(dw._slot_ip.values())
+    hot = set(dw.slot_addresses().values())
     assert len(hot) <= 32 and (dw._pin_counts == 0).all()
     m1.close(), mn.close()
 

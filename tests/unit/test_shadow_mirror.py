@@ -432,7 +432,7 @@ def test_get_reads_a_record_in_any_of_its_three_homes():
         _hold(pair, [ip_of(i), ip_of(i + 1)], {0: i + 1})
     nat = pair.nat
     homes = {"mirror": [ip for ip in map(ip_of, range(6))
-                        if ip in nat._slot_ip.values()],
+                        if ip in nat.slot_addresses().values()],
              "dict": list(nat._shadow), "warm": nat._warm.keys()}
     assert all(homes.values()) and sum(map(len, homes.values())) == 6
     for i in range(6):

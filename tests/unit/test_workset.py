@@ -38,11 +38,11 @@ def nb():
 def _work_from(nb, rows=None):
     rows = np.arange(nb.n, dtype=np.int64) if rows is None else rows
     text = nb.text()
-    ips_u, ip_inv = unique_spans(
+    ips_u, ip_inv, _ = unique_spans(
         nb.ip_off[rows], nb.ip_len[rows], lambda k: nb.ip(int(rows[k])),
         blob=nb.blob, text=text,
     )
-    hosts_u, host_inv = unique_spans(
+    hosts_u, host_inv, _ = unique_spans(
         nb.host_off[rows], nb.host_len[rows], lambda k: nb.host(int(rows[k])),
         blob=nb.blob, text=text,
     )
@@ -269,8 +269,8 @@ def test_unique_spans_fallback_and_native_agree_on_nuls():
     def dec(k):
         return blob[int(offs[k]) : int(offs[k]) + int(lens[k])].decode()
 
-    s1, i1 = unique_spans(offs, lens, dec)  # scalar fallback
+    s1, i1, _ = unique_spans(offs, lens, dec)  # scalar fallback
     assert s1 == ["a\x00b", "a\x00c"] and i1.tolist() == [0, 0, 1]
     if native.available():
-        s2, i2 = unique_spans(offs, lens, dec, blob=blob)
+        s2, i2, _ = unique_spans(offs, lens, dec, blob=blob)
         assert s2 == s1 and i2.tolist() == i1.tolist()
