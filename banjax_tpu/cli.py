@@ -249,8 +249,8 @@ class BanjaxApp:
         # streaming pipeline scheduler (banjax_tpu/pipeline/): sits between
         # the tailer and the matcher when enabled — overlapped stages,
         # adaptive batch sizing, bounded backpressure, drain-time staleness.
-        # Disabled: _consume_lines keeps the reference-shaped synchronous
-        # per-batch path.
+        # Disabled: _consume_lines calls matcher.consume_lines, which runs
+        # the same four matcher stages in turn on the tailer's thread.
         self.pipeline = None
         if getattr(config, "pipeline_enabled", False):
             from banjax_tpu.pipeline import PipelineScheduler

@@ -112,30 +112,6 @@ def _pad_to(n: int, mult: int) -> int:
     return ((n + mult - 1) // mult) * mult
 
 
-def auto_shards(n_words: int, max_wps: int = 512) -> int:
-    """Shard count minimizing total padded words (the dot's row axis).
-
-    Each shard's word slab pads up to a KERNEL_WORD_ALIGN multiple, so the
-    FLOP cost is `n_shards * pad(ceil(n_words / n_shards), align)`. Ties
-    break toward fewer shards (fewer grid steps). `max_wps` caps the slab
-    so the per-step VMEM transients stay comfortable at block=256.
-    """
-    if n_words <= 0:
-        return 1
-    best, best_cost = 1, None
-    for ns in range(1, max(2, -(-n_words // 64)) + 1):
-        # 4% slack over the even split: rulec's branch-atomic greedy packing
-        # can overfill the fullest shard slightly beyond ceil(n_words / ns)
-        wps_est = -(-n_words * 26 // (25 * ns))
-        wps_p = max(KERNEL_WORD_ALIGN, _pad_to(wps_est, KERNEL_WORD_ALIGN))
-        if wps_p > max_wps:
-            continue
-        cost = ns * wps_p
-        if best_cost is None or cost < best_cost:
-            best, best_cost = ns, cost
-    return best
-
-
 def prepare(compiled: CompiledRules) -> PallasRules:
     """Repack a compiled ruleset for the kernel.
 

@@ -761,12 +761,6 @@ class CompiledRules:
     def n_words(self) -> int:
         return self.n_shards * self.words_per_shard
 
-    @property
-    def n_positions(self) -> int:
-        # every position is either branch-initial (an inject bit) or shifted into
-        used = self.shift_in | self.inject_always | self.inject_start
-        return int(sum(bin(int(w)).count("1") for w in used))
-
 
 def compile_rules(patterns: Sequence[str], n_shards=1) -> CompiledRules:
     """Compile a full ruleset into one packed tensor set.

@@ -1,21 +1,28 @@
 """TpuMatcher: the batched device-backed implementation of the Matcher seam.
 
-Pipeline per batch (SURVEY.md §7.1 / BASELINE.json north star):
+One drive of a batch through the matcher, four stages (SURVEY.md §7.1 /
+BASELINE.json north star):
 
-  host parse (encode.parse_line, the exact consumeLine splits)
-    → byte-class encode → device NFA match (nfa_jax.match_batch: all lines ×
-      all rules in one jitted shift-and scan)
-    → host fixed-window pass in original line order (the authoritative
-      RegexRateLimitStates — byte-identical window semantics by construction)
-    → Banner side effects (BanOrChallengeIp + LogRegexBan), identical call
-      sequence to the CPU reference path.
+  begin    host parse (encode.parse_line, the exact consumeLine splits),
+           allowlist gate, byte-class encode
+  submit   the one pass over the batch's distinct addresses (slot-
+           admission gate + window slots), then the device dispatch:
+           match AND window commit in one fused program a chunk, or the
+           match bitmap alone (classic: a rule or a line only the host's
+           `re` decides, a refused placement, no device windows)
+  collect  wait for the device
+  finish   the window events (fused: pulled; classic: applied here, on
+           the device or in the host's RegexRateLimitStates) replayed
+           into results and Banner side effects (BanOrChallengeIp +
+           LogRegexBan), the call sequence of the CPU reference path
 
-The device decides only the regex-match bitmap — the O(lines × rules) hot
-loop of /root/reference/internal/regex_rate_limiter.go:234. Rule/line cases
-the device can't decide exactly (rules rulec can't lower; lines with a
-byte over 0x7F or past 8,192 bytes — matcher/longrows.py) fall back to
-host `re` per rule or per line, so the
-observable Decision stream is byte-identical to CpuMatcher for any input.
+and two callers of it: the streaming scheduler's stage threads
+(pipeline/scheduler.py), and the synchronous consume_lines, which calls
+the four in turn on its own thread.  Cases the device can't decide
+exactly (rules rulec can't lower; lines with a byte over 0x7F or past
+8,192 bytes — matcher/longrows.py) fall back to host `re` per rule or
+per line, so the observable Decision stream is byte-identical to
+CpuMatcher for any input.
 
 Selected by `matcher: tpu` in banjax-config.yaml (the Matcher interface
 flag named in BASELINE.json); CpuMatcher remains the default.
@@ -668,13 +675,15 @@ class TpuMatcher(Matcher):
     def consume_lines_serial(
         self, lines: Sequence[str], now_unix: Optional[float] = None
     ) -> List[ConsumeLineResult]:
-        """consume_lines with the fused single-dispatch path disabled —
-        the streaming scheduler's generic drain uses this: a generic batch
-        drains on the drain thread while LATER batches' fused chunks
-        already hold fused-pipeline order turns, so an inline fused burst
-        here would wait on turns that only release after this very drain
-        completes (deadlock).  The classic bitmap path it takes instead is
-        differentially proven byte-identical."""
+        """consume_lines with the fused path off — the streaming
+        scheduler's generic drain uses this: a generic batch drains on the
+        drain thread while LATER batches' fused chunks already hold
+        fused-pipeline order turns, so a fused chunk dispatched here
+        would wait on turns that only release after this very drain
+        completes (deadlock).  The batch rides the classic `pend`
+        instead, differentially proven byte-identical; while it owes its
+        window apply, batches submitted behind it go classic too
+        (_single_kernel_ordered)."""
         return self.consume_lines(lines, now_unix, _fused_ok=False)
 
     def effective_latency_budget_s(self) -> float:
@@ -804,55 +813,42 @@ class TpuMatcher(Matcher):
         self, lines: Sequence[str], now_unix: Optional[float] = None,
         fused_ok: bool = True,
     ) -> List[ConsumeLineResult]:
+        """The synchronous drive of the four stages (see the split
+        protocol below): `lines` in consecutive batches of at most
+        matcher_batch_lines, each run to its end on this thread before
+        the next begins.  One reading of the clock serves the gate, the
+        submit and the finish, so no line ages between the stages and
+        `old_line` is the gate's alone."""
         now = time.time() if now_unix is None else now_unix
+        mb = self._max_batch
+        if len(lines) <= mb:
+            return self._consume_batch(lines, now, fused_ok)
         results = LazyResults(len(lines))
-
-        # 1. host parse + allowlist exemption (see _gate)
-        work, pre_encoded = self._gate(lines, now, results)
-        if not len(work):
-            return results
-
-        # 1b. cold-tier slot admission: refused rows take the classic
-        #     per-line host path (matched device-statelessly, windows
-        #     applied host-side into the warm tier); admitted rows
-        #     continue below with hot-tier slots
-        part = self._partition_admission(work, pre_encoded)
-        if part is not None:
-            work, pre_encoded, work_r, pre_r = part
-            self._consume_refused(work_r, pre_r, results)
-            if not len(work):
-                return results
-
-        # 2a. fully-fused pipeline: match + window apply in ONE device
-        #     dispatch (matcher/fused_windows.py) — no dense bitmap ever
-        #     crosses the host boundary. Eligible when every rule is
-        #     device-decidable and every row is the device's to decide:
-        #     in the short matrix, or a long row its chunk's operand holds
-        #     (_fused_rows_ok).
-        if (
-            fused_ok
-            and self.device_windows is not None
-            and self._fw_pipeline is not None
-        ):
-            if pre_encoded is None:
-                pre_encoded = self._encode_work(work)
-            if self._fused_rows_ok(pre_encoded):
-                self._consume_via_pipeline(work, pre_encoded, results)
-                return results
-
-        # 2b. device match bitmap for all matchable lines
-        bits = self._match_bits(work, pre_encoded)
-
-        # 3a. device window pass: fold the whole batch of match events into
-        #     the persistent on-device counters in one step, then replay the
-        #     per-event outcomes into results/effectors in reference order
-        if self.device_windows is not None:
-            self._apply_device_windows(work, bits, results)
-            return results
-
-        # 3b. host window pass in original line order
-        self._apply_host_windows(work, bits, results)
+        for row0 in range(0, len(lines), mb):
+            results.absorb(
+                self._consume_batch(lines[row0 : row0 + mb], now, fused_ok),
+                row0,
+            )
         return results
+
+    def _consume_batch(self, lines, now: float, fused_ok: bool):
+        """One batch through begin → submit → collect → finish under the
+        synchronous entry's failure contract: what a stage raises leaves
+        here, once the batch's remaining order turns and pins are free —
+        consume_lines records the breaker failure and re-runs the lines
+        on the CPU reference.  `fused_ok=False` (consume_lines_serial):
+        the batch rides the classic `pend` and applies its windows in the
+        finish."""
+        state = self.pipeline_begin(lines, now, use_scratch=True)
+        if not fused_ok:
+            state.pop("fused_eligible", None)
+        try:
+            self.pipeline_submit(state, now)
+            self.pipeline_collect(state)
+            return self._finish_batch(state, now)[0]
+        except Exception:
+            self.pipeline_abort(state)
+            raise
 
     def _apply_host_windows(self, work, bits, results) -> None:
         """Host window pass in original line order: per-site rules for the
@@ -878,16 +874,14 @@ class TpuMatcher(Matcher):
                 results[i].error = True
 
     def close(self) -> None:
-        """No buffered state: consume_lines is synchronous per batch."""
+        """No buffered state: a batch's four stages have run to their end
+        when consume_lines returns, and the scheduler drains its own."""
 
-    # ---- streaming-pipeline split protocol (pipeline/scheduler.py) ----
+    # ---- the four stages (module docstring): one batch's drive ----
     #
-    # consume_lines, split at its two natural seams so the scheduler can
-    # run the pieces on different stage threads: begin (host parse/gate/
-    # encode) → submit (device dispatch, no host sync) → collect (force
-    # device→host) → finish (window updates + Banner replay, which the
-    # scheduler serializes in admission order).
-    #
+    # The scheduler runs them on its stage threads, any number of batches
+    # overlapped, and serializes the finishes in admission order; the
+    # synchronous consume_lines (_consume_batch) calls them in turn.
     # Two device protocols ride the same four calls:
     #
     #   * classic bitmap — _match_bits_submit/collect, dense [B, n_rules]
@@ -896,22 +890,23 @@ class TpuMatcher(Matcher):
     #     matcher+windows pipeline is active and every row of the batch
     #     is the device's to decide (_fused_rows_ok: a line over the
     #     short width rides its chunk's long operand), submit dispatches
-    #     ONE program per chunk (match
-    #     + window commit, gated in the program on its overflow flags),
-    #     any number of batches ahead; finish pulls each chunk's buffer
-    #     in admission order and replays its events.  The dense bitmap
-    #     never crosses the host boundary — the ~16 MB per-65k-batch
-    #     re-upload the classic path pays is gone — and staleness is cut
-    #     at submit as a per-row live mask.  Overflowing chunks replay
-    #     classically mid-pipeline (the order turn held until the
-    #     fallback applies).
+    #     ONE program per chunk (match + window commit, gated in the
+    #     program on its overflow flags), any number of batches ahead;
+    #     finish pulls each chunk's buffer in admission order and replays
+    #     its events.  The dense bitmap never crosses the host boundary,
+    #     and staleness is cut at submit as a per-row live mask.
+    #     Overflowing chunks replay classically mid-pipeline (the order
+    #     turn held until the fallback applies).
 
-    def pipeline_begin(self, lines: Sequence[str], now: float) -> dict:
+    def pipeline_begin(self, lines: Sequence[str], now: float,
+                       use_scratch: bool = False) -> dict:
         """Encode stage: parse + gate + byte-class encode.  Fresh (non-
-        scratch) buffers — see _gate — because batches overlap in flight."""
+        scratch) buffers — see _gate — because batches overlap in flight;
+        a caller that runs each batch to its end before the next begins
+        (the synchronous entry) may reuse the matcher's."""
         results = LazyResults(len(lines))
         work, pre_encoded = self._gate(
-            lines, now, results, use_scratch=False
+            lines, now, results, use_scratch=use_scratch
         )
         return self._pipeline_state(lines, results, work, pre_encoded)
 
@@ -1062,33 +1057,13 @@ class TpuMatcher(Matcher):
     def pipeline_submit(self, state: dict, now: Optional[float] = None) -> None:
         if not len(state["work"]):
             return
-        # a batch that commits as ONE fused chunk resolves its distinct
-        # addresses in one pass (gate and slots together); any other
-        # batch, and a batch whose pass failed, takes the per-step calls
-        if (
-            state.get("fused_eligible")
-            and self.device_windows is not None
-            and len(self._fused_chunks(state["pre"])) == 1
-            and self._single_kernel_ordered()
-        ):
+        if self.device_windows is not None:
+            # the one pass over the batch's distinct addresses: the
+            # slot-admission gate's verdict, and for a batch that commits
+            # as one fused chunk its slots too
             self._resolve_submit(state)
             if not len(state["work"]):
                 return
-        if not state.get("gated"):
-            part = self._partition_admission(state["work"], state["pre"])
-            if part is not None:
-                state["work"], state["pre"], work_r, pre_r = part
-                # refused rows apply SYNCHRONOUSLY at submit: submits are
-                # sequential on the scheduler thread, so this batch's
-                # warm-tier writes land before the NEXT batch's admission
-                # probe/refill — a refused IP can never race its own
-                # state.  (Their results ride state["results"] out at
-                # finish; the shrunk work has no row the batch did not
-                # have, so fused eligibility computed at begin remains
-                # valid, and its chunks are cut anew at dispatch.)
-                self._consume_refused(work_r, pre_r, state["results"])
-                if not len(state["work"]):
-                    return
         if "slots" in state or (
             state.get("fused_eligible") and self._single_kernel_ordered()
         ):
@@ -1122,17 +1097,41 @@ class TpuMatcher(Matcher):
     def _resolve_submit(self, state: dict) -> None:
         """The submit stage's one pass over the batch's distinct
         addresses (DeviceWindows.resolve_addresses): the slot-admission
-        gate's verdict and the window slots from one encoding and one
-        probe of each table.  Leaves `state["gated"]`, and
-        `state["slots"]` — the admitted rows' (slots, pinned; address
-        hashes for the traffic sketch, None without one), or None when
-        placement refused (the batch then goes the classic way, as after
-        a refusing slots_for_unique_ips).  Rows the gate refused
-        are split off and applied here, between the pass's probe and its
-        placement: exactly where _partition_admission applied them."""
+        gate's verdict from one encoding and one probe of each table.
+        Rows the gate refused are split off and applied here,
+        synchronously — submits are sequential, so this batch's warm-tier
+        writes land before the NEXT batch's probe and a refused address
+        can never race its own state.  Their results ride
+        `state["results"]` out at the finish; the shrunk work has no row
+        the batch did not have, so its fused eligibility stands and its
+        chunks are cut anew at dispatch.
+
+        A batch that commits as ONE fused chunk is placed by the same
+        pass, after the refused rows' apply: `state["slots"]` — the
+        admitted rows' (slots, pinned; address hashes for the traffic
+        sketch, None without one), or None when placement refused (the
+        batch then goes the classic way).  Any other batch asks for the
+        probe alone, and only where the verdict is not "admit" by
+        arithmetic: its placement is its chunks' (_slots_for_work) or the
+        classic replay's (_with_window_slots).
+
+        The gate fails open: a pass that raises is logged and the whole
+        batch admitted; a placement that then raises too is the batch's
+        failure."""
         dw = self.device_windows
         sk = self.traffic_sketch
-        gate = self._slot_admission and sk is not None
+        gate = self._slot_admission
+        # a distinct address of a batch has at least one row, so `estimate
+        # + rows >= 1` whatever the sketch says: with a rule that bans on
+        # the first hit the verdict is "admit", unasked
+        asks = gate and self._admission_min_estimate > 1
+        whole = bool(
+            state.get("fused_eligible")
+            and len(self._fused_chunks(state["pre"])) == 1
+            and self._single_kernel_ordered()
+        )
+        if not (whole or asks):
+            return
         lap = trace.lap()
 
         def keep_slots(uinv):
@@ -1146,25 +1145,27 @@ class TpuMatcher(Matcher):
         with self._resolving(lap):
             uips, uinv = self._distinct_addresses(state["work"])
             counts = None
-            if gate and self._admission_min_estimate > 1:
+            if asks:
                 counts = np.bincount(
                     uinv, minlength=len(uips)
                 ).astype(np.int64)
             try:
-                res = dw.resolve_addresses(
-                    uips, counts=counts,
-                    min_estimate=self._admission_min_estimate,
-                    sketch=sk, gate=gate,
-                )
-            except Exception:  # noqa: BLE001 — fail open: per-step calls
-                log.exception(
-                    "address resolution failed; batch takes the per-step "
-                    "gate and slot calls"
-                )
+                if whole:
+                    res = dw.resolve_addresses(
+                        uips, counts=counts,
+                        min_estimate=self._admission_min_estimate,
+                        sketch=sk, gate=gate,
+                    )
+                else:
+                    res = dw.probe_addresses(
+                        uips, counts, self._admission_min_estimate, sk
+                    )
+            except Exception:  # noqa: BLE001 — the gate is an optimization; fail open
+                log.exception("slot-admission gate failed; admitting batch")
                 return
-            state["gated"] = True
             if not len(res.refused):
-                keep_slots(uinv)
+                if whole:
+                    keep_slots(uinv)
                 return
         # a threshold of 2 or more only
         lap.mark("pass")
@@ -1177,9 +1178,10 @@ class TpuMatcher(Matcher):
         )
         self._consume_refused(work_r, pre_r, state["results"])
         lap.mark("other")
-        with self._resolving(lap):
-            dw.place_resolved(res)
-            keep_slots(uinv[adm])
+        if whole:
+            with self._resolving(lap):
+                dw.place_resolved(res)
+                keep_slots(uinv[adm])
 
     def _distinct_addresses(self, work):
         """(a batch's distinct addresses, the per-row inverse) for the
@@ -1246,11 +1248,13 @@ class TpuMatcher(Matcher):
         # way after one had would count that chunk's hits twice.  One
         # chunk (the rule, unless long rows cut the batch): the submit
         # stage's pass has them (`state["slots"]`, with the rows' hashes
-        # beside), or the chunk's own submit gets them (None here).  Pins
-        # not yet handed to a chunk's submit are given back on every way
-        # out
+        # beside).  Pins not yet handed to a chunk's submit are given
+        # back on every way out
+        from banjax_tpu.matcher.windows import split_ns
+
         placed: list = []
         entries = []
+        n_stale = 0
         try:
             failpoints.check("matcher.device")
             work = state["work"]
@@ -1260,24 +1264,26 @@ class TpuMatcher(Matcher):
             lap = trace.lap()
             chunks = self._fused_chunks(pre)
             if "slots" in state:
-                placed = [state.pop("slots")]
-                if placed[0] is None:
+                got = state.pop("slots")
+                if got is None:
                     return False  # placement refused
-                if len(chunks) > 1:
+                if len(chunks) == 1:
+                    placed = [got]
+                else:
                     # the pass placed the batch as ONE chunk, and the rows
                     # it split off since left a smaller one, with a
                     # smaller long operand: placed again, chunk by chunk
-                    self.device_windows.release_pins(placed.pop()[0])
-            elif len(chunks) == 1:
-                placed = [None]
-            if len(chunks) > 1:
+                    self.device_windows.release_pins(got[0])
+            if not placed:
+                # several chunks, or one whose pass failed open
                 lap.mark("pass")
                 for s, stop in chunks:
-                    placed.append(self._slots_for_work(work[s:stop]))
-                    if placed[-1] is None:
+                    got = self._slots_for_work(work[s:stop])
+                    if got is None:
                         # more distinct IPs than free+unpinned slots
                         lap.mark("other")
                         return False
+                    placed.append(got)
             self._count_cut(chunks, len(work))
             placed.reverse()
             for s, stop in chunks:
@@ -1288,32 +1294,34 @@ class TpuMatcher(Matcher):
                 st = ages_s > OLD_LINE_CUTOFF_SECONDS
                 if st.any():
                     stale, live = st, ~st
+                    n_stale += int(st.sum())
                 # a phase ends before a span opens over what follows it
                 lap.mark("other")
                 with trace.span("program-ab-fused", args={"row0": s}):
-                    e = self._submit_pipeline_chunk(
-                        wc, _rows_of(pre, slice(s, stop)),
-                        live=live, placed=placed.pop(),
+                    lap.mark("operands")
+                    slots, row_hashes = placed[-1]
+                    pc = _rows_of(pre, slice(s, stop))
+                    ts_s, ts_ns = split_ns(wc.ts_array())
+                    host_idx = wc.host_idx(self._host_row)
+                    pend = self._fw_pipeline.submit(
+                        pc[0], pc[1], slots, ts_s, ts_ns, host_idx,
+                        live=live, long_rows=self._long_rows_of(wc, pc[3]),
+                        row_hashes=row_hashes,
                     )
-                if e is None:
-                    # the batch's one chunk, its own placement refused
-                    # (in-flight batches hold pins until their drains):
-                    # nothing is committed, classic path
-                    assert not entries
-                    return False
-                e["row0"] = s
-                e["live"] = live
-                e["stale"] = stale
-                entries.append(e)
+                    placed.pop()  # the pins are the pipeline's now
+                entries.append({
+                    "work": wc, "pre": pc, "slots": slots, "ts_s": ts_s,
+                    "ts_ns": ts_ns, "host_idx": host_idx, "pend": pend,
+                    "row0": s, "live": live, "stale": stale,
+                })
         except Exception:
             for prev in entries:
                 self._fw_pipeline.abandon(prev["pend"])
             raise
         finally:
             for left in placed:
-                if left is not None:
-                    self.device_windows.release_pins(left[0])
-        state["fused"] = entries
+                self.device_windows.release_pins(left[0])
+        state["fused"], state["n_stale"] = entries, n_stale
         return True
 
     def pipeline_collect(self, state: dict) -> None:
@@ -1361,34 +1369,68 @@ class TpuMatcher(Matcher):
                 self._drain_window_batches -= 1
 
     def pipeline_finish(self, state: dict, now: float):
-        """Drain stage: staleness re-check at EFFECTOR DRAIN time (the
-        reference's 10 s cutoff, regex_rate_limiter.go:164-167, applied
-        end-to-end — a line that aged out while queued in the pipeline is
-        dropped here, marked old_line, and counted), then the window pass
-        + Banner replay.  Returns (results, n_stale_dropped)."""
+        """Drain stage: _finish_batch under the drain's failure contract.
+        A fused chunk that fails at its settle costs its own lines —
+        marked `error`, one breaker failure — and the batch goes on with
+        the chunks after it: a dead chunk must not wedge the stream.  Any
+        other failure is the batch's and leaves here (the scheduler
+        counts its lines shed and aborts it)."""
         t0 = time.perf_counter()
+        try:
+            while True:
+                try:
+                    out = self._finish_batch(state, now)
+                except _ChunkFailed as failed:
+                    log.exception(
+                        "single-kernel chunk failed at its drain; chunk "
+                        "lines marked error"
+                    )
+                    e = failed.chunk
+                    orig = np.asarray(e["work"].orig_rows())
+                    if e["live"] is not None:
+                        orig = orig[e["live"]]
+                    for i in orig.tolist():
+                        state["results"][i].error = True
+                    self.note_device_outcome(0.0, ok=False)
+                    continue
+                self._note_health()
+                return out
+        finally:
+            self.stats.record_batch(
+                len(state["lines"]), time.perf_counter() - t0
+            )
+
+    def _finish_batch(self, state: dict, now: float):
+        """The finish stage's work, raising what it meets: the staleness
+        re-check at EFFECTOR DRAIN time (the reference's 10 s cutoff,
+        regex_rate_limiter.go:164-167, applied end-to-end — a line that
+        aged out while queued in the pipeline is dropped here, marked
+        old_line, and counted), then the window pass + Banner replay.
+        Returns (results, n_stale_dropped).
+
+        Fused chunks committed at submit (live mask = submit-time
+        staleness): their finish is pure event pull + replay, no
+        drain-time re-cut, chunk by chunk in admission order.  A chunk
+        leaves `state["fused"]` as its settle begins and a failing one
+        raises _ChunkFailed, so whoever catches finds the chunks after
+        it still there: to go on with (pipeline_finish calls again) or
+        to free (pipeline_abort, as the synchronous entry does)."""
         results = state["results"]
         work, bits = state["work"], state["bits"]
         n_stale = 0
         try:
             if not len(work):
                 return results, 0
-            if state.get("fused") is not None:
-                # fused chunks committed at submit (live mask = submit-
-                # time staleness): the drain is pure event pull + replay,
-                # no drain-time re-cut
-                n_stale = self._finish_single_kernel(state, results)
-                self._note_health()
-                return results, n_stale
+            entries = state.get("fused")
+            if entries is not None:
+                while entries:
+                    self._settle_chunk(entries.pop(0), results)
+                return results, state["n_stale"]
             ages_s = now - work.ts_array() / 1e9
             stale = ages_s > OLD_LINE_CUTOFF_SECONDS
             if stale.any():
                 n_stale = int(stale.sum())
-                for k in np.flatnonzero(stale):
-                    i, _ = work[int(k)]
-                    r = results[i]
-                    r.old_line = True
-                    r.rule_results = []
+                _mark_old(work, stale, results)
                 keep = np.flatnonzero(~stale)
                 work = work.take(keep)
                 bits = bits[keep]
@@ -1398,72 +1440,37 @@ class TpuMatcher(Matcher):
                 self._apply_device_windows(work, bits, results)
             else:
                 self._apply_host_windows(work, bits, results)
-            self._note_health()
             return results, n_stale
         finally:
             self._drain_window_done(state)
-            self.stats.record_batch(
-                len(state["lines"]), time.perf_counter() - t0
-            )
 
-    def _finish_single_kernel(self, state, results) -> int:
-        """Ordered drain for fused chunks: the window commit already ran
-        in the program at submit (the live mask carried the submit-time
-        10 s staleness cut), so each chunk's drain is a pure d2h pull
-        (async since submit) + decode + Banner replay.  Overflow /
-        chain-gated chunks replay classically in chunk order via the
-        fallback (their program committed nothing — its own gate)."""
+    def _settle_chunk(self, e, results) -> None:
+        """Settle one fused chunk at its order turn: the window commit
+        already ran in the program at submit (the live mask carried the
+        submit-time 10 s staleness cut), so this is a pure d2h pull
+        (async since submit) + decode + Banner replay.  An overflow /
+        chain-gated chunk replays classically through the fallback (its
+        program committed nothing — its own gate).  A failure leaves as
+        _ChunkFailed; on every way out the chunk's pins and order turn
+        are free."""
         from banjax_tpu.matcher.fused_windows import PipelineOverflow
 
-        entries = state["fused"]
-        state["fused"] = None
         fw = self._fw_pipeline
-        n_stale = 0
-        for e in entries:
-            stale = e.get("stale")
-            live = e.get("live")
-            if stale is not None:
-                n_stale += int(stale.sum())
-                for k in np.flatnonzero(stale):
-                    i, _ = e["work"][int(k)]
-                    r = results[i]
-                    r.old_line = True
-                    r.rule_results = []
-            chunk_stale = (
-                stale if stale is not None
-                else np.zeros(len(e["work"]), dtype=bool)
-            )
-            pend = e["pend"]
+        pend, live = e["pend"], e["live"]
+        if e["stale"] is not None:
+            _mark_old(e["work"], e["stale"], results)
+        try:
+            failpoints.check("matcher.resolve")
             try:
-                failpoints.check("matcher.resolve")
                 fw.resolve(pend)
             except PipelineOverflow as ov:
                 trace.instant("fused-overflow-fallback", {"row0": e["row0"]})
                 self.pipelined_fused_fallbacks += 1
-                try:
-                    self._pipeline_fallback_entry(e, ov, results, live=live)
-                except Exception:  # noqa: BLE001 — one chunk's loss, not the stream's
-                    log.exception(
-                        "single-kernel overflow fallback failed; chunk "
-                        "lines marked error"
-                    )
-                    self._mark_chunk_error(e, chunk_stale, results)
-                    self.note_device_outcome(0.0, ok=False)
-                self.stats.note_xfer(pend.h2d_bytes, pend.d2h_bytes)
-                continue
-            except Exception:  # noqa: BLE001 — a dead chunk must not wedge the drain
-                if pend.state == "submitted":
-                    fw.abandon(pend)
-                log.exception(
-                    "single-kernel event pull failed; chunk lines marked "
-                    "error"
-                )
-                self._mark_chunk_error(e, chunk_stale, results)
-                self.note_device_outcome(0.0, ok=False)
-                continue
+                self._pipeline_fallback_entry(e, ov, results, live=live)
+                return
             t0 = time.perf_counter()
-            with trace.span("effector-replay", args={"row0": e["row0"]}):
-                try:
+            try:
+                with trace.span("effector-replay", args={"row0": e["row0"]}):
                     res = fw.collect(pend)
                     self._replay_window_events(
                         e["work"], None,
@@ -1471,22 +1478,15 @@ class TpuMatcher(Matcher):
                         res.events, results, live_rows=live,
                     )
                     self.pipelined_fused_chunks += 1
-                except Exception:  # noqa: BLE001 — collect settled pins/turns in finally
-                    log.exception(
-                        "single-kernel event collect failed; chunk lines "
-                        "marked error"
-                    )
-                    self._mark_chunk_error(e, chunk_stale, results)
-                    self.note_device_outcome(0.0, ok=False)
-                finally:
-                    self.stats.note_xfer(pend.h2d_bytes, pend.d2h_bytes)
-            self.effector_replay_s += time.perf_counter() - t0
-        return n_stale
-
-    def _mark_chunk_error(self, e, chunk_stale, results) -> None:
-        for k in np.flatnonzero(~chunk_stale):
-            i, _ = e["work"][int(k)]
-            results[i].error = True
+            finally:
+                self.effector_replay_s += time.perf_counter() - t0
+        except Exception as exc:
+            # resolve, collect and the fallback settle what they took up;
+            # a chunk that died before its resolve is settled here
+            fw.abandon(pend)
+            raise _ChunkFailed(e) from exc
+        finally:
+            self.stats.note_xfer(pend.h2d_bytes, pend.d2h_bytes)
 
     def probe(self, now_unix: Optional[float] = None) -> bool:
         """Synthetic device probe (ROADMAP matcher-staleness item): one
@@ -1530,58 +1530,6 @@ class TpuMatcher(Matcher):
             uips, res, uinv, trace.lap())
 
     # ---- cold-tier slot admission (mega-state tiering) ----
-
-    def _partition_admission(self, work, pre_encoded):
-        """Split one batch at the slot-admission gate.  Returns None when
-        admission is off or every row admitted; else
-        (work_admitted, pre_admitted, work_refused, pre_refused) —
-        row-disjoint takes of the batch, partitioned per DISTINCT ip so
-        all of an IP's rows land on one side (per-IP event order is
-        therefore untouched; only cross-IP interleaving can differ from
-        the ungated engine).
-
-        The gate admits on `estimate + this batch's row count`, so an IP
-        whose cumulative rows reach the threshold is admitted in THAT
-        batch: a refused IP has strictly fewer than min_estimate total
-        rows behind it — the bounded-ban-delay invariant the
-        differential suite asserts.  Refused counts then fold into the
-        sketch's exact host mirror so the next batch's estimate sees
-        them.  Any gate failure admits the whole batch (fail open)."""
-        if (
-            not self._slot_admission
-            or self.device_windows is None
-            or self.traffic_sketch is None
-            or not len(work)
-            # a distinct address of a batch has at least one row, so
-            # `estimate + rows >= 1` whatever the sketch says: with a rule
-            # that bans on the first hit the verdict is "admit", unasked
-            or self._admission_min_estimate <= 1
-        ):
-            return None
-        try:
-            uips, uinv = work.unique_ips()
-            counts = np.bincount(uinv, minlength=len(uips)).astype(np.int64)
-            sk = self.traffic_sketch
-            hashes = sk.base_hashes(uips)
-            est = sk.estimate_ips(uips, hashes=hashes) + counts
-            mask_u = self.device_windows.admission_mask(
-                uips,
-                estimates=est,
-                min_estimate=self._admission_min_estimate,
-                counts=counts,
-            )
-            if mask_u.all():
-                return None
-            refused_u = np.flatnonzero(~mask_u)
-            sk.fold_refused(
-                [uips[int(i)] for i in refused_u],
-                counts[refused_u],
-                hashes=hashes[refused_u],
-            )
-            return self._split_rows(work, pre_encoded, mask_u[uinv])[:4]
-        except Exception:  # noqa: BLE001 — the gate is an optimization; fail open
-            log.exception("slot-admission gate failed; admitting batch")
-            return None
 
     @staticmethod
     def _split_rows(work, pre_encoded, row_mask):
@@ -1859,62 +1807,6 @@ class TpuMatcher(Matcher):
                 dw.release_pins(slots)
             raise
 
-    def _consume_via_pipeline(self, work, pre, results) -> None:
-        """The sync entry's fused path (matcher/fused_windows.py): each
-        chunk's program commits at submit, and at most two chunks are in
-        flight — chunk N's device→host pull, decode and replay hide
-        behind chunk N+1's compute.  Chunks settle strictly oldest first,
-        so when one overflows every earlier chunk has already applied and
-        its classic replay lands before any later chunk's (those were
-        gated off by the chain scalar and replay classically too)."""
-        failpoints.check("matcher.device")
-        from banjax_tpu.matcher.fused_windows import PipelineOverflow
-
-        q: List[dict] = []  # in-flight entries, oldest first
-
-        def settle(e):
-            """Collect e and replay it, classically if it overflowed.
-            Pins and the order turn are released on every way out."""
-            try:
-                res = self._fw_pipeline.collect(e["pend"])
-            except PipelineOverflow as ov:
-                self._pipeline_fallback_entry(e, ov, results)
-                return
-            self._replay_window_events(
-                e["work"], None, (res.matched_pairs, res.always_bits),
-                res.events, results,
-            )
-
-        try:
-            chunks = self._fused_chunks(pre)
-            self._count_cut(chunks, len(work))
-            for s, stop in chunks:
-                wc = work[s:stop]
-                pc = _rows_of(pre, slice(s, stop))
-                entry = self._submit_pipeline_chunk(wc, pc)
-                if entry is None:
-                    # slot allocation refused (more distinct IPs than
-                    # free+unpinned slots): drain in-flight pins, then run
-                    # this chunk through the splitting sync path
-                    while q:
-                        settle(q.pop(0))
-                    self._pipeline_chunk_sync(wc, pc, results)
-                    continue
-                q.append(entry)
-                if len(q) > 1:
-                    settle(q.pop(0))
-            while q:
-                settle(q.pop(0))
-        except Exception:
-            # failures mid-burst: settle what is still in flight so pins
-            # and the pipeline's order turns are not leaked
-            while q:
-                try:
-                    settle(q.pop(0))
-                except Exception:  # noqa: BLE001 — first error wins
-                    log.exception("pipeline drain after failure also failed")
-            raise
-
     def _long_rows_of(self, work, long_len):
         """A chunk's long rows as FusedWindowsPipeline.submit takes them:
         (rows, lens, their class ids back to back) — gathered from the
@@ -1929,89 +1821,6 @@ class TpuMatcher(Matcher):
         with trace.span("long-rows", args={"rows": int(ks.size)}):
             flat, lens = work.rest_bytes(ks)
             return ks.astype(np.int32), lens, self._class_of_byte[flat]
-
-    def _submit_pipeline_chunk(self, work, pre, live=None, placed=None):
-        """Allocate slots (unless the caller's pass already has: `placed`,
-        _slots_for_work's pair, pinned) + dispatch the chunk's fused
-        program (`live` is its commit mask) over `pre`, the chunk's rows
-        of the encoded batch (one of _fused_chunks'); None when slot
-        allocation refuses. Pins transfer to the pipeline on success."""
-        from banjax_tpu.matcher.windows import split_ns
-
-        dw = self.device_windows
-        lap = trace.lap()
-        if placed is None:
-            lap.mark("pass")
-            placed = self._slots_for_work(work)
-        if placed is None:
-            lap.mark("other")
-            return None
-        slots, row_hashes = placed
-        try:
-            lap.mark("operands")
-            ts_s, ts_ns = split_ns(work.ts_array())
-            host_idx = work.host_idx(self._host_row)
-            pend = self._fw_pipeline.submit(
-                pre[0], pre[1], slots, ts_s, ts_ns, host_idx, live=live,
-                long_rows=self._long_rows_of(work, pre[3]),
-                row_hashes=row_hashes,
-            )
-        except Exception:
-            dw.release_pins(slots)
-            raise
-        return {
-            "work": work, "pre": pre, "slots": slots,
-            "ts_s": ts_s, "ts_ns": ts_ns, "host_idx": host_idx,
-            "pend": pend,
-        }
-
-    def _pipeline_chunk_sync(self, work, pre, results) -> None:
-        """Non-overlapped fallback for a chunk whose slot allocation
-        refused even with nothing in flight: the shared splitting
-        scaffolding recursively halves until allocations fit, running each
-        piece submit→collect serially.  A piece with more long rows than
-        ITS operand holds (a half has half the room) goes back through
-        _consume_via_pipeline, which cuts it to fit."""
-        from banjax_tpu.matcher.fused_windows import PipelineOverflow
-
-        def make(pre_c):
-            def apply_fn(work_c, slots, row_hashes, ts_s, ts_ns, host_idx,
-                         results_c):
-                dw = self.device_windows
-                if len(self._fused_chunks(pre_c)) > 1:
-                    dw.release_pins(slots)
-                    self._consume_via_pipeline(work_c, pre_c, results_c)
-                    return
-                try:
-                    pend = self._fw_pipeline.submit(
-                        pre_c[0], pre_c[1], slots, ts_s, ts_ns, host_idx,
-                        long_rows=self._long_rows_of(work_c, pre_c[3]),
-                        row_hashes=row_hashes,
-                    )
-                except Exception:
-                    dw.release_pins(slots)
-                    raise
-                e = {
-                    "work": work_c, "pre": pre_c,
-                    "slots": slots, "ts_s": ts_s, "ts_ns": ts_ns,
-                    "host_idx": host_idx, "pend": pend,
-                }
-                try:
-                    res = self._fw_pipeline.collect(pend)
-                except PipelineOverflow as ov:
-                    self._pipeline_fallback_entry(e, ov, results_c)
-                    return
-                sparse = (res.matched_pairs, res.always_bits)
-                self._replay_window_events(
-                    work_c, None, sparse, res.events, results_c
-                )
-
-            def split(lo, hi):
-                return make(_rows_of(pre_c, slice(lo, hi)))
-
-            return split, apply_fn
-
-        self._with_window_slots(work, *make(pre), results)
 
     def _log_hottest_bucket(self) -> None:
         """Beside a candidates overflow: which factor bucket hit most
@@ -2448,6 +2257,15 @@ class TpuMatcher(Matcher):
 _MATCH_TYPES = tuple(RateLimitMatchType)
 
 
+class _ChunkFailed(Exception):
+    """A fused chunk's settle failed (the cause is chained); `chunk` is
+    its entry.  See TpuMatcher._finish_batch."""
+
+    def __init__(self, chunk: dict):
+        super().__init__("fused chunk failed at its settle")
+        self.chunk = chunk
+
+
 class _LineResultsFill:
     """The per-line results one replayed chunk owes (see
     TpuMatcher._replay_window_events): built from the chunk's arrays when
@@ -2501,6 +2319,15 @@ class _LineResultsFill:
                     ),
                 )
             results.owed(i).append(rr)
+
+
+def _mark_old(work, stale, results) -> None:
+    """Rows `stale` (bool [n]) of a work batch aged out in the pipeline:
+    old_line, and nothing else owed."""
+    for i in np.asarray(work.orig_rows())[stale].tolist():
+        r = results[i]
+        r.old_line = True
+        r.rule_results = []
 
 
 def _bucket(n: int, cap: int) -> int:

@@ -960,6 +960,29 @@ class DeviceWindows:
                 self._place_locked(res)
         return res
 
+    def probe_addresses(
+        self,
+        ips: Sequence[str],
+        counts: Optional[np.ndarray] = None,
+        min_estimate: int = 1,
+        sketch=None,
+    ) -> Resolution:
+        """The pass's probe alone: the slot-admission gate's verdict over
+        a batch's distinct addresses, nothing placed whatever it is —
+        for a batch whose placement is not one call (its chunks place
+        their own rows; the classic replay places at the drain).  An
+        address admitted on the sketch's word waits in `_sketch_pending`
+        for that placement, as after admission_mask."""
+        if self._sm is None:
+            return self._probe_dict(ips, counts, min_estimate, sketch, True)
+        with self._lock:
+            res = self._probe_locked(ips, counts, min_estimate, sketch, True)
+            if res._sketch_admitted is not None:
+                self._sketch_pending.update(
+                    ips[i] for i in res._sketch_admitted.tolist()
+                )
+        return res
+
     def place_resolved(self, res: Resolution) -> None:
         """The placement a resolve_addresses with refused addresses left
         open (after the caller applied the refused rows)."""
@@ -975,6 +998,12 @@ class DeviceWindows:
         """resolve_addresses without the native manager: today's
         per-step calls (admission_mask, then the dict loop), which are
         also what the native pass is compared with."""
+        res = self._probe_dict(ips, counts, min_estimate, sketch, gate)
+        if not len(res.refused):
+            self._place_dict(res)
+        return res
+
+    def _probe_dict(self, ips, counts, min_estimate, sketch, gate):
         n = len(ips)
         admit = np.ones(n, dtype=bool)
         hashes = None
@@ -994,13 +1023,10 @@ class DeviceWindows:
                 with self._lock:
                     self.gate_derived_batches += 1
         refused = np.flatnonzero(~admit)
-        res = Resolution(
+        return Resolution(
             ips=ips, admit=admit, refused=refused,
             refused_hashes=None if hashes is None else hashes[refused],
         )
-        if not len(refused):
-            self._place_dict(res)
-        return res
 
     def _place_dict(self, res: Resolution) -> None:
         res.placed = True

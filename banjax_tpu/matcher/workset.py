@@ -171,10 +171,13 @@ class LazyResults:
 
     def absorb(self, other: "LazyResults", row0: int) -> None:
         """Take over what was written to `other`'s rows, at row offset
-        `row0` (the sharded-encode merge step); untouched rows stay
-        untouched.  A shard of clean traffic writes nothing during the
-        gate — the counter makes that common case O(1) instead of a
-        scan."""
+        `row0` (the sharded-encode merge step; a finished batch of a
+        synchronous call of several), and the fills it is still owed;
+        untouched rows stay untouched.  A shard of clean traffic writes
+        nothing during the gate — the counter makes that common case
+        O(1) instead of a scan."""
+        self._deferred += [_Rebased(fill, row0) for fill in other._deferred]
+        other._deferred = []
         if other._n_set == 0:
             return
         dst = self._items
@@ -183,6 +186,23 @@ class LazyResults:
                 if dst[row0 + i] is None:
                     self._n_set += 1
                 dst[row0 + i] = row
+
+
+class _Rebased:
+    """A fill absorbed from another LazyResults: it runs against this
+    one's rows, `row0` further on."""
+
+    __slots__ = ("fill", "row0", "res")
+
+    def __init__(self, fill, row0: int):
+        self.fill, self.row0 = fill, row0
+
+    def __call__(self, res) -> None:
+        self.res = res
+        self.fill(self)
+
+    def owed(self, i: int) -> list:
+        return self.res.owed(self.row0 + i)
 
 
 class LazyLine:

@@ -204,7 +204,8 @@ FAMILIES: List[Family] = [
            "addresses, and misses not in the shadow, once each)",
            prom="banjax_submit_resolve_probes_total", labels=("table",)),
     Family(COUNTER, "passes over a batch's distinct addresses (the submit "
-           "stage's resolve, the sync entry's slot call), by the form the "
+           "stage's resolve, a chunk's or the classic replay's slot "
+           "call), by the form the "
            "addresses came in and were worked on: spans (the parse blob's "
            "bytes, merged by bytes; no string made of an address) or "
            "strings (encoded for the pass; the dict path's pass)",

@@ -487,10 +487,6 @@ class PipelineStats:
         by the scheduler when the batch carried any read stamp."""
         self.e2e_hists.observe(hop, max(0.0, seconds))
 
-    def device_p99_s(self) -> Optional[float]:
-        with self._lock:
-            return self._device_p99_ewma
-
     def suggested_latency_budget_s(self) -> float:
         """Derived breaker budget: 3x the EWMA device p99, floored at
         50 ms (ROADMAP breaker-tuning item).  0.0 until a p99 exists —

@@ -1,9 +1,12 @@
 """Streaming pipeline scheduler: overlapped tailer→device→effector batching.
 
-consume_lines is a synchronous submit→wait→collect loop, and the fixed
-device→host latency is only hidden when overlapped with compute.  This
-module is the continuous-batching scheduler that closes that gap — the
-inference-serving pattern (SURVEY §7.2 M5) applied to log classification.
+A batch goes through the matcher in four stages (begin, submit, collect,
+finish: matcher/runner.py).  The synchronous consume_lines calls them in
+turn on one thread, a batch at a time, and the fixed device→host latency
+is only hidden when overlapped with compute.  This module is the other
+caller of the same four: the continuous-batching scheduler that closes
+that gap — the inference-serving pattern (SURVEY §7.2 M5) applied to log
+classification.
 
 Stages, one thread each::
 
@@ -40,8 +43,10 @@ nothing and replays through the classic bitmap protocol at its drain
 turn; batches the fused path cannot take (host-evaluated rules, a
 refused slot allocation, a failed scan selftest) ride the classic
 protocol end to end, with the staleness cut at drain.  Generic drains
-use consume_lines_serial so an inline fused burst can't deadlock against
-in-flight fused order turns.
+use consume_lines_serial — the same four stages on the drain thread with
+the fused path off: a fused chunk dispatched there would wait on order
+turns that later batches, already submitted, hold until this very drain
+is done.
 
 Kafka commands: submit_commands() admits command messages into the SAME
 buffer as tailer lines — shared bounded-block/oldest-first-shed
@@ -767,11 +772,10 @@ class PipelineScheduler:
                         # generic path: full consume_lines semantics,
                         # including the breaker's CPU-reference fallback —
                         # never a loss.  consume_lines_serial (when the
-                        # matcher has it) keeps the fused single-dispatch
-                        # burst out of the drain thread: its order turns
-                        # belong to the fused pipeline and an inline
-                        # burst here would deadlock behind in-flight later
-                        # batches.
+                        # matcher has it) keeps fused dispatches out of
+                        # the drain thread: their order turns belong to
+                        # the fused pipeline and one taken here would
+                        # deadlock behind in-flight later batches.
                         sp.note("fallback", "generic-drain")
                         consume = getattr(
                             batch.matcher, "consume_lines_serial", None

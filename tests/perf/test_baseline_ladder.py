@@ -224,9 +224,10 @@ def test_config3_1k_rules_batch():
 
 
 def test_config4_fused_ua_path_100k_ips():
-    """Config 4: fused UA+path matching with 100k distinct client IPs
+    """Config 4: UA+path matching with 100k distinct client IPs
     (CI-scaled to 20k) and device windows on — the eviction-pressure
-    scenario of VERDICT weak #7."""
+    scenario of VERDICT weak #7.  The UA lists are decided on the host
+    (decisions/ua_lists.py; tests/unit/test_ua_lists.py)."""
     ua_yaml = DEFAULT_RULESET + """
 global_user_agent_decision_lists:
   challenge:
@@ -257,19 +258,6 @@ global_user_agent_decision_lists:
     if n_ips > m.device_windows.AUTO_START_CAPACITY:
         assert m.device_windows.grow_count > 0
         assert m.device_windows.capacity >= n_ips
-    # the fused ruleset side: UA patterns ride the same device pass
-    from banjax_tpu.decisions.ua_lists import build_ua_rules, check_ua_decision
-    from banjax_tpu.matcher.fused import DeviceUAMatcher
-
-    rules = build_ua_rules({
-        "challenge": ["Mozilla/4\\.[0-9]", "scanner"],
-        "nginx_block": ["sqlmap|nikto"],
-    })
-    dm = DeviceUAMatcher(rules)
-    uas = [l.split(" HTTP/1.1 ")[1].rsplit(" | ", 1)[0] for l in lines[:2048]]
-    got = dm.check_batch(uas)
-    want = [check_ua_decision(rules, ua) for ua in uas]
-    assert got == want
 
 
 def test_staleness_budget_under_sustained_load():

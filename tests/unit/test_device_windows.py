@@ -804,8 +804,8 @@ def test_a_wait_for_the_windows_lock_is_counted_under_the_waiters_stage(stage):
     """`DeviceWindows._lock` times only an acquire that finds it held, and
     books the wait under the stage the waiting thread said it runs
     (`trace.stage_thread`): the drain holds it for 50 ms, the submit
-    stage's thread asks meanwhile — one contention and 40-60 ms under
-    `submit`, nothing elsewhere; a waiting thread that declared no stage
+    stage's thread asks meanwhile — one contention and 40 ms or more
+    under `submit`, nothing elsewhere; a waiting thread that declared no stage
     is not counted, and an acquire that finds the lock free reads no
     clock."""
     import threading
@@ -846,8 +846,7 @@ def test_a_wait_for_the_windows_lock_is_counted_under_the_waiters_stage(stage):
     if stage is None:
         assert got["submit"] == (0.0, 0)
         return
+    # no upper limit: how long a sleep of 50 ms lasts is the machine's
     seconds, contentions = got["submit"]
-    assert contentions == 1 and 0.040 <= seconds <= 0.060
+    assert contentions == 1 and seconds >= 0.040
     assert seconds <= waited[0]
-    # the operator's reading: the mean wait of one contention
-    assert seconds / contentions == pytest.approx(0.05, abs=0.01)

@@ -211,7 +211,13 @@ def test_candidate_overflow_flag_with_tight_slot_capacity():
     )
     assert tpu.describe()["fused_protocol"] == "single-kernel"
     want = [cpu.consume_line(l, now + 1) for l in lines]
-    got = tpu.consume_lines(lines, now + 1)
+    # in pieces the 16 slots can hold: a batch with more distinct
+    # addresses than the table has slots dispatches nothing fused (its
+    # placement is refused and the classic replay halves it)
+    got = [
+        r for i in range(0, len(lines), 16)
+        for r in tpu.consume_lines(lines[i : i + 16], now + 1)
+    ]
     assert [_key(a) for a in want] == [_key(b) for b in got]
     assert cb.bans == tb.bans
     assert tpu._fw_pipeline.fallback_batches > 0
