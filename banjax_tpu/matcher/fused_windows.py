@@ -167,6 +167,7 @@ class FusedWindowsPipeline:
         # dispatched successor WITHOUT a host round-trip; None = seed the
         # next submit with a fresh ok (no poisoned chunk outstanding)
         self._chain_ok = None
+        self._chain_seed = jnp.int32(1)  # made once, never donated
         self.fused_batches = 0      # chunks committed by the fused program
         self.fallback_batches = 0   # routed to the classic fallback
         self.sk_d2h_bytes_total = 0  # the one-pull d2h witness
@@ -232,6 +233,8 @@ class FusedWindowsPipeline:
             skip_table=self.skip_table, KL=KL, sketch=self._traffic_sketch,
         )
         progs[key] = hit
+        # ... and the programs of a maintenance run its chunks cannot carry
+        self.windows.build_maintenance_steps(Bp)
         return hit
 
     # ---- host API (submit → resolve → collect, each in chunk order) ----
@@ -257,9 +260,9 @@ class FusedWindowsPipeline:
         under, in this dispatch — every real row, live or not, whatever
         the program's gate says; not read without a sketch.  The dispatch
         runs under the windows
-        lock: maintenance (evictions/restores) drains first, and the
-        state-chain order == seq order because both are taken inside the
-        same critical section."""
+        lock: the table's queued maintenance (evictions, then restores)
+        goes in with it as operands, and the state-chain order == seq
+        order because both are taken inside the same critical section."""
         pf = self.pf
         lap = trace.lap()  # the caller's `operands` phase runs on
         cls_ids = np.asarray(cls_ids, dtype=np.int32)
@@ -272,8 +275,7 @@ class FusedWindowsPipeline:
             combined, Bp, L_p = pf._assemble(
                 cls_ids, lens, self._progs_long, full_width=True)
             KL = longrows.operands(pf, Bp)
-            long_op = tuple(map(jnp.asarray, longrows.assemble(
-                pf, KL, long_rows, pad_row=Bp)))
+            long_op = longrows.assemble(pf, KL, long_rows, pad_row=Bp)
         else:
             combined, Bp, L_p = pf._assemble(cls_ids, lens, self._progs)
 
@@ -304,30 +306,42 @@ class FusedWindowsPipeline:
                     self._chain_ok = None
                 self._next_seq += 1
                 chain = self._chain_ok
+            # the table's queued evictions and restores ride this
+            # dispatch as two operands (what does not fit them runs here)
             lap.mark("maintenance")
-            wnd._run_maintenance_locked()
+            maintenance = wnd._run_maintenance_locked(carry_rows=Bp)
             lap.mark("dispatch")
+            # every operand goes in as it is, a numpy array or scalar:
+            # the call transfers them itself, and the chunk is ONE trip
+            # through the runtime
             operands = (
-                chain if chain is not None else jnp.int32(1),
-                jnp.asarray(combined), jnp.int32(B),
-                jnp.asarray(host_idx_p), jnp.asarray(slots_p),
-                jnp.asarray(ts_s_p), jnp.asarray(ts_ns_p),
-                jnp.asarray(live_p),
+                self._chain_seed if chain is None else chain,
+                combined, np.int32(B), host_idx_p, slots_p, ts_s_p, ts_ns_p,
+                live_p, *maintenance,
             )
-            if sk is None:
-                out = fn(wnd._state, *operands, *long_op)
-            else:
-                # the sketch's state lock inside the windows lock, held
-                # across the dispatch that donates both states; the
-                # hashes go in as they are (the call transfers them
-                # itself: no trip through the runtime of their own)
-                def run(sketch_state):
-                    *out, sketch_state = fn(
-                        wnd._state, sketch_state, *operands, hashes_p,
-                        *long_op)
-                    return sketch_state, out
+            try:
+                if sk is None:
+                    out = fn(wnd._state, *operands, *long_op)
+                else:
+                    # the sketch's state lock inside the windows lock,
+                    # held across the dispatch that donates both states
+                    def run(sketch_state):
+                        *out, sketch_state = fn(
+                            wnd._state, sketch_state, *operands, hashes_p,
+                            *long_op)
+                        return sketch_state, out
 
-                out = sk.dispatch_fold(run, B, "fused")
+                    out = sk.dispatch_fold(run, B, "fused")
+            except BaseException:
+                # nothing carried the table's maintenance: it is not lost
+                # with the chunk — and nobody will settle the chunk's turn
+                ev_slots, restore_rows = maintenance
+                wnd._maintenance_steps_locked(ev_slots, restore_rows[None])
+                with self._cv:
+                    self._dead.add(seq)
+                    self._sweep_locked(self._turn)
+                raise
+            trace.runtime_calls()
             wnd._state, chain_out, buf, bits_dev = out
             with self._cv:
                 self._chain_ok = chain_out
@@ -341,7 +355,8 @@ class FusedWindowsPipeline:
             # the whole h2d for the chunk: encoded classes + per-row
             # window metadata + the live mask + the chain scalar (+ the
             # rows' hashes for the sketch) — still no dense [B, n_rules]
-            # bitmap
+            # bitmap.  The table's maintenance operands are not the
+            # chunk's: no path counts them, carried or dispatched alone
             h2d_bytes=combined.nbytes + 4 * 3 * Bp + Bp + 4
             + sum(x.nbytes for x in long_op)
             + (0 if sk is None else hashes_p.nbytes),

@@ -35,9 +35,9 @@ buffer whose async copy started at submit.
 
 Ordering: the state commit happens at submit, and submits are already
 serialized (one device thread, chunks in admission order, under the
-windows lock, which also drains eviction maintenance first), so device
-apply order == log order by construction, with no host turn between a
-chunk's match and its commit.
+windows lock; the window table's queued evictions and restores ride the
+same program, at its head), so device apply order == log order by
+construction, with no host turn between a chunk's match and its commit.
 The overflow hazard of committing at submit — chunk N
 overflows, its classic re-apply would land AFTER an already-dispatched
 chunk N+1 — is closed DEVICE-SIDE by the chain scalar: every kernel
@@ -219,16 +219,26 @@ def build_single_program(
 
     Returns (fn, K, P, E) where
       fn(state, chain_ok, combined, n_real, host_idx, slots, ts_s,
-         ts_ns, live) -> (new_state, chain_ok_out, buf, bits_dev)
+         ts_ns, live, ev_slots, restore_rows)
+         -> (new_state, chain_ok_out, buf, bits_dev)
     with `state` donated (the HBM-resident window arrays mutate in
-    place) and `buf` the single uint8 pull.  With `KL` (longrows.operands'
+    place) and `buf` the single uint8 pull.  `ev_slots` s32[Bp] and
+    `restore_rows` s32[5, windows._restore_room(Bp)] are the window table's
+    queued maintenance (DeviceWindows._run_maintenance_locked hands them
+    over, all padding when nothing is queued): the program runs
+    windows._evict and then windows._restore on them at its head (the
+    restore rows past the first chunk under a `cond`: only where a key
+    lies there), outside the overflow / chain gate — a chunk that commits nothing still
+    evicts and restores, as when the two were dispatches of their own in
+    front of it.  With `KL` (longrows.operands'
     pairs) `fn` takes one more argument for each, last: the chunk's long
     rows as longrows.assemble lays them out (prefilter._match_core scans
     and merges them), and nothing else of the program or its output
     changes.  With `sketch` (obs/sketch.py TrafficSketch) the program
     carries the chunk's traffic-sketch fold as well:
       fn(state, sketch_state, chain_ok, combined, n_real, host_idx, slots,
-         ts_s, ts_ns, live, row_hashes, *long operands)
+         ts_s, ts_ns, live, ev_slots, restore_rows, row_hashes,
+         *long operands)
          -> (new_state, chain_ok_out, buf, bits_dev, new_sketch_state)
     with `sketch_state` = (cm, hll) donated like the window state and
     `row_hashes` u32[Bp], a row's address hash.  The fold is
@@ -268,7 +278,21 @@ def build_single_program(
     active_table = jnp.asarray(active_table)
 
     def match_and_commit(state, chain_ok, combined, n_real, host_idx, slots,
-                         ts_s, ts_ns, live, *long_ops):
+                         ts_s, ts_ns, live, ev_slots, restore_rows,
+                         *long_ops):
+        with jax.named_scope("window-maintenance"):
+            # the restore rows fill from the front: past the first chunk
+            # the scatters run only where a key lies there, so a chunk
+            # that restores a few addresses pays for one chunk's padding
+            # and not for its whole room
+            kr = W._RESTORE_CHUNK
+            state = W._restore(W._evict(state, ev_slots), restore_rows[:, :kr])
+            if restore_rows.shape[1] > kr:
+                rest = restore_rows[:, kr:]
+                state = jax.lax.cond(
+                    (rest[0] < state.slot_gen.shape[0]).any(),
+                    lambda st: W._restore(st, rest), lambda st: st, state,
+                )
         c = core(combined, *long_ops)
         keep = None
         if site_mask is not None:
@@ -368,9 +392,11 @@ def build_single_program(
 
     @functools.partial(jax.jit, donate_argnums=(0, 1))
     def single(state, sketch_state, chain_ok, combined, n_real, host_idx,
-               slots, ts_s, ts_ns, live, row_hashes, *long_ops):
+               slots, ts_s, ts_ns, live, ev_slots, restore_rows, row_hashes,
+               *long_ops):
         out = match_and_commit(state, chain_ok, combined, n_real, host_idx,
-                               slots, ts_s, ts_ns, live, *long_ops)
+                               slots, ts_s, ts_ns, live, ev_slots,
+                               restore_rows, *long_ops)
         with jax.named_scope("sketch-fold"):
             return out + (sketch.fold(*sketch_state, row_hashes, n_real),)
 

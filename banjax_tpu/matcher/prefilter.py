@@ -50,6 +50,7 @@ from banjax_tpu.matcher.rulec import (
     pack_programs,
     required_factors,
 )
+from banjax_tpu.obs import trace
 
 log = logging.getLogger(__name__)
 
@@ -1046,6 +1047,7 @@ class FusedPrefilter:
         combined, Bp, L_p = self._assemble(cls_ids, lens, self._fns)
         fn, K, P = self._fused(Bp, L_p)
         buf = fn(jnp.asarray(combined))
+        trace.runtime_calls(2)  # the transfer, the dispatch
         try:
             buf.copy_to_host_async()
         except AttributeError:  # interpret/CPU arrays may lack the method

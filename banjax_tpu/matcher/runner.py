@@ -2189,6 +2189,7 @@ class TpuMatcher(Matcher):
                 packed = nfa_jax.match_batch_packed(
                     self._params, pad_cls, pad_len, self.compiled.n_rules
                 )
+            trace.runtime_calls()
             chunks.append((rows, packed))
         return chunks
 

@@ -356,8 +356,19 @@ def _apply_step(state, bits, active_table, host_idx, slot_ids, ts_s, ts_ns,
 _RESTORE_CHUNK = 1024
 
 
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _evict_step(state: DeviceWindowState, ev_slots: jnp.ndarray):
+def _restore_room(carry_rows: int) -> int:
+    """Restored keys a fused chunk of `carry_rows` rows carries beside its
+    evictions: a key a row, in whole restore chunks and one at least.  A
+    batch that brings 750 addresses back restores 1.4 counters an address
+    where every line is a window event (`default.flood`: three runs in
+    five were past one chunk, PR 49), so one chunk's room is too little
+    for the batch the pipeline runs at, and a key a row is what a batch
+    cannot reach by its addresses alone.  The program runs the scatters
+    past the first chunk only where a key lies there."""
+    return _RESTORE_CHUNK * max(1, carry_rows // _RESTORE_CHUNK)
+
+
+def _evict(state: DeviceWindowState, ev_slots: jnp.ndarray):
     """Evict K slots ([K] int32, cap = none): two [K] scatters — the
     generation bump invalidates every rule's key of a slot without
     touching one of them; nothing here is sized by n_rules.  A slot
@@ -370,15 +381,14 @@ def _evict_step(state: DeviceWindowState, ev_slots: jnp.ndarray):
     )
 
 
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _restore_step(state: DeviceWindowState, rows: jnp.ndarray):
+def _restore(state: DeviceWindowState, rows: jnp.ndarray):
     """Restore Kr keys: five [Kr] scatters.  `rows` is [5, Kr] int32 —
     each key's slot (cap = none), flat key (cap * n_rules = none), hits,
     start_s, start_ns — one operand, so one host-to-device transfer.
-    Dispatched AFTER the evict step of the same maintenance run: a slot
-    can be evicted and at once reassigned and restored between two apply
-    steps, and the restored keys are stamped with the generation after
-    the bump."""
+    Run AFTER the evictions of the same maintenance run: a slot can be
+    evicted and at once reassigned and restored between two apply steps,
+    and the restored keys are stamped with the generation after the
+    bump."""
     r_slots, r_keys, r_hits, r_ss, r_sns = rows
     cap = state.slot_gen.shape[0]
     r_gen = state.slot_gen[jnp.minimum(r_slots, cap - 1)]
@@ -390,6 +400,13 @@ def _restore_step(state: DeviceWindowState, rows: jnp.ndarray):
         key_gen=state.key_gen.at[r_keys].set(r_gen, mode="drop"),
         ip_seen=state.ip_seen.at[r_slots].set(True, mode="drop"),
     )
+
+
+# a maintenance run's two steps have two carriers: a fused chunk's program
+# traces `_evict` and `_restore` at its head (kernels/fused_match_window.py)
+# and every other caller dispatches them as the programs below
+_evict_step = jax.jit(_evict, donate_argnums=(0,))
+_restore_step = jax.jit(_restore, donate_argnums=(0,))
 
 
 jax.tree_util.register_dataclass(
@@ -679,10 +696,12 @@ class DeviceWindows:
         # eviction only costs performance (a restore on re-admission), never
         # correctness; this counter surfaces the capacity pressure
         self.eviction_count = 0
-        # maintenance dispatches, and the int32 elements handed to the
-        # device by them (padding included): elems / evictions says whether
-        # the step stayed O(evicted slots) — a few, not a multiple of n_rules
-        self.maintenance_steps = 0
+        # maintenance runs, by what carried their steps to the device — a
+        # fused chunk's program or dispatches of their own — and the int32
+        # elements handed to the device by them (padding included): elems
+        # / evictions says whether the step stayed O(evicted slots) — a
+        # few, not a multiple of n_rules
+        self.maintenance_carried = {"fused": 0, "own": 0}
         self.maintenance_elems = 0
         # window events committed by device applies (fused or classic)
         self.device_events = 0
@@ -1685,43 +1704,121 @@ class DeviceWindows:
             taken += 1
         self.shadow_records["absorb"]["dict"] += taken
 
-    def _run_maintenance_locked(self) -> None:
+    def _run_maintenance_locked(self, carry_rows: Optional[int] = None):
         """Drain queued evictions, then restores, into the device state
         (caller holds the lock).  What goes to the device is two int32 per
         evicted slot and five per restored key — never a per-(slot, rule)
-        expansion.  Evicted slots are padded to a power of two (five
-        classes up to a 4,096-line batch), restored keys go in chunks of
-        _RESTORE_CHUNK (one program), so the jit cache stays bounded;
-        padded entries scatter out of range and drop."""
-        if not self._pending_evict and not self._pending_restore:
-            return
-        pend_ev = self._pending_evict
-        pend_rs = self._pending_restore
-        self._pending_evict = []
-        self._pending_restore = []
-        self.maintenance_steps += 1
-        if pend_ev:
-            ks = _bucket(len(pend_ev), _MIN_MAINT_BUCKET)
-            self.maintenance_elems += 2 * ks
-            ev_slots = np.full((ks,), self.capacity, dtype=np.int32)
-            ev_slots[: len(pend_ev)] = pend_ev
-            self._state = _evict_step(self._state, jnp.asarray(ev_slots))
-        for rows in self._restore_rows_locked(pend_rs):
-            self.maintenance_elems += rows.size
-            self._state = _restore_step(self._state, jnp.asarray(rows))
+        expansion; padded entries scatter out of range and drop.
 
-    def _restore_rows_locked(self, pending) -> np.ndarray:
-        """int32 [c, 5, _RESTORE_CHUNK]: the counters of the queued
-        restores that are still live, as `_restore_step` takes them —
-        (slot, flat key, hits, start_s, start_ns) a column, padded out
-        of range.  Read NOW, at the maintenance step, not when the
+        Two carriers, chosen by what the caller has.  A caller about to
+        dispatch a fused chunk of `carry_rows` rows gets the queued work
+        back as that program's two maintenance operands — (ev_slots int32
+        [carry_rows], restore rows int32 [5, _restore_room(carry_rows)]),
+        all padding when nothing is queued — and the program runs `_evict`
+        and `_restore` on them at its head, outside its commit gate: no
+        dispatch and no transfer of their own.  Every other caller (the
+        classic apply, a test) gets them run here, as `_evict_step` over
+        the evicted slots padded to a power of two (five classes up to a
+        4,096-line batch) and `_restore_step` over the restored keys in
+        chunks of _RESTORE_CHUNK (one program), so the jit cache stays
+        bounded.  So does a run that does not fit a chunk's operands —
+        more evicted slots than its rows, more live keys than its room —
+        whole, in front of the chunk's dispatch, which then carries
+        padding."""
+        queued = bool(self._pending_evict or self._pending_restore)
+        if not queued and carry_rows is None:
+            return None
+        room = _RESTORE_CHUNK if carry_rows is None else _restore_room(
+            carry_rows)
+        pend_ev, self._pending_evict = self._pending_evict, []
+        rows = self._restore_rows_locked(self._pending_restore, room)
+        self._pending_restore = []
+        rides = (
+            carry_rows is not None and len(pend_ev) <= carry_rows
+            and len(rows) <= 1
+        )
+        if queued:
+            self.maintenance_carried["fused" if rides else "own"] += 1
+            if rides:
+                self.maintenance_elems += 2 * carry_rows + 5 * room
+        if not rides:
+            ks = _bucket(len(pend_ev), _MIN_MAINT_BUCKET) if pend_ev else 0
+            self._maintenance_steps_locked(self._ev_slots(pend_ev, ks), rows)
+            pend_ev, rows = [], rows[:0]
+        if carry_rows is None:
+            return None
+        return self._ev_slots(pend_ev, carry_rows), (
+            rows[0] if len(rows) else self._no_restore(room))
+
+    def _ev_slots(self, evicted, size: int) -> np.ndarray:
+        """`evicted` slots as an evict operand of `size`, padded with the
+        slot that is none."""
+        out = np.full((size,), self.capacity, dtype=np.int32)
+        out[: len(evicted)] = evicted
+        return out
+
+    def _no_restore(self, room: int) -> np.ndarray:
+        """Restore rows for `room` keys that restore nothing: all padding."""
+        rows = np.zeros((5, room), dtype=np.int32)
+        rows[0] = self.capacity
+        rows[1] = self.capacity * self.n_rules
+        return rows
+
+    def _maintenance_steps_locked(self, ev_slots: np.ndarray, rows) -> None:
+        """The evict step over `ev_slots` (none when empty), then a
+        restore step for each _RESTORE_CHUNK keys of `rows` ([c, 5, whole
+        chunks]; a chunk that is all padding is left out), as dispatches
+        of their own (caller holds the lock): a maintenance run without a
+        fused chunk to carry it — or one whose chunk's dispatch failed
+        before it ran."""
+        if len(ev_slots):
+            self.maintenance_elems += 2 * len(ev_slots)
+            self._state = _evict_step(self._state, jnp.asarray(ev_slots))
+            trace.runtime_calls(2)  # the transfer, the step
+        parts = rows.reshape(
+            len(rows), 5, rows.shape[2] // _RESTORE_CHUNK, _RESTORE_CHUNK
+        ).swapaxes(1, 2).reshape(-1, 5, _RESTORE_CHUNK)
+        for part in parts[(parts[:, 0] < self.capacity).any(axis=1)]:
+            self.maintenance_elems += part.size
+            self._state = _restore_step(self._state, jnp.asarray(part))
+            trace.runtime_calls(2)
+
+    def build_maintenance_steps(self, max_rows: int) -> None:
+        """Build the separate steps' programs a run of up to `max_rows`
+        evicted slots dispatches — every evict class up to it, the one
+        restore chunk — by running each on padding, which leaves the table
+        as it was.  For whoever builds a fused program of that many rows:
+        with the fused carrier the separate steps are the rare way, and
+        the first run that takes it must not find its programs unbuilt
+        minutes into a stream."""
+        ks = _MIN_MAINT_BUCKET
+        sizes = [ks]
+        while ks < max_rows:
+            ks <<= 1
+            sizes.append(ks)
+        with self._lock:
+            for ks in sizes:
+                self._state = _evict_step(self._state, self._ev_slots((), ks))
+            self._state = _restore_step(
+                self._state, self._no_restore(_RESTORE_CHUNK))
+        trace.runtime_calls(len(sizes) + 1)
+
+    @property
+    def maintenance_steps(self) -> int:
+        """Maintenance runs that had work, whatever carried them."""
+        return sum(self.maintenance_carried.values())
+
+    def _restore_rows_locked(self, pending, kr: int) -> np.ndarray:
+        """int32 [c, 5, kr]: the counters of the queued restores that are
+        still live, as `_restore` takes them — (slot, flat key, hits,
+        start_s, start_ns) a column, `kr` keys a chunk, padded out of
+        range.  Read NOW, at the maintenance step, not when the
         restore was queued: an earlier in-flight chunk's absorb may have
         landed in between.  A restore is stale once its slot was
         re-evicted (and possibly reassigned to a DIFFERENT address) —
         scattering the old address's counters would resurrect them into
         the new owner's rows: the dict form knows by the slot's owner,
         the mirror by the record's stamp."""
-        kr = _RESTORE_CHUNK
         cap_r = self.capacity * self.n_rules
         if self._mirror is not None:
             if not pending:

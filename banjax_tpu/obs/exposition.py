@@ -234,6 +234,11 @@ def render_prometheus(
         fam = registry.PROM_FAMILIES["banjax_window_events_total"]
         w.sample(fam, dw.site_events, {"scope": "site"})
         w.sample(fam, dw.device_events - dw.site_events, {"scope": "global"})
+    if dw is not None and hasattr(dw, "maintenance_carried"):
+        fam = registry.PROM_FAMILIES[
+            "banjax_device_windows_maintenance_steps_by_carrier_total"]
+        for carrier, v in dw.maintenance_carried.items():
+            w.sample(fam, v, {"carrier": carrier})
     if dw is not None and hasattr(dw, "shadow_records"):
         fam = registry.PROM_FAMILIES["banjax_shadow_records_total"]
         for op, by_path in dw.shadow_records.items():
@@ -272,6 +277,8 @@ def render_prometheus(
             w.sample(fam, round(seconds, 6), {"phase": phase})
         w.sample(registry.PROM_FAMILIES["banjax_submit_cpu_seconds_total"],
                  round(cpu_s, 6))
+        w.sample(registry.PROM_FAMILIES["banjax_submit_runtime_calls_total"],
+                 pipeline.submit_runtime_calls())
         fam = registry.PROM_FAMILIES[
             "banjax_pipeline_batch_target_changes_total"]
         for direction, n in pipeline.batch_target_changes().items():

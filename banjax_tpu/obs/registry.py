@@ -123,10 +123,19 @@ FAMILIES: List[Family] = [
            prom="banjax_device_windows_evictions_total"),
     Family(GAUGE, "evictions in this reporting interval (line-only delta)",
            line_key="DeviceWindowsEvictionsPerInterval"),
-    Family(COUNTER, "device window maintenance dispatches (evictions and "
-           "restores drained into the device state)",
+    Family(COUNTER, "device window maintenance runs (queued evictions "
+           "and restores drained into the device state), whatever carried "
+           "them",
            line_key="DeviceWindowsMaintenanceSteps",
            prom="banjax_device_windows_maintenance_steps_total"),
+    Family(COUNTER, "device window maintenance runs by what carried "
+           "their evict and restore steps to the device: fused (two "
+           "operands of a fused chunk's program — no dispatch and no "
+           "transfer of their own) or own (dispatches of their own: the "
+           "classic apply, a run past one chunk's operands); the two sum "
+           "to banjax_device_windows_maintenance_steps_total",
+           prom="banjax_device_windows_maintenance_steps_by_carrier_total",
+           labels=("carrier",)),
     Family(COUNTER, "int32 elements handed to the device by maintenance "
            "dispatches, padding included (divide by evictions: single "
            "digits while the step is O(evicted slots))",
@@ -245,6 +254,12 @@ FAMILIES: List[Family] = [
            "thread waited — for the interpreter, a lock, the device, a "
            "core",
            prom="banjax_submit_cpu_seconds_total"),
+    Family(COUNTER, "calls into the device runtime made by the submitting "
+           "thread inside the submit stage: every dispatch of a program "
+           "and every explicit host-to-device transfer, counted where it "
+           "is made (one a fused chunk; each gives the interpreter up and "
+           "queues for it again)",
+           prom="banjax_submit_runtime_calls_total"),
     Family(COUNTER, "seconds the pipeline's stages waited for the device "
            "windows' lock when it was held (an acquire that finds it free "
            "is not timed), by the stage the waiting thread runs: submit "

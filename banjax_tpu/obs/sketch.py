@@ -368,6 +368,7 @@ class TrafficSketch:
         if len(h) != Bp:
             h = np.concatenate([h, np.zeros(Bp - len(h), dtype=np.uint32)])
         n_real = min(int(n_real), Bp)
+        trace.runtime_calls()
         self.dispatch_fold(
             lambda state: (self._standalone(state, h, np.int32(n_real)), None),
             n_real, "standalone",

@@ -45,7 +45,9 @@ scheduler's `batch.t0_device` and the end of `pipeline_submit` into
 SUBMIT_PHASES, with the seconds its thread ran beside the seconds that
 passed.  The sums are exported whether tracing is on or off
 (`banjax_submit_phase_seconds_total{phase}`,
-`banjax_submit_cpu_seconds_total`); with tracing on
+`banjax_submit_cpu_seconds_total`), and so is the count of the calls the
+stage's thread made into the device runtime
+(`banjax_submit_runtime_calls_total`); with tracing on
 each named phase is also a `submit-<phase>` child span of the batch's
 `submit` span, in the ring and through the JAX bridge.
 """
@@ -379,9 +381,12 @@ class LapClock:
     a system call, 17 us under load on a sandboxed kernel, and the
     stage's thread sets the pipeline's rate.  Wall less cpu is the time
     the thread did not run (waiting for the interpreter, a lock, the
-    device, a core).  `wall` and `cpu_s` are sums over every batch so
-    far, written by the clock's thread alone and read by whoever exports
-    them.
+    device, a core).  And the calls the thread made into the device
+    runtime, `runtime_calls`: every dispatch of a program and every
+    explicit transfer, counted where it is made (`runtime_calls()`
+    below) — each gives the interpreter up and queues for it again.
+    `wall`, `cpu_s` and `runtime_calls` are sums over every batch so far,
+    written by the clock's thread alone and read by whoever exports them.
 
     A mark costs one clock read and one attribute check, and allocates
     nothing; it makes a span only between `under(parent)` and the end of
@@ -390,12 +395,13 @@ class LapClock:
     next mark — before a context-manager span opened before it exits, so
     that the JAX bridge's annotations nest."""
 
-    __slots__ = ("wall", "cpu_s", "phase", "t", "_c", "_rows", "_row0",
-                 "_parent", "_jax_ctx")
+    __slots__ = ("wall", "cpu_s", "runtime_calls", "phase", "t", "_c",
+                 "_rows", "_row0", "_parent", "_jax_ctx")
 
     def __init__(self):
         self.wall = dict.fromkeys(SUBMIT_PHASES, 0.0)
         self.cpu_s = 0.0
+        self.runtime_calls = 0
         self._parent = NOOP_SPAN
         self._jax_ctx = None
         self.start()
@@ -482,6 +488,14 @@ def lap() -> LapClock:
     except AttributeError:
         clock = _this_thread.clock = LapClock()
         return clock
+
+
+def runtime_calls(n: int = 1) -> None:
+    """This thread made `n` calls into the device runtime (a program's
+    dispatch, an explicit host-to-device transfer): counted on its lap
+    clock, so the submit stage's are `banjax_submit_runtime_calls_total`
+    and any other thread's are its own affair."""
+    lap().runtime_calls += n
 
 
 # ---- process-wide tracer -------------------------------------------------

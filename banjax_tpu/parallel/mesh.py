@@ -487,6 +487,7 @@ class ShardedMatchBackend:
         return hit
 
     def _dispatch(self, fn, params, cls_dev, lens_dev):
+        trace.runtime_calls(3)  # two transfers, the dispatch
         if self.backend == "xla":
             return fn(params, jnp.asarray(cls_dev), jnp.asarray(lens_dev))
         cls_t = np.ascontiguousarray(cls_dev.T)

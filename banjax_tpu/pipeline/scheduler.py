@@ -909,6 +909,11 @@ class PipelineScheduler:
         ran of all of it."""
         return dict(self._lap.wall), self._lap.cpu_s
 
+    def submit_runtime_calls(self) -> int:
+        """Calls into the device runtime the device thread made inside
+        the submit stage, over every batch so far (obs/trace.py)."""
+        return self._lap.runtime_calls
+
     def collector_stats(self) -> dict:
         """The cyclic collector since start(): collections, seconds
         paused and objects freed, each a list by generation, and the

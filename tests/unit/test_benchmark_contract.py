@@ -665,6 +665,11 @@ _SUBMIT_FAMILIES = {
         [{"thread": t} for t in _PIPELINE_THREADS],
     "banjax_pipeline_batch_target_changes_total":
         [{"direction": "up"}, {"direction": "down"}],
+    # one call into the runtime a chunk, the window table's maintenance
+    # riding it (ISSUE 49)
+    "banjax_submit_runtime_calls_total": [{}],
+    "banjax_device_windows_maintenance_steps_by_carrier_total":
+        [{"carrier": c} for c in ("fused", "own")],
     # the cyclic collector under the pipeline (ISSUE 40)
     "banjax_gc_collections_total": [{"generation": g} for g in "012"],
     "banjax_gc_pause_seconds_total": [{"generation": g} for g in "012"],
@@ -695,6 +700,15 @@ def test_submit_stage_family_is_on_metrics_with_its_labels(
         assert 0 < prom.value(snap, family) <= wall + 1e-3
     if family == "banjax_thread_cpu_seconds_total":
         assert prom.value(snap, family, thread="pipeline-device") > 0
+    if family == "banjax_submit_runtime_calls_total":
+        # six batches, each one fused chunk: one call a batch, and one a
+        # separate step run on padding beside each program built
+        before = submit_scrapes[0]
+        batches = prom.delta(before, snap, "banjax_pipeline_batches_total")
+        assert batches <= prom.delta(before, snap, family) <= batches + 8
+    if family.endswith("_steps_by_carrier_total"):
+        assert prom.value(snap, family) == prom.value(
+            snap, "banjax_device_windows_maintenance_steps_total")
     if family == "banjax_gc_collections_total":
         # the freeze at the first matcher collects the heap whole first
         assert prom.value(snap, family, generation="2") >= 1
@@ -790,6 +804,50 @@ def test_submit_stage_reader_reads_its_family(submit_scrapes, name):
     real0, real1, seconds = submit_scrapes
     got = reader.read({"prom0": real0, "prom1": real1, "seconds": seconds})
     assert got is not None and got >= 0
+
+
+_CALLS = "banjax_submit_runtime_calls_total"
+_BY_CARRIER = "banjax_device_windows_maintenance_steps_by_carrier_total"
+_CALL_READERS = {
+    # reader: (its entry in BENCHMARK.json less the name, the reading off
+    #          the synthetic pair below)
+    "submit_runtime_calls_per_batch": (
+        {"unit": "count/batch", "better": "lower", "layer": "matcher"}, 1.2),
+    "maintenance_fused_share": (
+        {"unit": "%", "better": "higher",
+         "layer": "device windows and tiers"}, 90.0),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_CALL_READERS))
+def test_runtime_call_reader_reads_its_family(submit_scrapes, name):
+    """ISSUE 49's two readers: listed for every cell, read their families
+    off two synthetic scrapes (60 calls over 50 batches; 90 maintenance
+    runs carried fused of 100) and off a real pair, and are silent on a
+    program without the counter and over an idle window."""
+    from benchmark.harness import found
+
+    entry, want = _CALL_READERS[name]
+    assert [m for m in found.benchmark_json()["per_layer"]
+            if m["name"] == name] == [{
+                "name": name, **entry, "source": "program_counter",
+                "moves": "lines_per_s"}]
+    fused = (_BY_CARRIER, (("carrier", "fused"),))
+    own = (_BY_CARRIER, (("carrier", "own"),))
+    batches = ("banjax_pipeline_batches_total", ())
+    p0 = {(_CALLS, ()): 100.0, batches: 10.0, fused: 8.0, own: 2.0}
+    p1 = {(_CALLS, ()): 160.0, batches: 60.0, fused: 98.0, own: 12.0}
+    reader = found.module("layers", name)
+    assert reader.read({"prom0": p0, "prom1": p1}) == pytest.approx(want)
+    assert reader.read({"prom0": {}, "prom1": {}}) is None
+    assert reader.read({"prom0": p1, "prom1": p1}) is None
+    real0, real1, _ = submit_scrapes
+    got = reader.read({"prom0": real0, "prom1": real1})
+    if name == "submit_runtime_calls_per_batch":
+        assert 1.0 <= got <= 2.5
+    else:
+        # 600 addresses through 256 slots: every run rode its chunk
+        assert got == 100.0
 
 
 def test_stage2_readers_split_a_trace_by_nfa_words():

@@ -83,9 +83,13 @@ class _Program:
             )
         return self._progs[key]
 
-    def run(self, rests, slots, ts_ns, host_idx=None, live=None):
+    def run(self, rests, slots, ts_ns, host_idx=None, live=None,
+            maintenance=None):
         """→ (flags, K, P, E).  Asserts the whole parity and, where the
-        program committed, carries its state on to the next batch."""
+        program committed, carries its state on to the next batch.
+        `maintenance` = (evicted slots, restore rows [5, _restore_room(Bp)]):
+        the program carries them as operands, the reference runs the
+        separate steps in front of its apply."""
         B = len(rests)
         cls_ids, lens = self.encode(rests)
         combined, Bp, L_p = self.pf._assemble(cls_ids, lens)
@@ -102,11 +106,27 @@ class _Program:
         args = (pad(host_idx), pad(slots), pad(ts_s), pad(ts_n))
         live_p = pad(live, np.uint8)
         copy = lambda: jax.tree_util.tree_map(jnp.array, self.state)  # noqa: E731
-        before = jax.tree_util.tree_map(np.asarray, self.state)
+        # nothing is queued on this table: all padding
+        ev_slots, restore_rows = self.dw._run_maintenance_locked(
+            carry_rows=Bp)
+        if maintenance is not None:
+            ev_slots[: len(maintenance[0])] = maintenance[0]
+            restore_rows = maintenance[1]
+
+        def start():
+            """The state the separate steps leave in front of the chunk."""
+            if maintenance is None:
+                return copy()
+            return W._restore_step(
+                W._evict_step(copy(), jnp.asarray(ev_slots)),
+                jnp.asarray(restore_rows))
+
+        before = jax.tree_util.tree_map(np.asarray, start())
 
         new_state, chain, buf, bits_dev = fn(
             copy(), jnp.int32(1), jnp.asarray(combined), jnp.int32(B),
-            args[0], args[1], args[2], args[3], live_p,
+            args[0], args[1], args[2], args[3], live_p, ev_slots,
+            restore_rows,
         )
         buf = np.asarray(buf)
         bits = np.asarray(bits_dev)
@@ -122,7 +142,8 @@ class _Program:
         assert flags[1] > K or np.array_equal(bits, want_bits)
 
         if not flags[0]:
-            # an overflow commits nothing at all
+            # an overflow commits nothing at all (the table's
+            # maintenance is no part of the chunk's commit: it applies)
             after = jax.tree_util.tree_map(np.asarray, new_state)
             for f in ("hits", "start_s", "start_ns", "key_gen", "slot_gen",
                       "ip_seen"):
@@ -131,7 +152,7 @@ class _Program:
             return flags, K, P, E
 
         ref_state, out = W._apply_step(
-            copy(), jnp.asarray(bits * np.asarray(live_p)[:, None]),
+            start(), jnp.asarray(bits * np.asarray(live_p)[:, None]),
             jnp.asarray(self.active), *args,
             self.dw._limits, self.dw._iv_s, self.dw._iv_ns,
             n_rules=self.R, max_events=E,
@@ -226,18 +247,18 @@ def test_fused_events_equal_the_dense_extraction(name):
         t0 = int(ts[-1]) + 1
         host_idx = rng.integers(0, prog.active.shape[0], n).astype(np.int32)
         live = (rng.random(n) < kw.get("live_rate", 1.0)).astype(np.uint8)
+        maintenance = None
         if kw.get("evict") and step:
             # a slot loses its keys and gets some back, as the maintenance
-            # step does between two batches
+            # run queues them between two batches
             s = int(slots[0])
-            prog.state = W._evict_step(
-                prog.state, jnp.asarray([s], jnp.int32))
             rows = np.full((5, W._RESTORE_CHUNK), -1, np.int32)
             rows[0, :] = prog.dw.capacity
             rows[1, :] = prog.dw.capacity * prog.R
             rows[:, 0] = (s, s * prog.R + 1, 2, int(t0 // 10**9) - 1, 5)
-            prog.state = W._restore_step(prog.state, jnp.asarray(rows))
-        flags, K, P, E = prog.run(rests, slots, ts, host_idx, live)
+            maintenance = ([s], rows)
+        flags, K, P, E = prog.run(rests, slots, ts, host_idx, live,
+                                  maintenance)
         assert flags[0] == 1
         n_events += int(flags[3])
     assert n_events > 0

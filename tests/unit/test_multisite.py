@@ -182,7 +182,10 @@ def test_the_cell_is_the_issues():
         "sketch_fused_share",
         # the address pass's form and the victims' walk (ISSUE 45): every
         # cell
-        "resolve_spans_share", "eviction_scan_slots_per_kline"])
+        "resolve_spans_share", "eviction_scan_slots_per_kline",
+        # one call into the runtime a chunk, the table's maintenance
+        # riding it (ISSUE 49): every cell
+        "submit_runtime_calls_per_batch", "maintenance_fused_share"])
     pc = CONFIG["product_config"]
     assert {k: v for k, v in pc.items() if k != "config_version"} == {
         k: v for k, v in found.data("configs", "upstream-stress10k")[

@@ -314,10 +314,11 @@ def test_sketch_off_builds_and_dispatches_the_program_without_operands(
         assert off._fw_pipeline.fused_batches == 2
         with_sk = [n for sk, ns in built if sk is not None for n in ns]
         without = [n for sk, ns in built if sk is None for n in ns]
-        # state, chain, combined, n_real, host, slots, ts_s, ts_ns, live
-        assert without and set(without) == {9}
+        # state, chain, combined, n_real, host, slots, ts_s, ts_ns, live,
+        # the window table's evicted slots and restore rows
+        assert without and set(without) == {11}
         # ... and the sketch's state and the rows' hashes
-        assert with_sk and set(with_sk) == {11}
+        assert with_sk and set(with_sk) == {13}
         assert "banjax_sketch_updates_total{" not in _metrics(off)
     finally:
         on.close(), off.close()

@@ -205,6 +205,14 @@ def _stress_state(one_chip):
     )
 
 
+def _maintenance_operands(sds):
+    """A full chunk's evicted slots and its room of restore rows, as the
+    fused program takes them (windows._run_maintenance_locked)."""
+    from banjax_tpu.matcher import windows as W
+
+    return sds((B,), jnp.int32), sds((5, W._restore_room(B)), jnp.int32)
+
+
 def _fits_with_the_table_aliased(compiled):
     m = compiled.memory_analysis()
     table = 16 * STRESS_SLOTS * STRESS_RULES
@@ -251,7 +259,7 @@ def stress_single(one_chip, stress_prefilter):
         state, sketch_state, sds((), jnp.int32),
         sds((B, 1 + L_P // 4), jnp.int32),
         sds((), jnp.int32), vec, vec, vec, vec, sds((B,), jnp.uint8),
-        sds((B,), jnp.uint32),
+        *_maintenance_operands(sds), sds((B,), jnp.uint32),
     ).compile()
 
 
@@ -261,7 +269,12 @@ def test_stress10k_single_program_holds_the_table_in_place(
     """The window table is an argument aliased to the program's output."""
     # the (row, rule) pair encoding is int32: rows x packed rule columns
     assert B * stress_prefilter._nf8 * 8 < 2**31 // 50
-    assert stress_single.as_text().count("tpu_custom_call") >= 3
+    text = stress_single.as_text()
+    assert text.count("tpu_custom_call") >= 3
+    # the table's evictions and restores at the program's head (PR 49)
+    assert "window-maintenance" in text
+    # ... restoring past the first 1,024 keys only where a key lies there
+    assert " conditional(" in text
     m = _fits_with_the_table_aliased(stress_single)
     assert m.temp_size_in_bytes < 1e9
     # the sketch's state (count-min 4 x 8,192 and 4,096 HLL registers,
@@ -384,6 +397,7 @@ def multisite_single(one_chip):
     return pf, fn.lower(
         state, sds((), jnp.int32), sds((B, 1 + L_P // 4), jnp.int32),
         sds((), jnp.int32), vec, vec, vec, vec, sds((B,), jnp.uint8),
+        *_maintenance_operands(sds),
     ).compile()
 
 
