@@ -100,6 +100,11 @@ class IPFilter:
             except ValueError:
                 continue
 
+    def __bool__(self) -> bool:
+        """Whether any entry was taken: a filter built from an empty (or
+        wholly unparseable) list allows nothing."""
+        return bool(self._slow_singles or self._slow_networks)
+
     def allowed(self, ip_string: str) -> bool:
         parsed = _fast_parse_ip(ip_string)
         if parsed is None:
@@ -244,17 +249,20 @@ class StaticDecisionLists:
     def has_any_allow_entries(self) -> bool:
         """True when ANY allow source exists (exact or CIDR, global or any
         site). When False, check_is_allowed is False for every input — the
-        matcher gate skips its per-distinct-(host, ip) loop entirely."""
+        matcher gate skips its per-distinct-(host, ip) loop entirely.  A
+        filter with no entry is no source: the shipped configuration
+        spells its lists out empty (`allow: []`), and the global filters
+        are built for every key that is there."""
         c = self._snapshot
         if any(d == Decision.ALLOW for d in c.global_decision_lists.values()):
             return True
-        if Decision.ALLOW in c.global_ip_filters:
+        if c.global_ip_filters.get(Decision.ALLOW):
             return True
         for site_map in c.per_site_decision_lists.values():
             if any(d == Decision.ALLOW for d in site_map.values()):
                 return True
         for filters in c.per_site_ip_filters.values():
-            if Decision.ALLOW in filters:
+            if filters.get(Decision.ALLOW):
                 return True
         return False
 

@@ -59,7 +59,9 @@ from banjax_tpu.matcher.workset import (
     LazyResults,
     ListWork,
     NativeWork,
-    unique_spans,
+    SpanStrings,
+    StringCount,
+    decode_spans,
 )
 from banjax_tpu.matcher.rulec import compile_rules
 from banjax_tpu.matcher.rulecache import RuleCache
@@ -129,6 +131,13 @@ class TpuMatcher(Matcher):
         self.long_lines = 0
         self.long_line_bytes = 0
         self.unfused_batches = {"line_length": 0, "non_ascii": 0}
+        # encode shards (an unsharded batch is one) by what gated them:
+        # the one native call, or the per-line Python loop (no library,
+        # a line with a newline in it) — added up where the encode thread
+        # merges the shards; and the address strings made from spans
+        # since (workset.SpanStrings), whoever asked
+        self.gate_shards = {"native": 0, "python": 0}
+        self._address_strings = StringCount()
         # host wall seconds inside the drain's `effector-replay` spans
         # (event decode + shadow absorb + Banner replay of committed chunks)
         self.effector_replay_s = 0.0
@@ -689,7 +698,7 @@ class TpuMatcher(Matcher):
     def effective_latency_budget_s(self) -> float:
         """The breaker's per-batch latency budget: the configured
         `matcher_latency_budget_ms` when set, else the pipeline-derived
-        value (3x EWMA device p99, floor 50 ms) when a scheduler has
+        value (3x EWMA device p99, floor 1 s) when a scheduler has
         installed a source, else 0 (budget check disabled)."""
         if self._latency_budget_s:
             return self._latency_budget_s
@@ -764,6 +773,11 @@ class TpuMatcher(Matcher):
                 f"breaker {state}; batches on CPU reference matcher",
             )
 
+    @property
+    def gate_address_strings(self) -> int:
+        """Address strings made from gated batches' spans so far."""
+        return self._address_strings.n
+
     def _gate(self, lines, now, results, use_scratch=True,
               parse_threads=None):
         """Step 1: host parse + allowlist exemption
@@ -775,7 +789,9 @@ class TpuMatcher(Matcher):
         path.  `use_scratch=False` (the pipeline split path) allocates
         fresh parse/dedup buffers: with batches in flight concurrently,
         batch N's work set must not alias buffers batch N+1's parse
-        reuses."""
+        reuses.  → (work, pre_encoded, path): `path` says what gated the
+        lines — "native" (the one call) or "python" (the per-line loop
+        below) — for the caller to count (gate_shards)."""
         pre_encoded = None
         nb = None
         if self._native:
@@ -807,7 +823,7 @@ class TpuMatcher(Matcher):
                     continue
                 lw.append((i, p))
             work = lw
-        return work, pre_encoded
+        return work, pre_encoded, "python" if nb is None else "native"
 
     def _consume_lines_inner(
         self, lines: Sequence[str], now_unix: Optional[float] = None,
@@ -905,9 +921,10 @@ class TpuMatcher(Matcher):
         a caller that runs each batch to its end before the next begins
         (the synchronous entry) may reuse the matcher's."""
         results = LazyResults(len(lines))
-        work, pre_encoded = self._gate(
+        work, pre_encoded, path = self._gate(
             lines, now, results, use_scratch=use_scratch
         )
+        self.gate_shards[path] += 1
         return self._pipeline_state(lines, results, work, pre_encoded)
 
     def encode_shard(self, lines: Sequence[str], now: float):
@@ -916,19 +933,20 @@ class TpuMatcher(Matcher):
         run concurrently on the scheduler's worker pool — the native
         parse and the columnar gate are GIL-free/thread-safe).  Returned
         indices are LOCAL to the shard; pipeline_begin_from_shards
-        rebases them."""
+        rebases them, and counts the shard by the `path` it returns
+        (no counter is touched on the pool's threads)."""
         results = LazyResults(len(lines))
-        work, pre_encoded = self._gate(
+        work, pre_encoded, path = self._gate(
             lines, now, results, use_scratch=False, parse_threads=1
         )
-        return work, pre_encoded, results
+        return work, pre_encoded, results, path
 
     def pipeline_begin_from_shards(
         self, lines: Sequence[str], now: float, shards
     ) -> dict:
         """Merge encode_shard outputs back into the exact state
         pipeline_begin would have produced single-threaded.  `shards` is
-        [(row0, (work, pre, results)), ...] in row order, covering
+        [(row0, encode_shard's output), ...] in row order, covering
         `lines` exactly.  The merge is strict line order end to end:
         results rows rebase by row0, work sets concatenate positionally
         (workset.CompositeWork), the encoded arrays concatenate row-wise,
@@ -939,7 +957,8 @@ class TpuMatcher(Matcher):
         results = LazyResults(len(lines))
         parts, offsets, pres = [], [], []
         native_pre = True
-        for row0, (work, pre, shard_results) in shards:
+        for row0, (work, pre, shard_results, path) in shards:
+            self.gate_shards[path] += 1
             results.absorb(shard_results, row0)
             if not len(work):
                 continue
@@ -1575,111 +1594,48 @@ class TpuMatcher(Matcher):
         self._replay_window_events(work, bits, None, events, results)
 
     def _native_gate(self, nb, lines, now, results, use_scratch=True):
-        """Vectorized step 1 over a native ParsedBatch: flag masks, unique
-        ip/host tables (workset.unique_spans), allowlist per DISTINCT
-        (host, ip) with a snapshot-keyed cache, and a columnar NativeWork.
-        Semantics identical to the per-line reference loop; cost is
-        O(distinct strings + matched rows), not O(lines)."""
+        """Step 1 over a native ParsedBatch: ONE call into C (native.gate:
+        the candidate rows, the first-appearance tables of their
+        addresses and hosts as spans of the blob, the per-row columns),
+        then only what is rare — a deferred row's Python parse patched
+        in, an error or old row's mark, the allowlist per DISTINCT (host,
+        ip) with a snapshot-keyed cache where the lists have any allow
+        entry — and a columnar NativeWork.  No string is made of an
+        address here (workset.SpanStrings makes one when asked).
+        Semantics identical to the per-line reference loop."""
         from banjax_tpu import native
 
-        dedup_scratch = self._dedup_scratch if use_scratch else None
-
         n = nb.n
-        flags = np.asarray(nb.flags[:n])
-        err = (flags & native.FLAG_ERROR) != 0
-        old = (flags & native.FLAG_OLD) != 0
-        ts = nb.ts_ns[:n].astype(np.int64, copy=True)
-
+        g = native.gate(nb, self._dedup_scratch if use_scratch else None)
+        if g is None:  # an empty batch
+            return ListWork(), None
+        flags = nb.flags
         defer_map: Dict[int, ParsedLine] = {}
-        for r in np.flatnonzero(flags & native.FLAG_DEFER):
-            r = int(r)
-            p = parse_line(lines[r], now, OLD_LINE_CUTOFF_SECONDS)
-            defer_map[r] = p
-            err[r] = p.error
-            old[r] = p.old_line
-            if not p.error:
-                # Python float()*1e9 can exceed int64 (the columnar array
-                # feeding the device windows); clamp HERE only — replay and
-                # the host window path read the exact Python int from the
-                # deferred ParsedLine itself
-                ts[r] = min(max(p.timestamp_ns, -(2**63)), 2**63 - 1)
+        span_buf = nb.blob
+        ips_u = SpanStrings(
+            span_buf, g.ip_off, g.ip_len, self._address_strings
+        )
+        hosts_u = decode_spans(span_buf, g.host_off, g.host_len)
+        err_rows = old_rows = ()
+        if g.n_defer:
+            defer_map, err_rows, old_rows, ips_u, span_buf = \
+                self._patch_deferred(nb, g, lines, now, ips_u, hosts_u)
+        else:
+            if g.n_err:
+                err_rows = np.flatnonzero(flags & native.FLAG_ERROR).tolist()
+            if g.n_old:
+                old_rows = np.flatnonzero(flags & native.FLAG_OLD).tolist()
+        for r in err_rows:
+            log.warning("could not parse log line: %r", lines[r])
+            results[r].error = True
+        for r in old_rows:
+            results[r].old_line = True
 
-        for r in np.flatnonzero(err):
-            log.warning("could not parse log line: %r", lines[int(r)])
-            results[int(r)].error = True
-        for r in np.flatnonzero(old & ~err):
-            results[int(r)].old_line = True
-
-        cand = np.flatnonzero(~err & ~old)
+        cand = g.rows
         if cand.size == 0:
             return ListWork(), None
-
-        # distinct ip/host string tables over the candidate rows; deferred
-        # rows have no blob spans — patch their strings in via the tables
-        dset = set(defer_map)
-        vrows = np.asarray(
-            [r for r in cand if int(r) not in dset], dtype=np.int64
-        ) if dset else cand
-        text = nb.text()
-        ip_off, ip_len = nb.ip_off[vrows], nb.ip_len[vrows]
-        ips_u, ip_inv_v, ip_first = unique_spans(
-            ip_off, ip_len, lambda k: nb.ip(int(vrows[k])),
-            blob=nb.blob, text=text, dedup_scratch=dedup_scratch,
-        )
-        hosts_u, host_inv_v, _ = unique_spans(
-            nb.host_off[vrows], nb.host_len[vrows],
-            lambda k: nb.host(int(vrows[k])),
-            blob=nb.blob, text=text, dedup_scratch=dedup_scratch,
-        )
-        # the distinct addresses' key bytes where the parse blob holds
-        # them: what the submit stage's pass over them works on
-        span_buf = nb.blob
-        span_off = ip_off[ip_first].astype(np.int64)
-        span_len = ip_len[ip_first].astype(np.int64)
-        ip_inv = np.empty(cand.size, dtype=np.int64)
-        host_inv = np.empty(cand.size, dtype=np.int64)
-        if dset:
-            # vectorized membership/positions (cand is sorted): a python
-            # per-element loop here would cost O(lines) whenever ANY row
-            # deferred
-            # sorted so deferred rows append to the unique tables in LINE
-            # order (first-appearance contract), not set hash order
-            darr = np.sort(np.fromiter(dset, dtype=np.int64))
-            vmask = ~np.isin(cand, darr)
-            ip_inv[vmask] = ip_inv_v
-            host_inv[vmask] = host_inv_v
-            iidx = {s: j for j, s in enumerate(ips_u)}
-            hidx = {s: j for j, s in enumerate(hosts_u)}
-            patched: List[bytes] = []  # a Python-parsed address's bytes
-            for r in darr.tolist():
-                p = defer_map[r]
-                # position of r in cand, or absent (errored/old defer rows)
-                k = int(np.searchsorted(cand, r))
-                if k >= cand.size or cand[k] != r:
-                    continue
-                j = iidx.get(p.ip)
-                if j is None:
-                    j = len(ips_u)
-                    ips_u.append(p.ip)
-                    iidx[p.ip] = j
-                    patched.append(p.ip.encode("utf-8", "surrogatepass"))
-                ip_inv[k] = j
-                j = hidx.get(p.host)
-                if j is None:
-                    j = len(hosts_u)
-                    hosts_u.append(p.host)
-                    hidx[p.host] = j
-                host_inv[k] = j
-            if patched:
-                # ... lie behind the blob in a copy of it
-                lens_p = np.fromiter(map(len, patched), np.int64, len(patched))
-                offs_p = len(span_buf) + np.cumsum(lens_p) - lens_p
-                span_buf = b"".join([span_buf, *patched])
-                span_off = np.concatenate([span_off, offs_p])
-                span_len = np.concatenate([span_len, lens_p])
-        else:
-            ip_inv[:] = ip_inv_v
-            host_inv[:] = host_inv_v
+        ip_inv, host_inv, ts = g.ip_inv, g.host_inv, g.ts
+        host_eval, long_len = g.host_eval, g.long_len
 
         # allowlist per distinct (host, ip) pair, cached across batches
         # until the static-lists generation bumps (hot reload) — the CIDR
@@ -1697,7 +1653,9 @@ class TpuMatcher(Matcher):
         has_allow = getattr(
             self.decision_lists, "has_any_allow_entries", lambda: True
         )()
+        rows = cand
         if has_allow:
+            ips_u = list(ips_u)  # the check takes strings
             n_ip = max(1, len(ips_u))
             pair = host_inv * n_ip + ip_inv
             upair, upair_inv = np.unique(pair, return_inverse=True)
@@ -1713,23 +1671,20 @@ class TpuMatcher(Matcher):
                     cache[(h, ip)] = v
                 allowed_u[j] = v
             allowed = allowed_u[upair_inv]
-            for k in np.flatnonzero(allowed):
-                results[int(cand[k])].exempted = True
-            keep = ~allowed
-            rows = cand[keep]
-        else:
-            # no allow entries anywhere: nothing can be exempted
-            keep = slice(None)
-            rows = cand
-        if rows.size == 0:
-            return ListWork(), None
+            if allowed.any():
+                for k in np.flatnonzero(allowed):
+                    results[int(cand[k])].exempted = True
+                keep = ~allowed
+                rows = cand[keep]
+                if rows.size == 0:
+                    return ListWork(), None
+                ip_inv, host_inv, ts = ip_inv[keep], host_inv[keep], ts[keep]
+                host_eval, long_len = host_eval[keep], long_len[keep]
         work = NativeWork(
-            nb, rows, ips_u, ip_inv[keep], hosts_u, host_inv[keep],
-            ts[rows], defer_map,
-            (np.frombuffer(span_buf, dtype=np.uint8), span_off, span_len),
+            nb, rows, ips_u, ip_inv, hosts_u, host_inv, ts, defer_map,
+            (np.frombuffer(span_buf, dtype=np.uint8), g.ip_off, g.ip_len),
         )
 
-        deferred = (flags[rows] & native.FLAG_DEFER) != 0
         if rows.size == n:
             # nothing filtered (the common clean-traffic batch): views,
             # not 33 MB gather copies of the class matrix
@@ -1738,31 +1693,103 @@ class TpuMatcher(Matcher):
         else:
             cls_ids = nb.cls_ids[rows]
             lens = nb.lens[rows]
-        host_eval = (flags[rows] & native.FLAG_HOST_EVAL) != 0
-        long_len = None
-        if host_eval.any():
-            # beside host_eval, as longrows.long_lens has it: a LONG row's
-            # length, -1 for a row past LONG_WIDTH, 0 for every other row
-            rest_len = nb.rest_len[:n][rows]
-            long_len = np.where(
-                (flags[rows] & native.FLAG_LONG) != 0, rest_len,
-                np.where(host_eval & (rest_len > LONG_WIDTH), -1, 0),
-            ).astype(np.int32)
-        if deferred.any():
-            # deferred rows were Python-parsed: encode them the Python way
-            # into the same arrays
-            d_idx = np.flatnonzero(deferred)
-            d_cls, d_lens, d_he, d_long = self._encode_work(
-                [work[int(k)] for k in d_idx]
-            )
-            cls_ids[d_idx] = d_cls
-            lens[d_idx] = d_lens
-            host_eval[d_idx] = d_he
-            if d_long is not None:
-                if long_len is None:
-                    long_len = np.zeros(len(lens), dtype=np.int32)
-                long_len[d_idx] = d_long
+        if not g.n_host_eval or (rows is not cand and not host_eval.any()):
+            # as longrows.long_lens has it: None where the dense matrix
+            # holds every row
+            long_len = None
+        if defer_map:
+            deferred = (flags[rows] & native.FLAG_DEFER) != 0
+            if deferred.any():
+                # deferred rows were Python-parsed: encode them the Python
+                # way into the same arrays
+                d_idx = np.flatnonzero(deferred)
+                d_cls, d_lens, d_he, d_long = self._encode_work(
+                    [work[int(k)] for k in d_idx]
+                )
+                cls_ids[d_idx] = d_cls
+                lens[d_idx] = d_lens
+                host_eval[d_idx] = d_he
+                if d_long is not None:
+                    if long_len is None:
+                        long_len = np.zeros(len(lens), dtype=np.int32)
+                    long_len[d_idx] = d_long
         return work, (cls_ids, lens, host_eval, long_len)
+
+    def _patch_deferred(self, nb, g, lines, now, ips_u, hosts_u):
+        """The rows the C parse deferred (FLAG_DEFER: a timestamp whose
+        text Python's float() may read differently), parsed the Python
+        way and patched into the gate's columns `g` where they are
+        candidates: they take their places among the rows in LINE order
+        and append to the distinct tables in line order (the first-
+        appearance contract) — `hosts_u` in place, the addresses to a
+        list made of `ips_u`.  → (defer_map, error rows, old rows, the
+        address table, the buffer its spans lie in).  Rare by
+        construction; what it costs is the strings of the batch's
+        distinct addresses, made here."""
+        from banjax_tpu import native
+
+        flags = nb.flags
+        err = (flags & native.FLAG_ERROR) != 0
+        old = (flags & native.FLAG_OLD) != 0
+        defer_map: Dict[int, ParsedLine] = {}
+        live: List[int] = []  # deferred rows that are candidates
+        for r in np.flatnonzero(flags & native.FLAG_DEFER).tolist():
+            p = parse_line(lines[r], now, OLD_LINE_CUTOFF_SECONDS)
+            defer_map[r] = p
+            err[r] = p.error
+            old[r] = p.old_line
+            if not (p.error or p.old_line):
+                live.append(r)
+        err_rows = np.flatnonzero(err).tolist()
+        old_rows = np.flatnonzero(old & ~err).tolist()
+        span_buf = nb.blob
+        if not live:
+            return defer_map, err_rows, old_rows, ips_u, span_buf
+
+        cand = np.flatnonzero(~err & ~old)
+        vmask = ~np.isin(cand, np.asarray(live, dtype=np.int64))
+        at = np.flatnonzero(~vmask).tolist()  # where the live rows go
+
+        def widened(col):
+            out = np.zeros(cand.size, dtype=col.dtype)
+            out[vmask] = col
+            return out
+
+        ts, ip_inv, host_inv = (
+            widened(g.ts), widened(g.ip_inv), widened(g.host_inv)
+        )
+        ips_u = list(ips_u)
+        iidx = {s: j for j, s in enumerate(ips_u)}
+        hidx = {s: j for j, s in enumerate(hosts_u)}
+        patched: List[bytes] = []  # a Python-parsed address's bytes
+        for k, r in zip(at, live):
+            p = defer_map[r]
+            # Python float()*1e9 can exceed int64 (the columnar array
+            # feeding the device windows); clamp HERE only — replay and
+            # the host window path read the exact Python int from the
+            # deferred ParsedLine itself
+            ts[k] = min(max(p.timestamp_ns, -(2**63)), 2**63 - 1)
+            j = iidx.get(p.ip)
+            if j is None:
+                j = iidx[p.ip] = len(ips_u)
+                ips_u.append(p.ip)
+                patched.append(p.ip.encode("utf-8", "surrogatepass"))
+            ip_inv[k] = j
+            j = hidx.get(p.host)
+            if j is None:
+                j = hidx[p.host] = len(hosts_u)
+                hosts_u.append(p.host)
+            host_inv[k] = j
+        g.host_eval, g.long_len = widened(g.host_eval), widened(g.long_len)
+        g.rows, g.ts, g.ip_inv, g.host_inv = cand, ts, ip_inv, host_inv
+        if patched:
+            # ... lie behind the blob in a copy of it
+            lens_p = np.fromiter(map(len, patched), np.int64, len(patched))
+            offs_p = len(span_buf) + np.cumsum(lens_p) - lens_p
+            span_buf = b"".join([span_buf, *patched])
+            g.ip_off = np.concatenate([g.ip_off, offs_p])
+            g.ip_len = np.concatenate([g.ip_len, lens_p])
+        return defer_map, err_rows, old_rows, ips_u, span_buf
 
     def _with_window_slots(self, work, split, apply_fn, results) -> None:
         """Shared scaffolding for every device-windows consume path: slot

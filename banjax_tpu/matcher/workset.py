@@ -6,10 +6,12 @@ builds a Python object + several strings per line, which at 65k-line
 batches costs ~300 ms — far more than the device match itself (the r3
 end-to-end wall). This module keeps the batch COLUMNAR end to end:
 
-  * `NativeWork` holds numpy row indices + unique-string tables from the
-    native parse (banjax_tpu/native). Per-row Python objects materialize
-    lazily, only for rows something actually touches — matched rows, ban
-    logging, error paths — which is a few percent of traffic.
+  * `NativeWork` holds numpy row indices + the distinct-address and
+    distinct-host tables from the native gate (banjax_tpu/native).  The
+    addresses stay byte spans of the parse blob (`SpanStrings`): a string
+    is made of one when something asks for it.  Per-row Python objects
+    materialize lazily, only for rows something actually touches —
+    matched rows, ban logging, error paths — a few percent of traffic.
   * `ListWork` wraps the per-line-parsed fallback path (no native lib,
     deferred timestamps) in the same interface, so every consumer
     (window-slot scaffolding, the fused pipeline, replay) is agnostic.
@@ -26,8 +28,9 @@ The interface both provide:
 from __future__ import annotations
 
 import itertools
+import operator
 from collections import OrderedDict
-from typing import Dict, List, Tuple
+from typing import Dict, List, Sequence, Tuple
 
 import numpy as np
 
@@ -205,6 +208,83 @@ class _Rebased:
         return self.res.owed(self.row0 + i)
 
 
+class StringCount:
+    """How many strings the SpanStrings of one matcher have made
+    (banjax_gate_address_strings_total).  Plain adds, no lock: in a
+    running pipeline one thread reads them (the drain, `lines_at`)."""
+
+    __slots__ = ("n",)
+
+    def __init__(self):
+        self.n = 0
+
+
+def _decode_span(blob: bytes, off: int, length: int) -> str:
+    # a parse blob is made with surrogatepass: any line's str comes back
+    return blob[off : off + length].decode("utf-8", "surrogatepass")
+
+
+def decode_spans(blob: bytes, offs: np.ndarray, lens: np.ndarray) -> List[str]:
+    """The strings of (offset, length) spans of a parse blob."""
+    return [
+        _decode_span(blob, o, n) for o, n in zip(offs.tolist(), lens.tolist())
+    ]
+
+
+class SpanStrings:
+    """A gated batch's distinct addresses as the list of `str` they used
+    to be, made on demand: `len`, an index (negative too), a slice (a
+    list), iteration and `==` against a list give what the eager list
+    gave, and each string is decoded from its span of `blob` when it is
+    asked for — the rows that exceeded a limit, the dict path, the
+    allowlist; the hot path reads the spans (NativeWork.ip_spans).
+
+    `blob` is the batch's immutable bytes and `offs` / `lens` are this
+    sequence's own (the gate's output, never a parse scratch), so a
+    string read after the matcher's scratch served another batch is
+    still this batch's.  `count` is told of every string made."""
+
+    __slots__ = ("_blob", "_offs", "_lens", "_count")
+
+    def __init__(self, blob: bytes, offs: np.ndarray, lens: np.ndarray,
+                 count: "StringCount | None" = None):
+        self._blob = blob
+        self._offs = offs
+        self._lens = lens
+        self._count = count if count is not None else StringCount()
+
+    def __len__(self) -> int:
+        return len(self._offs)
+
+    def _made(self, offs, lens) -> List[str]:
+        self._count.n += len(offs)
+        return decode_spans(self._blob, offs, lens)
+
+    def __getitem__(self, j):
+        if isinstance(j, slice):
+            return self._made(self._offs[j], self._lens[j])
+        j = operator.index(j)
+        if not -len(self._offs) <= j < len(self._offs):
+            raise IndexError("list index out of range")
+        self._count.n += 1
+        return _decode_span(
+            self._blob, int(self._offs[j]), int(self._lens[j])
+        )
+
+    def __iter__(self):
+        return iter(self._made(self._offs, self._lens))
+
+    def __eq__(self, other):
+        if not isinstance(other, (list, SpanStrings)):
+            return NotImplemented
+        return list(self) == list(other)
+
+    __hash__ = None
+
+    def __repr__(self) -> str:
+        return f"SpanStrings({list(self)!r})"
+
+
 class LazyLine:
     """ParsedLine-compatible view over one native-parsed row.
 
@@ -237,12 +317,15 @@ class NativeWork:
     """(orig_index, line) sequence backed by the native ParsedBatch.
 
     `rows` are indices into the parse batch (== original line indices);
-    `ip_inv`/`host_inv` index the shared unique-string tables. Slicing
-    shares the tables (compaction happens in unique_ips, where a stale
-    entry would otherwise leak a slot pin).  `ip_spans` is the key bytes
-    of `ips_u`, entry for entry, as (buf uint8, offs int64, lens int64):
-    what the submit stage's address pass works on (None: it takes the
-    strings)."""
+    `ip_inv`/`host_inv` index the shared distinct-address and
+    distinct-host tables: `ips_u` a SpanStrings (a string an address
+    when one is asked for; a list where the gate had to make them
+    anyway), `hosts_u` a list — few, and `host_idx` reads every one for
+    every chunk.  Slicing shares the tables (compaction happens in
+    unique_ips, where a stale entry would otherwise leak a slot pin).
+    `ip_spans` is the key bytes of `ips_u`, entry for entry, as (buf
+    uint8, offs int64, lens int64): what the submit stage's address
+    pass works on (None: it takes the strings)."""
 
     __slots__ = (
         "nb", "rows", "ips_u", "ip_inv", "hosts_u", "host_inv", "ts_ns",
@@ -253,7 +336,7 @@ class NativeWork:
                  defer_map, ip_spans=None):
         self.nb = nb
         self.rows = rows                  # np.int64 [n] — nb/original rows
-        self.ips_u: List[str] = ips_u
+        self.ips_u: Sequence[str] = ips_u
         self.ip_inv = ip_inv              # np.int64 [n] -> ips_u
         self.hosts_u: List[str] = hosts_u
         self.host_inv = host_inv          # np.int64 [n] -> hosts_u
@@ -321,7 +404,7 @@ class NativeWork:
             return None
         return merge_spans([(self.ip_spans, self.ip_inv)])
 
-    def unique_ips(self) -> Tuple[List[str], np.ndarray]:
+    def unique_ips(self) -> Tuple[Sequence[str], np.ndarray]:
         """(distinct ips present in THIS view, per-row inverse). Compacts
         the shared table so a slice never allocates (and pins) window
         slots for ips that aren't in it."""
@@ -507,7 +590,10 @@ class CompositeWork:
         return CompositeWork(parts, offsets)
 
     def unique_ips(self) -> Tuple[List[str], np.ndarray]:
-        tables = [w.unique_ips() for w in self.parts]
+        tables = [
+            (list(ips_u), inv)
+            for ips_u, inv in (w.unique_ips() for w in self.parts)
+        ]
         # dict.fromkeys keeps each string where it is met first: shard
         # order, then the shard's own first-appearance order
         strings = list(dict.fromkeys(
@@ -559,54 +645,3 @@ class CompositeWork:
             np.concatenate([g[0] for g in got]),
             np.concatenate([g[1] for g in got]),
         )
-
-
-def unique_spans(
-    offs: np.ndarray, lens: np.ndarray, decode,
-    blob: "bytes | None" = None, text: "str | None" = None,
-    dedup_scratch=None,
-) -> Tuple[List[str], np.ndarray, np.ndarray]:
-    """Distinct-string extraction over (offset, length) spans of a blob.
-
-    Fast path (native lib + `blob`): C open-addressing dedup
-    (fastparse.c fp_dedup_spans) emits first-appearance-ordered ids
-    directly; unique strings slice out of the ASCII `text` in one comp.
-    Fallback (native lib failed to load mid-flight — the gate itself only
-    runs with it loaded, so this is belt-and-braces): exact per-row dict
-    dedup over decoded strings, trivially correct and first-appearance
-    ordered. Returns (unique strings, per-row inverse, the row each
-    string was first met in — where its bytes lie)."""
-    n = len(offs)
-    if n == 0:
-        return [], np.zeros(0, dtype=np.int64), np.zeros(0, dtype=np.int64)
-    if blob is not None:
-        from banjax_tpu import native as _native
-
-        df = _native.dedup_spans(blob, offs, lens, dedup_scratch)
-        if df is not None:
-            ids, first = df
-            if text is not None:
-                # tolist() first: per-item numpy-scalar -> int conversions
-                # cost more than the slices themselves at 65k uniques
-                ot = offs.tolist()
-                lt = lens.tolist()
-                strings = [
-                    text[ot[r] : ot[r] + lt[r]] for r in first.tolist()
-                ]
-            else:
-                strings = [decode(int(r)) for r in first]
-            return strings, ids, first
-    seen: Dict[str, int] = {}
-    strings: List[str] = []
-    first_rows: List[int] = []
-    inv = np.empty(n, dtype=np.int64)
-    for r in range(n):
-        s = decode(r)
-        j = seen.get(s)
-        if j is None:
-            j = len(strings)
-            strings.append(s)
-            first_rows.append(r)
-            seen[s] = j
-        inv[r] = j
-    return strings, inv, np.asarray(first_rows, dtype=np.int64)

@@ -14,6 +14,7 @@ per-line parse loop is the host bottleneck; this runs it at memory speed.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -25,7 +26,6 @@ from typing import Optional
 import numpy as np
 
 from banjax_tpu.matcher.longrows import LONG_WIDTH
-from banjax_tpu.native.cptr import array_ptr
 
 log = logging.getLogger(__name__)
 
@@ -47,8 +47,12 @@ def _so_path() -> str:
         "BANJAX_NATIVE_CACHE", os.path.join(tempfile.gettempdir(), "banjax-native")
     )
     os.makedirs(cache_dir, exist_ok=True)
-    src_mtime = int(os.stat(_SRC).st_mtime)
-    return os.path.join(cache_dir, f"fastparse_{plat}_{src_mtime}.so")
+    # named by what the source says, not by when it was written: two
+    # checkouts unpacked in the same second (a parent and a change, one
+    # cache directory) must not load each other's build
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha1(f.read()).hexdigest()[:16]
+    return os.path.join(cache_dir, f"fastparse_{plat}_{digest}.so")
 
 
 def _compile(so: str) -> bool:
@@ -85,22 +89,27 @@ def _load() -> Optional[ctypes.CDLL]:
         except OSError as e:
             log.warning("could not load %s: %s", so, e)
             return None
-        i64p = ctypes.POINTER(ctypes.c_int64)
-        i32p = ctypes.POINTER(ctypes.c_int32)
-        u8p = ctypes.POINTER(ctypes.c_uint8)
-        lib.fp_split_lines.restype = ctypes.c_int64
-        lib.fp_split_lines.argtypes = [u8p, ctypes.c_int64, i64p, i64p, ctypes.c_int64]
-        lib.fp_parse_encode.restype = ctypes.c_int64
+        # every pointer argument is a plain address: the blob goes in as
+        # the bytes object it is, an array as the address its scratch
+        # took once (ParseScratch / DedupScratch), with nothing to cast
+        ptr, i64 = ctypes.c_void_p, ctypes.c_int64
+        lib.fp_split_lines.restype = i64
+        lib.fp_split_lines.argtypes = [ptr, i64, ptr, ptr, i64]
+        lib.fp_parse_encode.restype = i64
         lib.fp_parse_encode.argtypes = [
-            u8p, ctypes.c_int64, i64p, i64p, ctypes.c_int64,
-            i32p, ctypes.c_int32, ctypes.c_int32, ctypes.c_double,
+            ptr, i64, ptr, ptr, i64,
+            ptr, ctypes.c_int32, ctypes.c_int32, ctypes.c_double,
             ctypes.c_double,
-            i64p, u8p, i64p, i32p, i64p, i32p, i64p, i32p, i32p, i32p,
+            ptr, ptr, ptr, ptr, ptr, ptr, ptr, ptr, ptr, ptr,
         ]
-        lib.fp_dedup_spans.restype = ctypes.c_int64
+        lib.fp_dedup_spans.restype = i64
         lib.fp_dedup_spans.argtypes = [
-            u8p, ctypes.c_int64, i64p, i32p, ctypes.c_int64,
-            i64p, ctypes.c_int64, i64p, i64p,
+            ptr, i64, ptr, ptr, i64, ptr, i64, ptr, ptr,
+        ]
+        lib.fp_gate.restype = i64
+        lib.fp_gate.argtypes = [
+            ptr, i64, ptr, ptr, ptr, ptr, ptr, ptr, ptr, ctypes.c_int32,
+            ptr, i64, ptr,
         ]
         _LIB = lib
         log.info("native fastparse loaded (%s)", so)
@@ -121,11 +130,11 @@ class ParsedBatch:
     __slots__ = (
         "blob", "n", "ts_ns", "flags", "ip_off", "ip_len",
         "host_off", "host_len", "rest_off", "rest_len", "cls_ids", "lens",
-        "_text",
+        "addrs",
     )
 
     def __init__(self, blob, n, ts_ns, flags, ip_off, ip_len, host_off,
-                 host_len, rest_off, rest_len, cls_ids, lens):
+                 host_len, rest_off, rest_len, cls_ids, lens, addrs=None):
         self.blob = blob
         self.n = n
         self.ts_ns = ts_ns
@@ -135,17 +144,9 @@ class ParsedBatch:
         self.rest_off, self.rest_len = rest_off, rest_len
         self.cls_ids = cls_ids
         self.lens = lens
-        self._text = False  # False = not computed; None = non-ascii blob
-
-    def text(self):
-        """The whole blob as ONE str when it is pure ASCII (byte offsets
-        == str offsets, so span strings are plain slices — ~10x cheaper
-        than per-span bytes.decode), else None. Decoded once, cached."""
-        if self._text is False:
-            self._text = (
-                self.blob.decode("ascii") if self.blob.isascii() else None
-            )
-        return self._text
+        # the columns' addresses as their scratch holds them
+        # (ParseScratch.addrs): what gate() hands to C
+        self.addrs = addrs
 
     def _span(self, off, ln, i) -> str:
         o = int(off[i])
@@ -194,7 +195,21 @@ class ParseScratch:
         self.rest_len = np.empty(cap, dtype=np.int32)
         self.cls_ids = np.empty((cap, max_len), dtype=np.int32)
         self.lens = np.empty(cap, dtype=np.int32)
+        # the arrays' addresses, taken here once and good until a buffer
+        # grows: starts, ends, then fp_parse_encode's outputs in its
+        # argument order; and the bytes a row of each
+        cols = (
+            self.starts, self.ends, self.ts_ns, self.flags, self.ip_off,
+            self.ip_len, self.host_off, self.host_len, self.rest_off,
+            self.rest_len, self.cls_ids, self.lens,
+        )
+        self.addrs = tuple(a.ctypes.data for a in cols)
+        self.strides = tuple(a.strides[0] for a in cols)
 
+
+# where a column's address lies in ParseScratch.addrs
+_STARTS, _ENDS, _TS_NS, _FLAGS, _IP_OFF, _IP_LEN, _HOST_OFF, _HOST_LEN, \
+    _REST_OFF, _REST_LEN = range(10)
 
 # parse threads: fp_parse_encode is row-parallel and ctypes releases the
 # GIL, so splitting the row range across a few threads scales the 14.5 ms
@@ -223,7 +238,6 @@ def parse_encode_batch(
         return None
     blob = "\n".join(lines).encode("utf-8", "surrogatepass")
     n = len(lines)
-    buf = np.frombuffer(blob, dtype=np.uint8)
     if n == 0:
         empty64 = np.zeros(0, dtype=np.int64)
         empty32 = np.zeros(0, dtype=np.int32)
@@ -233,36 +247,26 @@ def parse_encode_batch(
 
     s = scratch if scratch is not None else ParseScratch()
     s.ensure(n, max_len)
-    starts, ends = s.starts[:n], s.ends[:n]
-    u8p = ctypes.POINTER(ctypes.c_uint8)
-    i64p = ctypes.POINTER(ctypes.c_int64)
-    i32p = ctypes.POINTER(ctypes.c_int32)
-
-    P = array_ptr
-    blob_ptr = array_ptr(buf, u8p) if buf.size else ctypes.cast(
-        ctypes.c_char_p(b""), u8p
-    )
-    got = lib.fp_split_lines(blob_ptr, len(blob), P(starts, i64p), P(ends, i64p), n)
+    addrs = s.addrs
+    got = lib.fp_split_lines(blob, len(blob), addrs[_STARTS], addrs[_ENDS], n)
     # embedded newline inside a "line" (callers pass tailer lines, which
     # cannot contain one) would shift every subsequent span: fall back
     # rather than misattribute. Detection rides the split itself (no extra
     # blob scan): extra newlines make the capped split stop short of the
     # blob end (or, for a trailing newline, return n-1 lines).
-    if got != n or int(ends[n - 1]) != len(blob):
+    if got != n or int(s.ends[n - 1]) != len(blob):
         return None
 
     table = np.ascontiguousarray(byte_to_class[:256], dtype=np.int32)
+    table_addr = table.ctypes.data
 
     def run_range(i0: int, cnt: int) -> None:
+        a = addrs if i0 == 0 else tuple(
+            p + i0 * st for p, st in zip(addrs, s.strides)
+        )
         lib.fp_parse_encode(
-            blob_ptr, len(blob),
-            P(s.starts[i0:], i64p), P(s.ends[i0:], i64p), cnt,
-            P(table, i32p), max_len, LONG_WIDTH, now_unix, old_cutoff,
-            P(s.ts_ns[i0:], i64p), P(s.flags[i0:], u8p),
-            P(s.ip_off[i0:], i64p), P(s.ip_len[i0:], i32p),
-            P(s.host_off[i0:], i64p), P(s.host_len[i0:], i32p),
-            P(s.rest_off[i0:], i64p), P(s.rest_len[i0:], i32p),
-            P(s.cls_ids[i0:], i32p), P(s.lens[i0:], i32p),
+            blob, len(blob), a[_STARTS], a[_ENDS], cnt,
+            table_addr, max_len, LONG_WIDTH, now_unix, old_cutoff, *a[2:],
         )
 
     limit = _PARSE_THREADS if max_threads is None else max(1, max_threads)
@@ -286,11 +290,11 @@ def parse_encode_batch(
     return ParsedBatch(blob, n, s.ts_ns[:n], s.flags[:n], s.ip_off[:n],
                        s.ip_len[:n], s.host_off[:n], s.host_len[:n],
                        s.rest_off[:n], s.rest_len[:n], s.cls_ids[:n],
-                       s.lens[:n])
+                       s.lens[:n], addrs)
 
 
 class DedupScratch:
-    """Reusable hash-table + output buffers for dedup_spans."""
+    """Reusable hash-table + output buffers for dedup_spans and gate."""
 
     def __init__(self):
         self.cap = 0
@@ -306,6 +310,11 @@ class DedupScratch:
         self.table = np.empty(tcap, dtype=np.int64)
         self.ids = np.empty(cap, dtype=np.int64)
         self.first = np.empty(cap, dtype=np.int64)
+        # (table, ids, first): addresses good until the next growth
+        self.addrs = (
+            self.table.ctypes.data, self.ids.ctypes.data,
+            self.first.ctypes.data,
+        )
 
 
 def dedup_spans(blob, offs, lens, scratch=None):
@@ -320,20 +329,65 @@ def dedup_spans(blob, offs, lens, scratch=None):
     s.ensure(n)
     if n == 0:
         return np.zeros(0, dtype=np.int64), np.zeros(0, dtype=np.int64)
-    buf = np.frombuffer(blob, dtype=np.uint8)
     offs = np.ascontiguousarray(offs, dtype=np.int64)
     lens = np.ascontiguousarray(lens, dtype=np.int32)
-    u8p = ctypes.POINTER(ctypes.c_uint8)
-    i64p = ctypes.POINTER(ctypes.c_int64)
-    i32p = ctypes.POINTER(ctypes.c_int32)
-    tcap = len(s.table)
+    table, ids, first = s.addrs
     n_uniq = lib.fp_dedup_spans(
-        array_ptr(buf, u8p), len(blob),
-        array_ptr(offs, i64p), array_ptr(lens, i32p), n,
-        array_ptr(s.table, i64p), tcap,
-        array_ptr(s.ids, i64p), array_ptr(s.first, i64p),
+        bytes(blob), len(blob), offs.ctypes.data, lens.ctypes.data, n,
+        table, len(s.table), ids, first,
     )
-    # copies, NOT views: a second dedup with the same scratch (the gate
-    # runs ip then host spans back to back) must not clobber the first
-    # call's result
+    # copies, NOT views: a second dedup with the same scratch (the
+    # reference gate runs ip then host spans back to back) must not
+    # clobber the first call's result
     return s.ids[:n].copy(), s.first[:n_uniq].copy()
+
+
+class GatedBatch:
+    """What one fp_gate call leaves of a ParsedBatch: the candidate rows
+    (neither error nor old nor deferred) in row order and, row for row,
+    their `ts`, `ip_inv` / `host_inv` (first-appearance ids of the
+    address and of the host) and `host_eval` / `long_len` (as
+    longrows.long_lens has it); the distinct addresses' and hosts' spans
+    in the blob, id order, int64; and how many rows carried ERROR, OLD,
+    DEFER and (among the candidates) HOST_EVAL.  Every array is a view
+    of one allocation of this call's own — nothing aliases a scratch."""
+
+    __slots__ = (
+        "rows", "ts", "ip_inv", "host_inv", "ip_off", "ip_len", "host_off",
+        "host_len", "long_len", "host_eval", "n_err", "n_old", "n_defer",
+        "n_host_eval",
+    )
+
+
+def gate(nb: ParsedBatch, scratch: Optional[DedupScratch] = None
+         ) -> Optional[GatedBatch]:
+    """The gate's columnar half over a parsed batch, one foreign call
+    (fastparse.c fp_gate); None without the native library or over a
+    batch that did not come off a ParseScratch."""
+    lib = _load()
+    n = nb.n
+    if lib is None or nb.addrs is None or n == 0:
+        return None
+    s = scratch if scratch is not None else DedupScratch()
+    s.ensure(n)
+    a = nb.addrs
+    out = np.empty(10 * n + 8, dtype=np.int64)
+    lib.fp_gate(
+        nb.blob, n, a[_FLAGS], a[_TS_NS], a[_IP_OFF], a[_IP_LEN],
+        a[_HOST_OFF], a[_HOST_LEN], a[_REST_LEN], LONG_WIDTH,
+        s.addrs[0], len(s.table), out.ctypes.data,
+    )
+    g = GatedBatch()
+    c, n_ip, n_host, g.n_err, g.n_old, g.n_defer, g.n_host_eval, _ = \
+        out[10 * n :].tolist()
+    g.rows = out[:c]
+    g.ts = out[n : n + c]
+    g.ip_inv = out[2 * n : 2 * n + c]
+    g.host_inv = out[3 * n : 3 * n + c]
+    g.ip_off = out[4 * n : 4 * n + n_ip]
+    g.ip_len = out[5 * n : 5 * n + n_ip]
+    g.host_off = out[6 * n : 6 * n + n_host]
+    g.host_len = out[7 * n : 7 * n + n_host]
+    g.long_len = out[8 * n : 9 * n].view(np.int32)[:c]
+    g.host_eval = out[9 * n : 10 * n].view(np.bool_)[:c]
+    return g

@@ -142,6 +142,45 @@ static uint64_t span_hash(const uint8_t *p, int64_t n) {
     return h;
 }
 
+/* First-appearance dedup of n byte spans of `blob`.  Span i is
+ * (offs[r], lens[r]) with r = rows[i], or r = i where `rows` is NULL.
+ * ids_out[i]: the 0-based id of span i's bytes; first_out[id]: the first
+ * i carrying it.  `table`: table_cap int64 of scratch, table_cap a power
+ * of two >= 2n, primed here.  Returns the distinct count. */
+static int64_t dedup_rows(
+    const uint8_t *blob, const int64_t *offs, const int32_t *lens,
+    const int64_t *rows, int64_t n, int64_t *table, int64_t table_cap,
+    int64_t *ids_out, int64_t *first_out) {
+    for (int64_t i = 0; i < table_cap; i++)
+        table[i] = -1;
+    uint64_t mask = (uint64_t)table_cap - 1;
+    int64_t n_uniq = 0;
+    for (int64_t i = 0; i < n; i++) {
+        int64_t r = rows ? rows[i] : i;
+        const uint8_t *p = blob + offs[r];
+        int64_t len = lens[r];
+        uint64_t slot = span_hash(p, len) & mask;
+        for (;;) {
+            int64_t j = table[slot];
+            if (j < 0) {
+                table[slot] = i;
+                ids_out[i] = n_uniq;
+                first_out[n_uniq] = i;
+                n_uniq++;
+                break;
+            }
+            int64_t rj = rows ? rows[j] : j;
+            if (lens[rj] == len &&
+                memcmp(blob + offs[rj], p, (size_t)len) == 0) {
+                ids_out[i] = ids_out[j];
+                break;
+            }
+            slot = (slot + 1) & mask;
+        }
+    }
+    return n_uniq;
+}
+
 /* Deduplicate (offset, length) spans into first-appearance-ordered ids.
  *
  * Replaces the hot path's numpy window-gather + sort-based unique: at 65k
@@ -160,31 +199,91 @@ int64_t fp_dedup_spans(
     int64_t *table, int64_t table_cap,
     int64_t *ids_out, int64_t *first_out) {
     (void)blob_len;
-    for (int64_t i = 0; i < table_cap; i++)
-        table[i] = -1;
-    uint64_t mask = (uint64_t)table_cap - 1;
-    int64_t n_uniq = 0;
-    for (int64_t i = 0; i < n; i++) {
-        const uint8_t *p = blob + offs[i];
-        int64_t len = lens[i];
-        uint64_t slot = span_hash(p, len) & mask;
-        for (;;) {
-            int64_t j = table[slot];
-            if (j < 0) {
-                table[slot] = i;
-                ids_out[i] = n_uniq;
-                first_out[n_uniq] = i;
-                n_uniq++;
-                break;
-            }
-            if (lens[j] == len && memcmp(blob + offs[j], p, (size_t)len) == 0) {
-                ids_out[i] = ids_out[j];
-                break;
-            }
-            slot = (slot + 1) & mask;
+    return dedup_rows(blob, offs, lens, NULL, n, table, table_cap, ids_out,
+                      first_out);
+}
+
+/* The gate over one parsed batch (or shard), in one pass: what
+ * matcher/runner.py _native_gate composed from flag masks, two
+ * fp_dedup_spans calls and a dozen gathers.  From the parse's columns it
+ * writes, for the candidate rows — neither ERROR nor OLD nor DEFER (a
+ * deferred row is the caller's to parse and patch in) — in row order:
+ *
+ *   out[0n..]  rows       the candidates' row numbers
+ *   out[1n..]  ts         their ts_ns (a copy: the parse's may be scratch)
+ *   out[2n..]  ip_inv     row -> distinct address, first-appearance ids
+ *   out[3n..]  host_inv   row -> distinct host
+ *   out[4n..]  ip_off     the distinct addresses' spans in the blob,
+ *   out[5n..]  ip_len       id order, as int64
+ *   out[6n..]  host_off   the distinct hosts' spans
+ *   out[7n..]  host_len
+ *   out[8n..]  long_len   int32 per candidate: a LONG row's rest_len, -1
+ *                         for a row past long_width, 0 for every other
+ *   out[9n..]  host_eval  one byte per candidate, 1 where HOST_EVAL
+ *
+ * `out` is 10n + 8 int64 of the caller's; the last 8 are the counts:
+ * candidates, distinct addresses, distinct hosts, ERROR rows, OLD rows,
+ * DEFER rows, HOST_EVAL candidates, 0.  `table` as in fp_dedup_spans
+ * (>= 2n).  out[4n..8n] serve as the dedups' scratch before they hold
+ * the spans.  Returns the number of candidates. */
+int64_t fp_gate(
+    const uint8_t *blob, int64_t n,
+    const uint8_t *flags, const int64_t *ts_ns,
+    const int64_t *ip_off, const int32_t *ip_len,
+    const int64_t *host_off, const int32_t *host_len,
+    const int32_t *rest_len, int32_t long_width,
+    int64_t *table, int64_t table_cap, int64_t *out) {
+    int64_t *rows = out, *ts = out + n, *ip_inv = out + 2 * n,
+            *host_inv = out + 3 * n, *u_ip_off = out + 4 * n,
+            *u_ip_len = out + 5 * n, *u_host_off = out + 6 * n,
+            *u_host_len = out + 7 * n, *counts = out + 10 * n;
+    int32_t *long_len = (int32_t *)(out + 8 * n);
+    uint8_t *host_eval = (uint8_t *)(out + 9 * n);
+    int64_t n_c = 0, n_err = 0, n_old = 0, n_defer = 0, n_he = 0;
+    for (int64_t r = 0; r < n; r++) {
+        uint8_t f = flags[r];
+        if (f & FLAG_ERROR) {
+            n_err++;
+        } else if (f & FLAG_OLD) {
+            n_old++;
+        } else if (f & FLAG_DEFER) {
+            n_defer++;
+        } else {
+            uint8_t he = (f & FLAG_HOST_EVAL) != 0;
+            rows[n_c] = r;
+            ts[n_c] = ts_ns[r];
+            host_eval[n_c] = he;
+            long_len[n_c] = (f & FLAG_LONG) ? rest_len[r]
+                            : (he && rest_len[r] > long_width) ? -1 : 0;
+            n_he += he;
+            n_c++;
         }
     }
-    return n_uniq;
+    /* each dedup's first rows land in the slab that takes the lengths,
+     * and are read out before that entry is written */
+    int64_t n_ip = dedup_rows(blob, ip_off, ip_len, rows, n_c, table,
+                              table_cap, ip_inv, u_ip_len);
+    for (int64_t u = 0; u < n_ip; u++) {
+        int64_t r = rows[u_ip_len[u]];
+        u_ip_off[u] = ip_off[r];
+        u_ip_len[u] = ip_len[r];
+    }
+    int64_t n_host = dedup_rows(blob, host_off, host_len, rows, n_c, table,
+                                table_cap, host_inv, u_host_len);
+    for (int64_t u = 0; u < n_host; u++) {
+        int64_t r = rows[u_host_len[u]];
+        u_host_off[u] = host_off[r];
+        u_host_len[u] = host_len[r];
+    }
+    counts[0] = n_c;
+    counts[1] = n_ip;
+    counts[2] = n_host;
+    counts[3] = n_err;
+    counts[4] = n_old;
+    counts[5] = n_defer;
+    counts[6] = n_he;
+    counts[7] = 0;
+    return n_c;
 }
 
 /* Parse + encode every line. Outputs are caller-allocated arrays sized

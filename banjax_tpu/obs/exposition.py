@@ -198,6 +198,16 @@ def render_prometheus(
         for cause, v in unfused.items():
             w.sample(fam, v, {"cause": cause})
 
+    # the encode stage's gate: shards by what gated them, and the
+    # address strings made from spans since
+    gated = getattr(matcher, "gate_shards", None) if matcher else None
+    if gated is not None:
+        fam = registry.PROM_FAMILIES["banjax_encode_gate_shards_total"]
+        for path, v in gated.items():
+            w.sample(fam, v, {"path": path})
+        w.sample(registry.PROM_FAMILIES["banjax_gate_address_strings_total"],
+                 matcher.gate_address_strings)
+
     # ban-log writes by file: with banjax_regex_ban_records_total,
     # records a write
     writes = getattr(

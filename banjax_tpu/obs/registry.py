@@ -360,6 +360,18 @@ FAMILIES: List[Family] = [
            "line_length (a line past 8,192 bytes); no fused dispatch, so "
            "banjax_pipelined_fused_fallbacks_total does not see them",
            prom="banjax_matcher_unfused_batches_total", labels=("cause",)),
+    Family(COUNTER, "encode shards (an unsharded batch is one) by what "
+           "gated their lines: native (one call into C over the parse's "
+           "columns: candidate rows, first-appearance tables of addresses "
+           "and hosts, per-row columns) or python (the per-line loop: no "
+           "native library, a line with a newline in it); counted where "
+           "the encode thread merges the shards",
+           prom="banjax_encode_gate_shards_total", labels=("path",)),
+    Family(COUNTER, "address strings made from a gated batch's byte "
+           "spans, whoever asked (the exceeded rows' lines at the drain, "
+           "a deferred row's patch, the allowlist, the dict path); the "
+           "hot path reads the spans and makes none",
+           prom="banjax_gate_address_strings_total"),
     Family(COUNTER, "window events committed by fused programs, by where "
            "the program took the event from: a (row, rule) pair of the "
            "filtered rules, or a set bit of an always-column (their sum is "

@@ -35,6 +35,14 @@ from banjax_tpu.obs.registry import Histogram, StageHistograms
 
 _LATENCY_RING = 512  # recent batch latencies kept for the percentiles
 _DEVICE_RING = 256   # recent device-stage latencies for the pipeline p99
+# Floor of the breaker's derived latency budget.  The stage's wall is mostly
+# the submitting thread's wait for the interpreter, so a batch of a few lines
+# takes 0.1-0.5 s whenever another thread of the process computes (found on
+# the chip: PERF.md, PR 41 and PR 50), and 3x a p99 of 20-50 ms is under that:
+# the faster the stage, the sooner three such batches in a row sent every
+# line to the CPU matcher.  A second is a tenth of the age at which a line is
+# dropped as stale, and far under what a wedged device costs
+_BUDGET_FLOOR_S = 1.0
 
 
 def _r3(v):
@@ -489,12 +497,12 @@ class PipelineStats:
 
     def suggested_latency_budget_s(self) -> float:
         """Derived breaker budget: 3x the EWMA device p99, floored at
-        50 ms (ROADMAP breaker-tuning item).  0.0 until a p99 exists —
-        the breaker treats 0 as 'no budget', same as the unset config."""
+        `_BUDGET_FLOOR_S`.  0.0 until a p99 exists — the breaker treats 0
+        as 'no budget', same as the unset config."""
         with self._lock:
             if self._device_p99_ewma is None:
                 return 0.0
-            return max(0.05, 3.0 * self._device_p99_ewma)
+            return max(_BUDGET_FLOOR_S, 3.0 * self._device_p99_ewma)
 
     def _totals_locked(self) -> Dict[str, object]:
         return {

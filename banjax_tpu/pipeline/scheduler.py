@@ -503,7 +503,7 @@ class PipelineScheduler:
                     # breaker-budget satellite: when
                     # matcher_latency_budget_ms is unset the breaker
                     # derives it from this pipeline's observed device p99
-                    # (3x EWMA p99, floor 50 ms)
+                    # (3x EWMA p99, floor 1 s)
                     matcher.set_latency_budget_source(
                         self.stats.suggested_latency_budget_s
                     )

@@ -793,7 +793,7 @@ class ScenarioRunner:
             true_positives=tp,
             precision=round(precision, 6),
             recall=round(recall, 6),
-            # the derived-budget input (3x p99, floor 50 ms): hostile-
+            # the derived-budget input (3x p99, floor 1 s): hostile-
             # shape device p99, banked so the chip round can set
             # matcher_latency_budget_ms from episode data
             device_p99_ms=peek.get("PipelineDeviceP99Ms"),

@@ -670,6 +670,11 @@ _SUBMIT_FAMILIES = {
     "banjax_submit_runtime_calls_total": [{}],
     "banjax_device_windows_maintenance_steps_by_carrier_total":
         [{"carrier": c} for c in ("fused", "own")],
+    # the encode stage's gate: one native call a shard, a string an
+    # address only where one is asked for (ISSUE 50)
+    "banjax_encode_gate_shards_total":
+        [{"path": p} for p in ("native", "python")],
+    "banjax_gate_address_strings_total": [{}],
     # the cyclic collector under the pipeline (ISSUE 40)
     "banjax_gc_collections_total": [{"generation": g} for g in "012"],
     "banjax_gc_pause_seconds_total": [{"generation": g} for g in "012"],
@@ -709,6 +714,16 @@ def test_submit_stage_family_is_on_metrics_with_its_labels(
     if family.endswith("_steps_by_carrier_total"):
         assert prom.value(snap, family) == prom.value(
             snap, "banjax_device_windows_maintenance_steps_total")
+    if family == "banjax_encode_gate_shards_total":
+        # six batches under the shard floor: each gated whole, natively
+        before = submit_scrapes[0]
+        assert prom.delta(before, snap, family, path="native") == \
+            prom.delta(before, snap, "banjax_pipeline_batches_total") == 6
+        assert prom.value(snap, family, path="python") == 0
+    if family == "banjax_gate_address_strings_total":
+        # six hundred distinct addresses gated, placed and counted in
+        # their windows, none past its limit: nothing asked for a string
+        assert prom.delta(submit_scrapes[0], snap, family) == 0
     if family == "banjax_gc_collections_total":
         # the freeze at the first matcher collects the heap whole first
         assert prom.value(snap, family, generation="2") >= 1
@@ -848,6 +863,63 @@ def test_runtime_call_reader_reads_its_family(submit_scrapes, name):
     else:
         # 600 addresses through 256 slots: every run rode its chunk
         assert got == 100.0
+
+
+_GATE_SHARDS = "banjax_encode_gate_shards_total"
+_GATE_STRINGS = "banjax_gate_address_strings_total"
+_GATE_READERS = {
+    # reader: (its entry in BENCHMARK.json less the name, the reading off
+    #          the synthetic pair below)
+    "encode_cpu_ms_per_kline": (
+        {"unit": "ms/kline", "better": "lower"}, 350.0),
+    "gate_native_share": ({"unit": "%", "better": "higher"}, 95.0),
+    "gate_address_strings_per_kline": (
+        {"unit": "count/kline", "better": "lower"}, 4.0),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_GATE_READERS))
+def test_gate_reader_reads_its_family(submit_scrapes, name):
+    """ISSUE 50's three readers: listed for every cell under the layer
+    "host encode", read their families off two synthetic scrapes (3.5 s
+    of encode-side CPU, 95 of 100 shards gated natively and 40 address
+    strings made, over 10,000 lines) and off a real pair, and are silent
+    on a program without the family and over an idle window."""
+    from benchmark.harness import found
+
+    entry, want = _GATE_READERS[name]
+    assert [m for m in found.benchmark_json()["per_layer"]
+            if m["name"] == name] == [{
+                "name": name, **entry, "source": "program_counter",
+                "layer": "host encode", "moves": "lines_per_s"}]
+    cpu = "banjax_thread_cpu_seconds_total"
+    p0, p1 = _scrape_pair(cpu, [{"thread": "pipeline-encode"}], 1.0, 2.5)
+    worker = (cpu, (("thread", "pipeline-encode-worker"),))
+    device = (cpu, (("thread", "pipeline-device"),))
+    native = (_GATE_SHARDS, (("path", "native"),))
+    python = (_GATE_SHARDS, (("path", "python"),))
+    p0.update({worker: 4.0, device: 9.0, native: 10.0, python: 0.0,
+               (_GATE_STRINGS, ()): 5.0})
+    p1.update({worker: 6.0, device: 99.0, native: 105.0, python: 5.0,
+               (_GATE_STRINGS, ()): 45.0})
+    reader = found.module("layers", name)
+    assert reader.read({"prom0": p0, "prom1": p1}) == pytest.approx(want)
+    assert reader.read({"prom0": {}, "prom1": {}}) is None
+    assert reader.read({"prom0": p1, "prom1": p1}) is None
+    if name == "encode_cpu_ms_per_kline":
+        # a pipeline without the pool (no shard ever fanned out) has the
+        # encode thread's clock alone
+        del p0[worker], p1[worker]
+        assert reader.read({"prom0": p0, "prom1": p1}) == pytest.approx(150.0)
+    real0, real1, _ = submit_scrapes
+    got = reader.read({"prom0": real0, "prom1": real1})
+    if name == "gate_native_share":
+        assert got == 100.0
+    elif name == "gate_address_strings_per_kline":
+        # 600 addresses, none past its limit: no row's line was asked for
+        assert got == 0.0
+    else:
+        assert got is not None and got >= 0
 
 
 def test_stage2_readers_split_a_trace_by_nfa_words():

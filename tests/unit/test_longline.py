@@ -372,7 +372,7 @@ def test_native_and_python_parse_agree_on_long_rows():
     assert ((flags & native.FLAG_LONG) != 0).tolist() == (want > 0).tolist()
     assert nb.rest_len[want > 0].tolist() == want[want > 0].tolist()
     # the matcher's two encodes of the batch agree to the element
-    work, pre = m._gate(lines, now, [None] * len(lines))
+    work, pre, _ = m._gate(lines, now, [None] * len(lines))
     py = m._encode_work(ListWork(
         (i, _parsed(ln, now)) for i, ln in enumerate(lines)))
     for a, b in zip(pre, py):

@@ -185,7 +185,11 @@ def test_the_cell_is_the_issues():
         "resolve_spans_share", "eviction_scan_slots_per_kline",
         # one call into the runtime a chunk, the table's maintenance
         # riding it (ISSUE 49): every cell
-        "submit_runtime_calls_per_batch", "maintenance_fused_share"])
+        "submit_runtime_calls_per_batch", "maintenance_fused_share",
+        # the encode side's CPU and the gate's two counters (ISSUE 50):
+        # every cell
+        "encode_cpu_ms_per_kline", "gate_native_share",
+        "gate_address_strings_per_kline"])
     pc = CONFIG["product_config"]
     assert {k: v for k, v in pc.items() if k != "config_version"} == {
         k: v for k, v in found.data("configs", "upstream-stress10k")[

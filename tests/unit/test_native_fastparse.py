@@ -184,11 +184,12 @@ def test_fast_timestamp_path_bit_identical_to_python_float():
 
 
 def test_unique_spans_fallback_matches_native():
-    """The scalar fallback of workset.unique_spans (native lib absent)
-    produces the same first-appearance-ordered tables as the C dedup."""
+    """The scalar fallback of the reference gate's unique_spans (native
+    lib absent) produces the same first-appearance-ordered tables as the
+    C dedup."""
     import numpy as np
 
-    from banjax_tpu.matcher.workset import unique_spans
+    from tests.gate_reference import unique_spans
 
     blob = b"zz one two one three two zz one"
     words = blob.split(b" ")

@@ -8,6 +8,7 @@ JAX_PLATFORMS=cpu) — tier-1 marker hygiene for the pipeline suite.
 """
 
 import gc
+import statistics
 import threading
 import time
 import weakref
@@ -580,11 +581,32 @@ class TestProbeAndBudget:
         stats = PipelineStats()
         m.set_latency_budget_source(stats.suggested_latency_budget_s)
         assert m.effective_latency_budget_s() == 0.0  # no samples yet
-        stats.observe_device(0.004)  # 4 ms p99 → 3x = 12 ms → 50 ms floor
-        assert m.effective_latency_budget_s() == pytest.approx(0.05)
+        stats.observe_device(0.004)  # 4 ms p99 → 3x = 12 ms → the 1 s floor
+        assert m.effective_latency_budget_s() == pytest.approx(1.0)
         for _ in range(300):
-            stats.observe_device(0.1)  # 100 ms p99 → 300 ms budget
-        assert m.effective_latency_budget_s() == pytest.approx(0.3, rel=0.1)
+            stats.observe_device(0.1)  # 100 ms p99 → 300 ms: the floor still
+        assert m.effective_latency_budget_s() == pytest.approx(1.0)
+        for _ in range(300):
+            stats.observe_device(0.6)  # 600 ms p99 → 1.8 s budget
+        assert m.effective_latency_budget_s() == pytest.approx(1.8, rel=0.1)
+
+    def test_a_fast_stage_does_not_open_the_breaker_on_three_slow_batches(self):
+        """The derived budget follows the stage's p99 down only as far as
+        its floor: behind a stage of 20 ms a batch, three batches in a row
+        that waited 0.1-0.5 s for the interpreter (what the benchmark's
+        tail sends while its reference computes; the driver's first check
+        of PR 50) are no failures, and three of 1.5 s are."""
+        m, _, _ = make_matcher()  # budget unset, threshold 3
+        stats = PipelineStats()
+        m.set_latency_budget_source(stats.suggested_latency_budget_s)
+        for _ in range(300):
+            stats.observe_device(0.020)
+        for slow in (0.12, 0.46, 0.30):
+            m.note_device_outcome(slow, ok=True)
+        assert m.budget_trips == 0 and m.breaker.state == CLOSED
+        for _ in range(3):
+            m.note_device_outcome(1.5, ok=True)
+        assert m.budget_trips == 3 and m.breaker.state == OPEN
 
 
 class TestStartUpSamples:
@@ -663,7 +685,7 @@ class TestStartUpSamples:
         seen = []
 
         class DeviceBound:
-            """Every batch takes 40 ms of a device that runs one at a
+            """Every batch takes 100 ms of a device that runs one at a
             time; submit waits for the predecessor (as a full dispatch
             queue does), collect for the batch itself."""
             free_at = 0.0
@@ -673,7 +695,7 @@ class TestStartUpSamples:
 
             def pipeline_submit(self, state):
                 time.sleep(max(0.0, self.free_at - time.perf_counter()))
-                self.free_at = time.perf_counter() + 0.040
+                self.free_at = time.perf_counter() + 0.100
                 state["done_at"] = self.free_at
 
             def pipeline_collect(self, state):
@@ -693,7 +715,10 @@ class TestStartUpSamples:
         sched.stop()
         service = [ms["device"] for ms in seen[2:]]
         assert len(service) >= 8
-        assert all(30.0 <= d <= 60.0 for d in service), service
+        # d, not 2d: the median, because on a loaded machine one late
+        # wake-up makes one sample long and the next one short by as much
+        assert 85.0 <= statistics.median(service) <= 130.0, service
+        assert all(25.0 <= d <= 175.0 for d in service), service
 
 
 # ---------------------------------------------------------------------------

@@ -129,6 +129,15 @@ regexes_with_rates: []
     ):
         sl2 = StaticDecisionLists(config_from_yaml_text(base + yaml_frag))
         assert sl2.has_any_allow_entries(), yaml_frag
+    # a list spelled out empty is no source (the shipped deploy/banjax-config.yaml has `allow: []`):
+    # its filter allows nothing, and the matcher's gate may skip its check
+    for yaml_frag in (
+        "global_decision_lists:\n  allow: []\n  challenge: []\n",
+        "per_site_decision_lists:\n  a.com:\n    allow: []\n",
+    ):
+        sl4 = StaticDecisionLists(config_from_yaml_text(base + yaml_frag))
+        assert not sl4.has_any_allow_entries(), yaml_frag
+        assert not sl4.check_is_allowed("a.com", "1.1.1.1")
     # non-allow lists alone do not count
     sl3 = StaticDecisionLists(config_from_yaml_text(
         base + "global_decision_lists:\n  nginx_block:\n    - 3.3.3.3\n"
@@ -197,3 +206,22 @@ def test_ipfilter_scoped_ipv6_slow_path():
     # a scoped input is not equal to the unscoped single (ipaddress
     # equality includes the zone), so it must NOT match
     assert f.allowed("fe80::1%eth0") is False
+
+
+def test_the_shipped_configuration_has_no_allow_source():
+    """deploy/banjax-config.yaml — what the benchmark's cells and a fresh
+    deployment run — lists every decision with no address: the matcher's
+    gate makes no string of an address for its allowlist there."""
+    import os
+
+    from banjax_tpu.config.schema import config_from_yaml_text
+    from banjax_tpu.decisions.static_lists import IPFilter, StaticDecisionLists
+
+    here = os.path.dirname(os.path.abspath(__file__))
+    path = os.path.join(here, "..", "..", "deploy", "banjax-config.yaml")
+    with open(path, encoding="utf-8") as f:
+        cfg = config_from_yaml_text(f.read())
+    assert cfg.global_decision_lists.get("allow") == []
+    assert not StaticDecisionLists(cfg).has_any_allow_entries()
+    assert not IPFilter([]) and not IPFilter(["", "junk"])
+    assert IPFilter(["10.0.0.0/8"]) and IPFilter(["::1"])

@@ -15,8 +15,8 @@ from banjax_tpu.matcher.workset import (
     LazyResults,
     ListWork,
     NativeWork,
-    unique_spans,
 )
+from tests.gate_reference import blob_text, unique_spans
 
 
 def _native_batch(lines, max_len=64):
@@ -37,7 +37,7 @@ def nb():
 
 def _work_from(nb, rows=None):
     rows = np.arange(nb.n, dtype=np.int64) if rows is None else rows
-    text = nb.text()
+    text = blob_text(nb.blob)
     ips_u, ip_inv, _ = unique_spans(
         nb.ip_off[rows], nb.ip_len[rows], lambda k: nb.ip(int(rows[k])),
         blob=nb.blob, text=text,
